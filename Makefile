@@ -1,6 +1,7 @@
 # Developer/CI entry points. The heavy lifting lives in bench.py /
 # bench_sweep.py / deploy/*; these targets pin the hardware-free invocations
-# so CI and laptops run the same commands.
+# so CI and laptops run the same commands. chip-smoke, ttft-sweep and a bare
+# `python bench.py` need a TPU and fail without one.
 
 PY ?= python
 
@@ -8,7 +9,19 @@ PY ?= python
 	overload-smoke resume-smoke reconcile-smoke trace-smoke lint \
 	locksan-smoke aot-smoke pipeline-smoke ragged-smoke flight-smoke \
 	devmon-smoke capacity-smoke bench-diff bench-ragged bench-mixedfeat \
-	bench-prefixtier autoscale-smoke
+	bench-prefixtier autoscale-smoke chip-smoke chip-smoke-4
+
+# The served path end to end ON THE CHIP (chip_smoke.py): the default server
+# at Qwen3-0.6B width and depth, random seeded weights, real HTTP requests,
+# kernel parity and end-to-end logprob checks. One process holds the chip;
+# exits non-zero without a TPU. The last stdout line is the verdict.
+chip-smoke:
+	$(PY) chip_smoke.py
+
+# The sharded path (--tp 4), its answers checked against the same weights held
+# whole on one device; four chips.
+chip-smoke-4:
+	$(PY) chip_smoke.py --chips 4
 
 # The tier-1 gate's shape (serial, CPU, slow tests excluded).
 test:
@@ -210,11 +223,12 @@ bench-diff:
 
 # Full bench field-plumbing proof on CPU (tiny model, ~15 s): one JSON line
 # with every real-run field (bblock, weights_dtype, dma_steps_per_substep,
-# last_tpu, roofline names).
+# device_kind), labelled "dry": true — never a device number.
 bench-dry:
 	$(PY) bench.py --dry
 
-# TTFT prefill-lever curve on the real chip (prefill batch x chunked
-# interleave; see bench_sweep.TTFT_GRID).
+# TTFT prefill-lever curve ON THE CHIP (prefill batch x chunked interleave;
+# see bench_sweep.TTFT_GRID). Each config is one `bench.py --measure`
+# process, which exits non-zero without a TPU.
 ttft-sweep:
 	$(PY) bench_sweep.py --ttft

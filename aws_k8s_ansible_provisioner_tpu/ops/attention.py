@@ -112,7 +112,7 @@ def make_decode_attend_carry(lengths: jnp.ndarray, impl: str = "auto",
         """
         from aws_k8s_ansible_provisioner_tpu.ops import pallas_attention
 
-        interpret = jax.default_backend() != "tpu"
+        interpret = not pallas_attention.supported()
         ck, cv = cache["k"], cache["v"]
         quant = "ks" in cache
         S_local = ck.shape[3]
@@ -167,7 +167,6 @@ def make_decode_attend_carry(lengths: jnp.ndarray, impl: str = "auto",
         if resolved == "pallas":
             knew, vnew = k[:, 0], v[:, 0]
             if mesh is not None:
-                from jax.experimental.shard_map import shard_map
                 from jax.sharding import PartitionSpec as P
 
                 from aws_k8s_ansible_provisioner_tpu.parallel.sharding import (
@@ -176,7 +175,7 @@ def make_decode_attend_carry(lengths: jnp.ndarray, impl: str = "auto",
                 # single source of sharding truth: the same specs the Engine
                 # allocates the cache with
                 cache_spec = cache_pspecs(quant=kvc.is_quantized(cache))
-                fn = shard_map(
+                fn = jax.shard_map(
                     _write_attend, mesh=mesh,
                     in_specs=(P("dp", None, "tp", None),  # q [B,1,Hq,D]
                               cache_spec,                 # cache leaf dict
@@ -185,7 +184,7 @@ def make_decode_attend_carry(lengths: jnp.ndarray, impl: str = "auto",
                               P("dp"),                    # lengths [B]
                               P()),                       # layer scalar
                     out_specs=(P("dp", None, "tp", None), cache_spec),
-                    check_rep=False,
+                    check_vma=False,
                 )
                 ctx, cache = fn(q, cache, knew, vnew, lengths, layer)
             else:
@@ -266,7 +265,7 @@ def make_spec_attend_carry(lengths: jnp.ndarray, impl: str = "auto",
         """Per-shard body: R in-place row writes + one multi-query flash."""
         from aws_k8s_ansible_provisioner_tpu.ops import pallas_attention
 
-        interpret = jax.default_backend() != "tpu"
+        interpret = not pallas_attention.supported()
         R = q.shape[1]
         quant = kvc.is_quantized(cache)
         ck, cv = cache["k"], cache["v"]
@@ -298,14 +297,13 @@ def make_spec_attend_carry(lengths: jnp.ndarray, impl: str = "auto",
         cache, layer = cache_l
         if resolved == "pallas":
             if mesh is not None:
-                from jax.experimental.shard_map import shard_map
                 from jax.sharding import PartitionSpec as P
 
                 from aws_k8s_ansible_provisioner_tpu.parallel.sharding import (
                     cache_pspecs)
 
                 cache_spec = cache_pspecs(quant=kvc.is_quantized(cache))
-                fn = shard_map(
+                fn = jax.shard_map(
                     _write_attend_spec, mesh=mesh,
                     in_specs=(P("dp", None, "tp", None),  # q [B,R,Hq,D]
                               cache_spec,                 # cache leaf dict
@@ -314,7 +312,7 @@ def make_spec_attend_carry(lengths: jnp.ndarray, impl: str = "auto",
                               P("dp"),                    # lengths [B]
                               P()),                       # layer scalar
                     out_specs=(P("dp", None, "tp", None), cache_spec),
-                    check_rep=False,
+                    check_vma=False,
                 )
                 ctx, cache = fn(q, cache, k, v, lengths, layer)
             else:
@@ -464,7 +462,7 @@ def make_decode_attend_carry_paged(lengths: jnp.ndarray, table: jnp.ndarray,
     def _write_attend_paged(q, pool, knew, vnew, lens, tab, layer):
         from aws_k8s_ansible_provisioner_tpu.ops import pallas_attention
 
-        interpret = jax.default_backend() != "tpu"
+        interpret = not pallas_attention.supported()
         if dp > 1:
             # The table carries GLOBAL page ids; this shard's pool slice is
             # its dp group's partition — rebase to local ids. OOB_PAGE
@@ -500,14 +498,13 @@ def make_decode_attend_carry_paged(lengths: jnp.ndarray, table: jnp.ndarray,
         if resolved == "pallas":
             knew, vnew = k[:, 0], v[:, 0]
             if mesh is not None:
-                from jax.experimental.shard_map import shard_map
                 from jax.sharding import PartitionSpec as P
 
                 from aws_k8s_ansible_provisioner_tpu.parallel.sharding import (
                     pool_pspecs)
 
                 pool_spec = pool_pspecs(quant="ks" in pool)
-                fn = shard_map(
+                fn = jax.shard_map(
                     _write_attend_paged, mesh=mesh,
                     in_specs=(P("dp", None, "tp", None),  # q [B,1,Hq,D]
                               pool_spec,                  # pool leaf dict
@@ -517,7 +514,7 @@ def make_decode_attend_carry_paged(lengths: jnp.ndarray, table: jnp.ndarray,
                               P("dp", None),              # table (slot rows)
                               P()),                       # layer scalar
                     out_specs=(P("dp", None, "tp", None), pool_spec),
-                    check_rep=False,
+                    check_vma=False,
                 )
                 ctx, pool = fn(q, pool, knew, vnew, lengths, table, layer)
             else:
@@ -552,7 +549,7 @@ def make_spec_attend_carry_paged(lengths: jnp.ndarray, table: jnp.ndarray,
     def _write_attend_spec_paged(q, pool, k, v, lens, tab, layer):
         from aws_k8s_ansible_provisioner_tpu.ops import pallas_attention
 
-        interpret = jax.default_backend() != "tpu"
+        interpret = not pallas_attention.supported()
         if spec_dp > 1:
             # global→local page-id rebase, same as _write_attend_paged (the
             # Engine currently gates spec to dp == 1, so this is latent)
@@ -594,14 +591,13 @@ def make_spec_attend_carry_paged(lengths: jnp.ndarray, table: jnp.ndarray,
         R = q.shape[1]
         if resolved == "pallas":
             if mesh is not None:
-                from jax.experimental.shard_map import shard_map
                 from jax.sharding import PartitionSpec as P
 
                 from aws_k8s_ansible_provisioner_tpu.parallel.sharding import (
                     pool_pspecs)
 
                 pool_spec = pool_pspecs(quant="ks" in pool)
-                fn = shard_map(
+                fn = jax.shard_map(
                     _write_attend_spec_paged, mesh=mesh,
                     in_specs=(P("dp", None, "tp", None),  # q [B,R,Hq,D]
                               pool_spec,                  # pool leaf dict
@@ -611,7 +607,7 @@ def make_spec_attend_carry_paged(lengths: jnp.ndarray, table: jnp.ndarray,
                               P("dp", None),              # table (slot rows)
                               P()),                       # layer scalar
                     out_specs=(P("dp", None, "tp", None), pool_spec),
-                    check_rep=False,
+                    check_vma=False,
                 )
                 ctx, pool = fn(q, pool, k, v, lengths, table, layer)
             else:
@@ -667,7 +663,7 @@ def make_mixed_attend_carry_paged(write_rows: jnp.ndarray,
                             layer):
         from aws_k8s_ansible_provisioner_tpu.ops import pallas_attention
 
-        interpret = jax.default_backend() != "tpu"
+        interpret = not pallas_attention.supported()
         ck, cv = pool["k"], pool["v"]
         if "ks" in pool:
             ck, ks = pallas_attention.cache_write_row_quant_paged(
@@ -699,14 +695,13 @@ def make_mixed_attend_carry_paged(write_rows: jnp.ndarray,
             # packed layout: batch axis is 1, rows live on the seq axis
             q3, knew, vnew = q[0], k[0], v[0]        # [N, H*, D]
             if mesh is not None:
-                from jax.experimental.shard_map import shard_map
                 from jax.sharding import PartitionSpec as P
 
                 from aws_k8s_ansible_provisioner_tpu.parallel.sharding import (
                     pool_pspecs)
 
                 pool_spec = pool_pspecs(quant="ks" in pool)
-                fn = shard_map(
+                fn = jax.shard_map(
                     _write_attend_mixed, mesh=mesh,
                     in_specs=(P(None, "tp", None),    # q3 [N,Hq,D]
                               pool_spec,              # pool leaf dict
@@ -717,7 +712,7 @@ def make_mixed_attend_carry_paged(write_rows: jnp.ndarray,
                               P(None, None),          # row_tables
                               P()),                   # layer scalar
                     out_specs=(P(None, "tp", None), pool_spec),
-                    check_rep=False,
+                    check_vma=False,
                 )
                 ctx, pool = fn(q3, pool, knew, vnew, write_rows,
                                row_limits, row_tables, layer)
@@ -755,8 +750,8 @@ def make_prefill_attend_paged_carry(pages: jnp.ndarray, seq_len: jnp.ndarray,
         cache, layer = cache_l
         ps = cache["k"].shape[3]
         ctx = causal_attend(q, k, v, seq_lens=seq_len[None], window=window)
-        cache = pkv.write_chunk_paged_layer(cache, layer, pages,
-                                            jnp.int32(0), k, v, ps)
+        cache = pkv.write_chunk_paged_layer(cache, layer, pages, 0, k, v,
+                                            ps)
         return ctx, (cache, layer)
 
     return attend

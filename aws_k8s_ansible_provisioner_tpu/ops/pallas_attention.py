@@ -200,6 +200,18 @@ def _decode_kernel_layer_q(lengths_ref,     # scalar prefetch [B] int32
         o_ref[0, :, :] = (acc_ref[:] / l).astype(o_ref.dtype)
 
 
+def _per_slot(vals, shape):
+    """Broadcast BB per-slot int32 SCALARS along axis 0 of ``shape``
+    ([BB, rows, cols]) with an iota select — the layout Mosaic accepts for
+    the batch-blocked kernels' per-slot column masks."""
+    out = jnp.full(shape, vals[0], jnp.int32)
+    if len(vals) > 1:
+        slot = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        for i in range(1, len(vals)):
+            out = jnp.where(slot == i, vals[i], out)
+    return out
+
+
 def _decode_kernel_layer_bb(lengths_ref,    # scalar prefetch [B] int32
                             layer_ref,      # scalar prefetch [1] int32
                             q_ref,          # [BB, Hq, D]
@@ -212,29 +224,33 @@ def _decode_kernel_layer_bb(lengths_ref,    # scalar prefetch [B] int32
                             ks_ref=None, vs_ref=None):
     """Batch-blocked flash decode: BB slots per grid step.
 
-    The round-5 TPU decomposition (BENCH_session_r5.json) put the decode
-    substep at ~3x its bandwidth bound; at grid (B=128, chunks=4) x 28
-    layers each step streams only ~0.5 MB, so fixed per-grid-step cost
-    (DMA issue + kernel overhead, ~1 us class) rivals the stream time
-    itself. Blocking BB slots into one grid step multiplies the DMA size
-    by BB and divides the step count by BB, pushing the kernel back toward
-    the stream bound. Trade: the chunk-skip clamp must cover the LONGEST
+    The round-5 TPU decomposition (a measurement of older code on the dense
+    path, whose record is no longer in the tree) put the decode substep at
+    ~3x its bandwidth bound; at grid (B=128, chunks=4) x 28 layers each
+    step streams only ~0.5 MB, so fixed per-grid-step cost (DMA issue +
+    kernel overhead, ~1 us class) rivals the stream time itself. Blocking
+    BB slots into one grid step multiplies the DMA size by BB and divides
+    the step count by BB, pushing the kernel back toward the stream bound.
+    Trade: the chunk-skip clamp must cover the LONGEST
     slot in the block (shorter slots' dead chunks ride along), so blocks
     of similar-length slots waste nothing and mixed blocks pay up to
     (max-min) extra rows — the engine's slot allocator is FCFS, which
-    correlates neighbors' ages. Gated by PALLAS_DECODE_BBLOCK until
-    measured on hardware (the recovery sweep carries it).
+    correlates neighbors' ages. Gated by PALLAS_DECODE_BBLOCK; compiled
+    for the chip (tests/test_tpu_compile.py), not yet timed on it.
     """
     bbi = pl.program_id(0)
     c = pl.program_id(1)
     num_chunks = pl.num_programs(1)
     hq, d = q_ref.shape[1], q_ref.shape[2]
     hkv = k_ref.shape[2]
-    lens = jnp.stack([lengths_ref[bbi * bb + i] for i in range(bb)])  # [BB]
-    max_len = jnp.max(lens)
-    lo = jnp.maximum(lens - window, 0) if window > 0 else \
-        jnp.zeros_like(lens)
-    lo_min = jnp.min(lo)
+    # Per-slot lengths stay SCALARS (SMEM reads): Mosaic cannot lay out a
+    # stacked [BB] scalar vector reshaped to [BB, 1, 1] for the column mask,
+    # so the mask operand is built by _per_slot's iota select instead.
+    lens = [lengths_ref[bbi * bb + i] for i in range(bb)]
+    max_len = functools.reduce(jnp.maximum, lens)
+    lo = [jnp.maximum(ln - window, 0) if window > 0 else jnp.int32(0)
+          for ln in lens]
+    lo_min = functools.reduce(jnp.minimum, lo)
 
     @pl.when(c == 0)
     def _init():
@@ -255,7 +271,9 @@ def _decode_kernel_layer_bb(lengths_ref,    # scalar prefetch [B] int32
         s = s.reshape(bb, hq, chunk)
         col = c * chunk + jax.lax.broadcasted_iota(jnp.int32,
                                                    (bb, hq, chunk), 2)
-        live = (col < lens[:, None, None]) & (col >= lo[:, None, None])
+        live = col < _per_slot(lens, (bb, hq, chunk))
+        if window > 0:
+            live &= col >= _per_slot(lo, (bb, hq, chunk))
         s = jnp.where(live, s, NEG_INF)
         m_prev = m_ref[:, :, :1]
         l_prev = l_ref[:, :, :1]
@@ -501,6 +519,10 @@ def decode_attend_pallas_layer(q: jnp.ndarray, cache_k: jnp.ndarray,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
             interpret=interpret,
+            # BB double-buffered K/V blocks outgrow the 16 MiB default scoped
+            # VMEM at bb=8 x chunk=256 (16.35 MiB at Qwen3-0.6B widths)
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=32 * 2**20),
         )(lengths, layer_arr, *operands)
         return out[:, None]
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -884,8 +906,9 @@ def _paged_db_body(lengths_ref, layer_ref, table_ref, q_ref, k_hbm, v_hbm,
     One grid step handles BB slots end to end: init flash state, then walk
     the block's live logical pages [lo, hi] with a two-slot VMEM buffer —
     issue page c+1's copies, wait page c's, accumulate page c. The table is
-    scalar-prefetched (SMEM), so physical ids resolve in-kernel with no HBM
-    round trip. Per-slot raggedness inside a block rides the column mask
+    scalar-prefetched (SMEM, FLATTENED row-major — see _paged_flash_db), so
+    physical ids resolve in-kernel with no HBM round trip. Per-slot
+    raggedness inside a block rides the column mask
     (shorter slots' dead columns contribute exp(-1e30 - m) == 0 exactly once
     any live column has been seen — bit-identical to the skip-based
     single-slot accumulation); the per-slot page index clamps into the
@@ -898,17 +921,20 @@ def _paged_db_body(lengths_ref, layer_ref, table_ref, q_ref, k_hbm, v_hbm,
     hq = q_ref.shape[1] // R
     d = q_ref.shape[2]
     hkv = k_buf.shape[2]
-    lens = jnp.stack([lengths_ref[g * bb + i] for i in range(bb)])   # [BB]
-    extent = lens + (R if spec else 0)
-    hi = jnp.maximum(pl.cdiv(extent, ps) - 1, 0)                     # [BB]
-    hi_max = jnp.max(hi)
+    # BB per-slot SCALARS (see _per_slot: a stacked scalar vector reshaped
+    # to [BB, 1, 1] is a shape cast Mosaic refuses)
+    lens = [lengths_ref[g * bb + i] for i in range(bb)]
+    hi = [jnp.maximum(pl.cdiv(ln + (R if spec else 0), ps) - 1, 0)
+          for ln in lens]
+    hi_max = functools.reduce(jnp.maximum, hi)
     if window > 0:
-        wstart = jnp.maximum(lens + (1 if spec else 0) - window, 0)
-        lo = wstart // ps                                            # [BB]
-        lo_min = jnp.min(lo)
+        lo = [jnp.maximum(ln + (1 if spec else 0) - window, 0) // ps
+              for ln in lens]
+        lo_min = functools.reduce(jnp.minimum, lo)
     else:
-        lo = jnp.zeros_like(lens)
+        lo = [jnp.int32(0)] * bb
         lo_min = jnp.int32(0)
+    lens_b = _per_slot(lens, (bb, hq, ps))
 
     def live(c: int):
         # block-level liveness of logical page c (c is a python int): some
@@ -922,7 +948,8 @@ def _paged_db_body(lengths_ref, layer_ref, table_ref, q_ref, k_hbm, v_hbm,
         for i in range(bb):
             # clamp into slot i's own live range: table entries past it may
             # be anything valid (scratch, stale) — never fetch them
-            pg = table_ref[g * bb + i, jnp.clip(c, lo[i], hi[i])]
+            pg = table_ref[(g * bb + i) * num_pages
+                           + jnp.clip(c, lo[i], hi[i])]
             out.append(pltpu.make_async_copy(
                 k_hbm.at[lay, pg], k_buf.at[slot, i], sem.at[slot, i, 0]))
             out.append(pltpu.make_async_copy(
@@ -963,8 +990,10 @@ def _paged_db_body(lengths_ref, layer_ref, table_ref, q_ref, k_hbm, v_hbm,
             k3 = k_buf[buf].astype(jnp.float32).reshape(bb * hkv, ps, d)
             v3 = v_buf[buf].astype(jnp.float32).reshape(bb * hkv, ps, d)
             if quant:
-                kscale = ks_buf[buf].reshape(bb * hkv, ps)
-                vscale = vs_buf[buf].reshape(bb * hkv, ps)
+                # scale pages arrive lane-padded (paged_kv.scale_lanes);
+                # only the first ``ps`` lanes are rows of this page
+                kscale = ks_buf[buf][:, :, :ps].reshape(bb * hkv, ps)
+                vscale = vs_buf[buf][:, :, :ps].reshape(bb * hkv, ps)
             for r in range(R):         # static unroll over draft rows
                 sl = slice(r * hq, (r + 1) * hq)
                 q3 = (q_ref[:, sl].astype(jnp.float32) * scale) \
@@ -977,7 +1006,7 @@ def _paged_db_body(lengths_ref, layer_ref, table_ref, q_ref, k_hbm, v_hbm,
                 s = s.reshape(bb, hq, ps)
                 col = c * ps + jax.lax.broadcasted_iota(jnp.int32,
                                                         (bb, hq, ps), 2)
-                limit = lens[:, None, None] + (1 + r if spec else 0)
+                limit = lens_b + (1 + r if spec else 0)
                 live_col = col < limit
                 if window > 0:
                     live_col &= col >= limit - window
@@ -1059,7 +1088,11 @@ def _paged_flash_db(q2, pool_k, pool_v, lengths, layer_arr, table,
         pltpu.VMEM((2, bb, Hkv, ps, D), pool_v.dtype),     # v page buffers
     ]
     if quant:
-        scratch += [pltpu.VMEM((2, bb, Hkv, ps), pool_ks.dtype)] * 2
+        # Scale pages move whole: [Hkv, lanes] with lanes = the scale leaf's
+        # minor dim (paged_kv.scale_lanes pads page_size up to the 128-lane
+        # tile — Mosaic refuses a DMA slice whose minor dim is narrower).
+        scratch += [pltpu.VMEM((2, bb, Hkv, pool_ks.shape[3]),
+                               pool_ks.dtype)] * 2
     scratch += [
         pltpu.VMEM((bb, RHq, D), jnp.float32),             # acc
         pltpu.VMEM((bb, RHq, 128), jnp.float32),           # m
@@ -1082,7 +1115,11 @@ def _paged_flash_db(q2, pool_k, pool_v, lengths, layer_arr, table,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, RHq, D), q2.dtype),
         interpret=interpret,
-    )(lengths, layer_arr, table, *operands)
+    # The table rides SMEM flattened: a 2-D s32[N, max_pages] operand pads
+    # its minor dim to 128 lanes there, and the mixed program's per-ROW table
+    # (N = slots + chunk = 2,080 rows x 32 pages at the default config)
+    # then needs 1.04 MiB of the chip's 1 MiB.
+    )(lengths, layer_arr, table.reshape(-1), *operands)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "window", "bblock"))
@@ -1210,15 +1247,17 @@ def cache_write_row_paged(pool: jnp.ndarray, new: jnp.ndarray,
     rows = rows.astype(jnp.int32)
     layer_arr = jnp.asarray(layer, jnp.int32).reshape(1)
     table = table.astype(jnp.int32)
-    S_v = table.shape[1] * ps
+    MP = table.shape[1]
+    S_v = MP * ps
     ROWS = 8 if ps % 8 == 0 else ps
 
     def new_map(b, lens, lay, tab):
         return (b, 0, 0)
 
     def blk_map(b, lens, lay, tab):
+        # tab: the table FLATTENED row-major (SMEM; see _paged_flash_db)
         r = jnp.clip(lens[b], 0, S_v - 1)
-        return (lay[0], tab[b, r // ps], 0, (r % ps) // ROWS, 0)
+        return (lay[0], tab[b * MP + r // ps], 0, (r % ps) // ROWS, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -1246,7 +1285,7 @@ def cache_write_row_paged(pool: jnp.ndarray, new: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
         input_output_aliases={4: 0},   # pool operand (after 3 scalars + new)
         interpret=interpret,
-    )(rows, layer_arr, table, new, pool)
+    )(rows, layer_arr, table.reshape(-1), new, pool)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -1256,28 +1295,31 @@ def cache_write_row_quant_paged(pool: jnp.ndarray, scales: jnp.ndarray,
                                 interpret: bool = False):
     """Quantizing paged row write: int8 pool + per-row scales, both aliased.
 
-    pool: [L, P, Hkv, page, D] int8; scales: [L, P, Hkv, page] f32; new:
-    [B, Hkv, D] float. Same quantizer as the dense kernel
-    (kv_cache.quantize_rows) so prefilled and decoded rows are
+    pool: [L, P, Hkv, page, D] int8; scales: [L, P, Hkv, lanes >= page] f32
+    (paged_kv.scale_lanes); new: [B, Hkv, D] float. Same quantizer as the
+    dense kernel (kv_cache.quantize_rows) so prefilled and decoded rows are
     interchangeable. Returns (pool, scales) — same buffers.
     """
     L, P, Hkv, ps, D = pool.shape
+    lanes = scales.shape[3]     # >= ps: lane-padded (paged_kv.scale_lanes)
     rows = rows.astype(jnp.int32)
     layer_arr = jnp.asarray(layer, jnp.int32).reshape(1)
     table = table.astype(jnp.int32)
-    S_v = table.shape[1] * ps
+    MP = table.shape[1]
+    S_v = MP * ps
     ROWS = 32 if ps % 32 == 0 else ps
 
     def new_map(b, lens, lay, tab):
         return (b, 0, 0)
 
     def blk_map(b, lens, lay, tab):
+        # tab: the table FLATTENED row-major (SMEM; see _paged_flash_db)
         r = jnp.clip(lens[b], 0, S_v - 1)
-        return (lay[0], tab[b, r // ps], 0, (r % ps) // ROWS, 0)
+        return (lay[0], tab[b * MP + r // ps], 0, (r % ps) // ROWS, 0)
 
     def scale_map(b, lens, lay, tab):
         r = jnp.clip(lens[b], 0, S_v - 1)
-        return (lay[0], tab[b, r // ps], 0, 0)
+        return (lay[0], tab[b * MP + r // ps], 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -1285,11 +1327,11 @@ def cache_write_row_quant_paged(pool: jnp.ndarray, scales: jnp.ndarray,
         in_specs=[
             pl.BlockSpec((1, Hkv, D), new_map),
             pl.BlockSpec((1, 1, Hkv, ROWS, D), blk_map),
-            pl.BlockSpec((1, 1, Hkv, ps), scale_map),
+            pl.BlockSpec((1, 1, Hkv, lanes), scale_map),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, Hkv, ROWS, D), blk_map),
-            pl.BlockSpec((1, 1, Hkv, ps), scale_map),
+            pl.BlockSpec((1, 1, Hkv, lanes), scale_map),
         ],
     )
 
@@ -1306,7 +1348,7 @@ def cache_write_row_quant_paged(pool: jnp.ndarray, scales: jnp.ndarray,
         row = jax.lax.broadcasted_iota(jnp.int32, (Hkv, ROWS, D), 1)
         cout_ref[0, 0] = jnp.where(row == r, q8[:, None, :], cin_ref[0, 0])
         # scale block spans one whole page: target column = tgt % page
-        rs = jax.lax.broadcasted_iota(jnp.int32, (Hkv, ps), 1)
+        rs = jax.lax.broadcasted_iota(jnp.int32, (Hkv, lanes), 1)
         tgt_col = jnp.where(in_window, jnp.clip(tgt, 0, S_v - 1) % ps, -1)
         sout_ref[0, 0] = jnp.where(rs == tgt_col, sc[:, None], sin_ref[0, 0])
 
@@ -1319,12 +1361,14 @@ def cache_write_row_quant_paged(pool: jnp.ndarray, scales: jnp.ndarray,
         ],
         input_output_aliases={4: 0, 5: 1},  # pool, scales (3 scalars + new)
         interpret=interpret,
-    )(rows, layer_arr, table, new, pool, scales)
+    )(rows, layer_arr, table.reshape(-1), new, pool, scales)
 
 
-def supported(cfg=None) -> bool:
-    """Pallas decode path is compiled only on TPU backends (interpret elsewhere)."""
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+def supported() -> bool:
+    """True when the kernels compile natively (the process's default backend
+    is a TPU); elsewhere they run in interpret mode. The ONE place the
+    attention layer asks — ``ops/attention.resolve_impl('auto')`` and every
+    ``interpret=`` argument read it — so a deviceless compile for a described
+    chip (tests/test_tpu_compile.py, a scratch script) steers a single
+    function instead of the JAX backend."""
+    return jax.default_backend() == "tpu"

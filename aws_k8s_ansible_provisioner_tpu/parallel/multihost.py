@@ -53,16 +53,7 @@ def init_distributed(coordinator_address: Optional[str] = None,
     TPU metadata environment. Explicit args are for DCN rigs without metadata
     (and for multi-process CPU tests). Returns a summary dict.
     """
-    # jax.distributed.is_initialized() only exists from jax 0.5; on older
-    # runtimes (the pinned image ships 0.4.x) fall back to the internal
-    # client handle the initialize() call populates.
-    if hasattr(jax.distributed, "is_initialized"):
-        initialized = jax.distributed.is_initialized()
-    else:
-        from jax._src import distributed as _dist
-
-        initialized = _dist.global_state.client is not None
-    if not initialized:
+    if not jax.distributed.is_initialized():
         if coordinator_address is None:
             jax.distributed.initialize()
         else:

@@ -43,7 +43,6 @@ from typing import Any, Callable, Tuple
 import jax
 import jax.numpy as jnp
 import optax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from aws_k8s_ansible_provisioner_tpu.config import ModelConfig
@@ -115,9 +114,8 @@ def make_pipeline_lm_loss(cfg: ModelConfig, mesh: Mesh, n_microbatches: int,
     def shard_body(params, tokens, loss_mask):
         # tokens: [M, mb, T] (this dp shard's microbatches)
         pp_idx = jax.lax.axis_index("pp")
-        # static stage count from the mesh (jax.lax.axis_size only exists on
-        # newer jax than the pinned 0.4.x image; PP feeds range()/arange(), so
-        # it must be a Python int anyway)
+        # static stage count from the mesh: PP feeds range()/arange(), so it
+        # must be a Python int
         PP = int(mesh.shape["pp"])
         p_stage = jax.tree.map(lambda x: x[0], params["layers"])  # [Lpp, ...]
         _, mb, T = tokens.shape
@@ -193,11 +191,11 @@ def make_pipeline_lm_loss(cfg: ModelConfig, mesh: Mesh, n_microbatches: int,
         mask_m = loss_mask.reshape(M, mb, T)
         specs = pipeline_param_pspecs(cfg, params)
         data_spec = P(None, "dp", None) if has_dp else P(None, None, None)
-        fn = shard_map(
+        fn = jax.shard_map(
             shard_body, mesh=mesh,
             in_specs=(specs, data_spec, data_spec),
             out_specs=P(),
-            check_rep=False)
+            check_vma=False)
         return fn(params, tokens_m, mask_m)
 
     return loss

@@ -110,27 +110,13 @@ def make_ring_attend(mesh: Mesh, axis_name: str = "sp"):
     cache is passed through untouched (training / full-sequence path).
     """
 
-    # jax.shard_map(check_vma=...) is the >= 0.6 API; the pinned 0.4.x image
-    # only has jax.experimental.shard_map (check_rep). Same semantics here:
-    # both flags just disable the replication/varying-manual-axes check.
-    if hasattr(jax, "shard_map"):
-        local = jax.shard_map(
-            lambda q, k, v: ring_attend_local(q, k, v, axis_name),
-            mesh=mesh,
-            in_specs=(P("dp", axis_name, "tp", None),) * 3,
-            out_specs=P("dp", axis_name, "tp", None),
-            check_vma=False,
-        )
-    else:
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        local = _shard_map(
-            lambda q, k, v: ring_attend_local(q, k, v, axis_name),
-            mesh=mesh,
-            in_specs=(P("dp", axis_name, "tp", None),) * 3,
-            out_specs=P("dp", axis_name, "tp", None),
-            check_rep=False,
-        )
+    local = jax.shard_map(
+        lambda q, k, v: ring_attend_local(q, k, v, axis_name),
+        mesh=mesh,
+        in_specs=(P("dp", axis_name, "tp", None),) * 3,
+        out_specs=P("dp", axis_name, "tp", None),
+        check_vma=False,
+    )
 
     def attend(q, k, v, cache) -> Tuple[jnp.ndarray, object]:
         return local(q, k, v), cache

@@ -22,10 +22,13 @@ from __future__ import annotations
 
 import collections
 import ctypes
+import logging
 import os
 import threading
 from dataclasses import dataclass
 from typing import Optional, Tuple
+
+log = logging.getLogger(__name__)
 
 _LIB_PATHS = tuple(p for p in (
     # Container image sets TPU_SERVE_NATIVE_DIR (the package is pip-installed
@@ -68,6 +71,7 @@ def _load_lib() -> Optional[ctypes.CDLL]:
     for path in _LIB_PATHS:
         if os.path.exists(path):
             lib = ctypes.CDLL(os.path.abspath(path))
+            _lib_cache["path"] = os.path.abspath(path)
             lib.ts_create.restype = ctypes.c_void_p
             lib.ts_create.argtypes = [ctypes.c_int32] * 3
             lib.ts_destroy.argtypes = [ctypes.c_void_p]
@@ -364,6 +368,10 @@ def make_scheduler(num_slots: int, max_len: int, page_size: int,
     """
     want_native = os.environ.get("TPU_SERVE_NATIVE_RUNTIME", "1") != "0"
     if want_native and native_available():
+        log.info("scheduler: native (%s)", _lib_cache.get("path"))
         return NativeScheduler(num_slots, max_len, page_size,
                                max_queue=max_queue)
+    log.info("scheduler: python (%s)",
+             "TPU_SERVE_NATIVE_RUNTIME=0" if not want_native
+             else "no libtpu_serve_runtime.so built — make -C native runtime")
     return PyScheduler(num_slots, max_len, page_size, max_queue=max_queue)

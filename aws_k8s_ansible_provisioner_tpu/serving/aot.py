@@ -14,8 +14,11 @@ exit) instead of OOMing on the first burst.
 Compilation target, best available first:
 
 1. ``jax.experimental.topologies`` — an abstract TPU topology (default
-   ``v5e:2x4`` = v5e-8) when libtpu is importable: real Mosaic/XLA-TPU
-   lowering, no chips needed. The GCE metadata probe is skipped explicitly
+   ``v5e:2x4`` = v5e-8) when libtpu is importable AND the process's own
+   backend is a TPU: real Mosaic/XLA-TPU lowering of the chip's branch, for
+   chips that need not be attached. On a host-only process the attention
+   layer would trace its host branch, so ``--platform tpu`` refuses there
+   and ``auto`` takes target 2. The GCE metadata probe is skipped explicitly
    (``TPU_SKIP_MDS_QUERY``) — without it the topology lookup hangs on
    non-GCE hosts.
 2. An 8-device host-platform mesh of identical axis shapes otherwise
@@ -140,21 +143,28 @@ def _mesh_for(devices, dp: int, tp: int):
     return make_mesh(MeshConfig(dp=dp, tp=tp), devices=list(devices)[:need])
 
 
-def _with_sharding(sds_tree, pspec_tree, mesh):
-    """Attach NamedShardings to a ShapeDtypeStruct pytree (no-op without a
-    mesh — single-device AOT lowers unsharded, like the engine)."""
+def _with_sharding(sds_tree, pspec_tree, mesh, device=None):
+    """Attach shardings to a ShapeDtypeStruct pytree: NamedShardings under a
+    mesh; without one, every leaf is PLACED on ``device`` (the described
+    chip) — an unplaced operand would lower the program for the process's
+    default backend, i.e. compile it for the host under the chip's name."""
     import jax
-    from jax.sharding import NamedSharding
+    from jax.sharding import NamedSharding, SingleDeviceSharding
 
     if mesh is None:
-        return sds_tree
+        if device is None:
+            return sds_tree
+        one = SingleDeviceSharding(device)
+        return jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one),
+            sds_tree)
     return jax.tree.map(
         lambda s, spec: jax.ShapeDtypeStruct(
             s.shape, s.dtype, sharding=NamedSharding(mesh, spec)),
         sds_tree, pspec_tree)
 
 
-def _abstract_state(plan, mesh):
+def _abstract_state(plan, mesh, device=None):
     """(params, cache) as ShapeDtypeStruct pytrees with the engine's
     shardings — via eval_shape over the engine's own init/quantize fns, so
     shapes can never drift from what the engine dispatches."""
@@ -175,17 +185,20 @@ def _abstract_state(plan, mesh):
     if plan.weights_quant:
         params = jax.eval_shape(lambda p: quantize_params(p, cfg), params)
     params = _with_sharding(
-        params, param_pspecs(cfg, quant_weights=plan.weights_quant), mesh)
+        params, param_pspecs(cfg, quant_weights=plan.weights_quant), mesh,
+        device)
     if plan.paged:
         cache = jax.eval_shape(
             lambda: pkv.init_pool(cfg, plan.total_pages, serving.page_size,
                                   dtype, quant=plan.kv_quant))
-        cache = _with_sharding(cache, pool_pspecs(plan.kv_quant), mesh)
+        cache = _with_sharding(cache, pool_pspecs(plan.kv_quant), mesh,
+                               device)
     else:
         cache = jax.eval_shape(
             lambda: kvc.init_cache(cfg, plan.num_slots, plan.max_len, dtype,
                                    quant=plan.kv_quant))
-        cache = _with_sharding(cache, cache_pspecs(plan.kv_quant), mesh)
+        cache = _with_sharding(cache, cache_pspecs(plan.kv_quant), mesh,
+                               device)
     return params, cache
 
 
@@ -465,11 +478,24 @@ def build_manifest(cfg, serving, dp: int = 1, tp: int = 1,
 
     if devices is None:
         devices = jax.devices()
+    if platform == "tpu":
+        from aws_k8s_ansible_provisioner_tpu.ops import pallas_attention
+
+        if not pallas_attention.supported():
+            # The attention layer picks its branch from the process's
+            # backend: on a host-only process it traces the XLA fallback and
+            # interpret-mode kernels, which is not the program the chip runs.
+            raise RuntimeError(
+                "aot: refusing to compile under the name 'tpu' — this "
+                "process's JAX backend is "
+                f"{jax.default_backend()!r}, so the programs would take the "
+                "host branch (XLA attention, interpret-mode kernels). Run "
+                "on a TPU host, or pass --platform host for the host proxy")
     plan = ProgramPlan(cfg, serving, dp=dp, tp=tp)
     mesh = _mesh_for(devices, dp, tp) if dp * tp > 1 else None
     if mesh is not None and cfg.num_experts > 0 and cfg.moe_impl != "gshard":
         plan.cfg = cfg = cfg.scaled(moe_impl="gshard")  # engine mesh path
-    params, cache = _abstract_state(plan, mesh)
+    params, cache = _abstract_state(plan, mesh, devices[0])
     programs = enumerate_programs(plan, mesh, params, cache, bblock=bblock)
     if progress:
         progress(f"compiling {len(programs)} programs for "
@@ -502,12 +528,17 @@ def _acquire_devices(args):
     """(devices, platform, topology): abstract TPU topology devices when
     libtpu imports (and --platform allows), else host-platform devices."""
     if args.platform in ("auto", "tpu"):
+        from aws_k8s_ansible_provisioner_tpu.ops import pallas_attention
+
         try:
             import libtpu  # noqa: F401
             have_libtpu = True
         except ImportError:
             have_libtpu = False
-        if have_libtpu:
+        # 'auto' takes the described chip only where the programs would
+        # trace the chip's own branch (build_manifest refuses otherwise)
+        if have_libtpu and (args.platform == "tpu"
+                            or pallas_attention.supported()):
             # Without the skip flag the topology lookup queries the GCE
             # metadata server and hangs (effectively) forever off-GCE.
             os.environ.setdefault("TPU_SKIP_MDS_QUERY", "1")

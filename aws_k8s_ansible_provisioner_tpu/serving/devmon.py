@@ -33,6 +33,7 @@ testable under a fake clock.
 
 from __future__ import annotations
 
+import logging
 import threading
 import time
 from collections import deque
@@ -42,6 +43,8 @@ from typing import Callable, Deque, Dict, Optional, Tuple
 from aws_k8s_ansible_provisioner_tpu.serving.metrics import (
     Gauge, Registry)
 from aws_k8s_ansible_provisioner_tpu.serving.slo import trim_window
+
+log = logging.getLogger(__name__)
 
 # Attribution window (seconds). One window: the dashboard question is "what
 # is the device doing NOW", not SLO burn over an hour — slo.py owns that.
@@ -195,8 +198,14 @@ class DevMon:
                  hbm_gbps: float = DEFAULT_HBM_GBPS,
                  hbm_tolerance_mb: float = DEFAULT_HBM_TOLERANCE_MB,
                  window_s: float = WINDOW_S,
-                 clock: Callable[[], float] = time.monotonic):
+                 clock: Callable[[], float] = time.monotonic,
+                 device_kind: str = ""):
         self.enabled = enabled
+        # what JAX reports the device to be; the peaks below are the
+        # operator's flags (v5e's by default), NOT derived from it — the
+        # two are logged and snapshotted side by side so a v5e peak under
+        # another chip's name is visible
+        self.device_kind = device_kind
         self.peak_flops = max(1.0, peak_tflops) * 1e12
         self.peak_bw = max(1.0, hbm_gbps) * 1e9
         self.hbm_tolerance_bytes = max(0.0, hbm_tolerance_mb) * 1e6
@@ -349,6 +358,7 @@ class DevMon:
         return {
             "enabled": self.enabled,
             "window_s": self.window_s,
+            "device_kind": self.device_kind,
             "peak_tflops": self.peak_flops / 1e12,
             "peak_hbm_gbps": self.peak_bw / 1e9,
             "duty_cycle": self.duty_cycle(now),
@@ -393,6 +403,10 @@ def configure(**kw) -> DevMon:
     with _monitor_lock:
         old = _monitor
         _monitor = DevMon(**kw)
+        log.info("devmon: device_kind=%r; utilization gauges divide by "
+                 "%.1f TFLOP/s and %.1f GB/s (--devmon-peak-* flags)",
+                 _monitor.device_kind, _monitor.peak_flops / 1e12,
+                 _monitor.peak_bw / 1e9)
         if old is not None:
             if old.cost_model is not None and _monitor.cost_model is None:
                 _monitor.cost_model = old.cost_model

@@ -1617,7 +1617,7 @@ class Engine(EnginePrograms):
     last_error: str = ""
     # monotonic timestamp of the step currently executing (0.0 = idle):
     # /health derives a "stalled" status from it — a wedged device dispatch
-    # (hung tunnel, driver fault) hangs INSIDE step() and would otherwise
+    # (hung runtime, driver fault) hangs INSIDE step() and would otherwise
     # look healthy forever, since run_forever never returns to record an
     # error (failure-detection beyond the reference's set -e, SURVEY.md §5).
     last_step_start: float = 0.0

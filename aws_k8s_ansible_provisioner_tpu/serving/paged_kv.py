@@ -9,7 +9,8 @@ admitting far more concurrent short requests from the same HBM. This module is
 the TPU-native equivalent:
 
 - **Page pool**: ``k, v : [L, P, Hkv, page, D]`` (+ per-row scale leaves
-  ``ks, vs : [L, P, Hkv, page]`` when int8) — P physical pages shared by all
+  ``ks, vs : [L, P, Hkv, lanes]`` when int8; lanes = page rounded up to the
+  128-lane tile, :func:`scale_lanes`) — P physical pages shared by all
   slots, allocated once at startup (XLA static shapes; capacity planning picks
   P, not per-slot reservations).
 - **Block tables**: host numpy ``[num_slots, max_pages_per_slot]`` int32 of
@@ -56,27 +57,39 @@ from aws_k8s_ansible_provisioner_tpu.serving.kv_cache import quantize_rows
 OOB_PAGE = np.int32(2**31 - 1)
 
 
+def scale_lanes(page_size: int) -> int:
+    """Minor dim of the int8 pool's scale leaves: ``page_size`` rounded up to
+    the TPU's 128-lane tile. The paged decode kernel moves one page's scales
+    per DMA, and Mosaic refuses a DMA slice whose minor dim is not
+    128-aligned (page 64 died there); lanes >= page_size are padding no
+    reader indexes. HBM cost on the chip is nil — XLA's tiled layout already
+    padded a 64-wide f32 minor dim to 128."""
+    return -(-page_size // 128) * 128
+
+
 def init_pool(cfg: ModelConfig, num_pages: int, page_size: int,
               dtype=jnp.bfloat16, quant: bool = False) -> dict:
     """Allocate the physical page pool. Leaves carry a leading [L] axis."""
     shape = (cfg.num_layers, num_pages, cfg.num_kv_heads, page_size,
              cfg.head_dim)
     if quant:
+        sshape = shape[:3] + (scale_lanes(page_size),)
         return {
             "k": jnp.zeros(shape, jnp.int8),
             "v": jnp.zeros(shape, jnp.int8),
-            "ks": jnp.zeros(shape[:-1], jnp.float32),
-            "vs": jnp.zeros(shape[:-1], jnp.float32),
+            "ks": jnp.zeros(sshape, jnp.float32),
+            "vs": jnp.zeros(sshape, jnp.float32),
         }
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
 def pool_bytes(cfg: ModelConfig, num_pages: int, page_size: int,
                dtype=jnp.bfloat16, quant: bool = False) -> int:
-    rows = 2 * cfg.num_layers * num_pages * page_size * cfg.num_kv_heads
+    heads = 2 * cfg.num_layers * num_pages * cfg.num_kv_heads
     if quant:
-        return rows * (cfg.head_dim + 4)
-    return rows * cfg.head_dim * jnp.dtype(dtype).itemsize
+        return heads * (page_size * cfg.head_dim
+                        + 4 * scale_lanes(page_size))
+    return heads * page_size * cfg.head_dim * jnp.dtype(dtype).itemsize
 
 
 def _write_kv(pool: dict, update, k_val: jnp.ndarray, v_val: jnp.ndarray) -> dict:
@@ -147,6 +160,67 @@ def write_prompts_paged(pool_l: dict, tables: jnp.ndarray, k: jnp.ndarray,
         k, v)
 
 
+def _write_span_by_page(pool: dict, layer, tables: jnp.ndarray, start,
+                        k: jnp.ndarray, v: jnp.ndarray,
+                        page_size: int) -> dict:
+    """Rows [start, start+T) of N sequences into the FULL pool at ``layer``,
+    one WHOLE PAGE per scatter window (read-modify-write).
+
+    tables: [N, max_pages]; k/v: [N, T, Hkv, D]; start: scalar (python int
+    or traced). Same index/drop contract as the row-granular per-layer
+    writers above (logical pages past the table and OOB_PAGE entries drop;
+    rows of a touched page outside the span keep their content).
+
+    Why pages and not rows: a row-granular scatter on the head-major pool
+    (``arr.at[layer, pg, :, off]``, window [Hkv, D] split by the page axis)
+    made the chip's compiler RELAYOUT THE WHOLE POOL to [.., page, Hkv, D]
+    and back around every prefill — two full-pool copies and a pool-sized
+    temp in each prefill program (7.0 GiB of temp beside a 7.0 GiB pool at
+    the default config, deviceless compile for v5e, PR 21). A [Hkv, page, D]
+    window is contiguous in the pool's own layout, so this form compiles
+    with no pool copy and ~0 temp."""
+    ps = page_size
+    N, T = k.shape[:2]
+    aligned = isinstance(start, int) and start % ps == 0
+    # logical pages touched. Never ONE: XLA rewrites a single-window scatter
+    # as a dynamic-update-slice whose layout it takes from the transposed
+    # update, and relayouts the whole pool again (buckets <= one page did);
+    # a second window — its rows all outside the span, rewritten unchanged —
+    # keeps it a scatter in the pool's own layout.
+    n = max(2, -(-T // ps) + (0 if aligned else 1))
+    start = jnp.asarray(start, jnp.int32)
+    p0 = start // ps
+    delta = p0 * ps - start                      # in (-ps, 0]
+    lp = p0 + jnp.arange(n, dtype=jnp.int32)     # [n] logical page ids
+    pg = jnp.where((lp < tables.shape[1])[None],
+                   tables[:, jnp.clip(lp, 0, tables.shape[1] - 1)],
+                   OOB_PAGE)                     # [N, n] physical ids
+    # span token held by row r of touched page j; live = inside the span
+    tok = (jnp.arange(n, dtype=jnp.int32)[:, None] * ps
+           + jnp.arange(ps, dtype=jnp.int32)[None] + delta)     # [n, ps]
+    live = (tok >= 0) & (tok < T)
+
+    def update(arr, val):
+        # val [N, T, Hkv, (D)] -> per-page blocks [N, n, Hkv, ps, (D)]
+        pad = [(0, 0)] * val.ndim
+        pad[1] = (ps, n * ps - T)
+        win = jax.lax.dynamic_slice_in_dim(jnp.pad(val, pad), ps + delta,
+                                           n * ps, axis=1)
+        new = jnp.moveaxis(win.reshape((N, n, ps) + val.shape[2:]), 2, 3)
+        mask = live[None, :, None, :]
+        if val.ndim == 4:
+            mask = mask[..., None]
+        else:                                    # scale leaf: lane padding
+            lanes = arr.shape[3] - ps
+            new = jnp.pad(new, [(0, 0)] * 3 + [(0, lanes)])
+            mask = jnp.pad(mask, [(0, 0)] * 3 + [(0, lanes)])
+        old = arr.at[layer, pg].get(mode="clip")
+        return arr.at[layer, pg].set(
+            jnp.where(mask, new.astype(arr.dtype), old), mode="drop")
+
+    return _write_kv(pool, update, k, v)
+
+
 def write_prompts_paged_layer(pool: dict, layer, tables: jnp.ndarray,
                               k: jnp.ndarray, v: jnp.ndarray,
                               page_size: int) -> dict:
@@ -154,36 +228,21 @@ def write_prompts_paged_layer(pool: dict, layer, tables: jnp.ndarray,
     for the scan-CARRY prefill path (round 5): the pool stays in the layer
     scan's carry — XLA's loop-carry aliasing keeps it in place — instead of
     streaming xs→ys, whose re-stack held a second full-size pool buffer in
-    the compiled program (the batch-128 paged HBM OOM recorded in
-    BENCH_session_r5.stderr.txt; the dense cache's simpler scatter pattern
-    aliased and survived). Same index/drop contract as the per-layer form,
-    with the scalar ``layer`` leading the scatter."""
-    N, T = k.shape[:2]
-    tok = jnp.arange(T, dtype=jnp.int32)
-    pg = tables[:, tok // page_size]                   # [N, T]
-    off = jnp.broadcast_to(tok % page_size, (N, T))
-    return _write_kv(
-        pool,
-        lambda arr, val: arr.at[layer, pg, :, off].set(val, mode="drop"),
-        k, v)
+    the compiled program (the batch-128 paged HBM OOM of the round-5 chip
+    run, older code, whose record is no longer in the tree). Same
+    index/drop contract as the per-layer form; page-granular windows, see
+    :func:`_write_span_by_page`."""
+    return _write_span_by_page(pool, layer, tables, 0, k, v, page_size)
 
 
 def write_chunk_paged_layer(pool: dict, layer, pages: jnp.ndarray,
                             start, k: jnp.ndarray, v: jnp.ndarray,
                             page_size: int) -> dict:
     """FULL-pool variant of :func:`write_chunk_paged` (carry prefill path —
-    see write_prompts_paged_layer). k/v: [1, C, Hkv, D]."""
-    C = k.shape[1]
-    rows = start + jnp.arange(C, dtype=jnp.int32)      # [C]
-    idx = rows // page_size
-    valid = idx < pages.shape[0]
-    pg = jnp.where(valid, pages[jnp.clip(idx, 0, pages.shape[0] - 1)],
-                   OOB_PAGE)
-    off = rows % page_size
-    return _write_kv(
-        pool,
-        lambda arr, val: arr.at[layer, pg, :, off].set(val, mode="drop"),
-        k[0], v[0])
+    see write_prompts_paged_layer). k/v: [1, C, Hkv, D]; ``start`` may be a
+    python int (page-aligned starts then touch one page fewer)."""
+    return _write_span_by_page(pool, layer, pages[None], start, k, v,
+                               page_size)
 
 
 def write_chunk_paged(pool_l: dict, pages: jnp.ndarray, start: jnp.ndarray,
@@ -238,6 +297,8 @@ def gather_slot(pool_l: dict, pages: jnp.ndarray, page_size: int,
     reads the cached prefix); the decode kernels never gather.
     """
     arr = pool_l[name][pages]                    # [n, Hkv, page, (D)]
+    if arr.ndim == 3:
+        arr = arr[..., :page_size]               # drop scale lane padding
     arr = jnp.moveaxis(arr, 1, 0)                # [Hkv, n, page, (D)]
     return arr.reshape((arr.shape[0], -1) + arr.shape[3:])
 
@@ -247,9 +308,12 @@ def gather_layer_dense(pool: dict, layer, table: jnp.ndarray) -> dict:
     {name: [B, Hkv, S_v, (D)]}. Test/CPU path only — a full gather per step
     is exactly what the Pallas paged kernels avoid."""
     out = {}
+    ps = pool["k"].shape[3]
     for name, arr in pool.items():
         al = jax.lax.dynamic_index_in_dim(arr, layer, 0, keepdims=False)
         g = al[table]                            # [B, n, Hkv, page, (D)]
+        if g.ndim == 4:
+            g = g[..., :ps]                      # drop scale lane padding
         g = jnp.moveaxis(g, 2, 1)                # [B, Hkv, n, page, (D)]
         out[name] = g.reshape(g.shape[:2] + (-1,) + g.shape[4:])
     return out

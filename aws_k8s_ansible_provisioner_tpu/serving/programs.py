@@ -2479,17 +2479,16 @@ class EnginePrograms:
 
         scope="full" (serving): every variant — each prefill bucket, batched/
         chunked prefill, prefix cache, speculative, penalties, logprobs, both
-        decode horizons. ~10 programs; over a network-attached chip this is
-        minutes of XLA time, which is fine at server startup (the readiness
-        probe gates traffic) but NOT inside a bounded benchmark window.
+        decode horizons. ~20 programs, minutes of XLA time cold — fine at
+        server startup (the readiness probe gates traffic) but NOT inside a
+        bounded benchmark window.
 
         scope="bench": only the two programs the benchmark path executes —
         the full-width batched prefill and the fused-horizon decode (bench
         prompts sit below the prefix-cache min length, spec decode is off,
         and the fill loop admits batches until the queue drains, so no other
-        program is ever dispatched). This is what lets bench.py fit warmup +
-        measurement inside the driver's ~900s budget (BENCH_r02 postmortem:
-        serial full warmup plausibly consumed the whole window).
+        program is ever dispatched). This is what keeps bench.py's warmup
+        to two compiles instead of ~20.
         """
         t0 = time.monotonic()
         try:

@@ -1674,6 +1674,7 @@ def build_state(serving_cfg=None, model_cfg=None, params=None,
     # Device telemetry: configure() carries over the cost model + HBM
     # samplers the engine installed during construction above.
     devmon.configure(
+        device_kind=jax.devices()[0].device_kind,
         enabled=getattr(serving, "devmon_enabled", True),
         peak_tflops=getattr(serving, "devmon_peak_tflops", 197.0),
         hbm_gbps=getattr(serving, "devmon_peak_hbm_gbps", 819.0),
@@ -1729,9 +1730,9 @@ def serve(state: ServerState, host: str, port: int,
     httpd.server_close()
 
 
-def main(argv=None):
-    from aws_k8s_ansible_provisioner_tpu.config import ServingConfig
-
+def build_parser() -> argparse.ArgumentParser:
+    """The server's command line. ``main`` and anything that must serve the
+    SAME configuration a user's flags produce (chip_smoke.py) parse here."""
     p = argparse.ArgumentParser(description="TPU-native OpenAI-compatible "
                                             "LLM server")
     p.add_argument("--model", default="Qwen/Qwen3-0.6B")
@@ -1894,39 +1895,15 @@ def main(argv=None):
                         "enforced, ledger surfaced on /healthz and the "
                         "tpu_serve_hbm_compiled_bytes gauge")
     p.add_argument("-v", "--verbose", action="store_true")
-    args = p.parse_args(argv)
+    return p
 
-    logging.basicConfig(
-        level=logging.DEBUG if args.verbose else logging.INFO,
-        format="%(asctime)s %(name)s %(levelname)s %(message)s")
 
-    if args.platform:
-        import jax
+def serving_config_from_args(args):
+    """The ServingConfig the parsed flags describe."""
+    from aws_k8s_ansible_provisioner_tpu.config import (MeshConfig,
+                                                        ServingConfig)
 
-        jax.config.update("jax_platforms", args.platform)
-
-    # Persistent XLA compilation cache: warmup compiles ~13 programs (20-40s
-    # each on TPU); a CONTAINER restart (the liveness probe's stall-recovery
-    # kick) must not pay that again. The serving manifest backs the path
-    # with an emptyDir and pins JAX_COMPILATION_CACHE_DIR to it — pod-level
-    # restarts (rollout, node drain) start cold; back the path with a PVC if
-    # rollout survival matters. Env JAX_COMPILATION_CACHE_DIR overrides.
-    cache_dir = os.environ.get(
-        "JAX_COMPILATION_CACHE_DIR",
-        os.path.join(tempfile.gettempdir(), "tpu-serve-xla-cache"))
-    try:
-        import jax
-
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    # tpulint: disable=R3 startup nicety — a missing compile cache slows warmup but must never block serving; warning carries the traceback
-    except Exception:
-        log.warning("persistent compile cache unavailable", exc_info=True)
-
-    from aws_k8s_ansible_provisioner_tpu.config import MeshConfig
-
-    serving = ServingConfig(
+    return ServingConfig(
         model=args.model, port=args.port, host=args.host,
         max_decode_slots=args.max_decode_slots,
         max_cache_len=args.max_cache_len, dtype=args.dtype,
@@ -1961,6 +1938,36 @@ def main(argv=None):
         capacity_window_s=args.capacity_window_s,
         capacity_trend_window_s=args.capacity_trend_window_s,
         mesh=MeshConfig(dp=args.dp, tp=args.tp, sp=args.sp, ep=args.ep))
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+
+    logging.basicConfig(
+        level=logging.DEBUG if args.verbose else logging.INFO,
+        format="%(asctime)s %(name)s %(levelname)s %(message)s")
+
+    if args.platform:
+        import jax
+
+        jax.config.update("jax_platforms", args.platform)
+
+    # Persistent XLA compilation cache: warmup compiles ~20 programs; a
+    # CONTAINER restart (the liveness probe's stall-recovery kick) must not
+    # pay that again. The serving manifest backs the path with an emptyDir
+    # and pins JAX_COMPILATION_CACHE_DIR to it — pod-level restarts
+    # (rollout, node drain) start cold; back the path with a PVC if rollout
+    # survival matters. Placement rule: utils/compile_cache.py.
+    try:
+        from aws_k8s_ansible_provisioner_tpu.utils.compile_cache import (
+            enable_compile_cache)
+
+        log.info("persistent compile cache: %s", enable_compile_cache())
+    # tpulint: disable=R3 startup nicety — a missing compile cache slows warmup but must never block serving; warning carries the traceback
+    except Exception:
+        log.warning("persistent compile cache unavailable", exc_info=True)
+
+    serving = serving_config_from_args(args)
     state = build_state(serving)
     if args.aot_manifest:
         # Fail fast BEFORE warmup: a mismatched or no-fit manifest means the

@@ -1,0 +1,796 @@
+"""Chip smoke: the served path, end to end, on the TPU JAX finds.
+
+    python chip_smoke.py              # one chip (what the driver runs)
+    python chip_smoke.py --chips 4    # the sharded path (--tp 4) against the
+                                      # same weights on one device; four chips
+
+One process holds the chip(s): the server is built by its own entry points
+(``serving.server.build_parser`` -> ``serving_config_from_args`` ->
+``build_state`` -> ``warmup`` -> ``serve``, the sequence ``main()`` runs) on a
+worker thread, and the HTTP client runs beside it. Flags are the README
+command's minus ``--checkpoint-dir``: Qwen/Qwen3-0.6B at full width and
+depth, random weights from the server's own seeded no-checkpoint path, byte
+tokenizer, paged pool, autotuned decode block, pipeline, ragged dispatch,
+full warm-up. Nothing here sets ``jax_platforms`` or passes ``--platform``.
+
+It fails (non-zero, no result line) unless ``jax.devices()[0].platform`` is
+"tpu". Every phase raises on failure; nothing is caught and carried past.
+The last stdout line of a passing run is exactly
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}
+
+Tolerances (stated once, used below):
+- KERNEL_TOL: compiled Pallas kernels vs the plain jax.numpy references, on
+  bf16 outputs of O(1) values (bf16 spacing there is 2**-8 ~ 4e-3).
+- NEAR_MAX_NATS: end-to-end, per generated position, how far the served
+  token's logprob under the teacher-forced reference may sit below the
+  reference's own maximum. Weights are random, so the top two logits are
+  often a few hundredths of a nat apart and bf16 matmuls at another batch
+  shape legitimately flip the argmax; a broken cache or kernel instead
+  lands ~3 nats down (logit sigma ~0.6 over a 152k vocabulary). Raw token
+  equality would be brittle; this is not.
+- LOGPROB_NATS: served chosen-token logprob vs the reference's logprob of
+  that same token. With --chips 4 the served side is the --tp 4 engine and
+  the reference holds the whole model on device 0.
+
+``--rehearse`` is the builder's CPU rehearsal of this same script (tiny
+model, XLA attention, interpret-mode kernel parity at small shapes, no
+persistent cache); it never prints an "ok" line.
+"""
+
+from __future__ import annotations
+
+import argparse
+import http.client
+import json
+import logging
+import os
+import socket
+import subprocess
+import sys
+import threading
+import time
+
+KERNEL_TOL = 2e-2
+NEAR_MAX_NATS = 0.35
+LOGPROB_NATS = 0.25
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+
+
+def say(msg: str) -> None:
+    print(f"chip_smoke: {msg}", flush=True)
+
+
+def check(cond, msg: str) -> None:
+    if not cond:
+        raise AssertionError(msg)
+
+
+# ---------------------------------------------------------------------------
+# HTTP client (stdlib; talks to the in-process server over loopback)
+# ---------------------------------------------------------------------------
+
+
+def http_json(port: int, method: str, path: str, body=None, timeout=900.0):
+    conn = http.client.HTTPConnection("127.0.0.1", port, timeout=timeout)
+    try:
+        data = json.dumps(body).encode() if body is not None else None
+        conn.request(method, path, body=data,
+                     headers={"Content-Type": "application/json"})
+        resp = conn.getresponse()
+        raw = resp.read()
+        return resp.status, raw
+    finally:
+        conn.close()
+
+
+def http_stream(port: int, path: str, body: dict, on_first=None,
+                timeout=900.0) -> dict:
+    """POST a stream=true request; returns {"status", "token_ids",
+    "logprobs", "done"} gathered from the SSE chunks."""
+    conn = http.client.HTTPConnection("127.0.0.1", port, timeout=timeout)
+    out = {"status": None, "token_ids": [], "logprobs": [], "done": False}
+    try:
+        conn.request("POST", path, body=json.dumps(body).encode(),
+                     headers={"Content-Type": "application/json"})
+        resp = conn.getresponse()
+        out["status"] = resp.status
+        if resp.status != 200:
+            out["error"] = resp.read()[:400]
+            return out
+        first = True
+        for raw in resp:
+            line = raw.strip()
+            if not line.startswith(b"data:"):
+                continue
+            payload = line[5:].strip()
+            if payload == b"[DONE]":
+                out["done"] = True      # keep reading to the chunked end:
+                continue                # closing on unread bytes is a reset
+            for ch in json.loads(payload).get("choices", []):
+                ids = ch.get("token_ids") or []
+                out["token_ids"] += ids
+                lp = ch.get("logprobs")
+                if lp and "token_logprobs" in lp:
+                    out["logprobs"] += lp["token_logprobs"]
+                if ids and first and on_first is not None:
+                    first = False
+                    on_first()
+        return out
+    finally:
+        conn.close()
+
+
+def parse_metrics(text: str) -> dict:
+    """Prometheus text -> {"name{labels}": value}."""
+    vals = {}
+    for line in text.splitlines():
+        if line and not line.startswith("#"):
+            key, _, val = line.rpartition(" ")
+            try:
+                vals[key] = float(val)
+            except ValueError:
+                pass
+    return vals
+
+
+def dispatched(port: int) -> set:
+    """Program kinds with device time in devmon's window (/debug/roofline).
+    The window is 60 s, so callers ask right after the traffic they mean."""
+    status, raw = http_json(port, "GET", "/debug/roofline")
+    check(status == 200, f"/debug/roofline -> {status}")
+    return {k for k, p in json.loads(raw)["programs"].items()
+            if p["device_seconds"] > 0}
+
+
+def prompt_of(n: int, salt: int) -> str:
+    """n ASCII bytes = n byte-tokenizer tokens; distinct per salt so no two
+    prompts share a page-long prefix by accident."""
+    words = ["tpu", "page", "ragged", "decode", "prefill", "kernel", "slot",
+             "block", "cache", "chip", "mesh", "shard"]
+    s, i = f"[{salt}] ", salt
+    while len(s) < n:
+        s += words[i % len(words)] + " "
+        i += 3 + salt
+    return s[:n]
+
+
+# ---------------------------------------------------------------------------
+# The server under test
+# ---------------------------------------------------------------------------
+
+
+def build_native_scheduler() -> None:
+    """The native scheduler is the intended one (runtime/__init__.py: the
+    C++ core is authoritative, Python the fallback), and make_scheduler picks
+    up whatever libtpu_serve_runtime.so it finds. Rebuild it from the
+    committed sources every time, so the smoke behaves the same on a
+    checkout (no native/build/) and on a disk copy (a stale one)."""
+    subprocess.run(["make", "-B", "-C", os.path.join(HERE, "native"),
+                    "runtime"], check=True, stdout=subprocess.DEVNULL)
+
+
+class Server:
+    """build_state -> warmup -> serve on a worker thread, as main() does."""
+
+    def __init__(self, flags):
+        from aws_k8s_ansible_provisioner_tpu.serving import server
+
+        self.port = _free_port()
+        argv = list(flags) + ["--host", "127.0.0.1", "--port", str(self.port)]
+        args = server.build_parser().parse_args(argv)
+        t0 = time.monotonic()
+        self.state = server.build_state(server.serving_config_from_args(args))
+        self.build_s = time.monotonic() - t0
+        self.engine = self.state.engine
+        t0 = time.monotonic()
+        self.engine.warmup()
+        self.warmup_s = time.monotonic() - t0
+        ready = threading.Event()
+        # daemon: a failed phase must end the process, not leave it serving
+        self._thread = threading.Thread(
+            target=server.serve, name="serve", daemon=True,
+            args=(self.state, "127.0.0.1", self.port, ready))
+        self._thread.start()
+        check(ready.wait(60), "server did not come up")
+
+    def drain(self) -> None:
+        """End the run through the server's own drain (POST /admin/drain ->
+        begin_drain -> stop once idle), then release the device buffers."""
+        status, _ = http_json(self.port, "POST", "/admin/drain", {})
+        check(status == 200, f"/admin/drain -> {status}")
+        self._thread.join(120)
+        check(not self._thread.is_alive(), "server did not stop after drain")
+        self.engine.cache = None
+        self.engine.params = None
+        self.engine = self.state = None
+
+
+def _free_port() -> int:
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        return s.getsockname()[1]
+
+
+# ---------------------------------------------------------------------------
+# Phase: requests
+# ---------------------------------------------------------------------------
+
+# (name, prompt tokens, max_tokens, streamed-with-logprobs?) — lengths on both
+# sides of one page (64) and of the smallest bucket (32); the streamed ones
+# carry token ids + chosen logprobs for the numerics checks.
+WAVE = [("c12", 12, 32, False), ("c70", 70, 48, True),
+        ("c300", 300, 64, False), ("c1100", 1100, 40, False),
+        ("c20", 20, 32, False), ("c25", 25, 32, False),
+        ("c9", 9, 32, False), ("c30", 30, 32, True)]
+
+
+def run_requests(srv: Server, model: str) -> dict:
+    """The whole request phase against one server. Returns what the
+    numerics phase compares: {name: {"prompt", "token_ids", "logprobs"}} for
+    the streamed requests."""
+    port = srv.port
+    status, raw = http_json(port, "GET", "/v1/models")
+    check(status == 200, f"/v1/models -> {status}")
+    ids = [m["id"] for m in json.loads(raw)["data"]]
+    check(model in ids, f"/v1/models lists {ids}, not {model}")
+
+    status, raw = http_json(port, "GET", "/metrics")
+    check(status == 200, f"/metrics -> {status}")
+    before = parse_metrics(raw.decode())
+
+    expected = 0
+    streams: dict = {}
+    errors: list = []
+    gate = threading.Barrier(len(WAVE))
+
+    def one(i, name, n_prompt, n_gen, streamed):
+        try:
+            body = {"model": model, "prompt": prompt_of(n_prompt, i + 1),
+                    "max_tokens": n_gen, "temperature": 0.0,
+                    "ignore_eos": True}
+            gate.wait(60)
+            if streamed:
+                r = http_stream(port, "/v1/completions",
+                                dict(body, stream=True, logprobs=0))
+                check(r["status"] == 200 and r["done"],
+                      f"{name}: stream status {r['status']} done {r['done']}")
+                check(len(r["token_ids"]) == n_gen,
+                      f"{name}: {len(r['token_ids'])} tokens, want {n_gen}")
+                check(len(r["logprobs"]) == n_gen
+                      and all(isinstance(x, float) for x in r["logprobs"]),
+                      f"{name}: logprobs {r['logprobs'][:4]}...")
+                streams[name] = {"prompt": body["prompt"],
+                                 "token_ids": r["token_ids"],
+                                 "logprobs": r["logprobs"]}
+            else:
+                status, raw = http_json(port, "POST", "/v1/completions",
+                                        body)
+                check(status == 200, f"{name}: status {status} {raw[:300]}")
+                usage = json.loads(raw)["usage"]
+                check(usage["completion_tokens"] == n_gen
+                      and usage["prompt_tokens"] == n_prompt,
+                      f"{name}: usage {usage}, want {n_prompt}+{n_gen}")
+        except BaseException as e:      # re-raised on the main thread below
+            errors.append(e)
+            gate.abort()
+
+    threads = [threading.Thread(target=one, args=(i,) + w)
+               for i, w in enumerate(WAVE)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    if errors:
+        raise errors[0]
+    expected += sum(w[2] for w in WAVE)
+    ran = dispatched(port)
+    say(f"requests: {len(WAVE)} concurrent /v1/completions ok "
+        f"(prompts {[w[1] for w in WAVE]}, {expected} tokens)")
+
+    # one streamed chat completion
+    r = http_stream(port, "/v1/chat/completions", {
+        "model": model, "stream": True, "max_tokens": 32,
+        "temperature": 0.0, "ignore_eos": True,
+        "messages": [{"role": "user", "content": "Say something long."}]})
+    check(r["status"] == 200 and r["done"] and len(r["token_ids"]) == 32,
+          f"chat stream: status {r['status']} done {r['done']} "
+          f"tokens {len(r['token_ids'])}")
+    expected += 32
+    say("requests: streamed /v1/chat/completions ok (32 tokens)")
+
+    # the long prompt again WHILE another stream decodes: its 17 full pages
+    # are a prefix-cache hit, and the suffix walk finds live decode rows, so
+    # it rides the ragged mixed program
+    started = threading.Event()
+    bg: dict = {}
+
+    def background():
+        bg.update(http_stream(
+            port, "/v1/completions",
+            {"model": model, "prompt": prompt_of(40, 99), "stream": True,
+             "max_tokens": 256, "temperature": 0.0, "ignore_eos": True},
+            on_first=started.set))
+        started.set()
+
+    t = threading.Thread(target=background)
+    t.start()
+    check(started.wait(600), "background stream never produced a token")
+    status, raw = http_json(
+        port, "POST", "/v1/completions",
+        {"model": model, "prompt": prompt_of(1100, 4), "max_tokens": 32,
+         "temperature": 0.0, "ignore_eos": True})
+    t.join()
+    check(status == 200
+          and json.loads(raw)["usage"]["completion_tokens"] == 32,
+          f"repeated long prompt: {status} {raw[:300]}")
+    check(bg.get("status") == 200 and bg["done"]
+          and len(bg["token_ids"]) == 256,
+          f"background stream: {bg.get('status')} "
+          f"{len(bg.get('token_ids', []))} tokens")
+    expected += 32 + 256
+    ran |= dispatched(port)
+    say("requests: repeated 1100-token prompt beside a live stream ok")
+
+    status, raw = http_json(port, "GET", "/metrics")
+    check(status == 200, f"/metrics -> {status}")
+    after = parse_metrics(raw.decode())
+
+    def delta(key):
+        return after.get(key, 0.0) - before.get(key, 0.0)
+
+    got = delta("tpu_serve_generated_tokens_total")
+    check(got == expected,
+          f"generated-token counter moved {got}, requests asked {expected}")
+    for bad in ("error", "timeout"):
+        key = f'tpu_serve_request_total{{status="{bad}"}}'
+        check(delta(key) == 0, f"{key} moved by {delta(key)}")
+    hits = delta("tpu_serve_prefix_cache_hits_total")
+    check(hits >= 1, "the repeated long prompt did not hit the prefix cache")
+
+    status, raw = http_json(port, "GET", "/healthz")
+    check(status == 200, f"/healthz -> {status}")
+    hz = json.loads(raw)
+    check(hz["status"] == "ok" and not hz["last_error"],
+          f"/healthz status {hz['status']} last_error {hz['last_error']}")
+    for kind in ("prefill", "prefill_batch", "decode", "mixed_step"):
+        check(kind in ran, f"program kind {kind!r} never dispatched "
+                           f"(dispatched: {sorted(ran)})")
+    say(f"requests: /metrics tokens +{int(got)}, prefix hits +{int(hits)}, "
+        f"0 error/timeout; programs dispatched: {sorted(ran)}")
+    return streams
+
+
+# ---------------------------------------------------------------------------
+# Phase: end-to-end numerics against teacher-forced model_forward
+# ---------------------------------------------------------------------------
+
+
+def reference_logprobs(cfg, params, tokenizer, prompt: str, token_ids):
+    """Teacher-forced float32 log-softmax of the plain model (``model_forward``
+    with its default XLA causal attention, no cache, no kernel) over
+    prompt + served tokens: returns (logprob of each served token, the
+    reference's own max logprob) per generated position."""
+    import jax
+    import jax.numpy as jnp
+
+    from aws_k8s_ansible_provisioner_tpu.models.layers import model_forward
+
+    ids = tokenizer.encode(prompt) + [int(t) for t in token_ids]
+    n_prompt = len(ids) - len(token_ids)
+    T = -(-len(ids) // 64) * 64
+    toks = jnp.asarray([ids + [0] * (T - len(ids))], jnp.int32)
+    pos = jnp.arange(T, dtype=jnp.int32)[None]
+
+    @jax.jit
+    def fwd(params, toks, pos):
+        logits, _ = model_forward(params, cfg, toks, pos)
+        return jax.nn.log_softmax(logits[0].astype(jnp.float32), axis=-1)
+
+    lp = fwd(params, toks, pos)
+    # position p's logits predict token p+1
+    rows = lp[n_prompt - 1:len(ids) - 1]
+    served = rows[jnp.arange(len(token_ids)), jnp.asarray(token_ids)]
+    return jax.device_get(served), jax.device_get(rows.max(axis=-1))
+
+
+def single_device_params(cfg, serving):
+    """The server's own no-checkpoint weights as ONE device holds them
+    (build_state's seeded init, then the engine's single-device int8
+    quantization) — what the tp=4 engine's answers are compared with."""
+    import jax
+    import jax.numpy as jnp
+
+    from aws_k8s_ansible_provisioner_tpu.models.layers import init_params
+    from aws_k8s_ansible_provisioner_tpu.models.quant import quantize_params
+
+    dtype = jnp.bfloat16 if serving.dtype == "bfloat16" else jnp.float32
+    params = init_params(cfg, jax.random.PRNGKey(0), dtype)
+    check(serving.weights_dtype == "int8", "default weights are int8")
+    return quantize_params(params, cfg)
+
+
+def check_numerics(name: str, stream: dict, cfg, params, tokenizer) -> None:
+    import numpy as np
+
+    served_ref, ref_max = reference_logprobs(
+        cfg, params, tokenizer, stream["prompt"], stream["token_ids"])
+    gap = float(np.max(ref_max - served_ref))
+    agree = float(np.max(np.abs(np.asarray(stream["logprobs"])
+                                - served_ref)))
+    exact = int(np.sum(served_ref == ref_max))
+    say(f"numerics[{name}]: {len(served_ref)} positions; served token below "
+        f"reference max by <= {gap:.4f} nats (tol {NEAR_MAX_NATS}); served "
+        f"vs reference logprob differ <= {agree:.4f} nats (tol "
+        f"{LOGPROB_NATS}); reference argmax == served at {exact} positions")
+    check(np.all(np.isfinite(served_ref)), "non-finite reference logprobs")
+    check(gap <= NEAR_MAX_NATS,
+          f"{name}: a served token sits {gap:.3f} nats below the reference "
+          f"maximum")
+    check(agree <= LOGPROB_NATS,
+          f"{name}: served logprobs differ from the reference by "
+          f"{agree:.3f} nats")
+
+
+# ---------------------------------------------------------------------------
+# Phase: on-chip kernel parity (compiled Pallas vs plain jax.numpy)
+# ---------------------------------------------------------------------------
+
+
+def kernel_parity(cfg, slots: int, window: int, page: int, bblocks,
+                  interpret: bool) -> None:
+    """decode / ragged / write kernels at the served widths against the
+    repo's jax.numpy references (ops/attention.decode_attend over
+    paged_kv.gather_layer_dense; paged_kv.write_token_layer_paged), on
+    seeded inputs, bf16 and int8 pools. Depth is cut to 2 layers — a layer
+    is an index into the pool here — everything else is the server's."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from aws_k8s_ansible_provisioner_tpu.ops import pallas_attention as pa
+    from aws_k8s_ansible_provisioner_tpu.ops.attention import decode_attend
+    from aws_k8s_ansible_provisioner_tpu.serving import kv_cache as kvc
+    from aws_k8s_ansible_provisioner_tpu.serving import paged_kv as pkv
+
+    Hq, Hkv, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    B, MP, L = slots, window // page, 2
+    P = B * MP + 1
+    layer = jnp.int32(1)
+    rng = np.random.default_rng(21)
+    # a permuted table (page 0 = scratch stays out), ragged lengths mixing a
+    # full window, page edges, one token, and mid-page
+    table = jnp.asarray((rng.permutation(B * MP) + 1)
+                        .reshape(B, MP).astype(np.int32))
+    base = [window, 1, page, page + 1, 2 * page - 1, 300, 1100, 7]
+    lengths = jnp.asarray([base[i % len(base)] for i in range(B)], jnp.int32)
+    keys = jax.random.split(jax.random.PRNGKey(21), 8)
+    q = jax.random.normal(keys[0], (B, 1, Hq, D), jnp.bfloat16)
+    shape = (L, P, Hkv, page, D)
+
+    def close(name, got, want):
+        got = np.asarray(jnp.asarray(got, jnp.float32))
+        want = np.asarray(jnp.asarray(want, jnp.float32))
+        check(np.all(np.isfinite(got)), f"{name}: non-finite output")
+        err = float(np.max(np.abs(got - want)
+                           / (KERNEL_TOL + KERNEL_TOL * np.abs(want))))
+        check(err <= 1.0, f"{name}: off by {err:.2f}x the tolerance "
+                          f"(atol=rtol={KERNEL_TOL})")
+        return float(np.max(np.abs(got - want)))
+
+    for quant in (False, True):
+        tag = "int8" if quant else "bf16"
+        kf = jax.random.normal(keys[1], shape, jnp.bfloat16)
+        vf = jax.random.normal(keys[2], shape, jnp.bfloat16)
+        if quant:
+            # the engine's int8 pool layout: scale leaves lane-padded
+            pad = [(0, 0)] * 3 + [(0, pkv.scale_lanes(page) - page)]
+            k8, ks = kvc.quantize_rows(kf)
+            v8, vs = kvc.quantize_rows(vf)
+            pool = {"k": k8, "v": v8,
+                    "ks": jnp.pad(ks, pad), "vs": jnp.pad(vs, pad)}
+            del k8, v8, ks, vs
+            skw = dict(pool_ks=pool["ks"], pool_vs=pool["vs"])
+        else:
+            pool = {"k": kf, "v": vf}
+            skw = {}
+        del kf, vf
+
+        def dense_view(tab):
+            d = pkv.gather_layer_dense(pool, layer, tab)
+            if quant:
+                return (kvc.dequantize(d["k"], d["ks"]),
+                        kvc.dequantize(d["v"], d["vs"]))
+            return d["k"], d["v"]
+
+        with jax.default_matmul_precision("highest"):
+            ck, cv = dense_view(table)
+            ref = decode_attend(q, ck, cv, lengths)
+        # ragged rows: every decode row, then 64 prefill-chunk rows of slot 3
+        # at positions 300..363 (limit = position + 1), as mixed_step packs
+        C = 64
+        crow = 300 + jnp.arange(C, dtype=jnp.int32)
+        limits = jnp.concatenate([lengths, crow + 1])
+        rtab = jnp.concatenate(
+            [table, jnp.broadcast_to(table[3][None], (C, MP))])
+        q3 = jax.random.normal(keys[3], (B + C, Hq, D), jnp.bfloat16)
+        with jax.default_matmul_precision("highest"):
+            ck, cv = dense_view(rtab)
+            rref = decode_attend(q3[:, None], ck, cv, limits)[:, 0]
+        del ck, cv
+        for bb in bblocks:
+            out = pa.decode_attend_pallas_paged(
+                q, pool["k"], pool["v"], lengths, layer, table,
+                interpret=interpret, bblock=bb, **skw)
+            e1 = close(f"decode_attend_pallas_paged {tag} bb={bb}", out, ref)
+            out = pa.ragged_attend_pallas_paged(
+                q3, pool["k"], pool["v"], limits, layer, rtab,
+                interpret=interpret, bblock=bb, **skw)
+            e2 = close(f"ragged_attend_pallas_paged {tag} bb={bb}", out,
+                       rref)
+            say(f"parity: decode/ragged paged {tag} bb={bb}: max abs err "
+                f"{e1:.2e} / {e2:.2e} (tol {KERNEL_TOL})")
+
+        # write kernels: one new row per slot at each slot's length (the
+        # full-window slot's row is out of range and must DROP)
+        new = jax.random.normal(keys[4], (B, Hkv, D), jnp.bfloat16)
+        want = pkv.write_token_layer_paged(
+            pool, layer, lengths, table, new[:, None], new[:, None], page)
+        if quant:
+            gk, gks = pa.cache_write_row_quant_paged(
+                pool["k"], pool["ks"], new, lengths, table, layer,
+                interpret=interpret)
+            check(bool(jnp.array_equal(gk, want["k"])),
+                  "cache_write_row_quant_paged: int8 rows differ")
+            close("cache_write_row_quant_paged scales", gks, want["ks"])
+        else:
+            gk = pa.cache_write_row_paged(pool["k"], new, lengths, table,
+                                          layer, interpret=interpret)
+            check(bool(jnp.array_equal(gk, want["k"])),
+                  "cache_write_row_paged: rows differ from the scatter")
+        say(f"parity: paged write kernel {tag}: equal to the jnp scatter")
+        del pool, want, gk
+
+
+# ---------------------------------------------------------------------------
+# Phase (--chips 4): shards and the compiled decode program
+# ---------------------------------------------------------------------------
+
+
+def check_shards(engine, n: int) -> None:
+    import jax
+
+    def per_device(tree):
+        by_dev: dict = {}
+        for leaf in jax.tree.leaves(tree):
+            for sh in leaf.addressable_shards:
+                by_dev[sh.device.id] = by_dev.get(sh.device.id, 0) \
+                    + sh.data.nbytes
+        return by_dev
+
+    for name, tree in (("params", engine.params), ("kv pool", engine.cache)):
+        by_dev = per_device(tree)
+        whole = sum(leaf.nbytes for leaf in jax.tree.leaves(tree))
+        say(f"shards[{name}]: whole {whole / 2**20:.1f} MiB; per device "
+            + ", ".join(f"d{d}: {b / 2**20:.1f}"
+                        for d, b in sorted(by_dev.items())))
+        check(len(by_dev) == n,
+              f"{name} lives on {len(by_dev)} device(s), want {n}")
+        # a quarter each, with room for the replicated leaves (norms,
+        # scales) every device holds whole
+        check(max(by_dev.values()) <= 0.35 * whole * 4 / n,
+              f"{name}: a device holds {max(by_dev.values())} of {whole} "
+              f"bytes — not sharded {n} ways")
+    stats = {d.id: d.memory_stats() for d in jax.devices()[:n]}
+    if not all(stats.values()):      # the CPU backend reports none
+        say("shards: memory_stats not reported by this backend")
+        return
+    used = {d: st["bytes_in_use"] for d, st in stats.items()}
+    say("shards: memory_stats bytes_in_use per device: "
+        + ", ".join(f"d{d}: {b / 2**20:.1f} MiB" for d, b in used.items()))
+    check(min(used.values()) >= 0.5 * max(used.values()),
+          f"device memory is lopsided: {used}")
+
+
+def check_decode_program(engine) -> None:
+    """The decode program the engine dispatches, lowered from the engine's
+    own enumeration (serving/aot.py), compiled for its mesh: the Pallas
+    kernels are in it, under shard_map, and decode attention needs no
+    collective (ops/attention.make_decode_attend_carry: none for dp/tp) —
+    the only ones are GSPMD's for the tensor-parallel matmuls."""
+    import re
+
+    from aws_k8s_ansible_provisioner_tpu.serving import aot
+
+    plan = aot.ProgramPlan(engine.cfg, engine.serving,
+                           tp=engine.mesh.shape["tp"])
+    params, cache = aot._abstract_state(plan, engine.mesh)
+    name, fn, args, kwargs = next(
+        p for p in aot.enumerate_programs(plan, engine.mesh, params, cache,
+                                          bblock=engine.decode_bblock)
+        if p[0].startswith("decode_fused_h"))
+    lowered = fn.lower(*args, **kwargs)
+    check("shard_map" in lowered.as_text(debug_info=True),
+          f"{name}: no shard_map in the lowered program")
+    text = lowered.compile().as_text()
+    n_kernels = text.count("tpu_custom_call")
+    coll = [ln for ln in text.splitlines() if re.search(
+        r"= \S+ (all-reduce|all-gather|all-to-all|collective-permute|"
+        r"reduce-scatter)(-start)?\(", ln)]
+    in_attn = [ln for ln in coll if "shard_map" in ln or "pallas" in ln]
+    kinds = sorted({re.search(r"(all-reduce|all-gather|all-to-all|"
+                              r"collective-permute|reduce-scatter)",
+                              ln).group(1) for ln in coll})
+    say(f"program[{name}]: {n_kernels} tpu_custom_call, {len(coll)} "
+        f"collectives {kinds}, {len(in_attn)} inside the attention "
+        f"shard_map")
+    check(n_kernels >= 3, f"{name}: {n_kernels} Pallas kernels compiled in, "
+                          f"want the two row writes and the attend")
+    check("all-reduce" in kinds, f"{name}: tensor-parallel matmuls need an "
+                                 f"all-reduce; found {kinds}")
+    check(not in_attn, f"{name}: a collective inside decode attention: "
+                       f"{[ln[:200] for ln in in_attn[:1]]}")
+
+
+# ---------------------------------------------------------------------------
+# Main
+# ---------------------------------------------------------------------------
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, default=1, choices=(1, 4))
+    ap.add_argument("--rehearse", action="store_true",
+                    help="builder's CPU rehearsal: tiny model, no TPU "
+                         "required, never prints an ok line")
+    opts = ap.parse_args()
+    # the server's own log lines (scheduler pick, compile cache, devmon's
+    # device kind and peaks, drain) go to stderr, as under main()
+    logging.basicConfig(
+        level=logging.INFO,
+        format="%(asctime)s %(name)s %(levelname)s %(message)s")
+
+    import jax
+
+    dev = jax.devices()[0]
+    device = {"platform": dev.platform, "kind": dev.device_kind,
+              "count": len(jax.devices())}
+    if dev.platform != "tpu" and not opts.rehearse:
+        print(f"chip_smoke: JAX found no TPU (platform {dev.platform!r}); "
+              f"this smoke runs on the chip or not at all", file=sys.stderr)
+        return 2
+    check(device["count"] >= opts.chips,
+          f"--chips {opts.chips} needs {opts.chips} devices, JAX sees "
+          f"{device['count']}")
+    try:
+        import libtpu
+
+        libtpu_version = libtpu.__version__
+    except ImportError:
+        libtpu_version = "absent"
+    say(f"device: {dev.device_kind} x{device['count']} ({dev.platform}); "
+        f"jax {jax.__version__}, libtpu {libtpu_version}")
+
+    from aws_k8s_ansible_provisioner_tpu.ops import pallas_attention as pa
+    from aws_k8s_ansible_provisioner_tpu.ops.attention import resolve_impl
+    from aws_k8s_ansible_provisioner_tpu.utils.compile_cache import (
+        enable_compile_cache)
+
+    # Compile cache: the one placement rule (utils/compile_cache.py), and a
+    # listener on JAX's own hit/miss events. The CPU rehearsal leaves it off:
+    # serializing interpret-mode Pallas executables has segfaulted
+    # (tests/conftest.py).
+    cache = {"hits": 0, "misses": 0}
+    if not opts.rehearse:
+        def on_event(event, **kw):
+            if event == "/jax/compilation_cache/cache_hits":
+                cache["hits"] += 1
+            elif event == "/jax/compilation_cache/cache_misses":
+                cache["misses"] += 1
+
+        jax.monitoring.register_event_listener(on_event)
+        cache_dir = enable_compile_cache()
+        say(f"compile cache: {cache_dir} (JAX_COMPILATION_CACHE_DIR "
+            f"{'set' if os.environ.get('JAX_COMPILATION_CACHE_DIR') else 'unset'}"
+            f"), {len(os.listdir(cache_dir)) if os.path.isdir(cache_dir) else 0}"
+            f" entries at start")
+
+    # every Pallas call the process traces, with its interpret flag
+    kernel_calls: list = []
+    real_pallas_call = pa.pl.pallas_call
+
+    def recording_pallas_call(kernel, *a, **kw):
+        kernel_calls.append((getattr(kernel, "func", kernel).__name__,
+                             bool(kw.get("interpret", False))))
+        return real_pallas_call(kernel, *a, **kw)
+
+    pa.pl.pallas_call = recording_pallas_call
+
+    build_native_scheduler()
+
+    model = "Qwen/Qwen3-0.6B"
+    if opts.rehearse:
+        # the same flags, window and traffic on a model the CPU can serve
+        from aws_k8s_ansible_provisioner_tpu import config as _config
+
+        model = "rehearse-qwen3"
+        _config.MODEL_REGISTRY[model] = _config.tiny_qwen3(
+            name=model, vocab_size=512, hidden_size=128,
+            intermediate_size=256, num_heads=8, num_kv_heads=4, head_dim=32,
+            max_seq_len=4096, eos_token_id=258)
+    flags = ["--model", model]
+    if opts.chips == 4:
+        flags += ["--tp", "4"]
+
+    srv = Server(flags)
+    eng = srv.engine
+    impl = resolve_impl(eng.serving.attention_impl)
+    say(f"server: flags {flags}; scheduler {type(eng.sched).__name__}; "
+        f"attention impl {impl}; kv layout "
+        f"{'paged' if eng.paged else 'dense'} page {eng.serving.page_size} "
+        f"dtype {'int8' if eng.kv_quant else eng.serving.dtype}; weights "
+        f"{eng.serving.weights_dtype}; slots {eng.num_slots} window "
+        f"{eng.max_len}; decode bblock {eng.decode_bblock} "
+        f"({'autotuned' if eng.serving.decode_bblock == 0 else 'pinned'}); "
+        f"pipeline {eng.serving.decode_pipeline} ragged "
+        f"{eng.serving.ragged_attention}")
+    say(f"server: build {srv.build_s:.1f}s, warm-up {srv.warmup_s:.1f}s; "
+        f"compile cache hits {cache['hits']} misses {cache['misses']}")
+    check(type(eng.sched).__name__ == "NativeScheduler",
+          "the native scheduler was built from source but not loaded")
+    check(eng.paged, "the engine is not on the paged pool")
+    if not opts.rehearse:
+        check(impl == "pallas", f"attention impl resolved to {impl!r}")
+        check(eng.serving.decode_bblock == 0, "bblock was not autotuned")
+
+    cfg, tokenizer = eng.cfg, srv.state.tokenizer
+    got = run_requests(srv, model)
+    peak = dev.memory_stats() or {} if dev.platform == "tpu" else {}
+    say(f"memory: peak_bytes_in_use {peak.get('peak_bytes_in_use')} "
+        f"bytes_limit {peak.get('bytes_limit')} after the requests")
+
+    bb, serving = eng.decode_bblock, eng.serving
+    slots, window, page = eng.num_slots, eng.max_len, serving.page_size
+    if opts.chips == 4:
+        check_shards(eng, 4)
+        if not opts.rehearse:
+            check_decode_program(eng)
+        srv.drain()
+        # what the tp=4 answers are compared with: the same weights whole on
+        # device 0, teacher-forced through the plain model over the tokens
+        # the sharded server produced
+        params = single_device_params(cfg, serving)
+        for name in ("c70", "c30"):
+            check_numerics(f"{name}, tp=4 vs one device", got[name], cfg,
+                           params, tokenizer)
+    else:
+        for name in ("c70", "c30"):
+            check_numerics(name, got[name], cfg, eng.params, tokenizer)
+        srv.drain()
+        if opts.rehearse:
+            # interpret mode is slow: same code path at a small shape
+            kernel_parity(cfg, 8, 256, 32, sorted({1, 4}), interpret=True)
+        else:
+            kernel_parity(cfg, slots, window, page, sorted({1, bb}),
+                          interpret=False)
+
+    names = sorted({n for n, _ in kernel_calls})
+    say(f"kernels: {len(kernel_calls)} pallas_call traces "
+        f"({', '.join(names)}); interpret=True in "
+        f"{sum(1 for _, i in kernel_calls if i)}")
+    say(f"compile cache: hits {cache['hits']} misses {cache['misses']} over "
+        f"the run")
+    if opts.rehearse:
+        print(json.dumps({"rehearsal": "passed", "device": device}))
+        return 0
+    check(kernel_calls and not any(i for _, i in kernel_calls),
+          "a Pallas kernel was traced with interpret=True (or none ran)")
+    if opts.chips == 4:
+        device["count"] = 4     # the count this run used
+    print(json.dumps({"ok": True, "device": device}))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

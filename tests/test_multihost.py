@@ -40,10 +40,10 @@ def _env(n_devices: int):
 
 @pytest.mark.slow
 def test_two_process_mesh_matches_single_process():
-    # slow AND capability-gated: the pinned jaxlib 0.4.x CPU backend rejects
-    # multi-process computations outright ("Multiprocess computations aren't
-    # implemented on the CPU backend") — on images with the CPU collectives
-    # plugin this runs; under tier-1 it cannot, so it lives behind -m slow.
+    # slow AND capability-gated: a CPU backend without the collectives
+    # plugin rejects multi-process computations outright ("Multiprocess
+    # computations aren't implemented on the CPU backend"), so this lives
+    # behind -m slow, outside tier-1.
     port = _free_port()
     cmd = [sys.executable, "-m",
            "aws_k8s_ansible_provisioner_tpu.parallel.multihost",

@@ -86,12 +86,6 @@ def test_pipeline_remat_parity(cpu_devices):
     np.testing.assert_allclose(float(l0), float(l1), rtol=1e-6)
 
 
-@pytest.mark.xfail(jax.__version__.startswith("0.4."),
-                   reason="jax 0.4.x shard_map transpose raises _SpecError "
-                          "for replicated (out_specs=P()) outputs under "
-                          "check_rep=False; fixed upstream in 0.5+ — the "
-                          "forward-parity tests above still pin the schedule",
-                   strict=False)
 def test_pipeline_train_step_matches_nonpipelined(cpu_devices):
     """One optimizer step through the pipeline == one step of the standard
     GSPMD train step: gradients through scan+ppermute are exact."""

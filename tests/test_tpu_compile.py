@@ -1,0 +1,173 @@
+"""Deviceless compiles for a described TPU v5e: what interpret mode cannot see.
+
+Every other test runs the Pallas kernels in interpret mode on the CPU, which
+accepts shapes the chip's compiler refuses (PR 21 found four such faults in
+the default serving path: a [BB] scalar stack reshaped to [BB, 1, 1], a
+64-lane scale-page DMA, a 1.04 MiB SMEM table, and a scatter that made XLA
+relayout the whole KV pool). The TPU compiler is installed here and compiles
+for a chip that is described, not attached
+(``jax.experimental.topologies``), so these cases compile the serving
+kernels at Qwen3-0.6B widths — 8 KV heads, 16 query heads, head_dim 128,
+page 64, 32 slots — for ``v5e:2x2`` and assert a Mosaic kernel came out.
+
+Nothing runs: a pass here says the chip's compiler ACCEPTS the kernel, not
+that its results are right (``python chip_smoke.py`` checks those on the
+chip). Skipped where the topology cannot be described. The persistent
+compilation cache is off around the compiles — such an executable can be
+written to it but not read back without a chip.
+
+The window is the served 2,048 (32 pages per slot) at block 1; the block-8
+and spec-verify cases cut it to 512 (8 pages): the kernel unrolls its page
+loop (times the draft rows), so compile time grows with the table width
+while the faults above sit in the per-page body, and the whole file has to
+stay well inside the tier-1 clock.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from aws_k8s_ansible_provisioner_tpu.ops import pallas_attention as pa
+from aws_k8s_ansible_provisioner_tpu.serving import paged_kv as pkv
+
+L, HKV, HQ, D, PS, B = 28, 8, 16, 128, 64, 32      # Qwen3-0.6B, default server
+P = B * 32 + 1                                     # default pool + scratch
+CHUNK = 2048                                       # mixed program: B + CHUNK rows
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """SingleDeviceSharding on one described v5e chip, cache off."""
+    import os
+
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    os.environ.setdefault("TPU_SKIP_MDS_QUERY", "1")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # no libtpu, or it cannot describe the chip
+        pytest.skip(f"v5e:2x2 topology cannot be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _pool(chip, quant):
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+
+    kv = sds((L, P, HKV, PS, D), jnp.int8 if quant else jnp.bfloat16)
+    scales = sds((L, P, HKV, pkv.scale_lanes(PS)), jnp.float32)
+    return sds, kv, (dict(pool_ks=scales, pool_vs=scales) if quant else {})
+
+
+def _compile(fn, *args, **kw):
+    return jax.jit(lambda *a, **k: fn(*a, **k)).lower(*args, **kw).compile()
+
+
+CASES = [
+    # (id, entry point, quant, bblock, rows, query rows per slot, pages)
+    ("decode-bf16-bb1", "decode", False, 1, B, 1, 32),
+    ("decode-bf16-bb8", "decode", False, 8, B, 1, 8),
+    ("decode-int8-bb1", "decode", True, 1, B, 1, 32),
+    ("decode-int8-bb8", "decode", True, 8, B, 1, 8),
+    # the mixed program's packed layout: every slot plus a full chunk, one
+    # table row per packed row — the shape that outgrew SMEM
+    ("ragged-bf16-bb1", "ragged", False, 1, B + CHUNK, 1, 32),
+    ("ragged-bf16-bb8", "ragged", False, 8, B + CHUNK, 1, 8),
+    ("spec-bf16-bb1", "spec", False, 1, B, 5, 8),
+]
+
+
+@pytest.mark.parametrize("entry,quant,bb,rows,R,pages",
+                         [c[1:] for c in CASES], ids=[c[0] for c in CASES])
+def test_paged_attention_kernel_compiles_for_v5e(chip, entry, quant, bb,
+                                                 rows, R, pages):
+    sds, kv, skw = _pool(chip, quant)
+    lens, lay = sds((rows,), jnp.int32), sds((), jnp.int32)
+    table = sds((rows, pages), jnp.int32)
+    if entry == "decode":
+        fn, q = pa.decode_attend_pallas_paged, sds((rows, 1, HQ, D),
+                                                   jnp.bfloat16)
+    elif entry == "ragged":
+        fn, q = pa.ragged_attend_pallas_paged, sds((rows, HQ, D),
+                                                   jnp.bfloat16)
+    else:
+        fn, q = pa.decode_attend_pallas_spec_paged, sds((rows, R, HQ, D),
+                                                        jnp.bfloat16)
+    compiled = _compile(functools.partial(fn, bblock=bb), q, kv, kv, lens,
+                        lay, table, **skw)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+def test_paged_write_kernel_compiles_for_v5e(chip, quant):
+    sds, kv, skw = _pool(chip, quant)
+    rows, lay = sds((B,), jnp.int32), sds((), jnp.int32)
+    table, new = sds((B, 32), jnp.int32), sds((B, HKV, D), jnp.bfloat16)
+    if quant:
+        compiled = _compile(pa.cache_write_row_quant_paged, kv,
+                            skw["pool_ks"], new, rows, table, lay)
+    else:
+        compiled = _compile(pa.cache_write_row_paged, kv, new, rows, table,
+                            lay)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+POOL_BYTES = 2 * L * P * HKV * PS * D * 2
+
+
+def test_paged_prefill_write_holds_no_pool_copy(chip):
+    """The prompt writer inside a layer scan, pool donated, as the prefill
+    programs run it: the row-granular scatter it replaced made XLA relayout
+    the whole pool around the loop — a pool-sized temp (7.0 GiB beside the
+    7.0 GiB default pool) in every prefill program."""
+    sds, kv, _ = _pool(chip, False)
+    pages = sds((32,), jnp.int32)
+    rows = sds((L, 1, 2048, HKV, D), jnp.bfloat16)
+
+    def prefill_writes(pool, pages, k, v):
+        def body(carry, kv_l):
+            pool, layer = carry
+            pool = pkv.write_chunk_paged_layer(pool, layer, pages, 0,
+                                               kv_l[0], kv_l[1], PS)
+            return (pool, layer + 1), None
+
+        (pool, _), _ = jax.lax.scan(body, (pool, jnp.int32(0)), (k, v))
+        return pool
+
+    compiled = jax.jit(prefill_writes, donate_argnums=(0,)).lower(
+        {"k": kv, "v": kv}, pages, rows, rows).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < POOL_BYTES // 16
+
+
+def test_smallest_prefill_program_holds_no_pool_copy(chip, monkeypatch):
+    """The whole ``prefill_b32`` program of the default server, from the
+    engine's own enumeration (serving/aot.py). A bucket that fits one page
+    is the case XLA rewrote as a dynamic-update-slice in the transposed
+    update's layout — relayouting the pool again — and it only shows with
+    the model around the writer."""
+    from aws_k8s_ansible_provisioner_tpu.config import (MODEL_REGISTRY,
+                                                        ServingConfig)
+    from aws_k8s_ansible_provisioner_tpu.serving import aot
+
+    # compile the chip's branch (the steer the program does not offer)
+    monkeypatch.setattr(pa, "supported", lambda: True)
+    plan = aot.ProgramPlan(MODEL_REGISTRY["Qwen/Qwen3-0.6B"],
+                           ServingConfig(model="Qwen/Qwen3-0.6B"))
+    params, cache = aot._abstract_state(plan, None,
+                                        next(iter(chip.device_set)))
+    _, fn, args, kwargs = next(
+        p for p in aot.enumerate_programs(plan, None, params, cache)
+        if p[0] == "prefill_b32")
+    compiled = fn.lower(*args, **kwargs).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < POOL_BYTES // 16
