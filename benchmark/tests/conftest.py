@@ -1,0 +1,13 @@
+"""The benchmark's own tests: run by hand with ``pytest benchmark/tests``
+(CPU). They are not part of the repo's tier-1 suite."""
+
+import os
+import sys
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+BENCH = os.path.dirname(HERE)
+ROOT = os.path.dirname(BENCH)
+for p in (ROOT, BENCH):
+    if p not in sys.path:
+        sys.path.insert(0, p)
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
