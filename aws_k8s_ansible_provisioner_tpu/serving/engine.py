@@ -56,14 +56,22 @@ from aws_k8s_ansible_provisioner_tpu.serving.programs import (  # noqa: F401
     BBLOCK_CANDIDATES,
     BIAS_K,
     LOGPROB_K,
+    PH_ADMIT,
+    PH_FETCH,
+    PH_IDLE,
+    PH_OPERANDS,
+    PH_REAP,
     _BBLOCK_CACHE,
     EnginePrograms,
+    _Dispatching,
     _host_lp,
+    _phase,
     decode_steps,
     pick_decode_bblock,
     prefill_batch_step,
     prefill_chunk_step,
     prefill_step,
+    install_compile_listeners,
     spec_decode_step,
 )
 
@@ -431,6 +439,11 @@ class Engine(EnginePrograms):
         # double-count device_busy_seconds.
         self._last_ready = 0.0
         self._busy_watermark = 0.0
+        # () -> the server's Tracer (or None), read at every dispatch close:
+        # engine.dispatch spans go to whatever exporter it holds THEN
+        # (build_state wires it; None = no spans, one attribute read)
+        self.tracer_source = None
+        install_compile_listeners()
         # Device telemetry (serving/devmon.py): hand the monitor the
         # analytical cost model and the host-metadata HBM samplers. Pure
         # wiring — recording happens at the programs.py busy sites, and the
@@ -738,14 +751,17 @@ class Engine(EnginePrograms):
         gbase = self._gbase(slot)
         data = {name: jnp.stack([e[name] for e in entries], axis=1)
                 for name in entries[0]}
-        self.cache = pkv.restore_pages(
-            self.cache, [int(p) + gbase for p in pids], data)
+        tokens = len(entries) * self.serving.page_size
+        drec = self._dispatch_open("_restore_scatter", "kv_restore",
+                                   prompt_tokens=tokens)
+        with _Dispatching(drec):
+            self.cache = pkv.restore_pages(
+                self.cache, [int(p) + gbase for p in pids], data)
         nbytes = len(entries) * self._page_bytes
         self._alloc(slot).host_tier.note_restored(len(entries), nbytes)
         self._restore_pending[slot] = {
-            "pages": len(entries),
-            "tokens": len(entries) * self.serving.page_size,
-            "bytes": nbytes, "t0": time.monotonic()}
+            "pages": len(entries), "tokens": tokens, "bytes": nbytes,
+            "drec": drec}
 
     def _settle_restore(self, slot: int):
         """Settle a scheduled restore before the slot's first suffix chunk:
@@ -756,9 +772,10 @@ class Engine(EnginePrograms):
         pend = self._restore_pending.pop(slot, None)
         if pend is None:
             return
-        jax.block_until_ready(self.cache["k"])
-        dt = time.monotonic() - pend["t0"]
-        _devmon.note("kv_restore", dt, tokens=pend["tokens"])
+        with _phase(PH_FETCH):
+            jax.block_until_ready(self.cache["k"])
+        self._dispatch_close(pend["drec"], time.monotonic(),
+                             tokens=pend["tokens"])
         self.metrics.kv_restore_bytes.inc(pend["bytes"])
         if self.host_tier is not None:
             self.host_tier.flush_to_host()
@@ -836,10 +853,6 @@ class Engine(EnginePrograms):
         self.metrics.kv_pages_free.set(sum(s["pages_free"] for s in sts))
         self.metrics.kv_pages_evictable.set(
             sum(s["pages_evictable"] for s in sts))
-        if self.host_tier is not None:
-            self.metrics.kv_host_tier_used_bytes.set(
-                self.host_tier.used_bytes)
-            self.metrics.kv_host_tier_entries.set(len(self.host_tier))
 
     def _ensure_pages(self, new_rows: int) -> bool:
         """Grow every active slot's page run to cover rows
@@ -1260,74 +1273,9 @@ class Engine(EnginePrograms):
         self._admission_blocked_since = now
         return True
 
-    def step(self) -> bool:
-        """One scheduling step. Priority: advance a chunked prefill (with one
-        decode step interleaved between chunks), else admit waiting prompts
-        (batched into one dispatch), else decode. Returns whether any work was
-        done."""
-        ch = _chaos.get()
-        if ch.enabled:
-            ch.on_engine_step(self)
-        # reap cancelled slots first so disconnected clients free capacity
-        for slot, r in enumerate(self.slot_req):
-            if r is not None and r.cancelled:
-                r.finish_reason = "cancelled"
-                _flight.record("cancel_reap", r.id, slot=slot)
-                self._finish(slot)
-        # then expired deadlines — every blocking wait in the pipeline keys
-        # off the same t_deadline, so enforcement here (between dispatches)
-        # is what turns a deadline into released capacity
-        self._reap_expired()
-        # A long prompt mid-chunking: alternate chunk and decode dispatches so
-        # in-flight streams keep progressing during the prefill (the whole
-        # point of chunking — VERDICT r1 missing #4).
-        if self._chunk is not None:
-            if self._chunk.get("mixed"):
-                # Ragged mixed walk: every chunk dispatch IS a decode
-                # dispatch for the whole batch (one program serves both),
-                # so the chunk/decode alternation — and the horizon-1
-                # garbage-row caveat it exists for — doesn't apply.
-                self._advance_chunk()
-                return True
-            if self._chunk_yield and self._active_slots():
-                self._chunk_yield = False
-                # horizon must be 1 while chunking: the decode program writes
-                # a k/v row for EVERY slot at its current length — for the
-                # chunking slot that row is garbage at offset `off`, which the
-                # next chunk overwrites only if the write stays within the
-                # next chunk's span.
-                self._do_decode(max_horizon=1)
-                return True
-            self._advance_chunk()
-            self._chunk_yield = True
-            return True
-        # Prefill/decode fairness floor (VERDICT r3 weak #5): prefill
-        # priority means decode runs only when nothing can be admitted, so a
-        # sustained admission stream can hold in-flight streams at a token
-        # trickle indefinitely. After prefill_fairness consecutive prefill
-        # dispatches with decode work pending, force ONE full-horizon decode
-        # dispatch before admitting more.
-        fair = max(0, self.serving.prefill_fairness)
-        if (fair and self._prefill_streak >= fair and self._active_slots()
-                and self.sched.stats().queue_depth > 0):
-            self._prefill_streak = 0
-            self._do_decode(fair_horizon=True)
-            return True
-        # Pipelined decode: settle the in-flight dispatch (its deferred
-        # emits, possible finishes) BEFORE admission can reuse a freed slot
-        # or start a chunk — slot reuse under unfetched tokens would
-        # mis-route the deferred emits to the new request. With the ragged
-        # mixed path on, admission under an in-flight dispatch is forced
-        # onto the chunk walk (below), which keeps the carry valid and
-        # never activates a slot before the dispatch settles — so the
-        # pipeline stays open across admissions (the whole point of the
-        # ragged program; deferred emits for a freed slot are discarded by
-        # the slot_req-is-None guard in _decode_fetch, never mis-routed,
-        # because _activate only runs after the in-flight fetch).
-        if (self._inflight is not None
-                and self.sched.stats().queue_depth > 0
-                and not self._ragged_on()):
-            self._drain_decode_pipeline("prefill")
+    def _admit_round(self):
+        """One admission pass of a step: (batch of (req, slot) that prefill
+        together, the admission that starts a chunk walk or None)."""
         # Admission decisions come from the runtime core (FCFS; skips
         # cancelled-in-queue requests, surfacing them for client notification).
         # Bucket-fitting prompts batch into one dispatch; a chunk-needing
@@ -1422,12 +1370,88 @@ class Engine(EnginePrograms):
                 chunk_next = (req, slot, pref)
                 break
             batch.append((req, slot))
+        return batch, chunk_next
+
+    def step(self) -> bool:
+        """One scheduling step. Priority: advance a chunked prefill (with one
+        decode step interleaved between chunks), else admit waiting prompts
+        (batched into one dispatch), else decode. Returns whether any work was
+        done."""
+        ch = _chaos.get()
+        if ch.enabled:
+            ch.on_engine_step(self)
+        with _phase(PH_REAP):
+            # reap cancelled slots first so disconnected clients free
+            # capacity
+            for slot, r in enumerate(self.slot_req):
+                if r is not None and r.cancelled:
+                    r.finish_reason = "cancelled"
+                    _flight.record("cancel_reap", r.id, slot=slot)
+                    self._finish(slot)
+            # then expired deadlines — every blocking wait in the pipeline
+            # keys off the same t_deadline, so enforcement here (between
+            # dispatches) is what turns a deadline into released capacity
+            self._reap_expired()
+        # A long prompt mid-chunking: alternate chunk and decode dispatches so
+        # in-flight streams keep progressing during the prefill (the whole
+        # point of chunking — VERDICT r1 missing #4).
+        if self._chunk is not None:
+            if self._chunk.get("mixed"):
+                # Ragged mixed walk: every chunk dispatch IS a decode
+                # dispatch for the whole batch (one program serves both),
+                # so the chunk/decode alternation — and the horizon-1
+                # garbage-row caveat it exists for — doesn't apply.
+                self._advance_chunk()
+                return True
+            if self._chunk_yield and self._active_slots():
+                self._chunk_yield = False
+                # horizon must be 1 while chunking: the decode program writes
+                # a k/v row for EVERY slot at its current length — for the
+                # chunking slot that row is garbage at offset `off`, which the
+                # next chunk overwrites only if the write stays within the
+                # next chunk's span.
+                self._do_decode(max_horizon=1)
+                return True
+            self._advance_chunk()
+            self._chunk_yield = True
+            return True
+        # Prefill/decode fairness floor (VERDICT r3 weak #5): prefill
+        # priority means decode runs only when nothing can be admitted, so a
+        # sustained admission stream can hold in-flight streams at a token
+        # trickle indefinitely. After prefill_fairness consecutive prefill
+        # dispatches with decode work pending, force ONE full-horizon decode
+        # dispatch before admitting more.
+        fair = max(0, self.serving.prefill_fairness)
+        if (fair and self._prefill_streak >= fair and self._active_slots()
+                and self.sched.stats().queue_depth > 0):
+            self._prefill_streak = 0
+            self._do_decode(fair_horizon=True)
+            return True
+        # Pipelined decode: settle the in-flight dispatch (its deferred
+        # emits, possible finishes) BEFORE admission can reuse a freed slot
+        # or start a chunk — slot reuse under unfetched tokens would
+        # mis-route the deferred emits to the new request. With the ragged
+        # mixed path on, admission under an in-flight dispatch is forced
+        # onto the chunk walk (below), which keeps the carry valid and
+        # never activates a slot before the dispatch settles — so the
+        # pipeline stays open across admissions (the whole point of the
+        # ragged program; deferred emits for a freed slot are discarded by
+        # the slot_req-is-None guard in _decode_fetch, never mis-routed,
+        # because _activate only runs after the in-flight fetch).
+        if (self._inflight is not None
+                and self.sched.stats().queue_depth > 0
+                and not self._ragged_on()):
+            self._drain_decode_pipeline("prefill")
+        with _phase(PH_ADMIT):
+            batch, chunk_next = self._admit_round()
         if batch or chunk_next is not None:
             self._admission_blocked_since = 0.0
         elif self.paged:
             # nothing admitted although work waits: if a slot is free, the
             # head is page-starved — degrade by policy, don't wedge
-            if self._relieve_admission_pressure():
+            with _phase(PH_ADMIT):
+                relieved = self._relieve_admission_pressure()
+            if relieved:
                 # The preemption IS this step's work: when the victim was the
                 # only active slot, falling through would return False with
                 # the queue non-empty, and every caller that treats a False
@@ -1466,11 +1490,13 @@ class Engine(EnginePrograms):
                     req.out_queue.put(None)
                 raise
             if chunk_next is not None:  # chunking starts next step
-                self._start_chunk(*chunk_next)
+                with _phase(PH_ADMIT):
+                    self._start_chunk(*chunk_next)
                 self._chunk_yield = False
             return True
         if chunk_next is not None:
-            self._start_chunk(*chunk_next)
+            with _phase(PH_ADMIT):
+                self._start_chunk(*chunk_next)
             self._advance_chunk()
             self._chunk_yield = True
             return True
@@ -1582,7 +1608,10 @@ class Engine(EnginePrograms):
         while not stop.is_set():
             self.last_step_start = time.monotonic()
             try:
-                did_work = self.step()
+                # host work no narrower phase claims (routing, building the
+                # next dispatch's operands) reads as engine.operands
+                with _phase(PH_OPERANDS):
+                    did_work = self.step()
             # tpulint: disable=R3 fail-loud catch-all — _fail_all fails every in-flight request with its sentinel, /health records the error, loop keeps serving
             except Exception as e:
                 log.exception("engine step failed; failing in-flight requests")
@@ -1593,7 +1622,8 @@ class Engine(EnginePrograms):
             with self._lock:
                 self._stall_abort = False   # the aborted step has unwound
             if not did_work:
-                self._work_event.wait(timeout=0.05)
+                with _phase(PH_IDLE):
+                    self._work_event.wait(timeout=0.05)
                 self._work_event.clear()
 
     def _watchdog_loop(self, stop: threading.Event):

@@ -3,7 +3,7 @@
 When a request dies today — deadline expiry, watchdog stall, shed, failover —
 all the stack keeps is a counter increment; the *why* is gone. This module is
 the serving path's black box: a lock-light, bounded ring of structured events
-(admit, queue, prefill-chunk, pipeline dispatch/fetch, preempt, drain, shed,
+(admit, queue, prefill-chunk, device dispatch, compile, preempt, drain, shed,
 deadline-reap, failover-resume, chaos-fault) stamped with ``monotonic_ns``
 plus the request's trace/span ids, and — on any anomalous terminal edge — a
 snapshot of that request's complete timeline into a capped on-disk JSONL

@@ -218,11 +218,6 @@ class EngineMetrics:
             "End-to-end request latency (vllm-compatible alias)"))
         self.ttft = r.register(Histogram(
             "tpu_serve_time_to_first_token_seconds", "Time to first token"))
-        self.decode_step_duration = r.register(Histogram(
-            "tpu_serve_decode_step_seconds",
-            "Per-token decode DEVICE time over all slots (device window / "
-            "horizon; wall time includes pipeline overlap and host bubble)",
-            buckets=(.001, .0025, .005, .01, .025, .05, .1, .25, .5, 1., 2.5)))
         self.tokens_per_second = r.register(Gauge(
             "tpu_serve_tokens_per_second", "Recent decode throughput"))
         # Decode pipeline (perf_opt r9): bubble = device idle between a
@@ -299,12 +294,6 @@ class EngineMetrics:
             "tpu_serve_kv_restore_dropped_total",
             "Host-tier entries dropped at restore (corrupt/truncated/raced "
             "away; the span re-prefilled instead)"))
-        self.kv_host_tier_used_bytes = r.register(Gauge(
-            "tpu_serve_kv_host_tier_used_bytes",
-            "Bytes of spilled KV pages resident in the host tier"))
-        self.kv_host_tier_entries = r.register(Gauge(
-            "tpu_serve_kv_host_tier_entries",
-            "Spilled KV pages resident in the host tier"))
         # Batch-block size the decode kernels run with (autotuned at engine
         # start per (batch, page_size, kv_dtype) — see
         # Engine._resolve_decode_bblock). A dashboard seeing 1 on a TPU pod
@@ -415,3 +404,49 @@ class PipelineMetrics:
 
 
 pipeline = PipelineMetrics()
+
+
+class CompileMetrics:
+    """Process-wide compile time by program and stage, fed by the
+    ``jax.monitoring`` duration listeners serving/programs.py registers once
+    per process; rendered by BOTH /metrics routes beside
+    ``tpu_serve_compile_seconds_total`` (which is one number around
+    ``warmup()``).
+
+    ``program`` is a closed set: the step programs' names
+    (programs.STEP_PROGRAMS) and ``other`` for everything else (nested
+    Pallas wrappers, eager ops, a caller's own jits). A nested trace lands
+    in ``other`` AND inside the enclosing step program's ``trace`` seconds,
+    so sum stages per program, never across ``other``. ``stage`` is
+    ``trace`` (Python tracing to a jaxpr), ``lower`` (jaxpr to MLIR, Pallas
+    lowering included), ``backend`` (XLA compile, without any cache load)
+    or ``cache_load`` (persistent-cache retrieval).
+
+    ``serving`` flips when the server reports ready (server.serve): from
+    then on a step program that traces or compiles stalls live streams, and
+    each such event counts in ``serving_compiles`` by program.
+    """
+
+    def __init__(self):
+        self.registry = Registry()
+        r = self.registry
+        self.stage_seconds = r.register(Counter(
+            "tpu_serve_compile_stage_seconds_total",
+            "Seconds spent tracing, lowering, compiling or cache-loading "
+            "programs, by step program (or other) and stage",
+            ("program", "stage")))
+        self.serving_compiles = r.register(Counter(
+            "tpu_serve_serving_compiles_total",
+            "Step programs first traced after the server reported ready "
+            "(each stalls every live stream while it compiles)",
+            ("program",)))
+        self.serving = False
+
+    def stage_totals(self) -> Dict[Tuple[str, str], float]:
+        """{(program, stage): seconds} (benchmark readers, tests)."""
+        with self.stage_seconds._lock:
+            return {(dict(k).get("program", ""), dict(k).get("stage", "")): v
+                    for k, v in self.stage_seconds._values.items()}
+
+
+compile_stages = CompileMetrics()

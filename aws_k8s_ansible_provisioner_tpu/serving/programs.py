@@ -22,7 +22,9 @@ drain, streaming queues.
 
 from __future__ import annotations
 
+import itertools
 import os
+import threading
 import time
 from functools import partial
 from typing import List, Optional
@@ -60,6 +62,121 @@ from aws_k8s_ansible_provisioner_tpu.serving import flightrec as _flight
 from aws_k8s_ansible_provisioner_tpu.serving import kv_cache as kvc
 from aws_k8s_ansible_provisioner_tpu.serving import metrics as _metrics
 from aws_k8s_ansible_provisioner_tpu.serving import slo as _slo
+from aws_k8s_ansible_provisioner_tpu.serving import tracing as _tracing
+
+
+# ---------------------------------------------------------------------------
+# The engine loop reporting itself: phases, the dispatch record, compile time
+# ---------------------------------------------------------------------------
+
+# Phases of one Engine.step, as jax.profiler.TraceAnnotation on the engine
+# thread: they land on /host:CPU of the same .xplane.pb as the device's XLA
+# Modules / XLA Ops, so a device idle gap can be put down to a phase. A
+# fixed, closed set (PERF.md section 3); outside a profiler session an
+# annotation is a flag test (~0.5 us). They nest, and the innermost names
+# the time: run_forever opens engine.operands around the whole step (routing
+# and the host side of building a dispatch's operands: mirrors, eager
+# uploads, _next_rng), and reap, admit, dispatch (the jitted call alone),
+# fetch (blocked on a transfer) and emit claim their parts of it.
+ENGINE_PHASES = (PH_REAP, PH_ADMIT, PH_OPERANDS, PH_DISPATCH, PH_FETCH,
+                 PH_EMIT, PH_IDLE) = (
+    "engine.reap", "engine.admit", "engine.operands", "engine.dispatch",
+    "engine.fetch", "engine.emit", "engine.idle")
+_phase = jax.profiler.TraceAnnotation
+
+# The jitted step functions by the name the trace prints (jit_<name>): the
+# closed ``program`` label set of tpu_serve_compile_stage_seconds_total.
+STEP_PROGRAMS = ("prefill_step", "prefill_batch_step", "prefill_chunk_step",
+                 "decode_steps", "mixed_step", "spec_decode_step")
+
+# Process-wide sequence number of device dispatches: the k-th engine.dispatch
+# annotation of a program is the k-th execution of jit_<program> after it
+# (one stream, in order), and the same seq is on the engine.dispatch span.
+_DISPATCH_SEQ = itertools.count(1)
+# engine.dispatch spans take their ids from the record, never from the
+# request tracer's seeded generator (whose draws must stay a pure function
+# of the requests); the prefix keeps replicas apart in one backend.
+_SPAN_PREFIX = os.urandom(8).hex()
+
+_COMPILE_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load",
+}
+# per thread: the dispatch record whose jitted call is running (``open``),
+# and a persistent-cache retrieval waiting for the backend event of the
+# same compile (``load``; the retrieval event carries no fun_name)
+_compile_tls = threading.local()
+_compile_install_lock = threading.Lock()
+_compile_installed = False
+
+
+def _on_compile_event(event: str, duration: float, fun_name: str = "",
+                      **_kw) -> None:
+    stage = _COMPILE_STAGES.get(event)
+    if stage is None:
+        return
+    tls = _compile_tls
+    if stage == "cache_load":
+        tls.load = getattr(tls, "load", 0.0) + duration
+        return
+    name = fun_name[4:-1] if fun_name.startswith("jit(") else fun_name
+    program = name if name in STEP_PROGRAMS else "other"
+    cm = _metrics.compile_stages
+    if stage == "backend":
+        # backend_compile_duration wraps compile_or_get_cached: take the
+        # retrieval it contains out, so a warm cache reads as cache_load
+        load = getattr(tls, "load", 0.0)
+        if load:
+            tls.load = 0.0
+            cm.stage_seconds.inc(load, program=program, stage="cache_load")
+            duration = max(0.0, duration - load)
+    cm.stage_seconds.inc(duration, program=program, stage=stage)
+    if program == "other" or not cm.serving:
+        return
+    # a step program first used while serving: every stream stalls for it
+    rec = getattr(tls, "open", None)
+    if rec is not None:
+        rec["first_use"] = True
+    if stage == "trace":        # every compile begins with one trace
+        cm.serving_compiles.inc(program=program)
+    _flight.record("compile", None, program=program, stage=stage,
+                   seconds=duration,
+                   seq=rec["seq"] if rec is not None else 0)
+
+
+def install_compile_listeners() -> None:
+    """Register the jax.monitoring duration listener, once per process
+    (listeners cannot be removed; ``jax_log_compiles`` stays off)."""
+    global _compile_installed
+    with _compile_install_lock:
+        if _compile_installed:
+            return
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_compile_event)
+        _compile_installed = True
+
+
+class _Dispatching:
+    """``engine.dispatch`` around ONE jitted call: the annotation carries
+    the record's ``seq`` and ``program``, and a compile event that fires
+    inside marks the record ``first_use``."""
+
+    __slots__ = ("rec", "ann")
+
+    def __init__(self, rec: dict):
+        self.rec = rec
+        self.ann = _phase(PH_DISPATCH, seq=rec["seq"],
+                          program=rec["program"])
+
+    def __enter__(self):
+        _compile_tls.open = self.rec
+        self.ann.__enter__()
+
+    def __exit__(self, *exc):
+        self.ann.__exit__(*exc)
+        _compile_tls.open = None
 
 
 # ---------------------------------------------------------------------------
@@ -1235,6 +1352,59 @@ class EnginePrograms:
         self._rng, sub = jax.random.split(self._rng)
         return sub
 
+    # -- the dispatch record -------------------------------------------------
+
+    def _dispatch_open(self, program: str, kind: str, active=(),
+                       **given) -> dict:
+        """Enqueue half of THE record of one device dispatch: what the
+        program is given, exactly, from the host mirrors (no device read —
+        tpulint R8 stands). ``program`` is the jitted function as the trace
+        prints it, ``kind`` devmon's program kind, ``active`` the decode
+        rows live, ``given`` the static key and the per-kind facts
+        (horizon, chunk_rows, chunk_n, chunk_off, bucket, rows,
+        prompt_tokens, padded_tokens, carry_steps = steps of an unfetched
+        predecessor the device-side lengths are ahead of the mirrors by).
+        Closed by ``_dispatch_close`` on the blocking half."""
+        n = len(active)
+        lens = self.lengths[list(active)] if n else None
+        return {"seq": next(_DISPATCH_SEQ), "program": program, "kind": kind,
+                "active": n,
+                "ctx_tokens": int(lens.sum()) if n else 0,
+                "ctx_max": int(lens.max()) if n else 0,
+                "first_use": False, **given, "t_enqueue": time.monotonic()}
+
+    def _dispatch_close(self, rec: dict, t_ready: float, *, batch: int = 1,
+                        tokens: int = 1, ctx_rows: float = 0.0,
+                        steps: int = 1, guided_rows: int = 0,
+                        tail: bool = True, emitted: int = 0) -> None:
+        """Blocking half, and the ONE site that feeds the sinks: the
+        device-busy counters, devmon's window, one ``dispatch`` event on
+        the flight ring and — if the server's tracer has an exporter at
+        this moment — one ``engine.dispatch`` span. The busy window opens
+        at this dispatch's enqueue or the previous dispatch's completion,
+        whichever is later, so overlapped (pipelined) dispatches never
+        double-count device seconds."""
+        device_s = max(0.0, t_ready - max(rec["t_enqueue"],
+                                          self._busy_watermark))
+        self._busy_watermark = t_ready
+        rec["t_ready"] = t_ready
+        rec["tail"] = tail
+        rec["emitted"] = emitted
+        self.metrics.device_busy_seconds.inc(device_s)
+        _devmon.note(rec["kind"], device_s, batch=batch, tokens=tokens,
+                     ctx_rows=ctx_rows, steps=steps, guided_rows=guided_rows)
+        _flight.record("dispatch", None, **rec)
+        src = self.tracer_source
+        tracer = src() if src is not None else None
+        exporter = tracer.exporter if tracer is not None else None
+        if exporter is not None:
+            sid = format(rec["seq"], "016x")
+            span = _tracing.Span(
+                PH_DISPATCH, _tracing.SpanContext(_SPAN_PREFIX + sid, sid),
+                start_ns=_tracing.mono_ns(rec["t_enqueue"]), attributes=rec)
+            span.end_ns = _tracing.mono_ns(t_ready)
+            exporter.export(span, tracer.service_name)
+
     def _activate(self, req: Request, slot: int, token: int, lp=None,
                   ids: Optional[List[int]] = None, resumed: bool = False):
         """Shared post-prefill bookkeeping: slot state + TTFT + first token.
@@ -1360,12 +1530,11 @@ class EnginePrograms:
         tokens = np.zeros((1, bucket), np.int32)
         tokens[0, :len(ids)] = ids
         self._fill_sampling_rows(req, slot)
-        t0 = time.monotonic()
-        out = prefill_step(
-            self.cfg, self.params, self.cache,
-            jnp.asarray(tokens), jnp.int32(len(ids)), jnp.int32(slot),
-            self._next_rng(), jnp.float32(req.temperature),
-            jnp.int32(req.top_k), jnp.float32(req.top_p),
+        args = (jnp.asarray(tokens), jnp.int32(len(ids)),
+                jnp.int32(slot), self._next_rng(),
+                jnp.float32(req.temperature), jnp.int32(req.top_k),
+                jnp.float32(req.top_p))
+        kw = dict(
             logprobs=req.logprobs is not None,
             pages=jnp.asarray(self.table[slot]) if self.paged else None,
             seed=jnp.uint32(req.eff_seed),
@@ -1378,23 +1547,30 @@ class EnginePrograms:
             lora_idx=(jnp.asarray(self.lora_idx[slot:slot + 1])
                       if self.lora_names else None),
             prompt_logprobs=req.prompt_logprobs is not None)
+        drec = self._dispatch_open(
+            "prefill_step", "prefill", bucket=bucket,
+            prompt_tokens=len(ids), padded_tokens=bucket)
+        with _Dispatching(drec):
+            out = prefill_step(self.cfg, self.params, self.cache, *args,
+                               **kw)
         items = list(out)
         self.cache, token = items[0], items[1]
         pos = 2
         lp = None
-        if req.logprobs is not None:
-            lp = _host_lp(items[pos], 0, req.logprobs)
-            pos += 1
-        if req.prompt_logprobs is not None:
-            self._host_prompt_lp(req, items[pos], 0, len(ids))
-        token = int(token)  # device sync
-        dt = time.monotonic() - t0
-        self.metrics.device_busy_seconds.inc(dt)
-        _devmon.note("prefill", dt, batch=1, tokens=len(ids))
+        with _phase(PH_FETCH):
+            if req.logprobs is not None:
+                lp = _host_lp(items[pos], 0, req.logprobs)
+                pos += 1
+            if req.prompt_logprobs is not None:
+                self._host_prompt_lp(req, items[pos], 0, len(ids))
+            token = int(token)  # device sync
+        self._dispatch_close(drec, time.monotonic(), batch=1,
+                             tokens=len(ids))
         if self.draft is not None:
             self.draft.prefill(self, tokens, np.asarray([len(ids)], np.int32),
                                np.asarray([slot], np.int32))
-        self._activate(req, slot, token, lp)
+        with _phase(PH_EMIT):
+            self._activate(req, slot, token, lp)
 
     def _do_prefill_batch(self, batch: List):
         """Prefill N waiting prompts in one dispatch (rows padded to a power
@@ -1453,41 +1629,51 @@ class EnginePrograms:
                 if req.guided is not None:
                     self._fill_allow(aw, i, req)
             allow = jnp.asarray(aw)
-        t0 = time.monotonic()
         want_lp = self._want_logprobs([r for r, _ in batch])
         want_plp = any(r.prompt_logprobs is not None for r, _ in batch)
-        out = prefill_batch_step(
-            self.cfg, self.params, self.cache, jnp.asarray(tokens),
-            jnp.asarray(true_lens), jnp.asarray(slots), self._next_rng(),
-            jnp.asarray(temps), jnp.asarray(top_ks), jnp.asarray(top_ps),
+        args = (jnp.asarray(tokens), jnp.asarray(true_lens),
+                jnp.asarray(slots), self._next_rng(), jnp.asarray(temps),
+                jnp.asarray(top_ks), jnp.asarray(top_ps))
+        kw = dict(
             logprobs=want_lp, tables=tables, seeds=jnp.asarray(seeds),
-            ban_ids=jnp.asarray(ban_ids), ban_until=jnp.asarray(ban_until),
-            bias_ids=jnp.asarray(bias_ids), bias_vals=jnp.asarray(bias_vals),
+            ban_ids=jnp.asarray(ban_ids),
+            ban_until=jnp.asarray(ban_until),
+            bias_ids=jnp.asarray(bias_ids),
+            bias_vals=jnp.asarray(bias_vals),
             reps=jnp.asarray(reps), allow=allow,
-            lora_idx=(jnp.asarray(row_lora) if self.lora_names else None),
+            lora_idx=(jnp.asarray(row_lora) if self.lora_names
+                      else None),
             prompt_logprobs=want_plp)
+        n_prompt = int(true_lens[:len(batch)].sum())
+        drec = self._dispatch_open(
+            "prefill_batch_step", "prefill_batch", rows=n_bucket,
+            bucket=t_bucket, prompt_tokens=n_prompt,
+            padded_tokens=n_bucket * t_bucket)
+        with _Dispatching(drec):
+            out = prefill_batch_step(self.cfg, self.params, self.cache,
+                                     *args, **kw)
         items = list(out)
         self.cache, toks = items[0], items[1]
         pos = 2
         lp_t = None
-        if want_lp:
-            lp_t = tuple(np.asarray(a) for a in items[pos])  # ONE transfer
-            pos += 1
-        plp_t = tuple(np.asarray(a) for a in items[pos]) \
-            if want_plp else None                        # ONE bulk transfer
-        toks = np.asarray(toks)  # device sync
-        dt = time.monotonic() - t0
-        self.metrics.device_busy_seconds.inc(dt)
-        _devmon.note("prefill_batch", dt, batch=len(batch),
-                     tokens=int(true_lens.sum()))
+        with _phase(PH_FETCH):
+            if want_lp:
+                lp_t = tuple(np.asarray(a) for a in items[pos])  # ONE transfer
+                pos += 1
+            plp_t = tuple(np.asarray(a) for a in items[pos]) \
+                if want_plp else None                    # ONE bulk transfer
+            toks = np.asarray(toks)  # device sync
+        self._dispatch_close(drec, time.monotonic(), batch=len(batch),
+                             tokens=int(true_lens.sum()))
         if self.draft is not None:
             self.draft.prefill(self, tokens, true_lens, slots)
-        for i, (req, slot) in enumerate(batch):
-            lp = _host_lp(lp_t, i, req.logprobs) \
-                if req.logprobs is not None else None
-            if req.prompt_logprobs is not None:
-                self._host_prompt_lp(req, plp_t, i, len(req.prompt_ids))
-            self._activate(req, slot, int(toks[i]), lp)
+        with _phase(PH_EMIT):
+            for i, (req, slot) in enumerate(batch):
+                lp = _host_lp(lp_t, i, req.logprobs) \
+                    if req.logprobs is not None else None
+                if req.prompt_logprobs is not None:
+                    self._host_prompt_lp(req, plp_t, i, len(req.prompt_ids))
+                self._activate(req, slot, int(toks[i]), lp)
 
     def _start_chunk(self, req: Request, slot: int, pref):
         """Begin chunked prefill of ``req`` into ``slot``.
@@ -1554,15 +1740,16 @@ class EnginePrograms:
         if pref is not None:
             src, n = pref
             if src != slot:   # reusing the same slot: rows already in place
-                t0 = time.monotonic()
-                self.cache = kvc.copy_prefix(self.cache, src, slot, n)
+                drec = self._dispatch_open("_copy_prefix", "prefix_copy",
+                                           prompt_tokens=n)
+                with _Dispatching(drec):
+                    self.cache = kvc.copy_prefix(self.cache, src, slot, n)
                 # sync before reading the clock: the copy is async, and an
                 # unsynced window would record ~0 busy time for the device
                 # work this feature adds
-                jax.block_until_ready(self.cache["k"])
-                dt = time.monotonic() - t0
-                self.metrics.device_busy_seconds.inc(dt)
-                _devmon.note("prefix_copy", dt, tokens=n)
+                with _phase(PH_FETCH):
+                    jax.block_until_ready(self.cache["k"])
+                self._dispatch_close(drec, time.monotonic(), tokens=n)
             off = n
             self.metrics.prefix_cache_hits.inc()
             self.metrics.prefix_tokens_reused.inc(n)
@@ -1604,18 +1791,18 @@ class EnginePrograms:
         _flight.record("prefill_chunk", req.id, off=off, n=len(chunk))
         tokens = np.zeros((1, C), np.int32)
         tokens[0, :len(chunk)] = chunk
-        t0 = time.monotonic()
+        final_lp = (req.logprobs is not None and not st.get("resumed")
+                    and off + len(chunk) >= len(ids))
         lp_t = None
         try:
-            out = prefill_chunk_step(
-                self.cfg, self.params, self.cache, jnp.asarray(tokens),
-                jnp.int32(off), jnp.int32(slot), jnp.int32(len(chunk)),
-                self._next_rng(), jnp.float32(req.temperature),
-                jnp.int32(req.top_k), jnp.float32(req.top_p),
-                logprobs=(req.logprobs is not None
-                          and not st.get("resumed")
-                          and off + len(chunk) >= len(ids)),
-                pages=jnp.asarray(self.table[slot]) if self.paged else None,
+            args = (jnp.asarray(tokens), jnp.int32(off), jnp.int32(slot),
+                    jnp.int32(len(chunk)), self._next_rng(),
+                    jnp.float32(req.temperature), jnp.int32(req.top_k),
+                    jnp.float32(req.top_p))
+            kw = dict(
+                logprobs=final_lp,
+                pages=(jnp.asarray(self.table[slot]) if self.paged
+                       else None),
                 seed=jnp.uint32(req.eff_seed),
                 ban_ids=jnp.asarray(self.ban_ids[slot]),
                 ban_until=jnp.int32(self.ban_until[slot]),
@@ -1626,8 +1813,13 @@ class EnginePrograms:
                 allow=self._allow_row(req),
                 lora_idx=(jnp.asarray(self.lora_idx[slot:slot + 1])
                           if self.lora_names else None))
-            if req.logprobs is not None and not st.get("resumed") \
-                    and off + len(chunk) >= len(ids):
+            drec = self._dispatch_open(
+                "prefill_chunk_step", "prefill_chunk", chunk_rows=C,
+                chunk_n=len(chunk), chunk_off=off)
+            with _Dispatching(drec):
+                out = prefill_chunk_step(self.cfg, self.params, self.cache,
+                                         *args, **kw)
+            if final_lp:
                 self.cache, token, lp_t = out
             else:
                 self.cache, token = out
@@ -1639,9 +1831,9 @@ class EnginePrograms:
             self.metrics.mark_request("error", 0.0)
             req.out_queue.put(None)
             raise
-        dt = time.monotonic() - t0
-        self.metrics.device_busy_seconds.inc(dt)
-        _devmon.note("prefill_chunk", dt, tokens=len(chunk))
+        # closed at enqueue: the walk reads the sampled token only after
+        # the final chunk (at _activate, below)
+        self._dispatch_close(drec, time.monotonic(), tokens=len(chunk))
         st["off"] = off + len(chunk)
         # Interleaved decode dispatches write a (garbage) k/v row for every
         # slot at its host length; keeping this slot's length at the chunk
@@ -1649,10 +1841,13 @@ class EnginePrograms:
         self.lengths[slot] = st["off"]
         if st["off"] >= len(ids):
             self._chunk = None
-            lp = _host_lp(lp_t, 0, req.logprobs) \
-                if req.logprobs is not None and lp_t is not None else None
-            self._activate(req, slot, int(token), lp, ids=list(ids),
-                           resumed=st.get("resumed", False))
+            with _phase(PH_FETCH):
+                lp = _host_lp(lp_t, 0, req.logprobs) \
+                    if req.logprobs is not None and lp_t is not None else None
+                token = int(token)  # device sync
+            with _phase(PH_EMIT):
+                self._activate(req, slot, token, lp, ids=list(ids),
+                               resumed=st.get("resumed", False))
 
     def _advance_chunk_mixed(self, st: dict) -> None:
         """One RAGGED mixed dispatch: this walk's next prefill chunk packed
@@ -1689,7 +1884,8 @@ class EnginePrograms:
         # active slots left) is deliberately ignored: the chunk must
         # proceed even with zero active decode rows.
         grow = 1 + (prev["horizon"] if prev is not None else 0)
-        self._ensure_pages(grow)
+        with _phase(PH_ADMIT):      # pool bookkeeping, as at admission
+            self._ensure_pages(grow)
         if prev is not None and not self._carry_valid():
             # _ensure_pages preempted under the in-flight dispatch
             self._drain_decode_pipeline("prefill")
@@ -1748,8 +1944,9 @@ class EnginePrograms:
         lp = _host_lp(rec["chunk_lp_t"], 0, req.logprobs) \
             if rec["chunk_lp"] else None
         self._chunk = None
-        self._activate(req, slot, rec["chunk_token"], lp, ids=list(ids),
-                       resumed=st.get("resumed", False))
+        with _phase(PH_EMIT):
+            self._activate(req, slot, rec["chunk_token"], lp, ids=list(ids),
+                           resumed=st.get("resumed", False))
 
     def _mixed_dispatch(self, st: dict, chunk, tok_in, len_in) -> dict:
         """Enqueue ONE ragged mixed dispatch (prefill chunk + decode batch)
@@ -1767,9 +1964,6 @@ class EnginePrograms:
         gslots = [s for s in active
                   if self.slot_req[s] is not None
                   and self.slot_req[s].guided is not None]
-        allow = self._allow_words(gslots)
-        pallow = self._allow_row(req)
-        oc = self._decode_operands()
         want_lp = self._want_logprobs(self.slot_req)
         want_pen = self.counts is not None and bool(
             self.pres_pens.any() or self.freq_pens.any()
@@ -1778,49 +1972,62 @@ class EnginePrograms:
                     and off + len(chunk) >= len(ids))
         tokens = np.zeros((1, st["C"]), np.int32)
         tokens[0, :len(chunk)] = chunk
-        t0 = time.monotonic()
-        if self._last_ready > 0.0:
-            self.metrics.decode_bubble_seconds.inc(
-                max(0.0, t0 - self._last_ready))
-            self._last_ready = 0.0
+        allow = self._allow_words(gslots)
+        pallow = self._allow_row(req)
+        oc = self._decode_operands()
+        args = (jnp.asarray(tokens), jnp.int32(slot), jnp.int32(off),
+                jnp.int32(len(chunk)),
+                jnp.float32(req.repetition_penalty or 1.0),
+                jnp.asarray(st["rep_seen"]), jnp.uint32(req.eff_seed),
+                jnp.float32(req.temperature), jnp.int32(req.top_k),
+                jnp.float32(req.top_p), self._next_rng(),
+                oc["temps"], oc["top_ks"], oc["top_ps"])
+        prev = self._inflight
+        drec = self._dispatch_open(
+            "mixed_step", "mixed_step", active, horizon=1,
+            chunk_rows=st["C"], chunk_n=len(chunk), chunk_off=off,
+            carry_steps=prev["horizon"] if prev is not None else 0)
+        self._book_bubble(drec["t_enqueue"])
         real_counts = self.counts
-        self.cache, new_counts, out, pout, tok, lens = mixed_step(
-            self.cfg, self.params, self.cache, tok_in, len_in,
-            jnp.asarray(tokens), jnp.int32(slot), jnp.int32(off),
-            jnp.int32(len(chunk)),
-            jnp.float32(req.repetition_penalty or 1.0),
-            jnp.asarray(st["rep_seen"]), jnp.uint32(req.eff_seed),
-            jnp.float32(req.temperature), jnp.int32(req.top_k),
-            jnp.float32(req.top_p), self._next_rng(),
-            oc["temps"], oc["top_ks"], oc["top_ps"],
-            mesh=self.mesh, impl=self.serving.attention_impl,
-            logprobs=want_lp, chunk_logprobs=chunk_lp,
-            counts=self.counts if want_pen else None,
-            presence=oc["pres"] if want_pen else None,
-            frequency=oc["freq"] if want_pen else None,
-            repetition=oc["rep"] if want_pen else None,
-            prompt_mask=self.prompt_mask if want_pen else None,
-            penalties=want_pen,
-            table=oc["table"],
-            seeds=oc["seeds"],
-            ban_ids=oc["ban_ids"],
-            ban_until=oc["ban_until"],
-            bias_ids=oc["bias_ids"],
-            bias_vals=oc["bias_vals"],
-            allow=allow,
-            pallow=pallow,
-            lora_idx=oc["lora"],
-            bblock=self.decode_bblock)
+        with _Dispatching(drec):
+            self.cache, new_counts, out, pout, tok, lens = mixed_step(
+                self.cfg, self.params, self.cache, tok_in, len_in, *args,
+                mesh=self.mesh, impl=self.serving.attention_impl,
+                logprobs=want_lp, chunk_logprobs=chunk_lp,
+                counts=self.counts if want_pen else None,
+                presence=oc["pres"] if want_pen else None,
+                frequency=oc["freq"] if want_pen else None,
+                repetition=oc["rep"] if want_pen else None,
+                prompt_mask=self.prompt_mask if want_pen else None,
+                penalties=want_pen,
+                table=oc["table"],
+                seeds=oc["seeds"],
+                ban_ids=oc["ban_ids"],
+                ban_until=oc["ban_until"],
+                bias_ids=oc["bias_ids"],
+                bias_vals=oc["bias_vals"],
+                allow=allow,
+                pallow=pallow,
+                lora_idx=oc["lora"],
+                bblock=self.decode_bblock)
         self.counts = new_counts if want_pen else real_counts
         self._pipe_carry = (tok, lens, self._carry_gen)
         _metrics.pipeline.dispatches.inc()
-        _flight.record("pipeline_dispatch", None, horizon=1,
-                       batch=len(active), mixed=True)
         return {"mixed": True, "out": out, "pout": pout, "horizon": 1,
                 "active": active, "gset": frozenset(gslots),
                 "gslots": gslots,
                 "want_lp": want_lp, "chunk_lp": chunk_lp,
-                "want_pen": want_pen, "chunk_n": len(chunk), "t0": t0}
+                "want_pen": want_pen, "chunk_n": len(chunk), "drec": drec}
+
+    def _book_bubble(self, t_enqueue: float) -> None:
+        """The device has sat idle since the previous fetch completed with
+        nothing enqueued behind it; the gap until THIS enqueue is pure
+        host-side bubble — the cost the one-deep pipeline exists to hide
+        (and the sync path pays every dispatch)."""
+        if self._last_ready > 0.0:
+            self.metrics.decode_bubble_seconds.inc(
+                max(0.0, t_enqueue - self._last_ready))
+            self._last_ready = 0.0
 
     def _propose_drafts(self, active: List[int]):
         """Proposal source for the verify dispatch. With a draft model
@@ -1896,18 +2103,24 @@ class EnginePrograms:
         and their surplus K/V row writes follow the standard rewrite
         invariant) but emit nothing — their tokens come from the next plain
         step, which applies the features the verify pass lacks."""
-        t0 = time.monotonic()
         R = self.serving.spec_k + 1
         tokens = np.concatenate([self.last_token[:, None], drafts], axis=1)
-        self.cache, out, accepted = spec_decode_step(
-            self.cfg, R, self.params, self.cache, jnp.asarray(tokens),
-            jnp.asarray(self.lengths), self._next_rng(),
-            jnp.asarray(self.temps), jnp.asarray(self.top_ks),
-            jnp.asarray(self.top_ps), impl=self.serving.attention_impl,
+        args = (jnp.asarray(tokens), jnp.asarray(self.lengths),
+                self._next_rng(), jnp.asarray(self.temps),
+                jnp.asarray(self.top_ks), jnp.asarray(self.top_ps))
+        kw = dict(
+            impl=self.serving.attention_impl,
             table=jnp.asarray(self.table) if self.paged else None,
             seeds=jnp.asarray(self.seeds), mesh=self.mesh,
-            lora_idx=self._lora_vec(),
-            bblock=self.decode_bblock)
+            lora_idx=self._lora_vec(), bblock=self.decode_bblock)
+        drec = self._dispatch_open("spec_decode_step", "spec_decode", active,
+                                   rows=R)
+        t0 = drec["t_enqueue"]
+        ctx_rows = float(np.mean(self.lengths[list(active)])) \
+            if active else 0.0
+        with _Dispatching(drec):
+            self.cache, out, accepted = spec_decode_step(
+                self.cfg, R, self.params, self.cache, *args, **kw)
         ch = _chaos.get()
         if ch.enabled:
             # an armed "ragged_feature_error" raises here, standing in for
@@ -1915,45 +2128,43 @@ class EnginePrograms:
             # the failover path discards the whole dispatch un-emitted and
             # releases every slot exactly once (engine._fail_all)
             ch.on_feature_path(self, kind="spec")
-        out = np.asarray(out)
-        accepted = np.asarray(accepted)
-        dt = time.monotonic() - t0
-        self.metrics.device_busy_seconds.inc(dt)
-        _devmon.note("spec_decode", dt, batch=len(active),
-                     tokens=R * len(active),
-                     ctx_rows=float(np.mean(self.lengths[list(active)]))
-                     if active else 0.0)
+        with _phase(PH_FETCH):
+            out = np.asarray(out)
+            accepted = np.asarray(accepted)
+        t_ready = time.monotonic()
         emitted = 0
-        for slot in active:
-            if slot in skip:
-                continue
-            acc = int(accepted[slot])
-            if slot in proposed:  # acceptance rate over REAL proposals
-                # clamp both sides to the slot's true draft count: the verify
-                # pass can "accept" zero-padding past a short draft, which
-                # would otherwise inflate the acceptance rate (ADVICE r2)
-                n_drafted = proposed[slot]
-                self.metrics.spec_drafted_tokens.inc(n_drafted)
-                self.metrics.spec_accepted_tokens.inc(
-                    min(max(acc - 1, 0), n_drafted))
-                d = self.metrics.spec_drafted_tokens.total()
-                if d > 0:
-                    self.metrics.spec_acceptance_rate.set(
-                        self.metrics.spec_accepted_tokens.total() / d)
-            slot_emitted = 0
-            for i in range(acc):
-                if self.slot_req[slot] is None:
-                    break  # hit a stop condition mid-prefix
-                self.lengths[slot] += 1
-                self.sched.note_decode(slot, 1)
-                self._emit(slot, int(out[slot, i]))
-                emitted += 1
-                slot_emitted += 1
-            if self.draft is not None and slot in proposed:
-                # newest token + accepted drafts are now true draft context
-                self.draft.note_emitted(slot, slot_emitted)
-        self.metrics.decode_step_duration.observe(
-            dt / max(1.0, emitted / max(1, len(active))))
+        with _phase(PH_EMIT):
+            for slot in active:
+                if slot in skip:
+                    continue
+                acc = int(accepted[slot])
+                if slot in proposed:  # acceptance rate over REAL proposals
+                    # clamp both sides to the slot's true draft count: the verify
+                    # pass can "accept" zero-padding past a short draft, which
+                    # would otherwise inflate the acceptance rate (ADVICE r2)
+                    n_drafted = proposed[slot]
+                    self.metrics.spec_drafted_tokens.inc(n_drafted)
+                    self.metrics.spec_accepted_tokens.inc(
+                        min(max(acc - 1, 0), n_drafted))
+                    d = self.metrics.spec_drafted_tokens.total()
+                    if d > 0:
+                        self.metrics.spec_acceptance_rate.set(
+                            self.metrics.spec_accepted_tokens.total() / d)
+                slot_emitted = 0
+                for i in range(acc):
+                    if self.slot_req[slot] is None:
+                        break  # hit a stop condition mid-prefix
+                    self.lengths[slot] += 1
+                    self.sched.note_decode(slot, 1)
+                    self._emit(slot, int(out[slot, i]))
+                    emitted += 1
+                    slot_emitted += 1
+                if self.draft is not None and slot in proposed:
+                    # newest token + accepted drafts are now true draft context
+                    self.draft.note_emitted(slot, slot_emitted)
+        self._dispatch_close(
+            drec, t_ready, batch=len(active), tokens=R * len(active),
+            ctx_rows=ctx_rows, emitted=emitted)
         self._tok_times.append((t0, emitted))
         if len(self._tok_times) >= 2:
             span = time.monotonic() - self._tok_times[0][0]
@@ -2162,7 +2373,9 @@ class EnginePrograms:
                 # the unfetched dispatch writes its own horizon of rows
                 # before the one about to be enqueued
                 grow += prev["horizon"]
-            if not self._ensure_pages(grow):
+            with _phase(PH_ADMIT):  # pool bookkeeping, as at admission
+                grown = self._ensure_pages(grow)
+            if not grown:
                 return
             active = self._active_slots()
             if prev is not None and not self._carry_valid():
@@ -2307,47 +2520,42 @@ class EnginePrograms:
         belong in _decode_fetch), so the host is free to emit the previous
         dispatch's tokens while the device runs this one."""
         oc = self._decode_operands()
-        t0 = time.monotonic()
-        if self._last_ready > 0.0:
-            # the device has sat idle since the previous fetch completed
-            # with nothing enqueued behind it; the gap until THIS enqueue
-            # is pure host-side bubble — the cost the one-deep pipeline
-            # exists to hide (and the sync path pays every dispatch)
-            self.metrics.decode_bubble_seconds.inc(
-                max(0.0, t0 - self._last_ready))
-            self._last_ready = 0.0
+        rng = self._next_rng()
+        allow = self._allow_words(gslots)
+        prev = self._inflight
+        drec = self._dispatch_open(
+            "decode_steps", "decode", active, horizon=horizon,
+            carry_steps=prev["horizon"] if prev is not None else 0)
+        self._book_bubble(drec["t_enqueue"])
         real_counts = self.counts
-        self.cache, new_counts, out, tok, lens = decode_steps(
-            self.cfg, horizon, self.params, self.cache, tok_in, len_in,
-            self._next_rng(), oc["temps"], oc["top_ks"], oc["top_ps"],
-            mesh=self.mesh, impl=self.serving.attention_impl,
-            logprobs=want_lp,
-            counts=self.counts if want_pen else None,
-            presence=oc["pres"] if want_pen else None,
-            frequency=oc["freq"] if want_pen else None,
-            repetition=oc["rep"] if want_pen else None,
-            prompt_mask=self.prompt_mask if want_pen else None,
-            penalties=want_pen,
-            table=oc["table"] if self.paged else None,
-            seeds=oc["seeds"],
-            ban_ids=oc["ban_ids"],
-            ban_until=oc["ban_until"],
-            bias_ids=oc["bias_ids"],
-            bias_vals=oc["bias_vals"],
-            allow=self._allow_words(gslots),
-            lora_idx=oc["lora"],
-            bblock=self.decode_bblock)
+        with _Dispatching(drec):
+            self.cache, new_counts, out, tok, lens = decode_steps(
+                self.cfg, horizon, self.params, self.cache, tok_in, len_in,
+                rng, oc["temps"], oc["top_ks"], oc["top_ps"],
+                mesh=self.mesh, impl=self.serving.attention_impl,
+                logprobs=want_lp,
+                counts=self.counts if want_pen else None,
+                presence=oc["pres"] if want_pen else None,
+                frequency=oc["freq"] if want_pen else None,
+                repetition=oc["rep"] if want_pen else None,
+                prompt_mask=self.prompt_mask if want_pen else None,
+                penalties=want_pen,
+                table=oc["table"] if self.paged else None,
+                seeds=oc["seeds"],
+                ban_ids=oc["ban_ids"],
+                ban_until=oc["ban_until"],
+                bias_ids=oc["bias_ids"],
+                bias_vals=oc["bias_vals"],
+                allow=allow,
+                lora_idx=oc["lora"],
+                bblock=self.decode_bblock)
         # un-penalized dispatches return a dummy counts array — keep ours
         self.counts = new_counts if want_pen else real_counts
         self._pipe_carry = (tok, lens, self._carry_gen)
         _metrics.pipeline.dispatches.inc()
-        # ring-only flight event (no per-request timeline work): a pure
-        # deque append, safe on the async-dispatch half (tpulint R8)
-        _flight.record("pipeline_dispatch", None, horizon=horizon,
-                       batch=len(active))
         return {"out": out, "horizon": horizon, "active": list(active),
                 "gset": gset, "gslots": gslots, "want_lp": want_lp,
-                "want_pen": want_pen, "t0": t0}
+                "want_pen": want_pen, "drec": drec}
 
     def _decode_fetch(self, rec: dict, tail: bool) -> None:
         """Blocking half of a decode dispatch: transfer the sampled tokens,
@@ -2385,88 +2593,84 @@ class EnginePrograms:
                 ch.on_feature_path(self, kind="guided")
         out = rec["out"]
         lp_t = None
-        if rec["want_lp"]:
-            out, lp_t = out          # ([h, B], ([h,B], [h,B,K], [h,B,K]))
-            # ONE bulk transfer; per-token slicing below is pure numpy (3
-            # tiny device gathers per emitted token would round-trip the
-            # network-attached chip thousands of times per dispatch)
-            lp_t = tuple(np.asarray(a) for a in lp_t)
-        out = np.asarray(out)  # [horizon, B] — blocks until device-complete
-        if rec.get("mixed"):
-            # chunk-row outputs ride the same record: the sampled token of
-            # the chunk's last position (only meaningful on the final
-            # chunk, where _advance_chunk_mixed activates with it)
-            pout = rec["pout"]
-            if rec["chunk_lp"]:
-                ptok_arr, plp = pout
-                rec["chunk_token"] = int(np.asarray(ptok_arr)[0])
-                rec["chunk_lp_t"] = tuple(np.asarray(a) for a in plp)
-            else:
-                rec["chunk_token"] = int(np.asarray(pout)[0])
+        with _phase(PH_FETCH):
+            if rec["want_lp"]:
+                out, lp_t = out      # ([h, B], ([h,B], [h,B,K], [h,B,K]))
+                # ONE bulk transfer; per-token slicing below is pure numpy
+                # (3 tiny device gathers per emitted token would round-trip
+                # the network-attached chip thousands of times per dispatch)
+                lp_t = tuple(np.asarray(a) for a in lp_t)
+            out = np.asarray(out)  # [horizon, B] — blocks until complete
+            if rec.get("mixed"):
+                # chunk-row outputs ride the same record: the sampled token
+                # of the chunk's last position (only meaningful on the final
+                # chunk, where _advance_chunk_mixed activates with it)
+                pout = rec["pout"]
+                if rec["chunk_lp"]:
+                    ptok_arr, plp = pout
+                    rec["chunk_token"] = int(np.asarray(ptok_arr)[0])
+                    rec["chunk_lp_t"] = tuple(np.asarray(a) for a in plp)
+                else:
+                    rec["chunk_token"] = int(np.asarray(pout)[0])
         t_ready = time.monotonic()
         horizon = rec["horizon"]
-        # Device-time attribution: the busy window opens at this dispatch's
-        # enqueue or the previous dispatch's completion, whichever is later
-        # — overlapped dispatches must not double-count device seconds, and
-        # decode_step_duration reports device time now that wall time
-        # includes pipeline overlap.
-        busy_start = max(rec["t0"], self._busy_watermark)
-        dev_dt = max(0.0, t_ready - busy_start)
-        self._busy_watermark = t_ready
-        self.metrics.device_busy_seconds.inc(dev_dt)
-        self.metrics.decode_step_duration.observe(dev_dt / horizon)
-        _devmon.note("mixed_step" if rec.get("mixed") else "decode", dev_dt,
-                     batch=len(rec["active"]) + (1 if rec.get("mixed")
-                                                 else 0),
-                     tokens=horizon * len(rec["active"])
-                     + rec.get("chunk_n", 0),
-                     ctx_rows=float(np.mean(self.lengths[
-                         list(rec["active"])])) if rec["active"] else 0.0,
-                     steps=horizon, guided_rows=len(rec["gslots"]))
+        active = rec["active"]
+        ctx_rows = float(np.mean(self.lengths[list(active)])) \
+            if active else 0.0
         gset = rec["gset"]
         emitted = 0
-        for s in range(horizon):
-            for slot in rec["active"]:
-                if self.slot_req[slot] is None:
-                    # finished earlier in this horizon — or after the
-                    # dispatch was enqueued (pipelined surplus discard)
-                    continue
-                if s > 0 and slot in gset:
-                    # guided slots advance one grammar-checked token per
-                    # dispatch; substeps past 0 are unconstrained surplus
-                    continue
-                req = self.slot_req[slot]
-                lp = None
-                if req.logprobs is not None and lp_t is not None:
-                    lp = _host_lp(tuple(a[s] for a in lp_t), slot,
-                                  req.logprobs)
-                self.lengths[slot] += 1
-                self.sched.note_decode(slot, 1)
-                self._emit(slot, int(out[s, slot]), lp)
-                emitted += 1
-        if rec["want_pen"] and rec["gslots"] and horizon > 1:
-            # the fused dispatch incremented guided slots' device-side
-            # penalty-count rows for EVERY substep, but only substep 0 was
-            # emitted — resync those rows from the authoritative host
-            # stream (review r5: the first fix dropped the whole batch to
-            # horizon 1 for one penalized guided request; this one costs a
-            # single [V]-row scatter per guided slot instead)
-            for slot in rec["gslots"]:
-                req = self.slot_req[slot]
-                if req is None or not (self.pres_pens[slot]
-                                       or self.freq_pens[slot]
-                                       or self.rep_pens[slot] != 1.0):
-                    continue
-                row = np.bincount(np.asarray(req.generated, np.int64),
-                                  minlength=self.cfg.vocab_size)
-                self.counts = _restore_count_row(
-                    self.counts, jnp.int32(slot),
-                    jnp.asarray(row, jnp.int32))
-        if tail and any(r is not None for r in self.slot_req):
-            self._last_ready = t_ready
-        _flight.record("pipeline_fetch", None, horizon=horizon,
-                       emitted=emitted, tail=tail)
-        self._tok_times.append((rec["t0"], emitted))
+        mixed = bool(rec.get("mixed"))
+        try:
+            with _phase(PH_EMIT):
+                for s in range(horizon):
+                    for slot in active:
+                        if self.slot_req[slot] is None:
+                            # finished earlier in this horizon — or after
+                            # the dispatch was enqueued (pipelined surplus
+                            # discard)
+                            continue
+                        if s > 0 and slot in gset:
+                            # guided slots advance one grammar-checked
+                            # token per dispatch; substeps past 0 are
+                            # unconstrained surplus
+                            continue
+                        req = self.slot_req[slot]
+                        lp = None
+                        if req.logprobs is not None and lp_t is not None:
+                            lp = _host_lp(tuple(a[s] for a in lp_t), slot,
+                                          req.logprobs)
+                        self.lengths[slot] += 1
+                        self.sched.note_decode(slot, 1)
+                        self._emit(slot, int(out[s, slot]), lp)
+                        emitted += 1
+            if rec["want_pen"] and rec["gslots"] and horizon > 1:
+                # the fused dispatch incremented guided slots' device-side
+                # penalty-count rows for EVERY substep, but only substep 0
+                # was emitted — resync those rows from the authoritative
+                # host stream (review r5: the first fix dropped the whole
+                # batch to horizon 1 for one penalized guided request; this
+                # one costs a single [V]-row scatter per guided slot instead)
+                for slot in rec["gslots"]:
+                    req = self.slot_req[slot]
+                    if req is None or not (self.pres_pens[slot]
+                                           or self.freq_pens[slot]
+                                           or self.rep_pens[slot] != 1.0):
+                        continue
+                    row = np.bincount(np.asarray(req.generated, np.int64),
+                                      minlength=self.cfg.vocab_size)
+                    self.counts = _restore_count_row(
+                        self.counts, jnp.int32(slot),
+                        jnp.asarray(row, jnp.int32))
+            if tail and any(r is not None for r in self.slot_req):
+                self._last_ready = t_ready
+        finally:
+            self._dispatch_close(
+                rec["drec"], t_ready,
+                batch=len(active) + (1 if mixed else 0),
+                tokens=horizon * len(active) + rec.get("chunk_n", 0),
+                ctx_rows=ctx_rows, steps=horizon,
+                guided_rows=len(rec["gslots"]), tail=tail, emitted=emitted)
+        self._tok_times.append((rec["drec"]["t_enqueue"], emitted))
         if len(self._tok_times) >= 2:
             span = time.monotonic() - self._tok_times[0][0]
             toks = sum(n for _, n in self._tok_times)

@@ -350,6 +350,7 @@ class Handler(BaseHTTPRequestHandler):
                     + capacity.metrics.registry.render(om)
                     + autoscaler.metrics.registry.render(om)
                     + metrics.pipeline.registry.render(om)
+                    + metrics.compile_stages.registry.render(om)
                     + render_engine_chips())
             if om:
                 text += "# EOF\n"
@@ -1663,6 +1664,10 @@ def build_state(serving_cfg=None, model_cfg=None, params=None,
         "tpu-serve-engine",
         endpoint=getattr(serving, "otlp_endpoint", "") or None,
         sample=getattr(serving, "trace_sample", 1.0))
+    # the engine loop exports its engine.dispatch spans through whatever
+    # tracer (and exporter) the server holds at that moment: tests and the
+    # benchmark install theirs on state.tracer after start
+    engine.tracer_source = lambda: state.tracer
     # Flight recorder + SLO engine: module singletons the engine's record/
     # finish shorthands already write through — configure() swaps in the
     # served settings (spool dir, objectives) atomically.
@@ -1716,6 +1721,9 @@ def serve(state: ServerState, host: str, port: int,
     server_thread = threading.Thread(target=httpd.serve_forever,
                                      daemon=True, name="http")
     server_thread.start()
+    # ready: from here on a step program that traces or compiles stalls
+    # live streams (tpu_serve_serving_compiles_total, flight "compile")
+    metrics.compile_stages.serving = True
     if ready_event is not None:
         ready_event.set()
     try:

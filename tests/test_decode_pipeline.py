@@ -776,7 +776,7 @@ def test_pipeline_depth_gauge_and_bubble_accounting(model):
     assert sync_bubble > 0.0, \
         "sync decode must account a host bubble between dispatches"
     assert pipe_bubble < sync_bubble, (pipe_bubble, sync_bubble)
-    # device-time accounting moved too (re-based decode_step_duration base)
+    # device-time accounting moved too
     assert sync.metrics.device_busy_seconds.total() > 0.0
     assert pipe.metrics.device_busy_seconds.total() > 0.0
 

@@ -115,6 +115,11 @@ def test_backend_death_cooldown_and_failover(stack):
     before_dead = m.dead_marks.total()
     ok = 0
     for i in range(4):
+        # the dead replica's last /load sample stays fresh, as within
+        # LOAD_TTL_S of its death: on a loaded machine the four requests can
+        # outlast the TTL, and a stale replica is tried last (so one request
+        # on the survivor is enough and nothing is ever dead-marked)
+        RouterHandler.pool.note_load(f"127.0.0.1:{BASE_PORT}", 0, 0)
         req = urllib.request.Request(
             _url(router, "/v1/completions"),
             data=json.dumps({"model": MODEL_NAME, "prompt": f"q{i}",

@@ -74,6 +74,17 @@ def _compile(fn, *args, **kw):
     return jax.jit(lambda *a, **k: fn(*a, **k)).lower(*args, **kw).compile()
 
 
+def _assert_named_after_wrapper(compiled, fn):
+    """The chip's trace names a Pallas call after the jitted wrapper that
+    makes it (``%decode_attend_pallas_paged.8 = ... custom-call(...)``), and
+    the benchmark's kernel metrics match ``^%<wrapper>``: a rename has to
+    fail here, not empty a metric (benchmark/layer_metrics/)."""
+    import re
+
+    assert re.search(rf"%{fn.__name__}(\.\d+)? = [^\n]*custom-call",
+                     compiled.as_text()), fn.__name__
+
+
 CASES = [
     # (id, entry point, quant, bblock, rows, query rows per slot, pages)
     ("decode-bf16-bb1", "decode", False, 1, B, 1, 32),
@@ -107,6 +118,7 @@ def test_paged_attention_kernel_compiles_for_v5e(chip, entry, quant, bb,
     compiled = _compile(functools.partial(fn, bblock=bb), q, kv, kv, lens,
                         lay, table, **skw)
     assert "tpu_custom_call" in compiled.as_text()
+    _assert_named_after_wrapper(compiled, fn)
 
 
 @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
@@ -120,6 +132,7 @@ def test_paged_write_kernel_compiles_for_v5e(chip, quant):
     else:
         compiled = _compile(pa.cache_write_row_paged, kv, new, rows, table,
                             lay)
+        _assert_named_after_wrapper(compiled, pa.cache_write_row_paged)
     assert "tpu_custom_call" in compiled.as_text()
 
 

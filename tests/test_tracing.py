@@ -379,15 +379,31 @@ def _run_golden(router, state):
                  "X-Request-Deadline-Ms": "30000"})
     with urllib.request.urlopen(req, timeout=120) as r:
         body = json.loads(r.read())
+    # Quiescence before the tracers go: both handlers finish their spans
+    # AFTER writing the response (server: phase children then
+    # server.request; router: the hop, then router.request last), so the
+    # client can hold the body while they are still emitting — and
+    # RouterHandler reads its class-level tracer again at finish. Returning
+    # early lost the tail of the tree in a loaded run (PRs 21, 23).
+    deadline = time.monotonic() + 30
+    while time.monotonic() < deadline:
+        names = [s.name for s, _ in list(rec.items)]
+        if "router.request" in names and "server.request" in names:
+            break
+        time.sleep(0.005)
     RouterHandler.tracer = None
     state.tracer = None
-    return rec.items, body
+    return list(rec.items), body
 
 
 def _tree(items):
-    spans = {"router.dispatch": [], "phases": []}
+    spans = {"router.dispatch": [], "phases": [], "engine": []}
     for s, svc in items:
-        if s.name == "router.request":
+        if s.name.startswith("engine."):
+            # the engine loop's own spans (one per device dispatch, ids
+            # from the dispatch record's seq): not part of the request tree
+            spans["engine"].append(s)
+        elif s.name == "router.request":
             spans["root"] = s
             assert svc == "tpu-serve-router"
         elif s.name == "router.dispatch":
