@@ -645,7 +645,9 @@ def make_mixed_attend_carry_paged(write_rows: jnp.ndarray,
       the slot's context length; chunk row at position p: p; -1 DROPS the
       write — used to suppress the chunking slot's garbage decode row);
     - ``row_limits`` [N]: live columns the row attends over (decode:
-      context + 1; chunk: p + 1 — plain causality);
+      context + 1; chunk: p + 1 — plain causality; 0 = a DEAD row — the
+      chunk's padding, the chunking slot's decode row — which fetches
+      nothing and returns zeros, from the kernel and the fallback alike);
     - ``row_tables`` [N, max_pages]: the page run of the slot row i belongs
       to (chunk rows repeat the chunking slot's run).
 
@@ -668,17 +670,20 @@ def make_mixed_attend_carry_paged(write_rows: jnp.ndarray,
         if "ks" in pool:
             ck, ks = pallas_attention.cache_write_row_quant_paged(
                 ck, pool["ks"], knew, wrows, tabs, layer,
-                interpret=interpret)
+                interpret=interpret, packed=True)
             cv, vs = pallas_attention.cache_write_row_quant_paged(
                 cv, pool["vs"], vnew, wrows, tabs, layer,
-                interpret=interpret)
+                interpret=interpret, packed=True)
             pool = {"k": ck, "v": cv, "ks": ks, "vs": vs}
             scale_kw = dict(pool_ks=ks, pool_vs=vs)
         else:
+            # packed: the chunk rows of one slot share 8-row blocks
             ck = pallas_attention.cache_write_row_paged(
-                ck, knew, wrows, tabs, layer, interpret=interpret)
+                ck, knew, wrows, tabs, layer, interpret=interpret,
+                packed=True)
             cv = pallas_attention.cache_write_row_paged(
-                cv, vnew, wrows, tabs, layer, interpret=interpret)
+                cv, vnew, wrows, tabs, layer, interpret=interpret,
+                packed=True)
             pool = {"k": ck, "v": cv}
             scale_kw = {}
         ctx = pallas_attention.ragged_attend_pallas_paged(
@@ -730,8 +735,9 @@ def make_mixed_attend_carry_paged(write_rows: jnp.ndarray,
             ck = kvc.dequantize(ck, dense["ks"], dtype=q.dtype)
             cv = kvc.dequantize(cv, dense["vs"], dtype=q.dtype)
         ctx = decode_attend(q[0][:, None], ck, cv, row_limits,
-                            window=window)
-        return ctx[:, 0][None], (pool, layer)
+                            window=window)[:, 0]
+        ctx = jnp.where((row_limits > 0)[:, None, None], ctx, 0)
+        return ctx[None], (pool, layer)
 
     return attend
 

@@ -200,13 +200,16 @@ def _decode_kernel_layer_q(lengths_ref,     # scalar prefetch [B] int32
         o_ref[0, :, :] = (acc_ref[:] / l).astype(o_ref.dtype)
 
 
-def _per_slot(vals, shape):
-    """Broadcast BB per-slot int32 SCALARS along axis 0 of ``shape``
-    ([BB, rows, cols]) with an iota select — the layout Mosaic accepts for
-    the batch-blocked kernels' per-slot column masks."""
+def _per_slot(vals, shape, axis: int = 0, each: int = 1):
+    """Broadcast BB per-slot int32 SCALARS along ``axis`` of ``shape``
+    (slot i owns indices [i*each, (i+1)*each) there) with an iota select —
+    the layout Mosaic accepts for the batch-blocked kernels' per-slot column
+    masks."""
     out = jnp.full(shape, vals[0], jnp.int32)
     if len(vals) > 1:
-        slot = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        slot = jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+        if each > 1:
+            slot = slot // each
         for i in range(1, len(vals)):
             out = jnp.where(slot == i, vals[i], out)
     return out
@@ -873,8 +876,9 @@ def cache_write_row_quant(cache: jnp.ndarray, scales: jnp.ndarray,
 # moving only ~0.5 MB — fixed per-step cost (DMA issue + kernel dispatch,
 # ~1 µs class) rivaled the stream time itself and pinned decode at ~36% of
 # the HBM roofline. Here the grid is (B/BB,): one step per BLOCK of BB
-# slots, the page loop lives inside the kernel (statically unrolled over the
-# table width), and each buffer fill issues BB page copies back-to-back —
+# slots, the page loop lives inside the kernel (a loop over the block's live
+# page range, bounds read from the lengths), and each buffer fill issues BB
+# page copies back-to-back —
 # BBx larger transfers in flight, BBx fewer grid steps, and dead pages
 # (beyond a block's longest slot, or below its sliding-window start) are
 # skipped outright rather than clamp-refetched. This is the TPU analogue of
@@ -888,32 +892,48 @@ def cache_write_row_quant(cache: jnp.ndarray, scales: jnp.ndarray,
 # special case; ragged_attend_pallas_paged exposes the general form — a
 # packed mix of decode rows and prefill-chunk rows served by ONE dispatch
 # (serving/programs.mixed_step rides it to keep the decode pipeline open
-# across prefill admissions).
+# across prefill admissions). Its work follows the live (row, page) pairs:
+# rows with limit 0 cost nothing, and a block whose rows share one table row
+# streams each page once.
 #
 # ``bblock`` (BB) is the knob the engine autotunes at startup
 # (Engine._resolve_decode_bblock: one-shot microbench over {1, 4, 8} per
 # (batch, page_size, kv_dtype)); 1 remains valid and still double-buffers.
 
 
-def _paged_db_body(lengths_ref, layer_ref, table_ref, q_ref, k_hbm, v_hbm,
-                   ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf,
-                   acc_ref, m_ref, l_ref, sem,
+def _paged_db_body(lengths_ref, layer_ref, table_ref, share_ref, q_ref,
+                   k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf,
+                   vs_buf, acc_ref, m_ref, l_ref, sem, acc_t, m_t, l_t,
                    *, ps: int, groups: int, scale: float, R: int, bb: int,
                    num_pages: int, window: int, spec: bool):
     """Shared double-buffered paged flash body (decode R=1 / spec-verify R>1,
     bf16 / int8 pools, full / sliding-window attention).
 
-    One grid step handles BB slots end to end: init flash state, then walk
-    the block's live logical pages [lo, hi] with a two-slot VMEM buffer —
+    One grid step handles BB rows end to end: init flash state, then walk
+    the block's live logical pages [lo_min, hi_max] (a loop with dynamic
+    bounds: pages no row needs cost nothing) with a two-slot VMEM buffer —
     issue page c+1's copies, wait page c's, accumulate page c. The table is
     scalar-prefetched (SMEM, FLATTENED row-major — see _paged_flash_db), so
-    physical ids resolve in-kernel with no HBM round trip. Per-slot
+    physical ids resolve in-kernel with no HBM round trip. Per-row
     raggedness inside a block rides the column mask
-    (shorter slots' dead columns contribute exp(-1e30 - m) == 0 exactly once
+    (shorter rows' dead columns contribute exp(-1e30 - m) == 0 exactly once
     any live column has been seen — bit-identical to the skip-based
-    single-slot accumulation); the per-slot page index clamps into the
-    slot's OWN live range so a mixed block never fetches a neighbor's
+    single-slot accumulation); the per-row page index clamps into the
+    row's OWN live range so a mixed block never fetches a neighbor's
     garbage table entries.
+
+    A row with NO live column (limit 0: an idle slot, a padding row of a
+    prefill chunk) is DEAD: it has no live page, widens no block's range,
+    none of its table entries is ever used as a page id, and its output is
+    exactly zero. A block of dead rows issues no DMA and runs no flash
+    update.
+
+    ``share_ref`` (ragged entry, bb > 1; else None and compiled out) holds
+    per block the row whose table every live row of the block shares, or -1.
+    A sharing block fetches page c ONCE (not once per row) and runs the
+    flash update with the block as one [bb*groups]-row query tile per KV
+    head — the chunk rows of one prefill are the case; each row still masks
+    to its own limit and window.
     """
     g = pl.program_id(0)
     lay = layer_ref[0]
@@ -921,72 +941,107 @@ def _paged_db_body(lengths_ref, layer_ref, table_ref, q_ref, k_hbm, v_hbm,
     hq = q_ref.shape[1] // R
     d = q_ref.shape[2]
     hkv = k_buf.shape[2]
-    # BB per-slot SCALARS (see _per_slot: a stacked scalar vector reshaped
+    ext = R if spec else 0      # spec rows see up to R columns past lengths
+    # BB per-row SCALARS (see _per_slot: a stacked scalar vector reshaped
     # to [BB, 1, 1] is a shape cast Mosaic refuses)
     lens = [lengths_ref[g * bb + i] for i in range(bb)]
-    hi = [jnp.maximum(pl.cdiv(ln + (R if spec else 0), ps) - 1, 0)
-          for ln in lens]
+    alive = [ln + ext > 0 for ln in lens]
+    hi = [jnp.minimum(pl.cdiv(ln + ext, ps), num_pages) - 1
+          for ln in lens]                                 # -1 = dead row
     hi_max = functools.reduce(jnp.maximum, hi)
     if window > 0:
         lo = [jnp.maximum(ln + (1 if spec else 0) - window, 0) // ps
               for ln in lens]
-        lo_min = functools.reduce(jnp.minimum, lo)
+        lo_min = functools.reduce(
+            jnp.minimum, [jnp.where(a, x, num_pages)
+                          for a, x in zip(alive, lo)])
     else:
         lo = [jnp.int32(0)] * bb
         lo_min = jnp.int32(0)
-    lens_b = _per_slot(lens, (bb, hq, ps))
 
-    def live(c: int):
-        # block-level liveness of logical page c (c is a python int): some
-        # slot in the block still has rows there
-        return (c <= hi_max) & (c >= lo_min)
+    def row_pages(c):
+        """(buffer row, physical page) of every row's page-c copy. A row
+        clamps into its own live range: table entries past it may be
+        anything valid (scratch, stale) — never fetch them. A dead row in a
+        live block rides along on physical page 0 (always in the pool)."""
+        return [(i, jnp.where(
+            alive[i],
+            table_ref[(g * bb + i) * num_pages
+                      + jnp.clip(c, lo[i], jnp.maximum(hi[i], 0))], 0))
+                for i in range(bb)]
 
-    def copies(c: int, slot: int):
-        """The block's page-c DMAs into buffer ``slot`` (created identically
-        at start and wait time — the documented make_async_copy pattern)."""
-        out = []
-        for i in range(bb):
-            # clamp into slot i's own live range: table entries past it may
-            # be anything valid (scratch, stale) — never fetch them
-            pg = table_ref[(g * bb + i) * num_pages
-                           + jnp.clip(c, lo[i], hi[i])]
-            out.append(pltpu.make_async_copy(
-                k_hbm.at[lay, pg], k_buf.at[slot, i], sem.at[slot, i, 0]))
-            out.append(pltpu.make_async_copy(
-                v_hbm.at[lay, pg], v_buf.at[slot, i], sem.at[slot, i, 1]))
-            if quant:
+    def walk(pages, update):
+        """The double-buffered walk over [lo_min, hi_max]: ``pages(c)`` names
+        page c's copies, ``update(c, buf)`` folds buffer ``buf`` into the
+        flash state."""
+
+        def copies(c):
+            # created identically at start and wait time — the documented
+            # make_async_copy pattern
+            slot = c % 2
+            out = []
+            for i, pg in pages(c):
                 out.append(pltpu.make_async_copy(
-                    ks_hbm.at[lay, pg], ks_buf.at[slot, i],
-                    sem.at[slot, i, 2]))
+                    k_hbm.at[lay, pg], k_buf.at[slot, i], sem.at[slot, i, 0]))
                 out.append(pltpu.make_async_copy(
-                    vs_hbm.at[lay, pg], vs_buf.at[slot, i],
-                    sem.at[slot, i, 3]))
-        return out
+                    v_hbm.at[lay, pg], v_buf.at[slot, i], sem.at[slot, i, 1]))
+                if quant:
+                    out.append(pltpu.make_async_copy(
+                        ks_hbm.at[lay, pg], ks_buf.at[slot, i],
+                        sem.at[slot, i, 2]))
+                    out.append(pltpu.make_async_copy(
+                        vs_hbm.at[lay, pg], vs_buf.at[slot, i],
+                        sem.at[slot, i, 3]))
+            return out
 
-    def start(c: int):
-        @pl.when(live(c))
-        def _():
-            for dma in copies(c, c % 2):
+        @pl.when(lo_min <= hi_max)
+        def _prologue():                   # first page in flight
+            for dma in copies(lo_min):
                 dma.start()
 
-    def wait(c: int):
-        @pl.when(live(c))
-        def _():
-            for dma in copies(c, c % 2):
+        def step(c, carry):
+            @pl.when(c < hi_max)
+            def _prefetch():               # fetch page c+1 while computing c
+                for dma in copies(c + 1):
+                    dma.start()
+
+            for dma in copies(c):
                 dma.wait()
+            update(c, c % 2)
+            return carry
 
-    acc_ref[:] = jnp.zeros_like(acc_ref)
-    m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-    l_ref[:] = jnp.zeros_like(l_ref)
-    start(0)                           # prologue: first page in flight
-    for c in range(num_pages):         # static unroll over the table width
-        if c + 1 < num_pages:
-            start(c + 1)               # fetch page c+1 while computing c
-        wait(c)
+        jax.lax.fori_loop(lo_min, hi_max + 1, step, 0)
 
-        @pl.when(live(c))
-        def _accumulate(c=c):
-            buf = c % 2
+    def flash(s, col, limit, m_ref, l_ref, acc_ref, sl, pv_of):
+        """One online-softmax update of state rows ``sl`` from the masked
+        logits of page columns ``col``; ``pv_of(p)`` is the page's P.V."""
+        live_col = col < limit
+        if window > 0:
+            live_col &= col >= limit - window
+        s = jnp.where(live_col, s, NEG_INF)
+        m_prev = m_ref[:, sl, :1]
+        l_prev = l_ref[:, sl, :1]
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        corr = jnp.exp(m_prev - m_cur)
+        p = jnp.exp(s - m_cur)
+        l_cur = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[:, sl] = acc_ref[:, sl] * corr + pv_of(p)
+        m_ref[:, sl, :1] = m_cur
+        l_ref[:, sl, :1] = l_cur
+
+    def reset(acc, m, l):
+        acc[:] = jnp.zeros_like(acc)
+        m[:] = jnp.full_like(m, NEG_INF)
+        l[:] = jnp.zeros_like(l)
+
+    def per_row():
+        """Every row streams its own pages: BB copies a page step, BB*Hkv
+        matmuls of ``groups`` rows."""
+        lens_b = _per_slot(lens, (bb, hq, ps))
+        q3s = [(q_ref[:, r * hq:(r + 1) * hq].astype(jnp.float32) * scale)
+               .reshape(bb * hkv, groups, d) for r in range(R)]
+
+        def update(c, buf):
             k3 = k_buf[buf].astype(jnp.float32).reshape(bb * hkv, ps, d)
             v3 = v_buf[buf].astype(jnp.float32).reshape(bb * hkv, ps, d)
             if quant:
@@ -994,58 +1049,111 @@ def _paged_db_body(lengths_ref, layer_ref, table_ref, q_ref, k_hbm, v_hbm,
                 # only the first ``ps`` lanes are rows of this page
                 kscale = ks_buf[buf][:, :, :ps].reshape(bb * hkv, ps)
                 vscale = vs_buf[buf][:, :, :ps].reshape(bb * hkv, ps)
+            col = c * ps + jax.lax.broadcasted_iota(jnp.int32,
+                                                    (bb, hq, ps), 2)
             for r in range(R):         # static unroll over draft rows
                 sl = slice(r * hq, (r + 1) * hq)
-                q3 = (q_ref[:, sl].astype(jnp.float32) * scale) \
-                    .reshape(bb * hkv, groups, d)
                 s = jax.lax.dot_general(
-                    q3, k3, (((2,), (2,)), ((0,), (0,))),
+                    q3s[r], k3, (((2,), (2,)), ((0,), (0,))),
                     preferred_element_type=jnp.float32)   # [BB*Hkv, G, ps]
                 if quant:
                     s = s * kscale[:, None, :]
-                s = s.reshape(bb, hq, ps)
-                col = c * ps + jax.lax.broadcasted_iota(jnp.int32,
-                                                        (bb, hq, ps), 2)
-                limit = lens_b + (1 + r if spec else 0)
-                live_col = col < limit
-                if window > 0:
-                    live_col &= col >= limit - window
-                s = jnp.where(live_col, s, NEG_INF)
-                m_prev = m_ref[:, sl, :1]
-                l_prev = l_ref[:, sl, :1]
-                m_cur = jnp.maximum(m_prev,
-                                    jnp.max(s, axis=-1, keepdims=True))
-                corr = jnp.exp(m_prev - m_cur)
-                p = jnp.exp(s - m_cur)
-                l_cur = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
-                p3 = p.reshape(bb * hkv, groups, ps)
+
+                def pv_of(p):
+                    p3 = p.reshape(bb * hkv, groups, ps)
+                    if quant:
+                        p3 = p3 * vscale[:, None, :]
+                    return jax.lax.dot_general(
+                        p3, v3, (((2,), (1,)), ((0,), (0,))),
+                        preferred_element_type=jnp.float32
+                    ).reshape(bb, hq, d)                  # [BB*Hkv, G, d]
+
+                flash(s.reshape(bb, hq, ps), col,
+                      lens_b + (1 + r if spec else 0), m_ref, l_ref,
+                      acc_ref, sl, pv_of)
+
+        reset(acc_ref, m_ref, l_ref)
+        walk(row_pages, update)
+        return acc_ref[:] / jnp.maximum(l_ref[:, :, :1], 1e-9)
+
+    def shared(row):
+        """All live rows read table row ``row``: ONE copy a page step into
+        buffer row 0, Hkv matmuls of BB*groups rows (tile row = b*groups +
+        group)."""
+        n = bb * groups
+
+        def tile(x):       # [BB, Hq, *] -> [Hkv, BB*groups, *]
+            return x.reshape(bb, hkv, groups, -1).transpose(1, 0, 2, 3) \
+                .reshape(hkv, n, -1)
+
+        qt = tile(q_ref[:].astype(jnp.float32) * scale)
+        limit = _per_slot(lens, (hkv, n, ps), axis=1, each=groups)
+
+        def update(c, buf):
+            k3 = k_buf[buf, 0].astype(jnp.float32)            # [Hkv, ps, d]
+            v3 = v_buf[buf, 0].astype(jnp.float32)
+            s = jax.lax.dot_general(
+                qt, k3, (((2,), (2,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)           # [Hkv, n, ps]
+            if quant:
+                s = s * ks_buf[buf, 0][:, :ps][:, None, :]
+
+            def pv_of(p):
                 if quant:
-                    p3 = p3 * vscale[:, None, :]
-                pv = jax.lax.dot_general(
-                    p3, v3, (((2,), (1,)), ((0,), (0,))),
-                    preferred_element_type=jnp.float32)   # [BB*Hkv, G, d]
-                acc_ref[:, sl] = acc_ref[:, sl] * corr \
-                    + pv.reshape(bb, hq, d)
-                m_ref[:, sl, :1] = m_cur
-                l_ref[:, sl, :1] = l_cur
+                    p = p * vs_buf[buf, 0][:, :ps][:, None, :]
+                return jax.lax.dot_general(
+                    p, v3, (((2,), (1,)), ((0,), (0,))),
+                    preferred_element_type=jnp.float32)       # [Hkv, n, d]
 
-    l_fin = jnp.maximum(l_ref[:, :, :1], 1e-9)
-    o_ref[:] = (acc_ref[:] / l_fin).astype(o_ref.dtype)
+            col = c * ps + jax.lax.broadcasted_iota(jnp.int32,
+                                                    (hkv, n, ps), 2)
+            flash(s, col, limit, m_t, l_t, acc_t, slice(None), pv_of)
+
+        reset(acc_t, m_t, l_t)
+        walk(lambda c: [(0, table_ref[row * num_pages + c])], update)
+        out = acc_t[:] / jnp.maximum(l_t[:, :, :1], 1e-9)
+        return out.reshape(hkv, bb, groups, d).transpose(1, 0, 2, 3) \
+            .reshape(bb, hq, d)
+
+    def emit(ctx):
+        # dead rows hold whatever rode through their lanes: exactly zero out
+        live_row = _per_slot(lens, ctx.shape) + ext > 0
+        o_ref[:] = jnp.where(live_row, ctx, 0.0).astype(o_ref.dtype)
+
+    if share_ref is None:
+        emit(per_row())
+    else:
+        row = share_ref[g]
+
+        @pl.when(row >= 0)
+        def _shared():
+            emit(shared(row))
+
+        @pl.when(row < 0)
+        def _per_row():
+            emit(per_row())
 
 
-def _paged_db_kernel(lengths_ref, layer_ref, table_ref, q_ref, k_hbm, v_hbm,
-                     o_ref, k_buf, v_buf, acc_ref, m_ref, l_ref, sem, **kw):
-    _paged_db_body(lengths_ref, layer_ref, table_ref, q_ref, k_hbm, v_hbm,
-                   None, None, o_ref, k_buf, v_buf, None, None,
-                   acc_ref, m_ref, l_ref, sem, **kw)
+def _paged_db_kernel(*refs, quant: bool, share: bool, **kw):
+    """Name the pallas_call's positional refs (scalar prefetch, inputs,
+    output, scratch, in _paged_flash_db's order) for _paged_db_body; what a
+    bf16 pool or a call without a share fact leaves out is None."""
+    it = iter(refs)
 
+    def take(n, present=True):
+        return [next(it) if present else None for _ in range(n)]
 
-def _paged_db_kernel_quant(lengths_ref, layer_ref, table_ref, q_ref, k_hbm,
-                           v_hbm, ks_hbm, vs_hbm, o_ref, k_buf, v_buf,
-                           ks_buf, vs_buf, acc_ref, m_ref, l_ref, sem, **kw):
-    _paged_db_body(lengths_ref, layer_ref, table_ref, q_ref, k_hbm, v_hbm,
-                   ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf,
-                   acc_ref, m_ref, l_ref, sem, **kw)
+    lengths_ref, layer_ref, table_ref = take(3)
+    share_ref, = take(1, share)
+    q_ref, k_hbm, v_hbm = take(3)
+    ks_hbm, vs_hbm = take(2, quant)
+    o_ref, k_buf, v_buf = take(3)
+    ks_buf, vs_buf = take(2, quant)
+    acc_ref, m_ref, l_ref, sem = take(4)
+    acc_t, m_t, l_t = take(3, share)
+    _paged_db_body(lengths_ref, layer_ref, table_ref, share_ref, q_ref,
+                   k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf,
+                   vs_buf, acc_ref, m_ref, l_ref, sem, acc_t, m_t, l_t, **kw)
 
 
 def _resolve_bb(bblock, B: int) -> int:
@@ -1058,12 +1166,14 @@ def _resolve_bb(bblock, B: int) -> int:
 
 def _paged_flash_db(q2, pool_k, pool_v, lengths, layer_arr, table,
                     *, bb: int, R: int, spec: bool, window: int,
-                    interpret: bool, pool_ks, pool_vs):
+                    interpret: bool, pool_ks, pool_vs, share=None):
     """Build + dispatch the double-buffered paged flash call.
 
     q2: [B, R*Hq, D] (R=1 for plain decode). Grid is (B // bb,); the pools
     ride as ANY-memory-space operands (never blocked by Pallas — the kernel
     DMAs exactly the live pages), q/o are VMEM-blocked per slot block.
+    ``share`` [B // bb] int32 (ragged entry only): per block, the row whose
+    table its live rows all share, or -1 — see _paged_db_body.
     """
     B, RHq, D = q2.shape
     Hkv, ps = pool_k.shape[2], pool_k.shape[3]
@@ -1071,14 +1181,7 @@ def _paged_flash_db(q2, pool_k, pool_v, lengths, layer_arr, table,
     num_pages = table.shape[1]
     quant = pool_ks is not None
 
-    def q_map(g, lens, lay, tab):
-        return (g, 0, 0)
-
-    in_specs = [
-        pl.BlockSpec((bb, RHq, D), q_map),
-        pl.BlockSpec(memory_space=pltpu.ANY),
-        pl.BlockSpec(memory_space=pltpu.ANY),
-    ]
+    in_specs = [pl.BlockSpec(memory_space=pltpu.ANY)] * 2    # the pools
     operands = [q2, pool_k, pool_v]
     if quant:
         in_specs += [pl.BlockSpec(memory_space=pltpu.ANY)] * 2
@@ -1099,15 +1202,31 @@ def _paged_flash_db(q2, pool_k, pool_v, lengths, layer_arr, table,
         pltpu.VMEM((bb, RHq, 128), jnp.float32),           # l
         pltpu.SemaphoreType.DMA((2, bb, 4 if quant else 2)),
     ]
+    # The table rides SMEM flattened: a 2-D s32[N, max_pages] operand pads
+    # its minor dim to 128 lanes there, and the mixed program's per-ROW table
+    # (N = slots + chunk = 2,080 rows x 32 pages at the default config)
+    # then needs 1.04 MiB of the chip's 1 MiB.
+    prefetch = [lengths, layer_arr, table.reshape(-1)]
+    if share is not None:
+        prefetch.append(share)
+        scratch += [                       # the sharing blocks' flash state
+            pltpu.VMEM((Hkv, bb * groups, D), jnp.float32),
+            pltpu.VMEM((Hkv, bb * groups, 128), jnp.float32),
+            pltpu.VMEM((Hkv, bb * groups, 128), jnp.float32),
+        ]
+
+    def q_map(g, *prefetched):
+        return (g, 0, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=len(prefetch),
         grid=(B // bb,),
-        in_specs=in_specs,
+        in_specs=[pl.BlockSpec((bb, RHq, D), q_map)] + in_specs,
         out_specs=pl.BlockSpec((bb, RHq, D), q_map),
         scratch_shapes=scratch,
     )
     kernel = functools.partial(
-        _paged_db_kernel_quant if quant else _paged_db_kernel,
+        _paged_db_kernel, quant=quant, share=share is not None,
         ps=ps, groups=groups, scale=1.0 / (D ** 0.5), R=R, bb=bb,
         num_pages=num_pages, window=window, spec=spec)
     return pl.pallas_call(
@@ -1115,11 +1234,7 @@ def _paged_flash_db(q2, pool_k, pool_v, lengths, layer_arr, table,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, RHq, D), q2.dtype),
         interpret=interpret,
-    # The table rides SMEM flattened: a 2-D s32[N, max_pages] operand pads
-    # its minor dim to 128 lanes there, and the mixed program's per-ROW table
-    # (N = slots + chunk = 2,080 rows x 32 pages at the default config)
-    # then needs 1.04 MiB of the chip's 1 MiB.
-    )(lengths, layer_arr, table.reshape(-1), *operands)
+    )(*prefetch, *operands)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "window", "bblock"))
@@ -1141,7 +1256,9 @@ def decode_attend_pallas_paged(q: jnp.ndarray, pool_k: jnp.ndarray,
     fetched). Returns [B, 1, Hq, D]. pool_ks/vs switch the int8 scale-folding
     body, as in the dense kernel. ``bblock`` slots share each grid step
     (resolved to the largest divisor of B); page i+1 prefetches while page i
-    computes regardless of bblock — see _paged_db_body.
+    computes regardless of bblock — see _paged_db_body. A slot of length 0
+    (idle) is a dead row: nothing is fetched for it and its output row is
+    exactly zero (it used to be the mean of its first page's V rows).
     """
     B = q.shape[0]
     lengths = lengths.astype(jnp.int32)
@@ -1180,9 +1297,18 @@ def ragged_attend_pallas_paged(q: jnp.ndarray, pool_k: jnp.ndarray,
     - a PREFILL-CHUNK row at position p carries the chunking slot's table
       row and limit = p + 1 (plain causality), so C chunk rows of one slot
       pack alongside B decode rows of B other slots and every row masks to
-      exactly its own live columns. Chunk rows of the same slot landing in
-      one bblock-wide grid step fetch the same pages — the block's page
-      stream amortizes over them exactly as it does over decode neighbors.
+      exactly its own live columns;
+    - a row with limit 0 (the chunk's padding rows, the chunking slot's own
+      decode row) is DEAD: it costs no fetch and no flash update, its table
+      entries are never used and may be anything, and its output is zero.
+
+    The work follows the live (row, page) pairs. Where the live rows of one
+    bblock-wide grid step all carry the same table row — the chunk rows of
+    one slot do — the step fetches each page ONCE and updates the block as
+    one query tile (_paged_db_body's sharing path); that is read off
+    ``row_tables`` here, per call, not set by anyone. Other blocks (decode
+    rows of distinct slots, a block straddling decode and chunk rows) stream
+    a page per row, as the decode entry does.
 
     q: [N, Hq, D] packed query rows; row_limits: [N] live columns per row;
     row_tables: [N, max_pages] int32 (entries at or past a row's live range
@@ -1193,13 +1319,27 @@ def ragged_attend_pallas_paged(q: jnp.ndarray, pool_k: jnp.ndarray,
     largest divisor of N).
     """
     N = q.shape[0]
+    bb = _resolve_bb(bblock, N)
     row_limits = row_limits.astype(jnp.int32)
+    row_tables = row_tables.astype(jnp.int32)
     layer_arr = jnp.asarray(layer, jnp.int32).reshape(1)
+    share = None
+    if bb > 1:
+        # per block: its first live row, if every live row's table equals
+        # that row's (then any page one of them needs is at the same entry
+        # of that row), else -1
+        live = (row_limits > 0).reshape(N // bb, bb)
+        tabs = row_tables.reshape(N // bb, bb, -1)
+        first = jnp.argmax(live, axis=1).astype(jnp.int32)
+        lead = jnp.take_along_axis(tabs, first[:, None, None], axis=1)
+        same = jnp.all((tabs == lead) | ~live[:, :, None], axis=(1, 2))
+        share = jnp.where(same & live.any(axis=1),
+                          jnp.arange(N // bb, dtype=jnp.int32) * bb + first,
+                          -1)
     return _paged_flash_db(
-        q, pool_k, pool_v, row_limits, layer_arr,
-        row_tables.astype(jnp.int32),
-        bb=_resolve_bb(bblock, N), R=1, spec=False, window=window,
-        interpret=interpret, pool_ks=pool_ks, pool_vs=pool_vs)
+        q, pool_k, pool_v, row_limits, layer_arr, row_tables,
+        bb=bb, R=1, spec=False, window=window, interpret=interpret,
+        pool_ks=pool_ks, pool_vs=pool_vs, share=share)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "window", "bblock"))
@@ -1218,7 +1358,8 @@ def decode_attend_pallas_spec_paged(q: jnp.ndarray, pool_k: jnp.ndarray,
     has already written all R rows (their pages allocated up front — the
     engine's ensure-pages step covers lengths + R). Same economics as the
     dense spec kernel: one page stream serves all R queries — and with
-    ``bblock`` > 1, all BB slots of a block.
+    ``bblock`` > 1, all BB slots of a block. No row is dead here: a slot of
+    length 0 still verifies R drafts over its first R rows.
     """
     B, R, Hq, D = q.shape
     lengths = lengths.astype(jnp.int32)
@@ -1230,11 +1371,31 @@ def decode_attend_pallas_spec_paged(q: jnp.ndarray, pool_k: jnp.ndarray,
     return out.reshape(B, R, Hq, D)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+def _write_block(b, rows, tab, src, *, MP: int, ps: int, ROWS: int):
+    """(physical page, row block) grid step b of a paged row write opens:
+    those of row ``src[b]`` if the call is packed, of row b otherwise. tab:
+    the table FLATTENED row-major (SMEM; see _paged_flash_db)."""
+    b = src[0][b] if src else b
+    r = jnp.clip(rows[b], 0, MP * ps - 1)
+    return tab[b * MP + r // ps], (r % ps) // ROWS
+
+
+def _packed_write_src(rows: jnp.ndarray, limit: int) -> jnp.ndarray:
+    """For a PACKED write (several rows of one slot in one call): per row, the
+    row whose pool block its grid step opens — itself, or for a DROPPED row
+    the nearest kept row before it, so a dropped row rides on the block the
+    step before it holds and never re-opens one an earlier step wrote."""
+    idx = jnp.arange(rows.shape[0], dtype=jnp.int32)
+    kept = (rows >= 0) & (rows < limit)
+    src = jax.lax.cummax(jnp.where(kept, idx, -1))
+    return jnp.where(src < 0, idx, src)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "packed"))
 def cache_write_row_paged(pool: jnp.ndarray, new: jnp.ndarray,
                           rows: jnp.ndarray, table: jnp.ndarray,
-                          layer: jnp.ndarray,
-                          interpret: bool = False) -> jnp.ndarray:
+                          layer: jnp.ndarray, interpret: bool = False,
+                          packed: bool = False) -> jnp.ndarray:
     """Write one new K (or V) row per slot into the PAGED pool, IN PLACE.
 
     pool: [L, P, Hkv, page, D]; new: [B, Hkv, D]; rows: [B] logical row per
@@ -1242,6 +1403,15 @@ def cache_write_row_paged(pool: jnp.ndarray, new: jnp.ndarray,
     [0, max_pages*page) DROP (surplus-write invariant). Same aliased-output
     design as the dense cache_write_row (see its docstring for why a kernel
     and not a scatter).
+
+    ``packed`` (static; the mixed program's layout, where the chunk rows of
+    one slot follow each other): consecutive grid steps may touch the SAME
+    8-row block. Pallas then neither refetches the input block nor writes
+    the output block back between them, so a step that merged its row into
+    the INPUT copy would drop every earlier row of the run (on the chip one
+    row in eight of a chunk landed — my chip run, PR 25). A packed step
+    merges into the output block the run has built so far. Without it
+    (one row per slot: every step its own block) the kernel is as it was.
     """
     L, P, Hkv, ps, D = pool.shape
     rows = rows.astype(jnp.int32)
@@ -1250,18 +1420,22 @@ def cache_write_row_paged(pool: jnp.ndarray, new: jnp.ndarray,
     MP = table.shape[1]
     S_v = MP * ps
     ROWS = 8 if ps % 8 == 0 else ps
+    prefetch = [rows, layer_arr, table.reshape(-1)]
+    if packed:
+        prefetch.append(_packed_write_src(rows, S_v))
 
-    def new_map(b, lens, lay, tab):
+    block = functools.partial(_write_block, MP=MP, ps=ps, ROWS=ROWS)
+
+    def new_map(b, *prefetched):
         return (b, 0, 0)
 
-    def blk_map(b, lens, lay, tab):
-        # tab: the table FLATTENED row-major (SMEM; see _paged_flash_db)
-        r = jnp.clip(lens[b], 0, S_v - 1)
-        return (lay[0], tab[b * MP + r // ps], 0, (r % ps) // ROWS, 0)
+    def blk_map(b, lens, lay, tab, *src):
+        pg, rb = block(b, lens, tab, src)
+        return (lay[0], pg, 0, rb, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B := new.shape[0],),
+        num_scalar_prefetch=len(prefetch),
+        grid=(new.shape[0],),
         in_specs=[
             pl.BlockSpec((1, Hkv, D), new_map),
             pl.BlockSpec((1, 1, Hkv, ROWS, D), blk_map),
@@ -1269,36 +1443,47 @@ def cache_write_row_paged(pool: jnp.ndarray, new: jnp.ndarray,
         out_specs=pl.BlockSpec((1, 1, Hkv, ROWS, D), blk_map),
     )
 
-    def kernel(lengths_ref, layer_ref, table_ref, new_ref, cin_ref, cout_ref):
+    def kernel(lengths_ref, layer_ref, table_ref, *refs):
+        new_ref, cin_ref, cout_ref = refs[-3:]
         b = pl.program_id(0)
         tgt = lengths_ref[b]
         in_window = (tgt >= 0) & (tgt < S_v)
         # ROWS divides page_size, so the in-block row is tgt % ROWS
         r = jnp.where(in_window, jnp.clip(tgt, 0, S_v - 1) % ROWS, -1)
         row = jax.lax.broadcasted_iota(jnp.int32, (Hkv, ROWS, D), 1)
-        cout_ref[0, 0] = jnp.where(row == r, new_ref[0][:, None, :],
-                                   cin_ref[0, 0])
+        base = cin_ref[0, 0]
+        if packed:
+            here = block(b, lengths_ref, table_ref, refs[:1])
+            prev = block(jnp.maximum(b - 1, 0), lengths_ref, table_ref,
+                         refs[:1])
+            same = (b > 0) & (here[0] == prev[0]) & (here[1] == prev[1])
+            base = jnp.where(same, cout_ref[0, 0], base)
+        cout_ref[0, 0] = jnp.where(row == r, new_ref[0][:, None, :], base)
 
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
-        input_output_aliases={4: 0},   # pool operand (after 3 scalars + new)
+        # the pool operand, after the scalars and ``new``
+        input_output_aliases={len(prefetch) + 1: 0},
         interpret=interpret,
-    )(rows, layer_arr, table.reshape(-1), new, pool)
+    )(*prefetch, new, pool)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("interpret", "packed"))
 def cache_write_row_quant_paged(pool: jnp.ndarray, scales: jnp.ndarray,
                                 new: jnp.ndarray, rows: jnp.ndarray,
                                 table: jnp.ndarray, layer: jnp.ndarray,
-                                interpret: bool = False):
+                                interpret: bool = False,
+                                packed: bool = False):
     """Quantizing paged row write: int8 pool + per-row scales, both aliased.
 
     pool: [L, P, Hkv, page, D] int8; scales: [L, P, Hkv, lanes >= page] f32
     (paged_kv.scale_lanes); new: [B, Hkv, D] float. Same quantizer as the
     dense kernel (kv_cache.quantize_rows) so prefilled and decoded rows are
-    interchangeable. Returns (pool, scales) — same buffers.
+    interchangeable. ``packed`` as in cache_write_row_paged (the scale block
+    is a whole page, so a run there is the steps of one page). Returns
+    (pool, scales) — same buffers.
     """
     L, P, Hkv, ps, D = pool.shape
     lanes = scales.shape[3]     # >= ps: lane-padded (paged_kv.scale_lanes)
@@ -1308,21 +1493,24 @@ def cache_write_row_quant_paged(pool: jnp.ndarray, scales: jnp.ndarray,
     MP = table.shape[1]
     S_v = MP * ps
     ROWS = 32 if ps % 32 == 0 else ps
+    prefetch = [rows, layer_arr, table.reshape(-1)]
+    if packed:
+        prefetch.append(_packed_write_src(rows, S_v))
 
-    def new_map(b, lens, lay, tab):
+    block = functools.partial(_write_block, MP=MP, ps=ps, ROWS=ROWS)
+
+    def new_map(b, *prefetched):
         return (b, 0, 0)
 
-    def blk_map(b, lens, lay, tab):
-        # tab: the table FLATTENED row-major (SMEM; see _paged_flash_db)
-        r = jnp.clip(lens[b], 0, S_v - 1)
-        return (lay[0], tab[b * MP + r // ps], 0, (r % ps) // ROWS, 0)
+    def blk_map(b, lens, lay, tab, *src):
+        pg, rb = block(b, lens, tab, src)
+        return (lay[0], pg, 0, rb, 0)
 
-    def scale_map(b, lens, lay, tab):
-        r = jnp.clip(lens[b], 0, S_v - 1)
-        return (lay[0], tab[b * MP + r // ps], 0, 0)
+    def scale_map(b, lens, lay, tab, *src):
+        return (lay[0], block(b, lens, tab, src)[0], 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=len(prefetch),
         grid=(new.shape[0],),
         in_specs=[
             pl.BlockSpec((1, Hkv, D), new_map),
@@ -1335,8 +1523,8 @@ def cache_write_row_quant_paged(pool: jnp.ndarray, scales: jnp.ndarray,
         ],
     )
 
-    def kernel(lengths_ref, layer_ref, table_ref, new_ref, cin_ref, sin_ref,
-               cout_ref, sout_ref):
+    def kernel(lengths_ref, layer_ref, table_ref, *refs):
+        new_ref, cin_ref, sin_ref, cout_ref, sout_ref = refs[-5:]
         b = pl.program_id(0)
         tgt = lengths_ref[b]
         in_window = (tgt >= 0) & (tgt < S_v)
@@ -1345,12 +1533,21 @@ def cache_write_row_quant_paged(pool: jnp.ndarray, scales: jnp.ndarray,
             quantize_rows)
 
         q8, sc = quantize_rows(new_ref[0])                    # [Hkv,D],[Hkv]
+        base, sbase = cin_ref[0, 0], sin_ref[0, 0]
+        if packed:
+            here = block(b, lengths_ref, table_ref, refs[:1])
+            prev = block(jnp.maximum(b - 1, 0), lengths_ref, table_ref,
+                         refs[:1])
+            same_page = (b > 0) & (here[0] == prev[0])
+            base = jnp.where(same_page & (here[1] == prev[1]),
+                             cout_ref[0, 0], base)
+            sbase = jnp.where(same_page, sout_ref[0, 0], sbase)
         row = jax.lax.broadcasted_iota(jnp.int32, (Hkv, ROWS, D), 1)
-        cout_ref[0, 0] = jnp.where(row == r, q8[:, None, :], cin_ref[0, 0])
+        cout_ref[0, 0] = jnp.where(row == r, q8[:, None, :], base)
         # scale block spans one whole page: target column = tgt % page
         rs = jax.lax.broadcasted_iota(jnp.int32, (Hkv, lanes), 1)
         tgt_col = jnp.where(in_window, jnp.clip(tgt, 0, S_v - 1) % ps, -1)
-        sout_ref[0, 0] = jnp.where(rs == tgt_col, sc[:, None], sin_ref[0, 0])
+        sout_ref[0, 0] = jnp.where(rs == tgt_col, sc[:, None], sbase)
 
     return pl.pallas_call(
         kernel,
@@ -1359,9 +1556,10 @@ def cache_write_row_quant_paged(pool: jnp.ndarray, scales: jnp.ndarray,
             jax.ShapeDtypeStruct(pool.shape, pool.dtype),
             jax.ShapeDtypeStruct(scales.shape, scales.dtype),
         ],
-        input_output_aliases={4: 0, 5: 1},  # pool, scales (3 scalars + new)
+        # pool and scales, after the scalars and ``new``
+        input_output_aliases={len(prefetch) + 1: 0, len(prefetch) + 2: 1},
         interpret=interpret,
-    )(rows, layer_arr, table.reshape(-1), new, pool, scales)
+    )(*prefetch, new, pool, scales)
 
 
 def supported() -> bool:

@@ -660,8 +660,10 @@ def mixed_step(cfg: ModelConfig, params, cache, tokens, lengths, ptokens,
     gave it — byte-identical streams either way (pinned by
     tests/test_decode_pipeline.py's ragged parity cases).
 
-    ``pslot``'s own decode row is a dead passenger while it chunks: its
-    K/V write is DROPPED (write row -1), it attends nothing (limit 0), and
+    ``pslot``'s own decode row is a dead passenger while it chunks, and so
+    is every chunk row at or past ``plen`` (the chunk arrives padded to C):
+    a dead row's K/V write is DROPPED (write row -1) and it attends nothing
+    (limit 0), which costs the ragged kernel nothing. For ``pslot``
     the returned carry overrides its lanes with the chunk's sample
     (``tok_out[pslot] = chunk token``, ``lens_out[pslot] = pstart + plen``)
     so the device carry matches the host mirrors a final-chunk activation
@@ -692,10 +694,14 @@ def mixed_step(cfg: ModelConfig, params, cache, tokens, lengths, ptokens,
     C = ptokens.shape[1]
     is_p = jnp.arange(B, dtype=jnp.int32) == pslot
     crows = pstart + jnp.arange(C, dtype=jnp.int32)
+    # the chunk is padded to C rows: rows past the prompt are dead too
+    is_pad = jnp.arange(C, dtype=jnp.int32) >= plen
     write_rows = jnp.concatenate(
-        [jnp.where(is_p, jnp.int32(-1), lengths), crows])
+        [jnp.where(is_p, jnp.int32(-1), lengths),
+         jnp.where(is_pad, jnp.int32(-1), crows)])
     row_limits = jnp.concatenate(
-        [jnp.where(is_p, jnp.int32(0), lengths + 1), crows + 1])
+        [jnp.where(is_p, jnp.int32(0), lengths + 1),
+         jnp.where(is_pad, jnp.int32(0), crows + 1)])
     row_tables = jnp.concatenate(
         [table, jnp.broadcast_to(table[pslot][None], (C, table.shape[1]))])
     packed = jnp.concatenate([tokens[None], ptokens], axis=1)     # [1, B+C]

@@ -300,23 +300,46 @@ def run_requests(srv: Server, model: str) -> dict:
     expected += 32
     say("requests: streamed /v1/chat/completions ok (32 tokens)")
 
-    # the long prompt again WHILE another stream decodes: its 17 full pages
-    # are a prefix-cache hit, and the suffix walk finds live decode rows, so
-    # it rides the ragged mixed program
-    started = threading.Event()
+    # Two admissions WHILE another stream decodes, so they ride the ragged
+    # mixed program. First a NEW 700-token prompt: no prefix to reuse, so
+    # all of it is one padded chunk (packed K/V writes, the ragged kernel's
+    # sharing blocks and dead rows); streamed with logprobs for the numerics
+    # phase, which no idle-engine prefill reaches. Then the long prompt
+    # again: its 17 full pages are a prefix-cache hit, the suffix is a chunk.
     bg: dict = {}
+    tokens_total = "tpu_serve_generated_tokens_total"
+
+    def generated() -> float:
+        status, raw = http_json(port, "GET", "/metrics")
+        check(status == 200, f"/metrics -> {status}")
+        return parse_metrics(raw.decode()).get(tokens_total, 0.0)
 
     def background():
+        t0 = time.monotonic()
         bg.update(http_stream(
             port, "/v1/completions",
             {"model": model, "prompt": prompt_of(40, 99), "stream": True,
-             "max_tokens": 256, "temperature": 0.0, "ignore_eos": True},
-            on_first=started.set))
-        started.set()
+             "max_tokens": 1900, "temperature": 0.0, "ignore_eos": True}))
+        bg["t_done"] = time.monotonic()
+        bg["seconds"] = bg["t_done"] - t0
 
+    base = generated()
     t = threading.Thread(target=background)
     t.start()
-    check(started.wait(600), "background stream never produced a token")
+    # the stream's own first chunk says nothing: a random-weight stream may
+    # hold its text to the end (server.py::_stream_completions), so wait for
+    # the engine's token counter instead
+    deadline = time.monotonic() + 600
+    while generated() < base + 16:
+        check(time.monotonic() < deadline and t.is_alive(),
+              "the background stream never got to decoding")
+        time.sleep(0.05)
+    body = {"model": model, "prompt": prompt_of(700, 55), "max_tokens": 24,
+            "temperature": 0.0, "ignore_eos": True}
+    t0 = time.monotonic()
+    r = http_stream(port, "/v1/completions",
+                    dict(body, stream=True, logprobs=0))
+    t_mixed = time.monotonic()
     status, raw = http_json(
         port, "POST", "/v1/completions",
         {"model": model, "prompt": prompt_of(1100, 4), "max_tokens": 32,
@@ -325,13 +348,24 @@ def run_requests(srv: Server, model: str) -> dict:
     check(status == 200
           and json.loads(raw)["usage"]["completion_tokens"] == 32,
           f"repeated long prompt: {status} {raw[:300]}")
+    check(r["status"] == 200 and r["done"] and len(r["token_ids"]) == 24
+          and len(r["logprobs"]) == 24,
+          f"m700: status {r['status']} tokens {len(r['token_ids'])}")
     check(bg.get("status") == 200 and bg["done"]
-          and len(bg["token_ids"]) == 256,
+          and len(bg["token_ids"]) == 1900,
           f"background stream: {bg.get('status')} "
           f"{len(bg.get('token_ids', []))} tokens")
-    expected += 32 + 256
+    check(bg["t_done"] > t_mixed,
+          f"the background stream ({bg['seconds']:.2f}s) ended before the "
+          f"700-token request did ({t_mixed - t0:.2f}s): it was not "
+          f"admitted under a live batch")
+    streams["m700"] = {"prompt": body["prompt"], "token_ids": r["token_ids"],
+                       "logprobs": r["logprobs"]}
+    expected += 32 + 24 + 1900
     ran |= dispatched(port)
-    say("requests: repeated 1100-token prompt beside a live stream ok")
+    say(f"requests: a new 700-token prompt ({t_mixed - t0:.2f}s) and the "
+        f"repeated 1100-token prompt beside a live stream of "
+        f"{bg['seconds']:.2f}s ok")
 
     status, raw = http_json(port, "GET", "/metrics")
     check(status == 200, f"/metrics -> {status}")
@@ -507,17 +541,22 @@ def kernel_parity(cfg, slots: int, window: int, page: int, bblocks,
         with jax.default_matmul_precision("highest"):
             ck, cv = dense_view(table)
             ref = decode_attend(q, ck, cv, lengths)
-        # ragged rows: every decode row, then 64 prefill-chunk rows of slot 3
-        # at positions 300..363 (limit = position + 1), as mixed_step packs
+        # ragged rows, as mixed_step packs them: every decode row (slot 3's
+        # is dead: it is the one chunking), then a 64-row chunk of slot 3 at
+        # positions 300.. of which 40 rows are prompt (limit = position + 1)
+        # and 24 are padding (limit 0: nothing fetched, output zero)
         C = 64
         crow = 300 + jnp.arange(C, dtype=jnp.int32)
-        limits = jnp.concatenate([lengths, crow + 1])
+        limits = jnp.concatenate(
+            [jnp.where(jnp.arange(B) == 3, 0, lengths),
+             jnp.where(jnp.arange(C) < 40, crow + 1, 0)])
         rtab = jnp.concatenate(
             [table, jnp.broadcast_to(table[3][None], (C, MP))])
         q3 = jax.random.normal(keys[3], (B + C, Hq, D), jnp.bfloat16)
         with jax.default_matmul_precision("highest"):
             ck, cv = dense_view(rtab)
             rref = decode_attend(q3[:, None], ck, cv, limits)[:, 0]
+        rref = jnp.where((limits > 0)[:, None, None], rref, 0)
         del ck, cv
         for bb in bblocks:
             out = pa.decode_attend_pallas_paged(
@@ -529,8 +568,13 @@ def kernel_parity(cfg, slots: int, window: int, page: int, bblocks,
                 interpret=interpret, bblock=bb, **skw)
             e2 = close(f"ragged_attend_pallas_paged {tag} bb={bb}", out,
                        rref)
+            check(not np.asarray(out, np.float32)[np.asarray(limits) == 0]
+                  .any(), f"ragged {tag} bb={bb}: a dead row is not zero")
             say(f"parity: decode/ragged paged {tag} bb={bb}: max abs err "
                 f"{e1:.2e} / {e2:.2e} (tol {KERNEL_TOL})")
+        if not interpret:
+            ragged_call_time(pa, pool, skw, table, lengths, layer, Hq, D,
+                             window, max(bblocks), tag)
 
         # write kernels: one new row per slot at each slot's length (the
         # full-window slot's row is out of range and must DROP)
@@ -549,8 +593,64 @@ def kernel_parity(cfg, slots: int, window: int, page: int, bblocks,
                                           layer, interpret=interpret)
             check(bool(jnp.array_equal(gk, want["k"])),
                   "cache_write_row_paged: rows differ from the scatter")
-        say(f"parity: paged write kernel {tag}: equal to the jnp scatter")
+        # the same kernels over mixed_step's packed rows: the chunk's 40 rows
+        # of slot 3 share 8- or 32-row blocks, its padding rows drop
+        prow = jnp.concatenate(
+            [jnp.where(jnp.arange(B) == 3, -1, lengths),
+             jnp.where(jnp.arange(C) < 40, crow, -1)])
+        pnew = jax.random.normal(keys[5], (B + C, Hkv, D), jnp.bfloat16)
+        want = pkv.write_token_layer_paged(
+            pool, layer, prow, rtab, pnew[:, None], pnew[:, None], page)
+        if quant:
+            gk, gks = pa.cache_write_row_quant_paged(
+                pool["k"], pool["ks"], pnew, prow, rtab, layer,
+                interpret=interpret, packed=True)
+            close("cache_write_row_quant_paged packed scales", gks,
+                  want["ks"])
+        else:
+            gk = pa.cache_write_row_paged(pool["k"], pnew, prow, rtab, layer,
+                                          interpret=interpret, packed=True)
+        check(bool(jnp.array_equal(gk, want["k"])),
+              f"paged write kernel {tag}: mixed_step's packed rows differ "
+              f"from the scatter")
+        say(f"parity: paged write kernel {tag}: equal to the jnp scatter, "
+            f"one row a slot and mixed_step's packed rows")
         del pool, want, gk
+
+
+def ragged_call_time(pa, pool, skw, table, lengths, layer, Hq, D, chunk,
+                     bb, tag) -> None:
+    """Device time of ONE ragged call at the served mixed step's shape:
+    every slot's decode row plus a ``chunk``-row chunk of slot 3 holding a
+    640-token prompt (the closed cells' mean) — with every chunk row given a
+    causal limit, as mixed_step did before PR 25, and with the 1,408 padding
+    rows dead, as it does now."""
+    import jax
+    import jax.numpy as jnp
+
+    B, MP = table.shape
+    j = jnp.arange(chunk, dtype=jnp.int32)
+    rtab = jnp.concatenate(
+        [table, jnp.broadcast_to(table[3][None], (chunk, MP))])
+    q = jax.random.normal(jax.random.PRNGKey(5), (B + chunk, Hq, D),
+                          jnp.bfloat16)
+    decode = jnp.where(jnp.arange(B) == 3, 0, lengths)
+    for name, climit in (("every chunk row live", j + 1),
+                         ("padding rows dead", jnp.where(j < 640, j + 1, 0))):
+        limits = jnp.concatenate([decode, climit])
+
+        def call():
+            return pa.ragged_attend_pallas_paged(
+                q, pool["k"], pool["v"], limits, layer, rtab, bblock=bb,
+                **skw)
+
+        call().block_until_ready()
+        t0, n = time.monotonic(), 20
+        for _ in range(n):
+            out = call()
+        out.block_until_ready()
+        say(f"ragged call {tag} bb={bb}, {B}+{chunk} rows, 640-token "
+            f"chunk, {name}: {(time.monotonic() - t0) / n * 1e3:.3f} ms")
 
 
 # ---------------------------------------------------------------------------
@@ -761,11 +861,11 @@ def main() -> int:
         # device 0, teacher-forced through the plain model over the tokens
         # the sharded server produced
         params = single_device_params(cfg, serving)
-        for name in ("c70", "c30"):
+        for name in ("c70", "c30", "m700"):
             check_numerics(f"{name}, tp=4 vs one device", got[name], cfg,
                            params, tokenizer)
     else:
-        for name in ("c70", "c30"):
+        for name in ("c70", "c30", "m700"):   # m700: through mixed_step
             check_numerics(name, got[name], cfg, eng.params, tokenizer)
         srv.drain()
         if opts.rehearse:

@@ -435,6 +435,61 @@ def test_mixed_traffic_pipeline_stays_open_and_byte_identical(model):
 
 
 @pytest.mark.ragged_smoke
+def test_mixed_step_drops_the_padding_rows_of_a_short_chunk(model):
+    """A chunk shorter than the program's C rides padded: the padding rows
+    are dead (write row -1, limit 0), so a mixed dispatch changes NO pool
+    row of the chunking slot at or past ``off + len(chunk)`` — dropped, not
+    parked on the slot's later rows or its scratch page — while the rows
+    the chunk owns are written. Streams stay the legacy walk's."""
+    import numpy as np
+
+    def run(ragged, spy=None):
+        eng = _ragged_engine(model, ragged)
+        if spy is not None:
+            real = eng._mixed_dispatch
+
+            def spying(st, chunk, tok_in, len_in):
+                before = {n: np.asarray(a) for n, a in eng.cache.items()}
+                rec = real(st, chunk, tok_in, len_in)
+                spy.append((st["C"], st["off"], len(chunk),
+                            eng.table[st["slot"]].copy(), before,
+                            {n: np.asarray(a) for n, a in eng.cache.items()}))
+                return rec
+
+            eng._mixed_dispatch = spying
+        first = eng.submit(Request(prompt_ids=[5, 9, 2], max_tokens=60,
+                                   temperature=0.9, seed=42,
+                                   ignore_eos=True))
+        for _ in range(6):
+            eng.step()
+        assert eng._inflight is not None
+        late = eng.submit(Request(prompt_ids=list(_LONG_A), max_tokens=8,
+                                  temperature=0.9, seed=7, ignore_eos=True))
+        _drain(eng)
+        _assert_released(eng)
+        return first, late
+
+    seen: list = []
+    ragged, legacy = run(1, seen), run(0)
+    for r, s in zip(ragged, legacy):
+        assert _stream_bytes(r) == _stream_bytes(s)
+    short = [d for d in seen if d[2] < d[0]]
+    assert short, "no mixed dispatch carried a padded chunk (test is vacuous)"
+    for C, off, n, pages, before, after in short:
+        ps = before["k"].shape[3]
+        rows = np.arange(len(pages) * ps)
+
+        def view(pool, name):      # the slot's logical rows: [rows, L, Hkv, D]
+            return pool[name][:, pages[rows // ps], :, rows % ps]
+
+        for name in ("k", "v"):
+            was, now = view(before, name), view(after, name)
+            assert np.array_equal(was[off + n:], now[off + n:]), \
+                f"{name}: a padding row's write landed past row {off + n}"
+            assert not np.array_equal(was[off:off + n], now[off:off + n])
+
+
+@pytest.mark.ragged_smoke
 def test_ragged_vs_legacy_parity_sampled_logprobs_penalties(model):
     """Feature parity through the mixed program: sampled, logprobs, and
     penalties requests produce byte-identical streams ragged vs legacy."""

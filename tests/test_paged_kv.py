@@ -448,3 +448,32 @@ def test_gather_restore_roundtrip():
         untouched = [p for p in range(before[name].shape[1]) if p not in dst]
         np.testing.assert_array_equal(got[:, untouched],
                                       before[name][:, untouched])
+
+
+@pytest.mark.parametrize("quant", [False, True])
+def test_paged_write_row_kernel_packed_rows_of_one_slot(quant):
+    """serving/programs.mixed_step's layout: one row per slot, then a run of
+    rows of ONE slot (consecutive grid steps on one 8- or 32-row block),
+    then dropped rows — against the jnp scatter. Merging into the input copy
+    of the block kept one row of each run (my chip run, PR 25)."""
+    _, pool, table = _identity_layout(quant=quant, perm_seed=29)
+    tab = np.asarray(table)
+    rows = jnp.asarray([5, -1, SV - 1] + list(range(3, 3 + 40)) + [-1] * 5,
+                       jnp.int32)
+    N = rows.shape[0]
+    rtab = jnp.asarray(np.concatenate([tab, np.repeat(tab[1:2], N - B, 0)]))
+    new = jax.random.normal(jax.random.PRNGKey(13), (N, 2, 16))
+    layer = jnp.int32(1)
+    want = pkv.write_token_layer_paged(pool, layer, rows, rtab, new[:, None],
+                                       new[:, None], PS)
+    if quant:
+        pk, pks = pa.cache_write_row_quant_paged(
+            pool["k"], pool["ks"], new, rows, rtab, layer, interpret=True,
+            packed=True)
+        # the scales: one ulp between the kernel's division and XLA's
+        np.testing.assert_allclose(np.asarray(pks), np.asarray(want["ks"]),
+                                   rtol=1e-6)
+    else:
+        pk = pa.cache_write_row_paged(pool["k"], new, rows, rtab, layer,
+                                      interpret=True, packed=True)
+    np.testing.assert_array_equal(np.asarray(pk), np.asarray(want["k"]))

@@ -417,3 +417,67 @@ def test_paged_db_poisoned_dead_pages_ignored():
                                         interpret=True, bblock=4)
     np.testing.assert_allclose(np.asarray(out), np.asarray(base),
                                rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# The ragged entry: a packed batch as serving/programs.mixed_step lays it out
+# — B decode rows, then C chunk rows of ONE slot at pstart + j; rows past
+# the prompt (j >= plen) and the chunking slot's own decode row are DEAD
+# (limit 0). Blocks of chunk rows share one page stream; a block straddling
+# decode and chunk rows (B % bb != 0) streams per row.
+# ---------------------------------------------------------------------------
+
+_RAGGED_GRID = [
+    pytest.param(quant, bb, window, B, 0, 11,
+                 id=f"{'int8' if quant else 'f32'}-bb{bb}-w{window}-"
+                    f"{'aligned' if B % 8 == 0 else 'straddle'}")
+    for quant in (False, True) for bb in (1, 8) for window in (0, 48)
+    for B in (8, 4)
+] + [
+    pytest.param(False, 8, 0, 8, 0, 16, id="f32-bb8-no-dead-row"),
+    pytest.param(False, 8, 0, 8, 70, 11, id="f32-bb8-pstart-70"),
+    pytest.param(True, 8, 48, 4, 70, 16, id="int8-bb8-w48-straddle-pstart-70"),
+]
+
+
+@pytest.mark.parametrize("quant,bb,window,B,pstart,plen", _RAGGED_GRID)
+def test_ragged_mixed_layout_parity(quant, bb, window, B, pstart, plen):
+    from aws_k8s_ansible_provisioner_tpu.ops import pallas_attention as pa
+    from aws_k8s_ansible_provisioner_tpu.serving import kv_cache as kvc
+
+    C, pslot, S, PS, Hq, D = 16 if B == 8 else 20, 2, 128, 32, 4, 32
+    dense, pool, table = _paged_layout(B=B, S=S, PS=PS, quant=quant, seed=53)
+    tab = np.asarray(table)
+    # one more page, all NaN, that no table names: an out-of-range id clamps
+    # onto it in interpret mode, so a fetch through a dead row's entry shows
+    pool = {n: jnp.concatenate([a, jnp.full_like(a[:, :1], jnp.nan)
+                                if a.dtype == jnp.float32
+                                else jnp.full_like(a[:, :1], 127)], axis=1)
+            for n, a in pool.items()}
+    lengths = np.asarray([1, 128, 0, 64, 33, 97, 2, 128][:B], np.int32)
+    j = np.arange(C)
+    limits = np.concatenate([np.where(np.arange(B) == pslot, 0, lengths),
+                             np.where(j < plen, pstart + j + 1, 0)])
+    rows_of = np.concatenate([np.arange(B), np.full(C, pslot)])
+    row_tables = tab[rows_of].copy()
+    row_tables[limits == 0] = 10_000          # dead rows: never a page id
+    N = B + C
+    q = jax.random.normal(jax.random.PRNGKey(7), (N, Hq, D))
+
+    ck, cv = dense["k"][0][rows_of], dense["v"][0][rows_of]
+    if quant:
+        ck = kvc.dequantize(ck, dense["ks"][0][rows_of])
+        cv = kvc.dequantize(cv, dense["vs"][0][rows_of])
+    ref = decode_attend(q[:, None], ck, cv, jnp.asarray(limits),
+                        window=window)[:, 0]
+    pkw = dict(pool_ks=pool["ks"], pool_vs=pool["vs"]) if quant else {}
+    out = pa.ragged_attend_pallas_paged(
+        q, pool["k"], pool["v"], jnp.asarray(limits), jnp.int32(0),
+        jnp.asarray(row_tables), interpret=True, window=window, bblock=bb,
+        **pkw)
+    out, live = np.asarray(out), limits > 0
+    assert live.sum() == B - 1 + plen
+    tol = 4e-2 if quant else 2e-5
+    np.testing.assert_allclose(out[live], np.asarray(ref)[live],
+                               rtol=tol, atol=tol)
+    assert np.array_equal(out[~live], np.zeros_like(out[~live]))
