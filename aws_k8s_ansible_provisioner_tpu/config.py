@@ -36,6 +36,10 @@ class ModelConfig:
 
     - ``norm``: "rmsnorm" (Qwen) or "layernorm" (Phi/OPT, with bias).
     - ``qk_norm``: per-head RMSNorm on q/k projections (Qwen3 innovation).
+    - ``qk_norm_span``: what one q/k RMSNorm runs over when ``qk_norm`` is
+      set: "head" (Qwen3: each head's ``head_dim`` after the split, weight
+      ``[head_dim]``) or "projection" (OLMoE: the whole q / k projection
+      BEFORE the split into heads, weights ``[q_size]`` / ``[kv_size]``).
     - ``parallel_block``: Phi-style parallel attention+MLP residual block.
     - ``rotary_pct``: fraction of head_dim that is rotated (Phi-2 uses 0.4);
       1.0 means full-dim RoPE (Qwen).
@@ -79,6 +83,7 @@ class ModelConfig:
     norm_zero_centered: bool = False
     embed_scale: bool = False
     qk_norm: bool = False
+    qk_norm_span: str = "head"
     # "silu" (SwiGLU, Qwen/Llama) and "gelu_tanh" (GeGLU, Gemma) are GATED
     # two-projection MLPs; "gelu_new"/"relu" are plain two-matmul MLPs.
     act: str = "silu"
@@ -355,9 +360,38 @@ QWEN3_30B_A3B = ModelConfig(
     hf_repo="Qwen/Qwen3-30B-A3B",
 )
 
+# allenai OLMoE (HF ``OlmoeForCausalLM``): every layer's FFN is a router over
+# 64 SwiGLU experts of width 1,024 (config.json's ``intermediate_size`` IS the
+# expert width), top-8 with the softmax weights used as they are, MHA, and the
+# q/k RMSNorm over the whole projection. config.json has no head_dim key
+# (hidden / heads) and no bos id.
+OLMOE_1B_7B_0125_INSTRUCT = ModelConfig(
+    name="allenai/OLMoE-1B-7B-0125-Instruct",
+    vocab_size=50304,
+    hidden_size=2048,
+    intermediate_size=1024,
+    num_layers=16,
+    num_heads=16,
+    num_kv_heads=16,
+    head_dim=128,
+    max_seq_len=4096,
+    rope_theta=10000.0,
+    norm_eps=1e-5,
+    qk_norm=True,
+    qk_norm_span="projection",
+    tie_embeddings=False,
+    eos_token_id=50279,
+    num_experts=64,
+    num_experts_per_tok=8,
+    moe_intermediate_size=1024,
+    norm_topk_prob=False,
+    hf_repo="allenai/OLMoE-1B-7B-0125-Instruct",
+)
+
 MODEL_REGISTRY = {
     "Qwen/Qwen3-0.6B": QWEN3_0_6B,
     "Qwen/Qwen3-30B-A3B": QWEN3_30B_A3B,
+    "allenai/OLMoE-1B-7B-0125-Instruct": OLMOE_1B_7B_0125_INSTRUCT,
     "Qwen/Qwen3-8B": QWEN3_8B,
     "microsoft/phi-2": PHI_2,
     "facebook/opt-125m": OPT_125M,
@@ -418,6 +452,34 @@ def tiny_qwen3_moe(**overrides) -> ModelConfig:
         num_experts=8,
         num_experts_per_tok=2,
         moe_intermediate_size=32,
+    )
+    base.update(overrides)
+    return ModelConfig(**base)
+
+
+def tiny_olmoe(**overrides) -> ModelConfig:
+    """A miniature OLMoE-shaped config: MHA, q/k RMSNorm over the whole
+    projection, top-2 of 8 experts with the softmax weights as they are."""
+    base = dict(
+        name="tiny-olmoe",
+        vocab_size=128,
+        hidden_size=64,
+        intermediate_size=32,
+        num_layers=2,
+        num_heads=4,
+        num_kv_heads=4,
+        head_dim=16,
+        max_seq_len=128,
+        rope_theta=10000.0,
+        norm_eps=1e-5,
+        qk_norm=True,
+        qk_norm_span="projection",
+        tie_embeddings=False,
+        eos_token_id=1,
+        num_experts=8,
+        num_experts_per_tok=2,
+        moe_intermediate_size=32,
+        norm_topk_prob=False,
     )
     base.update(overrides)
     return ModelConfig(**base)

@@ -10,6 +10,9 @@ checkpoint never materializes unsharded on one host (SURVEY.md §7 hard part #3)
 Key-name maps cover the supported families:
 - Qwen3*: ``model.layers.N.self_attn.{q,k,v,o}_proj``, ``q_norm``/``k_norm``,
   gated ``mlp.{gate,up,down}_proj``, RMSNorm weights.
+- Qwen3-MoE and OLMoE: router ``mlp.gate``, experts
+  ``mlp.experts.{e}.{gate,up,down}_proj``; OLMoE's ``q_norm``/``k_norm``
+  span the whole projection (``[q_size]`` / ``[kv_size]``).
 - Phi-2: ``self_attn.dense``, ``mlp.fc1/fc2`` with biases, LayerNorm
   weight+bias, ``lm_head`` with bias, no post-attention norm (parallel block).
 - OPT (pre-norm variants): ``model.decoder.layers.N.self_attn.*_proj``,
@@ -242,6 +245,40 @@ def config_from_hf_dir(checkpoint_dir: str) -> ModelConfig:
             num_experts_per_tok=hf["num_experts_per_tok"],
             moe_intermediate_size=hf["moe_intermediate_size"],
             norm_topk_prob=hf.get("norm_topk_prob", True),
+            hf_repo=name,
+        )
+    if model_type == "olmoe":
+        # allenai OLMoE: every layer sparse, ``intermediate_size`` is the
+        # width of ONE expert, q/k RMSNorm over the whole projection, top-k
+        # weights as the softmax gives them unless norm_topk_prob says so.
+        # Weight names are Qwen3-MoE's (mlp.gate, mlp.experts.{e}.*_proj,
+        # self_attn.{q,k}_norm at [q_size] / [kv_size]).
+        if hf.get("clip_qkv") is not None:
+            raise ValueError("olmoe checkpoints with clip_qkv set are not "
+                             "supported")
+        return ModelConfig(
+            name=name,
+            vocab_size=hf["vocab_size"],
+            hidden_size=hf["hidden_size"],
+            intermediate_size=hf["intermediate_size"],
+            num_layers=hf["num_hidden_layers"],
+            num_heads=hf["num_attention_heads"],
+            num_kv_heads=hf.get("num_key_value_heads")
+            or hf["num_attention_heads"],
+            head_dim=hf["hidden_size"] // hf["num_attention_heads"],
+            max_seq_len=hf.get("max_position_embeddings", 4096),
+            rope_theta=hf.get("rope_theta", 10000.0),
+            qk_norm=True,
+            qk_norm_span="projection",
+            norm_eps=hf.get("rms_norm_eps", 1e-5),
+            attention_bias=hf.get("attention_bias", False),
+            tie_embeddings=hf.get("tie_word_embeddings", False),
+            bos_token_id=hf.get("bos_token_id"),
+            eos_token_id=(hf.get("eos_token_id") or 0),
+            num_experts=hf["num_experts"],
+            num_experts_per_tok=hf["num_experts_per_tok"],
+            moe_intermediate_size=hf["intermediate_size"],
+            norm_topk_prob=hf.get("norm_topk_prob", False),
             hf_repo=name,
         )
     if model_type == "qwen3":

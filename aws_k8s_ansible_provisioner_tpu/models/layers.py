@@ -238,8 +238,11 @@ def init_layer_params(cfg: ModelConfig, key: jax.Array, dtype) -> dict:
         "wo": dense(ks[3], cfg.q_size, H, cfg.attention_bias),
     }
     if cfg.qk_norm:
-        params["q_norm"] = {"weight": jnp.ones((L, cfg.head_dim), dtype)}
-        params["k_norm"] = {"weight": jnp.ones((L, cfg.head_dim), dtype)}
+        whole = cfg.qk_norm_span == "projection"
+        params["q_norm"] = {"weight": jnp.ones(
+            (L, cfg.q_size if whole else cfg.head_dim), dtype)}
+        params["k_norm"] = {"weight": jnp.ones(
+            (L, cfg.kv_size if whole else cfg.head_dim), dtype)}
     if cfg.num_experts > 0:  # MoE (Qwen3-MoE): router + stacked expert FFNs
         E, Im = cfg.num_experts, cfg.moe_intermediate_size
         params["router"] = {"kernel": _dense_init(ks[7], (L, H, E), dtype)}
@@ -378,10 +381,15 @@ def decoder_block(cfg: ModelConfig, p: dict, x: jnp.ndarray,
     rotary_dim = int(cfg.head_dim * cfg.rotary_pct)
 
     h = apply_norm(cfg, x, p["input_norm"])
-    q = _linear(h, p["wq"]).reshape(B, T, cfg.num_heads, cfg.head_dim)
-    k = _linear(h, p["wk"]).reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
+    q, k = _linear(h, p["wq"]), _linear(h, p["wk"])
+    whole = cfg.qk_norm and cfg.qk_norm_span == "projection"
+    if whole:  # OLMoE: RMSNorm over the whole projection, before the split
+        q = rms_norm(q, p["q_norm"]["weight"], cfg.norm_eps)
+        k = rms_norm(k, p["k_norm"]["weight"], cfg.norm_eps)
+    q = q.reshape(B, T, cfg.num_heads, cfg.head_dim)
+    k = k.reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
     v = _linear(h, p["wv"]).reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
-    if cfg.qk_norm:  # per-head RMSNorm on q/k (Qwen3)
+    if cfg.qk_norm and not whole:  # per-head RMSNorm on q/k (Qwen3)
         q = rms_norm(q, p["q_norm"]["weight"], cfg.norm_eps)
         k = rms_norm(k, p["k_norm"]["weight"], cfg.norm_eps)
     if cfg.pos_embed == "rope":
@@ -499,12 +507,16 @@ def model_forward_carry(
     amortizes over many tokens).
     """
     x, cos, sin = _embed_inputs(params, cfg, tokens, positions)
+    from aws_k8s_ansible_provisioner_tpu.ops import moe
 
     def body(carry, p_l):
         x, cache, l = carry
         x, (cache, _) = decoder_block(cfg, p_l, x, cos, sin, attend, (cache, l))
-        return (x, cache, l + 1), None
+        # an MoE layer traced under ops.moe.routed_rows leaves its routing
+        # counts; they leave the scan stacked [L, 2] (None otherwise)
+        return (x, cache, l + 1), moe.take_layer_stats()
 
-    (x, cache, _), _ = jax.lax.scan(
+    (x, cache, _), per_layer = jax.lax.scan(
         body, (x, cache, jnp.int32(0)), params["layers"])
+    moe.put_stats(per_layer)
     return _final_logits(params, cfg, x), cache

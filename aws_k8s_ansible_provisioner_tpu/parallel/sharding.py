@@ -14,7 +14,8 @@ Layout summary (weights are ``[in, out]``, layers stacked on a leading L axis):
 - embedding table: vocab-sharded over ``tp`` (tied logits come out
   vocab-sharded, exactly what the loss wants); untied ``lm_head``: vocab-
   sharded on the output dim.
-- norms and per-head q/k norms: replicated (tiny).
+- norms and q/k norms (per head, or OLMoE's whole-projection form): replicated
+  (tiny).
 - token/position arrays: batch over ``dp``, sequence over ``sp``.
 - decode KV cache ``[L, slots, Hkv, S, D]``: kv heads over ``tp``, slots over
   ``dp`` (each data-parallel group owns its slots).

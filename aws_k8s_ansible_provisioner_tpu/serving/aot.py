@@ -301,6 +301,10 @@ def enumerate_programs(plan, mesh, params, cache, bblock: int = 1):
              bias_vals=sds((BIAS_K,), f32), rep=sds((), f32),
              rep_seen=sds((cfg.vocab_size,), jnp.bool_))))
 
+    # an MoE model's decode and mixed programs take the live-slot mask
+    # (EnginePrograms._live_rows); a dense model's take no such operand
+    live = sds((B,), jnp.bool_) if cfg.num_experts > 0 else None
+
     def decode_kwargs(penalties=False, logprobs=False):
         kw = dict(
             mesh=mesh, impl=serving.attention_impl, logprobs=logprobs,
@@ -308,7 +312,7 @@ def enumerate_programs(plan, mesh, params, cache, bblock: int = 1):
             table=sds((B, pps), i32) if plan.paged else None,
             seeds=sds((B,), u32), ban_ids=sds((B, BAN_K), i32),
             ban_until=sds((B,), i32), bias_ids=sds((B, BIAS_K), i32),
-            bias_vals=sds((B, BIAS_K), f32), bblock=bblock)
+            bias_vals=sds((B, BIAS_K), f32), bblock=bblock, live=live)
         if penalties:
             kw.update(counts=sds((B, cfg.vocab_size), i32),
                       presence=sds((B,), f32), frequency=sds((B,), f32),
@@ -348,7 +352,7 @@ def enumerate_programs(plan, mesh, params, cache, bblock: int = 1):
             table=sds((B, pps), i32), seeds=sds((B,), u32),
             ban_ids=sds((B, BAN_K), i32), ban_until=sds((B,), i32),
             bias_ids=sds((B, BIAS_K), i32),
-            bias_vals=sds((B, BIAS_K), f32), bblock=bblock)
+            bias_vals=sds((B, BIAS_K), f32), bblock=bblock, live=live)
         programs.append((f"mixed_c{plan.chunk}", mixed_step,
                          mixed_args, mixed_kwargs))
         if serving.ragged_features > 0:

@@ -130,7 +130,7 @@ class DraftModel:
                  if g == 1 and int(self.lens[s]) + K < self.max_len]
         if not ready:
             return None
-        self.cache, _, out, _, _ = decode_steps(
+        self.cache, _, out, _, _, _ = decode_steps(
             self.cfg, K, self.params, self.cache,
             jnp.asarray(engine.last_token), jnp.asarray(self.lens),
             engine._next_rng(),

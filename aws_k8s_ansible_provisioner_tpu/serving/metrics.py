@@ -240,6 +240,28 @@ class EngineMetrics:
         self.device_busy_seconds = r.register(Counter(
             "tpu_serve_device_busy_seconds_total",
             "Seconds spent in device dispatches (duty-cycle source)"))
+        # MoE models only (the counters stay empty for a dense model): what
+        # the expert layers of the decode and mixed dispatches were given,
+        # from the dispatch record (programs._dispatch_close). Bytes an MoE
+        # step streams follow the experts HIT, not the expert count:
+        # experts_hit_total / forward_passes_total is the mean a layer
+        # streamed, routed_rows_total / forward_passes_total the rows it had.
+        self.moe_routed_rows = r.register(Counter(
+            "tpu_serve_moe_routed_rows_total",
+            "(token, expert) rows of live tokens routed through the expert "
+            "layers, per layer, by step program", ("program",)))
+        self.moe_experts_hit = r.register(Counter(
+            "tpu_serve_moe_experts_hit_total",
+            "Experts with at least one live row, mean over layers, summed "
+            "over forward passes, by step program", ("program",)))
+        self.moe_forward_passes = r.register(Counter(
+            "tpu_serve_moe_forward_passes_total",
+            "Forward passes (decode substeps, mixed steps) the two MoE "
+            "totals above sum over, by step program", ("program",)))
+        self.moe_group_rows_max = r.register(Gauge(
+            "tpu_serve_moe_group_rows_max",
+            "Rows of the largest expert group in the last decode or mixed "
+            "dispatch"))
         self.prefix_cache_hits = r.register(Counter(
             "tpu_serve_prefix_cache_hits_total",
             "Requests that reused a cached prompt prefix"))

@@ -52,6 +52,7 @@ from aws_k8s_ansible_provisioner_tpu.ops.attention import (
     make_spec_attend_carry,
     make_spec_attend_carry_paged,
 )
+from aws_k8s_ansible_provisioner_tpu.ops import moe as _moe
 from aws_k8s_ansible_provisioner_tpu.ops.sampling import (apply_allow,
                                                            apply_penalties,
                                                            per_slot_keys,
@@ -371,6 +372,15 @@ def _restore_count_row(counts, slot, row):
         counts, row[None].astype(counts.dtype), (slot, jnp.int32(0)))
 
 
+def _moe_summary(stats):
+    """int32 [..., L, 2] per-layer (experts hit, largest group) → float32
+    [2]: mean experts hit, largest group anywhere. None stays None."""
+    if stats is None:
+        return None
+    return jnp.stack([stats[..., 0].astype(jnp.float32).mean(),
+                      stats[..., 1].max().astype(jnp.float32)])
+
+
 @partial(jax.jit, static_argnums=(0,),
          static_argnames=("logprobs", "prompt_logprobs"),
          donate_argnums=(2,))
@@ -548,14 +558,18 @@ def decode_steps(cfg: ModelConfig, n_steps: int, params, cache, tokens,
                  penalties: bool = False, table=None, seeds=None,
                  ban_ids=None, ban_until=None, bias_ids=None,
                  bias_vals=None, allow=None, lora_idx=None,
-                 bblock: int = 1):
+                 bblock: int = 1, live=None):
     """``n_steps`` fused decode steps for every slot, one device dispatch.
 
     tokens/lengths/sampling params: [B]. Returns
-    (cache, counts, out [n_steps, B], last_tok [B], lens [B]) — the final
-    token/length carry stays device-resident so a pipelined engine can feed
-    dispatch N's carry straight into dispatch N+1 (donated, no host
-    round-trip; see EnginePrograms._decode_dispatch).
+    (cache, counts, out [n_steps, B], last_tok [B], lens [B], moe) — the
+    final token/length carry stays device-resident so a pipelined engine can
+    feed dispatch N's carry straight into dispatch N+1 (donated, no host
+    round-trip; see EnginePrograms._decode_dispatch). ``moe`` is None for a
+    dense model; for an MoE model ``live`` [B] bool marks the slots that
+    hold a request (an idle slot's row is routed to no expert) and ``moe``
+    is float32 [2]: experts with a live row (mean over steps and layers)
+    and the rows of the largest group (``_moe_summary``).
 
     Fusing the token loop into one ``lax.scan`` is a TPU-first scheduling
     decision: per-dispatch host→device latency (worst over a network-attached
@@ -584,8 +598,9 @@ def decode_steps(cfg: ModelConfig, n_steps: int, params, cache, tokens,
             attend = make_decode_attend_carry(lens, impl=impl, mesh=mesh,
                                               window=cfg.sliding_window,
                                               bblock=bblock)
-        logits, cache = model_forward_carry(params, cfg, tok[:, None],
-                                            positions, cache, attend)
+        with _moe.routed_rows(live) as routing:
+            logits, cache = model_forward_carry(params, cfg, tok[:, None],
+                                                positions, cache, attend)
         step_logits = logits[:, 0, :]
         if penalties:
             # presence/frequency/repetition over the [B, V] generated-token
@@ -617,17 +632,18 @@ def decode_steps(cfg: ModelConfig, n_steps: int, params, cache, tokens,
         if penalties:
             cnts = cnts.at[jnp.arange(cnts.shape[0]), nxt].add(1)
         if logprobs:
-            return (cache, cnts, nxt, lens + 1), (
-                nxt, _logprob_topk(step_logits, nxt))
-        return (cache, cnts, nxt, lens + 1), nxt
+            nxt_out = (nxt, _logprob_topk(step_logits, nxt))
+        else:
+            nxt_out = nxt
+        return (cache, cnts, nxt, lens + 1), (nxt_out, routing["stats"])
 
     if counts is None:
         counts = jnp.zeros((tokens.shape[0], 1), jnp.int32)  # unused dummy
     rngs = jax.random.split(rng, n_steps)
     with lora_context(lora_idx):
-        (cache, counts, tok, lens), out = jax.lax.scan(
+        (cache, counts, tok, lens), (out, moe) = jax.lax.scan(
             body, (cache, counts, tokens, lengths), rngs)
-    return cache, counts, out, tok, lens
+    return cache, counts, out, tok, lens, _moe_summary(moe)
 
 
 @partial(jax.jit, static_argnums=(0,),
@@ -642,7 +658,8 @@ def mixed_step(cfg: ModelConfig, params, cache, tokens, lengths, ptokens,
                frequency=None, repetition=None, prompt_mask=None,
                penalties: bool = False, table=None, seeds=None,
                ban_ids=None, ban_until=None, bias_ids=None, bias_vals=None,
-               allow=None, pallow=None, lora_idx=None, bblock: int = 1):
+               allow=None, pallow=None, lora_idx=None, bblock: int = 1,
+               live=None):
     """ONE ragged dispatch serving a mixed batch: a decode step for every
     active slot AND one prefill chunk of slot ``pslot`` — the program that
     lets the one-deep pipeline ride across prefill admissions instead of
@@ -688,7 +705,10 @@ def mixed_step(cfg: ModelConfig, params, cache, tokens, lengths, ptokens,
     per-token branch).
 
     Returns (cache, counts, out [1, B] (+logprobs), chunk token [1]
-    (+chunk logprobs), tok_carry [B], lens_carry [B]).
+    (+chunk logprobs), tok_carry [B], lens_carry [B], moe). For an MoE model
+    ``live`` [B] bool marks the slots that hold a request; dead passengers
+    and the chunk's padding rows are routed to no expert, and ``moe`` is
+    decode_steps' pair over the packed rows that carry a token.
     """
     B = tokens.shape[0]
     C = ptokens.shape[1]
@@ -718,7 +738,10 @@ def mixed_step(cfg: ModelConfig, params, cache, tokens, lengths, ptokens,
     if lora_idx is not None:
         packed_lora = jnp.concatenate(
             [lora_idx, jnp.broadcast_to(lora_idx[pslot], (C,))])[None]
-    with lora_context(packed_lora):
+    packed_live = None
+    if live is not None:
+        packed_live = jnp.concatenate([live & ~is_p, ~is_pad])
+    with lora_context(packed_lora), _moe.routed_rows(packed_live) as routing:
         logits, cache = model_forward_carry(params, cfg, packed, positions,
                                             cache, attend)
     # -- decode rows: the decode_steps substep body, verbatim order --------
@@ -759,7 +782,8 @@ def mixed_step(cfg: ModelConfig, params, cache, tokens, lengths, ptokens,
     out = (nxt[None], tuple(a[None] for a in _logprob_topk(dec_logits, nxt))) \
         if logprobs else nxt[None]
     pout = (ptok, _logprob_topk(plast, ptok)) if chunk_logprobs else ptok
-    return cache, counts, out, pout, tok_out, lens_out
+    return cache, counts, out, pout, tok_out, lens_out, \
+        _moe_summary(routing["stats"])
 
 
 @partial(jax.jit, static_argnums=(0, 1), static_argnames=("impl", "mesh",
@@ -1358,6 +1382,20 @@ class EnginePrograms:
         self._rng, sub = jax.random.split(self._rng)
         return sub
 
+    def _live_rows(self, active):
+        """[B] bool device mask of the decode rows that hold a request, for
+        an MoE model's step programs (None for a dense model: no operand);
+        re-uploaded only when the active set changed."""
+        if self.cfg.num_experts <= 0:
+            return None
+        key = tuple(active)
+        oc = self._op_cache
+        if oc.get("live_key") != key:
+            mask = np.zeros(self.num_slots, bool)
+            mask[list(active)] = True
+            oc["live_key"], oc["live"] = key, jnp.asarray(mask)
+        return oc["live"]
+
     # -- the dispatch record -------------------------------------------------
 
     def _dispatch_open(self, program: str, kind: str, active=(),
@@ -1370,7 +1408,13 @@ class EnginePrograms:
         (horizon, chunk_rows, chunk_n, chunk_off, bucket, rows,
         prompt_tokens, padded_tokens, carry_steps = steps of an unfetched
         predecessor the device-side lengths are ahead of the mirrors by).
-        Closed by ``_dispatch_close`` on the blocking half."""
+        Closed by ``_dispatch_close`` on the blocking half. For an MoE
+        model ``_decode_fetch`` adds, to the decode and mixed records,
+        ``moe_rows`` ((token, expert) rows of live tokens per layer: k x
+        (horizon x active + chunk_n); padding rows and idle slots are not
+        routed), ``moe_experts_hit`` (experts with a live row, mean over
+        layers and substeps) and ``moe_group_max`` (rows of the largest
+        group), the last two from the program's own output."""
         n = len(active)
         lens = self.lengths[list(active)] if n else None
         return {"seq": next(_DISPATCH_SEQ), "program": program, "kind": kind,
@@ -1397,6 +1441,13 @@ class EnginePrograms:
         rec["tail"] = tail
         rec["emitted"] = emitted
         self.metrics.device_busy_seconds.inc(device_s)
+        if "moe_rows" in rec:
+            m, prog = self.metrics, rec["program"]
+            m.moe_routed_rows.inc(rec["moe_rows"], program=prog)
+            m.moe_experts_hit.inc(rec["moe_experts_hit"] * steps,
+                                  program=prog)
+            m.moe_forward_passes.inc(steps, program=prog)
+            m.moe_group_rows_max.set(rec["moe_group_max"])
         _devmon.note(rec["kind"], device_s, batch=batch, tokens=tokens,
                      ctx_rows=ctx_rows, steps=steps, guided_rows=guided_rows)
         _flight.record("dispatch", None, **rec)
@@ -1996,7 +2047,7 @@ class EnginePrograms:
         self._book_bubble(drec["t_enqueue"])
         real_counts = self.counts
         with _Dispatching(drec):
-            self.cache, new_counts, out, pout, tok, lens = mixed_step(
+            self.cache, new_counts, out, pout, tok, lens, moe = mixed_step(
                 self.cfg, self.params, self.cache, tok_in, len_in, *args,
                 mesh=self.mesh, impl=self.serving.attention_impl,
                 logprobs=want_lp, chunk_logprobs=chunk_lp,
@@ -2015,7 +2066,8 @@ class EnginePrograms:
                 allow=allow,
                 pallow=pallow,
                 lora_idx=oc["lora"],
-                bblock=self.decode_bblock)
+                bblock=self.decode_bblock,
+                live=self._live_rows(active))
         self.counts = new_counts if want_pen else real_counts
         self._pipe_carry = (tok, lens, self._carry_gen)
         _metrics.pipeline.dispatches.inc()
@@ -2023,7 +2075,8 @@ class EnginePrograms:
                 "active": active, "gset": frozenset(gslots),
                 "gslots": gslots,
                 "want_lp": want_lp, "chunk_lp": chunk_lp,
-                "want_pen": want_pen, "chunk_n": len(chunk), "drec": drec}
+                "want_pen": want_pen, "chunk_n": len(chunk), "drec": drec,
+                "moe": moe}
 
     def _book_bubble(self, t_enqueue: float) -> None:
         """The device has sat idle since the previous fetch completed with
@@ -2535,7 +2588,7 @@ class EnginePrograms:
         self._book_bubble(drec["t_enqueue"])
         real_counts = self.counts
         with _Dispatching(drec):
-            self.cache, new_counts, out, tok, lens = decode_steps(
+            self.cache, new_counts, out, tok, lens, moe = decode_steps(
                 self.cfg, horizon, self.params, self.cache, tok_in, len_in,
                 rng, oc["temps"], oc["top_ks"], oc["top_ps"],
                 mesh=self.mesh, impl=self.serving.attention_impl,
@@ -2554,14 +2607,15 @@ class EnginePrograms:
                 bias_vals=oc["bias_vals"],
                 allow=allow,
                 lora_idx=oc["lora"],
-                bblock=self.decode_bblock)
+                bblock=self.decode_bblock,
+                live=self._live_rows(active))
         # un-penalized dispatches return a dummy counts array — keep ours
         self.counts = new_counts if want_pen else real_counts
         self._pipe_carry = (tok, lens, self._carry_gen)
         _metrics.pipeline.dispatches.inc()
         return {"out": out, "horizon": horizon, "active": list(active),
                 "gset": gset, "gslots": gslots, "want_lp": want_lp,
-                "want_pen": want_pen, "drec": drec}
+                "want_pen": want_pen, "drec": drec, "moe": moe}
 
     def _decode_fetch(self, rec: dict, tail: bool) -> None:
         """Blocking half of a decode dispatch: transfer the sampled tokens,
@@ -2618,6 +2672,15 @@ class EnginePrograms:
                     rec["chunk_lp_t"] = tuple(np.asarray(a) for a in plp)
                 else:
                     rec["chunk_token"] = int(np.asarray(pout)[0])
+            if rec.get("moe") is not None:
+                # routing counts of an MoE model ride the same fetch: the
+                # program has ended, this is a copy of two floats
+                hit, largest = np.asarray(rec["moe"])
+                k = self.cfg.num_experts_per_tok
+                rec["drec"].update(
+                    moe_rows=k * (rec["horizon"] * len(rec["active"])
+                                  + rec.get("chunk_n", 0)),
+                    moe_experts_hit=float(hit), moe_group_max=int(largest))
         t_ready = time.monotonic()
         horizon = rec["horizon"]
         active = rec["active"]
@@ -2789,7 +2852,7 @@ class EnginePrograms:
                 self.submit(r)
             drain()
             if horizon > 1:
-                self.cache, _, _, _, _ = decode_steps(
+                self.cache, _, _, _, _, _ = decode_steps(
                     self.cfg, horizon, self.params, self.cache,
                     self._donatable(self.last_token),
                     self._donatable(self.lengths),
@@ -2803,7 +2866,8 @@ class EnginePrograms:
                     bias_ids=jnp.asarray(self.bias_ids),
                     bias_vals=jnp.asarray(self.bias_vals),
                     lora_idx=self._lora_vec(),
-                    bblock=self.decode_bblock)
+                    bblock=self.decode_bblock,
+                    live=self._live_rows(()))
             return
 
         # Distinct token values per warmup request — identical prompts would
@@ -2876,7 +2940,7 @@ class EnginePrograms:
         cnts = jnp.zeros((self.num_slots, self.cfg.vocab_size), jnp.int32)
         cnts = _reset_count_row(cnts, jnp.int32(0), jnp.int32(0))
         mask = jnp.zeros((self.num_slots, self.cfg.vocab_size), jnp.bool_)
-        self.cache, _, _, _, _ = decode_steps(
+        self.cache, _, _, _, _, _ = decode_steps(
             self.cfg, horizon, self.params, self.cache,
             self._donatable(self.last_token), self._donatable(self.lengths),
             self._next_rng(), jnp.asarray(self.temps),
@@ -2893,7 +2957,8 @@ class EnginePrograms:
             bias_ids=jnp.asarray(self.bias_ids),
             bias_vals=jnp.asarray(self.bias_vals),
                     lora_idx=self._lora_vec(),
-                    bblock=self.decode_bblock)
+                    bblock=self.decode_bblock,
+                    live=self._live_rows(()))
         del cnts, mask
         # Logprobs program variants ('logprobs' is a static arg on every step
         # fn — distinct programs): one isolated request compiles the
@@ -2919,7 +2984,7 @@ class EnginePrograms:
         # doesn't stall all in-flight streams on XLA. Direct call, no slot
         # state touched: writes land at position 0 of idle slots and are
         # overwritten by real prefills.
-        self.cache, _, _, _, _ = decode_steps(
+        self.cache, _, _, _, _, _ = decode_steps(
             self.cfg, 1, self.params, self.cache,
             self._donatable(self.last_token), self._donatable(self.lengths),
             self._next_rng(), jnp.asarray(self.temps),
@@ -2932,4 +2997,5 @@ class EnginePrograms:
             bias_ids=jnp.asarray(self.bias_ids),
             bias_vals=jnp.asarray(self.bias_vals),
                     lora_idx=self._lora_vec(),
-                    bblock=self.decode_bblock)
+                    bblock=self.decode_bblock,
+                    live=self._live_rows(()))

@@ -3,6 +3,9 @@
     python chip_smoke.py              # one chip (what the driver runs)
     python chip_smoke.py --chips 4    # the sharded path (--tp 4) against the
                                       # same weights on one device; four chips
+    python chip_smoke.py --config benchmark/configs/olmoe-1b-7b-int8.json
+                                      # another model, as its benchmark cell
+                                      # serves it (one chip)
 
 One process holds the chip(s): the server is built by its own entry points
 (``serving.server.build_parser`` -> ``serving_config_from_args`` ->
@@ -32,6 +35,17 @@ Tolerances (stated once, used below):
 - LOGPROB_NATS: served chosen-token logprob vs the reference's logprob of
   that same token. With --chips 4 the served side is the --tp 4 engine and
   the reference holds the whole model on device 0.
+
+``--config <file>`` serves the model of a benchmark configuration file with
+that file's server flags instead (one chip). A model whose bf16 tree no chip
+holds (OLMoE: 13.8 GB) cannot start from the server's own seeded init, so its
+weights come from the file's seeded maker, in int8, through
+``build_state(params=...)``, and the numerics phase compares with the file's
+plain float32 reference (benchmark/reference/), which shares no code with
+the program — for an MoE model it routes on its own activations. Everything
+else is the same run: the same requests, a new 700-token prompt admitted
+under a live stream (``mixed_step``'s expert path), kernel parity at the
+model's own head shape.
 
 ``--rehearse`` is the builder's CPU rehearsal of this same script (tiny
 model, XLA attention, interpret-mode kernel parity at small shapes, no
@@ -174,14 +188,15 @@ def build_native_scheduler() -> None:
 class Server:
     """build_state -> warmup -> serve on a worker thread, as main() does."""
 
-    def __init__(self, flags):
+    def __init__(self, flags, params=None):
         from aws_k8s_ansible_provisioner_tpu.serving import server
 
         self.port = _free_port()
         argv = list(flags) + ["--host", "127.0.0.1", "--port", str(self.port)]
         args = server.build_parser().parse_args(argv)
         t0 = time.monotonic()
-        self.state = server.build_state(server.serving_config_from_args(args))
+        self.state = server.build_state(server.serving_config_from_args(args),
+                                        params=params)
         self.build_s = time.monotonic() - t0
         self.engine = self.state.engine
         t0 = time.monotonic()
@@ -401,11 +416,23 @@ def run_requests(srv: Server, model: str) -> dict:
 # ---------------------------------------------------------------------------
 
 
-def reference_logprobs(cfg, params, tokenizer, prompt: str, token_ids):
+def bench_module(kind: str, name: str):
+    """benchmark/<kind>/<name>.py as a module (the benchmark's seeded weight
+    makers and plain references), found as the benchmark finds them."""
+    sys.path.insert(0, os.path.join(HERE, "benchmark"))
+    from benchlib import files
+
+    return files.load_module(kind, name)
+
+
+def reference_logprobs(cfg, params, tokenizer, prompt: str, token_ids,
+                       plain=None):
     """Teacher-forced float32 log-softmax of the plain model (``model_forward``
     with its default XLA causal attention, no cache, no kernel) over
     prompt + served tokens: returns (logprob of each served token, the
-    reference's own max logprob) per generated position."""
+    reference's own max logprob) per generated position. With ``plain`` (a
+    benchmark/reference/ module) that independent reference is asked
+    instead of the program's own model code."""
     import jax
     import jax.numpy as jnp
 
@@ -413,6 +440,15 @@ def reference_logprobs(cfg, params, tokenizer, prompt: str, token_ids):
 
     ids = tokenizer.encode(prompt) + [int(t) for t in token_ids]
     n_prompt = len(ids) - len(token_ids)
+    if plain is not None:
+        import dataclasses
+
+        import numpy as np
+
+        rows = plain.logprobs(dataclasses.asdict(cfg), params, ids,
+                              len(token_ids))
+        return (rows[np.arange(len(token_ids)), np.asarray(token_ids)],
+                rows.max(axis=-1))
     T = -(-len(ids) // 64) * 64
     toks = jnp.asarray([ids + [0] * (T - len(ids))], jnp.int32)
     pos = jnp.arange(T, dtype=jnp.int32)[None]
@@ -445,11 +481,12 @@ def single_device_params(cfg, serving):
     return quantize_params(params, cfg)
 
 
-def check_numerics(name: str, stream: dict, cfg, params, tokenizer) -> None:
+def check_numerics(name: str, stream: dict, cfg, params, tokenizer,
+                   plain=None) -> None:
     import numpy as np
 
     served_ref, ref_max = reference_logprobs(
-        cfg, params, tokenizer, stream["prompt"], stream["token_ids"])
+        cfg, params, tokenizer, stream["prompt"], stream["token_ids"], plain)
     gap = float(np.max(ref_max - served_ref))
     agree = float(np.max(np.abs(np.asarray(stream["logprobs"])
                                 - served_ref)))
@@ -658,6 +695,74 @@ def ragged_call_time(pa, pool, skw, table, lengths, layer, Hq, D, chunk,
 # ---------------------------------------------------------------------------
 
 
+def check_routing_counts(port: int, cfg) -> None:
+    """An MoE model's /metrics say what its expert layers were given."""
+    status, raw = http_json(port, "GET", "/metrics")
+    check(status == 200, f"/metrics -> {status}")
+    m = parse_metrics(raw.decode())
+    tot = {k: sum(v for name, v in m.items() if name.startswith(k))
+           for k in ("tpu_serve_moe_routed_rows_total",
+                     "tpu_serve_moe_experts_hit_total",
+                     "tpu_serve_moe_forward_passes_total")}
+    passes = tot["tpu_serve_moe_forward_passes_total"]
+    check(passes > 0, f"no MoE forward pass was counted: {tot}")
+    hit = tot["tpu_serve_moe_experts_hit_total"] / passes
+    rows = tot["tpu_serve_moe_routed_rows_total"] / passes
+    check(1 <= hit <= cfg.num_experts, f"experts hit a pass: {hit}")
+    say(f"routing: {int(passes)} forward passes of decode and mixed "
+        f"dispatches; {rows:.1f} routed rows and {hit:.1f} of "
+        f"{cfg.num_experts} experts hit a layer, mean; largest group last "
+        f"{m.get('tpu_serve_moe_group_rows_max')}")
+
+
+def expert_forms_parity(cfg, rows: int) -> None:
+    """The two forms of the expert FFN (ops/moe.py: every expert over every
+    row, and rows sorted into XLA's grouped matmul) on one layer of seeded
+    int8 stacks at the served widths: the same sum by two routes, idle rows
+    included. The served programs use the first for decode and short
+    prefills, the second for the mixed step and long prefills."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from aws_k8s_ansible_provisioner_tpu.ops import moe
+
+    E, H, inter = cfg.num_experts, cfg.hidden_size, cfg.moe_intermediate_size
+    ks = jax.random.split(jax.random.PRNGKey(31), 5)
+
+    def stack(key, din, dout, std):
+        q = jax.random.randint(key, (E, din, dout), -127, 128, jnp.int8)
+        return {"kernel": q,
+                "scale": jnp.full((E, dout), std / 73.6, jnp.float32)}
+
+    p = {"router": {"kernel": (jax.random.normal(ks[0], (H, E))
+                               * 2.0 / H ** 0.5).astype(jnp.bfloat16)},
+         "w_gate": stack(ks[1], H, inter, 0.9 / H ** 0.5),
+         "w_up": stack(ks[2], H, inter, 0.9 / H ** 0.5),
+         "w_down": stack(ks[3], inter, H, 0.9 / inter ** 0.5)}
+    x = jax.random.normal(ks[4], (rows, H), jnp.bfloat16)
+    live = jnp.arange(rows) % 5 != 3
+    want, gs = jax.jit(lambda x: moe._sorted_groups(cfg, x, p, live))(x)
+    got, gk = jax.jit(lambda x: moe._every_expert(cfg, x, p, live))(x)
+    got, want = (np.asarray(a.astype(jnp.float32)) for a in (got, want))
+    check(np.array_equal(np.asarray(gs), np.asarray(gk)),
+          "the two forms count different groups")
+    check(np.all(np.isfinite(got)) and np.all(np.isfinite(want)),
+          "expert FFN: non-finite output")
+    dead = np.asarray(~live)
+    check(not got[dead].any() and not want[dead].any(),
+          "an idle row got an expert's output")
+    err = float(np.max(np.abs(got - want)
+                       / (KERNEL_TOL + KERNEL_TOL * np.abs(want))))
+    check(err <= 1.0, f"expert FFN: the two forms are {err:.2f}x the "
+                      f"tolerance apart (atol=rtol={KERNEL_TOL})")
+    say(f"expert FFN parity [{rows} rows, {E} experts, int8 stacks]: every-"
+        f"expert vs sorted max |diff| "
+        f"{float(np.max(np.abs(got - want))):.4f} on outputs of max "
+        f"|{float(np.abs(want).max()):.2f}|; experts hit "
+        f"{int((np.asarray(gs) > 0).sum())}")
+
+
 def check_shards(engine, n: int) -> None:
     import jax
 
@@ -741,6 +846,9 @@ def check_decode_program(engine) -> None:
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, default=1, choices=(1, 4))
+    ap.add_argument("--config", default="",
+                    help="a benchmark configuration file: serve its model "
+                         "with its flags, seeded weights and reference")
     ap.add_argument("--rehearse", action="store_true",
                     help="builder's CPU rehearsal: tiny model, no TPU "
                          "required, never prints an ok line")
@@ -809,21 +917,50 @@ def main() -> int:
 
     build_native_scheduler()
 
-    model = "Qwen/Qwen3-0.6B"
+    from aws_k8s_ansible_provisioner_tpu import config as _config
+
+    model, params, plain, cfg_file = "Qwen/Qwen3-0.6B", None, None, None
+    if opts.config:
+        check(opts.chips == 1, "--config is a one-chip run")
+        with open(opts.config, encoding="utf-8") as f:
+            cfg_file = json.load(f)
+        model = cfg_file["registry_name"]
     if opts.rehearse:
         # the same flags, window and traffic on a model the CPU can serve
-        from aws_k8s_ansible_provisioner_tpu import config as _config
-
-        model = "rehearse-qwen3"
-        _config.MODEL_REGISTRY[model] = _config.tiny_qwen3(
-            name=model, vocab_size=512, hidden_size=128,
-            intermediate_size=256, num_heads=8, num_kv_heads=4, head_dim=32,
-            max_seq_len=4096, eos_token_id=258)
+        tiny = dict(vocab_size=512, hidden_size=128, max_seq_len=4096,
+                    eos_token_id=258)
+        if cfg_file is None:
+            model = "rehearse-qwen3"
+            _config.MODEL_REGISTRY[model] = _config.tiny_qwen3(
+                name=model, intermediate_size=256, num_heads=8,
+                num_kv_heads=4, head_dim=32, **tiny)
+        else:
+            check(_config.MODEL_REGISTRY[model].num_experts > 0,
+                  "the rehearsal's other model is the OLMoE-shaped one")
+            model = "rehearse-olmoe"
+            _config.MODEL_REGISTRY[model] = _config.tiny_olmoe(
+                name=model, intermediate_size=64, moe_intermediate_size=64,
+                num_heads=4, num_kv_heads=4, head_dim=32, **tiny)
     flags = ["--model", model]
+    if cfg_file is not None:
+        flags = list(cfg_file["server_flags"])
+        flags[flags.index("--model") + 1] = model
+        import dataclasses
+
+        t0 = time.monotonic()
+        params = bench_module("weight_makers", cfg_file["weights_maker"]).make(
+            dataclasses.asdict(_config.MODEL_REGISTRY[model]),
+            int(cfg_file["weights_seed"]), cfg_file["weights_dtype"] == "int8")
+        jax.block_until_ready(params)
+        plain = bench_module("reference", cfg_file["reference"])
+        say(f"weights: {cfg_file['weights_maker']} "
+            f"{cfg_file['weights_dtype']} made on the device in "
+            f"{time.monotonic() - t0:.1f}s; reference "
+            f"benchmark/reference/{cfg_file['reference']}.py")
     if opts.chips == 4:
         flags += ["--tp", "4"]
 
-    srv = Server(flags)
+    srv = Server(flags, params)
     eng = srv.engine
     impl = resolve_impl(eng.serving.attention_impl)
     say(f"server: flags {flags}; scheduler {type(eng.sched).__name__}; "
@@ -842,7 +979,8 @@ def main() -> int:
     check(eng.paged, "the engine is not on the paged pool")
     if not opts.rehearse:
         check(impl == "pallas", f"attention impl resolved to {impl!r}")
-        check(eng.serving.decode_bblock == 0, "bblock was not autotuned")
+        check(eng.serving.decode_bblock == 0 or cfg_file is not None,
+              "bblock was not autotuned")
 
     cfg, tokenizer = eng.cfg, srv.state.tokenizer
     got = run_requests(srv, model)
@@ -866,14 +1004,29 @@ def main() -> int:
                            params, tokenizer)
     else:
         for name in ("c70", "c30", "m700"):   # m700: through mixed_step
-            check_numerics(name, got[name], cfg, eng.params, tokenizer)
+            check_numerics(name, got[name], cfg, eng.params, tokenizer, plain)
+        if cfg.num_experts > 0:
+            check_routing_counts(srv.port, cfg)
         srv.drain()
+        params = None
+        # beside the served model's head shape: multi-head attention
+        # (groups = 1, 16 KV heads: OLMoE's), which gives a decode block of 8
+        # slots 8 query rows a KV head where the 0.6B gives 16
+        mha = _config.MODEL_REGISTRY["allenai/OLMoE-1B-7B-0125-Instruct"]
         if opts.rehearse:
             # interpret mode is slow: same code path at a small shape
             kernel_parity(cfg, 8, 256, 32, sorted({1, 4}), interpret=True)
+            if cfg_file is None:
+                kernel_parity(mha.scaled(num_heads=4, num_kv_heads=4,
+                                         head_dim=32), 8, 256, 32, [4],
+                              interpret=True)
         else:
             kernel_parity(cfg, slots, window, page, sorted({1, bb}),
                           interpret=False)
+            if cfg_file is None:
+                kernel_parity(mha, 24, window, page, [1, 8], interpret=False)
+        if cfg.num_experts > 0:
+            expert_forms_parity(cfg, slots)
 
     names = sorted({n for n, _ in kernel_calls})
     say(f"kernels: {len(kernel_calls)} pallas_call traces "

@@ -101,6 +101,11 @@ CASES = [
     ("ragged-bf16-bb8-tp4", "ragged", False, 8, B + CHUNK, 1, HQ // 4,
      HKV // 4),
     ("spec-bf16-bb1", "spec", False, 1, B, 5, HQ, HKV),
+    # OLMoE: multi-head attention, one query head a KV head (groups = 1),
+    # 16 KV heads, 24 slots — a decode block of 8 slots gives the kernel 8
+    # query rows a KV head, and a 64-token page is 262 KB each for K and V
+    ("decode-bf16-bb8-mha", "decode", False, 8, 24, 1, 16, 16),
+    ("ragged-bf16-bb8-mha", "ragged", False, 8, 24 + CHUNK, 1, 16, 16),
 ]
 
 
@@ -148,6 +153,79 @@ def test_paged_write_kernel_compiles_for_v5e(chip, quant, packed):
 
 
 POOL_BYTES = 2 * L * P * HKV * PS * D * 2
+
+
+def test_paged_write_kernel_compiles_for_v5e_at_mha_heads(chip):
+    """cache_write_row_paged at OLMoE's 16 KV heads, slots and packed."""
+    sds, kv, _ = _pool(chip, False, hkv=16)
+    for rows, packed in ((24, False), (24 + CHUNK, True)):
+        new = sds((rows, 16, D), jnp.bfloat16)
+        compiled = _compile(
+            functools.partial(pa.cache_write_row_paged, packed=packed),
+            kv, new, sds((rows,), jnp.int32), sds((rows, 32), jnp.int32),
+            sds((), jnp.int32))
+        assert "tpu_custom_call" in compiled.as_text()
+        _assert_named_after_wrapper(compiled, pa.cache_write_row_paged)
+
+
+@pytest.mark.parametrize("rows,form", [(24, "every-expert"),
+                                       (24 + CHUNK, "sorted")])
+def test_expert_ffn_ops_are_found_by_their_stack_operand(chip, rows, form):
+    """OLMoE's expert layer at published widths, int8 stacks, in both forms
+    ops/moe.py gives it (a decode batch: every expert over every row; the
+    mixed program's packed rows: sorted groups through XLA's ``ragged-dot``
+    custom calls). The profiler names a device operation by its HLO line
+    and keeps no scope metadata, so the benchmark's readers find the expert
+    FFN by the one thing every form's line holds: an expert stack's type
+    among the operands (benchmark/benchlib/moe_opsbytes.expert_ops_re). Also
+    here: no bf16 copy of a stack is made (PR 26: an ``astype`` in front of
+    ``ragged_dot`` was three 268-MB copies a layer)."""
+    import dataclasses
+    import os
+    import re
+    import sys
+
+    from aws_k8s_ansible_provisioner_tpu.config import MODEL_REGISTRY
+    from aws_k8s_ansible_provisioner_tpu.ops import moe
+
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark"))
+    from benchlib import moe_opsbytes
+
+    cfg = MODEL_REGISTRY["allenai/OLMoE-1B-7B-0125-Instruct"]
+    E, H, Im = cfg.num_experts, cfg.hidden_size, cfg.moe_intermediate_size
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+
+    def stack(din, dout):
+        return {"kernel": sds((E, din, dout), jnp.int8),
+                "scale": sds((E, dout), jnp.float32)}
+
+    p = {"router": {"kernel": sds((H, E), jnp.bfloat16)},
+         "w_gate": stack(H, Im), "w_up": stack(H, Im),
+         "w_down": stack(Im, H)}
+    text = jax.jit(lambda x, p: moe.moe_mlp(cfg, x, p)).lower(
+        sds((rows, H), jnp.bfloat16), p).compile().as_text()
+    assert ("ragged-dot" in text) == (form == "sorted")
+    pat = re.compile(moe_opsbytes.expert_ops_re(dataclasses.asdict(cfg)))
+    # the entry computation's instructions as the trace shows them: the
+    # profiler's line carries each operand's type, ``as_text`` does not
+    entry = text[text.index("ENTRY"):].splitlines()[1:]
+    types = dict(re.findall(r"(%[\w.\-]+) = (\w+\[[\d,]*\])", text))
+    lines = [re.sub(r"(%[\w.\-]+)(?=[,)])",
+                    lambda m: f"{types.get(m[1], '?')} {m[1]}", ln)
+             for ln in entry if " = " in ln and "parameter(" not in ln]
+    hits = [ln for ln in lines if pat.search(ln)]
+    found = [ln.split(" = ")[0].split()[-1] for ln in hits]
+    # between them the matched operations read all three stacks
+    stacks = {m for ln in hits for m in re.findall(
+        rf"s8\[{E},(?:{H},{Im}|{Im},{H})\] (%[\w.\-]+)", ln)}
+    assert len(stacks) == 3, (found, stacks)
+    if form == "sorted":
+        assert sum(f.startswith("%ragged-dot") for f in found) == 3, found
+    assert not re.search(rf"= bf16\[{E},({H},{Im}|{Im},{H})\]", text), \
+        "a bf16 copy of an expert stack is materialised"
 
 
 def test_paged_prefill_write_holds_no_pool_copy(chip):
