@@ -629,8 +629,8 @@ def make_spec_attend_carry_paged(lengths: jnp.ndarray, table: jnp.ndarray,
     return attend
 
 
-def make_mixed_attend_carry_paged(write_rows: jnp.ndarray,
-                                  row_limits: jnp.ndarray,
+def make_mixed_attend_carry_paged(dec_rows: jnp.ndarray, chunk_start,
+                                  chunk_len, row_limits: jnp.ndarray,
                                   row_tables: jnp.ndarray,
                                   impl: str = "auto", mesh=None,
                                   window: int = 0, bblock: int = 1):
@@ -640,60 +640,74 @@ def make_mixed_attend_carry_paged(write_rows: jnp.ndarray,
     .mixed_step — the dispatch that lets the decode pipeline ride across
     prefill admissions instead of draining).
 
-    Per packed row i the caller provides:
-    - ``write_rows`` [N]: the pool row this token's K/V lands at (decode:
-      the slot's context length; chunk row at position p: p; -1 DROPS the
-      write — used to suppress the chunking slot's garbage decode row);
-    - ``row_limits`` [N]: live columns the row attends over (decode:
+    The caller provides:
+    - ``dec_rows`` [B]: the pool row each decode token's K/V lands at (the
+      slot's context length; -1 DROPS the write — used to suppress the
+      chunking slot's garbage decode row);
+    - ``chunk_start``, ``chunk_len`` (scalars): the C chunk rows are rows
+      [chunk_start, chunk_start + C) of the chunking slot, of which the
+      first ``chunk_len`` carry a token — the padding behind them is not
+      written;
+    - ``row_limits`` [N]: live columns packed row i attends over (decode:
       context + 1; chunk: p + 1 — plain causality; 0 = a DEAD row — the
       chunk's padding, the chunking slot's decode row — which fetches
       nothing and returns zeros, from the kernel and the fallback alike);
     - ``row_tables`` [N, max_pages]: the page run of the slot row i belongs
       to (chunk rows repeat the chunking slot's run).
 
-    All N writes land before any row attends; causality then reduces to the
-    per-row column mask, so a chunk row sees exactly its prefix (earlier
-    chunks + this chunk's earlier rows) and a decode row sees exactly its
-    own slot — byte-identical math to the separate decode_attend/
-    chunk_attend programs it replaces. Mesh support mirrors
-    make_decode_attend_carry_paged's tp sharding (heads over ``tp``); the
-    engine gates ragged dispatch to mesh None / pure-tp, so no dp rebase
-    rides here."""
-    resolved = resolve_impl(impl)
+    Two needs, two writers: a decode row is one row in each of B page runs
+    (the row kernel decode_steps uses, one grid step a slot), the chunk is
+    one span of one run (paged_kv.write_chunk_paged_layer, the prefill
+    programs' writer, one page window at a time: 0.07 ms for the K and V
+    of a layer at the served shape, my chip run, PR 29 — a grid step a ROW
+    was 2,080 steps a call, two thirds of them padding, 0.8 ms a layer and
+    22 ms of a 58-ms step: ledger, PR 26). All writes land before any row
+    attends; causality then reduces to the per-row column mask, so a chunk
+    row sees exactly its prefix (earlier chunks + this chunk's earlier
+    rows) and a decode row sees exactly its own slot — byte-identical math
+    to the separate decode_attend/chunk_attend programs it replaces. Mesh
+    support mirrors make_decode_attend_carry_paged's tp sharding (heads
+    over ``tp``); the engine gates ragged dispatch to mesh None / pure-tp,
+    so no dp rebase rides here."""
+    from aws_k8s_ansible_provisioner_tpu.serving import paged_kv as pkv
 
-    def _write_attend_mixed(q3, pool, knew, vnew, wrows, limits, tabs,
-                            layer):
+    resolved = resolve_impl(impl)
+    B = dec_rows.shape[0]
+
+    def _write_chunk(pool, knew, vnew, start, n_valid, tabs, layer):
+        return pkv.write_chunk_paged_layer(
+            pool, layer, tabs[B], start, knew[None, B:], vnew[None, B:],
+            pool["k"].shape[3], n_valid=n_valid)
+
+    def _write_attend_mixed(q3, pool, knew, vnew, drows, start, n_valid,
+                            limits, tabs, layer):
         from aws_k8s_ansible_provisioner_tpu.ops import pallas_attention
 
         interpret = not pallas_attention.supported()
         ck, cv = pool["k"], pool["v"]
         if "ks" in pool:
             ck, ks = pallas_attention.cache_write_row_quant_paged(
-                ck, pool["ks"], knew, wrows, tabs, layer,
-                interpret=interpret, packed=True)
+                ck, pool["ks"], knew[:B], drows, tabs[:B], layer,
+                interpret=interpret)
             cv, vs = pallas_attention.cache_write_row_quant_paged(
-                cv, pool["vs"], vnew, wrows, tabs, layer,
-                interpret=interpret, packed=True)
+                cv, pool["vs"], vnew[:B], drows, tabs[:B], layer,
+                interpret=interpret)
             pool = {"k": ck, "v": cv, "ks": ks, "vs": vs}
-            scale_kw = dict(pool_ks=ks, pool_vs=vs)
         else:
-            # packed: the chunk rows of one slot share 8-row blocks
             ck = pallas_attention.cache_write_row_paged(
-                ck, knew, wrows, tabs, layer, interpret=interpret,
-                packed=True)
+                ck, knew[:B], drows, tabs[:B], layer, interpret=interpret)
             cv = pallas_attention.cache_write_row_paged(
-                cv, vnew, wrows, tabs, layer, interpret=interpret,
-                packed=True)
+                cv, vnew[:B], drows, tabs[:B], layer, interpret=interpret)
             pool = {"k": ck, "v": cv}
-            scale_kw = {}
+        pool = _write_chunk(pool, knew, vnew, start, n_valid, tabs, layer)
+        scale_kw = (dict(pool_ks=pool["ks"], pool_vs=pool["vs"])
+                    if "ks" in pool else {})
         ctx = pallas_attention.ragged_attend_pallas_paged(
-            q3, ck, cv, limits, layer, tabs, interpret=interpret,
-            window=window, bblock=bblock, **scale_kw)
+            q3, pool["k"], pool["v"], limits, layer, tabs,
+            interpret=interpret, window=window, bblock=bblock, **scale_kw)
         return ctx, pool
 
     def attend(q, k, v, cache_l) -> Tuple[jnp.ndarray, tuple]:
-        from aws_k8s_ansible_provisioner_tpu.serving import paged_kv as pkv
-
         pool, layer = cache_l
         ps = pool["k"].shape[3]
         if resolved == "pallas":
@@ -712,23 +726,26 @@ def make_mixed_attend_carry_paged(write_rows: jnp.ndarray,
                               pool_spec,              # pool leaf dict
                               P(None, "tp", None),    # knew [N,Hkv,D]
                               P(None, "tp", None),    # vnew
-                              P(None),                # write_rows [N]
+                              P(None),                # dec_rows [B]
+                              P(), P(),               # chunk start, len
                               P(None),                # row_limits [N]
                               P(None, None),          # row_tables
                               P()),                   # layer scalar
                     out_specs=(P(None, "tp", None), pool_spec),
                     check_vma=False,
                 )
-                ctx, pool = fn(q3, pool, knew, vnew, write_rows,
-                               row_limits, row_tables, layer)
+                ctx, pool = fn(q3, pool, knew, vnew, dec_rows, chunk_start,
+                               chunk_len, row_limits, row_tables, layer)
             else:
-                ctx, pool = _write_attend_mixed(q3, pool, knew, vnew,
-                                                write_rows, row_limits,
-                                                row_tables, layer)
+                ctx, pool = _write_attend_mixed(
+                    q3, pool, knew, vnew, dec_rows, chunk_start, chunk_len,
+                    row_limits, row_tables, layer)
             return ctx[None], (pool, layer)
-        pool = pkv.write_token_layer_paged(pool, layer, write_rows,
-                                           row_tables, k[0][:, None],
-                                           v[0][:, None], ps)
+        pool = pkv.write_token_layer_paged(pool, layer, dec_rows,
+                                           row_tables[:B], k[0][:B, None],
+                                           v[0][:B, None], ps)
+        pool = _write_chunk(pool, k[0], v[0], chunk_start, chunk_len,
+                            row_tables, layer)
         dense = pkv.gather_layer_dense(pool, layer, row_tables)
         ck, cv = dense["k"], dense["v"]
         if "ks" in dense:

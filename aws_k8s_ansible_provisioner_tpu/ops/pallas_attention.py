@@ -1371,31 +1371,18 @@ def decode_attend_pallas_spec_paged(q: jnp.ndarray, pool_k: jnp.ndarray,
     return out.reshape(B, R, Hq, D)
 
 
-def _write_block(b, rows, tab, src, *, MP: int, ps: int, ROWS: int):
-    """(physical page, row block) grid step b of a paged row write opens:
-    those of row ``src[b]`` if the call is packed, of row b otherwise. tab:
-    the table FLATTENED row-major (SMEM; see _paged_flash_db)."""
-    b = src[0][b] if src else b
+def _write_block(b, rows, tab, *, MP: int, ps: int, ROWS: int):
+    """(physical page, row block) grid step b of a paged row write opens.
+    tab: the table FLATTENED row-major (SMEM; see _paged_flash_db)."""
     r = jnp.clip(rows[b], 0, MP * ps - 1)
     return tab[b * MP + r // ps], (r % ps) // ROWS
 
 
-def _packed_write_src(rows: jnp.ndarray, limit: int) -> jnp.ndarray:
-    """For a PACKED write (several rows of one slot in one call): per row, the
-    row whose pool block its grid step opens — itself, or for a DROPPED row
-    the nearest kept row before it, so a dropped row rides on the block the
-    step before it holds and never re-opens one an earlier step wrote."""
-    idx = jnp.arange(rows.shape[0], dtype=jnp.int32)
-    kept = (rows >= 0) & (rows < limit)
-    src = jax.lax.cummax(jnp.where(kept, idx, -1))
-    return jnp.where(src < 0, idx, src)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret", "packed"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def cache_write_row_paged(pool: jnp.ndarray, new: jnp.ndarray,
                           rows: jnp.ndarray, table: jnp.ndarray,
-                          layer: jnp.ndarray, interpret: bool = False,
-                          packed: bool = False) -> jnp.ndarray:
+                          layer: jnp.ndarray,
+                          interpret: bool = False) -> jnp.ndarray:
     """Write one new K (or V) row per slot into the PAGED pool, IN PLACE.
 
     pool: [L, P, Hkv, page, D]; new: [B, Hkv, D]; rows: [B] logical row per
@@ -1404,14 +1391,13 @@ def cache_write_row_paged(pool: jnp.ndarray, new: jnp.ndarray,
     design as the dense cache_write_row (see its docstring for why a kernel
     and not a scatter).
 
-    ``packed`` (static; the mixed program's layout, where the chunk rows of
-    one slot follow each other): consecutive grid steps may touch the SAME
-    8-row block. Pallas then neither refetches the input block nor writes
-    the output block back between them, so a step that merged its row into
-    the INPUT copy would drop every earlier row of the run (on the chip one
-    row in eight of a chunk landed — my chip run, PR 25). A packed step
-    merges into the output block the run has built so far. Without it
-    (one row per slot: every step its own block) the kernel is as it was.
+    ONE ROW A SLOT is the contract: every grid step opens a block of its
+    own. Two steps on the same 8-row block would lose the first one's row
+    (Pallas neither refetches the input block nor writes the output block
+    back between consecutive steps that revisit it — on the chip one row in
+    eight of a chunk landed, PR 25), so several rows of ONE slot go through
+    paged_kv.write_chunk_paged_layer, a page window at a time, as the mixed
+    program's chunk does.
     """
     L, P, Hkv, ps, D = pool.shape
     rows = rows.astype(jnp.int32)
@@ -1420,21 +1406,18 @@ def cache_write_row_paged(pool: jnp.ndarray, new: jnp.ndarray,
     MP = table.shape[1]
     S_v = MP * ps
     ROWS = 8 if ps % 8 == 0 else ps
-    prefetch = [rows, layer_arr, table.reshape(-1)]
-    if packed:
-        prefetch.append(_packed_write_src(rows, S_v))
 
     block = functools.partial(_write_block, MP=MP, ps=ps, ROWS=ROWS)
 
-    def new_map(b, *prefetched):
+    def new_map(b, lens, lay, tab):
         return (b, 0, 0)
 
-    def blk_map(b, lens, lay, tab, *src):
-        pg, rb = block(b, lens, tab, src)
+    def blk_map(b, lens, lay, tab):
+        pg, rb = block(b, lens, tab)
         return (lay[0], pg, 0, rb, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(prefetch),
+        num_scalar_prefetch=3,
         grid=(new.shape[0],),
         in_specs=[
             pl.BlockSpec((1, Hkv, D), new_map),
@@ -1443,46 +1426,38 @@ def cache_write_row_paged(pool: jnp.ndarray, new: jnp.ndarray,
         out_specs=pl.BlockSpec((1, 1, Hkv, ROWS, D), blk_map),
     )
 
-    def kernel(lengths_ref, layer_ref, table_ref, *refs):
-        new_ref, cin_ref, cout_ref = refs[-3:]
+    def kernel(lengths_ref, layer_ref, table_ref, new_ref, cin_ref,
+               cout_ref):
         b = pl.program_id(0)
         tgt = lengths_ref[b]
         in_window = (tgt >= 0) & (tgt < S_v)
         # ROWS divides page_size, so the in-block row is tgt % ROWS
         r = jnp.where(in_window, jnp.clip(tgt, 0, S_v - 1) % ROWS, -1)
         row = jax.lax.broadcasted_iota(jnp.int32, (Hkv, ROWS, D), 1)
-        base = cin_ref[0, 0]
-        if packed:
-            here = block(b, lengths_ref, table_ref, refs[:1])
-            prev = block(jnp.maximum(b - 1, 0), lengths_ref, table_ref,
-                         refs[:1])
-            same = (b > 0) & (here[0] == prev[0]) & (here[1] == prev[1])
-            base = jnp.where(same, cout_ref[0, 0], base)
-        cout_ref[0, 0] = jnp.where(row == r, new_ref[0][:, None, :], base)
+        cout_ref[0, 0] = jnp.where(row == r, new_ref[0][:, None, :],
+                                   cin_ref[0, 0])
 
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
-        # the pool operand, after the scalars and ``new``
-        input_output_aliases={len(prefetch) + 1: 0},
+        # the pool: operand 4, after the three scalars and ``new``
+        input_output_aliases={4: 0},
         interpret=interpret,
-    )(*prefetch, new, pool)
+    )(rows, layer_arr, table.reshape(-1), new, pool)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "packed"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def cache_write_row_quant_paged(pool: jnp.ndarray, scales: jnp.ndarray,
                                 new: jnp.ndarray, rows: jnp.ndarray,
                                 table: jnp.ndarray, layer: jnp.ndarray,
-                                interpret: bool = False,
-                                packed: bool = False):
+                                interpret: bool = False):
     """Quantizing paged row write: int8 pool + per-row scales, both aliased.
 
     pool: [L, P, Hkv, page, D] int8; scales: [L, P, Hkv, lanes >= page] f32
     (paged_kv.scale_lanes); new: [B, Hkv, D] float. Same quantizer as the
     dense kernel (kv_cache.quantize_rows) so prefilled and decoded rows are
-    interchangeable. ``packed`` as in cache_write_row_paged (the scale block
-    is a whole page, so a run there is the steps of one page). Returns
+    interchangeable. One row a slot, as cache_write_row_paged. Returns
     (pool, scales) — same buffers.
     """
     L, P, Hkv, ps, D = pool.shape
@@ -1493,24 +1468,21 @@ def cache_write_row_quant_paged(pool: jnp.ndarray, scales: jnp.ndarray,
     MP = table.shape[1]
     S_v = MP * ps
     ROWS = 32 if ps % 32 == 0 else ps
-    prefetch = [rows, layer_arr, table.reshape(-1)]
-    if packed:
-        prefetch.append(_packed_write_src(rows, S_v))
 
     block = functools.partial(_write_block, MP=MP, ps=ps, ROWS=ROWS)
 
-    def new_map(b, *prefetched):
+    def new_map(b, lens, lay, tab):
         return (b, 0, 0)
 
-    def blk_map(b, lens, lay, tab, *src):
-        pg, rb = block(b, lens, tab, src)
+    def blk_map(b, lens, lay, tab):
+        pg, rb = block(b, lens, tab)
         return (lay[0], pg, 0, rb, 0)
 
-    def scale_map(b, lens, lay, tab, *src):
-        return (lay[0], block(b, lens, tab, src)[0], 0, 0)
+    def scale_map(b, lens, lay, tab):
+        return (lay[0], block(b, lens, tab)[0], 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(prefetch),
+        num_scalar_prefetch=3,
         grid=(new.shape[0],),
         in_specs=[
             pl.BlockSpec((1, Hkv, D), new_map),
@@ -1523,8 +1495,8 @@ def cache_write_row_quant_paged(pool: jnp.ndarray, scales: jnp.ndarray,
         ],
     )
 
-    def kernel(lengths_ref, layer_ref, table_ref, *refs):
-        new_ref, cin_ref, sin_ref, cout_ref, sout_ref = refs[-5:]
+    def kernel(lengths_ref, layer_ref, table_ref, new_ref, cin_ref, sin_ref,
+               cout_ref, sout_ref):
         b = pl.program_id(0)
         tgt = lengths_ref[b]
         in_window = (tgt >= 0) & (tgt < S_v)
@@ -1533,21 +1505,12 @@ def cache_write_row_quant_paged(pool: jnp.ndarray, scales: jnp.ndarray,
             quantize_rows)
 
         q8, sc = quantize_rows(new_ref[0])                    # [Hkv,D],[Hkv]
-        base, sbase = cin_ref[0, 0], sin_ref[0, 0]
-        if packed:
-            here = block(b, lengths_ref, table_ref, refs[:1])
-            prev = block(jnp.maximum(b - 1, 0), lengths_ref, table_ref,
-                         refs[:1])
-            same_page = (b > 0) & (here[0] == prev[0])
-            base = jnp.where(same_page & (here[1] == prev[1]),
-                             cout_ref[0, 0], base)
-            sbase = jnp.where(same_page, sout_ref[0, 0], sbase)
         row = jax.lax.broadcasted_iota(jnp.int32, (Hkv, ROWS, D), 1)
-        cout_ref[0, 0] = jnp.where(row == r, q8[:, None, :], base)
+        cout_ref[0, 0] = jnp.where(row == r, q8[:, None, :], cin_ref[0, 0])
         # scale block spans one whole page: target column = tgt % page
         rs = jax.lax.broadcasted_iota(jnp.int32, (Hkv, lanes), 1)
         tgt_col = jnp.where(in_window, jnp.clip(tgt, 0, S_v - 1) % ps, -1)
-        sout_ref[0, 0] = jnp.where(rs == tgt_col, sc[:, None], sbase)
+        sout_ref[0, 0] = jnp.where(rs == tgt_col, sc[:, None], sin_ref[0, 0])
 
     return pl.pallas_call(
         kernel,
@@ -1556,10 +1519,10 @@ def cache_write_row_quant_paged(pool: jnp.ndarray, scales: jnp.ndarray,
             jax.ShapeDtypeStruct(pool.shape, pool.dtype),
             jax.ShapeDtypeStruct(scales.shape, scales.dtype),
         ],
-        # pool and scales, after the scalars and ``new``
-        input_output_aliases={len(prefetch) + 1: 0, len(prefetch) + 2: 1},
+        # pool and scales: operands 4, 5 (after three scalars and ``new``)
+        input_output_aliases={4: 0, 5: 1},
         interpret=interpret,
-    )(*prefetch, new, pool, scales)
+    )(rows, layer_arr, table.reshape(-1), new, pool, scales)
 
 
 def supported() -> bool:

@@ -162,7 +162,7 @@ def write_prompts_paged(pool_l: dict, tables: jnp.ndarray, k: jnp.ndarray,
 
 def _write_span_by_page(pool: dict, layer, tables: jnp.ndarray, start,
                         k: jnp.ndarray, v: jnp.ndarray,
-                        page_size: int) -> dict:
+                        page_size: int, n_valid=None) -> dict:
     """Rows [start, start+T) of N sequences into the FULL pool at ``layer``,
     one WHOLE PAGE per scatter window (read-modify-write).
 
@@ -170,6 +170,10 @@ def _write_span_by_page(pool: dict, layer, tables: jnp.ndarray, start,
     or traced). Same index/drop contract as the row-granular per-layer
     writers above (logical pages past the table and OOB_PAGE entries drop;
     rows of a touched page outside the span keep their content).
+    ``n_valid`` (traced scalar; the mixed program's chunk, which arrives
+    padded to T): only rows [start, start+n_valid) are written, the
+    padding behind them keeps the pool's content like any row outside the
+    span. None writes all T.
 
     Why pages and not rows: a row-granular scatter on the head-major pool
     (``arr.at[layer, pg, :, off]``, window [Hkv, D] split by the page axis)
@@ -198,7 +202,7 @@ def _write_span_by_page(pool: dict, layer, tables: jnp.ndarray, start,
     # span token held by row r of touched page j; live = inside the span
     tok = (jnp.arange(n, dtype=jnp.int32)[:, None] * ps
            + jnp.arange(ps, dtype=jnp.int32)[None] + delta)     # [n, ps]
-    live = (tok >= 0) & (tok < T)
+    live = (tok >= 0) & (tok < (T if n_valid is None else n_valid))
 
     def update(arr, val):
         # val [N, T, Hkv, (D)] -> per-page blocks [N, n, Hkv, ps, (D)]
@@ -237,12 +241,13 @@ def write_prompts_paged_layer(pool: dict, layer, tables: jnp.ndarray,
 
 def write_chunk_paged_layer(pool: dict, layer, pages: jnp.ndarray,
                             start, k: jnp.ndarray, v: jnp.ndarray,
-                            page_size: int) -> dict:
+                            page_size: int, n_valid=None) -> dict:
     """FULL-pool variant of :func:`write_chunk_paged` (carry prefill path —
     see write_prompts_paged_layer). k/v: [1, C, Hkv, D]; ``start`` may be a
-    python int (page-aligned starts then touch one page fewer)."""
+    python int (page-aligned starts then touch one page fewer); ``n_valid``
+    as in :func:`_write_span_by_page` (the chunk of a mixed step)."""
     return _write_span_by_page(pool, layer, pages[None], start, k, v,
-                               page_size)
+                               page_size, n_valid)
 
 
 def write_chunk_paged(pool_l: dict, pages: jnp.ndarray, start: jnp.ndarray,

@@ -679,8 +679,9 @@ def mixed_step(cfg: ModelConfig, params, cache, tokens, lengths, ptokens,
 
     ``pslot``'s own decode row is a dead passenger while it chunks, and so
     is every chunk row at or past ``plen`` (the chunk arrives padded to C):
-    a dead row's K/V write is DROPPED (write row -1) and it attends nothing
-    (limit 0), which costs the ragged kernel nothing. For ``pslot``
+    a dead row's K/V is not written (the decode row's write row is -1, the
+    chunk is written as the span [pstart, pstart + plen)) and it attends
+    nothing (limit 0), which costs the ragged kernel nothing. For ``pslot``
     the returned carry overrides its lanes with the chunk's sample
     (``tok_out[pslot] = chunk token``, ``lens_out[pslot] = pstart + plen``)
     so the device carry matches the host mirrors a final-chunk activation
@@ -716,9 +717,6 @@ def mixed_step(cfg: ModelConfig, params, cache, tokens, lengths, ptokens,
     crows = pstart + jnp.arange(C, dtype=jnp.int32)
     # the chunk is padded to C rows: rows past the prompt are dead too
     is_pad = jnp.arange(C, dtype=jnp.int32) >= plen
-    write_rows = jnp.concatenate(
-        [jnp.where(is_p, jnp.int32(-1), lengths),
-         jnp.where(is_pad, jnp.int32(-1), crows)])
     row_limits = jnp.concatenate(
         [jnp.where(is_p, jnp.int32(0), lengths + 1),
          jnp.where(is_pad, jnp.int32(0), crows + 1)])
@@ -727,9 +725,12 @@ def mixed_step(cfg: ModelConfig, params, cache, tokens, lengths, ptokens,
     packed = jnp.concatenate([tokens[None], ptokens], axis=1)     # [1, B+C]
     positions = jnp.concatenate(
         [jnp.where(is_p, jnp.int32(0), lengths)[None], crows[None]], axis=1)
+    # K/V writes: one row a decode slot (pslot's own dropped), the chunk as
+    # the span [pstart, pstart + plen) of pslot's page run
     attend = make_mixed_attend_carry_paged(
-        write_rows, row_limits, row_tables, impl=impl, mesh=mesh,
-        window=cfg.sliding_window, bblock=bblock)
+        jnp.where(is_p, jnp.int32(-1), lengths), pstart, plen, row_limits,
+        row_tables, impl=impl, mesh=mesh, window=cfg.sliding_window,
+        bblock=bblock)
     # Per-TOKEN adapter indices over the packed layout: decode row b keeps
     # its slot's adapter, every chunk row runs the chunking slot's — one
     # program serves any adapter mix (models/layers._linear gathers factors
@@ -1407,7 +1408,9 @@ class EnginePrograms:
         rows live, ``given`` the static key and the per-kind facts
         (horizon, chunk_rows, chunk_n, chunk_off, bucket, rows,
         prompt_tokens, padded_tokens, carry_steps = steps of an unfetched
-        predecessor the device-side lengths are ahead of the mirrors by).
+        predecessor the device-side lengths are ahead of the mirrors by,
+        write_pages = page windows of the pool that hold a row of a mixed
+        step's chunk: what its span write changes, whatever chunk_rows is).
         Closed by ``_dispatch_close`` on the blocking half. For an MoE
         model ``_decode_fetch`` adds, to the decode and mixed records,
         ``moe_rows`` ((token, expert) rows of live tokens per layer: k x
@@ -2040,9 +2043,11 @@ class EnginePrograms:
                 jnp.float32(req.top_p), self._next_rng(),
                 oc["temps"], oc["top_ks"], oc["top_ps"])
         prev = self._inflight
+        ps = self.serving.page_size
         drec = self._dispatch_open(
             "mixed_step", "mixed_step", active, horizon=1,
             chunk_rows=st["C"], chunk_n=len(chunk), chunk_off=off,
+            write_pages=(off + len(chunk) - 1) // ps - off // ps + 1,
             carry_steps=prev["horizon"] if prev is not None else 0)
         self._book_bubble(drec["t_enqueue"])
         real_counts = self.counts

@@ -521,7 +521,8 @@ def kernel_parity(cfg, slots: int, window: int, page: int, bblocks,
     import numpy as np
 
     from aws_k8s_ansible_provisioner_tpu.ops import pallas_attention as pa
-    from aws_k8s_ansible_provisioner_tpu.ops.attention import decode_attend
+    from aws_k8s_ansible_provisioner_tpu.ops.attention import (
+        decode_attend, make_mixed_attend_carry_paged)
     from aws_k8s_ansible_provisioner_tpu.serving import kv_cache as kvc
     from aws_k8s_ansible_provisioner_tpu.serving import paged_kv as pkv
 
@@ -579,10 +580,10 @@ def kernel_parity(cfg, slots: int, window: int, page: int, bblocks,
             ck, cv = dense_view(table)
             ref = decode_attend(q, ck, cv, lengths)
         # ragged rows, as mixed_step packs them: every decode row (slot 3's
-        # is dead: it is the one chunking), then a 64-row chunk of slot 3 at
-        # positions 300.. of which 40 rows are prompt (limit = position + 1)
-        # and 24 are padding (limit 0: nothing fetched, output zero)
-        C = 64
+        # is dead: it is the one chunking), then a two-page chunk of slot 3
+        # at positions 300.. of which 40 rows are prompt (limit = position
+        # + 1) and the rest padding (limit 0: nothing fetched, output zero)
+        C = 2 * page
         crow = 300 + jnp.arange(C, dtype=jnp.int32)
         limits = jnp.concatenate(
             [jnp.where(jnp.arange(B) == 3, 0, lengths),
@@ -630,29 +631,70 @@ def kernel_parity(cfg, slots: int, window: int, page: int, bblocks,
                                           layer, interpret=interpret)
             check(bool(jnp.array_equal(gk, want["k"])),
                   "cache_write_row_paged: rows differ from the scatter")
-        # the same kernels over mixed_step's packed rows: the chunk's 40 rows
-        # of slot 3 share 8- or 32-row blocks, its padding rows drop
+        # mixed_step's writes as its attend makes them: the decode rows by
+        # the row kernel (slot 3, the one chunking, dropped), the chunk as
+        # ONE span of slot 3's page run — 40 rows from row 300, 44 rows into
+        # a page and across its edge — its padding rows unwritten
         prow = jnp.concatenate(
             [jnp.where(jnp.arange(B) == 3, -1, lengths),
              jnp.where(jnp.arange(C) < 40, crow, -1)])
         pnew = jax.random.normal(keys[5], (B + C, Hkv, D), jnp.bfloat16)
         want = pkv.write_token_layer_paged(
             pool, layer, prow, rtab, pnew[:, None], pnew[:, None], page)
-        if quant:
-            gk, gks = pa.cache_write_row_quant_paged(
-                pool["k"], pool["ks"], pnew, prow, rtab, layer,
-                interpret=interpret, packed=True)
-            close("cache_write_row_quant_paged packed scales", gks,
-                  want["ks"])
-        else:
-            gk = pa.cache_write_row_paged(pool["k"], pnew, prow, rtab, layer,
-                                          interpret=interpret, packed=True)
-        check(bool(jnp.array_equal(gk, want["k"])),
-              f"paged write kernel {tag}: mixed_step's packed rows differ "
-              f"from the scatter")
-        say(f"parity: paged write kernel {tag}: equal to the jnp scatter, "
-            f"one row a slot and mixed_step's packed rows")
+        attend = make_mixed_attend_carry_paged(
+            prow[:B], jnp.int32(300), jnp.int32(40), limits, rtab,
+            impl="pallas", bblock=max(bblocks))
+        _, (got, _) = jax.jit(attend)(q3[None], pnew[None], pnew[None],
+                                      (pool, layer))
+        for name in want:
+            if name in ("ks", "vs"):
+                close(f"mixed_step writes {tag}: {name}", got[name],
+                      want[name])
+                continue
+            # int8: the compiled quantizer may round one step from the
+            # eager one (kv_cache.quantize_rows); bf16 rows are copies
+            diff = np.abs(np.asarray(got[name], np.float32)
+                          - np.asarray(want[name], np.float32))
+            off = int((diff > 0).sum())
+            check(diff.max() <= (1 if quant else 0) and off <= 8,
+                  f"mixed_step writes {tag}: pool leaf {name} differs from "
+                  f"the row-by-row scatter at {off} elements (max "
+                  f"{diff.max()})")
+        say(f"parity: paged writes {tag}: equal to the jnp scatter, one row "
+            f"a slot (kernel) and mixed_step's rows (kernel + chunk span)")
+        if not interpret:
+            chunk_write_time(pkv, pool, table, layer, page, window, tag)
+        del got
         del pool, want, gk
+
+
+def chunk_write_time(pkv, pool, table, layer, page, chunk, tag) -> None:
+    """Device time of ONE layer's chunk write (K and V) at the served mixed
+    step's shape: a ``chunk``-row chunk of slot 3 from row 0 or 1, 640 rows
+    of it a prompt. 64 writes in one program, so the host's dispatch
+    (0.7 ms a call, ten times the write) is not what is timed."""
+    import jax
+    import jax.numpy as jnp
+
+    Hkv, D = pool["k"].shape[2], pool["k"].shape[4]
+    new = jax.random.normal(jax.random.PRNGKey(6), (1, chunk, Hkv, D),
+                            jnp.bfloat16)
+    reps = 64
+
+    def body(i, pool):
+        # the start moves, so nothing is hoisted out of the loop
+        return pkv.write_chunk_paged_layer(pool, layer, table[3], i % 2, new,
+                                           new, page, n_valid=640)
+
+    write = jax.jit(lambda pool: jax.lax.fori_loop(0, reps, body, pool),
+                    donate_argnums=(0,))
+    pool = jax.block_until_ready(write(dict(pool)))
+    t0, n = time.monotonic(), 5
+    for _ in range(n):
+        pool = write(pool)
+    jax.block_until_ready(pool)
+    say(f"chunk write {tag}, {chunk}-row chunk, 640 live, K and V of one "
+        f"layer: {(time.monotonic() - t0) / (n * reps) * 1e3:.3f} ms")
 
 
 def ragged_call_time(pa, pool, skw, table, lengths, layer, Hq, D, chunk,

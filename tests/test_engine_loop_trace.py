@@ -178,6 +178,13 @@ def test_every_dispatch_leaves_one_record(model, serving_kw, traffic,
         assert e["t_ready"] >= e["t_enqueue"]
         if "chunk_n" in e:
             assert 0 < e["chunk_n"] <= e["chunk_rows"]
+        # a mixed step's chunk is written a page window at a time: the
+        # record says how many hold one of its rows (page_size 32 here)
+        assert ("write_pages" in e) == (e["program"] == "mixed_step")
+        if "write_pages" in e:
+            first, last = e["chunk_off"], e["chunk_off"] + e["chunk_n"] - 1
+            assert e["write_pages"] == len({r // 32 for r in
+                                            range(first, last + 1)})
         if "prompt_tokens" in e and "padded_tokens" in e:
             assert e["prompt_tokens"] <= e["padded_tokens"]
     # the kind under test dispatched, and devmon booked it under the same

@@ -163,8 +163,6 @@ def _mixed_rows(cfg, tree, live_ids, chunk_ids, C=16):
     is_p = jnp.arange(3) == 1
     crows = jnp.arange(C, dtype=jnp.int32)
     is_pad = crows >= n
-    write_rows = jnp.concatenate([jnp.where(is_p, -1, lengths),
-                                  jnp.where(is_pad, -1, crows)])
     row_limits = jnp.concatenate([jnp.where(is_p, 0, lengths + 1),
                                   jnp.where(is_pad, 0, crows + 1)])
     row_tables = jnp.concatenate(
@@ -175,8 +173,9 @@ def _mixed_rows(cfg, tree, live_ids, chunk_ids, C=16):
         [jnp.asarray([live_ids[-1], 0, 0], jnp.int32), jnp.asarray(ptok)])
     positions = jnp.concatenate([jnp.where(is_p, 0, lengths), crows])
     live = jnp.concatenate([jnp.asarray([True, False, False]), ~is_pad])
-    attend = make_mixed_attend_carry_paged(write_rows, row_limits,
-                                           row_tables, impl="xla")
+    attend = make_mixed_attend_carry_paged(
+        jnp.where(is_p, -1, lengths), jnp.int32(0), jnp.int32(n), row_limits,
+        row_tables, impl="xla")
     with moe.routed_rows(live) as routing:
         logits, _ = model_forward_carry(tree, cfg, packed[None],
                                         positions[None], pool, attend)

@@ -450,30 +450,92 @@ def test_gather_restore_roundtrip():
                                       before[name][:, untouched])
 
 
-@pytest.mark.parametrize("quant", [False, True])
-def test_paged_write_row_kernel_packed_rows_of_one_slot(quant):
-    """serving/programs.mixed_step's layout: one row per slot, then a run of
-    rows of ONE slot (consecutive grid steps on one 8- or 32-row block),
-    then dropped rows — against the jnp scatter. Merging into the input copy
-    of the block kept one row of each run (my chip run, PR 25)."""
-    _, pool, table = _identity_layout(quant=quant, perm_seed=29)
-    tab = np.asarray(table)
-    rows = jnp.asarray([5, -1, SV - 1] + list(range(3, 3 + 40)) + [-1] * 5,
-                       jnp.int32)
-    N = rows.shape[0]
-    rtab = jnp.asarray(np.concatenate([tab, np.repeat(tab[1:2], N - B, 0)]))
-    new = jax.random.normal(jax.random.PRNGKey(13), (N, 2, 16))
-    layer = jnp.int32(1)
-    want = pkv.write_token_layer_paged(pool, layer, rows, rtab, new[:, None],
-                                       new[:, None], PS)
+MIX_PS, MIX_C = 32, 96          # page, chunk rows (three pages)
+
+
+@pytest.mark.parametrize("impl", ["pallas", "xla", "pallas-tp2"])
+@pytest.mark.parametrize("hkv", [8, 16])
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("plen", [1, MIX_PS - 1, MIX_PS + 3, MIX_C])
+@pytest.mark.parametrize("first", [0, 17])
+def test_mixed_step_write_path_matches_row_by_row(first, plen, quant, hkv,
+                                                  impl):
+    """serving/programs.mixed_step's K/V writes as ops/attention
+    .make_mixed_attend_carry_paged makes them — one row a decode slot, the
+    chunk as ONE span of the chunking slot's page run, a page window at a
+    time; through the row kernel (interpret mode), through the XLA writers,
+    and under ``shard_map`` with the KV heads over two devices as ``--tp``
+    runs it — against a plain numpy loop over the rows. The chunk starts
+    ``first`` rows into a page, holds ``plen`` tokens of MIX_C (the padding
+    behind them writes nothing), its slot's table is allocated only as far
+    as the prompt reaches (the tail names the scratch page, as the engine
+    leaves it) and its own decode row is dropped. The whole pool is
+    compared, so rows of a touched page outside [pstart, pstart + plen), the
+    scratch page and every other layer keep their content."""
+    from aws_k8s_ansible_provisioner_tpu.ops.attention import (
+        make_mixed_attend_carry_paged)
+
+    ps, C, nB, MP, D, L = MIX_PS, MIX_C, 3, 6, 16, 2
+    rng = np.random.default_rng(1000 * first + 10 * plen + hkv + quant)
+    P = 1 + nB * MP
+    shape = (L, P, hkv, ps, D)
     if quant:
-        pk, pks = pa.cache_write_row_quant_paged(
-            pool["k"], pool["ks"], new, rows, rtab, layer, interpret=True,
-            packed=True)
-        # the scales: one ulp between the kernel's division and XLA's
-        np.testing.assert_allclose(np.asarray(pks), np.asarray(want["ks"]),
-                                   rtol=1e-6)
+        pool = {n: jnp.asarray(rng.integers(-127, 128, shape, dtype=np.int8))
+                for n in ("k", "v")}
+        for n in ("ks", "vs"):
+            pool[n] = jnp.asarray(rng.random(
+                (L, P, hkv, pkv.scale_lanes(ps)), dtype=np.float32))
     else:
-        pk = pa.cache_write_row_paged(pool["k"], new, rows, rtab, layer,
-                                      interpret=True, packed=True)
-    np.testing.assert_array_equal(np.asarray(pk), np.asarray(want["k"]))
+        pool = {n: jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+                for n in ("k", "v")}
+    # everything is compared as float32: exact for bf16 and int8 alike
+    want = {n: np.array(a, np.float32) for n, a in pool.items()}
+    pslot, pstart, layer = 1, ps + first, 1
+    table = rng.permutation(np.arange(1, P)).reshape(nB, MP).astype(np.int32)
+    table[pslot, (pstart + plen - 1) // ps + 1:] = 0     # unallocated tail
+    lengths = np.array([5, 70, MP * ps - 1], np.int32)
+    dec_rows = np.where(np.arange(nB) == pslot, -1, lengths).astype(np.int32)
+    is_pad = np.arange(C) >= plen
+    limits = np.concatenate([np.where(dec_rows < 0, 0, lengths + 1),
+                             np.where(is_pad, 0, pstart + np.arange(C) + 1)])
+    tabs = np.concatenate([table, np.repeat(table[pslot:pslot + 1], C, 0)])
+    q, k, v = (jax.random.normal(jax.random.PRNGKey(i), (1, nB + C, hkv, D),
+                                 jnp.bfloat16) for i in (3, 4, 5))
+    mesh = None
+    if impl == "pallas-tp2":
+        from aws_k8s_ansible_provisioner_tpu.config import MeshConfig
+        from aws_k8s_ansible_provisioner_tpu.parallel.mesh import make_mesh
+        if len(jax.devices()) < 2:
+            pytest.skip("needs two devices")
+        mesh = make_mesh(MeshConfig(tp=2))
+    attend = make_mixed_attend_carry_paged(
+        jnp.asarray(dec_rows), jnp.int32(pstart), jnp.int32(plen),
+        jnp.asarray(limits, jnp.int32), jnp.asarray(tabs),
+        impl=impl.split("-")[0], mesh=mesh)
+    _, (got, _) = jax.jit(attend)(q, k, v, (pool, jnp.int32(layer)))
+
+    where = [(b, int(dec_rows[b]), table[b]) for b in range(nB)
+             if dec_rows[b] >= 0]
+    where += [(nB + i, pstart + i, table[pslot]) for i in range(plen)]
+    for name, val in (("k", k[0]), ("v", v[0])):
+        scales = None
+        if quant:
+            val, scales = kvc.quantize_rows(val)
+        val = np.asarray(val, np.float32)
+        for i, r, tab in where:
+            want[name][layer, tab[r // ps], :, r % ps] = val[i]
+            if quant:
+                want[name + "s"][layer, tab[r // ps], :, r % ps] = scales[i]
+    for name, w in want.items():
+        g = np.asarray(got[name], np.float32)
+        if name in ("ks", "vs"):
+            # the scales: one ulp between the kernel's division and XLA's
+            np.testing.assert_allclose(g, w, rtol=1e-6, err_msg=name)
+        elif quant:
+            # ... which may round a value the other way (quantize_rows'
+            # contract: one int8 step); untouched rows are exact either way
+            diff = np.abs(g - w)
+            assert diff.max() <= 1, name
+            assert (diff > 0).sum() <= max(4, len(where) * hkv * D // 1000)
+        else:
+            np.testing.assert_array_equal(g, w, err_msg=name)

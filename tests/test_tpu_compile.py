@@ -131,23 +131,19 @@ def test_paged_attention_kernel_compiles_for_v5e(chip, entry, quant, bb,
     _assert_named_after_wrapper(compiled, fn)
 
 
-@pytest.mark.parametrize("packed", [False, True], ids=["slots", "packed"])
 @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
-def test_paged_write_kernel_compiles_for_v5e(chip, quant, packed):
-    """One row per slot (decode), and the mixed program's packed rows, whose
-    steps merge into the output block (``packed``)."""
+def test_paged_write_kernel_compiles_for_v5e(chip, quant):
+    """One row per slot: the decode program's write, and the decode rows'
+    of the mixed program."""
     sds, kv, skw = _pool(chip, quant)
-    n = B + CHUNK if packed else B
-    rows, lay = sds((n,), jnp.int32), sds((), jnp.int32)
-    table, new = sds((n, 32), jnp.int32), sds((n, HKV, D), jnp.bfloat16)
+    rows, lay = sds((B,), jnp.int32), sds((), jnp.int32)
+    table, new = sds((B, 32), jnp.int32), sds((B, HKV, D), jnp.bfloat16)
     if quant:
-        compiled = _compile(
-            functools.partial(pa.cache_write_row_quant_paged, packed=packed),
-            kv, skw["pool_ks"], new, rows, table, lay)
+        compiled = _compile(pa.cache_write_row_quant_paged, kv,
+                            skw["pool_ks"], new, rows, table, lay)
     else:
-        compiled = _compile(
-            functools.partial(pa.cache_write_row_paged, packed=packed),
-            kv, new, rows, table, lay)
+        compiled = _compile(pa.cache_write_row_paged, kv, new, rows, table,
+                            lay)
         _assert_named_after_wrapper(compiled, pa.cache_write_row_paged)
     assert "tpu_custom_call" in compiled.as_text()
 
@@ -156,16 +152,13 @@ POOL_BYTES = 2 * L * P * HKV * PS * D * 2
 
 
 def test_paged_write_kernel_compiles_for_v5e_at_mha_heads(chip):
-    """cache_write_row_paged at OLMoE's 16 KV heads, slots and packed."""
+    """cache_write_row_paged at OLMoE's 16 KV heads, one row a slot."""
     sds, kv, _ = _pool(chip, False, hkv=16)
-    for rows, packed in ((24, False), (24 + CHUNK, True)):
-        new = sds((rows, 16, D), jnp.bfloat16)
-        compiled = _compile(
-            functools.partial(pa.cache_write_row_paged, packed=packed),
-            kv, new, sds((rows,), jnp.int32), sds((rows, 32), jnp.int32),
-            sds((), jnp.int32))
-        assert "tpu_custom_call" in compiled.as_text()
-        _assert_named_after_wrapper(compiled, pa.cache_write_row_paged)
+    compiled = _compile(
+        pa.cache_write_row_paged, kv, sds((24, 16, D), jnp.bfloat16),
+        sds((24,), jnp.int32), sds((24, 32), jnp.int32), sds((), jnp.int32))
+    assert "tpu_custom_call" in compiled.as_text()
+    _assert_named_after_wrapper(compiled, pa.cache_write_row_paged)
 
 
 @pytest.mark.parametrize("rows,form", [(24, "every-expert"),
@@ -273,3 +266,50 @@ def test_smallest_prefill_program_holds_no_pool_copy(chip, monkeypatch):
         if p[0] == "prefill_b32")
     compiled = fn.lower(*args, **kwargs).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < POOL_BYTES // 16
+
+
+@pytest.mark.parametrize(
+    "model,slots", [("Qwen/Qwen3-0.6B", 32), ("Qwen/Qwen3-8B", 16),
+                    ("allenai/OLMoE-1B-7B-0125-Instruct", 24)],
+    ids=["qwen3-0.6b", "qwen3-8b", "olmoe-1b-7b"])
+def test_mixed_step_holds_no_pool_copy(chip, monkeypatch, model, slots):
+    """The whole ``mixed_step`` of each benchmark cell (int8 weights, bf16
+    KV, 2,048-row chunk, block 8), from the engine's own enumeration. Its
+    body holds an aliased Pallas row write (the decode rows), the chunk's
+    page-window scatter and the ragged kernel on ONE pool: nothing but
+    those writers may produce a pool-shaped value (a copy or a relayout
+    would), and the temporaries stay what the packed rows' float32 logits
+    take."""
+    import math
+    import re
+
+    from aws_k8s_ansible_provisioner_tpu.config import (MODEL_REGISTRY,
+                                                        ServingConfig)
+    from aws_k8s_ansible_provisioner_tpu.serving import aot
+
+    monkeypatch.setattr(pa, "supported", lambda: True)
+    cfg = MODEL_REGISTRY[model]
+    plan = aot.ProgramPlan(cfg, ServingConfig(
+        model=model, max_decode_slots=slots, max_cache_len=2048,
+        weights_dtype="int8", decode_bblock=8, kv_host_tier_bytes=0))
+    params, cache = aot._abstract_state(plan, None,
+                                        next(iter(chip.device_set)))
+    _, fn, args, kwargs = next(
+        p for p in aot.enumerate_programs(plan, None, params, cache,
+                                          bblock=8)
+        if p[0] == f"mixed_c{CHUNK}")
+    compiled = fn.lower(*args, **kwargs).compile()
+    leaf = cache["k"]
+    assert leaf.dtype == jnp.bfloat16
+    logits = (slots + CHUNK) * cfg.vocab_size * 4
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < logits + 2 * math.prod(leaf.shape) // 8
+    text = compiled.as_text()
+    shape = re.escape("bf16[" + ",".join(map(str, leaf.shape)) + "]")
+    makers = set(re.findall(rf" = {shape}\S* ([\w\-]+)\(", text))
+    assert makers <= {"parameter", "get-tuple-element", "custom-call",
+                      "scatter", "fusion"}, makers
+    # the decode rows still go through the row kernel, attention through
+    # the ragged one (the names the benchmark's readers match)
+    _assert_named_after_wrapper(compiled, pa.cache_write_row_paged)
+    _assert_named_after_wrapper(compiled, pa.ragged_attend_pallas_paged)
