@@ -679,8 +679,8 @@ class ServingConfig:
     # as the decode batch (one ragged dispatch packs the chunk's tokens
     # alongside every decode row against the paged pool), so admissions no
     # longer drain the one-deep pipeline and the chunk/decode alternation
-    # disappears. Requires paged + decode_pipeline; auto-falls-back to the
-    # legacy serialized chunk path for dp/sp meshes or a draining engine
+    # disappears. Requires decode_pipeline; auto-falls-back to the
+    # legacy serialized chunk path for dp meshes or a draining engine
     # (and, with ragged_features=0, for spec decode / LoRA / guided slots).
     # 0 restores the legacy path everywhere (sync escape hatch; seeded
     # streams are byte-identical either way).
@@ -695,23 +695,18 @@ class ServingConfig:
     # to the sync floor) — the byte-identity A/B fallback arm; seeded
     # streams are byte-identical either way.
     ragged_features: int = 1
-    # Paged KV cache geometry.
+    # Paged KV (vLLM's on-demand block allocation; serving/paged_kv.py), the
+    # server's only KV layout: a shared physical page pool + per-slot block
+    # tables, so HBM cost tracks ACTUAL sequence lengths and admission is
+    # gated by free pages, not free slots. Composes with tp meshes (heads
+    # sharded over the pool) and dp meshes (pool page axis partitioned per
+    # dp group, per-group host allocators).
     page_size: int = 64
-    # True paged KV (vLLM's on-demand block allocation; serving/paged_kv.py):
-    # a shared physical page pool + per-slot block tables replace the
-    # slot-contiguous per-slot reservation, so HBM cost tracks ACTUAL
-    # sequence lengths and admission is gated by free pages, not free slots.
-    # Composes with tp meshes (heads sharded over the pool) and dp meshes
-    # (pool page axis partitioned per dp group, per-group host allocators);
-    # only sp meshes fall back to the dense layout (a page is a contiguous
-    # row run — splitting it across sequence shards defeats paging). The
-    # engine picks automatically.
-    paged: bool = True
     # Physical pages in the pool. 0 = max_decode_slots * ceil(max_cache_len /
-    # page_size) — the same HBM as the dense cache, useful as a drop-in.
-    # Sizing it SMALLER is the point of paging: e.g. 4x the slots of a dense
-    # config with the same pool lets 4x the concurrent short requests share
-    # the HBM that dense sizing reserves for worst-case windows; when the
+    # page_size) — a full window for every slot. Sizing it SMALLER is the
+    # point of paging: e.g. 4x the slots over the same pool lets 4x the
+    # concurrent short requests share the HBM that a full window per slot
+    # reserves for the worst case; when the
     # pool runs dry mid-decode the engine preempts the newest request
     # (vLLM-style recompute) rather than failing.
     kv_pool_pages: int = 0
@@ -737,19 +732,11 @@ class ServingConfig:
     # behavior inside the reference's serving pods). 0 disables chunking.
     prefill_chunk: int = 0
     # Automatic prefix caching (the vLLM feature of the same name): a new
-    # prompt sharing >= prefix_cache_min_len leading tokens with K/V rows
-    # still resident in another slot reuses them via one slot-to-slot row
-    # copy; only the suffix is prefilled (through the chunk program).
+    # prompt whose leading whole pages hash-match pages still in the pool
+    # shares them (refcounted, no copy); only the suffix is prefilled
+    # (through the chunk program).
     prefix_cache: bool = True
-    prefix_cache_min_len: int = 32
-    # A hit that ADDS dispatches vs the whole-prompt path (copy + suffix
-    # chunks > one bucket dispatch) must reuse at least this many rows: each
-    # extra dispatch is ~an RTT of latency, so small reuses only pay once
-    # the recomputed-prefill FLOPs they save outweigh it. Hits that don't
-    # add dispatches (same-slot reuse, would-chunk-anyway prompts) are
-    # always taken. See Engine._hit_pays.
-    prefix_cache_payback_rows: int = 256
-    # Paged-mode burst economics: under a burst the batched prefill normally
+    # Burst economics: under a burst the batched prefill normally
     # beats a prefix hit (a hit forces the serialized chunk walk), so
     # matches are dropped — UNLESS the reusable prefix spans at least this
     # many whole pages, where skipping the shared-prefix compute (and
@@ -909,7 +896,7 @@ class ServingConfig:
     # decode HBM streaming and half the cache footprint (so ~2x the slots fit
     # beside the weights), at near-lossless attention accuracy. The vLLM
     # engine inside the reference's serving pods ships the same knob as
-    # ``kv_cache_dtype``. See serving/kv_cache.py.
+    # ``kv_cache_dtype``. See ops/kv_pool.py.
     kv_dtype: str = "auto"
     # Weight storage dtype. "int8" is the SHIPPED DEFAULT (r6): weights-only
     # per-out-channel quantization at engine start (models/quant.py) halves
@@ -1012,11 +999,10 @@ def ansible_vars(cfg: FrameworkConfig | None = None,
     # a single source, unlike the reference's duplicated literals (SURVEY.md §1).
     d["model"] = cfg.serving.model
     d["serving_port"] = cfg.serving.port
-    # Serving mesh (chips per engine pod = tp * dp * sp; serving.yaml.j2
+    # Serving mesh (chips per engine pod = tp * dp * ep; serving.yaml.j2
     # passes these to the engine CLI and sizes the google.com/tpu limit).
     d["serving_tp"] = cfg.serving.mesh.tp
     d["serving_dp"] = cfg.serving.mesh.dp
-    d["serving_sp"] = cfg.serving.mesh.sp
     d["serving_ep"] = cfg.serving.mesh.ep
     d["serving_kv_dtype"] = cfg.serving.kv_dtype
     d["serving_weights_dtype"] = cfg.serving.weights_dtype

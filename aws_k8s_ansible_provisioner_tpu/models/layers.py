@@ -493,18 +493,18 @@ def model_forward_carry(
 ) -> Tuple[jnp.ndarray, Any]:
     """Decoder forward with the cache in the scan CARRY, not xs/ys.
 
-    ``model_forward`` streams per-layer cache slices through the layer scan as
-    xs and re-stacks them as ys — XLA cannot alias a scan's xs buffers to its
-    ys buffers, so every call pays a full-cache copy (for a batch-32
-    Qwen3-0.6B decode step that is ~7 GB of HBM traffic for a ~100 KB logical
-    write; measured 24 ms vs ~4 ms of useful work on v5e). Here the FULL cache
-    rides the carry — XLA's while-loop carry aliasing keeps it in place — and
-    ``attend`` receives ``(cache, layer_idx)``, writes via in-place scatter
-    (kv_cache.write_token_layer) and reads via the layer-indexed Pallas kernel
-    (ops/pallas_attention.decode_attend_pallas_layer), so per-step HBM traffic
-    is weights + live cache rows only. This is the serving decode hot path;
-    prefill keeps the xs/ys form (a prefill writes a whole prompt, so the copy
-    amortizes over many tokens).
+    Streaming per-layer cache slices through the layer scan as xs and
+    re-stacking them as ys (what ``model_forward`` does with a ``cache``)
+    costs a full-cache copy a call — XLA cannot alias a scan's xs buffers to
+    its ys buffers (for a batch-32 Qwen3-0.6B decode step that is ~7 GB of
+    HBM traffic for a ~100 KB logical write; measured 24 ms vs ~4 ms of
+    useful work on v5e; a prefill's restack buffer OOMed at batch 128). Here
+    the FULL cache rides the carry — XLA's while-loop carry aliasing keeps it
+    in place — and ``attend`` receives ``(cache, layer_idx)``, writes in
+    place and reads the layer straight out of the full buffer
+    (ops/attention.py's callbacks over the paged pool), so per-step HBM
+    traffic is weights + live cache rows only. Every serving step program
+    runs this form.
     """
     x, cos, sin = _embed_inputs(params, cfg, tokens, positions)
     from aws_k8s_ansible_provisioner_tpu.ops import moe

@@ -21,33 +21,41 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from aws_k8s_ansible_provisioner_tpu.serving import kv_cache as kvc
+from aws_k8s_ansible_provisioner_tpu.ops import kv_pool as kvp
+
 
 
 def decode_attend(q: jnp.ndarray, cache_k: jnp.ndarray, cache_v: jnp.ndarray,
                   lengths: jnp.ndarray, window: int = 0) -> jnp.ndarray:
-    """Cached decode attention for one new token per slot.
+    """Cached decode attention, R query rows per slot (plain decode: R = 1;
+    speculative verify: R > 1).
 
-    q: [B, 1, Hq, D]; cache_k/v: [B, Hkv, S, D] head-major (already containing
-    the new token's k/v at position lengths-1... i.e. caller writes first);
-    lengths: [B] = number of valid rows per slot (including the new token);
-    ``window`` > 0 = sliding-window attention (only the last ``window`` rows
-    are live). Returns [B, 1, Hq, D].
+    q: [B, R, Hq, D]; cache_k/v: [B, Hkv, S, D] head-major (already containing
+    the new tokens' k/v — the caller writes first); lengths: [B] = number of
+    valid rows per slot seen by query row 0 (including its own token); query
+    row r sees columns < lengths + r. ``window`` > 0 = sliding-window
+    attention (only the last ``window`` rows are live). Returns
+    [B, R, Hq, D].
     """
-    B, _, Hq, D = q.shape
+    B, R, Hq, D = q.shape
     Hkv, S = cache_k.shape[1], cache_k.shape[2]
     G = Hq // Hkv
-    qg = q[:, 0].reshape(B, Hkv, G, D).astype(jnp.float32)
+    # [B, Hkv, R*G, D]: the R rows ride the group axis of the GQA einsum
+    qg = q.reshape(B, R, Hkv, G, D).transpose(0, 2, 1, 3, 4).reshape(
+        B, Hkv, R * G, D).astype(jnp.float32)
     scale = 1.0 / jnp.sqrt(jnp.asarray(D, jnp.float32))
     logits = jnp.einsum("bkgd,bksd->bkgs", qg, cache_k.astype(jnp.float32)) * scale
-    valid = jnp.arange(S)[None, :] < lengths[:, None]          # [B, S]
+    limit = lengths[:, None] + jnp.arange(R)[None, :]          # [B, R]
+    cols = jnp.arange(S)[None, None, :]
+    valid = cols < limit[:, :, None]                           # [B, R, S]
     if window > 0:
-        valid = valid & (jnp.arange(S)[None, :]
-                         >= lengths[:, None] - window)
-    logits = jnp.where(valid[:, None, None, :], logits, -1e30)
+        valid = valid & (cols >= limit[:, :, None] - window)
+    valid = jnp.repeat(valid, G, axis=1)                       # [B, R*G, S]
+    logits = jnp.where(valid[:, None, :, :], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1)
     ctx = jnp.einsum("bkgs,bksd->bkgd", probs, cache_v.astype(jnp.float32))
-    return ctx.reshape(B, 1, Hq, D).astype(q.dtype)
+    ctx = ctx.reshape(B, Hkv, R, G, D).transpose(0, 2, 1, 3, 4)
+    return ctx.reshape(B, R, Hq, D).astype(q.dtype)
 
 
 def resolve_impl(impl: str = "auto") -> str:
@@ -65,298 +73,6 @@ def resolve_impl(impl: str = "auto") -> str:
 
         return "pallas" if pallas_attention.supported() else "xla"
     return impl
-
-
-def make_decode_attend_carry(lengths: jnp.ndarray, impl: str = "auto",
-                             mesh=None, window: int = 0,
-                             bblock: int = None):
-    """Carry-path decode attend: cache_l is ``(full_cache, layer_idx)``.
-
-    Used with ``models.layers.model_forward_carry`` — the full stacked cache
-    rides the layer-scan carry, the new token's K/V scatter in place
-    (kv_cache.write_token_layer), and the Pallas kernel reads the selected
-    layer straight out of the full buffer (no per-layer slice copy). The XLA
-    fallback pays one layer-slice copy per layer (fine on CPU, where the
-    tests run it; on TPU the Pallas path is the point).
-
-    Sharding: slots over ``dp``, kv heads over ``tp``, and the cache's
-    sequence axis over ``sp`` — shard_map runs the kernel on each device's
-    own cache shard (XLA can't partition a custom call on its own; without
-    shard_map it would force an all-gather of the cache). dp/tp decode needs
-    ZERO collectives. With ``sp > 1`` (long-context serving: the cache window
-    scales with the sp group's aggregate HBM) each shard computes flash
-    PARTIALS over its rows and the context is a log-sum-exp merge — one
-    [B,Hq,D]-sized psum per layer over ICI neighbors, the decode-side
-    equivalent of the training path's ring attention
-    (parallel/ring_attention.py).
-    """
-    resolved = resolve_impl(impl)
-    sp = mesh.shape.get("sp", 1) if mesh is not None else 1
-    if sp > 1 and window > 0:
-        # Enforced HERE, not only in Engine.__init__: the sp stats path below
-        # has no window support, and a direct caller must get an error — not
-        # silent full-attention results.
-        raise ValueError("sequence-parallel decode (sp > 1) does not compose "
-                         "with sliding-window attention")
-
-    def _write_attend(q, cache, knew, vnew, lens, layer):
-        """Per-shard body: in-place row writes + layer-indexed flash attend.
-
-        ``cache`` is the leaf dict ({k, v} bf16, or {k, v, ks, vs} int8 —
-        the quantized cache streams half the bytes and the kernels fold the
-        scales in VMEM). The writes use the aliased Pallas kernels — NOT a
-        functional scatter — so the multi-GB cache buffers are updated in
-        place even inside the decode scan's carry (XLA copy-insertion
-        materializes full-cache copies around scatters there; see
-        cache_write_row's docstring).
-        """
-        from aws_k8s_ansible_provisioner_tpu.ops import pallas_attention
-
-        interpret = not pallas_attention.supported()
-        ck, cv = cache["k"], cache["v"]
-        quant = "ks" in cache
-        S_local = ck.shape[3]
-        if sp > 1:
-            # This shard owns global rows [off, off + S_local). Writes use
-            # local row indices (non-owners fall out of [0, S) and DROP);
-            # reads mask by the local portion of each slot's length.
-            off = jax.lax.axis_index("sp").astype(jnp.int32) * S_local
-            w_rows = lens - off
-            r_lens = jnp.clip(lens + 1 - off, 0, S_local)
-        else:
-            w_rows = lens
-            r_lens = lens + 1
-        if quant:
-            ck, ks = pallas_attention.cache_write_row_quant(
-                ck, cache["ks"], knew, w_rows, layer, interpret=interpret)
-            cv, vs = pallas_attention.cache_write_row_quant(
-                cv, cache["vs"], vnew, w_rows, layer, interpret=interpret)
-            cache = {"k": ck, "v": cv, "ks": ks, "vs": vs}
-            scale_kw = dict(cache_ks=ks, cache_vs=vs)
-        else:
-            ck = pallas_attention.cache_write_row(ck, knew, w_rows, layer,
-                                                  interpret=interpret)
-            cv = pallas_attention.cache_write_row(cv, vnew, w_rows, layer,
-                                                  interpret=interpret)
-            cache = {"k": ck, "v": cv}
-            scale_kw = {}
-        if sp == 1:
-            ctx = pallas_attention.decode_attend_pallas_layer(
-                q, ck, cv, r_lens, layer, interpret=interpret,
-                window=window, bblock=bblock, **scale_kw)
-            return ctx, cache
-        # sp > 1 with a sliding window is rejected at Engine init: the
-        # window straddles shard boundaries and the partial merge would
-        # need cross-shard start offsets.
-        acc, m, l = pallas_attention.decode_attend_pallas_layer(
-            q, ck, cv, r_lens, layer, interpret=interpret, return_stats=True,
-            **scale_kw)
-        # Merge partial softmaxes across sequence shards. A shard with none
-        # of a slot's rows carries (acc=0, m=-inf, l=0); the -inf-safe
-        # weights zero it out of the combine.
-        m_glob = jax.lax.pmax(m, "sp")                        # [B, Hq]
-        m_safe = jnp.where(m_glob <= -1e29, 0.0, m_glob)
-        w = jnp.where(m <= -1e29, 0.0, jnp.exp(m - m_safe))
-        l_glob = jax.lax.psum(l * w, "sp")
-        acc_glob = jax.lax.psum(acc * w[..., None], "sp")
-        ctx = acc_glob / jnp.maximum(l_glob, 1e-9)[..., None]
-        return ctx[:, None].astype(q.dtype), cache
-
-    def attend(q, k, v, cache_l) -> Tuple[jnp.ndarray, tuple]:
-        cache, layer = cache_l
-        if resolved == "pallas":
-            knew, vnew = k[:, 0], v[:, 0]
-            if mesh is not None:
-                from jax.sharding import PartitionSpec as P
-
-                from aws_k8s_ansible_provisioner_tpu.parallel.sharding import (
-                    cache_pspecs)
-
-                # single source of sharding truth: the same specs the Engine
-                # allocates the cache with
-                cache_spec = cache_pspecs(quant=kvc.is_quantized(cache))
-                fn = jax.shard_map(
-                    _write_attend, mesh=mesh,
-                    in_specs=(P("dp", None, "tp", None),  # q [B,1,Hq,D]
-                              cache_spec,                 # cache leaf dict
-                              P("dp", "tp", None),        # knew [B,Hkv,D]
-                              P("dp", "tp", None),        # vnew
-                              P("dp"),                    # lengths [B]
-                              P()),                       # layer scalar
-                    out_specs=(P("dp", None, "tp", None), cache_spec),
-                    check_vma=False,
-                )
-                ctx, cache = fn(q, cache, knew, vnew, lengths, layer)
-            else:
-                ctx, cache = _write_attend(q, cache, knew, vnew, lengths,
-                                           layer)
-        else:
-            cache = kvc.write_token_layer(cache, layer, lengths, k, v)
-
-            def layer_slice(name):
-                return jax.lax.dynamic_index_in_dim(cache[name], layer, 0,
-                                                    keepdims=False)
-
-            ck, cv = layer_slice("k"), layer_slice("v")
-            if kvc.is_quantized(cache):
-                # model dtype, not f32: attention upcasts internally anyway
-                ck = kvc.dequantize(ck, layer_slice("ks"), dtype=q.dtype)
-                cv = kvc.dequantize(cv, layer_slice("vs"), dtype=q.dtype)
-            ctx = decode_attend(q, ck, cv, lengths + 1, window=window)
-        return ctx, (cache, layer)
-
-    return attend
-
-
-def decode_attend_multi(q: jnp.ndarray, cache_k: jnp.ndarray,
-                        cache_v: jnp.ndarray, base_lens: jnp.ndarray,
-                        window: int = 0) -> jnp.ndarray:
-    """XLA fallback for speculative verify: R query rows per slot.
-
-    q: [B, R, Hq, D]; cache_k/v: [B, Hkv, S, D] (rows base..base+R-1 already
-    written); query row r sees columns < base_lens + 1 + r. Returns
-    [B, R, Hq, D].
-    """
-    B, R, Hq, D = q.shape
-    Hkv, S = cache_k.shape[1], cache_k.shape[2]
-    G = Hq // Hkv
-    qg = q.reshape(B, R, Hkv, G, D).astype(jnp.float32)
-    scale = 1.0 / jnp.sqrt(jnp.asarray(D, jnp.float32))
-    logits = jnp.einsum("brkgd,bksd->brkgs", qg,
-                        cache_k.astype(jnp.float32)) * scale
-    limit = base_lens[:, None] + 1 + jnp.arange(R)[None, :]    # [B, R]
-    valid = jnp.arange(S)[None, None, :] < limit[:, :, None]   # [B, R, S]
-    if window > 0:
-        valid = valid & (jnp.arange(S)[None, None, :]
-                         >= limit[:, :, None] - window)
-    logits = jnp.where(valid[:, :, None, None, :], logits, -1e30)
-    probs = jax.nn.softmax(logits, axis=-1)
-    ctx = jnp.einsum("brkgs,bksd->brkgd", probs,
-                     cache_v.astype(jnp.float32))
-    return ctx.reshape(B, R, Hq, D).astype(q.dtype)
-
-
-def make_spec_attend_carry(lengths: jnp.ndarray, impl: str = "auto",
-                           mesh=None, window: int = 0):
-    """Carry-path attend for SPECULATIVE verify: R tokens per slot per step.
-
-    Same cache-in-scan-carry structure as make_decode_attend_carry, but the
-    incoming q/k/v carry R rows (last accepted token + R-1 prompt-lookup
-    drafts): all R K/V rows are written at positions lengths..lengths+R-1
-    (in-place Pallas row writes, R static unrolled — each a ~rows-sized DMA),
-    then one flash pass answers all R queries against one cache stream
-    (decode_attend_pallas_spec). Rows past the eventually-accepted prefix
-    hold garbage K/V beyond the slot's new length — overwritten when those
-    positions are next processed, the engine's standard surplus-write
-    invariant.
-
-    With a ``mesh``: heads shard over ``tp`` and shard_map runs the verify
-    kernel per shard, exactly like make_decode_attend_carry — every tp shard
-    sees identical token streams, so the data-dependent accept length is
-    shard-invariant and speculation is lossless under pure tp (vLLM runs
-    spec decode under TP for the same reason; VERDICT r3 missing #2). The
-    Engine gates spec to dp == 1 and sp == 1: dp shards SLOTS (per-group
-    accept lengths would desync the groups' fused horizons) and the sp
-    partial-softmax merge has no spec variant.
-    """
-    resolved = resolve_impl(impl)
-
-    def _write_attend_spec(q, cache, k, v, lens, layer):
-        """Per-shard body: R in-place row writes + one multi-query flash."""
-        from aws_k8s_ansible_provisioner_tpu.ops import pallas_attention
-
-        interpret = not pallas_attention.supported()
-        R = q.shape[1]
-        quant = kvc.is_quantized(cache)
-        ck, cv = cache["k"], cache["v"]
-        if quant:
-            ks, vs = cache["ks"], cache["vs"]
-            for r in range(R):
-                ck, ks = pallas_attention.cache_write_row_quant(
-                    ck, ks, k[:, r], lens + r, layer,
-                    interpret=interpret)
-                cv, vs = pallas_attention.cache_write_row_quant(
-                    cv, vs, v[:, r], lens + r, layer,
-                    interpret=interpret)
-            cache = {"k": ck, "v": cv, "ks": ks, "vs": vs}
-            scale_kw = dict(cache_ks=ks, cache_vs=vs)
-        else:
-            for r in range(R):
-                ck = pallas_attention.cache_write_row(
-                    ck, k[:, r], lens + r, layer, interpret=interpret)
-                cv = pallas_attention.cache_write_row(
-                    cv, v[:, r], lens + r, layer, interpret=interpret)
-            cache = {"k": ck, "v": cv}
-            scale_kw = {}
-        ctx = pallas_attention.decode_attend_pallas_spec(
-            q, ck, cv, lens, layer, interpret=interpret,
-            window=window, **scale_kw)
-        return ctx, cache
-
-    def attend(q, k, v, cache_l) -> Tuple[jnp.ndarray, tuple]:
-        cache, layer = cache_l
-        if resolved == "pallas":
-            if mesh is not None:
-                from jax.sharding import PartitionSpec as P
-
-                from aws_k8s_ansible_provisioner_tpu.parallel.sharding import (
-                    cache_pspecs)
-
-                cache_spec = cache_pspecs(quant=kvc.is_quantized(cache))
-                fn = jax.shard_map(
-                    _write_attend_spec, mesh=mesh,
-                    in_specs=(P("dp", None, "tp", None),  # q [B,R,Hq,D]
-                              cache_spec,                 # cache leaf dict
-                              P("dp", None, "tp", None),  # k [B,R,Hkv,D]
-                              P("dp", None, "tp", None),  # v
-                              P("dp"),                    # lengths [B]
-                              P()),                       # layer scalar
-                    out_specs=(P("dp", None, "tp", None), cache_spec),
-                    check_vma=False,
-                )
-                ctx, cache = fn(q, cache, k, v, lengths, layer)
-            else:
-                ctx, cache = _write_attend_spec(q, cache, k, v, lengths,
-                                                layer)
-            return ctx, (cache, layer)
-        # XLA fallback: scatter all R rows, then the multi-query masked attend
-        R = q.shape[1]
-        for r in range(R):
-            cache = kvc.write_token_layer(cache, layer, lengths + r,
-                                          k[:, r:r + 1], v[:, r:r + 1])
-
-        def layer_slice(name):
-            return jax.lax.dynamic_index_in_dim(cache[name], layer, 0,
-                                                keepdims=False)
-
-        ck, cv = layer_slice("k"), layer_slice("v")
-        if kvc.is_quantized(cache):
-            ck = kvc.dequantize(ck, layer_slice("ks"), dtype=q.dtype)
-            cv = kvc.dequantize(cv, layer_slice("vs"), dtype=q.dtype)
-        ctx = decode_attend_multi(q, ck, cv, lengths, window=window)
-        return ctx, (cache, layer)
-
-    return attend
-
-
-def make_prefill_attend_batch(slots: jnp.ndarray, seq_lens: jnp.ndarray,
-                              window: int = 0):
-    """Attend callback for BATCHED prefill: N prompts into N slots at once.
-
-    One dispatch prefills up to ``max_prefill_batch`` queued prompts — under a
-    burst, TTFT p50 scales with ceil(N/batch) dispatches instead of N
-    (VERDICT r1 missing #4). Padding rows carry an out-of-range slot index;
-    their cache writes are dropped (kv_cache.write_prompts mode='drop') and
-    their sampled tokens ignored by the host.
-    """
-    from aws_k8s_ansible_provisioner_tpu.models.layers import causal_attend
-
-    def attend(q, k, v, cache_l) -> Tuple[jnp.ndarray, dict]:
-        ctx = causal_attend(q, k, v, seq_lens=seq_lens, window=window)
-        cache_l = kvc.write_prompts(cache_l, slots, k, v)
-        return ctx, cache_l
-
-    return attend
 
 
 def chunk_attend(q: jnp.ndarray, ck: jnp.ndarray, cv: jnp.ndarray,
@@ -387,56 +103,12 @@ def chunk_attend(q: jnp.ndarray, ck: jnp.ndarray, cv: jnp.ndarray,
     return ctx.reshape(C, Hq, D)[None].astype(q.dtype)
 
 
-def make_chunk_prefill_attend(slot: jnp.ndarray, start: jnp.ndarray,
-                              window: int = 0):
-    """Attend callback for CHUNKED prefill: one chunk of a long prompt.
-
-    Writes the chunk's K/V rows into the slot, then attends the chunk queries
-    over the whole cached prefix (earlier chunks + this one). Decode steps for
-    other slots interleave between chunk dispatches, so in-flight streams keep
-    progressing during a long prefill — the vLLM chunked-prefill behavior
-    inside the reference's serving pods (SURVEY.md §7 hard part #2).
-    """
-
-    def attend(q, k, v, cache_l) -> Tuple[jnp.ndarray, dict]:
-        cache_l = kvc.write_chunk(cache_l, slot, start, k, v)
-        ck, cv = cache_l["k"][slot], cache_l["v"][slot]
-        if kvc.is_quantized(cache_l):
-            # Dequantized [Hkv, S, D] slices materialize per layer — a
-            # prefill-only cost that amortizes over the chunk's tokens (the
-            # decode hot loop never does this; its kernels fold the scales).
-            ck = kvc.dequantize(ck, cache_l["ks"][slot], dtype=q.dtype)
-            cv = kvc.dequantize(cv, cache_l["vs"][slot], dtype=q.dtype)
-        ctx = chunk_attend(q, ck, cv, start, window=window)
-        return ctx, cache_l
-
-    return attend
-
-
-def make_prefill_attend(slot: jnp.ndarray, seq_len: jnp.ndarray,
-                        window: int = 0):
-    """Attend callback for single-sequence prefill into one cache slot.
-
-    Causal attention over the (padded) prompt window + write of k/v rows into the
-    slot. ``seq_len`` masks right padding so padded keys never contribute.
-    """
-    from aws_k8s_ansible_provisioner_tpu.models.layers import causal_attend
-
-    def attend(q, k, v, cache_l) -> Tuple[jnp.ndarray, dict]:
-        ctx = causal_attend(q, k, v, seq_lens=seq_len[None], window=window)
-        cache_l = kvc.write_prompt(cache_l, slot, k, v)
-        return ctx, cache_l
-
-    return attend
-
-
 # ---------------------------------------------------------------------------
-# Paged variants (serving/paged_kv.py pool + block tables). Same contracts as
-# their dense counterparts; the ONLY difference is physical addressing via the
-# per-slot page table. Compose with tp meshes (heads sharded over the pool)
-# and dp meshes (page axis partitioned per dp group; tables carry GLOBAL ids
-# the shard_map bodies rebase — parallel/sharding.pool_pspecs). Only sp
-# serves the dense layout (a page is a contiguous row run).
+# Attend callbacks over the paged pool (ops/kv_pool.py pool + block
+# tables): physical addressing goes through the per-slot page table. They
+# compose with tp meshes (heads sharded over the pool) and dp meshes (page
+# axis partitioned per dp group; tables carry GLOBAL ids the shard_map bodies
+# rebase — parallel/sharding.pool_pspecs).
 # ---------------------------------------------------------------------------
 
 
@@ -453,8 +125,8 @@ def make_decode_attend_carry_paged(lengths: jnp.ndarray, table: jnp.ndarray,
     (parallel/sharding.pool_pspecs) and shard_map runs the paged kernels on
     each chip's head slice of every page — the block table, lengths, and
     allocator are head-independent and shared verbatim. The tp flagship
-    config (Qwen3-8B over v5e-8 ICI) thus keeps on-demand paging; dp/sp
-    meshes serve the dense layout (Engine gates)."""
+    config (Qwen3-8B over v5e-8 ICI) thus keeps on-demand paging; under a
+    dp mesh the table's GLOBAL page ids are rebased per shard."""
     resolved = resolve_impl(impl)
 
     dp = mesh.shape.get("dp", 1) if mesh is not None else 1
@@ -491,8 +163,6 @@ def make_decode_attend_carry_paged(lengths: jnp.ndarray, table: jnp.ndarray,
         return ctx, pool
 
     def attend(q, k, v, cache_l) -> Tuple[jnp.ndarray, tuple]:
-        from aws_k8s_ansible_provisioner_tpu.serving import paged_kv as pkv
-
         pool, layer = cache_l
         ps = pool["k"].shape[3]
         if resolved == "pallas":
@@ -521,13 +191,13 @@ def make_decode_attend_carry_paged(lengths: jnp.ndarray, table: jnp.ndarray,
                 ctx, pool = _write_attend_paged(q, pool, knew, vnew,
                                                 lengths, table, layer)
             return ctx, (pool, layer)
-        pool = pkv.write_token_layer_paged(pool, layer, lengths, table, k, v,
+        pool = kvp.write_token_layer_paged(pool, layer, lengths, table, k, v,
                                            ps)
-        dense = pkv.gather_layer_dense(pool, layer, table)
+        dense = kvp.gather_layer_dense(pool, layer, table)
         ck, cv = dense["k"], dense["v"]
         if "ks" in dense:
-            ck = kvc.dequantize(ck, dense["ks"], dtype=q.dtype)
-            cv = kvc.dequantize(cv, dense["vs"], dtype=q.dtype)
+            ck = kvp.dequantize(ck, dense["ks"], dtype=q.dtype)
+            cv = kvp.dequantize(cv, dense["vs"], dtype=q.dtype)
         ctx = decode_attend(q, ck, cv, lengths + 1, window=window)
         return ctx, (pool, layer)
 
@@ -541,7 +211,7 @@ def make_spec_attend_carry_paged(lengths: jnp.ndarray, table: jnp.ndarray,
     answers all R queries (pages covering lengths + R pre-allocated by the
     engine). With a ``mesh``, the pool's head axis shards over ``tp`` and the
     block table/lengths are shard-invariant — same contract as
-    make_decode_attend_carry_paged (Engine gates spec to dp == 1, sp == 1)."""
+    make_decode_attend_carry_paged."""
     resolved = resolve_impl(impl)
 
     spec_dp = mesh.shape.get("dp", 1) if mesh is not None else 1
@@ -551,8 +221,7 @@ def make_spec_attend_carry_paged(lengths: jnp.ndarray, table: jnp.ndarray,
 
         interpret = not pallas_attention.supported()
         if spec_dp > 1:
-            # global→local page-id rebase, same as _write_attend_paged (the
-            # Engine currently gates spec to dp == 1, so this is latent)
+            # global→local page-id rebase, same as _write_attend_paged
             tab = tab - jax.lax.axis_index("dp").astype(jnp.int32) \
                 * pool["k"].shape[1]
         R = q.shape[1]
@@ -584,8 +253,6 @@ def make_spec_attend_carry_paged(lengths: jnp.ndarray, table: jnp.ndarray,
         return ctx, pool
 
     def attend(q, k, v, cache_l) -> Tuple[jnp.ndarray, tuple]:
-        from aws_k8s_ansible_provisioner_tpu.serving import paged_kv as pkv
-
         pool, layer = cache_l
         ps = pool["k"].shape[3]
         R = q.shape[1]
@@ -615,15 +282,15 @@ def make_spec_attend_carry_paged(lengths: jnp.ndarray, table: jnp.ndarray,
                                                      table, layer)
             return ctx, (pool, layer)
         for r in range(R):
-            pool = pkv.write_token_layer_paged(pool, layer, lengths + r,
+            pool = kvp.write_token_layer_paged(pool, layer, lengths + r,
                                                table, k[:, r:r + 1],
                                                v[:, r:r + 1], ps)
-        dense = pkv.gather_layer_dense(pool, layer, table)
+        dense = kvp.gather_layer_dense(pool, layer, table)
         ck, cv = dense["k"], dense["v"]
         if "ks" in dense:
-            ck = kvc.dequantize(ck, dense["ks"], dtype=q.dtype)
-            cv = kvc.dequantize(cv, dense["vs"], dtype=q.dtype)
-        ctx = decode_attend_multi(q, ck, cv, lengths, window=window)
+            ck = kvp.dequantize(ck, dense["ks"], dtype=q.dtype)
+            cv = kvp.dequantize(cv, dense["vs"], dtype=q.dtype)
+        ctx = decode_attend(q, ck, cv, lengths + 1, window=window)
         return ctx, (pool, layer)
 
     return attend
@@ -657,7 +324,7 @@ def make_mixed_attend_carry_paged(dec_rows: jnp.ndarray, chunk_start,
 
     Two needs, two writers: a decode row is one row in each of B page runs
     (the row kernel decode_steps uses, one grid step a slot), the chunk is
-    one span of one run (paged_kv.write_chunk_paged_layer, the prefill
+    one span of one run (kv_pool.write_chunk_paged_layer, the prefill
     programs' writer, one page window at a time: 0.07 ms for the K and V
     of a layer at the served shape, my chip run, PR 29 — a grid step a ROW
     was 2,080 steps a call, two thirds of them padding, 0.8 ms a layer and
@@ -669,13 +336,11 @@ def make_mixed_attend_carry_paged(dec_rows: jnp.ndarray, chunk_start,
     support mirrors make_decode_attend_carry_paged's tp sharding (heads
     over ``tp``); the engine gates ragged dispatch to mesh None / pure-tp,
     so no dp rebase rides here."""
-    from aws_k8s_ansible_provisioner_tpu.serving import paged_kv as pkv
-
     resolved = resolve_impl(impl)
     B = dec_rows.shape[0]
 
     def _write_chunk(pool, knew, vnew, start, n_valid, tabs, layer):
-        return pkv.write_chunk_paged_layer(
+        return kvp.write_chunk_paged_layer(
             pool, layer, tabs[B], start, knew[None, B:], vnew[None, B:],
             pool["k"].shape[3], n_valid=n_valid)
 
@@ -741,16 +406,16 @@ def make_mixed_attend_carry_paged(dec_rows: jnp.ndarray, chunk_start,
                     q3, pool, knew, vnew, dec_rows, chunk_start, chunk_len,
                     row_limits, row_tables, layer)
             return ctx[None], (pool, layer)
-        pool = pkv.write_token_layer_paged(pool, layer, dec_rows,
+        pool = kvp.write_token_layer_paged(pool, layer, dec_rows,
                                            row_tables[:B], k[0][:B, None],
                                            v[0][:B, None], ps)
         pool = _write_chunk(pool, k[0], v[0], chunk_start, chunk_len,
                             row_tables, layer)
-        dense = pkv.gather_layer_dense(pool, layer, row_tables)
+        dense = kvp.gather_layer_dense(pool, layer, row_tables)
         ck, cv = dense["k"], dense["v"]
         if "ks" in dense:
-            ck = kvc.dequantize(ck, dense["ks"], dtype=q.dtype)
-            cv = kvc.dequantize(cv, dense["vs"], dtype=q.dtype)
+            ck = kvp.dequantize(ck, dense["ks"], dtype=q.dtype)
+            cv = kvp.dequantize(cv, dense["vs"], dtype=q.dtype)
         ctx = decode_attend(q[0][:, None], ck, cv, row_limits,
                             window=window)[:, 0]
         ctx = jnp.where((row_limits > 0)[:, None, None], ctx, 0)
@@ -764,16 +429,14 @@ def make_prefill_attend_paged_carry(pages: jnp.ndarray, seq_len: jnp.ndarray,
     """CARRY-path paged single-prompt prefill: the full pool rides the layer
     scan's carry (in place via loop aliasing) instead of xs→ys, whose
     restack buffer OOMed the batch-128 paged program on the real chip
-    (round 5; see paged_kv.write_prompts_paged_layer)."""
+    (round 5; see kv_pool.write_prompts_paged_layer)."""
     from aws_k8s_ansible_provisioner_tpu.models.layers import causal_attend
 
     def attend(q, k, v, cache_l):
-        from aws_k8s_ansible_provisioner_tpu.serving import paged_kv as pkv
-
         cache, layer = cache_l
         ps = cache["k"].shape[3]
         ctx = causal_attend(q, k, v, seq_lens=seq_len[None], window=window)
-        cache = pkv.write_chunk_paged_layer(cache, layer, pages, 0, k, v,
+        cache = kvp.write_chunk_paged_layer(cache, layer, pages, 0, k, v,
                                             ps)
         return ctx, (cache, layer)
 
@@ -788,12 +451,10 @@ def make_prefill_attend_batch_paged_carry(tables: jnp.ndarray,
     from aws_k8s_ansible_provisioner_tpu.models.layers import causal_attend
 
     def attend(q, k, v, cache_l):
-        from aws_k8s_ansible_provisioner_tpu.serving import paged_kv as pkv
-
         cache, layer = cache_l
         ps = cache["k"].shape[3]
         ctx = causal_attend(q, k, v, seq_lens=seq_lens, window=window)
-        cache = pkv.write_prompts_paged_layer(cache, layer, tables, k, v, ps)
+        cache = kvp.write_prompts_paged_layer(cache, layer, tables, k, v, ps)
         return ctx, (cache, layer)
 
     return attend
@@ -807,22 +468,20 @@ def make_chunk_prefill_attend_paged_carry(pages: jnp.ndarray, start,
     cost, exactly as the xs/ys form paid)."""
 
     def attend(q, k, v, cache_l):
-        from aws_k8s_ansible_provisioner_tpu.serving import paged_kv as pkv
-
         cache, layer = cache_l
         ps = cache["k"].shape[3]
-        cache = pkv.write_chunk_paged_layer(cache, layer, pages, start,
+        cache = kvp.write_chunk_paged_layer(cache, layer, pages, start,
                                             k, v, ps)
 
         def gl(name):
             sl = jax.lax.dynamic_index_in_dim(cache[name], layer, 0,
                                               keepdims=False)
-            return pkv.gather_slot({name: sl}, pages, ps, name)
+            return kvp.gather_slot({name: sl}, pages, ps, name)
 
         ck, cv = gl("k"), gl("v")
         if "ks" in cache:
-            ck = kvc.dequantize(ck, gl("ks"), dtype=q.dtype)
-            cv = kvc.dequantize(cv, gl("vs"), dtype=q.dtype)
+            ck = kvp.dequantize(ck, gl("ks"), dtype=q.dtype)
+            cv = kvp.dequantize(cv, gl("vs"), dtype=q.dtype)
         ctx = chunk_attend(q, ck, cv, start, window=window)
         return ctx, (cache, layer)
 

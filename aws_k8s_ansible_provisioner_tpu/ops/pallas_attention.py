@@ -1,203 +1,35 @@
-"""Pallas TPU kernel: ragged decode attention over the slot-contiguous KV cache.
+"""Pallas TPU kernels: ragged attention and row writes over the paged KV pool.
 
 This is the hot loop of the whole framework — the TPU-native equivalent of the
 paged-attention CUDA kernels inside the reference's external vLLM engine
 (SURVEY.md §3.3: "the true hot loop (token-by-token decode on the GPU) lives
-entirely inside the external vLLM container"; §7 hard part #1). One program
-instance handles one decode slot; the KV cache streams HBM→VMEM in chunks with
-flash-style online softmax, so per-step cost is cache-bandwidth-bound with no
-[B, S] float32 logits materialization in HBM.
+entirely inside the external vLLM container"; §7 hard part #1). The pool
+(ops/kv_pool.py: ``[L, P, Hkv, page, D]``) streams HBM→VMEM a page at a time
+with flash-style online softmax, so per-step cost is cache-bandwidth-bound
+with no [B, S] float32 logits materialization in HBM.
 
-Raggedness (every slot at a different length) is handled two ways:
-- masking: key columns ≥ length contribute -inf logits;
-- *DMA skipping*: the chunk index_map clamps dead chunks (beyond the slot's
-  length) to the last live chunk — Pallas skips re-fetch when a block index
-  repeats, so a slot at length 130 reads ~2 chunks of cache, not S/CHUNK.
-  With the identity block table of the slot-contiguous head-major cache
-  (serving/kv_cache.py pages_view), this IS paged attention: chunk c of
-  (slot b, head h) is page ``(b*Hkv + h)*pages_per_stream + c``.
+Raggedness (every row at a different length) is handled two ways:
+- masking: key columns ≥ the row's limit contribute -inf logits;
+- *page skipping*: the in-kernel page loop runs over a block's live page
+  range only, so a slot at length 130 reads 3 pages of 64, not the window.
 
 GQA grouping stays in-kernel: per KV head h, the G=Hq/Hkv query rows attend to
-one [CHUNK, D] K/V stream — no repeat_kv copy ever exists (the same design as
+one [page, D] K/V stream — no repeat_kv copy ever exists (the same design as
 the XLA fallback in ops/attention.py, here with explicit VMEM control).
 """
 
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from aws_k8s_ansible_provisioner_tpu.ops.kv_pool import quantize_rows
+
 NEG_INF = -1e30
-
-
-def _pick_chunk(S: int, chunk: int, interpret: bool, quant: bool) -> int:
-    """Largest legal cache-chunk size dividing the window S.
-
-    On real TPU the block shapes impose tiling rules Mosaic enforces at
-    lowering: the K/V block's sublane dim is the chunk (multiple of 8 for
-    bf16, 32 for int8), and the quantized path's scale block has the chunk on
-    the LANE axis (multiple of 128, or the full dimension). The engine's
-    windows are 256-aligned so the preferred chunk survives; this guard keeps
-    odd windows (or odd sp shards) compiling instead of dying in Mosaic.
-    Interpret mode (CPU tests) has no such constraints.
-    """
-    chunk = min(chunk, S)
-    while S % chunk:
-        chunk -= 1
-    if interpret or chunk == S:
-        return chunk
-    # quant: scale-block lane rule (128) also covers the int8 sublane rule
-    # (32); bf16/f32 caches only need the sublane rule (16 covers both).
-    align = 128 if quant else 16
-    if chunk % align == 0:
-        return chunk
-    best = next((c for c in range(chunk // align * align, align - 1, -align)
-                 if S % c == 0), None)
-    return best if best is not None else S
-
-
-def decode_attend_pallas(q: jnp.ndarray, cache_k: jnp.ndarray,
-                         cache_v: jnp.ndarray, lengths: jnp.ndarray,
-                         chunk: int = 256, interpret: bool = False) -> jnp.ndarray:
-    """Flash decode attention: q [B,1,Hq,D] over ONE layer's cache [B,Hkv,S,D]
-    (head-major, see serving/kv_cache.py), ragged by ``lengths`` [B] (counting
-    the just-written token). Returns [B,1,Hq,D].
-
-    Thin wrapper over the layer-indexed production kernel (the serving engine
-    always decodes against the full stacked cache; this single-layer form is
-    the parity-test surface and the API for callers holding one layer).
-    """
-    return decode_attend_pallas_layer(q, cache_k[None], cache_v[None], lengths,
-                                      jnp.int32(0), chunk=chunk,
-                                      interpret=interpret)
-
-
-def _decode_kernel_layer(lengths_ref,      # scalar prefetch [B] int32
-                         layer_ref,        # scalar prefetch [1] int32
-                         q_ref,            # [1, Hq, D]
-                         k_ref,            # [1, 1, Hkv, CHUNK, D]
-                         v_ref,            # [1, 1, Hkv, CHUNK, D]
-                         o_ref,            # [1, Hq, D]
-                         acc_ref, m_ref, l_ref,
-                         *, chunk: int, groups: int, scale: float,
-                         window: int = 0):
-    """Same flash accumulation as ``_decode_kernel`` but over the FULL
-    [L, B, Hkv, S, D] cache: the layer index arrives as a scalar-prefetch value
-    and the index_map selects the layer block, so the carry-path decode
-    (models/layers.model_forward_carry) never materializes a per-layer cache
-    slice in HBM. ``window`` > 0 = sliding-window attention: only the last
-    ``window`` columns are live; chunks entirely below it are skipped (their
-    DMA was already clamped away by the index map)."""
-    b = pl.program_id(0)
-    c = pl.program_id(1)
-    num_chunks = pl.num_programs(1)
-    length = lengths_ref[b]
-    hq, d = q_ref.shape[1], q_ref.shape[2]
-    hkv = k_ref.shape[2]
-    lo = jnp.maximum(length - window, 0) if window > 0 else 0
-
-    @pl.when(c == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-
-    @pl.when((c * chunk < length) & ((c + 1) * chunk > lo))
-    def _accumulate():
-        q3 = (q_ref[0].astype(jnp.float32) * scale).reshape(hkv, groups, d)
-        k3 = k_ref[0, 0].astype(jnp.float32)                      # [Hkv, C, D]
-        s = jax.lax.dot_general(
-            q3, k3, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)                   # [Hkv, G, C]
-        s = s.reshape(hq, chunk)
-        col = c * chunk + jax.lax.broadcasted_iota(jnp.int32, (hq, chunk), 1)
-        s = jnp.where((col < length) & (col >= lo), s, NEG_INF)
-        m_prev = m_ref[:, :1]
-        l_prev = l_ref[:, :1]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        corr = jnp.exp(m_prev - m_cur)
-        p = jnp.exp(s - m_cur)
-        l_cur = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
-        v3 = v_ref[0, 0].astype(jnp.float32)                      # [Hkv, C, D]
-        pv = jax.lax.dot_general(
-            p.reshape(hkv, groups, chunk), v3,
-            (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)                   # [Hkv, G, D]
-        acc_ref[:] = acc_ref[:] * corr + pv.reshape(hq, d)
-        m_ref[:, :1] = m_cur
-        l_ref[:, :1] = l_cur
-
-    @pl.when(c == num_chunks - 1)
-    def _finalize():
-        l = jnp.maximum(l_ref[:, :1], 1e-9)
-        o_ref[0, :, :] = (acc_ref[:] / l).astype(o_ref.dtype)
-
-
-def _decode_kernel_layer_q(lengths_ref,     # scalar prefetch [B] int32
-                           layer_ref,       # scalar prefetch [1] int32
-                           q_ref,           # [1, Hq, D]
-                           k_ref,           # [1, 1, Hkv, CHUNK, D] int8
-                           v_ref,           # [1, 1, Hkv, CHUNK, D] int8
-                           ks_ref,          # [1, 1, Hkv, CHUNK] f32 scales
-                           vs_ref,          # [1, 1, Hkv, CHUNK] f32 scales
-                           o_ref, acc_ref, m_ref, l_ref,
-                           *, chunk: int, groups: int, scale: float,
-                           window: int = 0):
-    """Int8-cache variant of ``_decode_kernel_layer``: K/V stream as int8 (half
-    the HBM traffic of bf16 — the whole point; decode is cache-bandwidth-bound)
-    and dequantization folds into the flash accumulation inside VMEM:
-    ``q·(k_q*ks) == (q·k_q)*ks`` per key column, and ``p·(v_q*vs) ==
-    (p*vs)·v_q`` per value row — the f32 cache never materializes anywhere.
-    """
-    b = pl.program_id(0)
-    c = pl.program_id(1)
-    num_chunks = pl.num_programs(1)
-    length = lengths_ref[b]
-    hq, d = q_ref.shape[1], q_ref.shape[2]
-    hkv = k_ref.shape[2]
-    lo = jnp.maximum(length - window, 0) if window > 0 else 0
-
-    @pl.when(c == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-
-    @pl.when((c * chunk < length) & ((c + 1) * chunk > lo))
-    def _accumulate():
-        q3 = (q_ref[0].astype(jnp.float32) * scale).reshape(hkv, groups, d)
-        k3 = k_ref[0, 0].astype(jnp.float32)                  # [Hkv, C, D]
-        s = jax.lax.dot_general(
-            q3, k3, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)               # [Hkv, G, C]
-        s = s * ks_ref[0, 0][:, None, :]                      # fold k scales
-        s = s.reshape(hq, chunk)
-        col = c * chunk + jax.lax.broadcasted_iota(jnp.int32, (hq, chunk), 1)
-        s = jnp.where((col < length) & (col >= lo), s, NEG_INF)
-        m_prev = m_ref[:, :1]
-        l_prev = l_ref[:, :1]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        corr = jnp.exp(m_prev - m_cur)
-        p = jnp.exp(s - m_cur)
-        l_cur = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
-        v3 = v_ref[0, 0].astype(jnp.float32)                  # [Hkv, C, D]
-        p3 = p.reshape(hkv, groups, chunk) * vs_ref[0, 0][:, None, :]
-        pv = jax.lax.dot_general(
-            p3, v3, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)               # [Hkv, G, D]
-        acc_ref[:] = acc_ref[:] * corr + pv.reshape(hq, d)
-        m_ref[:, :1] = m_cur
-        l_ref[:, :1] = l_cur
-
-    @pl.when(c == num_chunks - 1)
-    def _finalize():
-        l = jnp.maximum(l_ref[:, :1], 1e-9)
-        o_ref[0, :, :] = (acc_ref[:] / l).astype(o_ref.dtype)
 
 
 def _per_slot(vals, shape, axis: int = 0, each: int = 1):
@@ -215,660 +47,16 @@ def _per_slot(vals, shape, axis: int = 0, each: int = 1):
     return out
 
 
-def _decode_kernel_layer_bb(lengths_ref,    # scalar prefetch [B] int32
-                            layer_ref,      # scalar prefetch [1] int32
-                            q_ref,          # [BB, Hq, D]
-                            k_ref,          # [1, BB, Hkv, CHUNK, D]
-                            v_ref,          # [1, BB, Hkv, CHUNK, D]
-                            o_ref,          # [BB, Hq, D]
-                            acc_ref, m_ref, l_ref,   # [BB, Hq, *]
-                            *, chunk: int, groups: int, scale: float,
-                            bb: int, window: int = 0, quant: bool = False,
-                            ks_ref=None, vs_ref=None):
-    """Batch-blocked flash decode: BB slots per grid step.
-
-    The round-5 TPU decomposition (a measurement of older code on the dense
-    path, whose record is no longer in the tree) put the decode substep at
-    ~3x its bandwidth bound; at grid (B=128, chunks=4) x 28 layers each
-    step streams only ~0.5 MB, so fixed per-grid-step cost (DMA issue +
-    kernel overhead, ~1 us class) rivals the stream time itself. Blocking
-    BB slots into one grid step multiplies the DMA size by BB and divides
-    the step count by BB, pushing the kernel back toward the stream bound.
-    Trade: the chunk-skip clamp must cover the LONGEST
-    slot in the block (shorter slots' dead chunks ride along), so blocks
-    of similar-length slots waste nothing and mixed blocks pay up to
-    (max-min) extra rows — the engine's slot allocator is FCFS, which
-    correlates neighbors' ages. Gated by PALLAS_DECODE_BBLOCK; compiled
-    for the chip (tests/test_tpu_compile.py), not yet timed on it.
-    """
-    bbi = pl.program_id(0)
-    c = pl.program_id(1)
-    num_chunks = pl.num_programs(1)
-    hq, d = q_ref.shape[1], q_ref.shape[2]
-    hkv = k_ref.shape[2]
-    # Per-slot lengths stay SCALARS (SMEM reads): Mosaic cannot lay out a
-    # stacked [BB] scalar vector reshaped to [BB, 1, 1] for the column mask,
-    # so the mask operand is built by _per_slot's iota select instead.
-    lens = [lengths_ref[bbi * bb + i] for i in range(bb)]
-    max_len = functools.reduce(jnp.maximum, lens)
-    lo = [jnp.maximum(ln - window, 0) if window > 0 else jnp.int32(0)
-          for ln in lens]
-    lo_min = functools.reduce(jnp.minimum, lo)
-
-    @pl.when(c == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-
-    @pl.when((c * chunk < max_len) & ((c + 1) * chunk > lo_min))
-    def _accumulate():
-        q3 = (q_ref[:].astype(jnp.float32) * scale) \
-            .reshape(bb * hkv, groups, d)
-        k3 = k_ref[0].astype(jnp.float32).reshape(bb * hkv, chunk, d)
-        s = jax.lax.dot_general(
-            q3, k3, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)        # [BB*Hkv, G, C]
-        if quant:
-            s = s * ks_ref[0].reshape(bb * hkv, chunk)[:, None, :]
-        s = s.reshape(bb, hq, chunk)
-        col = c * chunk + jax.lax.broadcasted_iota(jnp.int32,
-                                                   (bb, hq, chunk), 2)
-        live = col < _per_slot(lens, (bb, hq, chunk))
-        if window > 0:
-            live &= col >= _per_slot(lo, (bb, hq, chunk))
-        s = jnp.where(live, s, NEG_INF)
-        m_prev = m_ref[:, :, :1]
-        l_prev = l_ref[:, :, :1]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        corr = jnp.exp(m_prev - m_cur)
-        p = jnp.exp(s - m_cur)
-        l_cur = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
-        v3 = v_ref[0].astype(jnp.float32).reshape(bb * hkv, chunk, d)
-        p3 = p.reshape(bb * hkv, groups, chunk)
-        if quant:
-            p3 = p3 * vs_ref[0].reshape(bb * hkv, chunk)[:, None, :]
-        pv = jax.lax.dot_general(
-            p3, v3, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)        # [BB*Hkv, G, D]
-        acc_ref[:] = acc_ref[:] * corr + pv.reshape(bb, hq, d)
-        m_ref[:, :, :1] = m_cur
-        l_ref[:, :, :1] = l_cur
-
-    @pl.when(c == num_chunks - 1)
-    def _finalize():
-        l = jnp.maximum(l_ref[:, :, :1], 1e-9)
-        o_ref[:] = (acc_ref[:] / l).astype(o_ref.dtype)
-
-
-def _decode_kernel_layer_q_bb(lengths_ref, layer_ref, q_ref, k_ref, v_ref,
-                              ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref,
-                              *, chunk: int, groups: int, scale: float,
-                              bb: int, window: int = 0):
-    """Int8 batch-blocked variant: scale folding as in
-    _decode_kernel_layer_q, DMA batching as in _decode_kernel_layer_bb."""
-    _decode_kernel_layer_bb(lengths_ref, layer_ref, q_ref, k_ref, v_ref,
-                            o_ref, acc_ref, m_ref, l_ref, chunk=chunk,
-                            groups=groups, scale=scale, bb=bb,
-                            window=window, quant=True, ks_ref=ks_ref,
-                            vs_ref=vs_ref)
-
-
-def _decode_kernel_layer_q_stats(lengths_ref, layer_ref, q_ref, k_ref, v_ref,
-                                 ks_ref, vs_ref, o_ref, mo_ref, lo_ref,
-                                 acc_ref, m_ref, l_ref,
-                                 *, chunk: int, groups: int, scale: float,
-                                 window: int = 0):
-    """Stats-emitting int8 variant (sequence-parallel decode merge)."""
-    _decode_kernel_layer_q(lengths_ref, layer_ref, q_ref, k_ref, v_ref,
-                           ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref,
-                           chunk=chunk, groups=groups, scale=scale,
-                           window=window)
-    c = pl.program_id(1)
-
-    @pl.when(c == pl.num_programs(1) - 1)
-    def _emit_stats():
-        o_ref[0, :, :] = acc_ref[:].astype(o_ref.dtype)  # overwrite normalized
-        mo_ref[0] = jnp.broadcast_to(m_ref[:, :1], mo_ref.shape[1:])
-        lo_ref[0] = jnp.broadcast_to(l_ref[:, :1], lo_ref.shape[1:])
-
-
-def _decode_kernel_layer_stats(lengths_ref, layer_ref, q_ref, k_ref, v_ref,
-                               o_ref,       # [1, Hq, D] f32 UNNORMALIZED acc
-                               mo_ref,      # [1, Hq, 128] f32 running max
-                               lo_ref,      # [1, Hq, 128] f32 running denom
-                               acc_ref, m_ref, l_ref,
-                               *, chunk: int, groups: int, scale: float,
-                               window: int = 0):
-    """Stats-emitting variant for sequence-parallel decode: instead of the
-    normalized context, outputs the raw flash triple (acc, m, l) so the
-    caller can merge partials across sequence shards with a log-sum-exp
-    combine (ops/attention.py sp path). A shard holding none of a slot's rows
-    emits (0, -inf, 0), which contributes nothing to the merge."""
-    _decode_kernel_layer(lengths_ref, layer_ref, q_ref, k_ref, v_ref,
-                         o_ref, acc_ref, m_ref, l_ref,
-                         chunk=chunk, groups=groups, scale=scale,
-                         window=window)
-    c = pl.program_id(1)
-
-    @pl.when(c == pl.num_programs(1) - 1)
-    def _emit_stats():
-        o_ref[0, :, :] = acc_ref[:].astype(o_ref.dtype)  # overwrite normalized
-        mo_ref[0] = jnp.broadcast_to(m_ref[:, :1], mo_ref.shape[1:])
-        lo_ref[0] = jnp.broadcast_to(l_ref[:, :1], lo_ref.shape[1:])
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("chunk", "interpret", "return_stats",
-                                    "window", "bblock"))
-def decode_attend_pallas_layer(q: jnp.ndarray, cache_k: jnp.ndarray,
-                               cache_v: jnp.ndarray, lengths: jnp.ndarray,
-                               layer: jnp.ndarray, chunk: int = 256,
-                               interpret: bool = False,
-                               return_stats: bool = False,
-                               cache_ks: jnp.ndarray = None,
-                               cache_vs: jnp.ndarray = None,
-                               window: int = 0,
-                               bblock: int = None):
-    """Flash decode attention over ONE layer of the full stacked cache.
-
-    q: [B, 1, Hq, D]; cache_k/v: [L, B, Hkv, S, D] (the whole cache buffer —
-    no per-layer slice is ever cut); lengths: [B] (counting the just-written
-    token); layer: scalar int32. Returns [B, 1, Hq, D].
-
-    With ``cache_ks``/``cache_vs`` ([L, B, Hkv, S] f32) the cache is int8 and
-    the kernel dequantizes in VMEM by folding the per-row scales into the
-    flash accumulation (see _decode_kernel_layer_q) — half the HBM streaming
-    of the bf16 cache.
-
-    The hot path of the carry-based decode loop: only the live chunks of the
-    selected layer stream HBM→VMEM (same DMA-skip clamping as
-    ``decode_attend_pallas``); everything else in the 4-GB-scale cache is
-    untouched.
-    """
-    B, _, Hq, D = q.shape
-    Hkv, S = cache_k.shape[2], cache_k.shape[3]
-    groups = Hq // Hkv
-    quant = cache_ks is not None
-    chunk = _pick_chunk(S, chunk, interpret, quant)
-    num_chunks = S // chunk
-    lengths = lengths.astype(jnp.int32)
-    layer_arr = jnp.asarray(layer, jnp.int32).reshape(1)
-
-    def q_map(b, c, lens, lay):
-        return (b, 0, 0)
-
-    def _clamped(b, c, lens):
-        # live chunk range [lo, hi]: above the slot's length AND (with a
-        # sliding window) below its window start, chunks clamp to the range
-        # edge — Pallas skips the repeated fetch, so dead cache never moves
-        hi = jnp.maximum(pl.cdiv(lens[b], chunk) - 1, 0)
-        if window > 0:
-            lo_chunk = jnp.maximum(lens[b] - window, 0) // chunk
-            return jnp.clip(c, lo_chunk, hi)
-        return jnp.minimum(c, hi)
-
-    def kv_map(b, c, lens, lay):
-        return (lay[0], b, 0, _clamped(b, c, lens), 0)
-
-    def scale_map(b, c, lens, lay):
-        return (lay[0], b, 0, _clamped(b, c, lens))
-
-    scratch = [
-        pltpu.VMEM((Hq, D), jnp.float32),
-        pltpu.VMEM((Hq, 128), jnp.float32),
-        pltpu.VMEM((Hq, 128), jnp.float32),
-    ]
-    in_specs = [
-        pl.BlockSpec((1, Hq, D), q_map),
-        pl.BlockSpec((1, 1, Hkv, chunk, D), kv_map),
-        pl.BlockSpec((1, 1, Hkv, chunk, D), kv_map),
-    ]
-    operands = [q[:, 0], cache_k, cache_v]
-    if quant:
-        # chunk on the LANE axis: legal because _pick_chunk forces a
-        # 128-multiple (or full-S) chunk on the compiled path
-        in_specs += [pl.BlockSpec((1, 1, Hkv, chunk), scale_map)] * 2
-        operands += [cache_ks, cache_vs]
-    scale = 1.0 / (D ** 0.5)
-    if return_stats:
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(B, num_chunks),
-            in_specs=in_specs,
-            out_specs=[
-                pl.BlockSpec((1, Hq, D), q_map),
-                pl.BlockSpec((1, Hq, 128), q_map),
-                pl.BlockSpec((1, Hq, 128), q_map),
-            ],
-            scratch_shapes=scratch,
-        )
-        kernel = functools.partial(
-            _decode_kernel_layer_q_stats if quant
-            else _decode_kernel_layer_stats,
-            chunk=chunk, groups=groups, scale=scale, window=window)
-        acc, m, l = pl.pallas_call(
-            kernel,
-            grid_spec=grid_spec,
-            out_shape=[
-                jax.ShapeDtypeStruct((B, Hq, D), jnp.float32),
-                jax.ShapeDtypeStruct((B, Hq, 128), jnp.float32),
-                jax.ShapeDtypeStruct((B, Hq, 128), jnp.float32),
-            ],
-            interpret=interpret,
-        )(lengths, layer_arr, *operands)
-        # stats are replicated along the 128-lane axis; take lane 0
-        return acc, m[:, :, 0], l[:, :, 0]
-    # Batch-blocking (PALLAS_DECODE_BBLOCK, default off): BB slots per grid
-    # step — BBx bigger DMAs, BB-fewer grid steps; see
-    # _decode_kernel_layer_bb for the measured rationale. Resolved to the
-    # largest divisor of B not exceeding the requested block.
-    bb = int(os.environ.get("PALLAS_DECODE_BBLOCK", "0") or 0) \
-        if bblock is None else bblock
-    bb = max(1, min(bb, B)) if bb else 1
-    while B % bb:
-        bb -= 1
-    if bb > 1:
-        def q_map_bb(g, c, lens, lay):
-            return (g, 0, 0)
-
-        def _clamped_bb(g, c, lens):
-            # the block's live range covers its LONGEST slot (and, with a
-            # window, its EARLIEST window start)
-            hi = jnp.int32(0)
-            lo_chunk = None
-            for i in range(bb):
-                ln = lens[g * bb + i]
-                hi = jnp.maximum(hi, pl.cdiv(ln, chunk) - 1)
-                if window > 0:
-                    lc = jnp.maximum(ln - window, 0) // chunk
-                    lo_chunk = lc if lo_chunk is None \
-                        else jnp.minimum(lo_chunk, lc)
-            hi = jnp.maximum(hi, 0)
-            if window > 0:
-                return jnp.clip(c, lo_chunk, hi)
-            return jnp.minimum(c, hi)
-
-        def kv_map_bb(g, c, lens, lay):
-            return (lay[0], g, 0, _clamped_bb(g, c, lens), 0)
-
-        def scale_map_bb(g, c, lens, lay):
-            return (lay[0], g, 0, _clamped_bb(g, c, lens))
-
-        in_specs_bb = [
-            pl.BlockSpec((bb, Hq, D), q_map_bb),
-            pl.BlockSpec((1, bb, Hkv, chunk, D), kv_map_bb),
-            pl.BlockSpec((1, bb, Hkv, chunk, D), kv_map_bb),
-        ]
-        if quant:
-            in_specs_bb += [pl.BlockSpec((1, bb, Hkv, chunk),
-                                         scale_map_bb)] * 2
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(B // bb, num_chunks),
-            in_specs=in_specs_bb,
-            out_specs=pl.BlockSpec((bb, Hq, D), q_map_bb),
-            scratch_shapes=[
-                pltpu.VMEM((bb, Hq, D), jnp.float32),
-                pltpu.VMEM((bb, Hq, 128), jnp.float32),
-                pltpu.VMEM((bb, Hq, 128), jnp.float32),
-            ],
-        )
-        kernel = functools.partial(
-            _decode_kernel_layer_q_bb if quant else _decode_kernel_layer_bb,
-            chunk=chunk, groups=groups, scale=scale, bb=bb, window=window)
-        out = pl.pallas_call(
-            kernel,
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
-            interpret=interpret,
-            # BB double-buffered K/V blocks outgrow the 16 MiB default scoped
-            # VMEM at bb=8 x chunk=256 (16.35 MiB at Qwen3-0.6B widths)
-            compiler_params=pltpu.CompilerParams(
-                vmem_limit_bytes=32 * 2**20),
-        )(lengths, layer_arr, *operands)
-        return out[:, None]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, num_chunks),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, Hq, D), q_map),
-        scratch_shapes=scratch,
-    )
-    kernel = functools.partial(
-        _decode_kernel_layer_q if quant else _decode_kernel_layer,
-        chunk=chunk, groups=groups, scale=scale, window=window)
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
-        interpret=interpret,
-    )(lengths, layer_arr, *operands)
-    return out[:, None]
-
-
-def _spec_accumulate(lengths_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
-                     o_ref, acc_ref, m_ref, l_ref,
-                     *, chunk: int, groups: int, scale: float, R: int,
-                     window: int = 0):
-    """Shared body for the R-draft speculative decode kernels.
-
-    q_ref: [1, R*Hq, D] — R query rows per slot (the last accepted token plus
-    R-1 draft continuations), rows ordered (draft, head). Query row r may see
-    cache columns < lengths[b] + 1 + r (its own just-written row included).
-    The K/V chunk streams ONCE per grid step and is reused by all R queries —
-    the whole point of verifying drafts in one pass: R tokens for one cache
-    read. ks/vs fold int8 scales when present (None = bf16 cache).
-    """
-    b = pl.program_id(0)
-    c = pl.program_id(1)
-    num_chunks = pl.num_programs(1)
-    length = lengths_ref[b]
-    d = q_ref.shape[2]
-    hkv = k_ref.shape[2]
-    hq = q_ref.shape[1] // R
-    # below-window compute skip (row 0's window start bounds all R rows)
-    lo = jnp.maximum(length + 1 - window, 0) if window > 0 else 0
-
-    @pl.when(c == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-
-    @pl.when((c * chunk < length + R) & ((c + 1) * chunk > lo))
-    def _accumulate():
-        k3 = k_ref[0, 0].astype(jnp.float32)                  # [Hkv, C, D]
-        v3 = v_ref[0, 0].astype(jnp.float32)
-        for r in range(R):                                    # static unroll
-            sl = slice(r * hq, (r + 1) * hq)
-            q3 = (q_ref[0, sl].astype(jnp.float32) * scale
-                  ).reshape(hkv, groups, d)
-            s = jax.lax.dot_general(
-                q3, k3, (((2,), (2,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32)           # [Hkv, G, C]
-            if ks_ref is not None:
-                s = s * ks_ref[0, 0][:, None, :]
-            s = s.reshape(hq, chunk)
-            col = c * chunk + jax.lax.broadcasted_iota(
-                jnp.int32, (hq, chunk), 1)
-            live = col < length + 1 + r
-            if window > 0:   # sliding window: row r sees its last W keys
-                live = live & (col >= length + 1 + r - window)
-            s = jnp.where(live, s, NEG_INF)
-            m_prev = m_ref[sl, :1]
-            l_prev = l_ref[sl, :1]
-            m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            corr = jnp.exp(m_prev - m_cur)
-            p = jnp.exp(s - m_cur)
-            l_cur = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
-            p3 = p.reshape(hkv, groups, chunk)
-            if vs_ref is not None:
-                p3 = p3 * vs_ref[0, 0][:, None, :]
-            pv = jax.lax.dot_general(
-                p3, v3, (((2,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32)           # [Hkv, G, D]
-            acc_ref[sl] = acc_ref[sl] * corr + pv.reshape(hq, d)
-            m_ref[sl, :1] = m_cur
-            l_ref[sl, :1] = l_cur
-
-    @pl.when(c == num_chunks - 1)
-    def _finalize():
-        l = jnp.maximum(l_ref[:, :1], 1e-9)
-        o_ref[0] = (acc_ref[:] / l).astype(o_ref.dtype)
-
-
-def _spec_kernel_plain(lengths_ref, layer_ref, q_ref, k_ref, v_ref, o_ref,
-                       acc_ref, m_ref, l_ref, **kw):
-    _spec_accumulate(lengths_ref, q_ref, k_ref, v_ref, None, None,
-                     o_ref, acc_ref, m_ref, l_ref, **kw)
-
-
-def _spec_kernel_quant(lengths_ref, layer_ref, q_ref, k_ref, v_ref, ks_ref,
-                       vs_ref, o_ref, acc_ref, m_ref, l_ref, **kw):
-    _spec_accumulate(lengths_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
-                     o_ref, acc_ref, m_ref, l_ref, **kw)
-
-
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret", "window"))
-def decode_attend_pallas_spec(q: jnp.ndarray, cache_k: jnp.ndarray,
-                              cache_v: jnp.ndarray, lengths: jnp.ndarray,
-                              layer: jnp.ndarray, chunk: int = 256,
-                              interpret: bool = False,
-                              cache_ks: jnp.ndarray = None,
-                              cache_vs: jnp.ndarray = None,
-                              window: int = 0) -> jnp.ndarray:
-    """Speculative-verify flash attention: R query rows per slot in one pass.
-
-    q: [B, R, Hq, D] — row r is the query at position lengths[b] + r (the
-    caller has already written all R K/V rows); returns [B, R, Hq, D]. Each
-    query row masks to its own causal frontier (lengths + 1 + r). One cache
-    stream serves all R rows, so verifying R-1 drafts costs ~one decode
-    step's HBM traffic — the bandwidth economics that make prompt-lookup
-    speculation profitable on a bandwidth-bound chip.
-    """
-    B, R, Hq, D = q.shape
-    Hkv, S = cache_k.shape[2], cache_k.shape[3]
-    groups = Hq // Hkv
-    quant = cache_ks is not None
-    chunk = _pick_chunk(S, chunk, interpret, quant)
-    num_chunks = S // chunk
-    lengths = lengths.astype(jnp.int32)
-    layer_arr = jnp.asarray(layer, jnp.int32).reshape(1)
-
-    def q_map(b, c, lens, lay):
-        return (b, 0, 0)
-
-    def _clamped(b, c, lens):
-        hi = jnp.maximum(pl.cdiv(lens[b] + R, chunk) - 1, 0)
-        if window > 0:
-            # lowest chunk any of the R rows can see (row 0's window start)
-            lo_chunk = jnp.maximum(lens[b] + 1 - window, 0) // chunk
-            return jnp.clip(c, lo_chunk, hi)
-        return jnp.minimum(c, hi)
-
-    def kv_map(b, c, lens, lay):
-        return (lay[0], b, 0, _clamped(b, c, lens), 0)
-
-    def scale_map(b, c, lens, lay):
-        return (lay[0], b, 0, _clamped(b, c, lens))
-
-    in_specs = [
-        pl.BlockSpec((1, R * Hq, D), q_map),
-        pl.BlockSpec((1, 1, Hkv, chunk, D), kv_map),
-        pl.BlockSpec((1, 1, Hkv, chunk, D), kv_map),
-    ]
-    operands = [q.reshape(B, R * Hq, D), cache_k, cache_v]
-    if quant:
-        in_specs += [pl.BlockSpec((1, 1, Hkv, chunk), scale_map)] * 2
-        operands += [cache_ks, cache_vs]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, num_chunks),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, R * Hq, D), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((R * Hq, D), jnp.float32),
-            pltpu.VMEM((R * Hq, 128), jnp.float32),
-            pltpu.VMEM((R * Hq, 128), jnp.float32),
-        ],
-    )
-    kernel = functools.partial(
-        _spec_kernel_quant if quant else _spec_kernel_plain,
-        chunk=chunk, groups=groups, scale=1.0 / (D ** 0.5), R=R,
-        window=window)
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, R * Hq, D), q.dtype),
-        interpret=interpret,
-    )(lengths, layer_arr, *operands)
-    return out.reshape(B, R, Hq, D)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def cache_write_row(cache: jnp.ndarray, new: jnp.ndarray,
-                    lengths: jnp.ndarray, layer: jnp.ndarray,
-                    interpret: bool = False) -> jnp.ndarray:
-    """Write one new K (or V) row per slot into the full cache, IN PLACE.
-
-    cache: [L, B, Hkv, S, D]; new: [B, Hkv, D]; lengths: [B] (row index per
-    slot — rows outside [0, S) are DROPPED, which both makes surplus
-    mid-horizon writes safe and lets sequence-parallel shards pass
-    ``global_row - shard_offset`` and have exactly the owning shard write);
-    layer: scalar int32. Returns the updated cache — same buffer.
-
-    Why a kernel for a 2 KB-per-slot write: the functional alternatives all
-    copy. ``.at[layer, rows, :, lengths].set(...)`` lowers to scatter, and
-    XLA's copy-insertion around scatters in while-loop carries materializes
-    full-cache copies (measured: 7 copies of the 3.6 GB cache per decode step,
-    22.9 GB accessed — 330 ms/token). ``input_output_aliases`` lowers to a
-    custom call with output-operand aliasing, which buffer assignment MUST
-    honor — the 938M-element buffer is never copied; each grid step DMAs one
-    [Hkv, D] row. This is the TPU equivalent of vLLM's in-place
-    ``cache_kernel`` CUDA writes (reference SURVEY.md §2.2 row 1).
-    """
-    L, B, Hkv, S, D = cache.shape
-    lengths = lengths.astype(jnp.int32)
-    layer_arr = jnp.asarray(layer, jnp.int32).reshape(1)
-    # Pallas TPU blocks need the sublane dim divisible by 8: touch the 8-row
-    # block containing the target row and mask the single row in (8 rows
-    # in + out per slot ≈ 32 KB — still ~10^5x less traffic than the
-    # full-cache copies this kernel exists to avoid).
-    ROWS = 8 if S % 8 == 0 else S
-
-    def new_map(b, lens, lay):
-        return (b, 0, 0)
-
-    def blk_map(b, lens, lay):
-        # S-axis block size ROWS -> block index = row // ROWS. Out-of-window
-        # rows (negative under sequence sharding, or >= S) clamp to a valid
-        # block here and are DROPPED by the kernel's row mask — the scatter
-        # mode='drop' contract.
-        return (lay[0], b, 0, jnp.clip(lens[b], 0, S - 1) // ROWS, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B,),
-        in_specs=[
-            pl.BlockSpec((1, Hkv, D), new_map),
-            pl.BlockSpec((1, 1, Hkv, ROWS, D), blk_map),
-        ],
-        out_specs=pl.BlockSpec((1, 1, Hkv, ROWS, D), blk_map),
-    )
-
-    def kernel(lengths_ref, layer_ref, new_ref, cin_ref, cout_ref):
-        b = pl.program_id(0)
-        tgt = lengths_ref[b]
-        in_window = (tgt >= 0) & (tgt < S)
-        r = jnp.where(in_window, jnp.clip(tgt, 0, S - 1) % ROWS, -1)
-        row = jax.lax.broadcasted_iota(jnp.int32, (Hkv, ROWS, D), 1)
-        cout_ref[0, 0] = jnp.where(row == r, new_ref[0][:, None, :],
-                                   cin_ref[0, 0])
-
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(cache.shape, cache.dtype),
-        input_output_aliases={3: 0},   # cache operand (after 2 scalars + new)
-        interpret=interpret,
-    )(lengths, layer_arr, new, cache)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def cache_write_row_quant(cache: jnp.ndarray, scales: jnp.ndarray,
-                          new: jnp.ndarray, lengths: jnp.ndarray,
-                          layer: jnp.ndarray, interpret: bool = False):
-    """Quantizing variant of :func:`cache_write_row` for the int8 cache.
-
-    cache: [L, B, Hkv, S, D] int8; scales: [L, B, Hkv, S] f32; new: [B, Hkv, D]
-    float. Quantizes each new row per-head in VMEM (round-half-even, matching
-    kv_cache.quantize_rows bit-for-bit so XLA-prefilled and Pallas-decoded
-    rows are interchangeable) and writes the int8 row + its scale IN PLACE
-    (both buffers aliased). Out-of-window rows drop, as in the bf16 kernel.
-    Returns (cache, scales) — same buffers.
-    """
-    L, B, Hkv, S, D = cache.shape
-    lengths = lengths.astype(jnp.int32)
-    layer_arr = jnp.asarray(layer, jnp.int32).reshape(1)
-    # int8 arrays tile as (32, 128) on TPU: touch a 32-row block (vs 8 for
-    # bf16), falling back to the FULL window when 32 doesn't divide it (an
-    # 8-row fallback would violate the int8 sublane rule in Mosaic). Still
-    # ~128 KB in+out per slot — noise next to the full-cache copies this
-    # kernel avoids.
-    ROWS = 32 if S % 32 == 0 else S
-
-    def new_map(b, lens, lay):
-        return (b, 0, 0)
-
-    def blk_map(b, lens, lay):
-        return (lay[0], b, 0, jnp.clip(lens[b], 0, S - 1) // ROWS, 0)
-
-    def scale_map(b, lens, lay):
-        # Full-S scale block: S is the scales array's minormost (lane) axis
-        # and a lane-axis block must be a 128-multiple or the full dimension —
-        # a ROWS-sized block would fail Mosaic lowering. Hkv*S*4 bytes
-        # in+out per slot is still noise next to the copies this avoids.
-        return (lay[0], b, 0, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B,),
-        in_specs=[
-            pl.BlockSpec((1, Hkv, D), new_map),
-            pl.BlockSpec((1, 1, Hkv, ROWS, D), blk_map),
-            pl.BlockSpec((1, 1, Hkv, S), scale_map),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, Hkv, ROWS, D), blk_map),
-            pl.BlockSpec((1, 1, Hkv, S), scale_map),
-        ],
-    )
-
-    def kernel(lengths_ref, layer_ref, new_ref, cin_ref, sin_ref,
-               cout_ref, sout_ref):
-        b = pl.program_id(0)
-        tgt = lengths_ref[b]
-        in_window = (tgt >= 0) & (tgt < S)
-        r = jnp.where(in_window, jnp.clip(tgt, 0, S - 1) % ROWS, -1)
-        # the one shared quantizer (plain jnp ops, valid inside Pallas):
-        # XLA-prefilled and Pallas-decoded rows MUST quantize identically
-        from aws_k8s_ansible_provisioner_tpu.serving.kv_cache import (
-            quantize_rows)
-
-        q8, sc = quantize_rows(new_ref[0])                    # [Hkv,D],[Hkv]
-        row = jax.lax.broadcasted_iota(jnp.int32, (Hkv, ROWS, D), 1)
-        cout_ref[0, 0] = jnp.where(row == r, q8[:, None, :], cin_ref[0, 0])
-        # scale block spans the whole window: target column is tgt itself
-        # (masked to -1 out of window, matching the row-block drop)
-        rs = jax.lax.broadcasted_iota(jnp.int32, (Hkv, S), 1)
-        tgt_col = jnp.where(in_window, tgt, -1)
-        sout_ref[0, 0] = jnp.where(rs == tgt_col, sc[:, None], sin_ref[0, 0])
-
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct(cache.shape, cache.dtype),
-            jax.ShapeDtypeStruct(scales.shape, scales.dtype),
-        ],
-        input_output_aliases={3: 0, 4: 1},  # cache, scales (after 2 scalars + new)
-        interpret=interpret,
-    )(lengths, layer_arr, new, cache, scales)
-
-
 # ---------------------------------------------------------------------------
-# Paged variants: physical page pool + per-slot block tables, DOUBLE-BUFFERED
+# Attention over the physical page pool + per-slot block tables,
+# DOUBLE-BUFFERED
 # ---------------------------------------------------------------------------
 #
-# The dense kernels above address chunk c of slot b at cache[(lay, b, :,
-# c*CHUNK:(c+1)*CHUNK)] — an IDENTITY block table (kv_cache.pages_view). The
-# paged variants below keep the same flash math but OWN their data movement:
-# the pools stay in HBM (memory_space=ANY) and the kernel streams pages
-# through a two-slot VMEM buffer with explicit async copies — page c+1's
-# DMAs are issued BEFORE page c's flash update runs, so the fetch of the
-# next page overlaps the compute of the current one instead of serializing
-# behind it at a grid-step boundary.
+# The kernels OWN their data movement: the pools stay in HBM
+# (memory_space=ANY) and the kernel streams pages through a two-slot VMEM
+# buffer with explicit async copies — page c+1's DMAs are issued BEFORE page
+# c's flash update runs, so the fetch of the next page overlaps the compute of
+# the current one instead of serializing behind it at a grid-step boundary.
 #
 # Why not the implicit grid pipeline (the pre-r6 implementation): with grid
 # (B, max_pages) every (slot, page) pair is its own grid step, and the r5
@@ -1045,7 +233,7 @@ def _paged_db_body(lengths_ref, layer_ref, table_ref, share_ref, q_ref,
             k3 = k_buf[buf].astype(jnp.float32).reshape(bb * hkv, ps, d)
             v3 = v_buf[buf].astype(jnp.float32).reshape(bb * hkv, ps, d)
             if quant:
-                # scale pages arrive lane-padded (paged_kv.scale_lanes);
+                # scale pages arrive lane-padded (kv_pool.scale_lanes);
                 # only the first ``ps`` lanes are rows of this page
                 kscale = ks_buf[buf][:, :, :ps].reshape(bb * hkv, ps)
                 vscale = vs_buf[buf][:, :, :ps].reshape(bb * hkv, ps)
@@ -1192,7 +380,7 @@ def _paged_flash_db(q2, pool_k, pool_v, lengths, layer_arr, table,
     ]
     if quant:
         # Scale pages move whole: [Hkv, lanes] with lanes = the scale leaf's
-        # minor dim (paged_kv.scale_lanes pads page_size up to the 128-lane
+        # minor dim (kv_pool.scale_lanes pads page_size up to the 128-lane
         # tile — Mosaic refuses a DMA slice whose minor dim is narrower).
         scratch += [pltpu.VMEM((2, bb, Hkv, pool_ks.shape[3]),
                                pool_ks.dtype)] * 2
@@ -1254,7 +442,7 @@ def decode_attend_pallas_paged(q: jnp.ndarray, pool_k: jnp.ndarray,
     physical page ids (row b maps slot b's logical pages; entries at or past
     the slot's live range may be any valid id — they are clamped away, never
     fetched). Returns [B, 1, Hq, D]. pool_ks/vs switch the int8 scale-folding
-    body, as in the dense kernel. ``bblock`` slots share each grid step
+    body. ``bblock`` slots share each grid step
     (resolved to the largest divisor of B); page i+1 prefetches while page i
     computes regardless of bblock — see _paged_db_body. A slot of length 0
     (idle) is a dead row: nothing is fetched for it and its output row is
@@ -1356,8 +544,8 @@ def decode_attend_pallas_spec_paged(q: jnp.ndarray, pool_k: jnp.ndarray,
 
     q: [B, R, Hq, D]; row r masks to columns < lengths + 1 + r. The caller
     has already written all R rows (their pages allocated up front — the
-    engine's ensure-pages step covers lengths + R). Same economics as the
-    dense spec kernel: one page stream serves all R queries — and with
+    engine's ensure-pages step covers lengths + R). One page stream serves
+    all R queries — and with
     ``bblock`` > 1, all BB slots of a block. No row is dead here: a slot of
     length 0 still verifies R drafts over its first R rows.
     """
@@ -1387,16 +575,25 @@ def cache_write_row_paged(pool: jnp.ndarray, new: jnp.ndarray,
 
     pool: [L, P, Hkv, page, D]; new: [B, Hkv, D]; rows: [B] logical row per
     slot; table: [B, max_pages] int32; layer: scalar. Rows outside
-    [0, max_pages*page) DROP (surplus-write invariant). Same aliased-output
-    design as the dense cache_write_row (see its docstring for why a kernel
-    and not a scatter).
+    [0, max_pages*page) DROP (surplus-write invariant). Returns the updated
+    pool — same buffer.
+
+    Why a kernel for a 2 KB-per-slot write: the functional alternatives all
+    copy. A ``.at[...].set(...)`` lowers to scatter, and XLA's copy-insertion
+    around scatters in while-loop carries materializes full-cache copies
+    (measured on the slot-contiguous cache this pool replaced: 7 copies of
+    3.6 GB per decode step, 330 ms/token). ``input_output_aliases`` lowers to
+    a custom call with output-operand aliasing, which buffer assignment MUST
+    honor — the buffer is never copied; each grid step DMAs one 8-row block.
+    This is the TPU equivalent of vLLM's in-place ``cache_kernel`` CUDA
+    writes (reference SURVEY.md §2.2 row 1).
 
     ONE ROW A SLOT is the contract: every grid step opens a block of its
     own. Two steps on the same 8-row block would lose the first one's row
     (Pallas neither refetches the input block nor writes the output block
     back between consecutive steps that revisit it — on the chip one row in
     eight of a chunk landed, PR 25), so several rows of ONE slot go through
-    paged_kv.write_chunk_paged_layer, a page window at a time, as the mixed
+    kv_pool.write_chunk_paged_layer, a page window at a time, as the mixed
     program's chunk does.
     """
     L, P, Hkv, ps, D = pool.shape
@@ -1455,13 +652,13 @@ def cache_write_row_quant_paged(pool: jnp.ndarray, scales: jnp.ndarray,
     """Quantizing paged row write: int8 pool + per-row scales, both aliased.
 
     pool: [L, P, Hkv, page, D] int8; scales: [L, P, Hkv, lanes >= page] f32
-    (paged_kv.scale_lanes); new: [B, Hkv, D] float. Same quantizer as the
-    dense kernel (kv_cache.quantize_rows) so prefilled and decoded rows are
+    (kv_pool.scale_lanes); new: [B, Hkv, D] float. Same quantizer as the
+    XLA writers (kv_pool.quantize_rows) so prefilled and decoded rows are
     interchangeable. One row a slot, as cache_write_row_paged. Returns
     (pool, scales) — same buffers.
     """
     L, P, Hkv, ps, D = pool.shape
-    lanes = scales.shape[3]     # >= ps: lane-padded (paged_kv.scale_lanes)
+    lanes = scales.shape[3]     # >= ps: lane-padded (kv_pool.scale_lanes)
     rows = rows.astype(jnp.int32)
     layer_arr = jnp.asarray(layer, jnp.int32).reshape(1)
     table = table.astype(jnp.int32)
@@ -1501,9 +698,8 @@ def cache_write_row_quant_paged(pool: jnp.ndarray, scales: jnp.ndarray,
         tgt = lengths_ref[b]
         in_window = (tgt >= 0) & (tgt < S_v)
         r = jnp.where(in_window, jnp.clip(tgt, 0, S_v - 1) % ROWS, -1)
-        from aws_k8s_ansible_provisioner_tpu.serving.kv_cache import (
-            quantize_rows)
-
+        # the one shared quantizer (plain jnp ops, valid inside Pallas):
+        # XLA-prefilled and Pallas-decoded rows MUST quantize identically
         q8, sc = quantize_rows(new_ref[0])                    # [Hkv,D],[Hkv]
         row = jax.lax.broadcasted_iota(jnp.int32, (Hkv, ROWS, D), 1)
         cout_ref[0, 0] = jnp.where(row == r, q8[:, None, :], cin_ref[0, 0])

@@ -24,7 +24,6 @@ from aws_k8s_ansible_provisioner_tpu.parallel.ring_attention import (  # noqa: F
     ring_attend_local,
 )
 from aws_k8s_ansible_provisioner_tpu.parallel.sharding import (  # noqa: F401
-    cache_pspecs,
     check_tp_divisibility,
     param_pspecs,
     param_shardings,

@@ -141,23 +141,6 @@ def param_pspecs(cfg: ModelConfig, quant_weights: bool = False) -> dict:
     return specs
 
 
-def cache_pspecs(quant: bool = False) -> dict:
-    """Decode cache [L, slots, Hkv, S, D]: slots over dp, kv heads over tp,
-    sequence over sp (no-op on meshes with a size-1 sp axis; with sp > 1 the
-    cache window scales with the sp group's aggregate HBM — the long-context
-    serving axis). With ``quant`` the int8 cache's per-row scale leaves
-    ``ks``/``vs`` [L, slots, Hkv, S] shard identically (minus the head_dim
-    axis)."""
-    specs = {
-        "k": P(None, "dp", "tp", "sp", None),
-        "v": P(None, "dp", "tp", "sp", None),
-    }
-    if quant:
-        specs["ks"] = P(None, "dp", "tp", "sp")
-        specs["vs"] = P(None, "dp", "tp", "sp")
-    return specs
-
-
 def pool_pspecs(quant: bool = False) -> dict:
     """Paged KV pool [L, pages, Hkv, page, D]: PAGES over dp, kv heads over
     tp. Page identity is head-independent, so block tables, lengths, and the
@@ -167,9 +150,9 @@ def pool_pspecs(quant: bool = False) -> dict:
     host allocator, and a slot's table only ever references its group's
     partition (Engine writes GLOBAL ids = local + group * partition; the
     shard_map kernels subtract their partition base). On dp=1 meshes the dp
-    axis has size 1 and this degenerates to the tp-only layout. Only sp
-    keeps the dense cache (a page is a contiguous row run — splitting it
-    across sequence shards defeats paging)."""
+    axis has size 1 and this degenerates to the tp-only layout. No ``sp``
+    axis: a page is a contiguous row run, and splitting it across sequence
+    shards would defeat paging (the engine refuses an sp > 1 mesh)."""
     specs = {
         "k": P(None, "dp", "tp", None, None),
         "v": P(None, "dp", "tp", None, None),

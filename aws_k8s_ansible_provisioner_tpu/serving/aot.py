@@ -87,19 +87,14 @@ class ProgramPlan:
             raise ValueError("no prefill bucket fits the cache window")
         self.kv_quant = serving.kv_dtype == "int8"
         self.weights_quant = serving.weights_dtype == "int8"
-        self.paged = bool(serving.paged)
-        ps = serving.page_size
-        self.pages_per_slot = -(-self.max_len // ps) if self.paged else 0
-        if self.paged:
-            pool_pages = serving.kv_pool_pages \
-                or self.num_slots * self.pages_per_slot
-            if serving.kv_pool_pages and pool_pages % dp:
-                raise ValueError(f"kv_pool_pages={pool_pages} must be "
-                                 f"divisible by dp={dp}")
-            # +1 scratch page per dp group (engine layout)
-            self.total_pages = dp * (pool_pages // dp + 1)
-        else:
-            self.total_pages = 0
+        self.pages_per_slot = -(-self.max_len // serving.page_size)
+        pool_pages = serving.kv_pool_pages \
+            or self.num_slots * self.pages_per_slot
+        if serving.kv_pool_pages and pool_pages % dp:
+            raise ValueError(f"kv_pool_pages={pool_pages} must be "
+                             f"divisible by dp={dp}")
+        # +1 scratch page per dp group (engine layout)
+        self.total_pages = dp * (pool_pages // dp + 1)
         # batched-prefill row bucket: the engine rounds the live batch up to
         # a power of two, warmup fills min(max_prefill_batch, num_slots)
         nb = max(1, min(serving.max_prefill_batch, self.num_slots))
@@ -118,11 +113,10 @@ class ProgramPlan:
             "model": self.cfg.name,
             "num_slots": self.num_slots,
             "max_len": self.max_len,
-            "page_size": self.serving.page_size if self.paged else 0,
+            "page_size": self.serving.page_size,
             "buckets": list(self.buckets),
             "weights_dtype": self.serving.weights_dtype,
             "kv_dtype": self.serving.kv_dtype,
-            "paged": self.paged,
             "dp": self.dp, "tp": self.tp,
         }
 
@@ -174,9 +168,8 @@ def _abstract_state(plan, mesh, device=None):
     from aws_k8s_ansible_provisioner_tpu.models.layers import init_params
     from aws_k8s_ansible_provisioner_tpu.models.quant import quantize_params
     from aws_k8s_ansible_provisioner_tpu.parallel.sharding import (
-        cache_pspecs, param_pspecs, pool_pspecs)
-    from aws_k8s_ansible_provisioner_tpu.serving import kv_cache as kvc
-    from aws_k8s_ansible_provisioner_tpu.serving import paged_kv as pkv
+        param_pspecs, pool_pspecs)
+    from aws_k8s_ansible_provisioner_tpu.ops import kv_pool as kvp
 
     cfg, serving = plan.cfg, plan.serving
     dtype = jnp.bfloat16 if serving.dtype == "bfloat16" else jnp.float32
@@ -187,18 +180,10 @@ def _abstract_state(plan, mesh, device=None):
     params = _with_sharding(
         params, param_pspecs(cfg, quant_weights=plan.weights_quant), mesh,
         device)
-    if plan.paged:
-        cache = jax.eval_shape(
-            lambda: pkv.init_pool(cfg, plan.total_pages, serving.page_size,
-                                  dtype, quant=plan.kv_quant))
-        cache = _with_sharding(cache, pool_pspecs(plan.kv_quant), mesh,
-                               device)
-    else:
-        cache = jax.eval_shape(
-            lambda: kvc.init_cache(cfg, plan.num_slots, plan.max_len, dtype,
-                                   quant=plan.kv_quant))
-        cache = _with_sharding(cache, cache_pspecs(plan.kv_quant), mesh,
-                               device)
+    cache = jax.eval_shape(
+        lambda: kvp.init_pool(cfg, plan.total_pages, serving.page_size,
+                              dtype, quant=plan.kv_quant))
+    cache = _with_sharding(cache, pool_pspecs(plan.kv_quant), mesh, device)
     return params, cache
 
 
@@ -259,12 +244,12 @@ def enumerate_programs(plan, mesh, params, cache, bblock: int = 1):
         single-prompt scalar layout otherwise."""
         if n is None:
             return dict(
-                pages=sds((pps,), i32) if plan.paged else None,
+                pages=sds((pps,), i32),
                 seed=sds((), u32), ban_ids=sds((BAN_K,), i32),
                 ban_until=scalar, bias_ids=sds((BIAS_K,), i32),
                 bias_vals=sds((BIAS_K,), f32), rep=sds((), f32))
         return dict(
-            tables=sds((n, pps), i32) if plan.paged else None,
+            tables=sds((n, pps), i32),
             seeds=sds((n,), u32), ban_ids=sds((n, BAN_K), i32),
             ban_until=sds((n,), i32), bias_ids=sds((n, BIAS_K), i32),
             bias_vals=sds((n, BIAS_K), f32), reps=sds((n,), f32))
@@ -273,7 +258,7 @@ def enumerate_programs(plan, mesh, params, cache, bblock: int = 1):
     for b in plan.buckets:
         programs.append((
             f"prefill_b{b}", prefill_step,
-            (cfg, params, cache, sds((1, b), i32), scalar, scalar, rng,
+            (cfg, params, cache, sds((1, b), i32), scalar, rng,
              sds((), f32), scalar, sds((), f32)),
             prefill_kwargs()))
     # logprobs variants compile against the smallest bucket (any bucket
@@ -281,21 +266,20 @@ def enumerate_programs(plan, mesh, params, cache, bblock: int = 1):
     b0 = plan.buckets[0]
     programs.append((
         f"prefill_b{b0}_logprobs", prefill_step,
-        (cfg, params, cache, sds((1, b0), i32), scalar, scalar, rng,
+        (cfg, params, cache, sds((1, b0), i32), scalar, rng,
          sds((), f32), scalar, sds((), f32)),
         dict(prefill_kwargs(), logprobs=True, prompt_logprobs=True)))
     n = plan.batch_rows
     programs.append((
         f"prefill_batch_n{n}_b{b0}", prefill_batch_step,
-        (cfg, params, cache, sds((n, b0), i32), sds((n,), i32),
-         sds((n,), i32), rng, sds((n,), f32), sds((n,), i32),
-         sds((n,), f32)),
+        (cfg, params, cache, sds((n, b0), i32), sds((n,), i32), rng,
+         sds((n,), f32), sds((n,), i32), sds((n,), f32)),
         prefill_kwargs(n)))
     programs.append((
         f"prefill_chunk_c{plan.chunk}", prefill_chunk_step,
         (cfg, params, cache, sds((1, plan.chunk), i32), scalar, scalar,
-         scalar, rng, sds((), f32), scalar, sds((), f32)),
-        dict(pages=sds((pps,), i32) if plan.paged else None,
+         rng, sds((), f32), scalar, sds((), f32)),
+        dict(pages=sds((pps,), i32),
              seed=sds((), u32), ban_ids=sds((BAN_K,), i32),
              ban_until=scalar, bias_ids=sds((BIAS_K,), i32),
              bias_vals=sds((BIAS_K,), f32), rep=sds((), f32),
@@ -309,7 +293,7 @@ def enumerate_programs(plan, mesh, params, cache, bblock: int = 1):
         kw = dict(
             mesh=mesh, impl=serving.attention_impl, logprobs=logprobs,
             penalties=penalties,
-            table=sds((B, pps), i32) if plan.paged else None,
+            table=sds((B, pps), i32),
             seeds=sds((B,), u32), ban_ids=sds((B, BAN_K), i32),
             ban_until=sds((B,), i32), bias_ids=sds((B, BIAS_K), i32),
             bias_vals=sds((B, BIAS_K), f32), bblock=bblock, live=live)
@@ -333,8 +317,7 @@ def enumerate_programs(plan, mesh, params, cache, bblock: int = 1):
                      decode_args, decode_kwargs(penalties=True)))
     programs.append((f"decode_fused_h{plan.horizon}_logprobs", decode_steps,
                      decode_args, decode_kwargs(logprobs=True)))
-    if (plan.paged and serving.ragged_attention > 0
-            and serving.decode_pipeline > 0
+    if (serving.ragged_attention > 0 and serving.decode_pipeline > 0
             and (serving.ragged_features > 0 or not serving.spec_decode)):
         # Ragged mixed-batch program (ISSUE 14): one dispatch serves a
         # prefill chunk packed alongside every decode row. Operand layout
@@ -373,7 +356,7 @@ def enumerate_programs(plan, mesh, params, cache, bblock: int = 1):
             (cfg, R, params, cache, sds((B, R), i32), sds((B,), i32), rng,
              sds((B,), f32), sds((B,), i32), sds((B,), f32)),
             dict(impl=serving.attention_impl, mesh=mesh,
-                 table=sds((B, pps), i32) if plan.paged else None,
+                 table=sds((B, pps), i32),
                  seeds=sds((B,), u32), bblock=bblock)))
     return programs
 
@@ -420,14 +403,12 @@ def compile_programs(programs, progress=None) -> list:
 def build_ledger(plan, mesh, params, cache, entries,
                  hbm_gib: float = V5E_HBM_GIB_PER_CHIP) -> dict:
     from aws_k8s_ansible_provisioner_tpu.parallel.sharding import (
-        cache_pspecs, param_pspecs, pool_pspecs)
+        param_pspecs, pool_pspecs)
 
     capacity = int(hbm_gib * 2**30)
     pspecs = param_pspecs(plan.cfg, quant_weights=plan.weights_quant)
     params_bytes = _sharded_bytes(params, pspecs, mesh)
-    kv_specs = pool_pspecs(plan.kv_quant) if plan.paged \
-        else cache_pspecs(plan.kv_quant)
-    kv_bytes = _sharded_bytes(cache, kv_specs, mesh)
+    kv_bytes = _sharded_bytes(cache, pool_pspecs(plan.kv_quant), mesh)
     max_temp = max((e["temp_bytes"] for e in entries), default=0)
     total = params_bytes + kv_bytes + max_temp
     return {
@@ -444,8 +425,7 @@ def build_ledger(plan, mesh, params, cache, entries,
         # reserve it on top of the process's baseline RSS. Absent from
         # LEDGER_FIELDS so pre-tier manifests still verify.
         "host_tier_bytes": int(getattr(plan.serving,
-                                       "kv_host_tier_bytes", 0))
-        if plan.paged else 0,
+                                       "kv_host_tier_bytes", 0)),
     }
 
 

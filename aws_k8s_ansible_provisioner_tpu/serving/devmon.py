@@ -57,8 +57,8 @@ DEFAULT_HBM_TOLERANCE_MB = 64.0
 
 # Program kinds the engine reports — the label set is closed so the gauge
 # cardinality is bounded no matter what traffic does.
-PROGRAM_KINDS = ("prefill", "prefill_batch", "prefill_chunk", "prefix_copy",
-                 "kv_restore", "decode", "spec_decode", "mixed_step")
+PROGRAM_KINDS = ("prefill", "prefill_batch", "prefill_chunk", "kv_restore",
+                 "decode", "spec_decode", "mixed_step")
 
 
 class DevMonMetrics:
@@ -111,7 +111,7 @@ class CostModel:
                            step, amortized over the whole batch.
     ``kv_row_bytes``     — k+v bytes for ONE token of context across all
                            layers/heads (int8 rows include their f32 scale,
-                           mirroring kv_cache.py's accounting).
+                           mirroring ops/kv_pool.py's accounting).
     ``mask_row_bytes``   — bytes of ONE row of the guided-decoding allow
                            bitset (ceil(V/32) uint32 words): the per-step
                            host→HBM upload a guided row adds when it rides
@@ -157,14 +157,11 @@ class CostModel:
         each generated token reads its whole context's KV rows.
         prefill-like: weights stream once; each prompt token writes its KV
         row (attention reads ride the same rows and stay sub-dominant).
-        prefix_copy: pure DMA — read + write of the copied rows, zero flops.
         kv_restore: host-tier restore (ISSUE 20) — one HBM write per
         restored KV row, zero flops. Its bandwidth-sense MFU column is the
         restore-vs-reprefill ledger: the same tokens through a prefill kind
         would have cost flops_per_token * tokens of MXU work.
         """
-        if kind == "prefix_copy":
-            return 0.0, 2.0 * tokens * self.kv_row_bytes
         if kind == "kv_restore":
             return 0.0, float(tokens) * self.kv_row_bytes
         flops = self.flops_per_token * tokens

@@ -45,16 +45,23 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from aws_k8s_ansible_provisioner_tpu.serving import kv_cache as kvc
+from aws_k8s_ansible_provisioner_tpu.ops import kv_pool as kvp
 
 
 class DraftModel:
     """Holds the draft network + its per-slot KV cache and sync state."""
 
-    def __init__(self, cfg, params, num_slots: int, max_len: int, dtype):
+    def __init__(self, cfg, params, num_slots: int, max_len: int, dtype,
+                 page_size: int):
         self.cfg = cfg
         self.params = params
-        self.cache = kvc.init_cache(cfg, num_slots, max_len, dtype)
+        # A pool of its own under a STATIC IDENTITY block table: slot i owns
+        # pages [i * MP, (i + 1) * MP). No allocator, no sharing — the draft
+        # is small, so every slot simply keeps a full window of pages.
+        mp = -(-max_len // page_size)
+        self.cache = kvp.init_pool(cfg, num_slots * mp, page_size, dtype)
+        self.table = np.arange(num_slots * mp, dtype=np.int32).reshape(
+            num_slots, mp)
         self.num_slots = num_slots
         self.max_len = max_len
         # rows of TRUE context K/V per slot (== next write position)
@@ -75,11 +82,18 @@ class DraftModel:
             prefill_batch_step)
 
         n = tokens.shape[0]
+        # padding rows (slot == num_slots) carry all-OOB_PAGE tables: their
+        # writes drop
+        slots = np.asarray(slots)
+        real = slots < self.num_slots
+        tables = np.where(real[:, None],
+                          self.table[np.where(real, slots, 0)],
+                          kvp.OOB_PAGE).astype(np.int32)
         out = prefill_batch_step(
             self.cfg, self.params, self.cache, jnp.asarray(tokens),
-            jnp.asarray(true_lens), jnp.asarray(slots), engine._next_rng(),
+            jnp.asarray(true_lens), engine._next_rng(),
             jnp.zeros(n, jnp.float32), jnp.zeros(n, jnp.int32),
-            jnp.ones(n, jnp.float32))
+            jnp.ones(n, jnp.float32), tables=jnp.asarray(tables))
         self.cache = out[0]
         for i in range(n):
             s = int(slots[i])
@@ -136,7 +150,7 @@ class DraftModel:
             engine._next_rng(),
             jnp.zeros(self.num_slots, jnp.float32),       # greedy rollout
             jnp.zeros(self.num_slots, jnp.int32),
-            jnp.ones(self.num_slots, jnp.float32))
+            jnp.ones(self.num_slots, jnp.float32), table=jnp.asarray(self.table))
         out = np.asarray(out)                              # [K, B]
         drafts = np.zeros((self.num_slots, K), np.int32)
         proposed = {}
@@ -177,7 +191,7 @@ class DraftModel:
             jnp.asarray(self.lens), engine._next_rng(),
             jnp.zeros(self.num_slots, jnp.float32),
             jnp.zeros(self.num_slots, jnp.int32),
-            jnp.ones(self.num_slots, jnp.float32))
+            jnp.ones(self.num_slots, jnp.float32), table=jnp.asarray(self.table))
         self.cache = out[0]
         self.lens += adv
 

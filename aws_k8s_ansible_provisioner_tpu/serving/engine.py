@@ -43,8 +43,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from aws_k8s_ansible_provisioner_tpu.config import ModelConfig, ServingConfig
+from aws_k8s_ansible_provisioner_tpu.ops import kv_pool as kvp
 from aws_k8s_ansible_provisioner_tpu.serving import capacity as _capacity
-from aws_k8s_ansible_provisioner_tpu.serving import paged_kv as pkv
 from aws_k8s_ansible_provisioner_tpu.serving import chaos as _chaos
 from aws_k8s_ansible_provisioner_tpu.serving import devmon as _devmon
 from aws_k8s_ansible_provisioner_tpu.serving import flightrec as _flight
@@ -241,6 +241,11 @@ class Request:
 class Engine(EnginePrograms):
     """Continuous-batching engine over a fixed set of decode slots."""
 
+    # The paged pool is the only KV layout. Read-only, kept for two readers:
+    # benchmark/benchlib/server_under_test.py (refuses a run whose engine
+    # did not resolve paged) and server.py's /debug/state.
+    paged = True
+
     # Single-writer contract (tpulint R5 / LockSan): these attributes are
     # mutated ONLY by the engine-step thread (run_forever -> step and its
     # helpers). Other threads may read them (GIL-atomic snapshots for
@@ -251,7 +256,7 @@ class Engine(EnginePrograms):
         "table", "lengths", "cache", "counts", "last_token",
         "slot_req", "temps", "pres_pens", "freq_pens", "rep_pens",
         "ban_until", "bias_ids", "bias_vals", "lora_idx", "_bias_n",
-        "_slot_pages", "_slot_tokens", "_chunk",
+        "_slot_pages", "_chunk",
         "_chunk_yield", "_prefill_streak", "_admission_blocked_since",
         "_tok_times", "_admit_seq", "_seq_counter", "prompt_mask",
         "_inflight", "_pipe_carry", "_carry_gen", "_op_cache",
@@ -268,7 +273,7 @@ class Engine(EnginePrograms):
         self.serving = serving
         # Draft-model speculation (serving/draft.py; VERDICT r4 next #7):
         # ``draft`` is (draft_cfg, draft_params). Requires spec_decode with
-        # spec_method="draft"; the DraftModel allocates its own dense cache
+        # spec_method="draft"; the DraftModel allocates its own small pool
         # after max_len resolves below.
         self._draft_src = draft
         self.eos_token_id = cfg.eos_token_id if eos_token_id is None \
@@ -294,8 +299,8 @@ class Engine(EnginePrograms):
         self.max_len = min(self.max_len, cfg.max_seq_len)
         self.buckets = tuple(b for b in serving.prefill_buckets
                              if b <= self.max_len)
-        # Program-operand construction (quantize/shard/LoRA, paged pool
-        # + dense cache) lives with the compiled-program registry:
+        # Program-operand construction (quantize/shard/LoRA, paged pool)
+        # lives with the compiled-program registry:
         # EnginePrograms._init_params_and_cache (serving/programs.py).
         self._init_params_and_cache(mesh, lora)
 
@@ -337,11 +342,8 @@ class Engine(EnginePrograms):
         self.bias_vals = np.zeros((self.num_slots, BIAS_K), np.float32)
         self._bias_n = np.zeros(self.num_slots, np.int32)
         # per-slot LoRA adapter index (0 = base); rides every dispatch when
-        # adapters are registered. _slot_lora mirrors the adapter whose
-        # projections produced each DENSE slot's retained rows — the dense
-        # prefix cache must never cross adapters (review r5).
+        # adapters are registered
         self.lora_idx = np.zeros(self.num_slots, np.int32)
-        self._slot_lora = np.zeros(self.num_slots, np.int32)
         self.pres_pens = np.zeros(self.num_slots, np.float32)
         self.freq_pens = np.zeros(self.num_slots, np.float32)
         self.rep_pens = np.ones(self.num_slots, np.float32)
@@ -376,11 +378,6 @@ class Engine(EnginePrograms):
         # Consecutive prefill dispatches since the last decode — the
         # prefill_fairness floor keys off this (step()).
         self._prefill_streak = 0
-        # Prefix cache: token ids whose K/V rows are resident in rows
-        # [0, len) of each slot — retained after a request finishes (rows are
-        # only ever written at/past a slot's current length, so a freed
-        # slot's prompt rows stay intact until the slot is reused).
-        self._slot_tokens: List[tuple] = [()] * self.num_slots
         # Batch-block size for the decode kernels (PALLAS_DECODE_BBLOCK
         # promoted to a first-class parameter): explicit config/env override,
         # else a one-shot deterministic startup microbench over
@@ -461,19 +458,16 @@ class Engine(EnginePrograms):
 
         def _live() -> dict:
             comp = {"params": float(params_bytes)}
-            if self.paged:
-                sts = [a.stats() for a in self.allocators]
-                total = sum(s["pages_total"] for s in sts) or 1
-                live = sum(s["pages_live"] for s in sts)
-                comp["kv_pages"] = cache_bytes * (live / total)
-                # evictable pages hold reusable prefixes but yield to the
-                # allocator on demand — ledger them as their own component
-                # so "pool full" and "pool full of reclaimable prefixes"
-                # read differently (ISSUE 20 satellite)
-                evict = sum(s["pages_evictable"] for s in sts)
-                comp["kv_pages_evictable"] = cache_bytes * (evict / total)
-            else:
-                comp["kv_cache"] = float(cache_bytes)
+            sts = [a.stats() for a in self.allocators]
+            total = sum(s["pages_total"] for s in sts) or 1
+            live = sum(s["pages_live"] for s in sts)
+            comp["kv_pages"] = cache_bytes * (live / total)
+            # evictable pages hold reusable prefixes but yield to the
+            # allocator on demand — ledger them as their own component
+            # so "pool full" and "pool full of reclaimable prefixes"
+            # read differently (ISSUE 20 satellite)
+            evict = sum(s["pages_evictable"] for s in sts)
+            comp["kv_pages_evictable"] = cache_bytes * (evict / total)
             carry = self._pipe_carry
             if carry is not None:
                 comp["sampler_carry"] = float(
@@ -503,11 +497,10 @@ class Engine(EnginePrograms):
     def _build_mesh(serving: ServingConfig):
         """Build the serving mesh from config (None for single-device).
 
-        All three axes serve: ``dp`` shards slots, ``tp`` shards heads
-        (Megatron), ``sp`` shards the KV cache's sequence axis — the
-        long-context axis, letting the cache window scale with the sp group's
-        aggregate HBM (decode merges per-shard flash partials; see
-        ops/attention.make_decode_attend_carry).
+        ``dp`` shards slots and the pool's pages, ``tp`` shards heads
+        (Megatron) and the pool's KV heads, ``ep`` shards an MoE model's
+        experts. A mesh with ``sp`` > 1 is refused
+        (EnginePrograms._init_params_and_cache).
         """
         mc = serving.mesh
         if mc.num_devices <= 1:
@@ -555,60 +548,6 @@ class Engine(EnginePrograms):
             return self.serving.prefill_chunk
         return self.buckets[-1]
 
-    def _find_prefix(self, req: Request, slot: int):
-        """Longest resident prompt prefix for ``req`` → (src_slot, n) or None.
-
-        Scans the per-slot retained prompt tokens (host-side; <= num_slots
-        short tuple comparisons). The reuse is capped one token short of the
-        prompt — the final token must run through prefill to produce the
-        request's first sampled token. ``slot`` is the slot just assigned to
-        the request (for the dispatch-economics gate; matching it means the
-        rows are already in place and reuse is free).
-        """
-        if not self.serving.prefix_cache or req.prompt_logprobs is not None:
-            return None
-        ids = req.prompt_ids
-        cap = len(ids) - 1
-        req_lidx = (self.lora_names.index(req.lora) + 1
-                    if req.lora is not None else 0)
-        best_n, best_s = 0, -1
-        for s, toks in enumerate(self._slot_tokens):
-            if self._slot_lora[s] != req_lidx:
-                # rows were projected under a different adapter (review r5)
-                continue
-            m = min(len(toks), cap)
-            if m <= best_n:
-                continue
-            n = 0
-            while n < m and toks[n] == ids[n]:
-                n += 1
-            if n > best_n:
-                best_n, best_s = n, s
-        if best_n < max(1, self.serving.prefix_cache_min_len):
-            return None
-        if not self._hit_pays(req, best_s, slot, best_n):
-            return None
-        return best_s, best_n
-
-    def _hit_pays(self, req: Request, src: int, slot: int, n: int) -> bool:
-        """Dispatch-economics gate on a prefix hit.
-
-        The hit path costs one slot-copy dispatch (zero when the request got
-        its own previous slot back) plus ceil(suffix/C) chunk dispatches; the
-        miss path costs one bucket dispatch (or ceil(len/C) chunks for a
-        prompt that chunks anyway). Each dispatch is ~an RTT on a
-        network-attached chip, so a hit that ADDS dispatches only pays once
-        the reused rows save enough prefill FLOPs to beat the added latency —
-        ``prefix_cache_payback_rows`` calibrates that crossover (lower it for
-        big models, where recompute dominates sooner)."""
-        C = self._chunk_size
-        ln = len(req.prompt_ids)
-        hit_disp = (0 if src == slot else 1) + max(1, -(-(ln - n) // C))
-        miss_disp = -(-ln // C) if self._should_chunk(req) else 1
-        if hit_disp <= miss_disp:
-            return True
-        return n >= max(1, self.serving.prefix_cache_payback_rows)
-
     # -- paged-KV lifecycle -------------------------------------------------
     # Slots map to dp groups contiguously (slot // slots_per_group); each
     # group's allocator works in LOCAL page ids (0 = its scratch page) and
@@ -634,8 +573,8 @@ class Engine(EnginePrograms):
         requeues; the admission gate makes this rare — it means evictable
         pages vanished between the gate and here).
 
-        ``isolated`` mirrors the dense path's dispatch-economics gate: a
-        prefix hit forces the serialized chunk path, so under a burst the
+        ``isolated`` is the dispatch-economics gate: a prefix hit forces
+        the serialized chunk path, so under a burst the
         batched prefill wins — unless the request would chunk anyway, or the
         match spans >= prefix_reuse_min_pages whole pages, where skipping
         the shared-prefix compute (and refcount-sharing the pages instead
@@ -755,7 +694,7 @@ class Engine(EnginePrograms):
         drec = self._dispatch_open("_restore_scatter", "kv_restore",
                                    prompt_tokens=tokens)
         with _Dispatching(drec):
-            self.cache = pkv.restore_pages(
+            self.cache = kvp.restore_pages(
                 self.cache, [int(p) + gbase for p in pids], data)
         nbytes = len(entries) * self._page_bytes
         self._alloc(slot).host_tier.note_restored(len(entries), nbytes)
@@ -764,11 +703,10 @@ class Engine(EnginePrograms):
             "drec": drec}
 
     def _settle_restore(self, slot: int):
-        """Settle a scheduled restore before the slot's first suffix chunk:
-        the paged analogue of the dense prefix-copy sync. The block is
-        sanctioned (R8) — the wait IS the PCIe DMA this feature trades for
-        the prefix re-prefill FLOPs, and devmon's kv_restore cost term needs
-        the real wall time."""
+        """Settle a scheduled restore before the slot's first suffix chunk.
+        The block is sanctioned (R8) — the wait IS the PCIe DMA this feature
+        trades for the prefix re-prefill FLOPs, and devmon's kv_restore cost
+        term needs the real wall time."""
         pend = self._restore_pending.pop(slot, None)
         if pend is None:
             return
@@ -794,7 +732,7 @@ class Engine(EnginePrograms):
             return
         allocator.evicted_log = []
         gbase = self._gbase(slot)
-        data = pkv.gather_pages(self.cache,
+        data = kvp.gather_pages(self.cache,
                                 [pid + gbase for pid, _, _ in log])
         for i, (_, key, toks) in enumerate(log):
             entry = {name: arr[:, i] for name, arr in data.items()}
@@ -833,8 +771,6 @@ class Engine(EnginePrograms):
         evictable LRU, still prefix-matchable) and point its table at the
         scratch page — idle slots' garbage decode writes must never land in
         pages another request now owns."""
-        if not self.paged:
-            return
         self._alloc(slot).release_all(self._slot_pages[slot])
         self._slot_pages[slot] = []
         # a restore scheduled for a slot torn down before its chunk started
@@ -863,8 +799,6 @@ class Engine(EnginePrograms):
         (vLLM-style recompute: pages freed, request resubmitted at the queue
         front) until allocation succeeds. Returns whether any slot is still
         active."""
-        if not self.paged:
-            return bool(self._active_slots())
         ps = self.serving.page_size
         # oldest first: under pressure the newest admissions yield their
         # pages (and their slots) to the oldest — FCFS fairness
@@ -972,11 +906,8 @@ class Engine(EnginePrograms):
             raise ContextLengthExceeded(len(req.prompt_ids), self.prompt_limit,
                                         self.max_len)
         if req.resume_ids:
-            # Failover continuation: rides the preemption-resume machinery,
-            # which is paged-only (_paged_admit consults _resume_ctx).
-            if not self.paged:
-                raise ValueError("continuation (resume_ids) requires the "
-                                 "paged engine")
+            # Failover continuation: rides the preemption-resume machinery
+            # (_paged_admit consults _resume_ctx).
             if len(req.prompt_ids) + len(req.resume_ids) > self.max_len - 2:
                 raise ContextLengthExceeded(
                     len(req.prompt_ids) + len(req.resume_ids),
@@ -1235,8 +1166,7 @@ class Engine(EnginePrograms):
             # the scheduler entry drains later as a "cancelled" pop; the
             # client is answered NOW with the real reason
             self.sched.cancel(r.id)
-            if self.paged:
-                self._resume_ctx.pop(r.id, None)
+            self._resume_ctx.pop(r.id, None)
             r.finish_reason = "timeout"
             self.metrics.deadline_expired.inc()
             self.metrics.mark_request("timeout", now - r.t_submit)
@@ -1283,7 +1213,7 @@ class Engine(EnginePrograms):
         batch: List = []
         chunk_next = None
         while len(batch) < max(1, self.serving.max_prefill_batch):
-            # Paged admission is gated by the allocators' headroom (free +
+            # Admission is gated by the allocators' headroom (free +
             # evictable pages) — capacity scales with ACTUAL lengths, the
             # vLLM on-demand-block behavior (VERDICT r2 missing #2). With dp
             # groups the gate is the BEST group's headroom (the scheduler
@@ -1292,15 +1222,13 @@ class Engine(EnginePrograms):
             # — the freed slot rotates to the back of the free deque, so
             # retries walk onto other groups' slots.
             action = self.sched.pop_admission(
-                max(a.free_pages for a in self.allocators)
-                if self.paged else None)
+                max(a.free_pages for a in self.allocators))
             if action is None:
                 break
             if action[0] == "cancelled":
                 with self._lock:
                     cand = self._queued.pop(action[1], None)
-                if self.paged:
-                    self._resume_ctx.pop(action[1], None)
+                self._resume_ctx.pop(action[1], None)
                 self.metrics.queue_depth.set(self.sched.stats().queue_depth)
                 if cand is not None:
                     cand.finish_reason = "cancelled"
@@ -1317,57 +1245,32 @@ class Engine(EnginePrograms):
                 continue
             if not req.t_prefill_start:
                 req.t_prefill_start = time.monotonic()
-            if self.paged:
-                isolated = (not batch
-                            and self.sched.stats().queue_depth == 0)
-                prep = self._paged_admit(req, slot, isolated)
-                if prep is None:
-                    # evictable pages vanished between the admission gate
-                    # and allocation (another admit this round took them):
-                    # requeue at the front and stop admitting this step
-                    self.sched.release(slot)
-                    with self._lock:
-                        self._queued[rid] = req
-                    ids_q = self._resume_ctx.get(rid, req.prompt_ids)
-                    self.sched.submit_front(
-                        rid, len(ids_q),
-                        max(1, req.max_tokens - len(req.generated)))
-                    break
-                ids, off, resumed = prep
-                # prefix reuse and resumes walk the chunk program from the
-                # reuse offset; fresh bucket-sized prompts join the batch.
-                # With a dispatch in flight on the ragged path, EVERY
-                # admission takes the chunk walk: the mixed program prefills
-                # it without draining the pipeline, where a batch prefill
-                # would activate slots under the in-flight carry.
-                if (off > 0 or resumed or self._should_chunk(req)
-                        or (self._ragged_on()
-                            and self._inflight is not None)):
-                    chunk_next = (req, slot, ("paged", ids, off, resumed))
-                    break
-                batch.append((req, slot))
-                continue
-            # Prefix reuse goes through the (serialized) chunk program, so
-            # only consult the cache for an ISOLATED arrival — empty batch
-            # and nothing else waiting. Under a burst, batched prefill wins:
-            # taking the chunk path per request would serialize the whole
-            # burst into one ~RTT dispatch each, costing far more than the
-            # prefix recompute it saves at bucket sizes (the isolated case —
-            # a follow-up chat turn re-sending its history — is where the
-            # rows are long and reuse pays). The consult happens BEFORE this
-            # slot's retained tokens are cleared so the request may match its
-            # own just-freed slot (the saturated-engine follow-up-turn case:
-            # rows already in place, reuse is free).
-            pref = None
-            if not batch and self.sched.stats().queue_depth == 0:
-                pref = self._find_prefix(req, slot)
-            # The slot just assigned will be overwritten by this admission
-            # round's prefill — its retained rows must stop matching as a
-            # prefix source from here on, or a later request in this same
-            # loop could copy rows the batch prefill is about to clobber.
-            self._slot_tokens[slot] = ()
-            if self._should_chunk(req) or pref is not None:
-                chunk_next = (req, slot, pref)
+            isolated = (not batch
+                        and self.sched.stats().queue_depth == 0)
+            prep = self._paged_admit(req, slot, isolated)
+            if prep is None:
+                # evictable pages vanished between the admission gate
+                # and allocation (another admit this round took them):
+                # requeue at the front and stop admitting this step
+                self.sched.release(slot)
+                with self._lock:
+                    self._queued[rid] = req
+                ids_q = self._resume_ctx.get(rid, req.prompt_ids)
+                self.sched.submit_front(
+                    rid, len(ids_q),
+                    max(1, req.max_tokens - len(req.generated)))
+                break
+            ids, off, resumed = prep
+            # prefix reuse and resumes walk the chunk program from the
+            # reuse offset; fresh bucket-sized prompts join the batch.
+            # With a dispatch in flight on the ragged path, EVERY
+            # admission takes the chunk walk: the mixed program prefills
+            # it without draining the pipeline, where a batch prefill
+            # would activate slots under the in-flight carry.
+            if (off > 0 or resumed or self._should_chunk(req)
+                    or (self._ragged_on()
+                        and self._inflight is not None)):
+                chunk_next = (req, slot, ids, off, resumed)
                 break
             batch.append((req, slot))
         return batch, chunk_next
@@ -1446,7 +1349,7 @@ class Engine(EnginePrograms):
             batch, chunk_next = self._admit_round()
         if batch or chunk_next is not None:
             self._admission_blocked_since = 0.0
-        elif self.paged:
+        else:
             # nothing admitted although work waits: if a slot is free, the
             # head is page-starved — degrade by policy, don't wedge
             with _phase(PH_ADMIT):
@@ -1480,7 +1383,7 @@ class Engine(EnginePrograms):
                                    phase="prefill_batch")
                     req.out_queue.put(None)
                 if chunk_next is not None:
-                    req, slot, _ = chunk_next
+                    req, slot = chunk_next[:2]
                     self._release_slot_pages(slot)
                     self.sched.release(slot)
                     req.finish_reason = "error"
@@ -1552,23 +1455,19 @@ class Engine(EnginePrograms):
         _flight.finish(req.id, reason=req.finish_reason or "stop",
                        ok=(status == "success"), slot=slot,
                        n_generated=len(req.generated))
-        if self.paged:
-            # Index the GENERATED pages too, so a follow-up turn whose prompt
-            # contains this response prefix-hits past the original prompt
-            # (ADVICE r3: only _activate indexed pages, so the generated
-            # region always re-prefilled). Same pending-row cap as
-            # preemption: the last emitted token's K/V row is written by the
-            # NEXT dispatch, which never came — cap at len(ids) - 1.
-            ids = req.prompt_ids + req.generated
-            self._index_prompt_pages(slot, ids, n_valid=len(ids) - 1)
+        # Index the GENERATED pages too, so a follow-up turn whose prompt
+        # contains this response prefix-hits past the original prompt
+        # (ADVICE r3: only _activate indexed pages, so the generated
+        # region always re-prefilled). Same pending-row cap as
+        # preemption: the last emitted token's K/V row is written by the
+        # NEXT dispatch, which never came — cap at len(ids) - 1.
+        ids = req.prompt_ids + req.generated
+        self._index_prompt_pages(slot, ids, n_valid=len(ids) - 1)
         self.slot_req[slot] = None
-        # Dense: keep the freed slot's length — decode dispatches write a
-        # scratch K/V row for EVERY slot at its current length, so a zeroed
-        # length would let that garbage land on row 0, corrupting the
-        # retained prompt rows the prefix cache reuses. (Paged: pages are
-        # RELEASED below — indexed ones stay prefix-matchable in the
-        # evictable LRU — and the zeroed table points idle writes at the
-        # scratch page, so the length resets to 0 there.)
+        # The pages are RELEASED below — indexed ones stay prefix-matchable
+        # in the evictable LRU — and the zeroed table points idle slots'
+        # garbage decode writes at the scratch page, so the length resets
+        # to 0.
         # NOTE: a finish does NOT bump _carry_gen — an in-flight pipelined
         # dispatch keeps decoding the freed slot as discardable garbage
         # (scratch-table writes, emits skipped); only a REUSE (_activate)
@@ -1682,8 +1581,7 @@ class Engine(EnginePrograms):
             self.metrics.mark_request("error", 0.0)
             _flight.finish(st["req"].id, "error", ok=False, detail=reason)
             st["req"].out_queue.put(None)
-        if self.paged:
-            self._resume_ctx.clear()   # queued resumes are failed below
+        self._resume_ctx.clear()   # queued resumes are failed below
         for slot, r in enumerate(self.slot_req):
             if r is not None:
                 r.finish_reason = "error"

@@ -1,23 +1,13 @@
-"""True paged KV cache: page pool + block tables + host allocator.
+"""Paged KV cache, host side: page allocator, prefix index, host tier.
 
-The slot-contiguous cache (serving/kv_cache.py) reserves ``max_len`` rows per
-slot forever — HBM cost is ``slots x window`` regardless of actual lengths, so
-a 16 GB chip tops out near 128-192 concurrent kilotoken windows (VERDICT r2
-missing #2). The vLLM engine the reference delegates to (SURVEY.md §2.2 row 1,
+The vLLM engine the reference delegates to (SURVEY.md §2.2 row 1,
 /root/reference/llm-d-deploy.yaml:176-193) allocates KV *blocks on demand*,
-admitting far more concurrent short requests from the same HBM. This module is
-the TPU-native equivalent:
+admitting far more concurrent short requests from the same HBM than a fixed
+window per slot would. This module is the host half of the TPU-native
+equivalent; the pool's arrays, writers and gathers are ops/kv_pool.py.
 
-- **Page pool**: ``k, v : [L, P, Hkv, page, D]`` (+ per-row scale leaves
-  ``ks, vs : [L, P, Hkv, lanes]`` when int8; lanes = page rounded up to the
-  128-lane tile, :func:`scale_lanes`) — P physical pages shared by all
-  slots, allocated once at startup (XLA static shapes; capacity planning picks
-  P, not per-slot reservations).
 - **Block tables**: host numpy ``[num_slots, max_pages_per_slot]`` int32 of
-  physical page ids, passed to each step program as a device array; the
-  Pallas kernels read it via scalar prefetch and fetch page
-  ``table[slot, logical_chunk]`` instead of the identity mapping
-  (ops/pallas_attention.py paged variants).
+  physical page ids, passed to each step program as a device array.
 - **Host allocator** (:class:`PagePool`): free list + per-page refcounts +
   a content-hash index over FULL pages for prefix reuse (vLLM's automatic
   prefix caching at page granularity — a new prompt whose leading full pages
@@ -25,366 +15,17 @@ the TPU-native equivalent:
   Freed requests' pages go to an LRU *evictable* pool keyed by that hash, so
   capacity is never held hostage by dead requests, yet follow-up turns still
   hit. O(n_pages) lookup per prompt, independent of slot count (VERDICT r2
-  weak #5 / next #8 — replaces the O(slots x prompt_len) token scan).
-
-Layout note: pages keep the head-major ``[Hkv, page, D]`` inner layout of the
-slot-contiguous design, so each Pallas grid step still DMAs one head-contiguous
-block and the MXU matmul shape is unchanged — the ONLY difference between
-dense and paged decode is which physical block the index_map picks. page_size
-must satisfy the same Mosaic tiling rules as the dense chunk (multiple of 8
-for bf16, 32 for int8; the int8 scale block spans the full page axis, which is
-always legal).
+  weak #5 / next #8).
+- **Host tier** (:class:`HostTier`): a byte-budgeted host-RAM store of
+  spilled pages keyed by the same chain hash.
 """
 
 from __future__ import annotations
 
 import collections
-import functools
 from typing import Dict, List, Optional, Sequence, Tuple
 
-import jax
-import jax.numpy as jnp
 import numpy as np
-
-from aws_k8s_ansible_provisioner_tpu.config import ModelConfig
-from aws_k8s_ansible_provisioner_tpu.serving.kv_cache import quantize_rows
-
-# Drop sentinel for page-table entries that must never be written (padding
-# rows of a batched prefill, out-of-window rows). Must be a LARGE POSITIVE
-# id: jnp scatters treat negative indices as wrapped (in-bounds!) — a -1
-# would silently write the pool's last page — while indices >= the pool size
-# are dropped by mode='drop'.
-OOB_PAGE = np.int32(2**31 - 1)
-
-
-def scale_lanes(page_size: int) -> int:
-    """Minor dim of the int8 pool's scale leaves: ``page_size`` rounded up to
-    the TPU's 128-lane tile. The paged decode kernel moves one page's scales
-    per DMA, and Mosaic refuses a DMA slice whose minor dim is not
-    128-aligned (page 64 died there); lanes >= page_size are padding no
-    reader indexes. HBM cost on the chip is nil — XLA's tiled layout already
-    padded a 64-wide f32 minor dim to 128."""
-    return -(-page_size // 128) * 128
-
-
-def init_pool(cfg: ModelConfig, num_pages: int, page_size: int,
-              dtype=jnp.bfloat16, quant: bool = False) -> dict:
-    """Allocate the physical page pool. Leaves carry a leading [L] axis."""
-    shape = (cfg.num_layers, num_pages, cfg.num_kv_heads, page_size,
-             cfg.head_dim)
-    if quant:
-        sshape = shape[:3] + (scale_lanes(page_size),)
-        return {
-            "k": jnp.zeros(shape, jnp.int8),
-            "v": jnp.zeros(shape, jnp.int8),
-            "ks": jnp.zeros(sshape, jnp.float32),
-            "vs": jnp.zeros(sshape, jnp.float32),
-        }
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
-
-
-def pool_bytes(cfg: ModelConfig, num_pages: int, page_size: int,
-               dtype=jnp.bfloat16, quant: bool = False) -> int:
-    heads = 2 * cfg.num_layers * num_pages * cfg.num_kv_heads
-    if quant:
-        return heads * (page_size * cfg.head_dim
-                        + 4 * scale_lanes(page_size))
-    return heads * page_size * cfg.head_dim * jnp.dtype(dtype).itemsize
-
-
-def _write_kv(pool: dict, update, k_val: jnp.ndarray, v_val: jnp.ndarray) -> dict:
-    """Mirror of kv_cache._write_kv for the pool layout: one indexing
-    expression updates k/v (and, quantized, the scale leaves — whose target is
-    the row target minus the trailing head_dim axis)."""
-    if "ks" in pool:
-        k_val, ks = quantize_rows(k_val)
-        v_val, vs = quantize_rows(v_val)
-        return {"k": update(pool["k"], k_val), "v": update(pool["v"], v_val),
-                "ks": update(pool["ks"], ks), "vs": update(pool["vs"], vs)}
-    return {"k": update(pool["k"], k_val), "v": update(pool["v"], v_val)}
-
-
-# ---------------------------------------------------------------------------
-# XLA writers (fallback + prefill paths). All take PHYSICAL page ids computed
-# from the slot's block table on the host or in-program from a table array.
-# ---------------------------------------------------------------------------
-
-
-def write_prompt_paged(pool_l: dict, pages: jnp.ndarray, k: jnp.ndarray,
-                       v: jnp.ndarray, page_size: int) -> dict:
-    """Write one prefilled prompt's K/V across its pages (single layer slice).
-
-    pool_l: {'k','v': [P, Hkv, page, D]}; pages: [max_pages] int32 physical
-    page ids for the destination slot; k/v: [1, T, Hkv, D] where T is the
-    BUCKET width, usually > the true prompt length.
-
-    Token t lands at (pages[t // page_size], t % page_size): one scatter with
-    advanced indices on (page, row), the head axis broadcast between them —
-    the same mode='drop' contract as the dense batched writer (OOB_PAGE ids
-    drop). CONTRACT: padded rows past the true prompt DO write through the
-    table, so every entry of ``pages`` must name either a page owned by this
-    slot or the engine's scratch page — never another slot's page (the
-    engine keeps unallocated table entries at scratch page 0; padding
-    garbage then lands in the slot's own partial tail page — rows >= the
-    true length, which reads mask and sharing never indexes — or in
-    scratch).
-    """
-    T = k.shape[1]
-    tok = jnp.arange(T, dtype=jnp.int32)
-    pg = pages[tok // page_size]                       # [T]
-    off = tok % page_size
-    return _write_kv(
-        pool_l,
-        lambda arr, val: arr.at[pg, :, off].set(val, mode="drop"),
-        k[0], v[0])
-
-
-def write_prompts_paged(pool_l: dict, tables: jnp.ndarray, k: jnp.ndarray,
-                        v: jnp.ndarray, page_size: int) -> dict:
-    """Batched prompt write: N prompts into their pages in one scatter.
-
-    pool_l: {'k','v': [P, Hkv, page, D]}; tables: [N, max_pages] int32 (row n
-    = destination pages of prompt n; PADDING rows of a power-of-two prefill
-    batch carry OOB_PAGE everywhere and drop); k/v: [N, T, Hkv, D]. Same
-    contract as :func:`write_prompt_paged`: rows padded past each prompt's
-    true length write through the table, so entries past a prompt's own
-    pages must be scratch/own pages, never another slot's.
-    """
-    N, T = k.shape[:2]
-    tok = jnp.arange(T, dtype=jnp.int32)
-    pg = tables[:, tok // page_size]                   # [N, T]
-    off = jnp.broadcast_to(tok % page_size, (N, T))
-    return _write_kv(
-        pool_l,
-        lambda arr, val: arr.at[pg, :, off].set(val, mode="drop"),
-        k, v)
-
-
-def _write_span_by_page(pool: dict, layer, tables: jnp.ndarray, start,
-                        k: jnp.ndarray, v: jnp.ndarray,
-                        page_size: int, n_valid=None) -> dict:
-    """Rows [start, start+T) of N sequences into the FULL pool at ``layer``,
-    one WHOLE PAGE per scatter window (read-modify-write).
-
-    tables: [N, max_pages]; k/v: [N, T, Hkv, D]; start: scalar (python int
-    or traced). Same index/drop contract as the row-granular per-layer
-    writers above (logical pages past the table and OOB_PAGE entries drop;
-    rows of a touched page outside the span keep their content).
-    ``n_valid`` (traced scalar; the mixed program's chunk, which arrives
-    padded to T): only rows [start, start+n_valid) are written, the
-    padding behind them keeps the pool's content like any row outside the
-    span. None writes all T.
-
-    Why pages and not rows: a row-granular scatter on the head-major pool
-    (``arr.at[layer, pg, :, off]``, window [Hkv, D] split by the page axis)
-    made the chip's compiler RELAYOUT THE WHOLE POOL to [.., page, Hkv, D]
-    and back around every prefill — two full-pool copies and a pool-sized
-    temp in each prefill program (7.0 GiB of temp beside a 7.0 GiB pool at
-    the default config, deviceless compile for v5e, PR 21). A [Hkv, page, D]
-    window is contiguous in the pool's own layout, so this form compiles
-    with no pool copy and ~0 temp."""
-    ps = page_size
-    N, T = k.shape[:2]
-    aligned = isinstance(start, int) and start % ps == 0
-    # logical pages touched. Never ONE: XLA rewrites a single-window scatter
-    # as a dynamic-update-slice whose layout it takes from the transposed
-    # update, and relayouts the whole pool again (buckets <= one page did);
-    # a second window — its rows all outside the span, rewritten unchanged —
-    # keeps it a scatter in the pool's own layout.
-    n = max(2, -(-T // ps) + (0 if aligned else 1))
-    start = jnp.asarray(start, jnp.int32)
-    p0 = start // ps
-    delta = p0 * ps - start                      # in (-ps, 0]
-    lp = p0 + jnp.arange(n, dtype=jnp.int32)     # [n] logical page ids
-    pg = jnp.where((lp < tables.shape[1])[None],
-                   tables[:, jnp.clip(lp, 0, tables.shape[1] - 1)],
-                   OOB_PAGE)                     # [N, n] physical ids
-    # span token held by row r of touched page j; live = inside the span
-    tok = (jnp.arange(n, dtype=jnp.int32)[:, None] * ps
-           + jnp.arange(ps, dtype=jnp.int32)[None] + delta)     # [n, ps]
-    live = (tok >= 0) & (tok < (T if n_valid is None else n_valid))
-
-    def update(arr, val):
-        # val [N, T, Hkv, (D)] -> per-page blocks [N, n, Hkv, ps, (D)]
-        pad = [(0, 0)] * val.ndim
-        pad[1] = (ps, n * ps - T)
-        win = jax.lax.dynamic_slice_in_dim(jnp.pad(val, pad), ps + delta,
-                                           n * ps, axis=1)
-        new = jnp.moveaxis(win.reshape((N, n, ps) + val.shape[2:]), 2, 3)
-        mask = live[None, :, None, :]
-        if val.ndim == 4:
-            mask = mask[..., None]
-        else:                                    # scale leaf: lane padding
-            lanes = arr.shape[3] - ps
-            new = jnp.pad(new, [(0, 0)] * 3 + [(0, lanes)])
-            mask = jnp.pad(mask, [(0, 0)] * 3 + [(0, lanes)])
-        old = arr.at[layer, pg].get(mode="clip")
-        return arr.at[layer, pg].set(
-            jnp.where(mask, new.astype(arr.dtype), old), mode="drop")
-
-    return _write_kv(pool, update, k, v)
-
-
-def write_prompts_paged_layer(pool: dict, layer, tables: jnp.ndarray,
-                              k: jnp.ndarray, v: jnp.ndarray,
-                              page_size: int) -> dict:
-    """FULL-pool ([L, P, ...] leaves) variant of :func:`write_prompts_paged`
-    for the scan-CARRY prefill path (round 5): the pool stays in the layer
-    scan's carry — XLA's loop-carry aliasing keeps it in place — instead of
-    streaming xs→ys, whose re-stack held a second full-size pool buffer in
-    the compiled program (the batch-128 paged HBM OOM of the round-5 chip
-    run, older code, whose record is no longer in the tree). Same
-    index/drop contract as the per-layer form; page-granular windows, see
-    :func:`_write_span_by_page`."""
-    return _write_span_by_page(pool, layer, tables, 0, k, v, page_size)
-
-
-def write_chunk_paged_layer(pool: dict, layer, pages: jnp.ndarray,
-                            start, k: jnp.ndarray, v: jnp.ndarray,
-                            page_size: int, n_valid=None) -> dict:
-    """FULL-pool variant of :func:`write_chunk_paged` (carry prefill path —
-    see write_prompts_paged_layer). k/v: [1, C, Hkv, D]; ``start`` may be a
-    python int (page-aligned starts then touch one page fewer); ``n_valid``
-    as in :func:`_write_span_by_page` (the chunk of a mixed step)."""
-    return _write_span_by_page(pool, layer, pages[None], start, k, v,
-                               page_size, n_valid)
-
-
-def write_chunk_paged(pool_l: dict, pages: jnp.ndarray, start: jnp.ndarray,
-                      k: jnp.ndarray, v: jnp.ndarray, page_size: int) -> dict:
-    """Write one prefill CHUNK's rows [start, start+C) across pages.
-
-    pool_l: {'k','v': [P, Hkv, page, D]}; pages: [max_pages] int32 for the
-    slot; start: scalar row offset; k/v: [1, C, Hkv, D]. Rows past max_pages *
-    page_size drop (mode='drop' via clamped gather producing OOB_PAGE).
-    """
-    C = k.shape[1]
-    rows = start + jnp.arange(C, dtype=jnp.int32)      # [C]
-    idx = rows // page_size
-    valid = idx < pages.shape[0]
-    pg = jnp.where(valid, pages[jnp.clip(idx, 0, pages.shape[0] - 1)],
-                   OOB_PAGE)
-    off = rows % page_size
-    return _write_kv(
-        pool_l,
-        lambda arr, val: arr.at[pg, :, off].set(val, mode="drop"),
-        k[0], v[0])
-
-
-def write_token_layer_paged(pool: dict, layer: jnp.ndarray,
-                            lengths: jnp.ndarray, table: jnp.ndarray,
-                            k: jnp.ndarray, v: jnp.ndarray,
-                            page_size: int) -> dict:
-    """Scatter one new token per slot into the FULL pool at a given layer
-    (XLA fallback for the Pallas paged row-write kernel).
-
-    pool: {'k','v': [L, P, Hkv, page, D]}; layer: scalar; lengths: [B] row
-    index per slot; table: [B, max_pages] int32; k/v: [B, 1, Hkv, D]. Rows
-    outside [0, max_pages*page_size) drop — the surplus-write invariant.
-    """
-    B = k.shape[0]
-    idx = lengths // page_size
-    valid = (lengths >= 0) & (idx < table.shape[1])
-    pg = jnp.where(valid,
-                   table[jnp.arange(B), jnp.clip(idx, 0, table.shape[1] - 1)],
-                   OOB_PAGE)
-    off = jnp.where(valid, lengths % page_size, 0)
-    return _write_kv(
-        pool,
-        lambda arr, val: arr.at[layer, pg, :, off].set(val, mode="drop"),
-        k[:, 0], v[:, 0])
-
-
-def gather_slot(pool_l: dict, pages: jnp.ndarray, page_size: int,
-                name: str) -> jnp.ndarray:
-    """Materialize one slot's logical [Hkv, S_v, D] view from its pages
-    (S_v = len(pages) * page_size). Prefill-only helper (chunk attention
-    reads the cached prefix); the decode kernels never gather.
-    """
-    arr = pool_l[name][pages]                    # [n, Hkv, page, (D)]
-    if arr.ndim == 3:
-        arr = arr[..., :page_size]               # drop scale lane padding
-    arr = jnp.moveaxis(arr, 1, 0)                # [Hkv, n, page, (D)]
-    return arr.reshape((arr.shape[0], -1) + arr.shape[3:])
-
-
-def gather_layer_dense(pool: dict, layer, table: jnp.ndarray) -> dict:
-    """One layer's logical dense view from the pool (XLA-fallback decode):
-    {name: [B, Hkv, S_v, (D)]}. Test/CPU path only — a full gather per step
-    is exactly what the Pallas paged kernels avoid."""
-    out = {}
-    ps = pool["k"].shape[3]
-    for name, arr in pool.items():
-        al = jax.lax.dynamic_index_in_dim(arr, layer, 0, keepdims=False)
-        g = al[table]                            # [B, n, Hkv, page, (D)]
-        if g.ndim == 4:
-            g = g[..., :ps]                      # drop scale lane padding
-        g = jnp.moveaxis(g, 2, 1)                # [B, Hkv, n, page, (D)]
-        out[name] = g.reshape(g.shape[:2] + (-1,) + g.shape[4:])
-    return out
-
-
-def gather_dense(pool: dict, table: jnp.ndarray, page_size: int) -> dict:
-    """Whole logical [L, B, Hkv, S_v, (D)] cache from the pool — a stack of
-    :func:`gather_layer_dense` slices, so the pool layout has exactly one
-    decoding (tests compare paged results against dense references through
-    this)."""
-    L = pool["k"].shape[0]
-    layers = [gather_layer_dense(pool, jnp.int32(l), table) for l in range(L)]
-    return {name: jnp.stack([g[name] for g in layers]) for name in pool}
-
-
-# ---------------------------------------------------------------------------
-# Host tier (tier-2 KV): spill/restore of whole pages across PCIe
-# ---------------------------------------------------------------------------
-
-
-def gather_pages(pool: dict, pages: Sequence[int]) -> dict:
-    """Enqueue a device-side gather of whole physical pages for spilling.
-
-    pool: FULL-pool leaves ``[L, P, ...]``; pages: global physical ids.
-    Returns ``{name: [L, k, Hkv, page, (D)]}`` — eager jnp ops only, so this
-    just enqueues device work without blocking the dispatch thread (R8-safe);
-    the actual PCIe copy is started with ``copy_to_host_async`` and settled
-    lazily by :meth:`HostTier.flush_to_host` at the next sanctioned block
-    point. The gather is enqueued BEFORE any program that overwrites the
-    reclaimed pages, so XLA's data-dependency ordering guarantees it reads
-    the pre-reclaim content.
-    """
-    idx = jnp.asarray(list(pages), jnp.int32)
-    return {name: jnp.take(arr, idx, axis=1) for name, arr in pool.items()}
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _restore_scatter(pool: dict, pages: jnp.ndarray, data: dict) -> dict:
-    return {name: arr.at[:, pages].set(data[name], mode="drop")
-            for name, arr in pool.items()}
-
-
-def restore_pages(pool: dict, pages: Sequence[int], data: dict) -> dict:
-    """Scatter host-tier page payloads back into freshly allocated pages.
-
-    pool: FULL-pool leaves (donated — the scatter is in place, no second
-    pool-sized buffer); pages: global physical ids; data: ``{name:
-    [L, k, Hkv, page, (D)]}`` stacked page payloads in the same per-page
-    layout ``write_prompts_paged_layer`` produces. The page axis is padded to
-    the next power of two with ``OOB_PAGE`` ids (dropped by the scatter) so
-    restore bursts of any size hit a log-bounded set of compiled programs.
-    """
-    k = len(pages)
-    width = 1
-    while width < k:
-        width *= 2
-    pg = np.full(width, OOB_PAGE, np.int32)
-    pg[:k] = list(pages)
-    padded = {}
-    for name, arr in data.items():
-        if arr.shape[1] != width:
-            pad = [(0, 0)] * arr.ndim
-            pad[1] = (0, width - arr.shape[1])
-            arr = jnp.pad(jnp.asarray(arr), pad)
-        padded[name] = jnp.asarray(arr)
-    return _restore_scatter(pool, jnp.asarray(pg), padded)
 
 
 class HostTier:

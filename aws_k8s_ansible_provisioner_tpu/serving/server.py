@@ -418,7 +418,7 @@ class Handler(BaseHTTPRequestHandler):
                 "pipeline": metrics.pipeline.snapshot(),
                 "weights_dtype": eng.serving.weights_dtype,
                 "kv_dtype": eng.serving.kv_dtype,
-                "paged": bool(getattr(eng, "paged", False)),
+                "paged": eng.paged,
                 # AOT manifest adoption summary (serving/aot.py): operators
                 # confirm the replica serves a pre-verified program set (and
                 # its HBM ledger headroom) straight off the probe; null means
@@ -1800,10 +1800,6 @@ def build_parser() -> argparse.ArgumentParser:
                         "ICI mesh; needs tp devices)")
     p.add_argument("--dp", type=int, default=1,
                    help="data-parallel degree (shards decode slots)")
-    p.add_argument("--sp", type=int, default=1,
-                   help="sequence-parallel degree (shards the KV cache's "
-                        "sequence axis — the long-context axis; decode "
-                        "merges per-shard flash partials over ICI)")
     p.add_argument("--ep", type=int, default=1,
                    help="expert-parallel degree (MoE models: shards experts "
                         "over the mesh; GSPMD emits the dispatch collectives)")
@@ -1945,7 +1941,7 @@ def serving_config_from_args(args):
         capacity_headroom_s=args.capacity_headroom_s,
         capacity_window_s=args.capacity_window_s,
         capacity_trend_window_s=args.capacity_trend_window_s,
-        mesh=MeshConfig(dp=args.dp, tp=args.tp, sp=args.sp, ep=args.ep))
+        mesh=MeshConfig(dp=args.dp, tp=args.tp, ep=args.ep))
 
 
 def main(argv=None):

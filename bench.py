@@ -177,10 +177,6 @@ def measure() -> None:
         # quantized tree). TPU_BENCH_WEIGHTS=bf16 is the A/B opt-out.
         weights_dtype=env("TPU_BENCH_WEIGHTS",
                           ServingConfig.weights_dtype),
-        # Default matches ServingConfig.paged=True so the headline number
-        # measures the path production actually executes (ADVICE r3);
-        # TPU_BENCH_PAGED=0 is an explicit A/B, never a silent retry.
-        paged=bool(int(env("TPU_BENCH_PAGED", "1"))),
         # Paged DMA granularity: the double-buffered paged decode kernel
         # streams one page per buffer fill, so page_size is its chunk size —
         # larger pages amortize DMA-issue overhead at the cost of coarser
@@ -260,10 +256,10 @@ def measure() -> None:
         # the block count; double-buffering overlaps — but does not remove —
         # each fill. ~14k at the r5 config (bb=1); /bb thereafter.
         bb = max(1, int(getattr(engine, "decode_bblock", 1)))
-        stream_chunk = serving.page_size if serving.paged else 256
         dma_steps = (cfg.num_layers
                      * -(-n_slots // bb)
-                     * max(1, -(-int(max(1.0, mean_ctx)) // stream_chunk)))
+                     * max(1, -(-int(max(1.0, mean_ctx))
+                                // serving.page_size)))
         model_tag = "tiny-qwen3 DRY" if dry else "qwen3-0.6b"
         out = {
             "metric": f"{model_tag} decode tokens/sec/chip "
@@ -277,7 +273,6 @@ def measure() -> None:
             "attention_impl": impl,
             "kv_dtype": serving.kv_dtype,
             "weights_dtype": serving.weights_dtype,
-            "paged": serving.paged,
             "decode_pipeline": serving.decode_pipeline,
             "bblock": bb,
             "dma_steps_per_substep": int(dma_steps),
@@ -945,7 +940,7 @@ def prefix_tier() -> None:
         serving = ServingConfig(
             model="tiny-qwen3", max_decode_slots=4,
             max_cache_len=plen + 3 * ps, prefill_buckets=(chunk,),
-            prefill_chunk=chunk, page_size=ps, paged=True,
+            prefill_chunk=chunk, page_size=ps,
             kv_pool_pages=pool, kv_host_tier_bytes=tier_bytes,
             dtype="float32")
         params = init_params(cfg, jax.random.PRNGKey(0), jnp.float32)

@@ -513,7 +513,7 @@ def kernel_parity(cfg, slots: int, window: int, page: int, bblocks,
                   interpret: bool) -> None:
     """decode / ragged / write kernels at the served widths against the
     repo's jax.numpy references (ops/attention.decode_attend over
-    paged_kv.gather_layer_dense; paged_kv.write_token_layer_paged), on
+    kv_pool.gather_layer_dense; kv_pool.write_token_layer_paged), on
     seeded inputs, bf16 and int8 pools. Depth is cut to 2 layers — a layer
     is an index into the pool here — everything else is the server's."""
     import jax
@@ -523,8 +523,7 @@ def kernel_parity(cfg, slots: int, window: int, page: int, bblocks,
     from aws_k8s_ansible_provisioner_tpu.ops import pallas_attention as pa
     from aws_k8s_ansible_provisioner_tpu.ops.attention import (
         decode_attend, make_mixed_attend_carry_paged)
-    from aws_k8s_ansible_provisioner_tpu.serving import kv_cache as kvc
-    from aws_k8s_ansible_provisioner_tpu.serving import paged_kv as pkv
+    from aws_k8s_ansible_provisioner_tpu.ops import kv_pool as kvp
 
     Hq, Hkv, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     B, MP, L = slots, window // page, 2
@@ -557,9 +556,9 @@ def kernel_parity(cfg, slots: int, window: int, page: int, bblocks,
         vf = jax.random.normal(keys[2], shape, jnp.bfloat16)
         if quant:
             # the engine's int8 pool layout: scale leaves lane-padded
-            pad = [(0, 0)] * 3 + [(0, pkv.scale_lanes(page) - page)]
-            k8, ks = kvc.quantize_rows(kf)
-            v8, vs = kvc.quantize_rows(vf)
+            pad = [(0, 0)] * 3 + [(0, kvp.scale_lanes(page) - page)]
+            k8, ks = kvp.quantize_rows(kf)
+            v8, vs = kvp.quantize_rows(vf)
             pool = {"k": k8, "v": v8,
                     "ks": jnp.pad(ks, pad), "vs": jnp.pad(vs, pad)}
             del k8, v8, ks, vs
@@ -570,10 +569,10 @@ def kernel_parity(cfg, slots: int, window: int, page: int, bblocks,
         del kf, vf
 
         def dense_view(tab):
-            d = pkv.gather_layer_dense(pool, layer, tab)
+            d = kvp.gather_layer_dense(pool, layer, tab)
             if quant:
-                return (kvc.dequantize(d["k"], d["ks"]),
-                        kvc.dequantize(d["v"], d["vs"]))
+                return (kvp.dequantize(d["k"], d["ks"]),
+                        kvp.dequantize(d["v"], d["vs"]))
             return d["k"], d["v"]
 
         with jax.default_matmul_precision("highest"):
@@ -617,7 +616,7 @@ def kernel_parity(cfg, slots: int, window: int, page: int, bblocks,
         # write kernels: one new row per slot at each slot's length (the
         # full-window slot's row is out of range and must DROP)
         new = jax.random.normal(keys[4], (B, Hkv, D), jnp.bfloat16)
-        want = pkv.write_token_layer_paged(
+        want = kvp.write_token_layer_paged(
             pool, layer, lengths, table, new[:, None], new[:, None], page)
         if quant:
             gk, gks = pa.cache_write_row_quant_paged(
@@ -639,7 +638,7 @@ def kernel_parity(cfg, slots: int, window: int, page: int, bblocks,
             [jnp.where(jnp.arange(B) == 3, -1, lengths),
              jnp.where(jnp.arange(C) < 40, crow, -1)])
         pnew = jax.random.normal(keys[5], (B + C, Hkv, D), jnp.bfloat16)
-        want = pkv.write_token_layer_paged(
+        want = kvp.write_token_layer_paged(
             pool, layer, prow, rtab, pnew[:, None], pnew[:, None], page)
         attend = make_mixed_attend_carry_paged(
             prow[:B], jnp.int32(300), jnp.int32(40), limits, rtab,
@@ -652,7 +651,7 @@ def kernel_parity(cfg, slots: int, window: int, page: int, bblocks,
                       want[name])
                 continue
             # int8: the compiled quantizer may round one step from the
-            # eager one (kv_cache.quantize_rows); bf16 rows are copies
+            # eager one (kv_pool.quantize_rows); bf16 rows are copies
             diff = np.abs(np.asarray(got[name], np.float32)
                           - np.asarray(want[name], np.float32))
             off = int((diff > 0).sum())
@@ -663,12 +662,12 @@ def kernel_parity(cfg, slots: int, window: int, page: int, bblocks,
         say(f"parity: paged writes {tag}: equal to the jnp scatter, one row "
             f"a slot (kernel) and mixed_step's rows (kernel + chunk span)")
         if not interpret:
-            chunk_write_time(pkv, pool, table, layer, page, window, tag)
+            chunk_write_time(kvp, pool, table, layer, page, window, tag)
         del got
         del pool, want, gk
 
 
-def chunk_write_time(pkv, pool, table, layer, page, chunk, tag) -> None:
+def chunk_write_time(kvp, pool, table, layer, page, chunk, tag) -> None:
     """Device time of ONE layer's chunk write (K and V) at the served mixed
     step's shape: a ``chunk``-row chunk of slot 3 from row 0 or 1, 640 rows
     of it a prompt. 64 writes in one program, so the host's dispatch
@@ -683,7 +682,7 @@ def chunk_write_time(pkv, pool, table, layer, page, chunk, tag) -> None:
 
     def body(i, pool):
         # the start moves, so nothing is hoisted out of the loop
-        return pkv.write_chunk_paged_layer(pool, layer, table[3], i % 2, new,
+        return kvp.write_chunk_paged_layer(pool, layer, table[3], i % 2, new,
                                            new, page, n_valid=640)
 
     write = jax.jit(lambda pool: jax.lax.fori_loop(0, reps, body, pool),
@@ -1006,8 +1005,7 @@ def main() -> int:
     eng = srv.engine
     impl = resolve_impl(eng.serving.attention_impl)
     say(f"server: flags {flags}; scheduler {type(eng.sched).__name__}; "
-        f"attention impl {impl}; kv layout "
-        f"{'paged' if eng.paged else 'dense'} page {eng.serving.page_size} "
+        f"attention impl {impl}; kv pool page {eng.serving.page_size} "
         f"dtype {'int8' if eng.kv_quant else eng.serving.dtype}; weights "
         f"{eng.serving.weights_dtype}; slots {eng.num_slots} window "
         f"{eng.max_len}; decode bblock {eng.decode_bblock} "
@@ -1018,7 +1016,6 @@ def main() -> int:
         f"compile cache hits {cache['hits']} misses {cache['misses']}")
     check(type(eng.sched).__name__ == "NativeScheduler",
           "the native scheduler was built from source but not loaded")
-    check(eng.paged, "the engine is not on the paged pool")
     if not opts.rehearse:
         check(impl == "pallas", f"attention impl resolved to {impl!r}")
         check(eng.serving.decode_bblock == 0 or cfg_file is not None,

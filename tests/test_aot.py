@@ -54,7 +54,7 @@ def _mk_engine(serving, mesh=None):
     {},
     {"max_cache_len": 500},               # 256-rounding path
     {"kv_pool_pages": 16},                # explicit pool size
-    {"paged": False},                     # dense cache
+    {"max_cache_len": 100, "page_size": 16},   # the page does not divide
     {"kv_dtype": "int8", "page_size": 32},
     {"prefill_chunk": 16},
 ])
@@ -68,14 +68,10 @@ def test_plan_matches_real_engine_sizing(srv_kw):
     assert plan.num_slots == eng.num_slots
     assert plan.max_len == eng.max_len
     assert plan.buckets == eng.buckets
-    assert plan.paged == eng.paged
     assert plan.kv_quant == eng.kv_quant
-    if eng.paged:
-        assert plan.pages_per_slot == eng.pages_per_slot
-        assert plan.total_pages == eng.cache["k"].shape[1]
-        assert plan.chunk == eng._chunk_size
-    else:
-        assert plan.total_pages == 0
+    assert plan.pages_per_slot == eng.pages_per_slot
+    assert plan.total_pages == eng.cache["k"].shape[1]
+    assert plan.chunk == eng._chunk_size
 
 
 def test_plan_matches_mesh_engine_pool_split(cpu_devices):

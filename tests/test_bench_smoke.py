@@ -29,10 +29,10 @@ def test_bench_default_decode_program_constructs(kv_dtype):
     serving = ServingConfig(
         model="tiny-qwen3", max_decode_slots=4, max_cache_len=128,
         page_size=32, dtype="float32", prefill_buckets=(16,),
-        paged=True, kv_dtype=kv_dtype, weights_dtype="int8",
+        kv_dtype=kv_dtype, weights_dtype="int8",
         decode_bblock=4, decode_horizon=2, attention_impl="pallas")
     engine = Engine(cfg, params, serving)
-    assert engine.paged and engine.decode_bblock == 4
+    assert engine.decode_bblock == 4
     reqs = [engine.submit(Request(prompt_ids=[7 + i, 9, 11], max_tokens=3,
                                   ignore_eos=True)) for i in range(2)]
     for _ in range(24):
@@ -52,7 +52,7 @@ def test_bench_spec_verify_program_constructs():
     serving = ServingConfig(
         model="tiny-qwen3", max_decode_slots=4, max_cache_len=128,
         page_size=32, dtype="float32", prefill_buckets=(32,),
-        paged=True, weights_dtype="int8", decode_bblock=4,
+        weights_dtype="int8", decode_bblock=4,
         decode_horizon=4, attention_impl="pallas",
         spec_decode=True, spec_k=2, spec_ngram=2)
     engine = Engine(cfg, params, serving)

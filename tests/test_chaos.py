@@ -128,9 +128,8 @@ def _assert_released(eng, n_terminal=None):
     scheduler's totals count again by design)."""
     st = _settled(eng)
     assert st.active_slots == 0, st
-    if eng.paged:
-        for a in eng.allocators:
-            assert a.stats()["pages_live"] == 0, a.stats()
+    for a in eng.allocators:
+        assert a.stats()["pages_live"] == 0, a.stats()
     if n_terminal is not None:
         assert st.finished_total + st.cancelled_total == n_terminal, st
     return st

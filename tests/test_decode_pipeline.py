@@ -120,9 +120,8 @@ def _settled(eng, timeout_s=30.0):
 def _assert_released(eng, n_terminal=None):
     st = _settled(eng)
     assert st.active_slots == 0, st
-    if eng.paged:
-        for a in eng.allocators:
-            assert a.stats()["pages_live"] == 0, a.stats()
+    for a in eng.allocators:
+        assert a.stats()["pages_live"] == 0, a.stats()
     if n_terminal is not None:
         assert st.finished_total + st.cancelled_total == n_terminal, st
     # the pipeline itself must be fully retired too (a run_forever thread

@@ -158,14 +158,14 @@ def test_golden_roofline_snapshot_hand_computed():
     assert late["dma_wait_fraction"] == 0.0
 
 
-def test_prefix_copy_is_pure_dma_and_disabled_noop():
+def test_kv_restore_is_pure_dma_and_disabled_noop():
     clk = FakeClock()
     m = _mon(clk)
-    # prefix_copy: read+write of 32 rows = 2*32*1e3 bytes, zero flops
-    m.note("prefix_copy", 0.001, tokens=32)
-    s = m.program_stats()["prefix_copy"]
+    # kv_restore: one HBM write of 32 rows = 32*1e3 bytes, zero flops
+    m.note("kv_restore", 0.001, tokens=32)
+    s = m.program_stats()["kv_restore"]
     assert s["mfu"] == 0.0
-    assert s["membw_util"] == pytest.approx(64e3 / (0.001 * 1e9))
+    assert s["membw_util"] == pytest.approx(32e3 / (0.001 * 1e9))
     # disabled monitor records nothing, snapshot still renders
     off = _mon(clk, enabled=False)
     off.note("decode", 1.0, tokens=8)

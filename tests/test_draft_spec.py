@@ -182,3 +182,45 @@ def test_draft_under_tp_mesh(cpu_devices):
     got = _drive(eng, _submit(eng, PROMPTS))
     assert got == ref
     assert eng.metrics.spec_drafted_tokens.total() > 0
+
+
+@pytest.mark.parametrize("n_prompts", [1, 3])
+def test_draft_pool_holds_the_rows_the_target_wrote(n_prompts):
+    """The draft keeps a pool of its own under a static identity block table
+    (slot i owns pages [i * MP, (i + 1) * MP)): with the draft network equal
+    to the target, a shared prompt's K/V rows read back the same from both
+    pools — the draft's through its identity table, the target's through the
+    engine's allocated one. One prompt takes prefill_step, three the batched
+    program (whose padding row must drop)."""
+    from aws_k8s_ansible_provisioner_tpu.ops import kv_pool as kvp
+
+    params = _params(0)
+    eng = Engine(CFG, params,
+                 _serving(spec_decode=True, spec_method="draft", spec_k=3,
+                          page_size=16),
+                 draft=(CFG, params))
+    mp = eng.pages_per_slot
+    np.testing.assert_array_equal(
+        eng.draft.table,
+        np.arange(eng.num_slots * mp).reshape(eng.num_slots, mp))
+    prompts = (PROMPTS + [[4, 4, 9, 1, 17]])[:n_prompts]
+    reqs = _submit(eng, prompts)
+    eng.step()                                     # the admission's prefill
+    slots = {id(r): s for s, r in enumerate(eng.slot_req) if r is not None}
+    assert len(slots) == n_prompts
+    ps = eng.serving.page_size
+    mine = kvp.gather_dense(eng.draft.cache, jnp.asarray(eng.draft.table), ps)
+    theirs = kvp.gather_dense(eng.cache, jnp.asarray(eng.table), ps)
+    for r in reqs:
+        s, n = slots[id(r)], len(r.prompt_ids)
+        assert eng.draft.lens[s] == n
+        for name in ("k", "v"):
+            np.testing.assert_allclose(
+                np.asarray(mine[name][:, s, :, :n]),
+                np.asarray(theirs[name][:, s, :, :n]), rtol=0, atol=1e-6,
+                err_msg=name)
+    # a slot nobody was admitted to: its pages are untouched (a padding row
+    # of the batched prefill carries an all-OOB table and drops)
+    idle = next(s for s in range(eng.num_slots) if s not in slots.values())
+    assert not np.asarray(mine["k"][:, idle]).any()
+    _drive(eng, reqs)

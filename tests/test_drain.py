@@ -126,8 +126,7 @@ def test_drain_timeout_cancels_stragglers_exactly_once(setup):
     # exactly-once: every slot free again, a second reap pass is a no-op
     eng._reap_expired()
     assert eng.metrics.deadline_expired.total() == d0 + 3
-    if eng.paged:
-        assert all(not p for p in eng._slot_pages)
+    assert all(not p for p in eng._slot_pages)
 
 
 def test_drain_deadline_tightens_not_loosens(setup):

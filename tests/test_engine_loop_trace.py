@@ -131,7 +131,7 @@ KINDS = [
     # padding rows of a batched prefill carry true_len 1 (as before this PR)
     ("batched-prefill", dict(), _three_prompts, "prefill_batch_step",
      "prefill_batch", lambda r: r["prompt_tokens"] + r["rows"] - 3),
-    ("chunk", dict(paged=False, prefill_chunk=16), _long_prompt,
+    ("chunk", dict(prefill_chunk=16), _long_prompt,
      "prefill_chunk_step", "prefill_chunk", lambda r: r["chunk_n"]),
     ("mixed", dict(decode_pipeline=1, ragged_attention=1),
      _admit_under_decode, "mixed_step", "mixed_step",

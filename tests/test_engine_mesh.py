@@ -80,32 +80,29 @@ def test_mesh_engine_pallas_interpret_parity(setup):
     assert got == expected
 
 
-def test_mesh_cache_is_actually_sharded(setup):
-    """The KV cache must be allocated sharded: each device holds 1/(dp*tp)
+@pytest.mark.parametrize("kv_dtype", ["auto", "int8"])
+def test_mesh_pool_is_actually_sharded(setup, kv_dtype):
+    """The KV pool must be allocated sharded: each device holds 1/(dp*tp)
     of it — ADVICE r1: allocating unsharded then resharding would OOM one
-    chip at init. Dense layout: slots over dp, kv heads over tp. Paged
-    layout: pool pages over dp, kv heads over tp."""
+    chip at init. Pool pages over dp, kv heads over tp; an int8 pool's
+    scale leaves shard with their rows."""
     cfg, params, serving = setup
-    mesh = _mesh(2, 2)
-    dense = dataclasses.replace(serving, paged=False)
-    engine = Engine(cfg, params, dense, mesh=mesh)
-    k = engine.cache["k"]  # [L, slots, Hkv, S, D]
-    sharding = k.sharding
-    assert isinstance(sharding, jax.sharding.NamedSharding)
-    assert sharding.spec == jax.sharding.PartitionSpec(
-        None, "dp", "tp", "sp", None)
-    shard_shape = k.addressable_shards[0].data.shape
-    assert shard_shape[1] == serving.max_decode_slots // 2   # slots / dp
-    assert shard_shape[2] == cfg.num_kv_heads // 2           # heads / tp
-
-    paged = Engine(cfg, params, serving, mesh=mesh)
-    assert paged.paged
-    pk = paged.cache["k"]  # [L, pages, Hkv, page, D]
-    assert pk.sharding.spec == jax.sharding.PartitionSpec(
-        None, "dp", "tp", None, None)
-    pshard = pk.addressable_shards[0].data.shape
-    assert pshard[1] == paged._group_pages                   # pages / dp
-    assert pshard[2] == cfg.num_kv_heads // 2                # heads / tp
+    serving = dataclasses.replace(
+        serving, kv_dtype=kv_dtype,
+        page_size=32 if kv_dtype == "int8" else serving.page_size)
+    engine = Engine(cfg, params, serving, mesh=_mesh(2, 2))
+    P = jax.sharding.PartitionSpec
+    want = {"k": P(None, "dp", "tp", None, None),
+            "v": P(None, "dp", "tp", None, None)}
+    if kv_dtype == "int8":
+        want.update(ks=P(None, "dp", "tp", None), vs=P(None, "dp", "tp", None))
+    assert set(engine.cache) == set(want)
+    for name, leaf in engine.cache.items():   # [L, pages, Hkv, page, (D)]
+        assert isinstance(leaf.sharding, jax.sharding.NamedSharding)
+        assert leaf.sharding.spec == want[name], name
+        shard = leaf.addressable_shards[0].data.shape
+        assert shard[1] == engine._group_pages                # pages / dp
+        assert shard[2] == cfg.num_kv_heads // 2              # heads / tp
 
 
 def test_mesh_dp_divisibility_error(setup):
@@ -156,63 +153,28 @@ def test_mesh_engine_continuous_batching_queueing(setup):
     assert got == expected
 
 
-# ---------------------------------------------------------------------------
-# Sequence-parallel (sp) long-context serving: cache S-axis sharded
-# ---------------------------------------------------------------------------
-
-
-def _mesh3(dp, tp, sp):
-    return make_mesh(MeshConfig(dp=dp, tp=tp, sp=sp),
-                     devices=jax.devices("cpu"))
-
-
-@pytest.mark.parametrize("dp,tp,sp", [(1, 1, 2), (2, 1, 2), (1, 2, 2),
-                                      (1, 1, 4)])
-def test_mesh_engine_sp_token_parity(setup, dp, tp, sp):
-    """Sequence-parallel decode — cache sequence axis sharded over sp, flash
-    partials merged with a log-sum-exp psum — must be token-identical to the
-    single-device engine (the long-context serving axis; SURVEY.md §5
-    'Long-context / sequence parallelism': absent in the reference)."""
+@pytest.mark.parametrize("dp,tp", [(2, 1), (1, 2)])
+def test_mesh_long_generation_crosses_pages(setup, dp, tp):
+    """Generate far past the first page's boundary, so decode rows land on
+    later pages of each slot's run (under dp: of its own group's partition,
+    through the table's global-to-local rebase) while attention spans all
+    of them."""
     cfg, params, serving = setup
-    serving_p = dataclasses.replace(serving, attention_impl="pallas")
-    rng = np.random.default_rng(5)
-    prompts = [rng.integers(2, cfg.vocab_size, n).tolist() for n in (3, 9, 14)]
-
-    single = Engine(cfg, params, serving)
-    expected = _run_all(single, prompts)
-
-    meshed = Engine(cfg, params, serving_p, mesh=_mesh3(dp, tp, sp))
-    got = _run_all(meshed, prompts)
-    assert got == expected, f"dp={dp} tp={tp} sp={sp} diverged"
-
-
-def test_mesh_engine_sp_long_generation_crosses_shards(setup):
-    """Generate far past the first sequence shard's boundary so decode rows
-    land on shard 1 while attention spans both shards."""
-    cfg, params, serving = setup
-    serving_p = dataclasses.replace(serving, attention_impl="pallas")
+    serving_p = dataclasses.replace(serving, attention_impl="pallas",
+                                    page_size=16)
     rng = np.random.default_rng(6)
-    prompts = [rng.integers(2, cfg.vocab_size, 4).tolist()]
+    prompts = [rng.integers(2, cfg.vocab_size, n).tolist() for n in (4, 13)]
 
-    single = Engine(cfg, params, serving)
-    expected = _run_all(single, prompts, max_tokens=40)   # crosses 64/2 = 32
+    single = Engine(cfg, params, dataclasses.replace(serving, page_size=16))
+    expected = _run_all(single, prompts, max_tokens=40)   # crosses 3 pages
 
-    meshed = Engine(cfg, params, serving_p, mesh=_mesh3(1, 1, 2))
-    got = _run_all(meshed, prompts, max_tokens=40)
-    assert got == expected
-
-
-def test_mesh_sp_divisibility_error(setup):
-    cfg, params, serving = setup
-    bad = dataclasses.replace(serving, max_cache_len=40)  # 40 % (2*8) != 0
-    with pytest.raises(ValueError, match="sequence shards"):
-        Engine(cfg, params, bad, mesh=_mesh3(1, 1, 2))
+    meshed = Engine(cfg, params, serving_p, mesh=_mesh(dp, tp))
+    assert _run_all(meshed, prompts, max_tokens=40) == expected
 
 
-def test_mesh_sp1_allows_unaligned_cache(setup):
-    """The sp alignment guard must not fire for sp=1 meshes: a dp/tp-only
-    engine with a non-8-aligned cache window worked before the sp axis
-    existed and must keep working (code-review r2 finding #3)."""
+def test_mesh_allows_unaligned_cache(setup):
+    """A meshed engine with a cache window that is no multiple of 8 rows
+    (nor of the page) must keep working (code-review r2 finding #3)."""
     cfg, params, serving = setup
     odd = dataclasses.replace(serving, max_cache_len=60)   # 60 % 8 != 0
     engine = Engine(cfg, params, odd, mesh=_mesh(2, 1))
@@ -224,14 +186,12 @@ def test_mesh_sp1_allows_unaligned_cache(setup):
 def test_tp_mesh_keeps_paged_cache(setup):
     """tp shards only the pool's head axis, so paging (page-gated admission,
     on-demand growth) must survive under a tp mesh — the Qwen3-8B/v5e-8
-    flagship config; sp meshes fall back to the dense layout."""
+    flagship config."""
     cfg, params, serving = setup
     tp_eng = Engine(cfg, params, serving, mesh=_mesh(1, 2))
-    assert tp_eng.paged and tp_eng.cache["k"].ndim == 5
+    assert tp_eng.cache["k"].ndim == 5
     assert tp_eng.cache["k"].shape[1] == \
         serving.max_decode_slots * (tp_eng.max_len // serving.page_size) + 1
-    sp_eng = Engine(cfg, params, serving, mesh=_mesh3(1, 1, 2))
-    assert not sp_eng.paged
 
     # page-gated admission works under the tp mesh: a pool of one window
     # serializes two prompts over 4 free slots
@@ -260,8 +220,7 @@ def test_tp_mesh_keeps_paged_cache(setup):
 def test_dp_mesh_keeps_paged_cache_with_token_parity(setup, impl):
     """dp shards the pool's PAGE axis into per-group partitions with
     per-group host allocators — multi-replica-per-host dp serving must keep
-    on-demand paging (the r3 fallback to dense re-imported the capacity
-    ceiling paging removes) AND hold greedy token parity with the
+    on-demand paging AND hold greedy token parity with the
     single-device paged engine."""
     cfg, params, serving = setup
     serving_i = dataclasses.replace(serving, attention_impl=impl)
@@ -270,11 +229,9 @@ def test_dp_mesh_keeps_paged_cache_with_token_parity(setup, impl):
                for n in (3, 7, 12, 5)]
 
     single = Engine(cfg, params, serving_i)
-    assert single.paged
     expected = _run_all(single, prompts)
 
     dp_eng = Engine(cfg, params, serving_i, mesh=_mesh(2, 1))
-    assert dp_eng.paged, "dp mesh must keep the paged pool"
     assert dp_eng.dp_groups == 2
     # pool page axis = dp * (group_pages + 1), sharded over dp
     group_pages = (serving.max_decode_slots
@@ -283,7 +240,6 @@ def test_dp_mesh_keeps_paged_cache_with_token_parity(setup, impl):
     assert _run_all(dp_eng, prompts) == expected
 
     dptp_eng = Engine(cfg, params, serving_i, mesh=_mesh(2, 2))
-    assert dptp_eng.paged
     assert _run_all(dptp_eng, prompts) == expected
 
 
@@ -296,7 +252,7 @@ def test_dp_paged_admission_and_preemption_are_group_local(setup):
     small = dataclasses.replace(serving, kv_pool_pages=8, page_size=8,
                                 max_cache_len=32, prefill_buckets=(8, 16, 32))
     eng = Engine(cfg, params, small, mesh=_mesh(2, 1))
-    assert eng.paged and eng.dp_groups == 2
+    assert eng.dp_groups == 2
     # per-group partition: 8 // 2 = 4 pages + scratch
     assert eng._group_pages == 5
     reqs = [eng.submit(Request(prompt_ids=[5 + i] * 17, max_tokens=4,

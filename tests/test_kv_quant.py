@@ -1,7 +1,7 @@
 """Int8 KV-cache quantization: math parity + engine end-to-end.
 
 The reference's serving pods get this feature from vLLM (``kv_cache_dtype=
-int8``); here it is in-repo (serving/kv_cache.py quantize_rows, the quantizing
+int8``); here it is in-repo (ops/kv_pool.py quantize_rows, the quantizing
 Pallas kernels in ops/pallas_attention.py). The load-bearing property is that
 the XLA write paths (prefill) and the Pallas write kernel (decode) quantize
 BIT-FOR-BIT identically, so rows written by either are interchangeable, and
@@ -16,9 +16,9 @@ import pytest
 
 from aws_k8s_ansible_provisioner_tpu.config import ServingConfig, tiny_qwen3
 from aws_k8s_ansible_provisioner_tpu.models.layers import init_params
+from aws_k8s_ansible_provisioner_tpu.ops import kv_pool as kvc
 from aws_k8s_ansible_provisioner_tpu.ops import pallas_attention as pa
 from aws_k8s_ansible_provisioner_tpu.ops.attention import decode_attend
-from aws_k8s_ansible_provisioner_tpu.serving import kv_cache as kvc
 from aws_k8s_ansible_provisioner_tpu.serving.engine import Engine, Request
 
 
@@ -54,79 +54,35 @@ def test_quant_cache_decode_close_to_float():
 
 def test_pallas_quant_attend_matches_xla_dequant():
     """The int8 Pallas kernel (interpret) == XLA attend over the dequantized
-    cache, to float tolerance — the scales fold exactly."""
-    L, B, Hkv, S, D, Hq = 3, 4, 2, 64, 32, 4
+    rows, to float tolerance — the scales fold exactly. The pool is the
+    logical cache cut into pages under an identity table, its scale leaves
+    lane-padded as the engine allocates them."""
+    L, B, Hkv, S, D, Hq, PS = 3, 4, 2, 64, 32, 4, 32
     rng = np.random.default_rng(2)
     k = jnp.asarray(rng.normal(0, 1, (L, B, Hkv, S, D)), dtype=jnp.float32)
     v = jnp.asarray(rng.normal(0, 1, (L, B, Hkv, S, D)), dtype=jnp.float32)
     qk, ks = kvc.quantize_rows(k)
     qv, vs = kvc.quantize_rows(v)
+
+    def pages(a):
+        # [L, B, Hkv, S, ...] -> [L, B * S/PS, Hkv, PS, ...]
+        a = a.reshape(L, B, Hkv, S // PS, PS, *a.shape[4:])
+        return jnp.moveaxis(a, 3, 2).reshape(L, B * (S // PS), Hkv, PS,
+                                             *a.shape[5:])
+
+    pad = [(0, 0)] * 3 + [(0, kvc.scale_lanes(PS) - PS)]
+    table = jnp.arange(B * (S // PS), dtype=jnp.int32).reshape(B, S // PS)
     lengths = jnp.asarray([1, 9, 33, 64], jnp.int32)
     q = jnp.asarray(rng.normal(0, 1, (B, 1, Hq, D)), dtype=jnp.float32)
     for layer in [0, 2]:
-        got = pa.decode_attend_pallas_layer(
-            q, qk, qv, lengths, jnp.int32(layer), chunk=16, interpret=True,
-            cache_ks=ks, cache_vs=vs)
+        got = pa.decode_attend_pallas_paged(
+            q, pages(qk), pages(qv), lengths, jnp.int32(layer), table,
+            interpret=True, pool_ks=jnp.pad(pages(ks), pad),
+            pool_vs=jnp.pad(pages(vs), pad))
         ref = decode_attend(q, kvc.dequantize(qk[layer], ks[layer]),
                             kvc.dequantize(qv[layer], vs[layer]), lengths)
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    atol=1e-5, rtol=1e-5)
-
-
-def test_pallas_quant_stats_merge_matches_plain():
-    """(acc, m, l) partial emission over the full window reconstructs the
-    normalized context (the sp-merge identity) with an int8 cache."""
-    L, B, Hkv, S, D, Hq = 2, 2, 2, 32, 16, 4
-    rng = np.random.default_rng(3)
-    k = jnp.asarray(rng.normal(0, 1, (L, B, Hkv, S, D)), dtype=jnp.float32)
-    v = jnp.asarray(rng.normal(0, 1, (L, B, Hkv, S, D)), dtype=jnp.float32)
-    qk, ks = kvc.quantize_rows(k)
-    qv, vs = kvc.quantize_rows(v)
-    lengths = jnp.asarray([7, 29], jnp.int32)
-    q = jnp.asarray(rng.normal(0, 1, (B, 1, Hq, D)), dtype=jnp.float32)
-    acc, m, l = pa.decode_attend_pallas_layer(
-        q, qk, qv, lengths, jnp.int32(1), chunk=16, interpret=True,
-        return_stats=True, cache_ks=ks, cache_vs=vs)
-    ctx = (acc / np.maximum(np.asarray(l), 1e-9)[..., None])[:, None]
-    ref = pa.decode_attend_pallas_layer(
-        q, qk, qv, lengths, jnp.int32(1), chunk=16, interpret=True,
-        cache_ks=ks, cache_vs=vs)
-    np.testing.assert_allclose(ctx, np.asarray(ref), atol=1e-5, rtol=1e-5)
-
-
-def test_write_row_quant_kernel_matches_xla_write():
-    """Pallas quantizing row-write == kv_cache.write_token_layer (XLA): same
-    rounding rule, so values agree to 1 int8 step (compiled-program fusion can
-    shift the scale by 1 ulp) — prefilled and decoded rows interchange."""
-    cfg = tiny_qwen3()
-    B, S = 4, 64
-    cache_pl = kvc.init_cache(cfg, B, S, quant=True)
-    cache_xla = kvc.init_cache(cfg, B, S, quant=True)
-    rng = np.random.default_rng(4)
-    lengths = jnp.asarray([0, 3, 17, 63], jnp.int32)
-    layer = jnp.int32(1)
-    new = jnp.asarray(rng.normal(0, 2, (B, cfg.num_kv_heads, cfg.head_dim)),
-                      dtype=jnp.float32)
-    ck, ks = pa.cache_write_row_quant(cache_pl["k"], cache_pl["ks"], new,
-                                      lengths, layer, interpret=True)
-    cache_xla = kvc.write_token_layer(cache_xla, layer, lengths, new[:, None],
-                                      new[:, None])
-    assert np.abs(np.asarray(ck, np.int32)
-                  - np.asarray(cache_xla["k"], np.int32)).max() <= 1
-    np.testing.assert_allclose(np.asarray(ks), np.asarray(cache_xla["ks"]),
-                               rtol=1e-6)
-
-
-def test_write_row_quant_out_of_window_drops():
-    cfg = tiny_qwen3()
-    B, S = 2, 32
-    cache = kvc.init_cache(cfg, B, S, quant=True)
-    new = jnp.ones((B, cfg.num_kv_heads, cfg.head_dim), jnp.float32)
-    ck, ks = pa.cache_write_row_quant(
-        cache["k"], cache["ks"], new, jnp.asarray([-5, S], jnp.int32),
-        jnp.int32(0), interpret=True)
-    assert int(np.abs(np.asarray(ck)).sum()) == 0
-    assert float(np.abs(np.asarray(ks)).sum()) == 0.0
 
 
 def _run_engine(cfg, params, serving, prompts, max_tokens=6):
@@ -161,12 +117,12 @@ def test_engine_int8_token_parity_across_backends(impl):
     assert eng.cache["k"].dtype == jnp.int8
 
 
-@pytest.mark.parametrize("sp", [1, 2])
-def test_engine_int8_mesh_token_parity(cpu_devices, sp):
-    """Mesh + int8 together: shard_map'd quant cache specs, the quantizing
-    Pallas write kernel per shard, and (sp=2) the quant stats emission merged
-    across sequence shards — token parity with the single-device int8 engine.
-    """
+@pytest.mark.parametrize("dp,tp", [(2, 1), (1, 2), (2, 2)])
+def test_engine_int8_mesh_token_parity(cpu_devices, dp, tp):
+    """Mesh + int8 together: the shard_map'd quantized pool (pages over dp,
+    KV heads over tp, the scale leaves with them) and the quantizing Pallas
+    write kernel per shard — token parity with the single-device int8
+    engine."""
     from aws_k8s_ansible_provisioner_tpu.config import MeshConfig
     from aws_k8s_ansible_provisioner_tpu.parallel import make_mesh
 
@@ -179,8 +135,7 @@ def test_engine_int8_mesh_token_parity(cpu_devices, sp):
                          kv_dtype="int8", attention_impl="pallas",
                          prefix_cache=False)
     ref, _ = _run_engine(cfg, params, base, prompts)
-    mesh = make_mesh(MeshConfig(dp=2, tp=2, sp=sp),
-                     devices=jax.devices()[:4 * sp])
+    mesh = make_mesh(MeshConfig(dp=dp, tp=tp), devices=jax.devices()[:dp * tp])
     eng = Engine(cfg, params, base, mesh=mesh)
     reqs = [eng.submit(Request(prompt_ids=list(p), max_tokens=6,
                                ignore_eos=True)) for p in prompts]
@@ -190,9 +145,9 @@ def test_engine_int8_mesh_token_parity(cpu_devices, sp):
     assert [r.generated for r in reqs] == ref
 
 
-def test_engine_int8_prefix_cache_copies_scales():
-    """copy_prefix must move the scale rows with the int8 rows: a prefix hit
-    into a quantized cache serves the same tokens as a cold engine."""
+def test_engine_int8_prefix_hit_shares_scales():
+    """A shared page carries its scale rows with its int8 rows: a prefix hit
+    into the quantized pool serves the same tokens as a cold engine."""
     cfg = tiny_qwen3()
     params = init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
     rng = np.random.default_rng(6)
@@ -201,12 +156,9 @@ def test_engine_int8_prefix_cache_copies_scales():
     serving = ServingConfig(weights_dtype="bf16", max_decode_slots=4, max_cache_len=64,
                             prefill_buckets=(64,), dtype="float32",
                             kv_dtype="int8", attention_impl="xla",
-                            prefix_cache=True, prefix_cache_min_len=8,
-                            prefix_cache_payback_rows=8,
-                            paged=False)   # dense copy_prefix under test
+                            prefix_cache=True, page_size=32)
     eng = Engine(cfg, params, serving)
-    r1 = eng.submit(Request(prompt_ids=list(seed), max_tokens=2,
-                            ignore_eos=True))
+    eng.submit(Request(prompt_ids=list(seed), max_tokens=2, ignore_eos=True))
     while eng.pending or any(s is not None for s in eng.slot_req) \
             or eng._chunk is not None:
         eng.step()

@@ -51,7 +51,7 @@ from aws_k8s_ansible_provisioner_tpu.ops import moe  # noqa: E402
 from aws_k8s_ansible_provisioner_tpu.ops.attention import (  # noqa: E402
     make_decode_attend_carry_paged, make_mixed_attend_carry_paged,
     make_prefill_attend_paged_carry)
-from aws_k8s_ansible_provisioner_tpu.serving import paged_kv as pkv  # noqa: E402
+from aws_k8s_ansible_provisioner_tpu.ops import kv_pool as kvp  # noqa: E402
 from aws_k8s_ansible_provisioner_tpu.serving import programs as pg  # noqa: E402
 from aws_k8s_ansible_provisioner_tpu.serving.engine import (  # noqa: E402
     Engine, Request)
@@ -117,7 +117,7 @@ def _prefill(cfg, tree, pool, pages, ids, bucket=32):
 
 
 def _pool(cfg, tree):
-    return pkv.init_pool(cfg, 2 * PPS + 1, PS,
+    return kvp.init_pool(cfg, 2 * PPS + 1, PS,
                          tree["final_norm"]["weight"].dtype)
 
 

@@ -1,12 +1,13 @@
-"""Automatic prefix caching (DENSE slot-contiguous mode: these tests pin
-the copy-based token-level cache used under a mesh; the paged page-sharing
-equivalent is covered by tests/test_paged_engine.py): K/V reuse across requests sharing a prompt prefix.
+"""Automatic prefix caching: K/V reuse across requests sharing a prompt
+prefix, at page granularity.
 
-The vLLM feature of the same name (inside the reference's serving pods),
-rebuilt for the slot-contiguous cache: the prefix is a contiguous row range,
-so reuse is one masked slot-to-slot copy + suffix-only prefill through the
-chunk program. Every test is token-parity against a prefix-cache-disabled
-engine — reuse must be invisible in the output stream.
+The vLLM feature of the same name (inside the reference's serving pods): a
+prompt whose leading WHOLE pages hash-match pages still in the pool shares
+them (refcounted, no copy) and prefills only its suffix through the chunk
+program. Every test is token-parity against a prefix-cache-disabled engine —
+reuse must be invisible in the output stream. (Sharing without copying, the
+preemption-resume hit and the follow-up-turn hit on generated pages are
+tests/test_paged_engine.py's; adapters never sharing is tests/test_lora.py's.)
 """
 
 import dataclasses
@@ -20,18 +21,16 @@ from aws_k8s_ansible_provisioner_tpu.config import ServingConfig, tiny_qwen3
 from aws_k8s_ansible_provisioner_tpu.models.layers import init_params
 from aws_k8s_ansible_provisioner_tpu.serving.engine import Engine, Request
 
+PS = 8
+
 
 @pytest.fixture(scope="module")
 def setup():
     cfg = tiny_qwen3()
     params = init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
-    # payback_rows=1 disables the dispatch-economics gate so these tests
-    # exercise the copy/suffix machinery with short prompts; the gate itself
-    # is covered by test_payback_gate_*.
-    serving = ServingConfig(weights_dtype="bf16", max_decode_slots=4, max_cache_len=128,
-                            prefill_buckets=(16, 64), dtype="float32",
-                            prefix_cache_min_len=8,
-                            prefix_cache_payback_rows=1, paged=False)
+    serving = ServingConfig(weights_dtype="bf16", max_decode_slots=4,
+                            max_cache_len=128, page_size=PS,
+                            prefill_buckets=(16, 64), dtype="float32")
     return cfg, params, serving
 
 
@@ -61,8 +60,9 @@ def _expected(cfg, params, serving, schedule, max_tokens=6):
 
 
 def test_prefix_hit_token_parity_and_counters(setup):
-    """B shares a 24-token prefix with finished request A: B must reuse it
-    (hit counter) and still produce exactly the no-reuse tokens."""
+    """B shares a 24-token prefix (3 whole pages) with finished request A: B
+    must reuse it (hit counter, reused tokens) and still produce exactly the
+    no-reuse tokens."""
     cfg, params, serving = setup
     rng = np.random.default_rng(0)
     shared = rng.integers(2, cfg.vocab_size, 24).tolist()
@@ -80,8 +80,9 @@ def test_prefix_hit_token_parity_and_counters(setup):
 
 
 def test_prefix_hit_from_active_slot(setup):
-    """The source slot may still be decoding — its prompt rows are immutable
-    once written, so an in-flight request is a valid prefix source."""
+    """The source may still be decoding — a prompt's whole pages are indexed
+    when its prefill lands and are immutable from then on, so an in-flight
+    request's pages are a valid prefix source (shared, refcount 2)."""
     cfg, params, serving = setup
     rng = np.random.default_rng(1)
     shared = rng.integers(2, cfg.vocab_size, 20).tolist()
@@ -100,19 +101,20 @@ def test_prefix_hit_from_active_slot(setup):
     engine = Engine(cfg, params, serving)
     ga = engine.submit(Request(prompt_ids=list(a), max_tokens=10,
                                ignore_eos=True))
-    engine.step()   # prefill a — a's slot is now a live prefix source
+    engine.step()   # prefill a — a's pages are now a live prefix source
     gb = engine.submit(Request(prompt_ids=list(b), max_tokens=10,
                                ignore_eos=True))
     _drain(engine)
     assert [ga.generated, gb.generated] == [ra.generated, rb.generated]
     assert engine.metrics.prefix_cache_hits.total() == 1
+    assert engine.metrics.prefix_tokens_reused.total() == 2 * PS
 
 
 def test_prefix_survives_interleaved_decodes(setup):
     """After A finishes, OTHER requests keep decoding (every decode dispatch
-    scatter-writes a scratch row for every slot) before B reuses A's rows —
-    the retained prefix must not be corrupted (freed slots keep their final
-    length so scratch writes land past the prompt)."""
+    writes a garbage row for every idle slot) before B reuses A's pages —
+    the released pages must not be corrupted (an idle slot's table points
+    at the scratch page)."""
     cfg, params, serving = setup
     rng = np.random.default_rng(2)
     shared = rng.integers(2, cfg.vocab_size, 16).tolist()
@@ -130,10 +132,10 @@ def test_prefix_survives_interleaved_decodes(setup):
     assert engine.metrics.prefix_cache_hits.total() == 1
 
 
-def test_short_prefix_not_reused(setup):
+def test_prefix_shorter_than_a_page_not_reused(setup):
     cfg, params, serving = setup
     rng = np.random.default_rng(3)
-    shared = rng.integers(2, cfg.vocab_size, 4).tolist()   # < min_len(8)
+    shared = rng.integers(2, cfg.vocab_size, PS - 1).tolist()
     a = shared + rng.integers(2, cfg.vocab_size, 6).tolist()
     b = shared + rng.integers(2, cfg.vocab_size, 8).tolist()
 
@@ -143,55 +145,71 @@ def test_short_prefix_not_reused(setup):
     assert engine.metrics.prefix_cache_hits.total() == 0
 
 
-def test_stale_entry_invalidated_on_slot_reuse(setup):
-    """Once a slot is overwritten by a new prompt, the old prompt must no
-    longer be offered as a prefix source."""
+def test_evicted_pages_no_longer_match(setup):
+    """Once a released prompt's pages are reclaimed for a new prompt, the
+    old prompt must no longer be offered as a prefix source."""
     cfg, params, serving = setup
-    one_slot = dataclasses.replace(serving, max_decode_slots=1)
+    # one slot, a pool of exactly one window: the second prompt can only be
+    # placed by reclaiming the first one's evictable pages (host tier off —
+    # with it on the reclaimed pages spill and the old prompt restores them,
+    # which the host-tier tests below cover)
+    tight = dataclasses.replace(serving, max_decode_slots=1,
+                                max_cache_len=32, prefill_buckets=(32,),
+                                kv_pool_pages=4, kv_host_tier_bytes=0)
     rng = np.random.default_rng(4)
-    old = rng.integers(2, cfg.vocab_size, 12).tolist()
-    new = rng.integers(2, cfg.vocab_size, 12).tolist()
+    old = rng.integers(2, cfg.vocab_size, 20).tolist()
+    new = rng.integers(2, cfg.vocab_size, 20).tolist()
     again_old = old + rng.integers(2, cfg.vocab_size, 3).tolist()
 
-    want = _expected(cfg, params, one_slot, [[old], [new], [again_old]])
+    want = _expected(cfg, params, tight, [[old], [new], [again_old]])
 
-    engine = Engine(cfg, params, one_slot)
-    got = (_run(engine, [old]) + _run(engine, [new])
-           + _run(engine, [again_old]))
+    engine = Engine(cfg, params, tight)
+    got = _run(engine, [old])
+    assert engine.allocators[0].stats()["pages_evictable"] >= 2
+    got += _run(engine, [new]) + _run(engine, [again_old])
     assert got == want
-    # the only slot now holds `new`; `again_old` must not have matched it
     assert engine.metrics.prefix_cache_hits.total() == 0
 
 
-def test_same_round_admission_never_matches_reassigned_slot(setup):
-    """A slot assigned earlier in the SAME admission round must stop acting
-    as a prefix source immediately: its rows are about to be overwritten by
-    this round's prefill, so a later request copying them would serve
-    garbage (code-review r2 finding #1). Both pop orders are exercised via
-    submit order; parity against a cache-off engine is the oracle."""
+@pytest.mark.parametrize("order", ["unrelated-first", "extension-first",
+                                   "twins"])
+def test_same_round_admission_never_matches_pages_being_written(setup, order):
+    """Pages are indexed when a prefill LANDS, never at admission: a request
+    admitted in the same round as another must not match pages the round's
+    prefill has yet to write. ``twins``: two prompts sharing a prefix with
+    each other and nothing resident arrive together — neither may borrow
+    from the other. The other two orders put an extension of a resident
+    prompt beside an unrelated one. Parity against a cache-off engine is the
+    oracle."""
     cfg, params, serving = setup
     two_slot = dataclasses.replace(serving, max_decode_slots=2)
     rng = np.random.default_rng(6)
     p = rng.integers(2, cfg.vocab_size, 16).tolist()
     a = rng.integers(2, cfg.vocab_size, 14).tolist()          # unrelated
     b = p + rng.integers(2, cfg.vocab_size, 5).tolist()       # extends p
-
-    for first, second in ((a, b), (b, a)):
-        want = _expected(cfg, params, two_slot, [[p], [first, second]])
+    if order == "twins":
+        first = [p + [7, 8, 9], p + [10, 11]]
+        want = _expected(cfg, params, two_slot, [first])
         engine = Engine(cfg, params, two_slot)
-        got = _run(engine, [p]) + _run(engine, [first, second])
-        assert got == want, f"order {first is a and 'a,b' or 'b,a'}"
+        assert _run(engine, first) == want
+        assert engine.metrics.prefix_cache_hits.total() == 0
+        return
+    pair = [a, b] if order == "unrelated-first" else [b, a]
+    want = _expected(cfg, params, two_slot, [[p], pair])
+    engine = Engine(cfg, params, two_slot)
+    assert _run(engine, [p]) + _run(engine, pair) == want
 
 
 def test_burst_keeps_batched_prefill(setup, monkeypatch):
-    """Prefix reuse must never break up batched prefill: a burst of
-    shared-prefix prompts prefills in ONE batched dispatch with zero reuse —
-    the serialized chunk path (one ~RTT dispatch per request) costs more
-    than the recompute it saves (code-review r2 finding #4). Reuse fires
-    only for isolated arrivals (the follow-up-chat-turn case)."""
+    """A match under ``prefix_reuse_min_pages`` must never break up batched
+    prefill: a burst of prompts sharing ONE page with a resident prompt
+    prefills in ONE batched dispatch with zero reuse — the serialized chunk
+    walk (one dispatch a request) costs more than the recompute it saves.
+    The same burst arriving one at a time (isolated) does reuse."""
     cfg, params, serving = setup
+    assert serving.prefix_reuse_min_pages == 2
     rng = np.random.default_rng(7)
-    shared = rng.integers(2, cfg.vocab_size, 16).tolist()
+    shared = rng.integers(2, cfg.vocab_size, PS + 3).tolist()   # one page
     p = shared + rng.integers(2, cfg.vocab_size, 3).tolist()
     burst = [shared + rng.integers(2, cfg.vocab_size, k).tolist()
              for k in (4, 5, 6)]
@@ -208,48 +226,31 @@ def test_burst_keeps_batched_prefill(setup, monkeypatch):
     assert all(g for g in got)
     assert engine.metrics.prefix_cache_hits.total() == 0
     assert batch_calls == [3]
+    for q in burst:
+        _run(engine, [q + [5]])
+    assert engine.metrics.prefix_cache_hits.total() == 3
 
 
-def test_payback_gate_blocks_dispatch_adding_hits(setup):
-    """At the default payback threshold, a short cross-slot reuse (copy +
-    chunk = 2 dispatches vs 1 bucket dispatch) is declined — the added RTT
-    outweighs the recompute saved (code-review r2 finding #2b)."""
+def test_burst_with_a_long_shared_prefix_reuses(setup):
+    """At or over ``prefix_reuse_min_pages`` whole pages the match is kept
+    even under a burst: skipping the shared compute beats the batch slot."""
     cfg, params, serving = setup
-    gated = dataclasses.replace(serving, prefix_cache_payback_rows=256)
     rng = np.random.default_rng(8)
-    shared = rng.integers(2, cfg.vocab_size, 24).tolist()
-    a = shared + rng.integers(2, cfg.vocab_size, 4).tolist()
-    b = shared + rng.integers(2, cfg.vocab_size, 6).tolist()
+    shared = rng.integers(2, cfg.vocab_size, 3 * PS).tolist()
+    p = shared + rng.integers(2, cfg.vocab_size, 3).tolist()
+    burst = [shared + rng.integers(2, cfg.vocab_size, k).tolist()
+             for k in (4, 5, 6)]
 
-    want = _expected(cfg, params, gated, [[a], [b]])
-    engine = Engine(cfg, params, gated)
-    got = _run(engine, [a]) + _run(engine, [b])
-    assert got == want
-    assert engine.metrics.prefix_cache_hits.total() == 0
-
-
-def test_same_slot_reuse_is_free_and_always_taken(setup):
-    """A follow-up turn that gets its own slot back (saturated/1-slot
-    engine) reuses resident rows with ZERO copy dispatch, so the payback
-    gate never blocks it (code-review r2 finding #2a)."""
-    cfg, params, serving = setup
-    one = dataclasses.replace(serving, max_decode_slots=1,
-                              prefix_cache_payback_rows=256)
-    rng = np.random.default_rng(9)
-    a = rng.integers(2, cfg.vocab_size, 20).tolist()
-    b = a + rng.integers(2, cfg.vocab_size, 6).tolist()
-
-    want = _expected(cfg, params, one, [[a], [b]])
-    engine = Engine(cfg, params, one)
-    got = _run(engine, [a]) + _run(engine, [b])
-    assert got == want
-    assert engine.metrics.prefix_cache_hits.total() == 1
-    assert engine.metrics.prefix_tokens_reused.total() == 20
+    want = _expected(cfg, params, serving, [[p], burst])
+    engine = Engine(cfg, params, serving)
+    assert _run(engine, [p]) + _run(engine, burst) == want
+    assert engine.metrics.prefix_cache_hits.total() == 3
+    assert engine.metrics.prefix_tokens_reused.total() == 3 * 3 * PS
 
 
 def test_prefix_hit_with_chunked_suffix(setup):
     """Prefix reuse composes with chunked prefill: a long suffix still walks
-    the chunk program from the copied offset."""
+    the chunk program from the reuse offset."""
     cfg, params, serving = setup
     chunked = dataclasses.replace(serving, prefill_chunk=16)
     rng = np.random.default_rng(5)
@@ -265,8 +266,44 @@ def test_prefix_hit_with_chunked_suffix(setup):
     assert engine.metrics.prefix_cache_hits.total() == 1
 
 
+def test_prefix_hit_suffix_rides_the_mixed_program(setup):
+    """A hit arriving beside a live stream: its suffix is prefilled by
+    ``mixed_step`` from the reuse offset (one dispatch with the decode
+    batch, the pipeline stays open), byte-identical to the cache-off
+    engine."""
+    cfg, params, serving = setup
+    rng = np.random.default_rng(9)
+    shared = rng.integers(2, cfg.vocab_size, 3 * PS).tolist()
+    a = shared + rng.integers(2, cfg.vocab_size, 4).tolist()
+    live = rng.integers(2, cfg.vocab_size, 6).tolist()
+    b = shared + rng.integers(2, cfg.vocab_size, 9).tolist()
+
+    def schedule(engine, spy=None):
+        out = _run(engine, [a])
+        rl = engine.submit(Request(prompt_ids=list(live), max_tokens=24,
+                                   ignore_eos=True))
+        for _ in range(4):
+            engine.step()            # live is decoding, a dispatch in flight
+        if spy is not None:
+            real = engine._mixed_dispatch
+            engine._mixed_dispatch = lambda st, *x: (spy.append(st["off"]),
+                                                     real(st, *x))[1]
+        rb = engine.submit(Request(prompt_ids=list(b), max_tokens=6,
+                                   ignore_eos=True))
+        _drain(engine)
+        return out + [rl.generated, rb.generated]
+
+    want = schedule(Engine(cfg, params,
+                           dataclasses.replace(serving, prefix_cache=False)))
+    offs = []
+    engine = Engine(cfg, params, serving)
+    assert schedule(engine, offs) == want
+    assert engine.metrics.prefix_cache_hits.total() == 1
+    assert offs and offs[0] == 3 * PS, offs
+
+
 # ---------------------------------------------------------------------------
-# Host tier (paged mode): eviction spills prefix pages to host RAM; a later
+# Host tier: eviction spills prefix pages to host RAM; a later
 # request whose prefix is gone from HBM restores the pages instead of
 # re-prefilling. Every test is token-parity: tier traffic must be invisible
 # in the output stream.
@@ -280,7 +317,7 @@ PS = 8
 def _paged_engine(model, **kw):
     cfg, params = model
     base = dict(max_decode_slots=4, max_cache_len=64, page_size=PS,
-                prefill_buckets=(8, 16, 32, 64), dtype="float32", paged=True,
+                prefill_buckets=(8, 16, 32, 64), dtype="float32",
                 kv_pool_pages=10, kv_host_tier_bytes=1 << 22)
     base.update(kw)
     return Engine(cfg, params, ServingConfig(weights_dtype="bf16", **base))

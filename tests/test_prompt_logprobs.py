@@ -84,24 +84,31 @@ def test_batched_prefill_matches_and_mixes_with_plain():
     assert r2.prompt_logprob_data == []
 
 
-@pytest.mark.parametrize("paged", [False, True])
-def test_prefix_cache_bypassed_for_prompt_logprobs(paged):
-    """With the shared prefix already resident, a prompt_logprobs request
-    must force a FULL prefill (reused rows skip the computation) and still
-    match the reference."""
-    eng = Engine(CFG, PARAMS, _serving(prefix_cache=True, paged=paged,
-                                       page_size=8, max_cache_len=64,
+@pytest.mark.parametrize("n_pages", [1, 2])
+def test_prefix_cache_bypassed_for_prompt_logprobs(n_pages):
+    """With the shared prefix already resident (``n_pages`` whole pages of
+    it indexed), a prompt_logprobs request must force a FULL prefill (reused
+    rows skip the computation) and still match the reference; the same
+    prompt WITHOUT prompt_logprobs does hit."""
+    prompt = (PROMPT * 3)[:8 * n_pages + 3]
+    eng = Engine(CFG, PARAMS, _serving(prefix_cache=True, page_size=8,
+                                       max_cache_len=64,
+                                       prefill_buckets=(16, 32),
                                        prefix_reuse_min_pages=1,
                                        max_prefill_batch=1))
-    seed = eng.submit(Request(prompt_ids=list(PROMPT), max_tokens=2,
-                              ignore_eos=True))
+    eng.submit(Request(prompt_ids=list(prompt), max_tokens=2,
+                       ignore_eos=True))
     _drain(eng)
     hits0 = eng.metrics.prefix_cache_hits.total()
-    req = eng.submit(Request(prompt_ids=list(PROMPT), max_tokens=2,
+    req = eng.submit(Request(prompt_ids=list(prompt), max_tokens=2,
                              ignore_eos=True, prompt_logprobs=2))
     _drain(eng)
     assert eng.metrics.prefix_cache_hits.total() == hits0
-    _check(req.prompt_logprob_data, _reference_plp(PROMPT, 2), 2)
+    _check(req.prompt_logprob_data, _reference_plp(prompt, 2), 2)
+    eng.submit(Request(prompt_ids=list(prompt), max_tokens=2,
+                       ignore_eos=True))
+    _drain(eng)
+    assert eng.metrics.prefix_cache_hits.total() == hits0 + 1
 
 
 def test_chunked_prompt_rejected():

@@ -99,7 +99,7 @@ def test_seeded_stream_survives_preemption(model):
     cfg, params = model
     mk = lambda: Engine(cfg, params, ServingConfig(weights_dtype="bf16", 
         max_decode_slots=4, max_cache_len=64, page_size=8,
-        prefill_buckets=(8, 16), dtype="float32", paged=True,
+        prefill_buckets=(8, 16), dtype="float32",
         kv_pool_pages=32))
     base_eng = mk()
     base = base_eng.submit(Request(**{**SEEDED, "max_tokens": 24}))

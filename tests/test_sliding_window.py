@@ -19,7 +19,6 @@ from aws_k8s_ansible_provisioner_tpu.config import (MeshConfig, ServingConfig,
 from aws_k8s_ansible_provisioner_tpu.models.layers import init_params
 from aws_k8s_ansible_provisioner_tpu.ops import pallas_attention as pa
 from aws_k8s_ansible_provisioner_tpu.ops.attention import decode_attend
-from aws_k8s_ansible_provisioner_tpu.serving import kv_cache as kvc
 from aws_k8s_ansible_provisioner_tpu.serving.engine import Engine, Request
 
 
@@ -30,8 +29,17 @@ def test_pallas_windowed_attend_matches_xla():
     v = jnp.asarray(rng.normal(0, 1, (L, B, Hkv, S, D)), jnp.float32)
     lengths = jnp.asarray([5, 33, 64], jnp.int32)   # below / beyond window
     q = jnp.asarray(rng.normal(0, 1, (B, 1, Hq, D)), jnp.float32)
-    got = pa.decode_attend_pallas_layer(q, k, v, lengths, jnp.int32(1),
-                                        chunk=16, interpret=True, window=W)
+    # the logical rows cut into 16-row pages under an identity table
+    PS = 16
+
+    def pages(a):
+        a = a.reshape(L, B, Hkv, S // PS, PS, D)
+        return jnp.moveaxis(a, 3, 2).reshape(L, B * (S // PS), Hkv, PS, D)
+
+    table = jnp.arange(B * (S // PS), dtype=jnp.int32).reshape(B, S // PS)
+    got = pa.decode_attend_pallas_paged(q, pages(k), pages(v), lengths,
+                                        jnp.int32(1), table, interpret=True,
+                                        window=W)
     ref = decode_attend(q, k[1], v[1], lengths, window=W)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                atol=1e-5, rtol=1e-5)
@@ -100,13 +108,25 @@ def test_spec_decode_windowed_stream_identity():
     assert got == ref
 
 
-def test_window_rejects_sp_mesh(cpu_devices):
+def test_windowed_decode_parity_under_dp_mesh(cpu_devices):
+    """A sliding-window model under a dp mesh: the window's low-page clamp
+    rides the per-group table rebase — token parity with one device, ~4
+    windows past W."""
     from aws_k8s_ansible_provisioner_tpu.parallel import make_mesh
 
     cfg = tiny_mistral()
     params = init_params(cfg, jax.random.PRNGKey(5), jnp.float32)
     serving = ServingConfig(weights_dtype="bf16", max_decode_slots=4, max_cache_len=64,
-                            prefill_buckets=(16,), dtype="float32")
-    mesh = make_mesh(MeshConfig(dp=2, sp=2), devices=cpu_devices[:4])
-    with pytest.raises(ValueError, match="sliding-window"):
-        Engine(cfg, params, serving, mesh=mesh)
+                            prefill_buckets=(16,), dtype="float32",
+                            attention_impl="pallas")
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(2, cfg.vocab_size, n).tolist() for n in (3, 13)]
+    want = _run(cfg, params, serving, prompts)
+    mesh = make_mesh(MeshConfig(dp=2), devices=cpu_devices[:2])
+    eng = Engine(cfg, params, serving, mesh=mesh)
+    reqs = [eng.submit(Request(prompt_ids=list(p), max_tokens=30,
+                               ignore_eos=True)) for p in prompts]
+    for _ in range(10000):
+        if not eng.step():
+            break
+    assert [r.generated for r in reqs] == want

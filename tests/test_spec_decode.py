@@ -100,15 +100,17 @@ def test_spec_sampled_slot_accepts_nothing():
     cfg = tiny_qwen3()
     params = init_params(cfg, jax.random.PRNGKey(4), jnp.float32)
     B, R = 2, 4
-    cache = __import__(
-        "aws_k8s_ansible_provisioner_tpu.serving.kv_cache",
-        fromlist=["init_cache"]).init_cache(cfg, B, 64, jnp.float32)
+    from aws_k8s_ansible_provisioner_tpu.ops import kv_pool as kvp
+
+    # an identity table: slot i owns pages [4 i, 4 i + 4) of 16 rows
+    cache = kvp.init_pool(cfg, B * 4, 16, jnp.float32)
+    table = jnp.arange(B * 4, dtype=jnp.int32).reshape(B, 4)
     tokens = jnp.asarray(np.full((B, R), 5, np.int32))
     lengths = jnp.asarray([3, 3], jnp.int32)
     _, out, accepted = spec_decode_step(
         cfg, R, params, cache, tokens, lengths, jax.random.PRNGKey(0),
         jnp.asarray([0.0, 0.9], jnp.float32), jnp.zeros(B, jnp.int32),
-        jnp.ones(B, jnp.float32), impl="xla")
+        jnp.ones(B, jnp.float32), impl="xla", table=table)
     accepted = np.asarray(accepted)
     assert accepted[1] == 1                 # sampled slot: one token only
     assert 1 <= accepted[0] <= R
@@ -138,7 +140,6 @@ def test_spec_under_tp_mesh_token_parity(cpu_devices):
     spec = dataclasses.replace(base, spec_decode=True, spec_k=4, spec_ngram=3)
     mesh = make_mesh(MeshConfig(dp=1, tp=2), devices=jax.devices("cpu"))
     eng = Engine(cfg, params, spec, mesh=mesh)
-    assert eng._spec_mesh_ok
     reqs = [eng.submit(Request(prompt_ids=list(p), max_tokens=24,
                                ignore_eos=True)) for p in prompts]
     for _ in range(10000):
@@ -172,7 +173,6 @@ def test_spec_parity_under_dp_mesh(cpu_devices, dp, tp):
     spec = dataclasses.replace(base, spec_decode=True, spec_k=4, spec_ngram=3)
     mesh = make_mesh(MeshConfig(dp=dp, tp=tp), devices=jax.devices("cpu"))
     eng = Engine(cfg, params, spec, mesh=mesh)
-    assert eng._spec_mesh_ok
     reqs = [eng.submit(Request(prompt_ids=list(p), max_tokens=24,
                                ignore_eos=True)) for p in prompts]
     for _ in range(10000):

@@ -32,7 +32,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from aws_k8s_ansible_provisioner_tpu.ops import pallas_attention as pa
-from aws_k8s_ansible_provisioner_tpu.serving import paged_kv as pkv
+from aws_k8s_ansible_provisioner_tpu.ops import kv_pool as kvp
 
 L, HKV, HQ, D, PS, B = 28, 8, 16, 128, 64, 32      # Qwen3-0.6B, default server
 P = B * 32 + 1                                     # default pool + scratch
@@ -67,7 +67,7 @@ def _pool(chip, quant, hkv=HKV):
         return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
 
     kv = sds((L, P, hkv, PS, D), jnp.int8 if quant else jnp.bfloat16)
-    scales = sds((L, P, hkv, pkv.scale_lanes(PS)), jnp.float32)
+    scales = sds((L, P, hkv, kvp.scale_lanes(PS)), jnp.float32)
     return sds, kv, (dict(pool_ks=scales, pool_vs=scales) if quant else {})
 
 
@@ -233,7 +233,7 @@ def test_paged_prefill_write_holds_no_pool_copy(chip):
     def prefill_writes(pool, pages, k, v):
         def body(carry, kv_l):
             pool, layer = carry
-            pool = pkv.write_chunk_paged_layer(pool, layer, pages, 0,
+            pool = kvp.write_chunk_paged_layer(pool, layer, pages, 0,
                                                kv_l[0], kv_l[1], PS)
             return (pool, layer + 1), None
 

@@ -194,7 +194,7 @@ def test_host_and_device_quantization_agree():
 
 
 def test_all_features_compose():
-    """Kitchen sink: paged KV + int8 KV cache + int8 weights + speculative
+    """Kitchen sink: int8 KV cache + int8 weights + speculative
     decoding + prefix cache in ONE engine — the full shipped-default stack
     plus every bandwidth lever — must generate the same stream as the same
     quantized engine with each subsystem individually disabled (the
@@ -204,9 +204,8 @@ def test_all_features_compose():
     params = init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
     oracle_cfg = ServingConfig(max_decode_slots=4, max_cache_len=128,
                                prefill_buckets=(32,), dtype="float32",
-                               weights_dtype="int8", paged=False,
-                               prefix_cache=False)
-    sink_cfg = dataclasses.replace(oracle_cfg, paged=True, page_size=32,
+                               weights_dtype="int8", prefix_cache=False)
+    sink_cfg = dataclasses.replace(oracle_cfg, page_size=32,
                                    kv_dtype="int8", spec_decode=True,
                                    spec_k=4, spec_ngram=3, prefix_cache=True,
                                    attention_impl="pallas")
@@ -216,7 +215,7 @@ def test_all_features_compose():
 
     oracle = _run(Engine(cfg, params, oracle_cfg), prompts, max_tokens=16)
     sink_eng = Engine(cfg, params, sink_cfg)
-    assert sink_eng.paged and weights_quantized(sink_eng.params)
+    assert weights_quantized(sink_eng.params)
     got = _run(sink_eng, prompts, max_tokens=16)
     assert got == oracle
 
