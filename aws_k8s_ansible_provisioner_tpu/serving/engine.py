@@ -211,7 +211,13 @@ class Request:
     # [(token_id, logprob) x k]) — filled at activation when
     # prompt_logprobs is requested
     prompt_logprob_data: List = field(default_factory=list)
+    # A stream's queue carries ITEMS: a list of the token ids one dispatch
+    # produced for this request (the activation token is an item of its
+    # own), then None when the request is done. ``pending`` holds the ids
+    # the emit phase in progress has recorded and not yet put (engine
+    # thread only; empty whenever the engine is outside an emit phase).
     out_queue: "queue.Queue" = field(default_factory=queue.Queue)
+    pending: List[int] = field(default_factory=list)
     t_submit: float = 0.0
     # first admission out of the queue into a slot (set-if-unset, so a
     # preempt/requeue round-trip keeps the original queue-wait boundary) —
@@ -262,7 +268,7 @@ class Engine(EnginePrograms):
         "_inflight", "_pipe_carry", "_carry_gen", "_op_cache",
         "_op_dirty_sampling", "_op_dirty_table", "_last_ready",
         "_busy_watermark", "_allow_dev", "_allow_batch_dev",
-        "_restore_pending",
+        "_restore_pending", "_emit_streams", "_dispatch_s",
     )
 
     def __init__(self, cfg: ModelConfig, params, serving: ServingConfig,
@@ -370,6 +376,9 @@ class Engine(EnginePrograms):
         self._lock = threading.Lock()
         self._work_event = threading.Event()
         self._tok_times: Deque = collections.deque(maxlen=50)
+        # streams the emit phase in progress recorded a token for, in the
+        # order of their first token (_emit appends, _flush_streams empties)
+        self._emit_streams: List[Request] = []
         # Chunked-prefill state: {"req", "slot", "off", "C"} while a prompt
         # (or a prefix-cache suffix) is being prefilled chunk-by-chunk; decode
         # steps interleave between chunks (self._chunk_yield alternates).
@@ -1155,7 +1164,7 @@ class Engine(EnginePrograms):
             _flight.record("deadline_reap", req.id, slot=slot,
                            phase="prefill_chunk")
             _flight.finish(req.id, "timeout", ok=False)
-            req.out_queue.put(None)
+            self._close_stream(req)
         expired = []
         with self._lock:
             for rid, r in list(self._queued.items()):
@@ -1172,7 +1181,7 @@ class Engine(EnginePrograms):
             self.metrics.mark_request("timeout", now - r.t_submit)
             _flight.record("deadline_reap", r.id, phase="queued")
             _flight.finish(r.id, "timeout", ok=False)
-            r.out_queue.put(None)
+            self._close_stream(r)
         if expired:
             self.metrics.queue_depth.set(self.sched.stats().queue_depth)
 
@@ -1234,7 +1243,7 @@ class Engine(EnginePrograms):
                     cand.finish_reason = "cancelled"
                     _flight.record("cancel_reap", cand.id, phase="queued")
                     _flight.finish(cand.id, "cancelled", ok=False)
-                    cand.out_queue.put(None)
+                    self._close_stream(cand)
                 continue
             _, rid, slot = action
             with self._lock:
@@ -1345,6 +1354,7 @@ class Engine(EnginePrograms):
                 and self.sched.stats().queue_depth > 0
                 and not self._ragged_on()):
             self._drain_decode_pipeline("prefill")
+        self._await_arrival()
         with _phase(PH_ADMIT):
             batch, chunk_next = self._admit_round()
         if batch or chunk_next is not None:
@@ -1381,7 +1391,7 @@ class Engine(EnginePrograms):
                     self.metrics.mark_request("error", 0.0)
                     _flight.finish(req.id, "error", ok=False,
                                    phase="prefill_batch")
-                    req.out_queue.put(None)
+                    self._close_stream(req)
                 if chunk_next is not None:
                     req, slot = chunk_next[:2]
                     self._release_slot_pages(slot)
@@ -1390,7 +1400,7 @@ class Engine(EnginePrograms):
                     self.metrics.mark_request("error", 0.0)
                     _flight.finish(req.id, "error", ok=False,
                                    phase="prefill_batch")
-                    req.out_queue.put(None)
+                    self._close_stream(req)
                 raise
             if chunk_next is not None:  # chunking starts next step
                 with _phase(PH_ADMIT):
@@ -1414,6 +1424,40 @@ class Engine(EnginePrograms):
             return True
         return False
 
+    def _await_arrival(self) -> None:
+        """Bind the next dispatch late. With a dispatch running on the
+        device the next one only has to be enqueued before that one ends,
+        and whatever is enqueued now stands between a request that arrives
+        a moment later and its admission for a whole dispatch more. So
+        with a slot free and nobody waiting, give an arrival the first half
+        of the running dispatch's expected time (its program's last one,
+        ``_dispatch_s``) to show up — the engine thread would otherwise
+        spend that time blocked in the fetch — and keep the other half for
+        building and enqueueing what comes next. Who gains: a caller that
+        asks again right after its answer (when a token woke its handler
+        the emit loop took tens of ms and such a caller was queued by the
+        time the engine looked; now the engine looks within a few ms), and
+        any arrival in the first half of a dispatch. The half is a choice,
+        not a swept optimum (PERF.md section 7)."""
+        rec = self._inflight
+        if rec is None or self.draining:
+            return
+        st = self.sched.stats()
+        if st.queue_depth > 0 or st.active_slots >= st.num_slots:
+            return
+        drec = rec["drec"]
+        expect = self._dispatch_s.get((drec["program"], drec.get("horizon")))
+        if expect is None:
+            return
+        until = max(drec["t_enqueue"], self._busy_watermark) + 0.5 * expect
+        self._work_event.clear()        # submit() and cancel() set it
+        if self.sched.stats().queue_depth > 0:
+            return                      # arrived between the two reads
+        timeout = until - time.monotonic()
+        if timeout > 0.001:
+            with _phase(PH_IDLE):
+                self._work_event.wait(timeout)
+
     def _emit(self, slot: int, token: int, lp=None):
         """Record one generated token for a slot; handle stop conditions."""
         req = self.slot_req[slot]
@@ -1431,7 +1475,9 @@ class Engine(EnginePrograms):
         self.last_token[slot] = token
         self.metrics.generated_tokens.inc()
         if req.stream:
-            req.out_queue.put(token)
+            if not req.pending:
+                self._emit_streams.append(req)
+            req.pending.append(token)
 
         hit_eos = ((token in self._eos_set and not req.ignore_eos)
                    or token in req.stop_token_ids) \
@@ -1441,6 +1487,29 @@ class Engine(EnginePrograms):
         if hit_eos or out_of_budget:
             req.finish_reason = "stop" if hit_eos else "length"
             self._finish(slot)
+
+    def _put_pending(self, req: Request) -> None:
+        """Hand a stream what the emit phase recorded for it, as ONE queue
+        item (one wake-up of its handler thread)."""
+        if not req.pending:
+            return
+        item, req.pending = req.pending, []
+        req.out_queue.put(item)
+        self.metrics.stream_items.inc()
+
+    def _flush_streams(self) -> None:
+        """Leave an emit phase: every stream it recorded a token for gets
+        its item now — no token waits for a later dispatch or step (a
+        request _finish closed meanwhile has nothing left to put)."""
+        for req in self._emit_streams:
+            self._put_pending(req)
+        self._emit_streams.clear()
+
+    def _close_stream(self, req: Request) -> None:
+        """The ONE way a request's queue ends: what is pending, then the
+        None sentinel."""
+        self._put_pending(req)
+        req.out_queue.put(None)
 
     def _finish(self, slot: int):
         req = self.slot_req[slot]
@@ -1486,7 +1555,7 @@ class Engine(EnginePrograms):
         self._release_slot_pages(slot)
         self.sched.release(slot)
         self.metrics.active_requests.set(len(self._active_slots()))
-        req.out_queue.put(None)  # sentinel: done
+        self._close_stream(req)
 
     # -- loop ---------------------------------------------------------------
 
@@ -1580,7 +1649,7 @@ class Engine(EnginePrograms):
             st["req"].finish_reason = "error"
             self.metrics.mark_request("error", 0.0)
             _flight.finish(st["req"].id, "error", ok=False, detail=reason)
-            st["req"].out_queue.put(None)
+            self._close_stream(st["req"])
         self._resume_ctx.clear()   # queued resumes are failed below
         for slot, r in enumerate(self.slot_req):
             if r is not None:
@@ -1593,7 +1662,7 @@ class Engine(EnginePrograms):
             r.finish_reason = "error"
             self.metrics.mark_request("error", 0.0)
             _flight.finish(r.id, "error", ok=False, detail=reason)
-            r.out_queue.put(None)
+            self._close_stream(r)
         # Drain the scheduler's cancelled-in-queue notifications so its queue
         # empties (the Request objects were already notified above). A request
         # submitted AFTER the failure may interleave here and surface as an

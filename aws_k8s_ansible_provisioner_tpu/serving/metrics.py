@@ -209,6 +209,14 @@ class EngineMetrics:
             "tpu_serve_queue_depth", "Requests waiting for a slot"))
         self.generated_tokens = r.register(Counter(
             "tpu_serve_generated_tokens_total", "Generated tokens"))
+        # one item = the tokens ONE dispatch produced for ONE stream = one
+        # wake-up of that stream's handler thread: generated_tokens_total /
+        # stream_items_total is the tokens a wake-up carries (the decode
+        # horizon under steady load, 1 where the horizon is 1)
+        self.stream_items = r.register(Counter(
+            "tpu_serve_stream_items_total",
+            "Queue items put to streamed requests (tokens of one dispatch "
+            "for one stream; one handler wake-up each)"))
         self.prompt_tokens = r.register(Counter(
             "tpu_serve_prompt_tokens_total", "Prompt tokens prefilled"))
         self.request_duration = r.register(Histogram(

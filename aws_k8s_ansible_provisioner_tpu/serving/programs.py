@@ -22,6 +22,7 @@ drain, streaming queues.
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import os
 import threading
@@ -1174,6 +1175,10 @@ class EnginePrograms:
         # slot -> scheduled-but-unsettled restore record (timing +
         # byte accounting; correctness rides XLA data dependencies)
         self._restore_pending: dict = {}
+        # (program, horizon) -> seconds the last such dispatch took on the
+        # device (_dispatch_close): how long the one in flight will take
+        # (Engine._await_arrival)
+        self._dispatch_s: dict = {}
         # per-slot global id of its group's scratch page (group 0's is 0,
         # preserving the single-device layout)
         self._scratch = np.repeat(
@@ -1354,10 +1359,25 @@ class EnginePrograms:
                 "ctx_max": int(lens.max()) if n else 0,
                 "first_use": False, **given, "t_enqueue": time.monotonic()}
 
+    @contextlib.contextmanager
+    def _emit_phase(self):
+        """An emit phase: the tokens a dispatch produced are recorded per
+        request (Engine._emit) and, on the way out, handed to each stream
+        as ONE queue item — so a handler thread is woken once a dispatch,
+        not once a token, and no token is held past the phase that
+        produced it (an exception inside it included)."""
+        with _phase(PH_EMIT):
+            try:
+                yield
+            finally:
+                self._flush_streams()
+
     def _dispatch_close(self, rec: dict, t_ready: float, *, batch: int = 1,
                         tokens: int = 1, ctx_rows: float = 0.0,
                         steps: int = 1, guided_rows: int = 0,
-                        tail: bool = True, emitted: int = 0) -> None:
+                        tail: bool = True, emitted: int = 0,
+                        puts: int = 0,
+                        t_wait: Optional[float] = None) -> None:
         """Blocking half, and the ONE site that feeds the sinks: the
         device-busy counters, devmon's window, one ``dispatch`` event on
         the flight ring and — if the server's tracer has an exporter at
@@ -1368,9 +1388,21 @@ class EnginePrograms:
         device_s = max(0.0, t_ready - max(rec["t_enqueue"],
                                           self._busy_watermark))
         self._busy_watermark = t_ready
+        if t_wait is not None and not rec["first_use"]:
+            # what the next such dispatch is expected to take (``t_wait``:
+            # when the host came to wait for this one). Still running then,
+            # t_ready is when it FINISHED and device_s is its time; already
+            # done (a host stall: another program's compile, say), it took
+            # at most this. first_use: its own compile would be in it.
+            key = rec["program"], rec.get("horizon")
+            if t_ready - t_wait > 0.1 * device_s:
+                self._dispatch_s[key] = device_s
+            elif key in self._dispatch_s:
+                self._dispatch_s[key] = min(self._dispatch_s[key], device_s)
         rec["t_ready"] = t_ready
         rec["tail"] = tail
         rec["emitted"] = emitted
+        rec["puts"] = puts
         self.metrics.device_busy_seconds.inc(device_s)
         if "moe_rows" in rec:
             m, prog = self.metrics, rec["program"]
@@ -1490,7 +1522,9 @@ class EnginePrograms:
                 # invariant "resumed slot => stale" independent of path
                 self.draft.mark_stale(slot)
         else:
+            # the first token is TTFT: an item of its own, put at once
             self._emit(slot, token, lp)
+            self._put_pending(req)
 
     @staticmethod
     def _host_prompt_lp(req: Request, plp, row: int, n_prompt: int) -> None:
@@ -1550,7 +1584,7 @@ class EnginePrograms:
         if self.draft is not None:
             self.draft.prefill(self, tokens, np.asarray([len(ids)], np.int32),
                                np.asarray([slot], np.int32))
-        with _phase(PH_EMIT):
+        with self._emit_phase():
             self._activate(req, slot, token, lp)
 
     def _do_prefill_batch(self, batch: List):
@@ -1641,7 +1675,7 @@ class EnginePrograms:
                              tokens=int(true_lens.sum()))
         if self.draft is not None:
             self.draft.prefill(self, tokens, true_lens, slots)
-        with _phase(PH_EMIT):
+        with self._emit_phase():
             for i, (req, slot) in enumerate(batch):
                 lp = _host_lp(lp_t, i, req.logprobs) \
                     if req.logprobs is not None else None
@@ -1717,7 +1751,7 @@ class EnginePrograms:
                                       time.monotonic() - req.t_submit)
             _flight.record("cancel_reap", req.id, phase="prefill_chunk")
             _flight.finish(req.id, "cancelled", ok=False)
-            req.out_queue.put(None)
+            self._close_stream(req)
             return
         if st["mixed"]:
             self._advance_chunk_mixed(st)
@@ -1771,7 +1805,7 @@ class EnginePrograms:
             self.sched.release(slot)
             req.finish_reason = "error"
             self.metrics.mark_request("error", 0.0)
-            req.out_queue.put(None)
+            self._close_stream(req)
             raise
         # closed at enqueue: the walk reads the sampled token only after
         # the final chunk (at _activate, below)
@@ -1787,7 +1821,7 @@ class EnginePrograms:
                 lp = _host_lp(lp_t, 0, req.logprobs) \
                     if req.logprobs is not None and lp_t is not None else None
                 token = int(token)  # device sync
-            with _phase(PH_EMIT):
+            with self._emit_phase():
                 self._activate(req, slot, token, lp, ids=list(ids),
                                resumed=st["resumed"])
 
@@ -1881,12 +1915,12 @@ class EnginePrograms:
             self.sched.release(slot)
             req.finish_reason = "error"
             self.metrics.mark_request("error", 0.0)
-            req.out_queue.put(None)
+            self._close_stream(req)
             raise
         lp = _host_lp(rec["chunk_lp_t"], 0, req.logprobs) \
             if rec["chunk_lp"] else None
         self._chunk = None
-        with _phase(PH_EMIT):
+        with self._emit_phase():
             self._activate(req, slot, rec["chunk_token"], lp, ids=list(ids),
                            resumed=st["resumed"])
 
@@ -2079,7 +2113,8 @@ class EnginePrograms:
             accepted = np.asarray(accepted)
         t_ready = time.monotonic()
         emitted = 0
-        with _phase(PH_EMIT):
+        puts0 = self.metrics.stream_items.total()
+        with self._emit_phase():
             for slot in active:
                 if slot in skip:
                     continue
@@ -2110,7 +2145,8 @@ class EnginePrograms:
                     self.draft.note_emitted(slot, slot_emitted)
         self._dispatch_close(
             drec, t_ready, batch=len(active), tokens=R * len(active),
-            ctx_rows=ctx_rows, emitted=emitted)
+            ctx_rows=ctx_rows, emitted=emitted,
+            puts=int(self.metrics.stream_items.total() - puts0))
         self._tok_times.append((t0, emitted))
         if len(self._tok_times) >= 2:
             span = time.monotonic() - self._tok_times[0][0]
@@ -2535,6 +2571,7 @@ class EnginePrograms:
                 ch.on_feature_path(self, kind="guided")
         out = rec["out"]
         lp_t = None
+        t_fetch = time.monotonic()
         with _phase(PH_FETCH):
             if rec["want_lp"]:
                 out, lp_t = out      # ([h, B], ([h,B], [h,B,K], [h,B,K]))
@@ -2570,9 +2607,10 @@ class EnginePrograms:
             if active else 0.0
         gset = rec["gset"]
         emitted = 0
+        puts0 = self.metrics.stream_items.total()
         mixed = bool(rec.get("mixed"))
         try:
-            with _phase(PH_EMIT):
+            with self._emit_phase():
                 for s in range(horizon):
                     for slot in active:
                         if self.slot_req[slot] is None:
@@ -2620,7 +2658,9 @@ class EnginePrograms:
                 batch=len(active) + (1 if mixed else 0),
                 tokens=horizon * len(active) + rec.get("chunk_n", 0),
                 ctx_rows=ctx_rows, steps=horizon,
-                guided_rows=len(rec["gslots"]), tail=tail, emitted=emitted)
+                guided_rows=len(rec["gslots"]), tail=tail, emitted=emitted,
+                puts=int(self.metrics.stream_items.total() - puts0),
+                t_wait=t_fetch)
         self._tok_times.append((rec["drec"]["t_enqueue"], emitted))
         if len(self._tok_times) >= 2:
             span = time.monotonic() - self._tok_times[0][0]

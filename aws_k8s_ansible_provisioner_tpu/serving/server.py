@@ -1267,9 +1267,19 @@ class Handler(BaseHTTPRequestHandler):
         self.send_header("Transfer-Encoding", "chunked")
         self.end_headers()
 
+        # Events gather here and leave with ONE write + flush per queue
+        # item (flush(), below): a logprobs stream sends a chunk a token,
+        # and each flush wakes the reader on the other end.
+        outbuf: List[bytes] = []
+
         def raw_write(data: bytes):
-            self.wfile.write(f"{len(data):x}\r\n".encode() + data + b"\r\n")
-            self.wfile.flush()
+            outbuf.append(f"{len(data):x}\r\n".encode() + data + b"\r\n")
+
+        def flush():
+            if outbuf:
+                self.wfile.write(b"".join(outbuf))
+                outbuf.clear()
+                self.wfile.flush()
 
         obj = "chat.completion.chunk" if chat else "text_completion"
         _sent = {"chunks": 0}
@@ -1310,7 +1320,9 @@ class Handler(BaseHTTPRequestHandler):
                 ch = _chaos.get()
                 if ch.enabled:
                     # kill_replica_after_chunks fault point: may RST the
-                    # connection and raise (unwound like a broken pipe)
+                    # connection and raise (unwound like a broken pipe) —
+                    # after the chunks counted so far have left
+                    flush()
                     ch.on_stream_chunk(self, _sent["chunks"])
 
         def consume_skip(s, text: str) -> str:
@@ -1374,31 +1386,41 @@ class Handler(BaseHTTPRequestHandler):
                     "top_logprobs": [dict(tops)], "text_offset": [off]}
 
         def drain(i: int, block_s: float) -> bool:
-            """Advance choice i by at most one queue item; emit any ready
-            text. Returns whether an item arrived."""
+            """Advance choice i by at most one queue item — the token ids
+            one engine dispatch produced for it, or None at the end — and
+            send what it makes ready in one write. Returns whether an item
+            arrived."""
             s = states[i]
             try:
                 item = s["req"].out_queue.get(timeout=block_s)
             except queue.Empty:
                 return False
             if lp_k is not None:
-                # Per-TOKEN chunks so the logprob arrays align with their
-                # token: each queue item emits one chunk carrying that
-                # token's text delta (possibly "" while a multi-byte
-                # sequence is incomplete) and its logprob record. Stop
-                # strings cut the accumulated text without holdback (the
-                # already-sent token entries stand — vLLM's streamed
-                # behavior has the same artifact).
-                if item is None:
-                    tail = s["detok"].finish()
-                    s["finish"] = s["req"].finish_reason or "stop"
-                    tail = consume_skip(s, s["carry"] + tail)
-                    s["carry"] = ""
-                    if tail:
-                        chunk(i, tail, None)
-                    chunk(i, None, s["finish"])
-                    return True
-                delta = s["detok"].push(item)
+                drain_lp(i, s, item)
+            else:
+                drain_text(i, s, item)
+            flush()
+            return True
+
+        def drain_lp(i: int, s: dict, item) -> None:
+            # Per-TOKEN chunks so the logprob arrays align with their
+            # token: each id emits one chunk carrying that token's text
+            # delta (possibly "" while a multi-byte sequence is
+            # incomplete) and its logprob record. Stop strings cut the
+            # accumulated text without holdback (the already-sent token
+            # entries stand — vLLM's streamed behavior has the same
+            # artifact).
+            if item is None:
+                tail = s["detok"].finish()
+                s["finish"] = s["req"].finish_reason or "stop"
+                tail = consume_skip(s, s["carry"] + tail)
+                s["carry"] = ""
+                if tail:
+                    chunk(i, tail, None)
+                chunk(i, None, s["finish"])
+                return
+            for tok in item:
+                delta = s["detok"].push(tok)
                 # windowed stop scan: only the region a NEW stop match could
                 # end in (delta + the longest stop's tail) — scanning the
                 # whole accumulated text would be O(len^2) per stream
@@ -1419,34 +1441,56 @@ class Handler(BaseHTTPRequestHandler):
                     # rides the first new token's chunk
                     delta, s["carry"] = s["carry"] + delta, ""
                 delta = consume_skip(s, delta)
-                chunk(i, delta, None, lp=token_lp(s, item, delta),
-                      tok_ids=[int(item)])
+                chunk(i, delta, None, lp=token_lp(s, tok, delta),
+                      tok_ids=[int(tok)])
                 if s["finish"]:
+                    # the rest of the item is past the cut: discarded
                     chunk(i, None, s["finish"])
-                return True
+                    return
+
+        def drain_text(i: int, s: dict, item) -> None:
+            # The ids pass the detokenizer and the stop scan ONE AT A TIME
+            # and stop at a cut, so text and token_ids end on the token
+            # they would end on alone; what that makes ready leaves as ONE
+            # chunk. An id never leaves ahead of its text: trailing ids
+            # whose bytes the detokenizer still holds (an incomplete
+            # multi-byte tail) wait in tok_pending for the chunk their text
+            # rides, as when each id was an item — the router's failover
+            # counts on it (ids it holds beyond the text would be replayed
+            # as done, and their text lost with the dead replica).
+            cut_text = None
+            n_ids = 0       # of tok_pending: up to the last id that gave text
             if item is None:
                 s["pending"] += s["detok"].finish()
                 s["finish"] = s["req"].finish_reason or "stop"
+                cut_text = _apply_stop_strings(s["pending"], stops)
             else:
-                s["pending"] += s["detok"].push(item)
-                s["tok_pending"].append(int(item))
-            cut_text = _apply_stop_strings(s["pending"], stops)
+                for tok in item:
+                    delta = s["detok"].push(tok)
+                    s["pending"] += delta
+                    s["tok_pending"].append(int(tok))
+                    cut_text = _apply_stop_strings(s["pending"], stops)
+                    if cut_text is not None:
+                        break        # the rest is past the cut: discarded
+                    if delta and len(s["pending"]) > hold:
+                        n_ids = len(s["tok_pending"])
             if cut_text is not None:
                 s["pending"], s["finish"] = cut_text, "stop"
                 st.engine.cancel(s["req"])  # free the slot; rest discarded
-            ready = s["pending"] if s["finish"] else (
-                s["pending"][:len(s["pending"]) - hold] if hold
-                else s["pending"])
+            if s["finish"]:
+                ready, n_ids = s["pending"], len(s["tok_pending"])
+            else:
+                ready = s["pending"][:len(s["pending"]) - hold] if hold \
+                    else s["pending"]
             if ready:
                 send = consume_skip(s, ready)
-                if send or s["tok_pending"]:
-                    chunk(i, send, None, tok_ids=s["tok_pending"])
-                    s["tok_pending"] = []
+                if send or n_ids:
+                    chunk(i, send, None, tok_ids=s["tok_pending"][:n_ids])
+                    del s["tok_pending"][:n_ids]
                 s["pending"] = s["pending"][len(ready):]
             if s["finish"]:
                 chunk(i, None, s["finish"], tok_ids=s["tok_pending"])
                 s["tok_pending"] = []
-            return True
 
         # No-progress backstop (r7): the configured deadline default, not a
         # hardcoded 600 — the engine reaps per-request deadlines and sends
@@ -1469,6 +1513,7 @@ class Handler(BaseHTTPRequestHandler):
                     # completions echo+stream: the prompt leads each
                     # choice's stream (vLLM's behavior)
                     chunk(i, echo_text, None)
+            flush()
             last_progress = time.monotonic()
             while any(s["finish"] is None for s in states):
                 progressed = False
@@ -1526,8 +1571,8 @@ class Handler(BaseHTTPRequestHandler):
                     final["failover"] = True
                 raw_write(("data: " + json.dumps(final) + "\n\n").encode())
             raw_write(b"data: [DONE]\n\n")
-            self.wfile.write(b"0\r\n\r\n")
-            self.wfile.flush()
+            outbuf.append(b"0\r\n\r\n")
+            flush()
         except (BrokenPipeError, ConnectionResetError):
             for s in states:
                 st.engine.cancel(s["req"])

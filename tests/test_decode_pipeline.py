@@ -56,6 +56,8 @@ from aws_k8s_ansible_provisioner_tpu.serving.guided import grammar_for
 from aws_k8s_ansible_provisioner_tpu.serving.server import build_state, serve
 from aws_k8s_ansible_provisioner_tpu.utils.tokenizer import ByteTokenizer
 
+from test_engine import _queue_items
+
 pytestmark = pytest.mark.pipeline_smoke
 
 MODEL = "tiny-qwen3"
@@ -546,6 +548,203 @@ def test_ragged_dispatch_error_drops_dispatch_keeps_serving(model):
     finally:
         stop.set()
         t.join(timeout=10)
+
+
+# -- a stream gets one queue item a dispatch (ISSUE 31) ----------------------
+
+
+def _plain_streams(model, eng):
+    return [eng.submit(Request(prompt_ids=[5, 9, 2 + i], max_tokens=21,
+                               temperature=0.9, seed=42 + i, ignore_eos=True,
+                               stream=True)) for i in range(2)], None
+
+
+def _mixed_streams(model, eng):
+    first = eng.submit(Request(prompt_ids=[5, 9, 2], max_tokens=60,
+                               temperature=0.9, seed=42, ignore_eos=True,
+                               stream=True))
+
+    def late():     # a chunked admission under the live stream: mixed_step
+        return eng.submit(Request(prompt_ids=list(_LONG_A), max_tokens=12,
+                                  temperature=0.9, seed=7, ignore_eos=True,
+                                  stream=True))
+    return [first], late
+
+
+def _spec_streams(model, eng):
+    tok = model[0]
+    return [eng.submit(Request(prompt_ids=tok.encode("ab" * 8),
+                               max_tokens=40, temperature=0.0,
+                               ignore_eos=True, stream=True)),
+            eng.submit(Request(stream=True, **SEEDED))], None
+
+
+def _guided_streams(model, eng):
+    tok = model[0]
+    g = grammar_for(tok, {"type": "json_object"}, [tok.eos_token_id])
+    return [eng.submit(Request(prompt_ids=[5, 9, 2], max_tokens=40,
+                               temperature=0.9, seed=42, ignore_eos=True,
+                               stream=True)),
+            eng.generate(tok.encode("json:"), guided=g, max_tokens=60,
+                         temperature=0.0, logit_bias=_PRESSURE,
+                         stream=True)], None
+
+
+_ITEM_KINDS = {
+    "plain": (dict(decode_pipeline=1), _plain_streams, "decode_steps"),
+    "mixed": (dict(decode_pipeline=1, ragged_attention=1, prefill_chunk=32,
+                   max_cache_len=256), _mixed_streams, "mixed_step"),
+    "spec": (dict(decode_pipeline=1, spec_decode=True, spec_k=4,
+                  spec_ngram=3), _spec_streams, "spec_decode_step"),
+    "guided": (dict(decode_pipeline=1), _guided_streams, "decode_steps"),
+}
+
+
+@pytest.mark.parametrize("horizon", [1, 8])
+@pytest.mark.parametrize("kind", list(_ITEM_KINDS))
+def test_stream_items_one_a_dispatch_and_concatenate(model, kind, horizon):
+    """The unit the engine hands a stream is what ONE dispatch produced for
+    it: the items of a stream concatenate to ``generated``, the activation
+    token is an item of its own and in the queue when its step returns, no
+    token waits for a later step, and a dispatch's record counts one put
+    for each stream it gave tokens (``puts``, beside ``emitted``)."""
+    over, traffic, program = _ITEM_KINDS[kind]
+    if kind == "spec" and horizon == 1:
+        program = "decode_steps"    # the verify path needs a horizon above 1
+    eng = _engine(model, decode_horizon=horizon, **over)
+    closed = []                     # (record, streams given tokens, tokens)
+    reqs = []
+
+    def spy(name):
+        real = getattr(eng, name)
+        real_close = eng._dispatch_close
+
+        def wrapped(*a, **kw):
+            got = {}
+            eng._dispatch_close = lambda rec, *ca, **ckw: (
+                got.update(rec=rec), real_close(rec, *ca, **ckw))[1]
+            before = {r.id: len(r.generated) for r in reqs}
+            try:
+                return real(*a, **kw)
+            finally:
+                eng._dispatch_close = real_close
+                grew = [len(r.generated) - before[r.id] for r in reqs
+                        if r.id in before]
+                closed.append((got["rec"], sum(1 for g in grew if g),
+                               sum(grew)))
+        setattr(eng, name, wrapped)
+
+    spy("_decode_fetch")
+    spy("_do_spec_decode")
+    first_seen = {}
+    streams, late = traffic(model, eng)
+    reqs.extend(streams)
+    for n in range(20000):
+        if late is not None and n == 6:
+            assert eng._inflight is not None    # or nothing rides mixed_step
+            reqs.append(late())
+        did = eng.step()
+        for r in reqs:
+            assert r.pending == [], "a token waits for a later step()"
+            if r.t_first_token and r.id not in first_seen:
+                # put at once: alone, ahead of everything else
+                first_seen[r.id] = list(r.out_queue.queue)[0]
+        if not did:
+            break
+    assert len(first_seen) == len(reqs)
+    for r in reqs:
+        items, nones = _queue_items(r)
+        assert nones == 1
+        assert [t for it in items for t in it] == r.generated
+        assert items[0] == [r.generated[0]] == first_seen[r.id]
+        assert all(it for it in items)
+        if kind != "spec":
+            assert all(len(it) <= horizon for it in items)
+    # the records: emitted and puts are what the dispatch did, counted here
+    # from the requests themselves
+    assert any(rec["program"] == program for rec, _, _ in closed), \
+        [rec["program"] for rec, _, _ in closed]
+    for rec, n_streams, n_tokens in closed:
+        assert rec["emitted"] == n_tokens
+        assert rec["puts"] == n_streams
+    if horizon == 8 and kind == "plain":
+        # one item a stream a dispatch, 8 ids each
+        assert any(rec["puts"] == 2 and rec["emitted"] == 16
+                   for rec, _, _ in closed)
+    if horizon == 1 and kind != "spec":
+        assert all(rec["emitted"] == rec["puts"] for rec, _, _ in closed)
+    total = sum(rec["puts"] for rec, _, _ in closed) + len(reqs)
+    assert eng.metrics.stream_items.total() == total
+    _assert_released(eng)
+
+
+class _ArrivalEvent:
+    """Stands in for the engine's work event: ``wait`` records the timeout
+    it was given and returns at once, after letting ``on_wait`` happen (an
+    arrival DURING the wait) — so the test runs on events, not on a clock."""
+
+    def __init__(self, real):
+        self.real, self.waits, self.on_wait = real, [], None
+
+    def wait(self, timeout=None):
+        self.waits.append(timeout)
+        if self.on_wait is not None:
+            arrive, self.on_wait = self.on_wait, None
+            arrive()
+        return True
+
+    def __getattr__(self, name):        # set / clear / is_set
+        return getattr(self.real, name)
+
+
+def test_next_dispatch_is_bound_late_for_an_arrival(model):
+    """With a dispatch running, a slot free and nobody queued, the step
+    gives an arrival the first half of the running dispatch's expected time
+    before it enqueues what comes next — so a request that shows up a
+    moment after a finish rides the NEXT dispatch (mixed_step) and not the
+    one after it. A full batch, a waiting request or an idle device wait
+    for nothing."""
+    eng = _engine(model, decode_horizon=8, decode_pipeline=1,
+                  ragged_attention=1, prefill_chunk=32, max_cache_len=256)
+    first = eng.submit(Request(prompt_ids=[5, 9, 2], max_tokens=100,
+                               ignore_eos=True))
+    for _ in range(5):
+        eng.step()
+    assert eng._inflight is not None
+    key = ("decode_steps", eng._inflight["horizon"])
+    # what was measured so far has no compile in it (a loaded host may not
+    # have caught a dispatch still running yet: then nothing is measured)
+    assert all(0 < v < 5.0 for v in eng._dispatch_s.values())
+    ev = eng._work_event = _ArrivalEvent(eng._work_event)
+
+    def step(expect=4.0):
+        eng._dispatch_s[key] = expect         # as if a dispatch took so long
+        eng._busy_watermark = time.monotonic()   # ... and has just begun
+        before = len(ev.waits)
+        eng.step()
+        return ev.waits[before:]
+
+    late = Request(prompt_ids=list(_LONG_B), max_tokens=4, ignore_eos=True)
+    ev.on_wait = lambda: eng.submit(late)
+    (timeout,) = step()                       # ONE wait: half of 4 s at most
+    assert 0 < timeout <= 2.0
+    assert eng._chunk is not None and eng._chunk["req"] is late \
+        and eng._chunk["mixed"], "admitted in the step that waited for it"
+    while eng._chunk is not None:             # the walk: steps wait for nothing
+        assert step() == []
+    # both slots busy: nothing could be admitted, so nothing is waited for
+    assert eng.sched.stats().active_slots == 2
+    assert step() == []
+    _drain(eng)
+    assert len(first.generated) == 100 and len(late.generated) == 4
+    # an expectation that was too high comes down by itself: a dispatch that
+    # had finished before the host looked took at most that long
+    assert eng._dispatch_s[key] < 4.0
+    # nothing in flight: an idle device is never made to wait
+    eng.submit(Request(prompt_ids=[7, 7], max_tokens=2, ignore_eos=True))
+    assert eng._inflight is None and step() == []
+    _drain(eng)
+    _assert_released(eng)
 
 
 # -- feature paths ride the ragged pipeline (ISSUE 16) -----------------------

@@ -172,9 +172,119 @@ def test_streaming_and_wait(setup):
         item = req.out_queue.get_nowait()
         if item is None:
             break
-        streamed.append(item)
+        streamed.extend(item)       # an item: one dispatch's ids
     assert streamed == req.generated
     assert len(streamed) == 4
+
+
+def _queue_items(req):
+    """Everything in a stream's queue: (items ahead of the sentinel, number
+    of sentinels). Nothing may follow a sentinel."""
+    import queue
+
+    items, nones = [], 0
+    while True:
+        try:
+            it = req.out_queue.get_nowait()
+        except queue.Empty:
+            return items, nones
+        if it is None:
+            nones += 1
+        else:
+            assert nones == 0, "an item after the sentinel"
+            items.append(it)
+
+
+def _end_by_length(engine, req):
+    run_engine(engine, [])
+
+
+def _end_by_cancel(engine, req):
+    engine.cancel(req)
+    run_engine(engine, [])
+
+
+def _end_by_deadline(engine, req):
+    import time
+
+    req.t_deadline = time.monotonic() - 1.0
+    run_engine(engine, [])
+
+
+def _end_by_fail_all(engine, req):
+    engine._fail_all("boom")
+
+
+def _end_by_error_inside_the_emit_loop(engine, req):
+    """A step that dies with tokens of its dispatch already recorded: they
+    reach the stream before the sentinel run_forever's failover puts."""
+    real, calls = engine._emit, {"n": 0}
+
+    def emit(slot, token, lp=None):
+        calls["n"] += 1
+        if calls["n"] == 3:
+            raise RuntimeError("boom")
+        return real(slot, token, lp)
+
+    engine._emit = emit
+    with pytest.raises(RuntimeError):
+        for _ in range(100):
+            engine.step()
+    assert req.pending == []        # flushed on the way out of the phase
+    engine._fail_all("boom")
+
+
+@pytest.mark.parametrize("horizon", [1, 8])
+@pytest.mark.parametrize("end,reason", [
+    (_end_by_length, "length"), (_end_by_cancel, "cancelled"),
+    (_end_by_deadline, "timeout"), (_end_by_fail_all, "error"),
+    (_end_by_error_inside_the_emit_loop, "error")],
+    ids=["length", "cancel", "deadline", "fail_all", "emit_error"])
+def test_stream_gets_its_tokens_then_one_sentinel(setup, end, reason,
+                                                  horizon):
+    """A stream's queue carries ITEMS — the ids one dispatch gave it — and
+    ends, whatever ends the request, with every generated token delivered
+    and exactly one sentinel behind them."""
+    cfg, params, serving = setup
+    engine = Engine(cfg, params, dataclasses.replace(
+        serving, decode_horizon=horizon))
+    req = Request(prompt_ids=[5, 6, 7], max_tokens=21, ignore_eos=True,
+                  stream=True)
+    engine.submit(req)
+    assert engine.step()                    # the prefill, and activation
+    # the first token is an item of its own, in the queue when the step
+    # that produced it returns
+    assert list(req.out_queue.queue) == [[req.generated[0]]]
+    # the pipeline fetches a dispatch a step after it was enqueued: two
+    # horizons are in the queue and a third is in flight when the end comes
+    for _ in range(3):
+        engine.step()
+        assert req.pending == []            # nothing waits for a later step
+    end(engine, req)
+    assert req.finish_reason == reason
+    items, nones = _queue_items(req)
+    assert nones == 1
+    assert [t for it in items for t in it] == req.generated
+    assert req.pending == []
+    assert items[0] == [req.generated[0]]
+    assert all(1 <= len(it) <= horizon for it in items)
+    if horizon == 8:
+        # a dispatch of 8 substeps puts ONE item of 8 ids
+        assert max(len(it) for it in items) == 8
+    assert engine.metrics.stream_items.total() == len(items)
+    assert all(r is None for r in engine.slot_req)
+
+
+def test_close_stream_puts_what_is_pending_first(setup):
+    """The one way a queue ends (_close_stream: cancel, deadline, _fail_all
+    and the chunk-error arms all go through it): pending ids, then None."""
+    cfg, params, serving = setup
+    engine = Engine(cfg, params, serving)
+    req = Request(prompt_ids=[5, 6, 7], stream=True)
+    req.pending = [11, 12]
+    engine._close_stream(req)
+    assert list(req.out_queue.queue) == [[11, 12], None]
+    assert req.pending == []
 
 
 def test_sampling_reproducible_and_bounded(setup):
@@ -256,7 +366,8 @@ def test_cancel_frees_slot(setup):
         it = req.out_queue.get_nowait()
         if it is None:
             break
-        items.append(it)
+        items.extend(it)
+    assert items == req.generated
 
 
 def test_engine_error_fails_requests_not_loop(setup):

@@ -187,6 +187,8 @@ def test_every_dispatch_leaves_one_record(model, serving_kw, traffic,
                                             range(first, last + 1)})
         if "prompt_tokens" in e and "padded_tokens" in e:
             assert e["prompt_tokens"] <= e["padded_tokens"]
+        # nobody streams here: tokens are emitted and no queue item is put
+        assert e["puts"] == 0 <= e["emitted"]
     # the kind under test dispatched, and devmon booked it under the same
     # kind with the token count it booked before this PR
     mine = [e for e in events if e["program"] == program]
@@ -198,6 +200,52 @@ def test_every_dispatch_leaves_one_record(model, serving_kw, traffic,
     # span ids come from the record, never from the tracer's generator
     assert all(s.context.span_id == format(s.attributes["seq"], "016x")
                for s in spans)
+
+
+@pytest.mark.parametrize("horizon", [1, 4])
+def test_record_counts_puts_and_the_counter_counts_items(model, horizon):
+    """``puts`` on the dispatch record (beside ``emitted``) and
+    tpu_serve_stream_items_total (beside tpu_serve_generated_tokens_total):
+    a stream is handed ONE queue item for what a dispatch gave it, so the
+    tokens a handler wake-up carries are the horizon, and 1 at horizon 1."""
+    _, cfg, params = model
+    eng = Engine(cfg, params, _serving(decode_horizon=horizon))
+    rec = _Recorder()
+    tracer = tracing.Tracer("tpu-serve-engine", exporter=rec)
+    eng.tracer_source = lambda: tracer
+    # the activation's token, then three fused horizons
+    reqs = [Request(prompt_ids=[3 + i, 9, 11], max_tokens=1 + horizon * 3,
+                    ignore_eos=True, stream=True) for i in range(3)]
+    for r in reqs:
+        eng.submit(r)
+    _drain(eng)
+    events = [e for e in _flight.get().tail(4096) if e["type"] == "dispatch"]
+    spans = [s.attributes for s, _ in rec.items]
+    assert [(s["seq"], s["puts"], s["emitted"]) for s in spans] == \
+        [(e["seq"], e["puts"], e["emitted"]) for e in events]
+    decodes = [e for e in events if e["program"] == "decode_steps"
+               and e["emitted"]]
+    assert decodes
+    for e in decodes:
+        # every live stream got its horizon of tokens as one item
+        assert e["puts"] == e["active"] == 3
+        assert e["emitted"] == e["puts"] * e["horizon"]
+    assert [e["horizon"] for e in decodes] == [horizon] * 3
+    # an activation's item is put outside any record (a prefill's record
+    # closes before its emit phase, as ``emitted`` 0 there always said)
+    assert all(e["puts"] == 0 for e in events
+               if e["program"].startswith("prefill"))
+    items = eng.metrics.stream_items.total()
+    assert items == len(reqs) + sum(e["puts"] for e in events)
+    tokens = eng.metrics.generated_tokens.total()
+    assert tokens == sum(len(r.generated) for r in reqs) \
+        == 3 * (1 + horizon * 3)
+    # tokens a wake-up carries, past the one-token item a stream starts
+    # with: the horizon
+    assert (tokens - len(reqs)) / (items - len(reqs)) == horizon
+    text = eng.metrics.registry.render()
+    assert f"tpu_serve_stream_items_total {float(items)}" in text \
+        or f"tpu_serve_stream_items_total {int(items)}" in text
 
 
 def test_exporter_installed_after_start_gets_spans_and_removed_gets_none(

@@ -231,7 +231,13 @@ def _collect_stream(rurl, payload):
     return ids, text, fin, done
 
 
-def test_replica_kill_mid_stream_failover_is_byte_identical():
+# a chunk carries what ONE dispatch gave the stream (horizon 8 here; 20
+# tokens = the first token's chunk, then 8, 8 and 3): the replica dies behind
+# the first token alone, behind 2-9 tokens, or behind 10-17 of the 20
+@pytest.mark.parametrize("after_chunks,ports", [
+    (1, (18244, 18245)), (2, (18240, 18241)), (3, (18246, 18247))])
+def test_replica_kill_mid_stream_failover_is_byte_identical(after_chunks,
+                                                            ports):
     """The ROADMAP's replica-kill-mid-stream-under-load scenario: kill a
     replica after K streamed chunks while concurrent seeded streams run
     through the router. EVERY client stream must complete with token ids
@@ -244,7 +250,7 @@ def test_replica_kill_mid_stream_failover_is_byte_identical():
 
     from aws_k8s_ansible_provisioner_tpu.serving import chaos
 
-    router, engines, teardown = _fresh_stack((18240, 18241))
+    router, engines, teardown = _fresh_stack(ports)
     rurl = f"http://127.0.0.1:{router.server_port}"
     N = 4
 
@@ -271,7 +277,7 @@ def test_replica_kill_mid_stream_failover_is_byte_identical():
             assert len(ref[i][0]) == 20 and ref[i][3], ref[i]
 
         chaos.reset()
-        chaos.kill_replica_after_chunks(5, times=1)
+        chaos.kill_replica_after_chunks(after_chunks, times=1)
         got = {}
         run_all(got)
         assert chaos.get().stats()["kill_stream"]["fired"] == 1
@@ -286,6 +292,48 @@ def test_replica_kill_mid_stream_failover_is_byte_identical():
         for state, _ in engines:
             st = state.engine.sched.stats()
             assert st.active_slots == 0 and st.queue_depth == 0, st
+    finally:
+        chaos.reset()
+        teardown()
+
+
+def test_replica_kill_behind_a_held_multibyte_tail_loses_no_text():
+    """The replica dies right after the LAST item's chunk, whose final token
+    is the lead byte of a character that never completes: the detokenizer
+    holds that byte until finish(), so its id must not have left with the
+    chunk — a router that held all ``max_tokens`` ids would be answered
+    with a bare finish chunk and the held text would die with the replica.
+    The continuation decodes that one token again and the stream ends on
+    the same replacement character as the undisturbed one."""
+    from aws_k8s_ansible_provisioner_tpu.serving import chaos
+
+    router, engines, teardown = _fresh_stack((18248, 18249))
+    rurl = f"http://127.0.0.1:{router.server_port}"
+    try:
+        # tokens drawn from "a" and the lead byte of "\u00e9"; 20 tokens
+        # are the first token's chunk, then items of 8, 8 and 3. Wanted: a
+        # stream that ends "a", lead byte, with text in every item
+        for seed in range(200):
+            payload = {"model": MODEL_NAME, "prompt": "held tail",
+                       "max_tokens": 20, "stream": True, "seed": seed,
+                       "temperature": 1.0, "ignore_eos": True,
+                       "logit_bias": {"97": 100, "195": 100}}
+            ref = _collect_stream(rurl, payload)
+            ids = ref[0]
+            if ids[-2:] == [97, 195] and 97 in ids[1:9] and 97 in ids[9:17]:
+                break
+        else:
+            pytest.fail("no seed in 200 ends on a held lead byte")
+        assert len(ids) == 20 and ref[1].endswith("a\ufffd") and ref[3], ref
+
+        chaos.reset()
+        chaos.kill_replica_after_chunks(4, times=1)
+        got = _collect_stream(rurl, payload)
+        assert chaos.get().stats()["kill_stream"]["fired"] == 1
+        assert RouterHandler.metrics.stream_failovers.total() == 1
+        assert got[0] == ids, "token ids diverged across the failover"
+        assert got[1] == ref[1], "the held tail was lost with the replica"
+        assert got[2] == ref[2] == "length" and got[3]
     finally:
         chaos.reset()
         teardown()

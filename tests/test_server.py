@@ -13,6 +13,7 @@ import urllib.request
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from aws_k8s_ansible_provisioner_tpu.config import ServingConfig, tiny_qwen3
@@ -24,23 +25,28 @@ from aws_k8s_ansible_provisioner_tpu.utils.tokenizer import ByteTokenizer
 MODEL_NAME = "tiny-qwen3"
 
 
-@pytest.fixture(scope="module")
-def server():
+def _serve_tiny(port, **serving_over):
     tok = ByteTokenizer()
     cfg = tiny_qwen3(vocab_size=tok.vocab_size, eos_token_id=tok.eos_token_id)
     params = init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
     serving = ServingConfig(weights_dtype="bf16", model=MODEL_NAME, max_decode_slots=4,
                             max_cache_len=128,
-                            prefill_buckets=(16, 32, 64), dtype="float32")
+                            prefill_buckets=(16, 32, 64), dtype="float32",
+                            **serving_over)
     state = build_state(serving, model_cfg=cfg, params=params, tokenizer=tok)
     ready, stop = threading.Event(), threading.Event()
     t = threading.Thread(target=serve,
-                         args=(state, "127.0.0.1", 18123, ready, stop),
+                         args=(state, "127.0.0.1", port, ready, stop),
                          daemon=True)
     t.start()
     assert ready.wait(10)
-    yield "http://127.0.0.1:18123"
+    yield f"http://127.0.0.1:{port}"
     stop.set()
+
+
+@pytest.fixture(scope="module")
+def server():
+    yield from _serve_tiny(18123)
 
 
 def _get(url):
@@ -582,3 +588,170 @@ def test_repetition_penalty_param(server):
         _post(server + "/v1/completions",
               {"model": MODEL_NAME, "prompt": "a", "repetition_penalty": 0})
     assert ei.value.code == 400
+
+
+# -- a stream chunk carries what one dispatch gave the stream (ISSUE 31) -----
+
+
+@pytest.fixture(scope="module")
+def per_token_server():
+    """The same server at decode_horizon 1: every dispatch gives a stream
+    one token, so every queue item and every chunk is one token — the
+    stream as it was when the engine put a queue item a token."""
+    yield from _serve_tiny(18124, decode_horizon=1)
+
+
+def _sse(url, payload, path="/v1/completions"):
+    """A streamed request as its client sees it, per choice index: joined
+    text, joined token_ids, finish_reason, the ids of each content chunk
+    and each chunk's logprobs entry."""
+    req = urllib.request.Request(
+        url + path, data=json.dumps(dict(payload, stream=True)).encode(),
+        headers={"Content-Type": "application/json"})
+    with urllib.request.urlopen(req, timeout=120) as r:
+        raw = r.read().decode()
+    lines = [ln[len("data: "):] for ln in raw.splitlines()
+             if ln.startswith("data: ")]
+    assert lines[-1] == "[DONE]" and lines.count("[DONE]") == 1
+    out = {}
+    for ev in map(json.loads, lines[:-1]):
+        for ch in ev["choices"]:
+            c = out.setdefault(ch["index"], {"text": "", "ids": [],
+                                             "finish": None, "chunks": [],
+                                             "lp": [], "sent": []})
+            c["text"] += ch.get("text") or (ch.get("delta") or {}).get(
+                "content") or ""
+            if ch.get("token_ids"):
+                c["ids"] += ch["token_ids"]
+                c["chunks"].append(ch["token_ids"])
+            if ch.get("logprobs"):
+                c["lp"].append(ch["logprobs"])
+            if ch.get("finish_reason"):
+                assert c["finish"] is None
+                c["finish"] = ch["finish_reason"]
+            c["sent"].append((len(c["ids"]), c["text"]))
+    return out
+
+
+def _same_stream(a, b):
+    return (a["text"], a["ids"], a["finish"]) == \
+        (b["text"], b["ids"], b["finish"])
+
+
+@pytest.mark.parametrize("sampling", [
+    {"temperature": 0.0}, {"temperature": 0.8, "seed": 11},
+    {"temperature": 1.0, "seed": 12, "top_p": 0.9}],
+    ids=["greedy", "seeded", "seeded-top-p"])
+def test_stream_equals_nonstream_and_the_per_token_stream(
+        server, per_token_server, sampling):
+    """Fewer events, the same stream: joined text and token_ids of a stream
+    whose chunks carry a dispatch's tokens equal the non-stream response's
+    and the one-token-a-chunk stream's, for the same seed."""
+    body = {"model": MODEL_NAME, "prompt": "same stream", "max_tokens": 21,
+            "ignore_eos": True, **sampling}
+    _, full = _post(server + "/v1/completions", body)
+    got = _sse(server, body)[0]
+    ref = _sse(per_token_server, body)[0]
+    assert got["text"] == full["choices"][0]["text"]
+    assert got["finish"] == full["choices"][0]["finish_reason"] == "length"
+    assert len(got["ids"]) == full["usage"]["completion_tokens"] == 21
+    assert _same_stream(got, ref)
+    # the first token alone (TTFT), then a chunk an item (8, 8 and 4 ids; the
+    # ids of a multi-byte tail an item ends in ride the next chunk) and at
+    # most one more for what finish() flushes
+    assert got["chunks"][0] == got["ids"][:1]
+    assert max(len(c) for c in got["chunks"]) >= 7
+    assert len(got["chunks"]) <= 5 < len(ref["chunks"])
+
+
+@pytest.mark.parametrize("at", [2, 5, 9, 12, 15])
+def test_stop_string_inside_an_item_cuts_where_the_per_token_stream_cuts(
+        server, per_token_server, at):
+    """A stop string matched in the middle of a multi-token item: the ids
+    are scanned one at a time, so text and token_ids end on the token they
+    end on when every item is one token."""
+    # lower-case letters only, drawn from the seed: 24 one-byte tokens of
+    # text that does not repeat the way the tiny model's greedy output does
+    body = {"model": MODEL_NAME, "prompt": "deterministic",
+            "max_tokens": 24, "ignore_eos": True, "temperature": 1.0,
+            "seed": 3,
+            "logit_bias": {str(c): 100 for c in range(97, 123)}}
+    _, full = _post(server + "/v1/completions", body)
+    text = full["choices"][0]["text"]
+    assert len(text) == 24
+    stop = text[at:at + 2]
+    assert text.find(stop) >= 1
+    body["stop"] = [stop]
+    got = _sse(server, body)[0]
+    ref = _sse(per_token_server, body)[0]
+    assert got["text"] == text[:text.find(stop)]
+    assert got["finish"] == "stop"
+    assert _same_stream(got, ref)
+    assert len(got["ids"]) < 24         # the rest of the item was dropped
+
+
+@pytest.mark.parametrize("seed", [21, 22, 23])
+def test_no_token_id_leaves_ahead_of_its_text(server, per_token_server,
+                                              seed):
+    """Items that end inside a multi-byte character (tokens drawn from "a"
+    and the two bytes of "\u00e9", in any order): an id whose bytes the
+    detokenizer still holds stays back with them, so after EVERY event the
+    ids a client holds decode to exactly the text it holds — what the
+    router's failover continuation counts on (ids beyond the text would be
+    replayed as already answered, and their text lost)."""
+    tok = ByteTokenizer()
+    body = {"model": MODEL_NAME, "prompt": "bytes", "max_tokens": 40,
+            "ignore_eos": True, "temperature": 1.0, "seed": seed,
+            "logit_bias": {"97": 100, "195": 100, "169": 100}}
+    _, full = _post(server + "/v1/completions", body)
+    got = _sse(server, body)[0]
+    ref = _sse(per_token_server, body)[0]
+    assert set(got["ids"]) == {97, 195, 169}
+    assert got["text"] == full["choices"][0]["text"]
+    assert _same_stream(got, ref)
+    for stream in (got, ref):
+        for n_ids, text in stream["sent"]:
+            assert tok.decode(stream["ids"][:n_ids]) == text
+    # not vacuous: some multi-token item ended on held bytes, and its chunk
+    # kept those ids back
+    ends = np.cumsum([1] + [8] * 5)[:len(got["chunks"])]
+    assert any(tok.decode(got["ids"][:e]) != tok.decode(got["ids"][:e]
+                                                        ).rstrip("\ufffd")
+               for e in ends[1:-1])
+    assert len(got["chunks"]) < len(ref["chunks"])
+
+
+def test_streaming_logprobs_one_aligned_entry_a_token(server,
+                                                      per_token_server):
+    """A logprobs stream keeps one chunk a token (its arrays align per
+    token) whatever the item held; only the writes are gathered."""
+    body = {"model": MODEL_NAME, "prompt": "abc", "max_tokens": 13,
+            "ignore_eos": True, "logprobs": 2, "temperature": 0.7,
+            "seed": 3}
+    got = _sse(server, body)[0]
+    ref = _sse(per_token_server, body)[0]
+    assert len(got["lp"]) == len(got["chunks"]) == len(got["ids"]) == 13
+    assert all(len(c) == 1 for c in got["chunks"])
+    for lp in got["lp"]:
+        assert len(lp["tokens"]) == len(lp["token_logprobs"]) == 1
+    assert _same_stream(got, ref)
+    assert [lp["tokens"] for lp in got["lp"]] == \
+        [lp["tokens"] for lp in ref["lp"]]
+    assert [lp["text_offset"] for lp in got["lp"]] == \
+        [lp["text_offset"] for lp in ref["lp"]]
+    np.testing.assert_allclose(
+        [lp["token_logprobs"][0] for lp in got["lp"]],
+        [lp["token_logprobs"][0] for lp in ref["lp"]], atol=1e-4)
+
+
+def test_streaming_n_choices_equal_the_per_token_stream(server,
+                                                        per_token_server):
+    body = {"model": MODEL_NAME, "prompt": "abc", "max_tokens": 12, "n": 2,
+            "ignore_eos": True, "temperature": 0.8, "seed": 5}
+    got = _sse(server, body)
+    ref = _sse(per_token_server, body)
+    assert set(got) == set(ref) == {0, 1}
+    for i in (0, 1):
+        assert _same_stream(got[i], ref[i])
+        assert len(got[i]["ids"]) == 12
+    assert got[0]["ids"] != got[1]["ids"]       # seed, seed + 1
