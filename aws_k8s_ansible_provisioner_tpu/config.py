@@ -114,11 +114,87 @@ class ModelConfig:
     # tokens past an expert's capacity fall back to the residual stream).
     moe_impl: str = "ragged"
     moe_capacity_factor: float = 2.0
+    # Router scoring: "softmax" over all experts (Qwen3-MoE, OLMoE) or
+    # "sigmoid" per expert with a per-expert SELECTION bias (the top-k are
+    # chosen by score + bias, weighted by the scores alone — the
+    # glm4_moe / solar_open router).
+    router_scoring: str = "softmax"
+    # A chip's SHARE of an expert-parallel layer: the router keeps its
+    # published width ``n_routed_experts`` (0 = ``num_experts``), the stacks
+    # hold the ``num_experts`` experts with ids [expert_offset,
+    # expert_offset + num_experts); a chosen expert held elsewhere adds
+    # nothing here (ops/moe.py).
+    n_routed_experts: int = 0
+    expert_offset: int = 0
+    # Shared experts: a dense SwiGLU of width n_shared_experts x
+    # moe_intermediate_size every token passes, added to the routed sum.
+    n_shared_experts: int = 0
+    # Per-layer block kinds: one character a layer of a PERIOD that repeats
+    # over the depth — "g" softmax attention (the GQA block), "k" KDA linear
+    # attention (ops/linear_attention.py). "" = every layer the one kind
+    # the fields above describe. A string, not a tuple of enums: the config
+    # is a jit static argument and is built from JSON by the benchmark.
+    layer_pattern: str = ""
+    # "g" layers: the attention output is gated elementwise by
+    # sigmoid(n . Wg) before the output projection.
+    attn_output_gate: bool = False
+    # "k" layers (Kimi Delta Attention, arXiv:2510.26692): heads of
+    # kda_head_dim (d_k = d_v), a short depthwise causal convolution on
+    # q/k/v (linear_attention.CONV_TAPS taps), low-rank decay and gate
+    # projections.
+    kda_num_heads: int = 0
+    kda_head_dim: int = 0
+    kda_low_rank: int = 0
     hf_repo: str = ""
+
+    def __post_init__(self):
+        pat = self.layer_pattern
+        if pat:
+            if set(pat) - set("gk") or pat.count("g") != 1:
+                raise ValueError(
+                    f"layer_pattern={pat!r}: one 'g' (attention) layer and "
+                    f"any number of 'k' (KDA) layers a period")
+            if self.num_layers % len(pat):
+                raise ValueError(f"num_layers={self.num_layers} is not a "
+                                 f"whole number of periods {pat!r}")
+            if "k" in pat and not (self.kda_num_heads and self.kda_head_dim):
+                raise ValueError("a 'k' layer needs kda_num_heads and "
+                                 "kda_head_dim")
 
     @property
     def gated_mlp(self) -> bool:
         return self.act in ("silu", "gelu_tanh")
+
+    @property
+    def recurrent(self) -> bool:
+        """Some layers keep a recurrent state per sequence beside K/V."""
+        return "k" in self.layer_pattern
+
+    @property
+    def num_periods(self) -> int:
+        return self.num_layers // len(self.layer_pattern or "g")
+
+    @property
+    def num_attn_layers(self) -> int:
+        """Layers that attend over K/V: the pool's leading axis."""
+        return self.num_periods if self.layer_pattern else self.num_layers
+
+    @property
+    def kda_per_period(self) -> int:
+        return self.layer_pattern.count("k")
+
+    @property
+    def kda_size(self) -> int:
+        return self.kda_num_heads * self.kda_head_dim
+
+    @property
+    def router_width(self) -> int:
+        return self.n_routed_experts or self.num_experts
+
+    @property
+    def expert_share(self) -> bool:
+        """The stacks hold only some of the experts the router scores."""
+        return self.router_width != self.num_experts
 
     @property
     def q_size(self) -> int:
@@ -480,6 +556,42 @@ def tiny_olmoe(**overrides) -> ModelConfig:
         num_experts_per_tok=2,
         moe_intermediate_size=32,
         norm_topk_prob=False,
+    )
+    base.update(overrides)
+    return ModelConfig(**base)
+
+
+def tiny_solar(**overrides) -> ModelConfig:
+    """A miniature Solar-Open2-shaped hybrid: two periods of one gated NoPE
+    GQA layer and three KDA layers; every FFN a sigmoid router over 16
+    experts of which this share holds 4, top-2, plus one shared expert."""
+    base = dict(
+        name="tiny-solar",
+        vocab_size=128,
+        hidden_size=64,
+        intermediate_size=32,
+        num_layers=8,
+        num_heads=4,
+        num_kv_heads=2,
+        head_dim=16,
+        max_seq_len=256,
+        norm_eps=1e-5,
+        pos_embed="none",
+        tie_embeddings=False,
+        eos_token_id=1,
+        num_experts=4,
+        n_routed_experts=16,
+        expert_offset=0,
+        num_experts_per_tok=2,
+        moe_intermediate_size=32,
+        norm_topk_prob=True,
+        router_scoring="sigmoid",
+        n_shared_experts=1,
+        layer_pattern="gkkk",
+        attn_output_gate=True,
+        kda_num_heads=4,
+        kda_head_dim=16,
+        kda_low_rank=16,
     )
     base.update(overrides)
     return ModelConfig(**base)

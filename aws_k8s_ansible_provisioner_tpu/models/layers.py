@@ -213,8 +213,83 @@ def _dense_init(key, shape, dtype, scale=0.02):
     return (scale * jax.random.normal(key, shape, jnp.float32)).astype(dtype)
 
 
+def _init_ffn_params(cfg: ModelConfig, key: jax.Array, dtype, lead) -> dict:
+    """Router, held expert stacks and shared expert of an expert FFN, every
+    leaf with the leading axes ``lead``."""
+    H, E, Im = cfg.hidden_size, cfg.num_experts, cfg.moe_intermediate_size
+    ks = jax.random.split(key, 8)
+    p = {"router": {"kernel": _dense_init(ks[0], lead + (H, cfg.router_width),
+                                          dtype)},
+         "w_gate": {"kernel": _dense_init(ks[1], lead + (E, H, Im), dtype)},
+         "w_up": {"kernel": _dense_init(ks[2], lead + (E, H, Im), dtype)},
+         "w_down": {"kernel": _dense_init(ks[3], lead + (E, Im, H), dtype)}}
+    if cfg.router_scoring == "sigmoid":
+        p["router"]["bias"] = jnp.zeros(lead + (cfg.router_width,),
+                                        jnp.float32)
+    if cfg.n_shared_experts:
+        Is = Im * cfg.n_shared_experts
+        p["shared"] = {
+            "w_gate": {"kernel": _dense_init(ks[4], lead + (H, Is), dtype)},
+            "w_up": {"kernel": _dense_init(ks[5], lead + (H, Is), dtype)},
+            "w_down": {"kernel": _dense_init(ks[6], lead + (Is, H), dtype)}}
+    return p
+
+
+def init_hybrid_layer_params(cfg: ModelConfig, key: jax.Array, dtype) -> dict:
+    """Stacked params of a model with a layer pattern: one sub-tree a KIND,
+    ``gqa`` leaves ``[P, ...]`` (one attention layer a period) and ``kda``
+    leaves ``[P, n_k, ...]`` — what the scan over periods slices."""
+    from aws_k8s_ansible_provisioner_tpu.ops.linear_attention import CONV_TAPS
+
+    P, nk, H = cfg.num_periods, cfg.kda_per_period, cfg.hidden_size
+    kg, kk = jax.random.split(key)
+
+    def dense(k, lead, din, dout):
+        return {"kernel": _dense_init(k, lead + (din, dout), dtype)}
+
+    def norm(lead, width=H):
+        return {"weight": jnp.ones(lead + (width,), dtype)}
+
+    ks = jax.random.split(kg, 8)
+    gqa = {"input_norm": norm((P,)), "post_norm": norm((P,)),
+           "wq": dense(ks[0], (P,), H, cfg.q_size),
+           "wk": dense(ks[1], (P,), H, cfg.kv_size),
+           "wv": dense(ks[2], (P,), H, cfg.kv_size),
+           "wo": dense(ks[3], (P,), cfg.q_size, H),
+           **_init_ffn_params(cfg, ks[4], dtype, (P,))}
+    if cfg.attn_output_gate:
+        gqa["wg"] = dense(ks[5], (P,), H, cfg.q_size)
+    out = {"gqa": gqa}
+    if nk:
+        lead, D, Hk = (P, nk), cfg.kda_size, cfg.kda_num_heads
+        r = cfg.kda_low_rank or cfg.kda_head_dim
+        ks = jax.random.split(kk, 16)
+        # the family's init: A = exp(A_log) uniform on [1, 16], and dt_bias
+        # the inverse softplus of a step log-uniform on [1e-3, 1e-1]
+        dt = jnp.exp(jax.random.uniform(ks[12], lead + (D,), jnp.float32,
+                                        jnp.log(1e-3), jnp.log(1e-1)))
+        out["kda"] = {
+            "input_norm": norm(lead), "post_norm": norm(lead),
+            "wq": dense(ks[0], lead, H, D), "wk": dense(ks[1], lead, H, D),
+            "wv": dense(ks[2], lead, H, D), "wo": dense(ks[3], lead, D, H),
+            "conv": {"weight": _dense_init(
+                ks[4], lead + (CONV_TAPS, 3 * D), dtype, 0.5)},
+            "f_a": dense(ks[5], lead, H, r), "f_b": dense(ks[6], lead, r, D),
+            "g_a": dense(ks[7], lead, H, r), "g_b": dense(ks[8], lead, r, D),
+            "w_beta": dense(ks[9], lead, H, Hk),
+            "A_log": jnp.log(jax.random.uniform(
+                ks[10], lead + (Hk,), jnp.float32, 1.0, 16.0)),
+            "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+            "o_norm": norm(lead, cfg.kda_head_dim),
+            **_init_ffn_params(cfg, ks[11], dtype, lead)}
+    return out
+
+
 def init_layer_params(cfg: ModelConfig, key: jax.Array, dtype) -> dict:
-    """Init stacked layer params: every leaf has leading [num_layers] axis."""
+    """Init stacked layer params: every leaf has leading [num_layers] axis
+    (a model with a layer pattern: :func:`init_hybrid_layer_params`)."""
+    if cfg.layer_pattern:
+        return init_hybrid_layer_params(cfg, key, dtype)
     L, H = cfg.num_layers, cfg.hidden_size
     ks = jax.random.split(key, 8)
 
@@ -360,7 +435,13 @@ def _mlp(cfg: ModelConfig, h: jnp.ndarray, p: dict) -> jnp.ndarray:
         from aws_k8s_ansible_provisioner_tpu.ops.moe import moe_mlp
 
         B, T, H = h.shape
-        return moe_mlp(cfg, h.reshape(B * T, H), p).reshape(B, T, H)
+        out = moe_mlp(cfg, h.reshape(B * T, H), p).reshape(B, T, H)
+        if cfg.n_shared_experts:   # a dense SwiGLU every token passes
+            sp = p["shared"]
+            out = out + _linear(
+                jax.nn.silu(_linear(h, sp["w_gate"])) * _linear(h, sp["w_up"]),
+                sp["w_down"])
+        return out
     if cfg.gated_mlp:  # SwiGLU (Qwen/Llama) / GeGLU (Gemma)
         gate_act = jax.nn.silu if cfg.act == "silu" \
             else partial(jax.nn.gelu, approximate=True)  # "gelu_tanh"
@@ -397,7 +478,10 @@ def decoder_block(cfg: ModelConfig, p: dict, x: jnp.ndarray,
         k = apply_rope(k, cos, sin, rotary_dim)
 
     ctx, new_cache_l = attend(q, k, v, cache_l)
-    attn_out = _linear(ctx.reshape(B, T, cfg.q_size), p["wo"])
+    ctx = ctx.reshape(B, T, cfg.q_size)
+    if cfg.attn_output_gate:   # elementwise, from its own projection
+        ctx = ctx * jax.nn.sigmoid(_linear(h, p["wg"]))
+    attn_out = _linear(ctx, p["wo"])
 
     if cfg.parallel_block:  # Phi: attn and MLP both read the same normed input
         x = x + attn_out + _mlp(cfg, h, p)
@@ -406,6 +490,30 @@ def decoder_block(cfg: ModelConfig, p: dict, x: jnp.ndarray,
         h2 = apply_norm(cfg, x, p["post_norm"])
         x = x + _mlp(cfg, h2, p)
     return x, new_cache_l
+
+
+def kda_block(cfg: ModelConfig, p: dict, x: jnp.ndarray, recur,
+              rec_l: Any) -> Tuple[jnp.ndarray, Any]:
+    """One KDA linear-attention block (ops/linear_attention.py has the
+    recurrence). ``p`` is a per-layer slice; ``recur`` runs the short
+    convolution and the recurrence over the per-slot state ``rec_l`` names
+    and returns the heads' outputs in float32."""
+    B, T, _ = x.shape
+    Hk, d = cfg.kda_num_heads, cfg.kda_head_dim
+    h = apply_norm(cfg, x, p["input_norm"])
+    qkv = jnp.concatenate([_linear(h, p["wq"]), _linear(h, p["wk"]),
+                           _linear(h, p["wv"])], axis=-1)
+    # log-decay per channel (<= 0) and step size per head in (0, 2): the 2
+    # is what lets a head's transition have negative eigenvalues
+    f = _linear(_linear(h, p["f_a"]), p["f_b"]).astype(jnp.float32)
+    g = -jnp.exp(p["A_log"].astype(jnp.float32))[:, None] * jax.nn.softplus(
+        (f + p["dt_bias"].astype(jnp.float32)).reshape(B, T, Hk, d))
+    beta = 2.0 * jax.nn.sigmoid(_linear(h, p["w_beta"]).astype(jnp.float32))
+    o, rec = recur(p["conv"]["weight"], qkv, g, beta, rec_l)
+    o = rms_norm(o, p["o_norm"]["weight"], cfg.norm_eps).astype(x.dtype)
+    gate = jax.nn.sigmoid(_linear(_linear(h, p["g_a"]), p["g_b"]))
+    x = x + _linear(o.reshape(B, T, Hk * d) * gate, p["wo"])
+    return x + _mlp(cfg, apply_norm(cfg, x, p["post_norm"]), p), rec
 
 
 def _embed_inputs(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
@@ -461,6 +569,19 @@ def model_forward(
 ) -> Tuple[jnp.ndarray, Any]:
     """Run the decoder; returns (logits [B, T, V], updated cache)."""
     attend = attend or make_default_attend(cfg)
+    if cfg.layer_pattern:
+        if cache is not None:
+            raise ValueError("a model with a layer pattern keeps its cache "
+                             "in the scan carry: model_forward_carry")
+        from aws_k8s_ansible_provisioner_tpu.ops.linear_attention import (
+            recur_from_zero)
+
+        # the stateless form: every sequence whole, from position 0
+        logits, _ = _hybrid_forward_carry(
+            params, cfg, tokens, positions, {},
+            lambda q, k, v, cl: (attend(q, k, v, None)[0], cl),
+            recur_from_zero, remat=remat)
+        return logits, None
     x, cos, sin = _embed_inputs(params, cfg, tokens, positions)
 
     def body(x, layer_in):
@@ -490,6 +611,7 @@ def model_forward_carry(
     positions: jnp.ndarray,       # [B, T] int32
     cache: Any,                   # full stacked cache ([L, ...] leaves)
     attend: AttendFn,             # receives cache_l = (full_cache, layer_idx)
+    recur=None,                   # KDA layers' callback (layer_pattern)
 ) -> Tuple[jnp.ndarray, Any]:
     """Decoder forward with the cache in the scan CARRY, not xs/ys.
 
@@ -506,6 +628,9 @@ def model_forward_carry(
     traffic is weights + live cache rows only. Every serving step program
     runs this form.
     """
+    if cfg.layer_pattern:
+        return _hybrid_forward_carry(params, cfg, tokens, positions, cache,
+                                     attend, recur)
     x, cos, sin = _embed_inputs(params, cfg, tokens, positions)
     from aws_k8s_ansible_provisioner_tpu.ops import moe
 
@@ -520,3 +645,59 @@ def model_forward_carry(
         body, (x, cache, jnp.int32(0)), params["layers"])
     moe.put_stats(per_layer)
     return _final_logits(params, cfg, x), cache
+
+
+def _hybrid_forward_carry(params, cfg: ModelConfig, tokens, positions, cache,
+                          attend: AttendFn, recur, remat: bool = False):
+    """model_forward_carry for a model with a layer pattern: ONE scan over
+    PERIODS whose body runs the period's kinds in order. ``cache`` holds
+    the pool's K/V leaves with a leading axis of ATTENDING layers (one a
+    period, so ``attend`` is handed ``(pool, period)``) and, beside them,
+    the KDA layers' per-slot leaves ``[P, n_k, slots, ...]``
+    (ops/linear_attention.py), which ``recur`` reads and writes as
+    ``(state, period, j)``. Both ride the carry."""
+    from aws_k8s_ansible_provisioner_tpu.ops import linear_attention as la
+    from aws_k8s_ansible_provisioner_tpu.ops import moe
+
+    x, cos, sin = _embed_inputs(params, cfg, tokens, positions)
+    pool = {n: a for n, a in cache.items() if not la.is_state(n)}
+    rec = {n: a for n, a in cache.items() if la.is_state(n)}
+
+    layers = params["layers"]
+
+    def layer(kind: str, *idx):
+        """One layer's params, each leaf ONE dynamic slice of its whole
+        stack at (period[, j]) — what a scan's xs slicing is. Handing the
+        period's ``[n_k, ...]`` slab to the body and indexing ``[j]`` there
+        made XLA copy every KDA weight of the period into a fresh buffer
+        first (13 of a decode step's 32 ms on the chip, PERF.md PR 32)."""
+        n = len(idx)
+        return jax.tree.map(
+            lambda a: jax.lax.dynamic_slice(
+                a, idx + (0,) * (a.ndim - n),
+                (1,) * n + a.shape[n:]).reshape(a.shape[n:]),
+            layers[kind])
+
+    def body(carry, _):
+        x, pool, rec, period = carry
+        stats, j = [], 0
+        for kind in cfg.layer_pattern:
+            if kind == "g":
+                x, (pool, _) = decoder_block(cfg, layer("gqa", period), x,
+                                             cos, sin, attend, (pool, period))
+            else:
+                x, rec = kda_block(cfg, layer("kda", period, j), x, recur,
+                                   (rec, period, j))
+                j += 1
+            stats.append(moe.take_layer_stats())
+        stats = None if stats[0] is None else jnp.stack(stats)
+        return (x, pool, rec, period + 1), stats
+
+    if remat:
+        body = jax.checkpoint(body)
+    (x, pool, rec, _), per_layer = jax.lax.scan(
+        body, (x, pool, rec, jnp.int32(0)), None, length=cfg.num_periods)
+    if per_layer is not None:       # [P, layers a period, n] -> [L, n]
+        per_layer = per_layer.reshape((-1,) + per_layer.shape[2:])
+    moe.put_stats(per_layer)
+    return _final_logits(params, cfg, x), {**pool, **rec}

@@ -42,12 +42,15 @@ import numpy as np
 
 from aws_k8s_ansible_provisioner_tpu.config import ModelConfig
 
-# key -> contraction (in) axis of the per-layer kernel. Dense kernels are
-# [L, in, out] (axis 1); MoE expert kernels are [L, E, in, out] (axis 2).
-_DENSE_AXES = {"wq": 1, "wk": 1, "wv": 1, "wo": 1,
-               "w_gate": 1, "w_up": 1, "w_down": 1}
-_MOE_AXES = {"wq": 1, "wk": 1, "wv": 1, "wo": 1,
-             "w_gate": 2, "w_up": 2, "w_down": 2}
+# The per-layer kernels that go to int8. Whatever leads it ([L] of a dense
+# stack, [L, E] of an expert stack, [P] / [P, n_k] of a kind's sub-tree in a
+# model with a layer pattern), a kernel is [..., in, out]: the contraction
+# axis is its second-to-last. ``wg`` is the attention gate, ``shared`` the
+# shared expert's sub-tree. A KDA layer's low-rank decay/gate projections,
+# step-size projection, convolution taps, A_log and dt_bias stay in the
+# model dtype (a few MB a layer, and the decay is precision-critical), like
+# the router and its bias.
+_QUANT_KEYS = ("wq", "wk", "wv", "wo", "wg", "w_gate", "w_up", "w_down")
 
 
 def _quant_kernel(w: jnp.ndarray, in_axis: int):
@@ -64,9 +67,24 @@ def _quant_kernel(w: jnp.ndarray, in_axis: int):
 def weights_quantized(params: dict) -> bool:
     """Whether ``params`` carries int8 weight leaves (scale siblings)."""
     try:
-        return "scale" in params["layers"]["wq"]
+        layers = params["layers"]
+        return "scale" in layers.get("gqa", layers)["wq"]
     except (KeyError, TypeError):
         return False
+
+
+def _quantize_layers(layers: dict, kern) -> dict:
+    """One stacked layer tree (a dense or MoE model's, or one kind's)."""
+    out = dict(layers)
+    for key in _QUANT_KEYS:
+        if key in out:
+            p = dict(out[key])
+            p["kernel"], p["scale"] = kern(p["kernel"],
+                                           in_axis=p["kernel"].ndim - 2)
+            out[key] = p
+    if "shared" in out:
+        out["shared"] = _quantize_layers(out["shared"], kern)
+    return out
 
 
 def _quant_kernel_host(w, in_axis: int):
@@ -92,21 +110,15 @@ def quantize_params(params: dict, cfg: ModelConfig,
     HBM peak the sharded loader exists to avoid (an 8B bf16 tree does not
     fit one v5e chip). Engine picks host=True whenever it has a mesh.
     """
-    axes = _MOE_AXES if cfg.num_experts > 0 else _DENSE_AXES
     kern = _quant_kernel_host if host else _quant_kernel
 
     def _go(params):
         out = jax.tree.map(lambda x: x, params)   # shallow-ish copy
-        layers = dict(out["layers"])
-        for key, in_axis in axes.items():
-            if key not in layers:
-                continue
-            p = dict(layers[key])
-            # contract over the in axis; scale keeps the remaining axes
-            # (dense [L, out]; experts [L, E, out])
-            q, s = kern(p["kernel"], in_axis=in_axis)
-            p["kernel"], p["scale"] = q, s
-            layers[key] = p
+        if cfg.layer_pattern:    # one sub-tree a kind
+            layers = {kind: _quantize_layers(sub, kern)
+                      for kind, sub in out["layers"].items()}
+        else:
+            layers = _quantize_layers(out["layers"], kern)
         out["layers"] = layers
         emb = dict(out["embed"])
         # [V, H]: per-vocab-row scales — the gather dequantizes one row per

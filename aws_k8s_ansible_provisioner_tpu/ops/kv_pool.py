@@ -61,8 +61,11 @@ def scale_lanes(page_size: int) -> int:
 
 def init_pool(cfg: ModelConfig, num_pages: int, page_size: int,
               dtype=jnp.bfloat16, quant: bool = False) -> dict:
-    """Allocate the physical page pool. Leaves carry a leading [L] axis."""
-    shape = (cfg.num_layers, num_pages, cfg.num_kv_heads, page_size,
+    """Allocate the physical page pool. Leaves carry a leading [L] axis:
+    the layers that ATTEND (all of them, or one a period of a model with a
+    layer pattern — its other layers keep per-slot state instead,
+    ops/linear_attention.init_state)."""
+    shape = (cfg.num_attn_layers, num_pages, cfg.num_kv_heads, page_size,
              cfg.head_dim)
     if quant:
         sshape = shape[:3] + (scale_lanes(page_size),)
@@ -77,7 +80,7 @@ def init_pool(cfg: ModelConfig, num_pages: int, page_size: int,
 
 def pool_bytes(cfg: ModelConfig, num_pages: int, page_size: int,
                dtype=jnp.bfloat16, quant: bool = False) -> int:
-    heads = 2 * cfg.num_layers * num_pages * cfg.num_kv_heads
+    heads = 2 * cfg.num_attn_layers * num_pages * cfg.num_kv_heads
     if quant:
         return heads * (page_size * cfg.head_dim
                         + 4 * scale_lanes(page_size))

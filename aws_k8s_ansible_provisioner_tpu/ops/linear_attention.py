@@ -1,0 +1,463 @@
+"""KDA linear attention (Kimi Delta Attention, arXiv:2510.26692): the
+recurrence in two exact forms, and the ``recur`` callbacks that run it over
+per-slot state in the layer scan's carry.
+
+Per head, with state ``S`` [d_k, d_v] (float32, zero at position 0), a
+log-decay per CHANNEL ``g_t`` <= 0 and a step size ``beta_t`` in (0, 2):
+
+    S'  = diag(exp(g_t)) S_{t-1}
+    S_t = S' + beta_t k_t (v_t - S'^T k_t)^T
+    o_t = S_t^T q_t
+
+- :func:`kda_step` — one token a row (decode): the state is read, decayed,
+  corrected by one rank-1 term and written. Elementwise and reductions in
+  float32 on the VPU, no matmul: ``o_t`` is taken from ``S'`` by
+  ``S_t^T q = S'^T q + (k.q) beta (v - S'^T k)``, so both reductions share
+  one pass over the state and the update is the second. On the TPU the
+  same step is :func:`kda_decode_update`, a Pallas kernel that holds a
+  tile of the state in VMEM and makes it ONE pass, in place.
+- :func:`kda_span` — a span of T rows (prefill, a chunk) in BLOCKS of
+  ``BLOCK`` rows: inside a block every pair (t, s <= t) interacts through
+  ``exp(G_t - G_s)`` (``G`` the running sum of ``g`` inside the block),
+  formed from the pairwise DIFFERENCE, which is never positive — the naive
+  ``exp(G_t) * exp(-G_s)`` overflows float32 once a channel has decayed by
+  e^88 inside a block. The triangular system the delta rule leaves
+  (``(I + A) U = beta (V - K~ S_0)``) is solved for all blocks at once
+  (``T = (I + A)^-1 diag(beta)`` does not depend on the
+  state), and the state is carried block to block by three small matmuls.
+  A token-by-token scan over a 2,048-row chunk would read and write the
+  4-MiB-a-slot state 2,048 times; this form does it T / BLOCK times.
+
+Rows that carry no token (a chunk's padding, a dead passenger of a mixed
+step, an idle slot) come with ``g`` = 0 and ``beta`` = 0: the identity on
+the state in both forms.
+
+The short convolution (depthwise, causal, ``K`` taps, SiLU) in front of
+q/k/v needs the ``K - 1`` rows before a span: they are the second per-slot
+leaf, ``kda_conv``.
+
+State layout, beside the paged pool in the same ``cache`` pytree the step
+programs donate: ``kda_state`` float32 ``[P, n_k, slots, H, d_k, d_v]`` and
+``kda_conv`` ``[P, n_k, slots, K - 1, 3 H d_k]`` (P periods, n_k KDA layers
+a period) — indexed by SLOT: row b of a decode batch is slot b, no gather.
+A span that starts at position 0 starts from zeros inside the program, so
+a slot's next occupant never reads its predecessor's state.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+
+from aws_k8s_ansible_provisioner_tpu.config import ModelConfig
+
+# rows a block of the span form; the pairwise decay tensor is
+# [blocks, H, BLOCK, BLOCK, d_k], so the work inside a block grows with it
+BLOCK = 16
+# taps of the short convolution in front of q/k/v (the published layer's
+# ``short_conv_kernel_size``): the ``kda_conv`` leaf keeps CONV_TAPS - 1 rows
+CONV_TAPS = 4
+_HI = jax.lax.Precision.HIGHEST
+
+
+def _state_shapes(cfg: ModelConfig, num_slots: int, dtype) -> dict:
+    P, nk, H, d = (cfg.num_periods, cfg.kda_per_period, cfg.kda_num_heads,
+                   cfg.kda_head_dim)
+    return {"kda_state": ((P, nk, num_slots, H, d, d), jnp.float32),
+            "kda_conv": ((P, nk, num_slots, CONV_TAPS - 1,
+                          3 * H * d), dtype)}
+
+
+def init_state(cfg: ModelConfig, num_slots: int, dtype=jnp.bfloat16) -> dict:
+    """The two per-slot leaves of a model with KDA layers."""
+    return {name: jnp.zeros(shape, dt) for name, (shape, dt)
+            in _state_shapes(cfg, num_slots, dtype).items()}
+
+
+def state_bytes(cfg: ModelConfig, num_slots: int, dtype=jnp.bfloat16) -> int:
+    if not cfg.recurrent:
+        return 0
+    return sum(math.prod(shape) * jnp.dtype(dt).itemsize for shape, dt
+               in _state_shapes(cfg, num_slots, dtype).values())
+
+
+def is_state(name: str) -> bool:
+    return name.startswith("kda_")
+
+
+# ---------------------------------------------------------------------------
+# The mathematics
+# ---------------------------------------------------------------------------
+
+
+def _l2norm(x):
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
+
+
+def conv_qkv(window: jnp.ndarray, taps: jnp.ndarray, H: int, d: int):
+    """Depthwise causal convolution + SiLU over ``window`` [..., T + K - 1,
+    3 H d] (the K - 1 rows of history in front), then the split:
+    ``q = L2norm(.) / sqrt(d)``, ``k = L2norm(.)``, ``v`` as it is — each
+    [..., T, H, d] float32."""
+    K = taps.shape[0]
+    T = window.shape[-2] - (K - 1)
+    w = taps.astype(jnp.float32)
+    win = window.astype(jnp.float32)
+    y = sum(w[i] * jax.lax.slice_in_dim(win, i, i + T, axis=-2)
+            for i in range(K))
+    y = jax.nn.silu(y)
+    q, k, v = (a.reshape(a.shape[:-1] + (H, d))
+               for a in jnp.split(y, 3, axis=-1))
+    return _l2norm(q) * (d ** -0.5), _l2norm(k), v
+
+
+def kda_step(S, q, k, v, g, beta):
+    """One token a row. S: [B, H, dk, dv] float32; q, k, g: [B, H, dk];
+    v: [B, H, dv]; beta: [B, H]. Returns (o [B, H, dv], S_new)."""
+    Sd = S * jnp.exp(g)[..., None]
+    u = jnp.sum(Sd * k[..., None], axis=-2)             # S'^T k
+    oq = jnp.sum(Sd * q[..., None], axis=-2)            # S'^T q
+    delta = beta[..., None] * (v - u)
+    o = oq + jnp.sum(k * q, axis=-1, keepdims=True) * delta
+    return o, Sd + k[..., None] * delta[..., None, :]
+
+
+def _unit_lower_inverse(A):
+    """(I + A)^-1 for strictly lower-triangular ``A`` [..., C, C], C a power
+    of two, by doubling: the inverses of the diagonal blocks of size s give
+    those of size 2s — [[X11, 0], [-X22 L21 X11, X22]] — so log2(C) rounds
+    of two small batched matmuls (forward substitution's C - 1 dependent
+    row updates each rewrote the whole batch: 23 ms of a mixed step)."""
+    C = A.shape[-1]
+    lead = A.shape[:-2]
+    X = jnp.ones(lead + (C, 1, 1), jnp.float32)          # size-1 blocks
+    s = 1
+    while s < C:
+        nb = C // (2 * s)
+        blocks = A.reshape(lead + (nb, 2 * s, nb, 2 * s))
+        diag = jnp.moveaxis(jnp.diagonal(blocks, axis1=-4, axis2=-2), -1, -3)
+        L21 = diag[..., s:, :s]                          # [..., nb, s, s]
+        Xp = X.reshape(lead + (nb, 2, s, s))
+        X11, X22 = Xp[..., 0, :, :], Xp[..., 1, :, :]
+        X21 = -jnp.einsum("...ij,...jk,...kl->...il", X22, L21, X11,
+                          precision=_HI)
+        X = jnp.concatenate(
+            [jnp.concatenate([X11, jnp.zeros_like(X11)], axis=-1),
+             jnp.concatenate([X21, X22], axis=-1)], axis=-2)
+        s *= 2
+    return X[..., 0, :, :]
+
+
+def kda_span(S0, q, k, v, g, beta, block: int = BLOCK):
+    """T rows of N sequences, exact, in blocks. S0: [N, H, dk, dv] float32;
+    q, k, g: [N, T, H, dk]; v: [N, T, H, dv]; beta: [N, T, H]; T a multiple
+    of ``block``. Returns (o [N, T, H, dv], S after the last row)."""
+    N, T, H, dk = q.shape
+    C, nb = block, T // block
+
+    def blocks(a):      # [N, T, H, ...] -> [nb, N, H, C, ...]
+        a = a.reshape((N, nb, C) + a.shape[2:])
+        return jnp.moveaxis(jnp.moveaxis(a, 3, 2), 1, 0)
+
+    q, k, v, g = blocks(q), blocks(k), blocks(v), blocks(g)
+    beta = blocks(beta)                                  # [nb, N, H, C]
+    G = jnp.cumsum(g, axis=-2)                           # [nb, N, H, C, dk]
+    # pairwise decay exp(G_t - G_s), s <= t: the difference is <= 0 there;
+    # masked BEFORE the exp, so no entry above the diagonal is ever formed
+    tri = jnp.tril(jnp.ones((C, C), bool))
+    diff = G[..., :, None, :] - G[..., None, :, :]       # [..., t, s, dk]
+    decay = jnp.exp(jnp.where(tri[..., None], diff, -jnp.inf))
+    # two reductions over d_k, written apart so that each takes the exp in
+    # (stacked into one, the exp was used twice and XLA wrote the
+    # [blocks, H, C, C, d_k] tensor out: 1 GiB a layer at a 2,048-row chunk)
+    kd = k[..., None, :, :] * decay                      # k_s exp(G_t - G_s)
+    A = beta[..., None] * jnp.where(
+        jnp.tril(tri, -1), jnp.sum(k[..., :, None, :] * kd, axis=-1), 0)
+    Bm = jnp.sum(q[..., :, None, :] * kd, axis=-1)       # q.k pairs, s <= t
+    X = _unit_lower_inverse(A)
+    Tm = X * beta[..., None, :]                          # (I + A)^-1 diag(b)
+    eG = jnp.exp(G)
+    W = jnp.einsum("...ts,...sk->...tk", Tm, k * eG, precision=_HI)
+    Ut = jnp.einsum("...ts,...sv->...tv", Tm, v, precision=_HI)
+    Qd = q * eG
+    Gl = G[..., -1:, :]                                  # the block's total
+    Kh = k * jnp.exp(Gl - G)                             # exp(G_C - G_s) <= 1
+    eGl = jnp.exp(Gl[..., 0, :])[..., None]              # [nb, N, H, dk, 1]
+
+    def step(S, xs):
+        # float32 products ("highest"): at the default a TPU rounds the
+        # float32 state to bf16 on every read, and what a prompt leaves
+        # behind is what every later token of the answer reads
+        W, Ut, Qd, Bm, Kh, eGl = xs
+        U = Ut - jnp.einsum("nhtk,nhkv->nhtv", W, S, precision=_HI)
+        o = jnp.einsum("nhtk,nhkv->nhtv", Qd, S, precision=_HI) \
+            + jnp.einsum("nhts,nhsv->nhtv", Bm, U, precision=_HI)
+        return eGl * S + jnp.einsum("nhsk,nhsv->nhkv", Kh, U,
+                                    precision=_HI), o
+
+    S, o = jax.lax.scan(step, S0, (W, Ut, Qd, Bm, Kh, eGl))
+    o = jnp.moveaxis(jnp.moveaxis(o, 0, 1), 3, 2)        # [N, nb, C, H, dv]
+    return o.reshape(N, T, H, -1), S
+
+
+def kda_scan(S0, q, k, v, g, beta):
+    """The same rows token by token (:func:`kda_step` under a scan): what
+    the span form is tested against."""
+    def step(S, xs):
+        o, S = kda_step(S, *xs)
+        return S, o
+
+    xs = tuple(jnp.moveaxis(a, 1, 0) for a in (q, k, v, g, beta))
+    S, o = jax.lax.scan(step, S0, xs)
+    return jnp.moveaxis(o, 0, 1), S
+
+
+# ---------------------------------------------------------------------------
+# recur callbacks: (conv taps [K, 3Hd], qkv [B, T, 3Hd] before the
+# convolution, g [B, T, H, d], beta [B, T, H], (rec, period, j)) ->
+# (o [B, T, H, d] float32, rec). ``rec`` holds the two FULL state leaves;
+# (period, j) names the layer (period traced, j static).
+# ---------------------------------------------------------------------------
+
+
+def _layer_get(rec, name, period, j):
+    """All slots' rows of one layer, [slots, ...]."""
+    arr = rec[name]
+    return jax.lax.dynamic_slice(
+        arr, (period, j) + (0,) * (arr.ndim - 2),
+        (1, 1) + arr.shape[2:])[0, 0]
+
+
+def _layer_set(rec, name, period, j, rows, slot=None):
+    """Write a layer's rows: all slots (``slot`` None, rows [slots, ...]) or
+    the N slots ``slot`` [N] (out-of-range ids drop)."""
+    arr = rec[name]
+    if slot is None:
+        arr = jax.lax.dynamic_update_slice(
+            arr, rows[None, None].astype(arr.dtype),
+            (period, j) + (0,) * (arr.ndim - 2))
+    else:
+        arr = arr.at[period, j, slot].set(rows.astype(arr.dtype),
+                                          mode="drop")
+    return {**rec, name: arr}
+
+
+def _pad_to_block(arrays, T: int):
+    """Rows padded with zeros (g = 0, beta = 0: the identity) up to a whole
+    number of blocks."""
+    pad = -T % BLOCK
+    if not pad:
+        return arrays
+    return tuple(jnp.pad(a, [(0, 0), (0, pad)] + [(0, 0)] * (a.ndim - 2))
+                 for a in arrays)
+
+
+def _span(rec_l, taps, qkv, g, beta, slots, fresh, n_valid):
+    """N spans [N, T, ...] into slots ``slots`` [N]: each starts from zeros
+    where ``fresh`` [N] (position 0) and from its slot's leaves otherwise;
+    rows at or past ``n_valid`` [N] are the identity and leave no
+    convolution row."""
+    rec, period, j = rec_l
+    N, T, H, d = g.shape
+    K = taps.shape[0]
+    # a padding slot id (batched prefill's padding rows) reads slot 0's
+    # leaves — fresh or not, its rows are dead and its write drops
+    rd = jnp.clip(slots, 0, rec["kda_state"].shape[2] - 1)
+    S0 = jnp.where(fresh[:, None, None, None], 0.0,
+                   rec["kda_state"][period, j, rd])
+    hist = jnp.where(fresh[:, None, None], 0,
+                     rec["kda_conv"][period, j, rd])
+    window = jnp.concatenate([hist.astype(qkv.dtype), qkv], axis=1)
+    q, k, v = conv_qkv(window, taps, H, d)
+    live = (jnp.arange(T)[None] < n_valid[:, None])          # [N, T]
+    g = jnp.where(live[..., None, None], g, 0.0)
+    beta = jnp.where(live[..., None], beta, 0.0)
+    q, k, v, g, beta = _pad_to_block((q, k, v, g, beta), T)
+    o, S = kda_span(S0, q, k, v, g, beta)
+    # the K - 1 rows in front of row n_valid of the span
+    tail = jax.vmap(lambda w, n: jax.lax.dynamic_slice_in_dim(
+        w, n, K - 1, axis=0))(window, n_valid)
+    rec = _layer_set(rec, "kda_state", period, j, S, slots)
+    rec = _layer_set(rec, "kda_conv", period, j, tail, slots)
+    return o[:, :T], rec
+
+
+def _rows(rec_l, taps, qkv, g, beta, live):
+    """One token for every slot (row b = slot b). ``live`` [B] bool or None:
+    a dead row is the identity and leaves no convolution row."""
+    rec, period, j = rec_l
+    B, H, d = g.shape
+    hist = _layer_get(rec, "kda_conv", period, j)            # [B, K-1, 3Hd]
+    window = jnp.concatenate([hist, qkv[:, None].astype(hist.dtype)], axis=1)
+    q, k, v = conv_qkv(window, taps, H, d)
+    tail = window[:, 1:]
+    if live is not None:
+        g = jnp.where(live[:, None, None], g, 0.0)
+        beta = jnp.where(live[:, None], beta, 0.0)
+        tail = jnp.where(live[:, None, None], tail, hist)
+    from aws_k8s_ansible_provisioner_tpu.ops import pallas_attention
+
+    if pallas_attention.supported():
+        # one pass over the state, in place (the XLA form below reads it
+        # twice: 55 % of its bytes' time on the chip, PERF.md PR 32)
+        o, arr = kda_decode_update(rec["kda_state"], period, j, q[:, 0],
+                                   k[:, 0], v[:, 0], g, beta)
+        rec = {**rec, "kda_state": arr}
+    else:
+        o, S = kda_step(_layer_get(rec, "kda_state", period, j), q[:, 0],
+                        k[:, 0], v[:, 0], g, beta)
+        rec = _layer_set(rec, "kda_state", period, j, S)
+    rec = _layer_set(rec, "kda_conv", period, j, tail)
+    return o, rec
+
+
+def make_recur_decode(live=None):
+    """decode_steps: x is [B, 1, ...], row b is slot b."""
+
+    def recur(taps, qkv, g, beta, rec_l):
+        o, rec = _rows(rec_l, taps, qkv[:, 0], g[:, 0], beta[:, 0], live)
+        return o[:, None], rec
+
+    return recur
+
+
+def make_recur_span(slot, start, n_valid):
+    """prefill_step / prefill_chunk_step: one sequence [1, T, ...], rows
+    [start, start + n_valid) of slot ``slot``."""
+    slots = jnp.asarray(slot, jnp.int32)[None]
+    fresh = (jnp.asarray(start, jnp.int32) == 0)[None]
+    n = jnp.asarray(n_valid, jnp.int32)[None]
+
+    def recur(taps, qkv, g, beta, rec_l):
+        return _span(rec_l, taps, qkv, g, beta, slots, fresh, n)
+
+    return recur
+
+
+def make_recur_batch(slots, true_lens):
+    """prefill_batch_step: N prompts [N, T, ...] from position 0; a padding
+    row's slot id is out of range and its writes drop."""
+    fresh = jnp.ones(slots.shape, bool)
+
+    def recur(taps, qkv, g, beta, rec_l):
+        return _span(rec_l, taps, qkv, g, beta, slots, fresh, true_lens)
+
+    return recur
+
+
+def make_recur_mixed(B: int, live, pslot, pstart, plen):
+    """mixed_step's packed [1, B + C, ...]: B decode rows (row b = slot b,
+    ``live`` [B] False for the chunking slot's own row and for idle slots),
+    then the C chunk rows of slot ``pslot`` from ``pstart``, ``plen`` of
+    them valid. The decode rows go first: the chunking slot's row is the
+    identity, so the chunk reads what the earlier chunks left."""
+    span = make_recur_span(pslot, pstart, plen)
+
+    def recur(taps, qkv, g, beta, rec_l):
+        rec, period, j = rec_l
+        od, rec = _rows((rec, period, j), taps, qkv[0, :B], g[0, :B],
+                        beta[0, :B], live)
+        oc, rec = span(taps, qkv[:, B:], g[:, B:], beta[:, B:],
+                       (rec, period, j))
+        return jnp.concatenate([od[None], oc], axis=1), rec
+
+    return recur
+
+
+def recur_from_zero(taps, qkv, g, beta, rec_l):
+    """No state kept: every sequence [N, T, ...] whole, from position 0
+    (model_forward without a cache: tests, training)."""
+    N, T, H, d = g.shape
+    K = taps.shape[0]
+    window = jnp.pad(qkv, [(0, 0), (K - 1, 0), (0, 0)])
+    q, k, v = conv_qkv(window, taps, H, d)
+    q, k, v, g, beta = _pad_to_block((q, k, v, g, beta), T)
+    o, _ = kda_span(jnp.zeros((N, H, d, d), jnp.float32), q, k, v, g, beta)
+    return o[:, :T], rec_l[0]
+
+
+# ---------------------------------------------------------------------------
+# The decode update as ONE pass over the state (TPU)
+# ---------------------------------------------------------------------------
+
+HEADS_PER_STEP = 8      # heads a grid step: 8 x [d, d] float32 tiles in VMEM
+
+
+@functools.partial(jax.jit, static_argnames=("j", "interpret"))
+def kda_decode_update(state, period, j: int, q, k, v, g, beta,
+                      interpret: bool = False):
+    """:func:`kda_step` for every slot of one layer, IN PLACE on the full
+    state leaf: ``state`` [P, n_k, B, H, d, d] float32 is read once and
+    written once (``input_output_aliases``), where the XLA form reads it
+    twice (a reduce fusion for the two products with ``S'``, then the
+    update fusion). q, k, g: [B, H, d]; v: [B, H, d]; beta: [B, H] (a dead
+    row comes with g = 0, beta = 0: the identity). Returns (o [B, H, d]
+    float32, state).
+
+    Grid (slots, heads / 8): a step holds 8 heads' tiles. The decay and the
+    rank-1 update scale ROWS of a tile, so q, k and exp(g) arrive as
+    columns — ``[B, H/8, d, 8]``, d on the sublanes, a head a lane — and v,
+    beta and the output as rows."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    P, nk, B, H, d, _ = state.shape
+    hb = HEADS_PER_STEP if H % HEADS_PER_STEP == 0 else H
+    nh = H // hb
+
+    def cols(a):        # [B, H, d] -> [B, nh, d, hb]
+        return jnp.swapaxes(a.reshape(B, nh, hb, d), 2, 3)
+
+    idx = jnp.stack([jnp.asarray(period, jnp.int32), jnp.int32(j)])
+
+    def col_map(b, h, idx):
+        return (b, h, 0, 0)
+
+    def row_map(b, h, idx):
+        return (b, h, 0)
+
+    def st_map(b, h, idx):
+        return (idx[0], idx[1], b, h, 0, 0)
+
+    def kernel(idx_ref, qc_ref, kc_ref, ec_ref, v_ref, beta_ref, s_ref,
+               o_ref, s_out_ref):
+        for hh in range(hb):
+            S = s_ref[0, 0, 0, hh]                        # [d, d]
+            kc = kc_ref[0, 0, :, hh:hh + 1]               # [d, 1]
+            qc = qc_ref[0, 0, :, hh:hh + 1]
+            Sd = S * ec_ref[0, 0, :, hh:hh + 1]
+            u = jnp.sum(Sd * kc, axis=0, keepdims=True)   # [1, d] = S'^T k
+            oq = jnp.sum(Sd * qc, axis=0, keepdims=True)
+            delta = beta_ref[0, hh:hh + 1, :] \
+                * (v_ref[0, hh:hh + 1, :] - u)            # [1, d]
+            kq = jnp.sum(kc * qc, axis=0, keepdims=True)  # [1, 1]
+            o_ref[0, hh:hh + 1, :] = oq + kq * delta
+            s_out_ref[0, 0, 0, hh] = Sd + kc * delta
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(B, nh),
+        in_specs=[pl.BlockSpec((1, 1, d, hb), col_map),
+                  pl.BlockSpec((1, 1, d, hb), col_map),
+                  pl.BlockSpec((1, 1, d, hb), col_map),
+                  pl.BlockSpec((1, hb, d), row_map),
+                  pl.BlockSpec((1, hb, d), row_map),
+                  pl.BlockSpec((1, 1, 1, hb, d, d), st_map)],
+        out_specs=[pl.BlockSpec((1, hb, d), row_map),
+                   pl.BlockSpec((1, 1, 1, hb, d, d), st_map)])
+    f32 = jnp.float32
+    o, state = pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((B, H, d), f32),
+                   jax.ShapeDtypeStruct(state.shape, state.dtype)],
+        # the state: operand 6, after the scalars and the five row operands
+        input_output_aliases={6: 1},
+        interpret=interpret,
+    )(idx, cols(q.astype(f32)), cols(k.astype(f32)),
+      cols(jnp.exp(g.astype(f32))), v.astype(f32),
+      jnp.broadcast_to(beta.astype(f32)[..., None], (B, H, d)),
+      state)
+    return o, state

@@ -38,17 +38,38 @@ import jax.numpy as jnp
 
 from aws_k8s_ansible_provisioner_tpu.config import ModelConfig
 
-def route(cfg: ModelConfig, x: jnp.ndarray, router_kernel: jnp.ndarray):
-    """Top-k routing. x: [N, H]; router_kernel: [H, E].
+def route(cfg: ModelConfig, x: jnp.ndarray, router_kernel: jnp.ndarray,
+          router_bias=None):
+    """Top-k routing. x: [N, H]; router_kernel: [H, E] with E the ROUTER's
+    width (all experts of the layer, held here or not).
 
     Returns (weights [N, k] in x.dtype, expert_idx [N, k] int32).
+    ``router_scoring`` "sigmoid": a score per expert, the top-k chosen by
+    score + ``router_bias`` ([E], a selection bias: it changes who is chosen
+    and never a weight), the weights the chosen SCORES, renormalised over
+    all k chosen (``norm_topk_prob``).
     """
     logits = x.astype(jnp.float32) @ router_kernel.astype(jnp.float32)
+    if cfg.router_scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        _, idx = jax.lax.top_k(scores + router_bias.astype(jnp.float32),
+                               cfg.num_experts_per_tok)
+        w = jnp.take_along_axis(scores, idx, axis=-1)
+        if cfg.norm_topk_prob:
+            w = w / (w.sum(axis=-1, keepdims=True) + 1e-20)
+        return w.astype(x.dtype), idx.astype(jnp.int32)
     probs = jax.nn.softmax(logits, axis=-1)                    # [N, E]
     w, idx = jax.lax.top_k(probs, cfg.num_experts_per_tok)     # [N, k]
     if cfg.norm_topk_prob:
         w = w / jnp.maximum(w.sum(axis=-1, keepdims=True), 1e-9)
     return w.astype(x.dtype), idx.astype(jnp.int32)
+
+
+def _route(cfg: ModelConfig, x: jnp.ndarray, p: dict):
+    r = p["router"]
+    if cfg.router_scoring == "sigmoid":
+        return route(cfg, x, r["kernel"], r["bias"])
+    return route(cfg, x, r["kernel"])
 
 
 def _expert_ffn_ragged(x: jnp.ndarray, p: dict, group_sizes: jnp.ndarray,
@@ -78,9 +99,14 @@ def _expert_ffn_ragged(x: jnp.ndarray, p: dict, group_sizes: jnp.ndarray,
 
 def _assignments(cfg: ModelConfig, idx: jnp.ndarray, live):
     """(flat expert id per (token, choice) [N*k] — E for a dead row's —,
-    live rows per expert [E])."""
+    live rows per expert [E]). With an expert SHARE (the router scores more
+    experts than the E held here) the ids are mapped to the held range and
+    a chosen expert held elsewhere is a dead row too: it joins no group."""
     E = cfg.num_experts
     flat_e = idx.reshape(-1)
+    if cfg.expert_share:
+        flat_e = flat_e - cfg.expert_offset
+        flat_e = jnp.where((flat_e >= 0) & (flat_e < E), flat_e, E)
     if live is not None:
         flat_e = jnp.where(jnp.repeat(live, idx.shape[1]), flat_e, E)
     return flat_e, jnp.zeros((E + 1,), jnp.int32).at[flat_e].add(1)[:E]
@@ -107,7 +133,7 @@ def _sorted_groups(cfg: ModelConfig, x: jnp.ndarray, p: dict, live=None):
     """
     N, H = x.shape
     E, k = cfg.num_experts, cfg.num_experts_per_tok
-    w, idx = route(cfg, x, p["router"]["kernel"])
+    w, idx = _route(cfg, x, p)
     flat_e, group_sizes = _assignments(cfg, idx, live)         # [N*k], [E]
     order = jnp.argsort(flat_e)                                # stable
     tok = order // k                                           # source token
@@ -116,7 +142,8 @@ def _sorted_groups(cfg: ModelConfig, x: jnp.ndarray, p: dict, live=None):
     ys = _expert_ffn_ragged(xs, p, group_sizes,
                             expert_of_row=jnp.minimum(sorted_e, E - 1))
     ys = ys * w.reshape(-1)[order][:, None]
-    if live is not None:   # rows of no group hold whatever the kernel left
+    if live is not None or cfg.expert_share:
+        # rows of no group hold whatever the kernel left
         ys = jnp.where((sorted_e < E)[:, None], ys, 0)
     # every token owns exactly k sorted rows: bring them home with the
     # inverse permutation and sum over k (a gather; the scatter-add this
@@ -145,7 +172,7 @@ def moe_mlp_gshard(cfg: ModelConfig, x: jnp.ndarray, p: dict) -> jnp.ndarray:
     N, H = x.shape
     E, k = cfg.num_experts, cfg.num_experts_per_tok
     C = gshard_capacity(cfg, N)
-    w, idx = route(cfg, x, p["router"]["kernel"])
+    w, idx = _route(cfg, x, p)
     # Queue position of each (token, choice) within its expert, in flat
     # (token-major) arrival order; positions >= C overflow and drop.
     onehot_e = jax.nn.one_hot(idx.reshape(-1), E, dtype=jnp.int32)  # [N*k, E]
@@ -185,7 +212,7 @@ def _every_expert(cfg: ModelConfig, x: jnp.ndarray, p: dict, live=None):
     that latches weight tiles 28 (PERF.md, PR 26).
     x: [N, H] → ([N, H], group_sizes [E])."""
     E = cfg.num_experts
-    w, idx = route(cfg, x, p["router"]["kernel"])
+    w, idx = _route(cfg, x, p)
     flat_e, group_sizes = _assignments(cfg, idx, live)
     # [N, k, E]: a dead row's id is E, which one_hot maps to all zeros
     hot = jax.nn.one_hot(flat_e.reshape(idx.shape), E, dtype=x.dtype)
@@ -220,7 +247,9 @@ def routed_rows(live):
     """``live`` [N] bool: the packed rows that carry a token. Yields a dict
     whose ``stats`` is, after a model_forward_carry inside, int32 [L, 2]:
     per layer the experts with at least one live row and the rows of the
-    largest group. With ``live`` None (a dense model's step program has no
+    largest group ([L, 3] for an expert share: then also the (token,
+    expert) pairs that landed on a held expert). With ``live`` None (a
+    dense model's step program has no
     such operand) nothing is installed and ``stats`` stays None."""
     if live is None:
         yield {"stats": None}
@@ -275,6 +304,10 @@ def moe_mlp(cfg: ModelConfig, x: jnp.ndarray, p: dict) -> jnp.ndarray:
     else:
         out, group_sizes = _sorted_groups(cfg, x, p, live)
     if ctx is not None:
-        ctx["layer"] = jnp.stack([(group_sizes > 0).sum(),
-                                  group_sizes.max()]).astype(jnp.int32)
+        stats = [(group_sizes > 0).sum(), group_sizes.max()]
+        if cfg.expert_share:
+            # (token, expert) pairs of live rows that landed on an expert
+            # held here (the host knows how many those rows chose)
+            stats.append(group_sizes.sum())
+        ctx["layer"] = jnp.stack(stats).astype(jnp.int32)
     return out

@@ -120,6 +120,11 @@ def param_pspecs(cfg: ModelConfig, quant_weights: bool = False) -> dict:
     """Full-parameter PartitionSpec pytree (same structure as init_params;
     with ``quant_weights`` the structure of models/quant.quantize_params,
     including MoE expert scales)."""
+    if cfg.layer_pattern:
+        raise ValueError(
+            "no rule says how a model with a layer pattern shards (per-kind "
+            "stacks, per-slot state leaves): it is served on one chip, "
+            "every leaf whole")
     specs: dict = {
         "embed": {"weight": P("tp", None)},  # vocab-sharded
         "layers": _layer_pspecs(cfg, quant_weights=quant_weights),

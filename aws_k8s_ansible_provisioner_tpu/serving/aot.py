@@ -47,6 +47,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import math
 import os
 import sys
 import time
@@ -177,13 +178,25 @@ def _abstract_state(plan, mesh, device=None):
         lambda: init_params(cfg, jax.random.PRNGKey(0), dtype))
     if plan.weights_quant:
         params = jax.eval_shape(lambda p: quantize_params(p, cfg), params)
+    # one chip holds every leaf whole: no spec is read (and a model with a
+    # layer pattern, which the engine refuses a mesh, has no rule table)
     params = _with_sharding(
-        params, param_pspecs(cfg, quant_weights=plan.weights_quant), mesh,
+        params, None if mesh is None
+        else param_pspecs(cfg, quant_weights=plan.weights_quant), mesh,
         device)
     cache = jax.eval_shape(
         lambda: kvp.init_pool(cfg, plan.total_pages, serving.page_size,
                               dtype, quant=plan.kv_quant))
     cache = _with_sharding(cache, pool_pspecs(plan.kv_quant), mesh, device)
+    if cfg.recurrent:
+        # the per-slot recurrent state beside the pool (one chip: the
+        # engine refuses a mesh for such a model)
+        from aws_k8s_ansible_provisioner_tpu.ops import linear_attention
+
+        state = jax.eval_shape(lambda: linear_attention.init_state(
+            cfg, plan.num_slots, dtype))
+        cache.update(_with_sharding(
+            state, jax.tree.map(lambda _: None, state), None, device))
     return params, cache
 
 
@@ -193,13 +206,16 @@ def _sharded_bytes(sds_tree, pspec_tree, mesh) -> int:
     (replicated leaves count whole — every chip holds them)."""
     import jax
 
+    if mesh is None:
+        return sum(math.prod(leaf.shape) * leaf.dtype.itemsize
+                   for leaf in jax.tree.leaves(sds_tree))
     total = 0
     leaves = zip(jax.tree.leaves(sds_tree),
                  jax.tree.leaves(pspec_tree, is_leaf=lambda x: x is None
                                  or isinstance(x, tuple)))
     for leaf, spec in leaves:
         shards = 1
-        if mesh is not None and spec is not None:
+        if spec is not None:
             for axes in spec:
                 for ax in ((axes,) if isinstance(axes, str)
                            else (axes or ())):
@@ -239,16 +255,21 @@ def enumerate_programs(plan, mesh, params, cache, bblock: int = 1):
     rng = jax.eval_shape(lambda: jax.random.PRNGKey(0))
     scalar = sds((), i32)
 
+    # a model with recurrent layers: the prefill programs are told whose
+    # per-slot state they build (EnginePrograms._state_kw)
+    slot_kw = dict(slot=scalar) if cfg.recurrent else {}
+
     def prefill_kwargs(n: Optional[int] = None):
         """Per-request operand rows; ``n`` rows for the batch program, the
         single-prompt scalar layout otherwise."""
         if n is None:
             return dict(
-                pages=sds((pps,), i32),
+                **slot_kw, pages=sds((pps,), i32),
                 seed=sds((), u32), ban_ids=sds((BAN_K,), i32),
                 ban_until=scalar, bias_ids=sds((BIAS_K,), i32),
                 bias_vals=sds((BIAS_K,), f32), rep=sds((), f32))
         return dict(
+            **({"slots": sds((n,), i32)} if cfg.recurrent else {}),
             tables=sds((n, pps), i32),
             seeds=sds((n,), u32), ban_ids=sds((n, BAN_K), i32),
             ban_until=sds((n,), i32), bias_ids=sds((n, BIAS_K), i32),
@@ -283,11 +304,12 @@ def enumerate_programs(plan, mesh, params, cache, bblock: int = 1):
              seed=sds((), u32), ban_ids=sds((BAN_K,), i32),
              ban_until=scalar, bias_ids=sds((BIAS_K,), i32),
              bias_vals=sds((BIAS_K,), f32), rep=sds((), f32),
-             rep_seen=sds((cfg.vocab_size,), jnp.bool_))))
+             rep_seen=sds((cfg.vocab_size,), jnp.bool_), **slot_kw)))
 
     # an MoE model's decode and mixed programs take the live-slot mask
     # (EnginePrograms._live_rows); a dense model's take no such operand
-    live = sds((B,), jnp.bool_) if cfg.num_experts > 0 else None
+    live = sds((B,), jnp.bool_) \
+        if cfg.num_experts > 0 or cfg.recurrent else None
 
     def decode_kwargs(penalties=False, logprobs=False):
         kw = dict(
@@ -406,8 +428,10 @@ def build_ledger(plan, mesh, params, cache, entries,
         param_pspecs, pool_pspecs)
 
     capacity = int(hbm_gib * 2**30)
-    pspecs = param_pspecs(plan.cfg, quant_weights=plan.weights_quant)
+    pspecs = None if mesh is None \
+        else param_pspecs(plan.cfg, quant_weights=plan.weights_quant)
     params_bytes = _sharded_bytes(params, pspecs, mesh)
+    # ``cache``: the pool and, beside it, a recurrent model's per-slot state
     kv_bytes = _sharded_bytes(cache, pool_pspecs(plan.kv_quant), mesh)
     max_temp = max((e["temp_bytes"] for e in entries), default=0)
     total = params_bytes + kv_bytes + max_temp

@@ -311,6 +311,7 @@ class Engine(EnginePrograms):
         self._init_params_and_cache(mesh, lora)
 
         self.metrics = EngineMetrics()
+        self.metrics.kda_state_bytes.set(self.kda_state_bytes)
         # AOT manifest summary (serving/aot.py), installed by
         # load_aot_manifest; surfaced on /healthz and the hbm gauge.
         self.aot = None
@@ -463,10 +464,14 @@ class Engine(EnginePrograms):
         mon.install_cost_model(_devmon.CostModel.from_config(
             self.cfg, kv_dtype=self.serving.kv_dtype,
             weight_bytes=params_bytes))
-        cache_bytes = _tree_bytes(self.cache)
+        # the pool alone: the per-slot recurrent state beside it is held
+        # whole whatever the pages do, and ledgered as its own component
+        cache_bytes = _tree_bytes(self.cache) - self.kda_state_bytes
 
         def _live() -> dict:
             comp = {"params": float(params_bytes)}
+            if self.kda_state_bytes:
+                comp["kda_state"] = float(self.kda_state_bytes)
             sts = [a.stats() for a in self.allocators]
             total = sum(s["pages_total"] for s in sts) or 1
             live = sum(s["pages_live"] for s in sts)
@@ -604,7 +609,12 @@ class Engine(EnginePrograms):
         matched: List[int] = []
         n = 0
         host_keys: List[tuple] = []
-        if self.serving.prefix_cache and req.prompt_logprobs is None:
+        if self.cfg.recurrent:
+            # restoring K/V pages without the recurrent state that goes
+            # with them would be wrong: no lookup, every admission (a
+            # preemption's resume too) prefills from token 0
+            self.metrics.prefix_lookups_skipped.inc(reason="recurrent_state")
+        elif self.serving.prefix_cache and req.prompt_logprobs is None:
             req_lidx = (self.lora_names.index(req.lora) + 1
                         if req.lora is not None else 0)
             matched, n, host_keys = allocator.lookup_prefix(
@@ -762,7 +772,7 @@ class Engine(EnginePrograms):
         row is still pending the next dispatch, so indexing past
         len(ids) - 1 would publish a page with one garbage row to every
         future prefix hit (review r3)."""
-        if not self.serving.prefix_cache:
+        if not self.serving.prefix_cache or self.cfg.recurrent:
             # no lookup side -> indexing would be pure overhead, and
             # unindexed pages go straight back to the free list at release
             return

@@ -270,6 +270,22 @@ class EngineMetrics:
             "tpu_serve_moe_group_rows_max",
             "Rows of the largest expert group in the last decode or mixed "
             "dispatch"))
+        self.moe_rows_held = r.register(Counter(
+            "tpu_serve_moe_rows_held_total",
+            "(token, expert) rows of live tokens that landed on an expert "
+            "held on this chip (an expert share routes over more experts "
+            "than it holds), per layer, by step program", ("program",)))
+        self.kda_rows = r.register(Counter(
+            "tpu_serve_kda_rows_total",
+            "Rows that advanced a recurrent (KDA) state, per layer, by step "
+            "program", ("program",)))
+        self.kda_state_bytes = r.register(Gauge(
+            "tpu_serve_kda_state_bytes",
+            "Bytes of per-slot recurrent state held beside the KV pool"))
+        self.prefix_lookups_skipped = r.register(Counter(
+            "tpu_serve_prefix_lookups_skipped_total",
+            "Admissions that did not consult the prefix index, by reason",
+            ("reason",)))
         self.prefix_cache_hits = r.register(Counter(
             "tpu_serve_prefix_cache_hits_total",
             "Requests that reused a cached prompt prefix"))

@@ -48,6 +48,7 @@ from aws_k8s_ansible_provisioner_tpu.ops.attention import (
     make_spec_attend_carry_paged,
 )
 from aws_k8s_ansible_provisioner_tpu.ops import kv_pool as kvp
+from aws_k8s_ansible_provisioner_tpu.ops import linear_attention as _la
 from aws_k8s_ansible_provisioner_tpu.ops import moe as _moe
 from aws_k8s_ansible_provisioner_tpu.ops.sampling import (apply_allow,
                                                            apply_penalties,
@@ -370,11 +371,25 @@ def _restore_count_row(counts, slot, row):
 
 def _moe_summary(stats):
     """int32 [..., L, 2] per-layer (experts hit, largest group) → float32
-    [2]: mean experts hit, largest group anywhere. None stays None."""
+    [2]: mean experts hit, largest group anywhere. None stays None. An
+    expert share's [..., L, 3] also carries the pairs that landed on a held
+    expert and gives [3]: summed over substeps, mean over layers — per
+    layer, as the record's ``moe_rows`` is."""
     if stats is None:
         return None
-    return jnp.stack([stats[..., 0].astype(jnp.float32).mean(),
-                      stats[..., 1].max().astype(jnp.float32)])
+    out = [stats[..., 0].astype(jnp.float32).mean(),
+           stats[..., 1].max().astype(jnp.float32)]
+    if stats.shape[-1] == 3:
+        out.append(stats[..., 2].astype(jnp.float32).reshape(
+            -1, stats.shape[-2]).sum(axis=0).mean())
+    return jnp.stack(out)
+
+
+def _recur(cfg: ModelConfig, make, *args):
+    """The KDA layers' callback for a step program, from the same row
+    metadata its ``attend`` is built from; None for a model without
+    recurrent layers (no operand, no op: its jaxpr is what it was)."""
+    return make(*args) if cfg.recurrent else None
 
 
 @partial(jax.jit, static_argnums=(0,),
@@ -384,12 +399,14 @@ def prefill_step(cfg: ModelConfig, params, cache, tokens, true_len, rng,
                  temperature, top_k, top_p, *, pages, logprobs: bool = False,
                  seed=None, ban_ids=None, ban_until=None,
                  bias_ids=None, bias_vals=None, rep=None, allow=None,
-                 lora_idx=None, prompt_logprobs: bool = False):
+                 lora_idx=None, prompt_logprobs: bool = False, slot=None):
     """Prefill one prompt into one slot; returns (cache, first sampled token).
 
     tokens: [1, T] right-padded to a bucket; true_len: scalar valid length;
     ``cache`` is the paged pool and ``pages`` ([max_pages] int32) the slot's
-    block table, through which the rows scatter (ops/kv_pool.py).
+    block table, through which the rows scatter (ops/kv_pool.py). ``slot``
+    (scalar; a model with recurrent layers only): whose per-slot state the
+    prompt builds, from zeros.
     """
     T = tokens.shape[1]
     positions = jnp.arange(T, dtype=jnp.int32)[None, :]
@@ -398,8 +415,9 @@ def prefill_step(cfg: ModelConfig, params, cache, tokens, true_len, rng,
         # restack buffer OOMed the batch-128 program on chip (r5)
         attend = make_prefill_attend_paged_carry(
             pages, true_len, window=cfg.sliding_window)
-        logits, cache = model_forward_carry(params, cfg, tokens, positions,
-                                            cache, attend)
+        logits, cache = model_forward_carry(
+            params, cfg, tokens, positions, cache, attend,
+            _recur(cfg, _la.make_recur_span, slot, 0, true_len))
     last = jnp.take(logits[0], true_len - 1, axis=0)[None]   # [1, V]
     last = _apply_prefill_repetition(last, tokens, true_len[None],
                                      rep[None] if rep is not None else None)
@@ -432,7 +450,8 @@ def prefill_batch_step(cfg: ModelConfig, params, cache, tokens, true_lens,
                        logprobs: bool = False, seeds=None,
                        ban_ids=None, ban_until=None,
                        bias_ids=None, bias_vals=None, reps=None, allow=None,
-                       lora_idx=None, prompt_logprobs: bool = False):
+                       lora_idx=None, prompt_logprobs: bool = False,
+                       slots=None):
     """Prefill N prompts into N slots in ONE dispatch.
 
     tokens: [N, T] right-padded to a (row, length) bucket; true_lens/
@@ -448,8 +467,9 @@ def prefill_batch_step(cfg: ModelConfig, params, cache, tokens, true_lens,
     with lora_context(lora_idx):
         attend = make_prefill_attend_batch_paged_carry(
             tables, true_lens, window=cfg.sliding_window)
-        logits, cache = model_forward_carry(params, cfg, tokens, positions,
-                                            cache, attend)
+        logits, cache = model_forward_carry(
+            params, cfg, tokens, positions, cache, attend,
+            _recur(cfg, _la.make_recur_batch, slots, true_lens))
     last = logits[jnp.arange(N), true_lens - 1]            # [N, V]
     last = _apply_prefill_repetition(last, tokens, true_lens, reps)
     if bias_ids is not None:
@@ -474,7 +494,7 @@ def prefill_chunk_step(cfg: ModelConfig, params, cache, tokens, start,
                        logprobs: bool = False, seed=None,
                        ban_ids=None, ban_until=None,
                        bias_ids=None, bias_vals=None, rep=None,
-                       rep_seen=None, allow=None, lora_idx=None):
+                       rep_seen=None, allow=None, lora_idx=None, slot=None):
     """Prefill ONE chunk of a long prompt; decode interleaves between chunks.
 
     tokens: [1, C] (the chunk, right-padded on the final chunk); start: row
@@ -491,8 +511,9 @@ def prefill_chunk_step(cfg: ModelConfig, params, cache, tokens, start,
     with lora_context(lora_idx):
         attend = make_chunk_prefill_attend_paged_carry(
             pages, start, window=cfg.sliding_window)
-        logits, cache = model_forward_carry(params, cfg, tokens, positions,
-                                            cache, attend)
+        logits, cache = model_forward_carry(
+            params, cfg, tokens, positions, cache, attend,
+            _recur(cfg, _la.make_recur_span, slot, start, chunk_len))
     last = jnp.take(logits[0], chunk_len - 1, axis=0)[None]  # [1, V]
     if rep is not None and rep_seen is not None:
         # chunks only carry a slice of the prompt: the seen-set over the
@@ -571,8 +592,9 @@ def decode_steps(cfg: ModelConfig, n_steps: int, params, cache, tokens,
             lens, table, impl=impl, mesh=mesh, window=cfg.sliding_window,
             bblock=bblock)
         with _moe.routed_rows(live) as routing:
-            logits, cache = model_forward_carry(params, cfg, tok[:, None],
-                                                positions, cache, attend)
+            logits, cache = model_forward_carry(
+                params, cfg, tok[:, None], positions, cache, attend,
+                _recur(cfg, _la.make_recur_decode, live))
         step_logits = logits[:, 0, :]
         if penalties:
             # presence/frequency/repetition over the [B, V] generated-token
@@ -714,9 +736,13 @@ def mixed_step(cfg: ModelConfig, params, cache, tokens, lengths, ptokens,
     packed_live = None
     if live is not None:
         packed_live = jnp.concatenate([live & ~is_p, ~is_pad])
+    recur = None
+    if cfg.recurrent:   # (not through _recur: its operands would be traced)
+        recur = _la.make_recur_mixed(
+            B, None if live is None else live & ~is_p, pslot, pstart, plen)
     with lora_context(packed_lora), _moe.routed_rows(packed_live) as routing:
         logits, cache = model_forward_carry(params, cfg, packed, positions,
-                                            cache, attend)
+                                            cache, attend, recur)
     # -- decode rows: the decode_steps substep body, verbatim order --------
     dec_logits = logits[0, :B]
     if penalties:
@@ -944,6 +970,40 @@ class EnginePrograms:
         _BBLOCK_CACHE[key] = choice
         return choice
 
+    @staticmethod
+    def _refuse_unsupported(cfg: ModelConfig, serving, mesh, lora) -> None:
+        """What cannot be right yet for a model with recurrent layers or an
+        expert share is refused at start-up, each with its reason."""
+        if not (cfg.recurrent or cfg.expert_share):
+            return
+        what = "recurrent (KDA) layers" if cfg.recurrent \
+            else "an expert share"
+        multi = mesh is not None or serving.mesh.num_devices > 1
+        for bad, why in (
+                (multi, "--tp/--dp > 1: no sharding rule says how the "
+                 "per-slot state leaves or a share's expert stacks divide "
+                 "over a mesh"),
+                (cfg.recurrent and serving.spec_decode,
+                 "speculative decoding: a rejected draft token has already "
+                 "advanced the recurrent state, and no snapshot exists to "
+                 "roll it back"),
+                (cfg.recurrent and bool(lora),
+                 "LoRA adapters: no adapter layout names the KDA layers' "
+                 "projections"),
+                (cfg.recurrent and serving.kv_host_tier_bytes > 0,
+                 "the host KV tier (--kv-host-tier-bytes > 0): a restored "
+                 "page carries K/V without the recurrent state that goes "
+                 "with it"),
+                (cfg.recurrent and serving.kv_dtype == "int8",
+                 "int8 KV: the recurrent state is float32 and the mixed "
+                 "cache has not been compared with the reference"),
+                (cfg.expert_share and cfg.moe_impl == "gshard",
+                 "moe_impl 'gshard': its capacity dispatch has no rule for "
+                 "a chosen expert that is held elsewhere")):
+            if bad:
+                raise ValueError(f"model {cfg.name} has {what} and cannot "
+                                 f"be served with {why}")
+
     def _init_params_and_cache(self, mesh, lora):
         """Program-operand construction, moved verbatim from
         ``Engine.__init__``: dtype resolution, weight quantization, mesh
@@ -986,6 +1046,7 @@ class EnginePrograms:
             raise ValueError(f"kv_dtype={serving.kv_dtype!r}: expected "
                              f"'auto' or 'int8'")
         self.kv_quant = serving.kv_dtype == "int8"
+        self._refuse_unsupported(cfg, serving, mesh, lora)
 
         # Multi-chip serving: a (dp, tp) mesh shards params (Megatron TP),
         # slots over dp, and kv heads over tp (parallel/sharding.py). The
@@ -1149,6 +1210,21 @@ class EnginePrograms:
         else:
             self.cache = kvp.init_pool(cfg, total_pages, ps, dtype,
                                        quant=self.kv_quant)
+        pool_leaves = dict(self.cache)
+        # Recurrent layers keep per-SLOT state beside the pool, in the same
+        # pytree the step programs donate (ops/linear_attention.py)
+        self.kda_state_bytes = _la.state_bytes(cfg, self.num_slots, dtype)
+        if cfg.recurrent:
+            self.cache.update(_la.init_state(cfg, self.num_slots, dtype))
+            import logging
+
+            logging.getLogger(__name__).info(
+                "cache: KV pool %.3f GiB (%d attending layers x %d pages) + "
+                "recurrent state %.3f GiB (%d KDA layers x %d slots)",
+                kvp.pool_bytes(cfg, total_pages, ps, dtype, self.kv_quant)
+                / 2**30, cfg.num_attn_layers, total_pages,
+                self.kda_state_bytes / 2**30,
+                cfg.num_periods * cfg.kda_per_period, self.num_slots)
         self.allocators = [pkv.PagePool(self._group_pages, ps,
                                         first_page=1)
                            for _ in range(self.dp_groups)]
@@ -1167,11 +1243,11 @@ class EnginePrograms:
         # leaf's expected per-page shape [L, Hkv, page, (D)] — the
         # fetch-time truncation check behind chaos kv_offload_error
         self._page_bytes = sum(
-            cfg.num_layers * int(np.prod(arr.shape[2:]))
-            * arr.dtype.itemsize for arr in self.cache.values())
+            cfg.num_attn_layers * int(np.prod(arr.shape[2:]))
+            * arr.dtype.itemsize for arr in pool_leaves.values())
         self._page_shapes = {
-            name: (cfg.num_layers,) + tuple(arr.shape[2:])
-            for name, arr in self.cache.items()}
+            name: (cfg.num_attn_layers,) + tuple(arr.shape[2:])
+            for name, arr in pool_leaves.items()}
         # slot -> scheduled-but-unsettled restore record (timing +
         # byte accounting; correctness rides XLA data dependencies)
         self._restore_pending: dict = {}
@@ -1316,11 +1392,28 @@ class EnginePrograms:
         self._rng, sub = jax.random.split(self._rng)
         return sub
 
+    def _state_kw(self, name: str, value) -> dict:
+        """The slot operand a prefill program takes for a model with
+        recurrent layers (whose per-slot state it builds); no operand at
+        all for any other model."""
+        if not self.cfg.recurrent:
+            return {}
+        return {name: jnp.asarray(value, jnp.int32)}
+
+    def _kda_rows(self, rows: int, slots: int = 0) -> dict:
+        """Dispatch-record fields of a model with recurrent layers:
+        ``kda_rows`` (rows that advance a state in this dispatch, per
+        layer: horizon x active for a decode dispatch) and ``kda_slots``
+        (slots whose state a decode or mixed dispatch reads and writes)."""
+        if not self.cfg.recurrent:
+            return {}
+        return {"kda_rows": int(rows), "kda_slots": int(slots)}
+
     def _live_rows(self, active):
         """[B] bool device mask of the decode rows that hold a request, for
         an MoE model's step programs (None for a dense model: no operand);
         re-uploaded only when the active set changed."""
-        if self.cfg.num_experts <= 0:
+        if self.cfg.num_experts <= 0 and not self.cfg.recurrent:
             return None
         key = tuple(active)
         oc = self._op_cache
@@ -1411,6 +1504,11 @@ class EnginePrograms:
                                   program=prog)
             m.moe_forward_passes.inc(steps, program=prog)
             m.moe_group_rows_max.set(rec["moe_group_max"])
+            if "moe_rows_held" in rec:
+                m.moe_rows_held.inc(rec["moe_rows_held"], program=prog)
+        if "kda_rows" in rec:
+            self.metrics.kda_rows.inc(rec["kda_rows"],
+                                      program=rec["program"])
         _devmon.note(rec["kind"], device_s, batch=batch, tokens=tokens,
                      ctx_rows=ctx_rows, steps=steps, guided_rows=guided_rows)
         _flight.record("dispatch", None, **rec)
@@ -1561,10 +1659,12 @@ class EnginePrograms:
             allow=self._allow_row(req),
             lora_idx=(jnp.asarray(self.lora_idx[slot:slot + 1])
                       if self.lora_names else None),
-            prompt_logprobs=req.prompt_logprobs is not None)
+            prompt_logprobs=req.prompt_logprobs is not None,
+            **self._state_kw("slot", slot))
         drec = self._dispatch_open(
             "prefill_step", "prefill", bucket=bucket,
-            prompt_tokens=len(ids), padded_tokens=bucket)
+            prompt_tokens=len(ids), padded_tokens=bucket,
+            **self._kda_rows(len(ids)))
         with _Dispatching(drec):
             out = prefill_step(self.cfg, self.params, self.cache, *args,
                                **kw)
@@ -1651,12 +1751,12 @@ class EnginePrograms:
             reps=jnp.asarray(reps), allow=allow,
             lora_idx=(jnp.asarray(row_lora) if self.lora_names
                       else None),
-            prompt_logprobs=want_plp)
+            prompt_logprobs=want_plp, **self._state_kw("slots", slots))
         n_prompt = int(true_lens[:len(batch)].sum())
         drec = self._dispatch_open(
             "prefill_batch_step", "prefill_batch", rows=n_bucket,
             bucket=t_bucket, prompt_tokens=n_prompt,
-            padded_tokens=n_bucket * t_bucket)
+            padded_tokens=n_bucket * t_bucket, **self._kda_rows(n_prompt))
         with _Dispatching(drec):
             out = prefill_batch_step(self.cfg, self.params, self.cache,
                                      *args, **kw)
@@ -1730,8 +1830,13 @@ class EnginePrograms:
         # program reading these pages
         self._settle_restore(slot)
         self.lengths[slot] = off
+        # What the walk prefills. A resume ends with decode RE-processing
+        # the last real token at its own row (_activate): rewriting a K/V
+        # row is idempotent, advancing a recurrent state twice is not — so
+        # a model with recurrent layers rebuilds over all but that token.
+        walk = ids[:-1] if resumed and self.cfg.recurrent else ids
         self._chunk = {"req": req, "slot": slot, "off": off,
-                       "C": self._chunk_size, "ids": ids,
+                       "C": self._chunk_size, "ids": ids, "walk": walk,
                        "resumed": resumed, "rep_seen": rep_seen,
                        "mixed": mixed}
 
@@ -1762,7 +1867,7 @@ class EnginePrograms:
             # chunk dispatch rewrites slot state out from under its carry
             self._drain_decode_pipeline("chunk")
         C = st["C"]
-        ids = st["ids"]
+        ids = st["walk"]
         off = st["off"]
         chunk = ids[off:off + C]
         _flight.record("prefill_chunk", req.id, off=off, n=len(chunk))
@@ -1788,10 +1893,12 @@ class EnginePrograms:
                 rep_seen=jnp.asarray(st["rep_seen"]),
                 allow=self._allow_row(req),
                 lora_idx=(jnp.asarray(self.lora_idx[slot:slot + 1])
-                          if self.lora_names else None))
+                          if self.lora_names else None),
+                **self._state_kw("slot", slot))
             drec = self._dispatch_open(
                 "prefill_chunk_step", "prefill_chunk", chunk_rows=C,
-                chunk_n=len(chunk), chunk_off=off)
+                chunk_n=len(chunk), chunk_off=off,
+                **self._kda_rows(len(chunk)))
             with _Dispatching(drec):
                 out = prefill_chunk_step(self.cfg, self.params, self.cache,
                                          *args, **kw)
@@ -1822,8 +1929,8 @@ class EnginePrograms:
                     if req.logprobs is not None and lp_t is not None else None
                 token = int(token)  # device sync
             with self._emit_phase():
-                self._activate(req, slot, token, lp, ids=list(ids),
-                               resumed=st["resumed"])
+                self._activate(req, slot, token, lp,
+                               ids=list(st["ids"]), resumed=st["resumed"])
 
     def _advance_chunk_mixed(self, st: dict) -> None:
         """One RAGGED mixed dispatch: this walk's next prefill chunk packed
@@ -1842,7 +1949,7 @@ class EnginePrograms:
         """
         req, slot = st["req"], st["slot"]
         C = st["C"]
-        ids = st["ids"]
+        ids = st["walk"]
         off = st["off"]
         chunk = ids[off:off + C]
         final = off + len(chunk) >= len(ids)
@@ -1921,8 +2028,8 @@ class EnginePrograms:
             if rec["chunk_lp"] else None
         self._chunk = None
         with self._emit_phase():
-            self._activate(req, slot, rec["chunk_token"], lp, ids=list(ids),
-                           resumed=st["resumed"])
+            self._activate(req, slot, rec["chunk_token"], lp,
+                           ids=list(st["ids"]), resumed=st["resumed"])
 
     def _mixed_dispatch(self, st: dict, chunk, tok_in, len_in) -> dict:
         """Enqueue ONE ragged mixed dispatch (prefill chunk + decode batch)
@@ -1930,7 +2037,7 @@ class EnginePrograms:
         device reads here (tpulint R8); the transfer and emits happen in
         _decode_fetch, which also unpacks the chunk-row outputs."""
         req, slot, off = st["req"], st["slot"], st["off"]
-        ids = st["ids"]
+        ids = st["walk"]
         active = [s for s in self._active_slots() if s != slot]
         # Feature operands (ISSUE 16): guided decode rows carry their FSM
         # allow-bitmask, a guided CHUNKING request carries its own over the
@@ -1964,7 +2071,8 @@ class EnginePrograms:
             "mixed_step", "mixed_step", active, horizon=1,
             chunk_rows=st["C"], chunk_n=len(chunk), chunk_off=off,
             write_pages=(off + len(chunk) - 1) // ps - off // ps + 1,
-            carry_steps=prev["horizon"] if prev is not None else 0)
+            carry_steps=prev["horizon"] if prev is not None else 0,
+            **self._kda_rows(len(active) + len(chunk), len(active)))
         self._book_bubble(drec["t_enqueue"])
         real_counts = self.counts
         with _Dispatching(drec):
@@ -2502,7 +2610,8 @@ class EnginePrograms:
         prev = self._inflight
         drec = self._dispatch_open(
             "decode_steps", "decode", active, horizon=horizon,
-            carry_steps=prev["horizon"] if prev is not None else 0)
+            carry_steps=prev["horizon"] if prev is not None else 0,
+            **self._kda_rows(horizon * len(active), len(active)))
         self._book_bubble(drec["t_enqueue"])
         real_counts = self.counts
         with _Dispatching(drec):
@@ -2594,12 +2703,17 @@ class EnginePrograms:
             if rec.get("moe") is not None:
                 # routing counts of an MoE model ride the same fetch: the
                 # program has ended, this is a copy of two floats
-                hit, largest = np.asarray(rec["moe"])
+                moe = np.asarray(rec["moe"])
+                hit, largest = moe[:2]
                 k = self.cfg.num_experts_per_tok
                 rec["drec"].update(
                     moe_rows=k * (rec["horizon"] * len(rec["active"])
                                   + rec.get("chunk_n", 0)),
                     moe_experts_hit=float(hit), moe_group_max=int(largest))
+                if moe.shape[0] == 3:
+                    # an expert share: the pairs that landed on an expert
+                    # held here, of the moe_rows chosen (per layer)
+                    rec["drec"]["moe_rows_held"] = float(moe[2])
         t_ready = time.monotonic()
         horizon = rec["horizon"]
         active = rec["active"]

@@ -1645,6 +1645,13 @@ def build_state(serving_cfg=None, model_cfg=None, params=None,
                                      eos_token_id=tokenizer.eos_token_id,
                                      num_layers=4, hidden_size=128,
                                      sliding_window=32)
+        elif serving.model == "tiny-solar":
+            # the hybrid dry-run model: gated NoPE GQA + KDA layers 1:3,
+            # an expert share (4 of 16 held) and a shared expert
+            from aws_k8s_ansible_provisioner_tpu.config import tiny_solar
+
+            model_cfg = tiny_solar(vocab_size=tokenizer.vocab_size,
+                                   eos_token_id=tokenizer.eos_token_id)
         else:
             raise ValueError(f"unknown model {serving.model!r} and no checkpoint")
 

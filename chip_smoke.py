@@ -45,7 +45,11 @@ plain float32 reference (benchmark/reference/), which shares no code with
 the program — for an MoE model it routes on its own activations. Everything
 else is the same run: the same requests, a new 700-token prompt admitted
 under a live stream (``mixed_step``'s expert path), kernel parity at the
-model's own head shape.
+model's own head shape. A configuration without a ``registry_name`` (a cut
+of a published model, e.g. the Solar-Open2 hybrid's expert share) is served
+from its own ``model_config`` fields; for it ``m700`` also passes the KDA
+chunk rows and the state hand-over at the chunk's end through
+``mixed_step`` against the reference.
 
 ``--rehearse`` is the builder's CPU rehearsal of this same script (tiny
 model, XLA attention, interpret-mode kernel parity at small shapes, no
@@ -313,6 +317,9 @@ def run_requests(srv: Server, model: str) -> dict:
           f"chat stream: status {r['status']} done {r['done']} "
           f"tokens {len(r['token_ids'])}")
     expected += 32
+    # whether the wave's first arrival was admitted alone (``prefill_step``)
+    # or batched with the rest is a race; this one met an idle engine
+    ran |= dispatched(port)
     say("requests: streamed /v1/chat/completions ok (32 tokens)")
 
     # Two admissions WHILE another stream decodes, so they ride the ragged
@@ -396,13 +403,24 @@ def run_requests(srv: Server, model: str) -> dict:
         key = f'tpu_serve_request_total{{status="{bad}"}}'
         check(delta(key) == 0, f"{key} moved by {delta(key)}")
     hits = delta("tpu_serve_prefix_cache_hits_total")
-    check(hits >= 1, "the repeated long prompt did not hit the prefix cache")
+    if srv.engine.cfg.recurrent:
+        # K/V pages restored without the recurrent state that goes with
+        # them would be wrong: such a model is never handed a prefix hit
+        skipped = sum(v for k, v in after.items() if k.startswith(
+            "tpu_serve_prefix_lookups_skipped_total"))
+        check(hits == 0 and skipped >= 1,
+              f"a model with recurrent layers read {hits} prefix hit(s); "
+              f"lookups skipped {skipped}")
+    else:
+        check(hits >= 1,
+              "the repeated long prompt did not hit the prefix cache")
 
     status, raw = http_json(port, "GET", "/healthz")
     check(status == 200, f"/healthz -> {status}")
     hz = json.loads(raw)
     check(hz["status"] == "ok" and not hz["last_error"],
           f"/healthz status {hz['status']} last_error {hz['last_error']}")
+    ran |= dispatched(port)      # the admissions beside a live stream
     for kind in ("prefill", "prefill_batch", "decode", "mixed_step"):
         check(kind in ran, f"program kind {kind!r} never dispatched "
                            f"(dispatched: {sorted(ran)})")
@@ -749,7 +767,9 @@ def check_routing_counts(port: int, cfg) -> None:
     check(passes > 0, f"no MoE forward pass was counted: {tot}")
     hit = tot["tpu_serve_moe_experts_hit_total"] / passes
     rows = tot["tpu_serve_moe_routed_rows_total"] / passes
-    check(1 <= hit <= cfg.num_experts, f"experts hit a pass: {hit}")
+    # an expert share counts the experts HELD here: one live row may hit none
+    check((0 if cfg.expert_share else 1) <= hit <= cfg.num_experts,
+          f"experts hit a pass: {hit}")
     say(f"routing: {int(passes)} forward passes of decode and mixed "
         f"dispatches; {rows:.1f} routed rows and {hit:.1f} of "
         f"{cfg.num_experts} experts hit a layer, mean; largest group last "
@@ -776,8 +796,10 @@ def expert_forms_parity(cfg, rows: int) -> None:
         return {"kernel": q,
                 "scale": jnp.full((E, dout), std / 73.6, jnp.float32)}
 
-    p = {"router": {"kernel": (jax.random.normal(ks[0], (H, E))
-                               * 2.0 / H ** 0.5).astype(jnp.bfloat16)},
+    R = cfg.router_width     # an expert share routes over more than E
+    p = {"router": {"kernel": (jax.random.normal(ks[0], (H, R))
+                               * 2.0 / H ** 0.5).astype(jnp.bfloat16),
+                    "bias": jnp.zeros((R,), jnp.float32)},
          "w_gate": stack(ks[1], H, inter, 0.9 / H ** 0.5),
          "w_up": stack(ks[2], H, inter, 0.9 / H ** 0.5),
          "w_down": stack(ks[3], inter, H, 0.9 / inter ** 0.5)}
@@ -802,6 +824,231 @@ def expert_forms_parity(cfg, rows: int) -> None:
         f"{float(np.max(np.abs(got - want))):.4f} on outputs of max "
         f"|{float(np.abs(want).max()):.2f}|; experts hit "
         f"{int((np.asarray(gs) > 0).sum())}")
+
+
+def handed_routing_program(cfg, T: int):
+    """The program's own stateless forward over one T-token sequence
+    (bfloat16, the served tree, ``kda_span`` from zero, the expert form the
+    shape picks), unrolled layer by layer with ``ops.moe.route`` wrapped: it
+    records each layer's choices and, where ``use`` is set, takes the
+    ``handed`` ones [layers, T, k] instead (weighted by the program's own
+    scores of them). Jitted: (tree, tokens [T], handed, use) -> (logprob
+    rows [T - 1, V] float32, the choices made [layers, T, k])."""
+    import jax
+    import jax.numpy as jnp
+
+    from aws_k8s_ansible_provisioner_tpu.models import layers as L
+    from aws_k8s_ansible_provisioner_tpu.ops import linear_attention as la
+    from aws_k8s_ansible_provisioner_tpu.ops import moe
+
+    def program(tree, toks, handed, use):
+        picked, own = [], moe.route
+
+        def route(c, x, kernel, bias=None):
+            w, idx = own(c, x, kernel, bias)
+            theirs = handed[len(picked)]
+            picked.append(idx)
+            s = jax.nn.sigmoid(x.astype(jnp.float32)
+                               @ kernel.astype(jnp.float32))
+            wt = jnp.take_along_axis(s, theirs, axis=-1)
+            wt = (wt / wt.sum(axis=-1, keepdims=True)).astype(w.dtype)
+            return jnp.where(use, wt, w), jnp.where(use, theirs, idx)
+
+        moe.route = route
+        try:
+            pos = jnp.arange(T, dtype=jnp.int32)[None]
+            x, cos, sin = L._embed_inputs(tree, cfg, toks[None], pos)
+            attend = L.make_default_attend(cfg)
+            for period in range(cfg.num_periods):
+                j = 0
+                for kind in cfg.layer_pattern:
+                    if kind == "g":
+                        lp = jax.tree.map(lambda a: a[period],
+                                          tree["layers"]["gqa"])
+                        x, _ = L.decoder_block(
+                            cfg, lp, x, cos, sin,
+                            lambda q, kk, v, cl: (attend(q, kk, v, None)[0],
+                                                  cl), None)
+                    else:
+                        lp = jax.tree.map(lambda a, j=j: a[period, j],
+                                          tree["layers"]["kda"])
+                        x, _ = L.kda_block(cfg, lp, x, la.recur_from_zero,
+                                           (None, 0, 0))
+                        j += 1
+            logits = L._final_logits(tree, cfg, x)[0].astype(jnp.float32)
+        finally:
+            moe.route = own
+        return jax.nn.log_softmax(logits, axis=-1)[:-1], jnp.stack(picked)
+
+    return jax.jit(program)
+
+
+def check_routing_cause(cfg, params, plain, rows: int,
+                        strict: bool = True) -> None:
+    """Where an expert share's distance from its reference comes from, and
+    whether the comparison can see ONE expert — directly, at the served
+    size, on the served int8 tree.
+
+    The reference routes on its own float32 activations; the program on
+    bfloat16 ones. Where a token's 8th and 9th scores are nearer than that
+    noise the two pick different experts, and with a renormalised sigmoid
+    router (eight nearly equal weights) each such flip swaps a whole eighth
+    of the routed sum. So this phase (1) COUNTS the token-layers whose chosen
+    sets differ, and those where the difference touches a held expert; (2)
+    hands each side the OTHER's choices and measures what is left — with the
+    held experts' down projections at ``GAIN`` times the benchmark maker's
+    (their scales alone are multiplied: the big leaves are shared), where
+    routing on their own the two sit far apart; (3) under handed routing,
+    DROPS one held expert (its down scale zeroed in every layer) and hands
+    the program a WRONG one (a held id shifted by one): each has to move the
+    logprobs past LOGPROB_NATS, which on its own routing no gain lets the
+    comparison tell from the flips (a flip IS a wrong expert in one
+    token-layer).
+
+    The program side is :func:`handed_routing_program`. Distances are over every position of one ``rows``-token sequence:
+    |program - reference| of the reference's most likely token, the worst
+    position and the median over all windows of 16 consecutive positions of
+    the window's worst (16 positions are what one comparison of the
+    benchmark sees)."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    GAIN, T = 6.0, rows
+    mc = dataclasses.asdict(cfg)
+    k, E, off = cfg.num_experts_per_tok, cfg.num_experts, cfg.expert_offset
+    n_layers = cfg.num_layers
+
+    def down_scaled(tree, factor, expert=None):
+        """``tree`` with the held experts' down scales times ``factor``
+        (``expert``: that held expert's alone)."""
+        out = dict(tree, layers={kind: dict(sub) for kind, sub
+                                 in tree["layers"].items()})
+        for sub in out["layers"].values():
+            sc = sub["w_down"]["scale"]                  # [..., E, H]
+            f = factor if expert is None else jnp.where(
+                jnp.arange(E)[:, None] == expert, factor, 1.0)
+            sub["w_down"] = dict(sub["w_down"], scale=sc * f)
+        return out
+
+    program = handed_routing_program(cfg, T)
+    ids = np.random.default_rng(20260928).integers(32, 127, T)
+    toks = jnp.asarray(ids, jnp.int32)
+    none = jnp.zeros((n_layers, T, k), jnp.int32)
+    hot = down_scaled(params, GAIN)
+    at = np.arange(T - 1)
+
+    def reference(tree, routing=None):
+        with jax.default_matmul_precision("highest"):
+            lg, idx = plain.forward(mc, tree, list(ids), T - 1,
+                                    routing=routing)
+            return (np.asarray(jax.nn.log_softmax(lg, axis=-1)),
+                    np.asarray(idx))
+
+    def served(tree, handed=None):
+        lp, idx = program(tree, toks, none if handed is None
+                          else jnp.asarray(handed), handed is not None)
+        return np.asarray(lp), np.asarray(idx)
+
+    def apart(a, b, tok):
+        d = np.abs(a[at, tok] - b[at, tok])
+        worst16 = [d[i:i + 16].max() for i in range(0, len(d) - 15)]
+        return float(d.max()), float(np.median(worst16))
+
+    def held(idx):      # per token-layer, which held experts were chosen
+        hit = np.zeros(idx.shape[:2] + (E + 1,), bool)
+        loc = np.where((idx >= off) & (idx < off + E), idx - off, E)
+        np.put_along_axis(hit, loc, True, axis=-1)
+        return hit[..., :E]
+
+    def flips(a, b):
+        """% of token-layers whose chosen SET differs, whose HELD set
+        differs, and the first by layer (layer 0 reads the same embedding
+        rows on both sides: what bfloat16 alone does to a top-k)."""
+        other = (np.sort(a, axis=-1) != np.sort(b, axis=-1)).any(axis=-1)
+        other_held = (held(a) != held(b)).any(axis=-1)
+        return (100 * other.mean(), 100 * other_held.mean(),
+                " ".join(f"{100 * x:.0f}" for x in other.mean(axis=1)))
+
+    t0 = time.monotonic()
+    ref_lp, ref_idx = reference(hot)
+    tok = ref_lp.argmax(axis=-1)
+    own_lp, own_idx = served(hot)
+    on_held = float(held(ref_idx).sum()) / ref_idx.size
+    d_own = apart(own_lp, ref_lp, tok)
+    d_handed = apart(served(hot, ref_idx)[0], ref_lp, tok)
+    d_back = apart(own_lp, reference(hot, own_idx)[0], tok)
+    base_lp, base_idx = served(params)
+    base_ref_lp, base_ref_idx = reference(params)
+    d_base = apart(base_lp, base_ref_lp, base_ref_lp.argmax(axis=-1))
+    f_hot, f_base = flips(ref_idx, own_idx), flips(base_ref_idx, base_idx)
+    # the held expert the reference chose most often, dropped; and the
+    # program handed its neighbour instead
+    e = int(np.bincount((ref_idx - off)[(ref_idx >= off)
+                                        & (ref_idx < off + E)],
+                        minlength=E).argmax())
+    d_drop = apart(served(down_scaled(hot, 0.0, e), ref_idx)[0], ref_lp, tok)
+    wrong = np.where(ref_idx == off + e, off + (e + 1) % E, ref_idx)
+    d_wrong = apart(served(hot, wrong)[0], ref_lp, tok)
+    uses = float((ref_idx == off + e).any(axis=-1).mean())
+    say(f"routing cause [{T} positions x {n_layers} layers; distances are "
+        f"(worst position, median worst-of-16) in nats; "
+        f"{100 * on_held:.1f} % of the pairs chosen land on a held expert]: "
+        f"AT THE BENCHMARK'S GAIN {f_base[0]:.1f} % of token-layers choose "
+        f"another set than the reference ({f_base[1]:.1f} % another HELD "
+        f"set; by layer {f_base[2]}), each on its own routing "
+        f"{d_base[0]:.3f} / {d_base[1]:.3f}; HELD DOWN PROJECTIONS x "
+        f"{GAIN:g}: {f_hot[0]:.1f} % ({f_hot[1]:.1f} % held; by layer "
+        f"{f_hot[2]}), own routing {d_own[0]:.3f} / {d_own[1]:.3f}; the "
+        f"program handed the reference's choices {d_handed[0]:.3f} / "
+        f"{d_handed[1]:.3f}; the reference handed the program's "
+        f"{d_back[0]:.3f} / {d_back[1]:.3f}; handed routing with held expert "
+        f"{e} DROPPED (chosen in {100 * uses:.1f} % of token-layers) "
+        f"{d_drop[0]:.3f} / {d_drop[1]:.3f}, with its neighbour computed in "
+        f"its place {d_wrong[0]:.3f} / {d_wrong[1]:.3f}; "
+        f"{time.monotonic() - t0:.0f}s")
+    if not strict:      # a tiny model's sizes say nothing about these
+        return
+    check(d_handed[0] <= LOGPROB_NATS and d_back[0] <= LOGPROB_NATS,
+          f"with the routing handed over the program and the reference still "
+          f"differ by {max(d_handed[0], d_back[0]):.3f} nats: the expert "
+          f"path itself is off, not the ties")
+    check(d_drop[1] > LOGPROB_NATS and d_wrong[1] > LOGPROB_NATS,
+          f"one dropped ({d_drop[1]:.3f}) or wrong ({d_wrong[1]:.3f}) held "
+          f"expert stays inside {LOGPROB_NATS} nats under handed routing")
+
+
+def check_lower_precision(name: str, stream: dict, cfg, params, tokenizer,
+                          plain) -> dict:
+    """The controls: the reference computed one precision BELOW what the
+    configuration states (benchmark/reference/solar_open2.py, ``lower``),
+    held against the served stream by the same two limits, has to come out
+    NOT correct — else the limits would pass a server computing in that
+    type. Returns {control: refused}."""
+    import dataclasses
+
+    import numpy as np
+
+    refused = {}
+    ids = tokenizer.encode(stream["prompt"]) \
+        + [int(t) for t in stream["token_ids"]]
+    n = len(stream["token_ids"])
+    at, tok = np.arange(n), np.asarray(stream["token_ids"])
+    for lower in ("state", "act"):
+        rows = plain.logprobs(dataclasses.asdict(cfg), params, ids, n,
+                              lower=lower)
+        gap = float(np.max(rows.max(axis=-1) - rows[at, tok]))
+        agree = float(np.max(np.abs(np.asarray(stream["logprobs"])
+                                    - rows[at, tok])))
+        refused[lower] = gap > NEAR_MAX_NATS or agree > LOGPROB_NATS
+        say(f"control[{name}, reference with lower={lower!r}]: served token "
+            f"below its maximum by <= {gap:.4f} nats (tol {NEAR_MAX_NATS}); "
+            f"served vs lowered reference differ <= {agree:.4f} nats (tol "
+            f"{LOGPROB_NATS}): "
+            f"{'NOT correct' if refused[lower] else 'correct'}")
+    return refused
 
 
 def check_shards(engine, n: int) -> None:
@@ -965,7 +1212,13 @@ def main() -> int:
         check(opts.chips == 1, "--config is a one-chip run")
         with open(opts.config, encoding="utf-8") as f:
             cfg_file = json.load(f)
-        model = cfg_file["registry_name"]
+        model = cfg_file.get("registry_name")
+        if model is None:
+            # a configuration the program registers no preset of (a cut of
+            # a published model): its own ModelConfig fields, under its name
+            mc = _config.ModelConfig(**cfg_file["model_config"])
+            model = mc.name
+            _config.MODEL_REGISTRY[model] = mc
     if opts.rehearse:
         # the same flags, window and traffic on a model the CPU can serve
         tiny = dict(vocab_size=512, hidden_size=128, max_seq_len=4096,
@@ -975,6 +1228,13 @@ def main() -> int:
             _config.MODEL_REGISTRY[model] = _config.tiny_qwen3(
                 name=model, intermediate_size=256, num_heads=8,
                 num_kv_heads=4, head_dim=32, **tiny)
+        elif _config.MODEL_REGISTRY[model].layer_pattern:
+            # the hybrid: gated NoPE GQA + KDA layers, an expert share
+            model = "rehearse-solar"
+            _config.MODEL_REGISTRY[model] = _config.tiny_solar(
+                name=model, intermediate_size=64, moe_intermediate_size=64,
+                num_heads=4, num_kv_heads=2, head_dim=32, kda_num_heads=4,
+                kda_head_dim=32, kda_low_rank=32, **tiny)
         else:
             check(_config.MODEL_REGISTRY[model].num_experts > 0,
                   "the rehearsal's other model is the OLMoE-shaped one")
@@ -1046,6 +1306,14 @@ def main() -> int:
             check_numerics(name, got[name], cfg, eng.params, tokenizer, plain)
         if cfg.num_experts > 0:
             check_routing_counts(srv.port, cfg)
+        if cfg.expert_share:
+            refused = check_lower_precision("m700", got["m700"], cfg,
+                                            eng.params, tokenizer, plain)
+            check(opts.rehearse or refused["act"],
+                  "the comparison passes a reference computed in float8")
+            check_routing_cause(cfg, eng.params, plain,
+                                64 if opts.rehearse else 256,
+                                strict=not opts.rehearse)
         srv.drain()
         params = None
         # beside the served model's head shape: multi-head attention

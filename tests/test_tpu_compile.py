@@ -313,3 +313,27 @@ def test_mixed_step_holds_no_pool_copy(chip, monkeypatch, model, slots):
     # the ragged one (the names the benchmark's readers match)
     _assert_named_after_wrapper(compiled, pa.cache_write_row_paged)
     _assert_named_after_wrapper(compiled, pa.ragged_attend_pallas_paged)
+
+
+def test_kda_decode_update_compiles_in_place_under_its_own_name(chip):
+    """The KDA decode step's state pass (ops/linear_attention.py, PR 32) at
+    the served shape — 64 slots, 64 heads of 128, two periods of three KDA
+    layers: Mosaic accepts it, the 1.5 GiB float32 state leaf is aliased and
+    not copied, and the trace will name it after its wrapper, which is how
+    the benchmark's readers and PERF.md find it."""
+    from aws_k8s_ansible_provisioner_tpu.ops import linear_attention as la
+
+    def sds(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+
+    periods, nk, slots, heads, d = 2, 3, 64, 64, 128
+    row = sds((slots, heads, d))
+    compiled = jax.jit(
+        lambda s, p, q, k, v, g, b: la.kda_decode_update(s, p, 1, q, k, v,
+                                                         g, b),
+        donate_argnums=(0,)).lower(
+        sds((periods, nk, slots, heads, d, d)), sds((), jnp.int32), row, row,
+        row, row, sds((slots, heads))).compile()
+    _assert_named_after_wrapper(compiled, la.kda_decode_update)
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= 4 * periods * nk * slots * heads * d * d
