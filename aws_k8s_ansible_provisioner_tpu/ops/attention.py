@@ -16,6 +16,7 @@ Design notes (TPU/HBM-first):
 
 from __future__ import annotations
 
+import functools
 from typing import Tuple
 
 import jax
@@ -112,6 +113,42 @@ def chunk_attend(q: jnp.ndarray, ck: jnp.ndarray, cv: jnp.ndarray,
 # ---------------------------------------------------------------------------
 
 
+def _length_order(lengths: jnp.ndarray, table: jnp.ndarray, dp: int,
+                  bblock: int) -> tuple:
+    """The decode kernel's rows in ONE stable ascending order of length:
+    ``(order, inverse, limits, table)`` — the permutation, its inverse, and
+    ``lengths + 1`` and ``table`` already in that order — or ``()`` where the
+    order cannot matter: blocks of one row, or one block (static facts).
+
+    Why: a grid step of decode_attend_pallas_paged serves ``bblock`` rows
+    and walks the pages of its LONGEST one, every shorter row re-copying its
+    last page and running a masked flash update to the end
+    (pallas_attention._paged_db_body). In slot order a block's rows are
+    strangers — a slot's context is wherever its request stands — and three
+    tenths of the pages walked lie beyond some row's end (PERF.md, PR 33).
+    Cut from the sorted order a block's rows are neighbours. A row's flash
+    state does not depend on its block-mates (a masked page adds
+    exp(-1e30 - m) = 0 and scales by exp(0) = 1), so the context is BITWISE
+    the slot-order call's. Under a ``dp`` mesh each shard orders its own
+    rows (indices local to the shard). Idle slots (short rows) sort to the
+    front and fill whole blocks of one page."""
+    from aws_k8s_ansible_provisioner_tpu.ops.pallas_attention import (
+        _resolve_bb)
+
+    rows = lengths.shape[0] // dp
+    bb = _resolve_bb(bblock, rows)
+    if bb == 1 or rows == bb:
+        return ()
+    lens = lengths.reshape(dp, rows)
+    order = jnp.argsort(lens, axis=1, stable=True).astype(jnp.int32)
+    inverse = jnp.argsort(order, axis=1).astype(jnp.int32)
+    limits = jnp.take_along_axis(lens, order, axis=1) + 1
+    tab = jnp.take_along_axis(table.reshape(dp, rows, -1),
+                              order[:, :, None], axis=1)
+    return (order.reshape(-1), inverse.reshape(-1), limits.reshape(-1),
+            tab.reshape(table.shape))
+
+
 def make_decode_attend_carry_paged(lengths: jnp.ndarray, table: jnp.ndarray,
                                    impl: str = "auto", mesh=None,
                                    window: int = 0, bblock: int = 1):
@@ -126,12 +163,20 @@ def make_decode_attend_carry_paged(lengths: jnp.ndarray, table: jnp.ndarray,
     each chip's head slice of every page — the block table, lengths, and
     allocator are head-independent and shared verbatim. The tp flagship
     config (Qwen3-8B over v5e-8 ICI) thus keeps on-demand paging; under a
-    dp mesh the table's GLOBAL page ids are rebased per shard."""
+    dp mesh the table's GLOBAL page ids are rebased per shard.
+
+    The kernel gets its rows IN ORDER OF LENGTH (_length_order, taken here,
+    once a substep: the layers share it), so the ``bblock`` rows of a grid
+    step walk the pages of neighbours; the rows are WRITTEN in slot order
+    (one grid step a slot either way) and the context comes back in slot
+    order."""
     resolved = resolve_impl(impl)
 
     dp = mesh.shape.get("dp", 1) if mesh is not None else 1
+    by_len = _length_order(lengths, table, dp, bblock) \
+        if resolved == "pallas" else ()
 
-    def _write_attend_paged(q, pool, knew, vnew, lens, tab, layer):
+    def _write_attend_paged(q, pool, knew, vnew, lens, tab, layer, *by_len):
         from aws_k8s_ansible_provisioner_tpu.ops import pallas_attention
 
         interpret = not pallas_attention.supported()
@@ -140,8 +185,10 @@ def make_decode_attend_carry_paged(lengths: jnp.ndarray, table: jnp.ndarray,
             # its dp group's partition — rebase to local ids. OOB_PAGE
             # (INT32_MAX) stays far out of range after the subtraction, so
             # padding writes still drop.
-            tab = tab - jax.lax.axis_index("dp").astype(jnp.int32) \
+            rebase = jax.lax.axis_index("dp").astype(jnp.int32) \
                 * pool["k"].shape[1]
+            tab = tab - rebase
+            by_len = by_len and by_len[:3] + (by_len[3] - rebase,)
         ck, cv = pool["k"], pool["v"]
         if "ks" in pool:
             ck, ks = pallas_attention.cache_write_row_quant_paged(
@@ -157,10 +204,20 @@ def make_decode_attend_carry_paged(lengths: jnp.ndarray, table: jnp.ndarray,
                 cv, vnew, lens, tab, layer, interpret=interpret)
             pool = {"k": ck, "v": cv}
             scale_kw = {}
-        ctx = pallas_attention.decode_attend_pallas_paged(
-            q, ck, cv, lens + 1, layer, tab, interpret=interpret,
+        read = functools.partial(
+            pallas_attention.decode_attend_pallas_paged, interpret=interpret,
             window=window, bblock=bblock, **scale_kw)
-        return ctx, pool
+        if not by_len:
+            return read(q, ck, cv, lens + 1, layer, tab), pool
+        order, inverse, limits, sorted_tab = by_len
+        # rows gathered through a 2-D [B, Hq*D] view: gathered as [B, Hq, D]
+        # the TPU compiler gives q a head-major layout and, to feed it,
+        # copies the whole transposed wq stack every dispatch (604 MB at
+        # the 8B; deviceless compile, PR 33)
+        rows = q.shape[0]
+        ctx = read(q.reshape(rows, -1)[order].reshape(q.shape), ck, cv,
+                   limits, layer, sorted_tab)
+        return ctx.reshape(rows, -1)[inverse].reshape(ctx.shape), pool
 
     def attend(q, k, v, cache_l) -> Tuple[jnp.ndarray, tuple]:
         pool, layer = cache_l
@@ -182,14 +239,17 @@ def make_decode_attend_carry_paged(lengths: jnp.ndarray, table: jnp.ndarray,
                               P("dp", "tp", None),        # vnew
                               P("dp"),                    # lengths [B]
                               P("dp", None),              # table (slot rows)
-                              P()),                       # layer scalar
+                              P())                        # layer scalar
+                    # the order in length: each dp shard's own rows (tp
+                    # shards heads and shares it)
+                    + (P("dp"), P("dp"), P("dp"), P("dp", None))[:len(by_len)],
                     out_specs=(P("dp", None, "tp", None), pool_spec),
                     check_vma=False,
                 )
-                ctx, pool = fn(q, pool, knew, vnew, lengths, table, layer)
             else:
-                ctx, pool = _write_attend_paged(q, pool, knew, vnew,
-                                                lengths, table, layer)
+                fn = _write_attend_paged
+            ctx, pool = fn(q, pool, knew, vnew, lengths, table, layer,
+                           *by_len)
             return ctx, (pool, layer)
         pool = kvp.write_token_layer_paged(pool, layer, lengths, table, k, v,
                                            ps)

@@ -13,6 +13,17 @@ Raggedness (every row at a different length) is handled two ways:
 - *page skipping*: the in-kernel page loop runs over a block's live page
   range only, so a slot at length 130 reads 3 pages of 64, not the window.
 
+WHO ORDERS THE ROWS: a block of ``bblock`` rows costs ``bblock`` x the pages
+of its LONGEST row (the shorter ones re-copy their last page and run a masked
+update to the end of the walk), so how rows are grouped into blocks decides
+how much of the walk is live. The kernels take the rows as given; the decode
+program sorts them by length around the call (ops/attention._length_order,
+in plain XLA ops: two small sorts a substep, two row gathers a layer) and
+un-permutes the context. Not in here: the order is the same for
+every layer and one call is one layer; a permutation outside leaves the body,
+its DMAs and its buffers as they are, where tier-1 can pin it bitwise; and
+the other entries (spec, ragged) arrange their blocks on other grounds.
+
 GQA grouping stays in-kernel: per KV head h, the G=Hq/Hkv query rows attend to
 one [page, D] K/V stream — no repeat_kv copy ever exists (the same design as
 the XLA fallback in ops/attention.py, here with explicit VMEM control).
@@ -444,7 +455,13 @@ def decode_attend_pallas_paged(q: jnp.ndarray, pool_k: jnp.ndarray,
     fetched). Returns [B, 1, Hq, D]. pool_ks/vs switch the int8 scale-folding
     body. ``bblock`` slots share each grid step
     (resolved to the largest divisor of B); page i+1 prefetches while page i
-    computes regardless of bblock — see _paged_db_body. A slot of length 0
+    computes regardless of bblock — see _paged_db_body. Rows are served in
+    the order given, ``bblock`` consecutive rows a grid step, and a step
+    walks the pages of its longest row for all of them: the caller that
+    wants a full walk hands neighbours in length (the decode program does:
+    ops/attention.make_decode_attend_carry_paged sorts rows, lengths and
+    table alike and un-permutes the result; a row's output does not depend
+    on its block-mates, bit for bit). A slot of length 0
     (idle) is a dead row: nothing is fetched for it and its output row is
     exactly zero (it used to be the mean of its first page's V rows).
     """

@@ -275,6 +275,15 @@ class EngineMetrics:
             "(token, expert) rows of live tokens that landed on an expert "
             "held on this chip (an expert share routes over more experts "
             "than it holds), per layer, by step program", ("program",)))
+        # live / walked is the fill of the decode kernel's page walk: a grid
+        # step walks the pages of its block's longest row for every row
+        self.decode_attn_pages = r.register(Counter(
+            "tpu_serve_decode_attn_pages_total",
+            "Pages of the plain decode dispatches' attention, per attending "
+            "layer, summed over substeps: kind=\"live\" the pages the rows "
+            "hold, kind=\"walked\" the pages their blocks walk (block rows "
+            "x the block's longest row, blocks cut in order of length)",
+            ("kind",)))
         self.kda_rows = r.register(Counter(
             "tpu_serve_kda_rows_total",
             "Rows that advanced a recurrent (KDA) state, per layer, by step "

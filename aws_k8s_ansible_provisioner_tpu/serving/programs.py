@@ -1409,6 +1409,36 @@ class EnginePrograms:
             return {}
         return {"kda_rows": int(rows), "kda_slots": int(slots)}
 
+    def _attn_pages(self, horizon: int, carry_steps: int) -> dict:
+        """Dispatch-record fields of a plain decode dispatch, per attending
+        layer, summed over its substeps: ``attn_pages_live`` (pages the rows
+        hold: ceil((len + 1) / page) a slot, the window's dead pages off)
+        and ``attn_pages_walked`` (pages the kernel's blocks walk: block
+        rows x [the block's first live page, its longest row's last]), the
+        blocks cut from the mirror's lengths as the device cuts them — each
+        dp shard's rows in one ascending order (ops/attention._length_order;
+        where the device takes no order, blocks of one row or one block,
+        the sum is the same in any order). Every slot counts: the kernel
+        walks an idle slot's row too. Their ratio is the walk's fill."""
+        from aws_k8s_ansible_provisioner_tpu.ops.pallas_attention import (
+            _resolve_bb)
+
+        ps, window = self.serving.page_size, self.cfg.sliding_window
+        dp = self.mesh.shape.get("dp", 1) if self.mesh is not None else 1
+        bb = _resolve_bb(self.decode_bblock, self.num_slots // dp)
+        # [substep, dp shard, row]: the live columns of each kernel row
+        limits = np.sort(
+            (self.lengths.astype(np.int64) + carry_steps + 1
+             + np.arange(horizon)[:, None]).reshape(horizon, dp, -1), axis=-1)
+        hi = np.minimum(-(-limits // ps), self.pages_per_slot)
+        lo = np.maximum(limits - window, 0) // ps if window > 0 \
+            else np.zeros_like(hi)
+        blocks = (horizon, -1, bb)
+        walked = bb * (hi.reshape(blocks)[..., -1]
+                       - lo.reshape(blocks)[..., 0])
+        return {"attn_pages_live": int((hi - lo).sum()),
+                "attn_pages_walked": int(walked.sum())}
+
     def _live_rows(self, active):
         """[B] bool device mask of the decode rows that hold a request, for
         an MoE model's step programs (None for a dense model: no operand);
@@ -1443,7 +1473,9 @@ class EnginePrograms:
         (horizon x active + chunk_n); padding rows and idle slots are not
         routed), ``moe_experts_hit`` (experts with a live row, mean over
         layers and substeps) and ``moe_group_max`` (rows of the largest
-        group), the last two from the program's own output."""
+        group), the last two from the program's own output. A plain
+        decode dispatch also carries ``attn_pages_live`` and
+        ``attn_pages_walked`` (``_attn_pages``)."""
         n = len(active)
         lens = self.lengths[list(active)] if n else None
         return {"seq": next(_DISPATCH_SEQ), "program": program, "kind": kind,
@@ -1509,6 +1541,11 @@ class EnginePrograms:
         if "kda_rows" in rec:
             self.metrics.kda_rows.inc(rec["kda_rows"],
                                       program=rec["program"])
+        if "attn_pages_live" in rec:
+            self.metrics.decode_attn_pages.inc(rec["attn_pages_live"],
+                                               kind="live")
+            self.metrics.decode_attn_pages.inc(rec["attn_pages_walked"],
+                                               kind="walked")
         _devmon.note(rec["kind"], device_s, batch=batch, tokens=tokens,
                      ctx_rows=ctx_rows, steps=steps, guided_rows=guided_rows)
         _flight.record("dispatch", None, **rec)
@@ -2612,6 +2649,7 @@ class EnginePrograms:
             "decode_steps", "decode", active, horizon=horizon,
             carry_steps=prev["horizon"] if prev is not None else 0,
             **self._kda_rows(horizon * len(active), len(active)))
+        drec.update(self._attn_pages(horizon, drec["carry_steps"]))
         self._book_bubble(drec["t_enqueue"])
         real_counts = self.counts
         with _Dispatching(drec):

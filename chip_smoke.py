@@ -540,7 +540,8 @@ def kernel_parity(cfg, slots: int, window: int, page: int, bblocks,
 
     from aws_k8s_ansible_provisioner_tpu.ops import pallas_attention as pa
     from aws_k8s_ansible_provisioner_tpu.ops.attention import (
-        decode_attend, make_mixed_attend_carry_paged)
+        decode_attend, make_decode_attend_carry_paged,
+        make_mixed_attend_carry_paged)
     from aws_k8s_ansible_provisioner_tpu.ops import kv_pool as kvp
 
     Hq, Hkv, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -586,8 +587,8 @@ def kernel_parity(cfg, slots: int, window: int, page: int, bblocks,
             skw = {}
         del kf, vf
 
-        def dense_view(tab):
-            d = kvp.gather_layer_dense(pool, layer, tab)
+        def dense_view(tab, of=None):
+            d = kvp.gather_layer_dense(of or pool, layer, tab)
             if quant:
                 return (kvp.dequantize(d["k"], d["ks"]),
                         kvp.dequantize(d["v"], d["vs"]))
@@ -627,6 +628,31 @@ def kernel_parity(cfg, slots: int, window: int, page: int, bblocks,
                   .any(), f"ragged {tag} bb={bb}: a dead row is not zero")
             say(f"parity: decode/ragged paged {tag} bb={bb}: max abs err "
                 f"{e1:.2e} / {e2:.2e} (tol {KERNEL_TOL})")
+        # the decode program's call: make_decode_attend_carry_paged writes
+        # each slot's row, then hands the kernel the rows IN ORDER OF LENGTH
+        # (every slot-order block of 8 here holds a one-page row beside a
+        # full window) and un-permutes the context — compiled, against the
+        # reference and BITWISE against the slot-order call on the same pool
+        bb = max(bblocks)
+        knew, vnew = (jax.random.normal(k, (B, 1, Hkv, D), jnp.bfloat16)
+                      for k in keys[6:8])
+        attend = make_decode_attend_carry_paged(lengths - 1, table,
+                                                impl="pallas", bblock=bb)
+        out, (wrote, _) = jax.jit(attend)(q, knew, vnew, (pool, layer))
+        wkw = dict(pool_ks=wrote["ks"], pool_vs=wrote["vs"]) if quant else {}
+        slot_order = pa.decode_attend_pallas_paged(
+            q, wrote["k"], wrote["v"], lengths, layer, table,
+            interpret=interpret, bblock=bb, **wkw)
+        check(bool(jnp.array_equal(out, slot_order)),
+              f"decode rows in order of length {tag} bb={bb}: the context "
+              f"is not bitwise the slot-order call's")
+        with jax.default_matmul_precision("highest"):
+            e3 = close(f"decode rows in order of length {tag} bb={bb}", out,
+                       decode_attend(q, *dense_view(table, wrote), lengths))
+        say(f"parity: decode rows in order of length {tag}, {B} slots x "
+            f"bb={bb}, {Hkv} KV heads: bitwise the slot-order call, max abs "
+            f"err {e3:.2e} (tol {KERNEL_TOL})")
+        del wrote, wkw, slot_order
         if not interpret:
             ragged_call_time(pa, pool, skw, table, lengths, layer, Hq, D,
                              window, max(bblocks), tag)

@@ -315,6 +315,49 @@ def test_mixed_step_holds_no_pool_copy(chip, monkeypatch, model, slots):
     _assert_named_after_wrapper(compiled, pa.ragged_attend_pallas_paged)
 
 
+@pytest.mark.parametrize(
+    "model,slots", [("Qwen/Qwen3-0.6B", 32), ("Qwen/Qwen3-8B", 16),
+                    ("allenai/OLMoE-1B-7B-0125-Instruct", 24)],
+    ids=["qwen3-0.6b", "qwen3-8b", "olmoe-1b-7b"])
+def test_decode_steps_orders_its_rows_once_a_substep(chip, monkeypatch,
+                                                     model, slots):
+    """The whole ``decode_steps`` of each closed cell (horizon 8, block 8),
+    from the engine's own enumeration: the rows' order in length (PR 33:
+    ops/attention._length_order) is taken in the SUBSTEP's body — two sorts,
+    the order and its inverse, beside the layer loop — and the layer's body
+    holds the kernel under its wrapper's name; the gathers around the call
+    bring no copy of a weight stack."""
+    import re
+
+    from aws_k8s_ansible_provisioner_tpu.config import (MODEL_REGISTRY,
+                                                        ServingConfig)
+    from aws_k8s_ansible_provisioner_tpu.serving import aot
+
+    monkeypatch.setattr(pa, "supported", lambda: True)
+    plan = aot.ProgramPlan(MODEL_REGISTRY[model], ServingConfig(
+        model=model, max_decode_slots=slots, max_cache_len=2048,
+        weights_dtype="int8", decode_bblock=8, kv_host_tier_bytes=0))
+    params, cache = aot._abstract_state(plan, None,
+                                        next(iter(chip.device_set)))
+    _, fn, args, kwargs = next(
+        p for p in aot.enumerate_programs(plan, None, params, cache,
+                                          bblock=8)
+        if p[0] == "decode_fused_h8")
+    compiled = fn.lower(*args, **kwargs).compile()
+    text = compiled.as_text()
+    _assert_named_after_wrapper(compiled, pa.decode_attend_pallas_paged)
+    # gathered as [B, Hq, D], q came out head-major and the compiler fed it
+    # from a transposed copy of the whole wq stack, made every dispatch
+    assert not re.search(r" copy\(%params__layers____w[qo]__", text)
+    bodies = text.split("\n\n")                     # one computation each
+    by_len = rf" = \(s32\[1,{slots}\]\S* s32\[1,{slots}\]\S* sort\("
+    substep, = [b for b in bodies if re.search(by_len, b)]
+    assert len(re.findall(by_len, substep)) == 2 and " while(" in substep
+    layer, = [b for b in bodies
+              if re.search(r"%decode_attend_pallas_paged(\.\d+)? = ", b)]
+    assert layer is not substep
+
+
 def test_kda_decode_update_compiles_in_place_under_its_own_name(chip):
     """The KDA decode step's state pass (ops/linear_attention.py, PR 32) at
     the served shape — 64 slots, 64 heads of 128, two periods of three KDA
