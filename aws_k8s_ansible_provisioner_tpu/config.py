@@ -129,15 +129,22 @@ class ModelConfig:
     # Shared experts: a dense SwiGLU of width n_shared_experts x
     # moe_intermediate_size every token passes, added to the routed sum.
     n_shared_experts: int = 0
-    # Per-layer block kinds: one character a layer of a PERIOD that repeats
-    # over the depth — "g" softmax attention (the GQA block), "k" KDA linear
-    # attention (ops/linear_attention.py). "" = every layer the one kind
+    # Per-layer block kinds, one character a layer: "g" softmax attention
+    # (the GQA block), "k" KDA linear attention, "s" softmax attention that
+    # SELECTS the pages it reads (ops/sparse_attention.py), "l" Lightning
+    # linear attention (ops/linear_attention.py). Two forms. A PERIOD of
+    # "g"/"k" kinds, shorter than the depth, repeats over it ("gkkk"). A
+    # LIST — one character a layer, any order, "g"/"s"/"l" kinds — is the
+    # layers held, as they are ("slllllls"). "" = every layer the one kind
     # the fields above describe. A string, not a tuple of enums: the config
     # is a jit static argument and is built from JSON by the benchmark.
     layer_pattern: str = ""
-    # "g" layers: the attention output is gated elementwise by
+    # "g"/"s" layers: the attention output is gated elementwise by
     # sigmoid(n . Wg) before the output projection.
     attn_output_gate: bool = False
+    # "g"/"s" layers of a model whose OTHER layers rotate (pos_embed "rope"
+    # is then the Lightning layers'): False = attention without positions.
+    attn_use_rope: bool = True
     # "k" layers (Kimi Delta Attention, arXiv:2510.26692): heads of
     # kda_head_dim (d_k = d_v), a short depthwise causal convolution on
     # q/k/v (linear_attention.CONV_TAPS taps), low-rank decay and gate
@@ -145,30 +152,122 @@ class ModelConfig:
     kda_num_heads: int = 0
     kda_head_dim: int = 0
     kda_low_rank: int = 0
+    # "l" layers (Lightning Attention): heads of lightning_head_dim with a
+    # fixed scalar decay a head, per-head RMSNorm on q/k, RoPE on q/k, a
+    # per-head RMSNorm and a sigmoid gate on the output.
+    lightning_num_heads: int = 0
+    lightning_head_dim: int = 0
+    # "s" layers (InfLLM-v2 block selection, arXiv:2509.24663): keys are
+    # mean-pooled over windows of sparse_kernel_size every
+    # sparse_kernel_stride tokens; a query scores the pooled keys, a block
+    # of sparse_block_size tokens (= the pool's page) takes the best score
+    # of the windows that overlap it, and the query attends the
+    # sparse_topk best blocks — the first sparse_init_blocks and the last
+    # sparse_window_size tokens' blocks always among them. A context
+    # shorter than sparse_dense_len is attended whole.
+    sparse_block_size: int = 0
+    sparse_kernel_size: int = 0
+    sparse_kernel_stride: int = 0
+    sparse_topk: int = 0
+    sparse_init_blocks: int = 0
+    sparse_window_size: int = 0
+    sparse_dense_len: int = 0
+    # MiniCPM's muP: the embedding times scale_emb; every residual add
+    # times scale_depth / sqrt(mup_depth) (mup_depth = the PUBLISHED depth,
+    # also where fewer layers are held; 0 = num_layers); the logits divided
+    # by hidden_size / dim_model_base. 1.0 / 0 = none of it.
+    scale_emb: float = 1.0
+    scale_depth: float = 0.0
+    mup_depth: int = 0
+    dim_model_base: int = 0
     hf_repo: str = ""
 
     def __post_init__(self):
         pat = self.layer_pattern
-        if pat:
-            if set(pat) - set("gk") or pat.count("g") != 1:
+        if not pat:
+            return
+        if set(pat) <= set("gk"):
+            if len(pat) == self.num_layers and "k" not in pat:
+                pass        # a list of plain attention layers
+            elif pat.count("g") != 1:
                 raise ValueError(
-                    f"layer_pattern={pat!r}: one 'g' (attention) layer and "
-                    f"any number of 'k' (KDA) layers a period")
+                    f"layer_pattern={pat!r}: a period holds one 'g' "
+                    f"(attention) layer and any number of 'k' (KDA) layers; "
+                    f"a list (one character a layer) holds 'g', 's' "
+                    f"(selecting attention) and 'l' (Lightning) layers")
             if self.num_layers % len(pat):
                 raise ValueError(f"num_layers={self.num_layers} is not a "
                                  f"whole number of periods {pat!r}")
             if "k" in pat and not (self.kda_num_heads and self.kda_head_dim):
                 raise ValueError("a 'k' layer needs kda_num_heads and "
                                  "kda_head_dim")
+            return
+        if "g" in pat and "s" in pat:
+            raise ValueError(
+                f"layer_pattern={pat!r}: the attending layers of a list "
+                f"either all select ('s') or none does ('g') — one attend "
+                f"callback serves them all")
+        if set(pat) - set("gsl"):
+            raise ValueError(
+                f"layer_pattern={pat!r}: a list holds 'g' (attention), 's' "
+                f"(selecting attention) and 'l' (Lightning) layers; 'k' "
+                f"(KDA) layers come in a period with one 'g'")
+        if len(pat) != self.num_layers:
+            raise ValueError(
+                f"layer_pattern={pat!r} names {len(pat)} layers, "
+                f"num_layers={self.num_layers}: a list with 's' or 'l' "
+                f"kinds gives one character a layer held")
+        if "l" in pat and not (self.lightning_num_heads
+                               and self.lightning_head_dim):
+            raise ValueError("an 'l' layer needs lightning_num_heads and "
+                             "lightning_head_dim")
+        if "s" in pat:
+            bs, ks, st = (self.sparse_block_size, self.sparse_kernel_size,
+                          self.sparse_kernel_stride)
+            if not (bs and ks and st and self.sparse_topk):
+                raise ValueError(
+                    "an 's' layer needs sparse_block_size, "
+                    "sparse_kernel_size, sparse_kernel_stride and "
+                    "sparse_topk")
+            if ks != 2 * st or bs % st:
+                raise ValueError(
+                    f"sparse_kernel_size={ks}, sparse_kernel_stride={st}, "
+                    f"sparse_block_size={bs}: the selector's cache keeps "
+                    f"sums of stride-sized runs of keys, so a window is two "
+                    f"strides and a block a whole number of them")
+            if self.sparse_window_size % bs:
+                raise ValueError(
+                    f"sparse_window_size={self.sparse_window_size} is not "
+                    f"a whole number of blocks of {bs}")
 
     @property
     def gated_mlp(self) -> bool:
         return self.act in ("silu", "gelu_tanh")
 
     @property
+    def layer_list(self) -> bool:
+        """The pattern is a LIST of the layers held (not a period)."""
+        return bool(self.layer_pattern) and (
+            bool(set(self.layer_pattern) & set("sl"))
+            or (len(self.layer_pattern) == self.num_layers
+                and "k" not in self.layer_pattern))
+
+    @property
     def recurrent(self) -> bool:
         """Some layers keep a recurrent state per sequence beside K/V."""
-        return "k" in self.layer_pattern
+        return bool(set(self.layer_pattern) & set("kl"))
+
+    @property
+    def recurrent_kinds(self) -> str:
+        """The recurrent kinds that are there, for a log line or a refusal."""
+        names = {"k": "KDA", "l": "Lightning"}
+        return "/".join(v for k, v in names.items()
+                        if k in self.layer_pattern)
+
+    @property
+    def selects(self) -> bool:
+        """Some attending layers select the pages a query reads."""
+        return "s" in self.layer_pattern
 
     @property
     def num_periods(self) -> int:
@@ -177,15 +276,49 @@ class ModelConfig:
     @property
     def num_attn_layers(self) -> int:
         """Layers that attend over K/V: the pool's leading axis."""
+        if self.layer_list:
+            return sum(self.layer_pattern.count(c) for c in "gs")
         return self.num_periods if self.layer_pattern else self.num_layers
+
+    @property
+    def num_recurrent_layers(self) -> int:
+        if self.layer_list:
+            return self.layer_pattern.count("l")
+        return self.num_periods * self.kda_per_period
 
     @property
     def kda_per_period(self) -> int:
         return self.layer_pattern.count("k")
 
     @property
+    def residual_scale(self) -> float:
+        """What every block's output is multiplied by before it is added
+        to the residual stream (muP); 1.0 = a plain add."""
+        if not self.scale_depth:
+            return 1.0
+        return self.scale_depth / (self.mup_depth or self.num_layers) ** 0.5
+
+    @property
+    def logit_scale(self) -> float:
+        if not self.dim_model_base:
+            return 1.0
+        return self.dim_model_base / self.hidden_size
+
+    @property
+    def sparse_select_width(self) -> int:
+        """Entries of a query's list of selected blocks: the top-k, or every
+        block of a context still under sparse_dense_len."""
+        bs = self.sparse_block_size
+        return max(self.sparse_topk, -(-self.sparse_dense_len // bs)) \
+            if bs else 0
+
+    @property
     def kda_size(self) -> int:
         return self.kda_num_heads * self.kda_head_dim
+
+    @property
+    def lightning_size(self) -> int:
+        return self.lightning_num_heads * self.lightning_head_dim
 
     @property
     def router_width(self) -> int:
@@ -592,6 +725,45 @@ def tiny_solar(**overrides) -> ModelConfig:
         kda_num_heads=4,
         kda_head_dim=16,
         kda_low_rank=16,
+    )
+    base.update(overrides)
+    return ModelConfig(**base)
+
+
+def tiny_sala(**overrides) -> ModelConfig:
+    """A miniature MiniCPM-SALA-shaped hybrid: a LIST of selecting
+    attention layers ("s": gated NoPE GQA that reads the top-4 blocks of 8)
+    and Lightning linear-attention layers ("l"), not a period; muP scales."""
+    base = dict(
+        name="tiny-sala",
+        vocab_size=128,
+        hidden_size=64,
+        intermediate_size=128,
+        num_layers=6,
+        num_heads=4,
+        num_kv_heads=2,
+        head_dim=16,
+        max_seq_len=256,
+        norm_eps=1e-6,
+        qk_norm=True,
+        tie_embeddings=False,
+        eos_token_id=1,
+        layer_pattern="sllssl",
+        attn_output_gate=True,
+        attn_use_rope=False,
+        lightning_num_heads=4,
+        lightning_head_dim=16,
+        sparse_block_size=8,
+        sparse_kernel_size=4,
+        sparse_kernel_stride=2,
+        sparse_topk=4,
+        sparse_init_blocks=1,
+        sparse_window_size=16,
+        sparse_dense_len=32,
+        scale_emb=12.0,
+        scale_depth=1.4,
+        mup_depth=32,
+        dim_model_base=16,
     )
     base.update(overrides)
     return ModelConfig(**base)

@@ -285,9 +285,60 @@ def init_hybrid_layer_params(cfg: ModelConfig, key: jax.Array, dtype) -> dict:
     return out
 
 
+def init_list_layer_params(cfg: ModelConfig, key: jax.Array, dtype) -> dict:
+    """Stacked params of a model whose layer kinds are a LIST: one sub-tree
+    a kind, ``attn`` leaves ``[n_a, ...]`` (the "g" / "s" layers in order;
+    selecting has no parameter of its own) and ``lightning`` leaves
+    ``[n_l, ...]`` — what a run of one kind scans over
+    (:func:`_list_forward_carry`). Every FFN is the dense gated MLP."""
+    H, I = cfg.hidden_size, cfg.intermediate_size
+    ka, kl = jax.random.split(key)
+
+    def dense(k, n, din, dout):
+        return {"kernel": _dense_init(k, (n, din, dout), dtype)}
+
+    def norm(n, width=H):
+        return {"weight": jnp.ones((n, width), dtype)}
+
+    def ffn(ks, n):
+        return {"w_gate": dense(ks[0], n, H, I), "w_up": dense(ks[1], n, H, I),
+                "w_down": dense(ks[2], n, I, H)}
+
+    out = {}
+    n = cfg.num_attn_layers
+    if n:
+        ks = jax.random.split(ka, 8)
+        out["attn"] = {
+            "input_norm": norm(n), "post_norm": norm(n),
+            "wq": dense(ks[0], n, H, cfg.q_size),
+            "wk": dense(ks[1], n, H, cfg.kv_size),
+            "wv": dense(ks[2], n, H, cfg.kv_size),
+            "wo": dense(ks[3], n, cfg.q_size, H), **ffn(ks[5:8], n)}
+        if cfg.attn_output_gate:
+            out["attn"]["wg"] = dense(ks[4], n, H, cfg.q_size)
+        if cfg.qk_norm:
+            out["attn"]["q_norm"] = norm(n, cfg.head_dim)
+            out["attn"]["k_norm"] = norm(n, cfg.head_dim)
+    n = cfg.layer_pattern.count("l")
+    if n:
+        ks = jax.random.split(kl, 8)
+        D, d = cfg.lightning_size, cfg.lightning_head_dim
+        out["lightning"] = {
+            "input_norm": norm(n), "post_norm": norm(n),
+            "wq": dense(ks[0], n, H, D), "wk": dense(ks[1], n, H, D),
+            "wv": dense(ks[2], n, H, D), "wg": dense(ks[3], n, H, D),
+            "wo": dense(ks[4], n, D, H),
+            "q_norm": norm(n, d), "k_norm": norm(n, d), "o_norm": norm(n, d),
+            **ffn(ks[5:8], n)}
+    return out
+
+
 def init_layer_params(cfg: ModelConfig, key: jax.Array, dtype) -> dict:
     """Init stacked layer params: every leaf has leading [num_layers] axis
-    (a model with a layer pattern: :func:`init_hybrid_layer_params`)."""
+    (a model with a layer pattern: :func:`init_hybrid_layer_params`, or
+    :func:`init_list_layer_params` where the pattern is a list)."""
+    if cfg.layer_list:
+        return init_list_layer_params(cfg, key, dtype)
     if cfg.layer_pattern:
         return init_hybrid_layer_params(cfg, key, dtype)
     L, H = cfg.num_layers, cfg.hidden_size
@@ -473,7 +524,7 @@ def decoder_block(cfg: ModelConfig, p: dict, x: jnp.ndarray,
     if cfg.qk_norm and not whole:  # per-head RMSNorm on q/k (Qwen3)
         q = rms_norm(q, p["q_norm"]["weight"], cfg.norm_eps)
         k = rms_norm(k, p["k_norm"]["weight"], cfg.norm_eps)
-    if cfg.pos_embed == "rope":
+    if cfg.pos_embed == "rope" and cfg.attn_use_rope:
         q = apply_rope(q, cos, sin, rotary_dim)
         k = apply_rope(k, cos, sin, rotary_dim)
 
@@ -486,10 +537,53 @@ def decoder_block(cfg: ModelConfig, p: dict, x: jnp.ndarray,
     if cfg.parallel_block:  # Phi: attn and MLP both read the same normed input
         x = x + attn_out + _mlp(cfg, h, p)
     else:
-        x = x + attn_out
+        x = _residual(cfg, x, attn_out)
         h2 = apply_norm(cfg, x, p["post_norm"])
-        x = x + _mlp(cfg, h2, p)
+        x = _residual(cfg, x, _mlp(cfg, h2, p))
     return x, new_cache_l
+
+
+def _residual(cfg: ModelConfig, x: jnp.ndarray, y: jnp.ndarray):
+    """``x + y``, the block's output first multiplied by the model's
+    residual scale where it has one (muP; a plain add otherwise — a static
+    fact, so the others' programs are what they were)."""
+    if cfg.residual_scale == 1.0:
+        return x + y
+    return x + (cfg.residual_scale * y).astype(x.dtype)
+
+
+def lightning_slopes(num_heads: int) -> jnp.ndarray:
+    """The Lightning-Attention-2 slope table: head h forgets at
+    ``exp(-s_h)`` a token, ``s_h = 2^(-8 (h + 1) / H)`` — fixed, not
+    learned."""
+    h = jnp.arange(1, num_heads + 1, dtype=jnp.float32)
+    return jnp.exp2(-8.0 * h / num_heads)
+
+
+def lightning_block(cfg: ModelConfig, p: dict, x: jnp.ndarray,
+                    cos: jnp.ndarray, sin: jnp.ndarray, recur,
+                    rec_l: Any) -> Tuple[jnp.ndarray, Any]:
+    """One Lightning linear-attention block (ops/linear_attention.py has the
+    recurrence): per-head RMSNorm then RoPE on q and k, the decayed
+    outer-product state, a per-head RMSNorm and a sigmoid gate on the
+    output. ``recur.lightning`` runs the recurrence over the per-slot state
+    ``rec_l`` names and returns the heads' outputs in float32."""
+    B, T, _ = x.shape
+    Hl, d = cfg.lightning_num_heads, cfg.lightning_head_dim
+    h = apply_norm(cfg, x, p["input_norm"])
+    q = _linear(h, p["wq"]).reshape(B, T, Hl, d)
+    k = _linear(h, p["wk"]).reshape(B, T, Hl, d)
+    v = _linear(h, p["wv"]).reshape(B, T, Hl, d)
+    if cfg.qk_norm:
+        q = rms_norm(q, p["q_norm"]["weight"], cfg.norm_eps)
+        k = rms_norm(k, p["k_norm"]["weight"], cfg.norm_eps)
+    q, k = apply_rope(q, cos, sin, d), apply_rope(k, cos, sin, d)
+    o, rec = recur.lightning(q, k, v, lightning_slopes(Hl), rec_l)
+    o = rms_norm(o, p["o_norm"]["weight"], cfg.norm_eps).astype(x.dtype)
+    o = o.reshape(B, T, Hl * d) * jax.nn.sigmoid(_linear(h, p["wg"]))
+    x = _residual(cfg, x, _linear(o, p["wo"]))
+    return _residual(cfg, x, _mlp(cfg, apply_norm(cfg, x, p["post_norm"]),
+                                  p)), rec
 
 
 def kda_block(cfg: ModelConfig, p: dict, x: jnp.ndarray, recur,
@@ -533,19 +627,26 @@ def _embed_inputs(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
         # Gemma scales embeddings by sqrt(H); HF casts the scalar to the
         # embedding dtype BEFORE multiplying — match that for logit parity.
         x = x * jnp.asarray(cfg.hidden_size ** 0.5, x.dtype)
+    if cfg.scale_emb != 1.0:    # MiniCPM's muP
+        x = x * jnp.asarray(cfg.scale_emb, x.dtype)
     if cfg.pos_embed == "learned":
         # OPT: absolute learned positions, +2 offset; no rotary tables needed
         # (dummy cos/sin keep the scan signature uniform).
         x = x + params["pos_embed"]["weight"][positions + 2]
         cos = sin = jnp.zeros(positions.shape + (0,), jnp.float32)
     else:
-        rotary_dim = int(cfg.head_dim * cfg.rotary_pct)
+        # the tables are the rotating layers': the attention layers', or the
+        # Lightning layers' where the attention layers have no positions
+        rotary_dim = int(cfg.head_dim * cfg.rotary_pct) if cfg.attn_use_rope \
+            else cfg.lightning_head_dim
         cos, sin = rope_cos_sin(positions, rotary_dim, cfg.rope_theta, cfg)
     return x, cos, sin
 
 
 def _final_logits(params: dict, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
     x = apply_norm(cfg, x, params["final_norm"])
+    if cfg.logit_scale != 1.0:      # muP: logits over hidden / dim_model_base
+        x = x * jnp.asarray(cfg.logit_scale, x.dtype)
     if cfg.tie_embeddings:
         emb = params["embed"]
         if "scale" in emb:
@@ -568,6 +669,11 @@ def model_forward(
     remat: bool = False,
 ) -> Tuple[jnp.ndarray, Any]:
     """Run the decoder; returns (logits [B, T, V], updated cache)."""
+    if attend is None and cfg.selects:
+        from aws_k8s_ansible_provisioner_tpu.ops.sparse_attention import (
+            make_stateless_attend_select)
+
+        attend = make_stateless_attend_select(cfg)
     attend = attend or make_default_attend(cfg)
     if cfg.layer_pattern:
         if cache is not None:
@@ -577,7 +683,8 @@ def model_forward(
             recur_from_zero)
 
         # the stateless form: every sequence whole, from position 0
-        logits, _ = _hybrid_forward_carry(
+        fwd = _list_forward_carry if cfg.layer_list else _hybrid_forward_carry
+        logits, _ = fwd(
             params, cfg, tokens, positions, {},
             lambda q, k, v, cl: (attend(q, k, v, None)[0], cl),
             recur_from_zero, remat=remat)
@@ -628,6 +735,9 @@ def model_forward_carry(
     traffic is weights + live cache rows only. Every serving step program
     runs this form.
     """
+    if cfg.layer_list:
+        return _list_forward_carry(params, cfg, tokens, positions, cache,
+                                   attend, recur)
     if cfg.layer_pattern:
         return _hybrid_forward_carry(params, cfg, tokens, positions, cache,
                                      attend, recur)
@@ -700,4 +810,79 @@ def _hybrid_forward_carry(params, cfg: ModelConfig, tokens, positions, cache,
     if per_layer is not None:       # [P, layers a period, n] -> [L, n]
         per_layer = per_layer.reshape((-1,) + per_layer.shape[2:])
     moe.put_stats(per_layer)
+    return _final_logits(params, cfg, x), {**pool, **rec}
+
+
+def layer_runs(pattern: str):
+    """A list of layer kinds as RUNS of one kind: ``(kind, index of the
+    run's first layer among the layers of its kind, length)`` —
+    "slllllls" is ``[("s", 0, 1), ("l", 0, 6), ("s", 1, 1)]``."""
+    runs, seen = [], {}
+    for kind in pattern:
+        stack = "l" if kind == "l" else "a"
+        if runs and runs[-1][0] == kind:
+            runs[-1][2] += 1
+        else:
+            runs.append([kind, seen.get(stack, 0), 1])
+        seen[stack] = seen.get(stack, 0) + 1
+    return [tuple(r) for r in runs]
+
+
+def _list_forward_carry(params, cfg: ModelConfig, tokens, positions, cache,
+                        attend: AttendFn, recur, remat: bool = False):
+    """model_forward_carry for a model whose layer kinds are a LIST (not a
+    period): the list is walked RUN by run of one kind, a run longer than
+    one layer as ONE scan over its layers — so the step programs hold one
+    layer body a run, not one a layer (the published 32-layer list has 9
+    runs; a pipeline stage "s llllll s" has 3). ``cache`` holds the pool's
+    leaves with a leading axis of ATTENDING layers (``attend`` is handed
+    ``(pool, index among the attending layers)``) and the Lightning
+    layers' per-slot state ``[n_l, 1, slots, ...]``, which
+    ``recur.lightning`` reads and writes as ``(state, index among the
+    Lightning layers, 0)``. Both ride the carry. Each layer's params are
+    one dynamic slice a leaf of the kind's whole stack (see
+    :func:`_hybrid_forward_carry`)."""
+    from aws_k8s_ansible_provisioner_tpu.ops import linear_attention as la
+    from aws_k8s_ansible_provisioner_tpu.ops import sparse_attention as sa
+
+    x, cos, sin = _embed_inputs(params, cfg, tokens, positions)
+    pool = {n: a for n, a in cache.items() if not la.is_state(n)}
+    rec = {n: a for n, a in cache.items() if la.is_state(n)}
+    if cfg.selects and pool:
+        # the selecting attends' tally of pages, in the carry (ops/
+        # sparse_attention.put_counts hands it to the step program)
+        pool[sa.TALLY] = jnp.zeros((2,), jnp.int32)
+    layers = params["layers"]
+
+    def layer(stack: str, i):
+        return jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
+            layers[stack])
+
+    def one(kind, carry, i):
+        x, pool, rec = carry
+        if kind == "l":
+            x, rec = lightning_block(cfg, layer("lightning", i), x, cos, sin,
+                                     recur, (rec, i, 0))
+        else:
+            x, (pool, _) = decoder_block(cfg, layer("attn", i), x, cos, sin,
+                                         attend, (pool, i))
+        return x, pool, rec
+
+    carry = (x, pool, rec)
+    for kind, first, n in layer_runs(cfg.layer_pattern):
+        if n == 1:
+            carry = one(kind, carry, jnp.int32(first))
+            continue
+
+        def body(carry, i, kind=kind):
+            return one(kind, carry, i), None
+
+        if remat:
+            body = jax.checkpoint(body)
+        carry, _ = jax.lax.scan(
+            body, carry, first + jnp.arange(n, dtype=jnp.int32))
+    x, pool, rec = carry
+    if sa.TALLY in pool:
+        sa.put_counts(pool.pop(sa.TALLY))
     return _final_logits(params, cfg, x), {**pool, **rec}

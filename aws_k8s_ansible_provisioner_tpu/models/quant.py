@@ -68,7 +68,9 @@ def weights_quantized(params: dict) -> bool:
     """Whether ``params`` carries int8 weight leaves (scale siblings)."""
     try:
         layers = params["layers"]
-        return "scale" in layers.get("gqa", layers)["wq"]
+        if "wq" not in layers:      # a layer pattern: one sub-tree a kind
+            layers = next(iter(layers.values()))
+        return "scale" in layers["wq"]
     except (KeyError, TypeError):
         return False
 

@@ -27,7 +27,8 @@ from aws_k8s_ansible_provisioner_tpu.ops import kv_pool as kvp
 
 
 def decode_attend(q: jnp.ndarray, cache_k: jnp.ndarray, cache_v: jnp.ndarray,
-                  lengths: jnp.ndarray, window: int = 0) -> jnp.ndarray:
+                  lengths: jnp.ndarray, window: int = 0,
+                  allowed: jnp.ndarray = None) -> jnp.ndarray:
     """Cached decode attention, R query rows per slot (plain decode: R = 1;
     speculative verify: R > 1).
 
@@ -35,7 +36,9 @@ def decode_attend(q: jnp.ndarray, cache_k: jnp.ndarray, cache_v: jnp.ndarray,
     the new tokens' k/v — the caller writes first); lengths: [B] = number of
     valid rows per slot seen by query row 0 (including its own token); query
     row r sees columns < lengths + r. ``window`` > 0 = sliding-window
-    attention (only the last ``window`` rows are live). Returns
+    attention (only the last ``window`` rows are live). ``allowed``
+    [B, Hkv, S] bool (ops/sparse_attention.py): the columns each KV head's
+    group may read at all — a slot's selected blocks. Returns
     [B, R, Hq, D].
     """
     B, R, Hq, D = q.shape
@@ -52,7 +55,10 @@ def decode_attend(q: jnp.ndarray, cache_k: jnp.ndarray, cache_v: jnp.ndarray,
     if window > 0:
         valid = valid & (cols >= limit[:, :, None] - window)
     valid = jnp.repeat(valid, G, axis=1)                       # [B, R*G, S]
-    logits = jnp.where(valid[:, None, :, :], logits, -1e30)
+    valid = valid[:, None, :, :]
+    if allowed is not None:
+        valid = valid & allowed[:, :, None, :]
+    logits = jnp.where(valid, logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1)
     ctx = jnp.einsum("bkgs,bksd->bkgd", probs, cache_v.astype(jnp.float32))
     ctx = ctx.reshape(B, Hkv, R, G, D).transpose(0, 2, 1, 3, 4)

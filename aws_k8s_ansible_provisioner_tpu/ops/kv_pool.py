@@ -75,7 +75,27 @@ def init_pool(cfg: ModelConfig, num_pages: int, page_size: int,
             "ks": jnp.zeros(sshape, jnp.float32),
             "vs": jnp.zeros(sshape, jnp.float32),
         }
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    pool = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    if cfg.selects:
+        pool["kc"] = jnp.zeros(selector_shape(cfg, num_pages, page_size),
+                               jnp.float32)
+    return pool
+
+
+def selector_shape(cfg: ModelConfig, num_pages: int, page_size: int) -> tuple:
+    """The selector's cache of a model whose attention selects its pages
+    (ops/sparse_attention.py): per physical page and KV head the SUMS of the
+    page's keys over runs of ``sparse_kernel_stride`` tokens, float32 — a
+    pooled key is the mean of two neighbouring runs. A leaf of the pool, so
+    it lives and dies with the page: the allocator never hears of it."""
+    return (cfg.num_attn_layers, num_pages, cfg.num_kv_heads,
+            page_size // cfg.sparse_kernel_stride, cfg.head_dim)
+
+
+def selector_bytes(cfg: ModelConfig, num_pages: int, page_size: int) -> int:
+    if not cfg.selects:
+        return 0
+    return 4 * int(np.prod(selector_shape(cfg, num_pages, page_size)))
 
 
 def pool_bytes(cfg: ModelConfig, num_pages: int, page_size: int,
@@ -84,7 +104,8 @@ def pool_bytes(cfg: ModelConfig, num_pages: int, page_size: int,
     if quant:
         return heads * (page_size * cfg.head_dim
                         + 4 * scale_lanes(page_size))
-    return heads * page_size * cfg.head_dim * jnp.dtype(dtype).itemsize
+    return heads * page_size * cfg.head_dim * jnp.dtype(dtype).itemsize \
+        + selector_bytes(cfg, num_pages, page_size)
 
 
 def quantize_rows(x: jnp.ndarray):

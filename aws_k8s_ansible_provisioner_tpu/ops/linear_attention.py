@@ -64,15 +64,25 @@ _HI = jax.lax.Precision.HIGHEST
 
 
 def _state_shapes(cfg: ModelConfig, num_slots: int, dtype) -> dict:
-    P, nk, H, d = (cfg.num_periods, cfg.kda_per_period, cfg.kda_num_heads,
-                   cfg.kda_head_dim)
-    return {"kda_state": ((P, nk, num_slots, H, d, d), jnp.float32),
-            "kda_conv": ((P, nk, num_slots, CONV_TAPS - 1,
-                          3 * H * d), dtype)}
+    out = {}
+    if "k" in cfg.layer_pattern:
+        P, nk, H, d = (cfg.num_periods, cfg.kda_per_period,
+                       cfg.kda_num_heads, cfg.kda_head_dim)
+        out.update({"kda_state": ((P, nk, num_slots, H, d, d), jnp.float32),
+                    "kda_conv": ((P, nk, num_slots, CONV_TAPS - 1,
+                                  3 * H * d), dtype)})
+    if "l" in cfg.layer_pattern:
+        # [n_l, 1, ...]: the KDA leaf's two leading axes, so the layer
+        # helpers and the decode kernel address it as (layer, 0)
+        H, d = cfg.lightning_num_heads, cfg.lightning_head_dim
+        out["lin_state"] = ((cfg.layer_pattern.count("l"), 1, num_slots, H,
+                             d, d), jnp.float32)
+    return out
 
 
 def init_state(cfg: ModelConfig, num_slots: int, dtype=jnp.bfloat16) -> dict:
-    """The two per-slot leaves of a model with KDA layers."""
+    """The per-slot leaves of a model with recurrent layers: two for KDA
+    layers, one for Lightning layers."""
     return {name: jnp.zeros(shape, dt) for name, (shape, dt)
             in _state_shapes(cfg, num_slots, dtype).items()}
 
@@ -85,7 +95,7 @@ def state_bytes(cfg: ModelConfig, num_slots: int, dtype=jnp.bfloat16) -> int:
 
 
 def is_state(name: str) -> bool:
-    return name.startswith("kda_")
+    return name.startswith(("kda_", "lin_"))
 
 
 # ---------------------------------------------------------------------------
@@ -245,14 +255,18 @@ def _layer_set(rec, name, period, j, rows, slot=None):
     return {**rec, name: arr}
 
 
-def _pad_to_block(arrays, T: int):
+def _pad_to(block: int, arrays, T: int):
     """Rows padded with zeros (g = 0, beta = 0: the identity) up to a whole
-    number of blocks."""
-    pad = -T % BLOCK
+    number of blocks of ``block`` rows."""
+    pad = -T % block
     if not pad:
         return arrays
     return tuple(jnp.pad(a, [(0, 0), (0, pad)] + [(0, 0)] * (a.ndim - 2))
                  for a in arrays)
+
+
+def _pad_to_block(arrays, T: int):
+    return _pad_to(BLOCK, arrays, T)
 
 
 def _span(rec_l, taps, qkv, g, beta, slots, fresh, n_valid):
@@ -321,6 +335,11 @@ def make_recur_decode(live=None):
         o, rec = _rows(rec_l, taps, qkv[:, 0], g[:, 0], beta[:, 0], live)
         return o[:, None], rec
 
+    def lightning(q, k, v, slopes, rec_l):
+        o, rec = _lin_rows(rec_l, q[:, 0], k[:, 0], v[:, 0], slopes, live)
+        return o[:, None], rec
+
+    recur.lightning = lightning
     return recur
 
 
@@ -334,6 +353,8 @@ def make_recur_span(slot, start, n_valid):
     def recur(taps, qkv, g, beta, rec_l):
         return _span(rec_l, taps, qkv, g, beta, slots, fresh, n)
 
+    recur.lightning = functools.partial(_lin_span, slots=slots, fresh=fresh,
+                                        n_valid=n)
     return recur
 
 
@@ -345,6 +366,8 @@ def make_recur_batch(slots, true_lens):
     def recur(taps, qkv, g, beta, rec_l):
         return _span(rec_l, taps, qkv, g, beta, slots, fresh, true_lens)
 
+    recur.lightning = functools.partial(_lin_span, slots=slots, fresh=fresh,
+                                        n_valid=true_lens)
     return recur
 
 
@@ -364,6 +387,14 @@ def make_recur_mixed(B: int, live, pslot, pstart, plen):
                        (rec, period, j))
         return jnp.concatenate([od[None], oc], axis=1), rec
 
+    def lightning(q, k, v, slopes, rec_l):
+        od, rec = _lin_rows(rec_l, q[0, :B], k[0, :B], v[0, :B], slopes,
+                            live)
+        oc, rec = span.lightning(q[:, B:], k[:, B:], v[:, B:], slopes,
+                                 (rec,) + tuple(rec_l[1:]))
+        return jnp.concatenate([od[None], oc], axis=1), rec
+
+    recur.lightning = lightning
     return recur
 
 
@@ -379,6 +410,135 @@ def recur_from_zero(taps, qkv, g, beta, rec_l):
     return o[:, :T], rec_l[0]
 
 
+def _lightning_from_zero(q, k, v, slopes, rec_l):
+    N, T, H, d = q.shape
+    g, beta = _lin_decay(slopes, jnp.ones((N, T), bool))
+    q, k, v, g, beta = _pad_to(LIN_BLOCK, (_lin_q(q), k.astype(jnp.float32),
+                                           v.astype(jnp.float32), g, beta), T)
+    o, _ = lightning_span(jnp.zeros((N, H, d, d), jnp.float32), q, k, v, g,
+                          beta)
+    return o[:, :T], rec_l[0]
+
+
+recur_from_zero.lightning = _lightning_from_zero
+
+
+# ---------------------------------------------------------------------------
+# Lightning linear attention: a fixed scalar decay a head, no delta rule,
+# no convolution. Per head, state S [d, d] float32:
+#
+#     S_t = lambda S_{t-1} + k_t^T v_t        o_t = (q_t / sqrt(d)) S_t
+#
+# with lambda = exp(-slope_h). The forms below take a LOG-decay per row and
+# head ``g`` (-slope for a row that carries a token, 0 for a dead one) and
+# ``beta`` (1 / 0 likewise), so a dead row is the identity on the state, as
+# for KDA; ``q`` arrives scaled.
+# ---------------------------------------------------------------------------
+
+# rows a block of the span form: the intra-block products are [C, C] a head
+LIN_BLOCK = 64
+
+
+def _lin_q(q):
+    return q.astype(jnp.float32) * (q.shape[-1] ** -0.5)
+
+
+def _lin_decay(slopes, live):
+    """(g, beta) [..., H] float32 of rows ``live`` [...] bool."""
+    g = jnp.where(live[..., None], -slopes.astype(jnp.float32), 0.0)
+    return g, jnp.broadcast_to(live[..., None].astype(jnp.float32), g.shape)
+
+
+def lightning_step(S, q, k, v, g, beta):
+    """One token a row. S: [B, H, d, d] float32; q (scaled), k, v: [B, H, d]
+    float32; g, beta: [B, H]. Returns (o [B, H, d], S_new)."""
+    S = S * jnp.exp(g)[..., None, None] \
+        + (beta[..., None] * k)[..., None] * v[..., None, :]
+    return jnp.sum(S * q[..., None], axis=-2), S
+
+
+def lightning_span(S0, q, k, v, g, beta, block: int = LIN_BLOCK):
+    """T rows of N sequences, exact, in blocks of ``block`` rows: inside a
+    block rows (t, s <= t) interact through ``exp(G_t - G_s)`` (``G`` the
+    running sum of ``g`` in the block; the difference is never positive),
+    the state is carried block to block. S0: [N, H, d, d] float32; q
+    (scaled), k, v: [N, T, H, d] float32; g, beta: [N, T, H]; T a multiple
+    of ``block``. Returns (o [N, T, H, d], S after the last row)."""
+    N, T, H, d = q.shape
+    C, nb = block, T // block
+
+    def blocks(a):      # [N, T, H, ...] -> [nb, N, H, C, ...]
+        a = a.reshape((N, nb, C) + a.shape[2:])
+        return jnp.moveaxis(jnp.moveaxis(a, 3, 2), 1, 0)
+
+    q, k, v = blocks(q), blocks(k), blocks(v * beta[..., None])
+    G = jnp.cumsum(blocks(g), axis=-1)                   # [nb, N, H, C]
+    tri = jnp.tril(jnp.ones((C, C), bool))
+    decay = jnp.exp(jnp.where(tri, G[..., :, None] - G[..., None, :],
+                              -jnp.inf))                 # [.., t, s]
+    A = jnp.einsum("...td,...sd->...ts", q, k, precision=_HI) * decay
+    intra = jnp.einsum("...ts,...sv->...tv", A, v, precision=_HI)
+    Qd = q * jnp.exp(G)[..., None]
+    Gl = G[..., -1:]                                     # the block's total
+    Kh = k * jnp.exp(Gl - G)[..., None]                  # exp(G_C - G_s) <= 1
+    eGl = jnp.exp(Gl)[..., None]                         # [nb, N, H, 1, 1]
+
+    def step(S, xs):
+        Qd, intra, Kh, v, eGl = xs
+        o = intra + jnp.einsum("nhtk,nhkv->nhtv", Qd, S, precision=_HI)
+        return eGl * S + jnp.einsum("nhsk,nhsv->nhkv", Kh, v,
+                                    precision=_HI), o
+
+    S, o = jax.lax.scan(step, S0, (Qd, intra, Kh, v, eGl))
+    o = jnp.moveaxis(jnp.moveaxis(o, 0, 1), 3, 2)        # [N, nb, C, H, d]
+    return o.reshape(N, T, H, -1), S
+
+
+def lightning_scan(S0, q, k, v, g, beta):
+    """The same rows token by token: what the span form is tested against."""
+    def step(S, xs):
+        o, S = lightning_step(S, *xs)
+        return S, o
+
+    xs = tuple(jnp.moveaxis(a, 1, 0) for a in (q, k, v, g, beta))
+    S, o = jax.lax.scan(step, S0, xs)
+    return jnp.moveaxis(o, 0, 1), S
+
+
+def _lin_span(q, k, v, slopes, rec_l, *, slots, fresh, n_valid):
+    """N spans [N, T, H, d] into slots ``slots`` [N] (see :func:`_span`)."""
+    rec, i, j = rec_l
+    N, T = q.shape[:2]
+    rd = jnp.clip(slots, 0, rec["lin_state"].shape[2] - 1)
+    S0 = jnp.where(fresh[:, None, None, None], 0.0,
+                   rec["lin_state"][i, j, rd])
+    g, beta = _lin_decay(slopes, jnp.arange(T)[None] < n_valid[:, None])
+    q, k, v, g, beta = _pad_to(LIN_BLOCK, (_lin_q(q), k.astype(jnp.float32),
+                                           v.astype(jnp.float32), g, beta), T)
+    o, S = lightning_span(S0, q, k, v, g, beta)
+    return o[:, :T], _layer_set(rec, "lin_state", i, j, S, slots)
+
+
+def _lin_rows(rec_l, q, k, v, slopes, live):
+    """One token for every slot (row b = slot b); see :func:`_rows`."""
+    rec, i, j = rec_l
+    B = q.shape[0]
+    g, beta = _lin_decay(slopes, jnp.ones((B,), bool) if live is None
+                         else live)
+    q, k, v = _lin_q(q), k.astype(jnp.float32), v.astype(jnp.float32)
+    from aws_k8s_ansible_provisioner_tpu.ops import pallas_attention
+
+    if pallas_attention.supported():
+        # the KDA kernel without its delta rule: one pass, in place
+        o, arr = kda_decode_update(
+            rec["lin_state"], i, j, q, k, v,
+            jnp.broadcast_to(g[..., None], q.shape), beta, delta_rule=False)
+        return o, {**rec, "lin_state": arr}
+    o, S = lightning_step(_layer_get(rec, "lin_state", i, j), q, k, v, g,
+                          beta)
+    return o, _layer_set(rec, "lin_state", i, j, S)
+
+
 # ---------------------------------------------------------------------------
 # The decode update as ONE pass over the state (TPU)
 # ---------------------------------------------------------------------------
@@ -386,16 +546,18 @@ def recur_from_zero(taps, qkv, g, beta, rec_l):
 HEADS_PER_STEP = 8      # heads a grid step: 8 x [d, d] float32 tiles in VMEM
 
 
-@functools.partial(jax.jit, static_argnames=("j", "interpret"))
+@functools.partial(jax.jit, static_argnames=("j", "interpret", "delta_rule"))
 def kda_decode_update(state, period, j: int, q, k, v, g, beta,
-                      interpret: bool = False):
+                      interpret: bool = False, delta_rule: bool = True):
     """:func:`kda_step` for every slot of one layer, IN PLACE on the full
     state leaf: ``state`` [P, n_k, B, H, d, d] float32 is read once and
     written once (``input_output_aliases``), where the XLA form reads it
     twice (a reduce fusion for the two products with ``S'``, then the
     update fusion). q, k, g: [B, H, d]; v: [B, H, d]; beta: [B, H] (a dead
     row comes with g = 0, beta = 0: the identity). Returns (o [B, H, d]
-    float32, state).
+    float32, state). ``delta_rule`` False is the Lightning layers' step on
+    the same tiles: ``S_t = diag(exp(g)) S + beta k v^T`` with no correction
+    by what the state already holds (``u`` is not formed).
 
     Grid (slots, heads / 8): a step holds 8 heads' tiles. The decay and the
     rank-1 update scale ROWS of a tile, so q, k and exp(g) arrive as
@@ -429,10 +591,12 @@ def kda_decode_update(state, period, j: int, q, k, v, g, beta,
             kc = kc_ref[0, 0, :, hh:hh + 1]               # [d, 1]
             qc = qc_ref[0, 0, :, hh:hh + 1]
             Sd = S * ec_ref[0, 0, :, hh:hh + 1]
-            u = jnp.sum(Sd * kc, axis=0, keepdims=True)   # [1, d] = S'^T k
+            if delta_rule:
+                u = jnp.sum(Sd * kc, axis=0, keepdims=True)  # [1, d] = S'^T k
             oq = jnp.sum(Sd * qc, axis=0, keepdims=True)
             delta = beta_ref[0, hh:hh + 1, :] \
-                * (v_ref[0, hh:hh + 1, :] - u)            # [1, d]
+                * ((v_ref[0, hh:hh + 1, :] - u) if delta_rule
+                   else v_ref[0, hh:hh + 1, :])           # [1, d]
             kq = jnp.sum(kc * qc, axis=0, keepdims=True)  # [1, 1]
             o_ref[0, hh:hh + 1, :] = oq + kq * delta
             s_out_ref[0, 0, 0, hh] = Sd + kc * delta

@@ -58,6 +58,21 @@ def _per_slot(vals, shape, axis: int = 0, each: int = 1):
     return out
 
 
+def _per_pair(vals, shape, hkv: int, groups: int, tiled: bool):
+    """Broadcast BB x Hkv int32 SCALARS (``vals[i * hkv + h]``: row i, KV
+    head h) over ``shape`` — ``[bb, hq, *]`` (row, query head), or with
+    ``tiled`` the sharing path's ``[hkv, bb * groups, *]`` (KV head, tile
+    row = row * groups + group). The iota-select form of
+    :func:`_per_slot`."""
+    a0 = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    a1 = jax.lax.broadcasted_iota(jnp.int32, shape, 1) // groups
+    pair = a1 * hkv + a0 if tiled else a0 * hkv + a1
+    out = jnp.full(shape, vals[0], jnp.int32)
+    for i in range(1, len(vals)):
+        out = jnp.where(pair == i, vals[i], out)
+    return out
+
+
 # ---------------------------------------------------------------------------
 # Attention over the physical page pool + per-slot block tables,
 # DOUBLE-BUFFERED
@@ -100,7 +115,8 @@ def _per_slot(vals, shape, axis: int = 0, each: int = 1):
 # (batch, page_size, kv_dtype)); 1 remains valid and still double-buffers.
 
 
-def _paged_db_body(lengths_ref, layer_ref, table_ref, share_ref, q_ref,
+def _paged_db_body(lengths_ref, layer_ref, table_ref, share_ref, rowmap_ref,
+                   sel_ref, cnt_ref, bits_ref, q_ref,
                    k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf,
                    vs_buf, acc_ref, m_ref, l_ref, sem, acc_t, m_t, l_t,
                    *, ps: int, groups: int, scale: float, R: int, bb: int,
@@ -133,6 +149,28 @@ def _paged_db_body(lengths_ref, layer_ref, table_ref, share_ref, q_ref,
     flash update with the block as one [bb*groups]-row query tile per KV
     head — the chunk rows of one prefill are the case; each row still masks
     to its own limit and window.
+
+    SELECTION (a model whose attention reads chosen pages only,
+    ops/sparse_attention.py; every operand None and compiled out
+    otherwise), per row AND KV head, in two forms:
+
+    - ``sel_ref`` / ``cnt_ref`` (the decode entry): a LIST of logical pages
+      in ascending order, ``cnt`` of them. The walk runs over list
+      POSITIONS — step c fetches, for each row and KV head apart, the page
+      its list names at c (one DMA a head: the heads of a row read
+      different pages) — so a row past the dense length costs ``topk``
+      page steps whatever its context; a list shorter than the block's
+      longest re-copies its last page under a mask, like a shorter row.
+    - ``bits_ref`` (the ragged entry): a BITMASK over logical pages. The
+      walk is the plain one over [lo_min, hi_max]; at page c a row's limit
+      for a KV head is its own where the bit is set and 0 where it is not,
+      and a page no row of the block selects skips its flash update. The
+      chunk rows of a prefill share one page stream a block (the sharing
+      path), each row and head masking what it did not choose.
+
+    ``rowmap_ref`` (ragged entry): packed row -> row of ``table_ref``, so
+    the chunk rows of one slot name ONE table row instead of each carrying
+    a copy (2,072 rows x 512 pages do not fit SMEM).
     """
     g = pl.program_id(0)
     lay = layer_ref[0]
@@ -148,6 +186,32 @@ def _paged_db_body(lengths_ref, layer_ref, table_ref, share_ref, q_ref,
     hi = [jnp.minimum(pl.cdiv(ln + ext, ps), num_pages) - 1
           for ln in lens]                                 # -1 = dead row
     hi_max = functools.reduce(jnp.maximum, hi)
+
+    def trow(row):
+        """Offset of packed row ``row``'s page run in the flat table."""
+        if rowmap_ref is not None:
+            row = rowmap_ref[row]
+        return row * num_pages
+
+    pairs = [(i, h) for i in range(bb) for h in range(hkv)]
+    if sel_ref is not None:
+        nsel = sel_ref.shape[0] // cnt_ref.shape[0]
+        cnts = [jnp.where(alive[i], cnt_ref[(g * bb + i) * hkv + h], 0)
+                for i, h in pairs]
+        hi_max = functools.reduce(jnp.maximum, cnts) - 1
+
+        def listed(c):
+            """The logical page each (row, KV head) reads at list position
+            c: clamped into its own list; 0 for an empty one."""
+            return [sel_ref[((g * bb + i) * hkv + h) * nsel
+                            + jnp.clip(c, 0, jnp.maximum(n - 1, 0))]
+                    for (i, h), n in zip(pairs, cnts)]
+
+    def chosen(c):
+        """Per (row, KV head): is page c in its selection (bits form)."""
+        nw = bits_ref.shape[0] // (lengths_ref.shape[0] * hkv)
+        return [(bits_ref[((g * bb + i) * hkv + h) * nw + c // 32]
+                 >> (c % 32)) & 1 for i, h in pairs]
     if window > 0:
         lo = [jnp.maximum(ln + (1 if spec else 0) - window, 0) // ps
               for ln in lens]
@@ -165,9 +229,15 @@ def _paged_db_body(lengths_ref, layer_ref, table_ref, share_ref, q_ref,
         live block rides along on physical page 0 (always in the pool)."""
         return [(i, jnp.where(
             alive[i],
-            table_ref[(g * bb + i) * num_pages
+            table_ref[trow(g * bb + i)
                       + jnp.clip(c, lo[i], jnp.maximum(hi[i], 0))], 0))
                 for i in range(bb)]
+
+    def listed_pages(c):
+        """row_pages for the list form: ((row, KV head), physical page)."""
+        return [((i, h), jnp.where(n > 0, table_ref[trow(g * bb + i) + lp],
+                                   0))
+                for (i, h), n, lp in zip(pairs, cnts, listed(c))]
 
     def walk(pages, update):
         """The double-buffered walk over [lo_min, hi_max]: ``pages(c)`` names
@@ -180,6 +250,15 @@ def _paged_db_body(lengths_ref, layer_ref, table_ref, share_ref, q_ref,
             slot = c % 2
             out = []
             for i, pg in pages(c):
+                if sel_ref is not None:     # (row, KV head): one head's rows
+                    i, h = i
+                    out.append(pltpu.make_async_copy(
+                        k_hbm.at[lay, pg, h], k_buf.at[slot, i, h],
+                        sem.at[slot, i, 2 * h]))
+                    out.append(pltpu.make_async_copy(
+                        v_hbm.at[lay, pg, h], v_buf.at[slot, i, h],
+                        sem.at[slot, i, 2 * h + 1]))
+                    continue
                 out.append(pltpu.make_async_copy(
                     k_hbm.at[lay, pg], k_buf.at[slot, i], sem.at[slot, i, 0]))
                 out.append(pltpu.make_async_copy(
@@ -250,6 +329,19 @@ def _paged_db_body(lengths_ref, layer_ref, table_ref, share_ref, q_ref,
                 vscale = vs_buf[buf][:, :, :ps].reshape(bb * hkv, ps)
             col = c * ps + jax.lax.broadcasted_iota(jnp.int32,
                                                     (bb, hq, ps), 2)
+            limit_b = lens_b
+            if sel_ref is not None:
+                # a column is where its (row, head)'s listed page puts it;
+                # past the end of a list: nowhere
+                base = [jnp.where(c < n, lp * ps, num_pages * ps)
+                        for n, lp in zip(cnts, listed(c))]
+                col = jax.lax.broadcasted_iota(jnp.int32, (bb, hq, ps), 2) \
+                    + _per_pair(base, (bb, hq, ps), hkv, groups, False)
+            elif bits_ref is not None:
+                limit_b = _per_pair(
+                    [jnp.where(b > 0, lens[i], 0)
+                     for (i, _), b in zip(pairs, chosen(c))],
+                    (bb, hq, ps), hkv, groups, False)
             for r in range(R):         # static unroll over draft rows
                 sl = slice(r * hq, (r + 1) * hq)
                 s = jax.lax.dot_general(
@@ -268,11 +360,11 @@ def _paged_db_body(lengths_ref, layer_ref, table_ref, share_ref, q_ref,
                     ).reshape(bb, hq, d)                  # [BB*Hkv, G, d]
 
                 flash(s.reshape(bb, hq, ps), col,
-                      lens_b + (1 + r if spec else 0), m_ref, l_ref,
+                      limit_b + (1 + r if spec else 0), m_ref, l_ref,
                       acc_ref, sl, pv_of)
 
         reset(acc_ref, m_ref, l_ref)
-        walk(row_pages, update)
+        walk(row_pages if sel_ref is None else listed_pages, update)
         return acc_ref[:] / jnp.maximum(l_ref[:, :, :1], 1e-9)
 
     def shared(row):
@@ -289,6 +381,18 @@ def _paged_db_body(lengths_ref, layer_ref, table_ref, share_ref, q_ref,
         limit = _per_slot(lens, (hkv, n, ps), axis=1, each=groups)
 
         def update(c, buf):
+            if bits_ref is None:
+                return fold(c, buf, limit)
+            picks = chosen(c)
+
+            @pl.when(functools.reduce(jnp.bitwise_or, picks) > 0)
+            def _some_row_chose_it():
+                fold(c, buf, _per_pair(
+                    [jnp.where(b > 0, lens[i], 0)
+                     for (i, _), b in zip(pairs, picks)],
+                    (hkv, n, ps), hkv, groups, True))
+
+        def fold(c, buf, limit):
             k3 = k_buf[buf, 0].astype(jnp.float32)            # [Hkv, ps, d]
             v3 = v_buf[buf, 0].astype(jnp.float32)
             s = jax.lax.dot_general(
@@ -309,7 +413,7 @@ def _paged_db_body(lengths_ref, layer_ref, table_ref, share_ref, q_ref,
             flash(s, col, limit, m_t, l_t, acc_t, slice(None), pv_of)
 
         reset(acc_t, m_t, l_t)
-        walk(lambda c: [(0, table_ref[row * num_pages + c])], update)
+        walk(lambda c: [(0, table_ref[trow(row) + c])], update)
         out = acc_t[:] / jnp.maximum(l_t[:, :, :1], 1e-9)
         return out.reshape(hkv, bb, groups, d).transpose(1, 0, 2, 3) \
             .reshape(bb, hq, d)
@@ -333,7 +437,8 @@ def _paged_db_body(lengths_ref, layer_ref, table_ref, share_ref, q_ref,
             emit(per_row())
 
 
-def _paged_db_kernel(*refs, quant: bool, share: bool, **kw):
+def _paged_db_kernel(*refs, quant: bool, share: bool, rowmap: bool = False,
+                     sel: bool = False, bits: bool = False, **kw):
     """Name the pallas_call's positional refs (scalar prefetch, inputs,
     output, scratch, in _paged_flash_db's order) for _paged_db_body; what a
     bf16 pool or a call without a share fact leaves out is None."""
@@ -344,13 +449,17 @@ def _paged_db_kernel(*refs, quant: bool, share: bool, **kw):
 
     lengths_ref, layer_ref, table_ref = take(3)
     share_ref, = take(1, share)
+    rowmap_ref, = take(1, rowmap)
+    sel_ref, cnt_ref = take(2, sel)
+    bits_ref, = take(1, bits)
     q_ref, k_hbm, v_hbm = take(3)
     ks_hbm, vs_hbm = take(2, quant)
     o_ref, k_buf, v_buf = take(3)
     ks_buf, vs_buf = take(2, quant)
     acc_ref, m_ref, l_ref, sem = take(4)
     acc_t, m_t, l_t = take(3, share)
-    _paged_db_body(lengths_ref, layer_ref, table_ref, share_ref, q_ref,
+    _paged_db_body(lengths_ref, layer_ref, table_ref, share_ref, rowmap_ref,
+                   sel_ref, cnt_ref, bits_ref, q_ref,
                    k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf,
                    vs_buf, acc_ref, m_ref, l_ref, sem, acc_t, m_t, l_t, **kw)
 
@@ -365,7 +474,8 @@ def _resolve_bb(bblock, B: int) -> int:
 
 def _paged_flash_db(q2, pool_k, pool_v, lengths, layer_arr, table,
                     *, bb: int, R: int, spec: bool, window: int,
-                    interpret: bool, pool_ks, pool_vs, share=None):
+                    interpret: bool, pool_ks, pool_vs, share=None,
+                    row_map=None, sel=None, cnt=None, bits=None):
     """Build + dispatch the double-buffered paged flash call.
 
     q2: [B, R*Hq, D] (R=1 for plain decode). Grid is (B // bb,); the pools
@@ -373,6 +483,10 @@ def _paged_flash_db(q2, pool_k, pool_v, lengths, layer_arr, table,
     DMAs exactly the live pages), q/o are VMEM-blocked per slot block.
     ``share`` [B // bb] int32 (ragged entry only): per block, the row whose
     table its live rows all share, or -1 — see _paged_db_body.
+    ``row_map`` [B]: the row of ``table`` each packed row reads (None: its
+    own). ``sel`` [B, Hkv, K] with ``cnt`` [B, Hkv], or ``bits``
+    [B, Hkv, ceil(pages / 32)] int32: the pages each row and KV head
+    reads, as a list or as a mask (bf16 pool, no window).
     """
     B, RHq, D = q2.shape
     Hkv, ps = pool_k.shape[2], pool_k.shape[3]
@@ -399,15 +513,27 @@ def _paged_flash_db(q2, pool_k, pool_v, lengths, layer_arr, table,
         pltpu.VMEM((bb, RHq, D), jnp.float32),             # acc
         pltpu.VMEM((bb, RHq, 128), jnp.float32),           # m
         pltpu.VMEM((bb, RHq, 128), jnp.float32),           # l
-        pltpu.SemaphoreType.DMA((2, bb, 4 if quant else 2)),
+        # (the list form copies a page a KV head at a time)
+        pltpu.SemaphoreType.DMA((2, bb, 4 if quant else
+                                 2 * Hkv if sel is not None else 2)),
     ]
     # The table rides SMEM flattened: a 2-D s32[N, max_pages] operand pads
     # its minor dim to 128 lanes there, and the mixed program's per-ROW table
     # (N = slots + chunk = 2,080 rows x 32 pages at the default config)
     # then needs 1.04 MiB of the chip's 1 MiB.
     prefetch = [lengths, layer_arr, table.reshape(-1)]
+    if sel is not None or bits is not None:
+        assert not quant and window == 0 and not spec, \
+            "page selection: bf16 pool, full attention, one row a query"
     if share is not None:
         prefetch.append(share)
+    if row_map is not None:
+        prefetch.append(row_map)
+    if sel is not None:
+        prefetch += [sel.reshape(-1), cnt.reshape(-1)]
+    if bits is not None:
+        prefetch.append(bits.reshape(-1))
+    if share is not None:
         scratch += [                       # the sharing blocks' flash state
             pltpu.VMEM((Hkv, bb * groups, D), jnp.float32),
             pltpu.VMEM((Hkv, bb * groups, 128), jnp.float32),
@@ -426,7 +552,9 @@ def _paged_flash_db(q2, pool_k, pool_v, lengths, layer_arr, table,
     )
     kernel = functools.partial(
         _paged_db_kernel, quant=quant, share=share is not None,
-        ps=ps, groups=groups, scale=1.0 / (D ** 0.5), R=R, bb=bb,
+        rowmap=row_map is not None, sel=sel is not None,
+        bits=bits is not None, ps=ps, groups=groups, scale=1.0 / (D ** 0.5),
+        R=R, bb=bb,
         num_pages=num_pages, window=window, spec=spec)
     return pl.pallas_call(
         kernel,
@@ -545,6 +673,136 @@ def ragged_attend_pallas_paged(q: jnp.ndarray, pool_k: jnp.ndarray,
         q, pool_k, pool_v, row_limits, layer_arr, row_tables,
         bb=bb, R=1, spec=False, window=window, interpret=interpret,
         pool_ks=pool_ks, pool_vs=pool_vs, share=share)
+
+
+# SMEM the ragged selecting entry lets its prefetched operands take in one
+# call (of the chip's 1 MiB; the compiler keeps scalars of its own there)
+SELECT_PREFETCH_BYTES = 768 * 1024
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "bblock"))
+def decode_attend_pallas_paged_select(q, pool_k, pool_v, lengths, layer,
+                                      table, sel, cnt,
+                                      interpret: bool = False,
+                                      bblock: int = 1):
+    """:func:`decode_attend_pallas_paged` over SELECTED pages: row b's KV
+    head h reads the ``cnt[b, h]`` logical pages ``sel[b, h, :cnt]``
+    (ascending; entries past ``cnt`` are never read) and nothing else —
+    the walk is over list positions, so its length is the longest list of
+    the block, not the longest context (_paged_db_body). q: [B, 1, Hq, D];
+    sel: [B, Hkv, K] int32; cnt: [B, Hkv] int32; ``lengths`` counts the
+    just-written token and masks the last page's tail. bf16 pool."""
+    B = q.shape[0]
+    out = _paged_flash_db(
+        q[:, 0], pool_k, pool_v, lengths.astype(jnp.int32),
+        jnp.asarray(layer, jnp.int32).reshape(1), table.astype(jnp.int32),
+        bb=_resolve_bb(bblock, B), R=1, spec=False, window=0,
+        interpret=interpret, pool_ks=None, pool_vs=None,
+        sel=sel.astype(jnp.int32), cnt=cnt.astype(jnp.int32))
+    return out[:, None]
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "bblock"))
+def ragged_attend_pallas_paged_select(q, pool_k, pool_v, row_limits, layer,
+                                      table, row_map, bits,
+                                      interpret: bool = False,
+                                      bblock: int = 1):
+    """:func:`ragged_attend_pallas_paged` under a page SELECTION a row and
+    KV head: ``bits`` [N, Hkv, W] int32, bit p % 32 of word p // 32 set
+    where the row's head reads logical page p. Every live page of a block
+    is still walked (a page nobody chose skips its update); what a row did
+    not choose is masked. ``table`` [S, max_pages] holds ONE row a slot and
+    ``row_map`` [N] names each packed row's — the chunk rows of a prefill
+    share an entry, which is also how a sharing block is recognised.
+    q: [N, Hq, D]. bf16 pool. More rows than one call's prefetched operands
+    fit in SMEM (``SELECT_PREFETCH_BYTES``) are walked by further calls."""
+    N = q.shape[0]
+    bb = _resolve_bb(bblock, N)
+    row_limits = row_limits.astype(jnp.int32)
+    row_map = row_map.astype(jnp.int32)
+    table = table.astype(jnp.int32)
+    bits = bits.astype(jnp.int32)
+    layer_arr = jnp.asarray(layer, jnp.int32).reshape(1)
+
+    def call(q, lim, rmap, bits):
+        n = q.shape[0]
+        share = None
+        if bb > 1:
+            live = (lim > 0).reshape(n // bb, bb)
+            slots = rmap.reshape(n // bb, bb)
+            first = jnp.argmax(live, axis=1).astype(jnp.int32)
+            lead = jnp.take_along_axis(slots, first[:, None], axis=1)
+            same = jnp.all((slots == lead) | ~live, axis=1)
+            share = jnp.where(
+                same & live.any(axis=1),
+                jnp.arange(n // bb, dtype=jnp.int32) * bb + first, -1)
+        return _paged_flash_db(
+            q, pool_k, pool_v, lim, layer_arr, table, bb=bb, R=1,
+            spec=False, window=0, interpret=interpret, pool_ks=None,
+            pool_vs=None, share=share, row_map=rmap, bits=bits)
+
+    # The prefetched operands ride SMEM: the table, and a packed row its
+    # limit, its map entry and its Hkv x W bit words (8,216 rows x 2 x 16
+    # words alone are the chip's 1 MiB). Rows are independent, so more rows
+    # than fit go in further calls, each of whole blocks.
+    per_row = 4 * (2 + bits.shape[1] * bits.shape[2]) + 4
+    fit = max(bb, (SELECT_PREFETCH_BYTES - 4 * table.size) // per_row
+              // bb * bb)
+    if N <= fit:
+        return call(q, row_limits, row_map, bits)
+    step = -(-N // -(-N // fit) // bb) * bb
+    return jnp.concatenate([
+        call(q[lo:lo + step], row_limits[lo:lo + step],
+             row_map[lo:lo + step], bits[lo:lo + step])
+        for lo in range(0, N, step)])
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "stride"))
+def selector_add_row_paged(kc, new, rows, table, layer, *, stride: int,
+                           interpret: bool = False):
+    """Add one new key a slot into the SELECTOR's cache, in place: ``kc``
+    [L, P, Hkv, runs, D] float32 keeps, a page, the sums of its keys over
+    runs of ``stride`` tokens; the key at logical row ``rows[b]`` is added
+    to its run — which first drops to zero where the key opens it, so a
+    page's next occupant never adds to its predecessor's sums. One row a
+    slot, a grid step a slot, the whole page's runs one block (the
+    contract of cache_write_row_paged; rows outside the table drop)."""
+    L, P, Hkv, runs, D = kc.shape
+    ps = runs * stride
+    rows = rows.astype(jnp.int32)
+    table = table.astype(jnp.int32)
+    MP = table.shape[1]
+    layer_arr = jnp.asarray(layer, jnp.int32).reshape(1)
+
+    def new_map(b, rws, lay, tab):
+        return (b, 0, 0)
+
+    def blk_map(b, rws, lay, tab):
+        r = jnp.clip(rws[b], 0, MP * ps - 1)
+        return (lay[0], tab[b * MP + r // ps], 0, 0, 0)
+
+    def kernel(rows_ref, layer_ref, table_ref, new_ref, cin_ref, cout_ref):
+        r = rows_ref[pl.program_id(0)]
+        ok = (r >= 0) & (r < MP * ps)
+        r = jnp.clip(r, 0, MP * ps - 1)
+        run = jnp.where(ok, (r % ps) // stride, -1)
+        idx = jax.lax.broadcasted_iota(jnp.int32, (Hkv, runs, D), 1)
+        old = cin_ref[0, 0]
+        kept = jnp.where(r % stride == 0, 0.0, old)
+        cout_ref[0, 0] = jnp.where(idx == run, kept + new_ref[0][:, None, :],
+                                   old)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(new.shape[0],),
+        in_specs=[pl.BlockSpec((1, Hkv, D), new_map),
+                  pl.BlockSpec((1, 1, Hkv, runs, D), blk_map)],
+        out_specs=pl.BlockSpec((1, 1, Hkv, runs, D), blk_map))
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(kc.shape, kc.dtype),
+        input_output_aliases={4: 0}, interpret=interpret,
+    )(rows, layer_arr, table.reshape(-1), new.astype(kc.dtype), kc)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "window", "bblock"))

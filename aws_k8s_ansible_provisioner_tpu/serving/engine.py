@@ -312,6 +312,11 @@ class Engine(EnginePrograms):
 
         self.metrics = EngineMetrics()
         self.metrics.kda_state_bytes.set(self.kda_state_bytes)
+        if cfg.recurrent:
+            self.metrics.recurrent_state_bytes.set(
+                self.kda_state_bytes, kind=cfg.recurrent_kinds)
+        if cfg.selects:
+            self.metrics.selector_cache_bytes.set(self.selector_bytes)
         # AOT manifest summary (serving/aot.py), installed by
         # load_aot_manifest; surfaced on /healthz and the hbm gauge.
         self.aot = None
@@ -1286,6 +1291,13 @@ class Engine(EnginePrograms):
             # admission takes the chunk walk: the mixed program prefills
             # it without draining the pipeline, where a batch prefill
             # would activate slots under the in-flight carry.
+            if (self._inflight is not None and req.prompt_logprobs is not None
+                    and not (off > 0 or resumed or self._should_chunk(req))):
+                # the chunk walk returns no prompt logprobs: a request that
+                # asks for them settles the pipeline and prefills whole (it
+                # lost them, one run in two under load: the echo+logprobs
+                # test's flake PR 33 recorded)
+                self._drain_decode_pipeline("prefill")
             if (off > 0 or resumed or self._should_chunk(req)
                     or (self._ragged_on()
                         and self._inflight is not None)):

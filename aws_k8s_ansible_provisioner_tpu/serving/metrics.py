@@ -286,8 +286,28 @@ class EngineMetrics:
             ("kind",)))
         self.kda_rows = r.register(Counter(
             "tpu_serve_kda_rows_total",
-            "Rows that advanced a recurrent (KDA) state, per layer, by step "
-            "program", ("program",)))
+            "Rows that advanced a KDA layer's recurrent state, per layer, by "
+            "step program (a model with KDA layers; any recurrent kind: "
+            "tpu_serve_state_rows_total)", ("program",)))
+        self.state_rows = r.register(Counter(
+            "tpu_serve_state_rows_total",
+            "Rows that advanced a recurrent state, per layer, by the kind "
+            "of layer that keeps it (KDA, Lightning) and step program",
+            ("kind", "program")))
+        self.recurrent_state_bytes = r.register(Gauge(
+            "tpu_serve_recurrent_state_bytes",
+            "Bytes of per-slot recurrent state held beside the KV pool, by "
+            "the kind of layer that keeps it", ("kind",)))
+        self.selector_cache_bytes = r.register(Gauge(
+            "tpu_serve_selector_cache_bytes",
+            "Bytes of the pool's selector cache (run sums of keys a page: "
+            "a model whose attention selects its pages)"))
+        self.sparse_pages = r.register(Counter(
+            "tpu_serve_sparse_pages_total",
+            "Pages of the decode and mixed dispatches' selecting attention, "
+            "per selecting layer and (row, KV head), summed over substeps: "
+            "kind=\"live\" the pages the rows hold, kind=\"selected\" the "
+            "pages they read", ("kind",)))
         self.kda_state_bytes = r.register(Gauge(
             "tpu_serve_kda_state_bytes",
             "Bytes of per-slot recurrent state held beside the KV pool"))

@@ -50,6 +50,7 @@ from aws_k8s_ansible_provisioner_tpu.ops.attention import (
 from aws_k8s_ansible_provisioner_tpu.ops import kv_pool as kvp
 from aws_k8s_ansible_provisioner_tpu.ops import linear_attention as _la
 from aws_k8s_ansible_provisioner_tpu.ops import moe as _moe
+from aws_k8s_ansible_provisioner_tpu.ops import sparse_attention as _sa
 from aws_k8s_ansible_provisioner_tpu.ops.sampling import (apply_allow,
                                                            apply_penalties,
                                                            per_slot_keys,
@@ -385,6 +386,16 @@ def _moe_summary(stats):
     return jnp.stack(out)
 
 
+def _aux(moe, picked):
+    """The step programs' last output: an MoE model's routing summary, a
+    selecting model's page counts ([2] int32: live and selected pages of the
+    (row, KV head) pairs, summed over substeps and selecting layers), None
+    for any other (no model has both)."""
+    if picked is not None:
+        return picked.reshape(-1, 2).sum(axis=0)
+    return _moe_summary(moe)
+
+
 def _recur(cfg: ModelConfig, make, *args):
     """The KDA layers' callback for a step program, from the same row
     metadata its ``attend`` is built from; None for a model without
@@ -413,8 +424,10 @@ def prefill_step(cfg: ModelConfig, params, cache, tokens, true_len, rng,
     with lora_context(lora_idx):
         # carry path: the pool stays in the layer scan's carry — the xs→ys
         # restack buffer OOMed the batch-128 program on chip (r5)
-        attend = make_prefill_attend_paged_carry(
-            pages, true_len, window=cfg.sliding_window)
+        attend = _sa.make_prefill_attend_select(
+            cfg, pages[None], true_len[None]) if cfg.selects \
+            else make_prefill_attend_paged_carry(
+                pages, true_len, window=cfg.sliding_window)
         logits, cache = model_forward_carry(
             params, cfg, tokens, positions, cache, attend,
             _recur(cfg, _la.make_recur_span, slot, 0, true_len))
@@ -465,8 +478,9 @@ def prefill_batch_step(cfg: ModelConfig, params, cache, tokens, true_lens,
     N, T = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None], (N, T))
     with lora_context(lora_idx):
-        attend = make_prefill_attend_batch_paged_carry(
-            tables, true_lens, window=cfg.sliding_window)
+        attend = _sa.make_prefill_attend_select(cfg, tables, true_lens) \
+            if cfg.selects else make_prefill_attend_batch_paged_carry(
+                tables, true_lens, window=cfg.sliding_window)
         logits, cache = model_forward_carry(
             params, cfg, tokens, positions, cache, attend,
             _recur(cfg, _la.make_recur_batch, slots, true_lens))
@@ -509,8 +523,10 @@ def prefill_chunk_step(cfg: ModelConfig, params, cache, tokens, start,
     C = tokens.shape[1]
     positions = start + jnp.arange(C, dtype=jnp.int32)[None, :]
     with lora_context(lora_idx):
-        attend = make_chunk_prefill_attend_paged_carry(
-            pages, start, window=cfg.sliding_window)
+        attend = _sa.make_chunk_prefill_attend_select(
+            cfg, pages, start, chunk_len) if cfg.selects \
+            else make_chunk_prefill_attend_paged_carry(
+                pages, start, window=cfg.sliding_window)
         logits, cache = model_forward_carry(
             params, cfg, tokens, positions, cache, attend,
             _recur(cfg, _la.make_recur_span, slot, start, chunk_len))
@@ -588,10 +604,13 @@ def decode_steps(cfg: ModelConfig, n_steps: int, params, cache, tokens,
         # copy cost dominated decode at ~24 ms/token on v5e; see
         # model_forward_carry's docstring); the kernels address the pool's
         # pages through the block ``table``.
-        attend = make_decode_attend_carry_paged(
-            lens, table, impl=impl, mesh=mesh, window=cfg.sliding_window,
-            bblock=bblock)
-        with _moe.routed_rows(live) as routing:
+        attend = _sa.make_decode_attend_select(
+            cfg, lens, table, impl=impl, bblock=bblock, live=live) \
+            if cfg.selects \
+            else make_decode_attend_carry_paged(
+                lens, table, impl=impl, mesh=mesh, window=cfg.sliding_window,
+                bblock=bblock)
+        with _moe.routed_rows(live) as routing, _sa.counting() as picked:
             logits, cache = model_forward_carry(
                 params, cfg, tok[:, None], positions, cache, attend,
                 _recur(cfg, _la.make_recur_decode, live))
@@ -629,15 +648,16 @@ def decode_steps(cfg: ModelConfig, n_steps: int, params, cache, tokens,
             nxt_out = (nxt, _logprob_topk(step_logits, nxt))
         else:
             nxt_out = nxt
-        return (cache, cnts, nxt, lens + 1), (nxt_out, routing["stats"])
+        return (cache, cnts, nxt, lens + 1), (nxt_out, routing["stats"],
+                                              picked["pages"])
 
     if counts is None:
         counts = jnp.zeros((tokens.shape[0], 1), jnp.int32)  # unused dummy
     rngs = jax.random.split(rng, n_steps)
     with lora_context(lora_idx):
-        (cache, counts, tok, lens), (out, moe) = jax.lax.scan(
+        (cache, counts, tok, lens), (out, moe, picked) = jax.lax.scan(
             body, (cache, counts, tokens, lengths), rngs)
-    return cache, counts, out, tok, lens, _moe_summary(moe)
+    return cache, counts, out, tok, lens, _aux(moe, picked)
 
 
 @partial(jax.jit, static_argnums=(0,),
@@ -714,17 +734,22 @@ def mixed_step(cfg: ModelConfig, params, cache, tokens, lengths, ptokens,
     row_limits = jnp.concatenate(
         [jnp.where(is_p, jnp.int32(0), lengths + 1),
          jnp.where(is_pad, jnp.int32(0), crows + 1)])
-    row_tables = jnp.concatenate(
+    row_tables = None if cfg.selects else jnp.concatenate(
         [table, jnp.broadcast_to(table[pslot][None], (C, table.shape[1]))])
     packed = jnp.concatenate([tokens[None], ptokens], axis=1)     # [1, B+C]
     positions = jnp.concatenate(
         [jnp.where(is_p, jnp.int32(0), lengths)[None], crows[None]], axis=1)
     # K/V writes: one row a decode slot (pslot's own dropped), the chunk as
     # the span [pstart, pstart + plen) of pslot's page run
-    attend = make_mixed_attend_carry_paged(
-        jnp.where(is_p, jnp.int32(-1), lengths), pstart, plen, row_limits,
-        row_tables, impl=impl, mesh=mesh, window=cfg.sliding_window,
-        bblock=bblock)
+    if cfg.selects:     # one table row a SLOT: the chunk rows name pslot's
+        attend = _sa.make_mixed_attend_select(
+            cfg, jnp.where(is_p, jnp.int32(-1), lengths), pstart, plen,
+            row_limits, table, pslot, impl=impl, bblock=bblock, live=live)
+    else:
+        attend = make_mixed_attend_carry_paged(
+            jnp.where(is_p, jnp.int32(-1), lengths), pstart, plen,
+            row_limits, row_tables, impl=impl, mesh=mesh,
+            window=cfg.sliding_window, bblock=bblock)
     # Per-TOKEN adapter indices over the packed layout: decode row b keeps
     # its slot's adapter, every chunk row runs the chunking slot's — one
     # program serves any adapter mix (models/layers._linear gathers factors
@@ -740,7 +765,8 @@ def mixed_step(cfg: ModelConfig, params, cache, tokens, lengths, ptokens,
     if cfg.recurrent:   # (not through _recur: its operands would be traced)
         recur = _la.make_recur_mixed(
             B, None if live is None else live & ~is_p, pslot, pstart, plen)
-    with lora_context(packed_lora), _moe.routed_rows(packed_live) as routing:
+    with lora_context(packed_lora), _moe.routed_rows(packed_live) as routing, \
+            _sa.counting() as picked:
         logits, cache = model_forward_carry(params, cfg, packed, positions,
                                             cache, attend, recur)
     # -- decode rows: the decode_steps substep body, verbatim order --------
@@ -782,7 +808,7 @@ def mixed_step(cfg: ModelConfig, params, cache, tokens, lengths, ptokens,
         if logprobs else nxt[None]
     pout = (ptok, _logprob_topk(plast, ptok)) if chunk_logprobs else ptok
     return cache, counts, out, pout, tok_out, lens_out, \
-        _moe_summary(routing["stats"])
+        _aux(routing["stats"], picked["pages"])
 
 
 @partial(jax.jit, static_argnums=(0, 1), static_argnames=("impl", "mesh",
@@ -972,14 +998,27 @@ class EnginePrograms:
 
     @staticmethod
     def _refuse_unsupported(cfg: ModelConfig, serving, mesh, lora) -> None:
-        """What cannot be right yet for a model with recurrent layers or an
-        expert share is refused at start-up, each with its reason."""
-        if not (cfg.recurrent or cfg.expert_share):
+        """What cannot be right yet for a model with recurrent layers, an
+        expert share or selecting attention is refused at start-up, each
+        with its reason."""
+        if not (cfg.recurrent or cfg.expert_share or cfg.selects):
             return
-        what = "recurrent (KDA) layers" if cfg.recurrent \
-            else "an expert share"
+        what = f"recurrent ({cfg.recurrent_kinds}) layers" if cfg.recurrent \
+            else "an expert share" if cfg.expert_share \
+            else "attention that selects its pages"
         multi = mesh is not None or serving.mesh.num_devices > 1
         for bad, why in (
+                (cfg.selects
+                 and serving.page_size != cfg.sparse_block_size,
+                 f"--page-size {serving.page_size}: its attention selects "
+                 f"BLOCKS of {cfg.sparse_block_size} tokens, and a block is "
+                 f"a page of the pool"),
+                (cfg.selects and serving.kv_dtype == "int8",
+                 "int8 KV: the selecting kernels read a bf16 pool (a key's "
+                 "scale would have to enter the selector's sums)"),
+                (cfg.selects and serving.spec_decode,
+                 "speculative decoding: the verify kernel walks every page "
+                 "of a row, not a selection"),
                 (multi, "--tp/--dp > 1: no sharding rule says how the "
                  "per-slot state leaves or a share's expert stacks divide "
                  "over a mesh"),
@@ -988,8 +1027,8 @@ class EnginePrograms:
                  "advanced the recurrent state, and no snapshot exists to "
                  "roll it back"),
                 (cfg.recurrent and bool(lora),
-                 "LoRA adapters: no adapter layout names the KDA layers' "
-                 "projections"),
+                 f"LoRA adapters: no adapter layout names the "
+                 f"{cfg.recurrent_kinds} layers' projections"),
                 (cfg.recurrent and serving.kv_host_tier_bytes > 0,
                  "the host KV tier (--kv-host-tier-bytes > 0): a restored "
                  "page carries K/V without the recurrent state that goes "
@@ -1214,17 +1253,21 @@ class EnginePrograms:
         # Recurrent layers keep per-SLOT state beside the pool, in the same
         # pytree the step programs donate (ops/linear_attention.py)
         self.kda_state_bytes = _la.state_bytes(cfg, self.num_slots, dtype)
+        self.selector_bytes = kvp.selector_bytes(cfg, total_pages, ps)
         if cfg.recurrent:
             self.cache.update(_la.init_state(cfg, self.num_slots, dtype))
+        if cfg.recurrent or cfg.selects:
             import logging
 
             logging.getLogger(__name__).info(
-                "cache: KV pool %.3f GiB (%d attending layers x %d pages) + "
-                "recurrent state %.3f GiB (%d KDA layers x %d slots)",
+                "cache: KV pool %.3f GiB (%d attending layers x %d pages; "
+                "of it the selector's cache %.3f GiB) + recurrent state "
+                "%.3f GiB (%d %s layers x %d slots)",
                 kvp.pool_bytes(cfg, total_pages, ps, dtype, self.kv_quant)
                 / 2**30, cfg.num_attn_layers, total_pages,
-                self.kda_state_bytes / 2**30,
-                cfg.num_periods * cfg.kda_per_period, self.num_slots)
+                self.selector_bytes / 2**30,
+                self.kda_state_bytes / 2**30, cfg.num_recurrent_layers,
+                cfg.recurrent_kinds or "recurrent", self.num_slots)
         self.allocators = [pkv.PagePool(self._group_pages, ps,
                                         first_page=1)
                            for _ in range(self.dp_groups)]
@@ -1402,12 +1445,19 @@ class EnginePrograms:
 
     def _kda_rows(self, rows: int, slots: int = 0) -> dict:
         """Dispatch-record fields of a model with recurrent layers:
-        ``kda_rows`` (rows that advance a state in this dispatch, per
-        layer: horizon x active for a decode dispatch) and ``kda_slots``
-        (slots whose state a decode or mixed dispatch reads and writes)."""
+        ``state_rows`` (rows that advance a state in this dispatch, per
+        layer: horizon x active for a decode dispatch), ``state_slots``
+        (slots whose state a decode or mixed dispatch reads and writes) and
+        ``state_kind`` (KDA, Lightning); a model with KDA layers carries the
+        first two as ``kda_rows`` / ``kda_slots`` too, the names its readers
+        know."""
         if not self.cfg.recurrent:
             return {}
-        return {"kda_rows": int(rows), "kda_slots": int(slots)}
+        out = {"state_rows": int(rows), "state_slots": int(slots),
+               "state_kind": self.cfg.recurrent_kinds}
+        if "k" in self.cfg.layer_pattern:
+            out.update(kda_rows=int(rows), kda_slots=int(slots))
+        return out
 
     def _attn_pages(self, horizon: int, carry_steps: int) -> dict:
         """Dispatch-record fields of a plain decode dispatch, per attending
@@ -1541,6 +1591,15 @@ class EnginePrograms:
         if "kda_rows" in rec:
             self.metrics.kda_rows.inc(rec["kda_rows"],
                                       program=rec["program"])
+        if "state_rows" in rec:
+            self.metrics.state_rows.inc(rec["state_rows"],
+                                        kind=rec["state_kind"],
+                                        program=rec["program"])
+        if "sparse_pages_live" in rec:
+            self.metrics.sparse_pages.inc(rec["sparse_pages_live"],
+                                          kind="live")
+            self.metrics.sparse_pages.inc(rec["sparse_pages_selected"],
+                                          kind="selected")
         if "attn_pages_live" in rec:
             self.metrics.decode_attn_pages.inc(rec["attn_pages_live"],
                                                kind="live")
@@ -1841,8 +1900,10 @@ class EnginePrograms:
         mixed = (self._ragged_on()
                  and (req.guided is None
                       or self.serving.ragged_features > 0)
+                 # (a model whose attention selects has ONE chunk program
+                 # that reads a long window: the ragged one)
                  and (self._inflight is not None
-                      or bool(self._active_slots())))
+                      or bool(self._active_slots()) or self.cfg.selects))
         if not mixed:
             # chunking rewrites the slot's length out of band of any decode
             # carry (admission already drained the pipeline; belt-and-braces)
@@ -1869,9 +1930,11 @@ class EnginePrograms:
         self.lengths[slot] = off
         # What the walk prefills. A resume ends with decode RE-processing
         # the last real token at its own row (_activate): rewriting a K/V
-        # row is idempotent, advancing a recurrent state twice is not — so
-        # a model with recurrent layers rebuilds over all but that token.
-        walk = ids[:-1] if resumed and self.cfg.recurrent else ids
+        # row is idempotent, advancing a recurrent state twice is not (nor
+        # adding a key to the selector's run sums twice) — so a model with
+        # recurrent or selecting layers rebuilds over all but that token.
+        walk = ids[:-1] if resumed and (self.cfg.recurrent
+                                        or self.cfg.selects) else ids
         self._chunk = {"req": req, "slot": slot, "off": off,
                        "C": self._chunk_size, "ids": ids, "walk": walk,
                        "resumed": resumed, "rep_seen": rep_seen,
@@ -2649,7 +2712,9 @@ class EnginePrograms:
             "decode_steps", "decode", active, horizon=horizon,
             carry_steps=prev["horizon"] if prev is not None else 0,
             **self._kda_rows(horizon * len(active), len(active)))
-        drec.update(self._attn_pages(horizon, drec["carry_steps"]))
+        if not self.cfg.selects:    # (its rows walk a selection: the
+            # program counts it, sparse_pages_* at the fetch)
+            drec.update(self._attn_pages(horizon, drec["carry_steps"]))
         self._book_bubble(drec["t_enqueue"])
         real_counts = self.counts
         with _Dispatching(drec):
@@ -2738,7 +2803,19 @@ class EnginePrograms:
                     rec["chunk_lp_t"] = tuple(np.asarray(a) for a in plp)
                 else:
                     rec["chunk_token"] = int(np.asarray(pout)[0])
-            if rec.get("moe") is not None:
+            if rec.get("moe") is not None and self.cfg.selects:
+                # a selecting model's page counts ride the same fetch (the
+                # programs' last output, _aux): per selecting layer, the
+                # (row, KV head) pairs' live and selected pages over the
+                # record's substeps
+                live_n, picked = (
+                    np.asarray(rec["moe"]) / self.cfg.num_attn_layers)
+                rec["drec"].update(
+                    sparse_rows=rec["horizon"] * len(rec["active"])
+                    + rec.get("chunk_n", 0),
+                    sparse_pages_live=float(live_n),
+                    sparse_pages_selected=float(picked))
+            elif rec.get("moe") is not None:
                 # routing counts of an MoE model ride the same fetch: the
                 # program has ended, this is a copy of two floats
                 moe = np.asarray(rec["moe"])

@@ -1652,6 +1652,19 @@ def build_state(serving_cfg=None, model_cfg=None, params=None,
 
             model_cfg = tiny_solar(vocab_size=tokenizer.vocab_size,
                                    eos_token_id=tokenizer.eos_token_id)
+        elif serving.model == "tiny-sala":
+            # the dry-run model whose layer kinds are a LIST: selecting
+            # attention ("s") and Lightning layers ("l"), muP scales
+            from aws_k8s_ansible_provisioner_tpu.config import tiny_sala
+
+            ps = serving.page_size      # a selected block is a page
+            model_cfg = tiny_sala(vocab_size=tokenizer.vocab_size,
+                                  eos_token_id=tokenizer.eos_token_id,
+                                  sparse_block_size=ps,
+                                  sparse_kernel_size=ps // 2,
+                                  sparse_kernel_stride=ps // 4,
+                                  sparse_window_size=2 * ps,
+                                  sparse_dense_len=4 * ps)
         else:
             raise ValueError(f"unknown model {serving.model!r} and no checkpoint")
 
@@ -1858,6 +1871,13 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--prefill-chunk", type=int, default=0,
                    help="chunked prefill size; 0 disables (long prompts "
                         "then cap at the largest bucket)")
+    p.add_argument("--prefill-buckets", default="",
+                   help="comma-separated prompt-length buckets of the "
+                        "whole-prompt prefill programs (default: powers of "
+                        "two from 32 to 2048). With --prefill-chunk a prompt "
+                        "longer than the chunk is chunked whatever the "
+                        "buckets say, so a bucket above the chunk compiles "
+                        "nothing: it names a length the deployment serves")
     p.add_argument("--no-prefix-cache", action="store_true",
                    help="disable automatic prompt-prefix K/V reuse")
     p.add_argument("--spec-decode", action="store_true",
@@ -1971,6 +1991,9 @@ def serving_config_from_args(args):
         kv_host_tier_bytes=args.kv_host_tier_bytes,
         checkpoint_dir=args.checkpoint_dir, chat_template=args.chat_template,
         prefill_chunk=args.prefill_chunk,
+        **({"prefill_buckets": tuple(sorted(
+            int(b) for b in args.prefill_buckets.split(",")))}
+           if args.prefill_buckets else {}),
         prefix_cache=not args.no_prefix_cache,
         spec_decode=args.spec_decode, spec_k=args.spec_k,
         spec_method=args.spec_method,

@@ -308,7 +308,13 @@ def run_requests(srv: Server, model: str) -> dict:
     say(f"requests: {len(WAVE)} concurrent /v1/completions ok "
         f"(prompts {[w[1] for w in WAVE]}, {expected} tokens)")
 
-    # one streamed chat completion
+    # one streamed chat completion, into an IDLE engine: the wave's clients
+    # have their last tokens, the engine may still hold its last (surplus)
+    # decode dispatch in flight for a step, and an admission under it would
+    # walk the mixed program instead of ``prefill_step``
+    deadline = time.monotonic() + 10
+    while srv.engine._inflight is not None and time.monotonic() < deadline:
+        time.sleep(0.01)
     r = http_stream(port, "/v1/chat/completions", {
         "model": model, "stream": True, "max_tokens": 32,
         "temperature": 0.0, "ignore_eos": True,
@@ -362,6 +368,30 @@ def run_requests(srv: Server, model: str) -> dict:
     r = http_stream(port, "/v1/completions",
                     dict(body, stream=True, logprobs=0))
     t_mixed = time.monotonic()
+    cfg = srv.engine.cfg
+    if cfg.selects:
+        # a prompt PAST the dense length, several chunks of mixed_step under
+        # the live stream: every later chunk's rows select their pages, the
+        # Lightning state is handed over between chunks, and the 24 generated
+        # tokens read 64 selected pages each
+        n_long = cfg.sparse_dense_len + cfg.sparse_dense_len // 8 + 37
+        rl = http_stream(port, "/v1/completions", {
+            "model": model, "prompt": prompt_of(n_long, 77), "stream": True,
+            "max_tokens": 24, "temperature": 0.0, "ignore_eos": True,
+            "logprobs": 0})
+        check(rl["status"] == 200 and rl["done"]
+              and len(rl["token_ids"]) == 24 and len(rl["logprobs"]) == 24,
+              f"mlong: status {rl['status']} tokens {len(rl['token_ids'])}")
+        check(not bg.get("t_done"),
+              "the background stream ended before the long prompt did: it "
+              "was not admitted under a live batch")
+        streams["mlong"] = {"prompt": prompt_of(n_long, 77),
+                            "token_ids": rl["token_ids"],
+                            "logprobs": rl["logprobs"]}
+        expected += 24
+        say(f"requests: a {n_long}-token prompt past the dense length "
+            f"({cfg.sparse_dense_len}) beside the live stream ok "
+            f"({time.monotonic() - t_mixed:.2f}s)")
     status, raw = http_json(
         port, "POST", "/v1/completions",
         {"model": model, "prompt": prompt_of(1100, 4), "max_tokens": 32,
@@ -852,6 +882,296 @@ def expert_forms_parity(cfg, rows: int) -> None:
         f"{int((np.asarray(gs) > 0).sum())}")
 
 
+def sala_kernel_parity(cfg, slots: int, window: int, page: int, bb: int,
+                       interpret: bool) -> None:
+    """The kernels a model with selecting attention and Lightning layers
+    adds, at the served widths (``groups`` 16, 512-page tables at the 32k
+    window) against jax.numpy: the decode kernel over LISTS of selected
+    pages, the ragged kernel under BITMASKS with one table row a slot, the
+    selector's row add, and the Lightning decode update — on seeded inputs
+    and seeded (random) selections that differ per KV head."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from aws_k8s_ansible_provisioner_tpu.ops import kv_pool as kvp
+    from aws_k8s_ansible_provisioner_tpu.ops import linear_attention as la
+    from aws_k8s_ansible_provisioner_tpu.ops import pallas_attention as pa
+    from aws_k8s_ansible_provisioner_tpu.ops import sparse_attention as sa
+
+    Hq, Hkv, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    B, MP, L = slots, window // page, 2
+    P = B * MP + 1
+    layer = jnp.int32(1)
+    rng = np.random.default_rng(34)
+    table = jnp.asarray((rng.permutation(B * MP) + 1)
+                        .reshape(B, MP).astype(np.int32))
+    dense_len, K = cfg.sparse_dense_len, cfg.sparse_select_width
+    base = [window, dense_len + 1, page + 1, 2 * dense_len - 7, 0, 300,
+            dense_len + 3 * page, 7]
+    lengths = np.asarray([min(base[i % len(base)], window) for i in range(B)],
+                         np.int32)
+    keys = jax.random.split(jax.random.PRNGKey(34), 8)
+    pool = {n: jax.random.normal(k, (L, P, Hkv, page, D), jnp.bfloat16)
+            for n, k in (("k", keys[1]), ("v", keys[2]))}
+
+    def close(name, got, want, tol=KERNEL_TOL):
+        got = np.asarray(jnp.asarray(got, jnp.float32))
+        want = np.asarray(jnp.asarray(want, jnp.float32))
+        check(np.all(np.isfinite(got)), f"{name}: non-finite output")
+        err = float(np.max(np.abs(got - want) / (tol + tol * np.abs(want))))
+        check(err <= 1.0, f"{name}: off by {err:.2f}x the tolerance "
+                          f"(atol=rtol={tol})")
+        return float(np.max(np.abs(got - want)))
+
+    def random_selection(lims):
+        """[R, Hkv, MP] bool: the last page, page 0 and a random subset of
+        the rest, at most topk pages, another set a KV head."""
+        sel = np.zeros((len(lims), Hkv, MP), bool)
+        for r, n in enumerate(lims):
+            live = -(-int(n) // page)
+            for h in range(Hkv):
+                if not live:
+                    continue
+                pick = rng.permutation(live)[:max(1, cfg.sparse_topk - 2)]
+                sel[r, h, pick] = True
+                sel[r, h, [0, live - 1]] = True
+                if n < dense_len:
+                    sel[r, h, :live] = True
+        return jnp.asarray(sel)
+
+    dense = kvp.gather_layer_dense(pool, layer, table)     # [B, Hkv, S, D]
+    # -- decode: lists ------------------------------------------------------
+    q = jax.random.normal(keys[0], (B, 1, Hq, D), jnp.bfloat16)
+    sel = random_selection(lengths)
+    pages, cnt = sa.as_list(cfg, sel)
+    with jax.default_matmul_precision("highest"):
+        want = jax.vmap(lambda q1, k1, v1, l1, s1: sa._attend_rows(
+            q1, k1, v1, l1[None], s1[None], page))(
+                q, dense["k"], dense["v"], jnp.asarray(lengths), sel)
+    worst = 0.0
+    for b in sorted({1, bb}):
+        got = pa.decode_attend_pallas_paged_select(
+            q, pool["k"], pool["v"], jnp.asarray(lengths), layer, table,
+            pages, cnt, interpret=interpret, bblock=b)
+        worst = max(worst, close(f"decode over selected pages, bblock {b}",
+                                 got, want))
+        check(not np.asarray(got, np.float32)[lengths == 0].any(),
+              "a dead row's output is not zero")
+    differ = int((np.asarray(sel)[:, 0] != np.asarray(sel)[:, 1])
+                 .any(axis=-1).sum())
+    say(f"kernel parity [decode, selected pages, groups {Hq // Hkv}, "
+        f"{MP}-page tables, lists of {K}]: max |diff| {worst:.4f}; "
+        f"{differ} of {B} rows read other pages a KV head")
+    # -- ragged: bitmasks, one table row a slot -------------------------------
+    C, pslot = 64 if interpret else 256, 3
+    off = int(lengths[1]) if lengths[1] + C <= window else page
+    lims = np.concatenate([np.where(np.arange(B) == pslot, 0, lengths),
+                           off + 1 + np.arange(C)]).astype(np.int32)
+    lims[B + C - 5:] = 0                           # the chunk's padding
+    row_map = jnp.concatenate([jnp.arange(B, dtype=jnp.int32),
+                               jnp.full((C,), pslot, jnp.int32)])
+    q3 = jax.random.normal(keys[3], (B + C, Hq, D), jnp.bfloat16)
+    sel = random_selection(lims)
+    with jax.default_matmul_precision("highest"):
+        want = jax.lax.map(
+            lambda a: sa._attend_rows(a[0][None], dense["k"][a[3]],
+                                      dense["v"][a[3]], a[1][None],
+                                      a[2][None], page)[0],
+            (q3, jnp.asarray(lims), sel, row_map))
+    got = pa.ragged_attend_pallas_paged_select(
+        q3, pool["k"], pool["v"], jnp.asarray(lims), layer, table, row_map,
+        sa.as_bits(sel), interpret=interpret, bblock=bb)
+    worst = close(f"ragged under page masks, bblock {bb}", got, want)
+    say(f"kernel parity [ragged, page masks, {B} decode rows + a {C}-row "
+        f"chunk at {off}, one table row a slot]: max |diff| {worst:.4f}")
+    del dense
+    # -- the selector's row add ----------------------------------------------
+    runs = page // cfg.sparse_kernel_stride
+    kc = jax.random.normal(keys[4], (L, P, Hkv, runs, D), jnp.float32)
+    knew = jax.random.normal(keys[5], (B, Hkv, D), jnp.bfloat16)
+    rows = jnp.asarray(np.where(np.arange(B) == pslot, -1,
+                                np.minimum(lengths, window - 1)), jnp.int32)
+    want = sa.add_rows(cfg, kc, layer, rows, table, knew, "xla")
+    got = pa.selector_add_row_paged(kc + 0, knew, rows, table, layer,
+                                    stride=cfg.sparse_kernel_stride,
+                                    interpret=interpret)
+    worst = close("selector row add", got, want, 1e-5)
+    say(f"kernel parity [selector row add, {runs} runs a page]: max |diff| "
+        f"{worst:.6f}")
+    # -- the Lightning decode update ------------------------------------------
+    H, d = cfg.lightning_num_heads, cfg.lightning_head_dim
+    nl = cfg.layer_pattern.count("l")
+    st = jax.random.normal(keys[6], (nl, 1, B, H, d, d), jnp.float32)
+    ks = jax.random.split(keys[7], 3)
+    ql, kl, vl = (jax.random.normal(k, (B, H, d), jnp.float32) for k in ks)
+    live = jnp.asarray(lengths > 0)
+    from aws_k8s_ansible_provisioner_tpu.models.layers import lightning_slopes
+
+    g, beta = la._lin_decay(lightning_slopes(H), live)
+    want_o, want_s = la.lightning_step(st[nl - 1, 0], ql, kl, vl, g, beta)
+    got_o, got_s = la.kda_decode_update(
+        st + 0, jnp.int32(nl - 1), 0, ql, kl, vl,
+        jnp.broadcast_to(g[..., None], ql.shape), beta, interpret=interpret,
+        delta_rule=False)
+    worst = max(close("Lightning decode update: output", got_o, want_o, 1e-3),
+                close("Lightning decode update: state", got_s[nl - 1, 0],
+                      want_s, 1e-3))
+    check(bool((got_s[0] == st[0]).all()) if nl > 1 else True,
+          "the Lightning update touched another layer's state")
+    say(f"kernel parity [Lightning decode update, {H} heads of {d}, in "
+        f"place]: max |diff| {worst:.5f}")
+
+
+def handed_selection_program(cfg, T: int):
+    """The program's own stateless forward over one T-token sequence
+    (bfloat16, the served tree, ``lightning_span`` from zero, the selecting
+    attention dense under its selection), unrolled layer by layer: each
+    selecting layer returns the blocks it chose and, where ``use`` is set,
+    reads the ``handed`` ones [selecting layers, T, Hkv, blocks] instead.
+    Jitted: (tree, tokens [T], handed, use) -> (logprob rows [T - 1, V]
+    float32, the choices made)."""
+    import jax
+    import jax.numpy as jnp
+
+    from aws_k8s_ansible_provisioner_tpu.models import layers as L
+    from aws_k8s_ansible_provisioner_tpu.ops import linear_attention as la
+    from aws_k8s_ansible_provisioner_tpu.ops import sparse_attention as sa
+
+    def program(tree, toks, handed, use):
+        pos = jnp.arange(T, dtype=jnp.int32)[None]
+        x, cos, sin = L._embed_inputs(tree, cfg, toks[None], pos)
+        picked, seen = [], {"attn": 0, "lightning": 0}
+        for kind in cfg.layer_pattern:
+            stack = "lightning" if kind == "l" else "attn"
+            lp = jax.tree.map(lambda a, i=seen[stack]: a[i],
+                              tree["layers"][stack])
+            seen[stack] += 1
+            if kind == "l":
+                x, _ = L.lightning_block(cfg, lp, x, cos, sin,
+                                         la.recur_from_zero, (None, 0, 0))
+                continue
+            own = sa.make_stateless_attend_select(cfg)
+            took = sa.make_stateless_attend_select(
+                cfg, handed=handed[len(picked)][None])
+
+            def attend(q, k, v, cl, own=own, took=took):
+                ctx_own, sel = own(q, k, v, None)
+                ctx = jnp.where(use, took(q, k, v, None)[0], ctx_own)
+                return ctx, sel[0]
+
+            x, sel = L.decoder_block(cfg, lp, x, cos, sin, attend, None)
+            picked.append(sel)
+        logits = L._final_logits(tree, cfg, x)[0].astype(jnp.float32)
+        return jax.nn.log_softmax(logits, axis=-1)[:-1], jnp.stack(picked)
+
+    return jax.jit(program)
+
+
+def check_selection_cause(cfg, params, plain, T: int,
+                          strict: bool = True) -> None:
+    """Where a selecting model's distance from its reference comes from,
+    directly, at the served size, on the served int8 tree.
+
+    The reference selects on its own float32 activations; the program on
+    bfloat16 ones. Where the 64th and 65th block scores of a token's KV head
+    are nearer than that noise the two read different blocks. So this phase
+    (1) COUNTS the (token, layer, KV head) triples past the dense length
+    whose selected sets differ, and how many blocks differ; (2) hands each
+    side the OTHER's choices and measures what is left — everything but
+    selection ties; (3) under handed selection, takes the LEARNED blocks
+    away (the forced ones only: the first block and the local window) and
+    separately hands every token its neighbour KV head's set: each has to
+    move the logprobs, else the comparison could not tell a selector that
+    chooses from one that does not.
+
+    Distances are over every position of one ``T``-token sequence past the
+    dense length: |program - reference| of the reference's most likely
+    token, the worst position and the median over all windows of 16
+    consecutive positions of the window's worst (16 positions are what one
+    comparison of the benchmark sees)."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    mc = dataclasses.asdict(cfg)
+    program = handed_selection_program(cfg, T)
+    ids = np.random.default_rng(20260929).integers(32, 127, T)
+    toks = jnp.asarray(ids, jnp.int32)
+    ns, Hkv = cfg.num_attn_layers, cfg.num_kv_heads
+    NB = -(-T // cfg.sparse_block_size)
+    none = jnp.zeros((ns, T, Hkv, NB), bool)
+    at = np.arange(T - 1)
+    past = np.arange(T) >= cfg.sparse_dense_len        # rows that select
+
+    def reference(selection=None):
+        with jax.default_matmul_precision("highest"):
+            lg, sel = plain.forward(mc, params, list(ids), T - 1,
+                                    selection=selection)
+            return (np.asarray(jax.nn.log_softmax(lg, axis=-1)),
+                    np.asarray(sel))
+
+    def served(handed=None):
+        lp, sel = program(params, toks, none if handed is None
+                          else jnp.asarray(handed), handed is not None)
+        return np.asarray(lp), np.asarray(sel)
+
+    def apart(a, b, tok):
+        d = np.abs(a[at, tok] - b[at, tok])[cfg.sparse_dense_len:]
+        if len(d) < 16:
+            d = np.abs(a[at, tok] - b[at, tok])
+        worst16 = [d[i:i + 16].max() for i in range(0, len(d) - 15)]
+        return float(d.max()), float(np.median(worst16))
+
+    t0 = time.monotonic()
+    ref_lp, ref_sel = reference()
+    tok = ref_lp.argmax(axis=-1)
+    own_lp, own_sel = served()
+    diff = (ref_sel != own_sel)[:, past]               # [ns, rows, Hkv, NB]
+    triples = diff.any(axis=-1)
+    by_layer = " ".join(f"{100 * x:.1f}" for x in triples.mean(axis=(1, 2)))
+    say(f"selection[{T} tokens, {int(past.sum())} past the dense length, "
+        f"{ns} selecting layers x {Hkv} KV heads]: the reference's and the "
+        f"program's selected sets differ in {100 * triples.mean():.2f} % of "
+        f"(token, layer, KV head) triples (by layer: {by_layer}); "
+        f"{diff.sum() / 2 / max(1, triples.sum()):.2f} blocks swapped where "
+        f"they differ, of {int(ref_sel[:, past].sum(-1).mean())} read")
+    d_own = apart(own_lp, ref_lp, tok)
+    d_handed = apart(served(ref_sel)[0], ref_lp, tok)
+    d_back = apart(own_lp, reference(own_sel)[0], tok)
+    say(f"selection cause: program vs reference on their own selections "
+        f"{d_own[0]:.4f} / {d_own[1]:.4f} nats (worst position / median of "
+        f"the worst of 16); the program handed the reference's "
+        f"{d_handed[0]:.4f} / {d_handed[1]:.4f}; the reference handed the "
+        f"program's {d_back[0]:.4f} / {d_back[1]:.4f} "
+        f"({time.monotonic() - t0:.1f}s)")
+    # what a flip costs, and whether the comparison sees the selector at all
+    bs = cfg.sparse_block_size
+    own_blk = (np.arange(T) // bs)[:, None]
+    blk = np.arange(NB)[None, :]
+    forced = ((blk < cfg.sparse_init_blocks)
+              | (blk > own_blk - cfg.sparse_window_size // bs)) \
+        & (blk <= own_blk)
+    only_forced = np.where(past[None, :, None, None],
+                           ref_sel & forced[None, :, None, :], ref_sel)
+    swapped = ref_sel[:, :, ::-1]                      # the other head's set
+    d_forced = apart(served(only_forced)[0], ref_lp, tok)
+    d_swapped = apart(served(swapped)[0], ref_lp, tok)
+    say(f"selection cause: the program handed ONLY the forced blocks "
+        f"{d_forced[0]:.4f} / {d_forced[1]:.4f} nats; handed the OTHER KV "
+        f"head's blocks {d_swapped[0]:.4f} / {d_swapped[1]:.4f}")
+    if strict:
+        check(d_handed[0] <= LOGPROB_NATS and d_back[0] <= LOGPROB_NATS,
+              "with the selection handed over the program and the reference "
+              "are still apart: the distance is not selection ties")
+        check(d_forced[0] > d_handed[0] and d_swapped[0] > d_handed[0],
+              "taking the learned blocks away (or reading the other KV "
+              "head's) moves nothing: the comparison cannot see the "
+              "selector")
+
+
 def handed_routing_program(cfg, T: int):
     """The program's own stateless forward over one T-token sequence
     (bfloat16, the served tree, ``kda_span`` from zero, the expert form the
@@ -1245,6 +1565,7 @@ def main() -> int:
             mc = _config.ModelConfig(**cfg_file["model_config"])
             model = mc.name
             _config.MODEL_REGISTRY[model] = mc
+    rehearse_flags: dict = {}
     if opts.rehearse:
         # the same flags, window and traffic on a model the CPU can serve
         tiny = dict(vocab_size=512, hidden_size=128, max_seq_len=4096,
@@ -1254,6 +1575,21 @@ def main() -> int:
             _config.MODEL_REGISTRY[model] = _config.tiny_qwen3(
                 name=model, intermediate_size=256, num_heads=8,
                 num_kv_heads=4, head_dim=32, **tiny)
+        elif _config.MODEL_REGISTRY[model].selects:
+            # the list hybrid: selecting attention + Lightning layers; the
+            # window and the chunk cut to what the CPU's dense fallback holds
+            model = "rehearse-sala"
+            _config.MODEL_REGISTRY[model] = _config.tiny_sala(
+                name=model, intermediate_size=256, num_heads=4,
+                num_kv_heads=2, head_dim=32, lightning_num_heads=4,
+                lightning_head_dim=32, sparse_block_size=64,
+                sparse_kernel_size=32, sparse_kernel_stride=16,
+                sparse_topk=4, sparse_window_size=128, sparse_dense_len=256,
+                dim_model_base=32, **tiny)
+            rehearse_flags = {"--max-cache-len": "4096",
+                              "--prefill-chunk": "128",
+                              "--prefill-buckets": "64,128,2048",
+                              "--max-decode-slots": "8"}
         elif _config.MODEL_REGISTRY[model].layer_pattern:
             # the hybrid: gated NoPE GQA + KDA layers, an expert share
             model = "rehearse-solar"
@@ -1272,6 +1608,8 @@ def main() -> int:
     if cfg_file is not None:
         flags = list(cfg_file["server_flags"])
         flags[flags.index("--model") + 1] = model
+        for flag, value in rehearse_flags.items():
+            flags[flags.index(flag) + 1] = value
         import dataclasses
 
         t0 = time.monotonic()
@@ -1328,8 +1666,19 @@ def main() -> int:
             check_numerics(f"{name}, tp=4 vs one device", got[name], cfg,
                            params, tokenizer)
     else:
-        for name in ("c70", "c30", "m700"):   # m700: through mixed_step
+        for name in ("c70", "c30", "m700") + (("mlong",) if cfg.selects
+                                              else ()):
+            # m700 (and mlong, several chunks): through mixed_step
             check_numerics(name, got[name], cfg, eng.params, tokenizer, plain)
+        if cfg.selects:
+            refused = check_lower_precision("mlong", got["mlong"], cfg,
+                                            eng.params, tokenizer, plain)
+            check(opts.rehearse or refused["act"],
+                  "the comparison passes a reference computed in float8")
+            check_selection_cause(
+                cfg, eng.params, plain,
+                cfg.sparse_dense_len + (64 if opts.rehearse else 1024),
+                strict=not opts.rehearse)
         if cfg.num_experts > 0:
             check_routing_counts(srv.port, cfg)
         if cfg.expert_share:
@@ -1346,7 +1695,17 @@ def main() -> int:
         # (groups = 1, 16 KV heads: OLMoE's), which gives a decode block of 8
         # slots 8 query rows a KV head where the 0.6B gives 16
         mha = _config.MODEL_REGISTRY["allenai/OLMoE-1B-7B-0125-Instruct"]
-        if opts.rehearse:
+        if cfg.selects:
+            # (the plain kernels' parity packs a table row a packed ROW,
+            # which a 512-page table does not fit: the default run has it)
+            if opts.rehearse:
+                sala_kernel_parity(cfg.scaled(
+                    lightning_num_heads=8, lightning_head_dim=128), 8,
+                    16 * page, page, 4, interpret=True)
+            else:
+                sala_kernel_parity(cfg, slots, window, page, bb,
+                                   interpret=False)
+        elif opts.rehearse:
             # interpret mode is slow: same code path at a small shape
             kernel_parity(cfg, 8, 256, 32, sorted({1, 4}), interpret=True)
             if cfg_file is None:

@@ -380,3 +380,83 @@ def test_kda_decode_update_compiles_in_place_under_its_own_name(chip):
     _assert_named_after_wrapper(compiled, la.kda_decode_update)
     assert compiled.memory_analysis().alias_size_in_bytes \
         >= 4 * periods * nk * slots * heads * d * d
+
+
+# -- the kernels of a model whose attention selects its pages (PR 34) --------
+# MiniCPM-SALA's shape as served: 2 KV heads, 32 query heads (groups 16), 24
+# slots, a 32k window (512-page tables), page 64, top-64 (lists of 128: a
+# context under the dense length reads up to 128 pages).
+
+S_HQ, S_HKV, S_B, S_MP, S_K = 32, 2, 24, 512, 128
+
+
+def _sala_pool(chip):
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+
+    return sds, sds((2, S_B * S_MP + 1, S_HKV, PS, D), jnp.bfloat16)
+
+
+@pytest.mark.parametrize("bb", [1, 8])
+def test_decode_kernel_over_selected_pages_compiles_for_v5e(chip, bb):
+    """The LIST form: a DMA a KV head a page, the table 4x wider than any
+    other case here, ``groups`` 16."""
+    import os
+    import sys
+
+    sds, kv = _sala_pool(chip)
+    i32 = jnp.int32
+    fn = pa.decode_attend_pallas_paged_select
+    compiled = _compile(
+        functools.partial(fn, bblock=bb), sds((S_B, 1, S_HQ, D), jnp.bfloat16),
+        kv, kv, sds((S_B,), i32), sds((), i32), sds((S_B, S_MP), i32),
+        sds((S_B, S_HKV, S_K), i32), sds((S_B, S_HKV), i32))
+    assert "tpu_custom_call" in compiled.as_text()
+    _assert_named_after_wrapper(compiled, fn)
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark"))
+    from benchlib import sala_opsbytes
+
+    assert sala_opsbytes.DECODE_KERNEL_RE == "^%" + fn.__name__
+
+
+@pytest.mark.parametrize("chunk", [1024, CHUNK, 8192])
+def test_ragged_kernel_under_page_masks_compiles_for_v5e(chip, chunk):
+    """The BITMASK form with ONE table row a slot (a table row a packed row
+    is 4.2 MB of SMEM at this window): 24 + chunk rows, 16 mask words a row
+    and KV head — at 8,192 rows (a chunk an operator may ask for; PR 34
+    tried it) the words alone are the chip's SMEM, and the entry walks the
+    rows in two calls."""
+    sds, kv = _sala_pool(chip)
+    i32, N = jnp.int32, S_B + chunk
+    fn = pa.ragged_attend_pallas_paged_select
+    compiled = _compile(
+        functools.partial(fn, bblock=8), sds((N, S_HQ, D), jnp.bfloat16), kv,
+        kv, sds((N,), i32), sds((), i32), sds((S_B, S_MP), i32),
+        sds((N,), i32), sds((N, S_HKV, S_MP // 32), i32))
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.as_text().count("custom_call_target=\"tpu_custom_call\"") \
+        == (2 if chunk == 8192 else 1)
+    _assert_named_after_wrapper(compiled, fn)
+
+
+def test_selector_row_add_and_lightning_update_compile_for_v5e(chip):
+    from aws_k8s_ansible_provisioner_tpu.ops import linear_attention as la
+
+    sds, _ = _sala_pool(chip)
+    i32, f32 = jnp.int32, jnp.float32
+    kc = sds((2, S_B * S_MP + 1, S_HKV, PS // 16, D), f32)
+    compiled = _compile(
+        functools.partial(pa.selector_add_row_paged, stride=16), kc,
+        sds((S_B, S_HKV, D), jnp.bfloat16), sds((S_B,), i32),
+        sds((S_B, S_MP), i32), sds((), i32))
+    assert "tpu_custom_call" in compiled.as_text()
+    _assert_named_after_wrapper(compiled, pa.selector_add_row_paged)
+    H, d = 32, 128
+    row = sds((S_B, H, d), f32)
+    compiled = _compile(
+        lambda st, i, *a: la.kda_decode_update(st, i, 0, *a,
+                                               delta_rule=False),
+        sds((6, 1, S_B, H, d, d), f32), sds((), i32), row, row, row, row,
+        sds((S_B, H), f32))
+    assert "tpu_custom_call" in compiled.as_text()
