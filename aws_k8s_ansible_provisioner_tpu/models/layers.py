@@ -643,7 +643,16 @@ def _embed_inputs(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
     return x, cos, sin
 
 
-def _final_logits(params: dict, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
+def _final_logits(params: dict, cfg: ModelConfig, x: jnp.ndarray,
+                  head_rows=None) -> jnp.ndarray:
+    """The head: final norm, muP scale, vocabulary matmul — all row-wise, so
+    with ``head_rows`` ([R] int32 over ``x``'s flattened ``B * T`` rows) it
+    runs over those rows alone and gives ``[R, V]``: the same numbers the
+    every-row ``[B, T, V]`` holds there. The head carries no adapter
+    (models/lora.py targets the attention and MLP projections), so the
+    gathered rows need no per-token index."""
+    if head_rows is not None:
+        x = x.reshape(-1, x.shape[-1])[head_rows]
     x = apply_norm(cfg, x, params["final_norm"])
     if cfg.logit_scale != 1.0:      # muP: logits over hidden / dim_model_base
         x = x * jnp.asarray(cfg.logit_scale, x.dtype)
@@ -719,6 +728,7 @@ def model_forward_carry(
     cache: Any,                   # full stacked cache ([L, ...] leaves)
     attend: AttendFn,             # receives cache_l = (full_cache, layer_idx)
     recur=None,                   # KDA layers' callback (layer_pattern)
+    head_rows=None,               # [R] int32: the rows whose logits are read
 ) -> Tuple[jnp.ndarray, Any]:
     """Decoder forward with the cache in the scan CARRY, not xs/ys.
 
@@ -734,13 +744,20 @@ def model_forward_carry(
     (ops/attention.py's callbacks over the paged pool), so per-step HBM
     traffic is weights + live cache rows only. Every serving step program
     runs this form.
+
+    ``head_rows`` names the rows the caller will read logits of, as indices
+    over the flattened ``B * T`` rows: the hidden state is gathered to them
+    BEFORE the head, and the logits come back ``[R, V]`` (a prefill-type
+    program samples one row a prompt; the head over every packed row was a
+    quarter of a small model's flops and gigabytes of temporaries). None
+    keeps ``[B, T, V]`` — the programs that read every row.
     """
     if cfg.layer_list:
         return _list_forward_carry(params, cfg, tokens, positions, cache,
-                                   attend, recur)
+                                   attend, recur, head_rows=head_rows)
     if cfg.layer_pattern:
         return _hybrid_forward_carry(params, cfg, tokens, positions, cache,
-                                     attend, recur)
+                                     attend, recur, head_rows=head_rows)
     x, cos, sin = _embed_inputs(params, cfg, tokens, positions)
     from aws_k8s_ansible_provisioner_tpu.ops import moe
 
@@ -754,11 +771,12 @@ def model_forward_carry(
     (x, cache, _), per_layer = jax.lax.scan(
         body, (x, cache, jnp.int32(0)), params["layers"])
     moe.put_stats(per_layer)
-    return _final_logits(params, cfg, x), cache
+    return _final_logits(params, cfg, x, head_rows), cache
 
 
 def _hybrid_forward_carry(params, cfg: ModelConfig, tokens, positions, cache,
-                          attend: AttendFn, recur, remat: bool = False):
+                          attend: AttendFn, recur, remat: bool = False,
+                          head_rows=None):
     """model_forward_carry for a model with a layer pattern: ONE scan over
     PERIODS whose body runs the period's kinds in order. ``cache`` holds
     the pool's K/V leaves with a leading axis of ATTENDING layers (one a
@@ -810,7 +828,7 @@ def _hybrid_forward_carry(params, cfg: ModelConfig, tokens, positions, cache,
     if per_layer is not None:       # [P, layers a period, n] -> [L, n]
         per_layer = per_layer.reshape((-1,) + per_layer.shape[2:])
     moe.put_stats(per_layer)
-    return _final_logits(params, cfg, x), {**pool, **rec}
+    return _final_logits(params, cfg, x, head_rows), {**pool, **rec}
 
 
 def layer_runs(pattern: str):
@@ -829,7 +847,8 @@ def layer_runs(pattern: str):
 
 
 def _list_forward_carry(params, cfg: ModelConfig, tokens, positions, cache,
-                        attend: AttendFn, recur, remat: bool = False):
+                        attend: AttendFn, recur, remat: bool = False,
+                        head_rows=None):
     """model_forward_carry for a model whose layer kinds are a LIST (not a
     period): the list is walked RUN by run of one kind, a run longer than
     one layer as ONE scan over its layers — so the step programs hold one
@@ -885,4 +904,4 @@ def _list_forward_carry(params, cfg: ModelConfig, tokens, positions, cache,
     x, pool, rec = carry
     if sa.TALLY in pool:
         sa.put_counts(pool.pop(sa.TALLY))
-    return _final_logits(params, cfg, x), {**pool, **rec}
+    return _final_logits(params, cfg, x, head_rows), {**pool, **rec}

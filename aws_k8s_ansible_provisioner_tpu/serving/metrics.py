@@ -219,6 +219,12 @@ class EngineMetrics:
             "for one stream; one handler wake-up each)"))
         self.prompt_tokens = r.register(Counter(
             "tpu_serve_prompt_tokens_total", "Prompt tokens prefilled"))
+        # the sampled rows alone (1, N, 1, slots + 1 a dispatch); every padded
+        # row only where prompt_logprobs reads them all
+        self.head_rows = r.register(Counter(
+            "tpu_serve_head_rows_total",
+            "Rows the model's head (final norm + vocabulary matmul) ran "
+            "over in prefill-type dispatches", ("program",)))
         self.request_duration = r.register(Histogram(
             "tpu_serve_request_duration_seconds", "End-to-end request latency"))
         self.vllm_request_duration = r.register(Histogram(

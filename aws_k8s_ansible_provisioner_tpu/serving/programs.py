@@ -330,6 +330,16 @@ def _prompt_logprobs(logits, tokens):
             jnp.swapaxes(ids, 0, 1))
 
 
+def _head(logits, rows):
+    """``[R, V]`` logits of ``rows`` (indices over the forward's flattened
+    ``B * T`` rows): what ``model_forward_carry(head_rows=rows)`` returned,
+    or those rows of an every-row ``[B, T, V]`` (a ``prompt_logprobs``
+    variant, which reads every row besides)."""
+    if logits.ndim == 2:
+        return logits
+    return logits.reshape(-1, logits.shape[-1])[rows]
+
+
 def _host_lp(lp_t, row: int, k: int):
     """Slice one row of a device (sel, vals, ids) triple into the host-side
     per-token logprob record: (own_logprob, [(token_id, logprob) x k])."""
@@ -421,6 +431,7 @@ def prefill_step(cfg: ModelConfig, params, cache, tokens, true_len, rng,
     """
     T = tokens.shape[1]
     positions = jnp.arange(T, dtype=jnp.int32)[None, :]
+    rows = (true_len - 1)[None]         # the one row that is sampled
     with lora_context(lora_idx):
         # carry path: the pool stays in the layer scan's carry — the xs→ys
         # restack buffer OOMed the batch-128 program on chip (r5)
@@ -430,8 +441,9 @@ def prefill_step(cfg: ModelConfig, params, cache, tokens, true_len, rng,
                 pages, true_len, window=cfg.sliding_window)
         logits, cache = model_forward_carry(
             params, cfg, tokens, positions, cache, attend,
-            _recur(cfg, _la.make_recur_span, slot, 0, true_len))
-    last = jnp.take(logits[0], true_len - 1, axis=0)[None]   # [1, V]
+            _recur(cfg, _la.make_recur_span, slot, 0, true_len),
+            head_rows=None if prompt_logprobs else rows)
+    last = _head(logits, rows)                               # [1, V]
     last = _apply_prefill_repetition(last, tokens, true_len[None],
                                      rep[None] if rep is not None else None)
     if bias_ids is not None:
@@ -477,14 +489,17 @@ def prefill_batch_step(cfg: ModelConfig, params, cache, tokens, true_lens,
     """
     N, T = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None], (N, T))
+    # each prompt's last row, over the [N * T] rows
+    rows = jnp.arange(N, dtype=jnp.int32) * T + true_lens - 1
     with lora_context(lora_idx):
         attend = _sa.make_prefill_attend_select(cfg, tables, true_lens) \
             if cfg.selects else make_prefill_attend_batch_paged_carry(
                 tables, true_lens, window=cfg.sliding_window)
         logits, cache = model_forward_carry(
             params, cfg, tokens, positions, cache, attend,
-            _recur(cfg, _la.make_recur_batch, slots, true_lens))
-    last = logits[jnp.arange(N), true_lens - 1]            # [N, V]
+            _recur(cfg, _la.make_recur_batch, slots, true_lens),
+            head_rows=None if prompt_logprobs else rows)
+    last = _head(logits, rows)                             # [N, V]
     last = _apply_prefill_repetition(last, tokens, true_lens, reps)
     if bias_ids is not None:
         last = _apply_logit_bias(last, bias_ids, bias_vals)
@@ -529,8 +544,9 @@ def prefill_chunk_step(cfg: ModelConfig, params, cache, tokens, start,
                 pages, start, window=cfg.sliding_window)
         logits, cache = model_forward_carry(
             params, cfg, tokens, positions, cache, attend,
-            _recur(cfg, _la.make_recur_span, slot, start, chunk_len))
-    last = jnp.take(logits[0], chunk_len - 1, axis=0)[None]  # [1, V]
+            _recur(cfg, _la.make_recur_span, slot, start, chunk_len),
+            head_rows=(chunk_len - 1)[None])
+    last = logits                       # [1, V]: the chunk's last valid row
     if rep is not None and rep_seen is not None:
         # chunks only carry a slice of the prompt: the seen-set over the
         # WHOLE context comes precomputed from the host ([V] bool)
@@ -767,10 +783,14 @@ def mixed_step(cfg: ModelConfig, params, cache, tokens, lengths, ptokens,
             B, None if live is None else live & ~is_p, pslot, pstart, plen)
     with lora_context(packed_lora), _moe.routed_rows(packed_live) as routing, \
             _sa.counting() as picked:
-        logits, cache = model_forward_carry(params, cfg, packed, positions,
-                                            cache, attend, recur)
+        # the head over the rows that are sampled: every decode row and the
+        # chunk's last valid one — [B + 1, V], not [B + C, V]
+        logits, cache = model_forward_carry(
+            params, cfg, packed, positions, cache, attend, recur,
+            head_rows=jnp.concatenate(
+                [jnp.arange(B, dtype=jnp.int32), (B + plen - 1)[None]]))
     # -- decode rows: the decode_steps substep body, verbatim order --------
-    dec_logits = logits[0, :B]
+    dec_logits = logits[:B]
     if penalties:
         dec_logits = apply_penalties(dec_logits, counts, presence, frequency,
                                      repetition, prompt_mask)
@@ -785,7 +805,7 @@ def mixed_step(cfg: ModelConfig, params, cache, tokens, lengths, ptokens,
         # stale-occupant rows)
         counts = counts.at[jnp.arange(counts.shape[0]), nxt].add(1)
     # -- chunk row: the prefill_chunk_step tail, verbatim order ------------
-    plast = jnp.take(logits[0, B:], plen - 1, axis=0)[None]       # [1, V]
+    plast = logits[B:]                                            # [1, V]
     if prep is not None and prep_seen is not None:
         r = prep.astype(jnp.float32)
         lf = plast.astype(jnp.float32)
@@ -1513,7 +1533,10 @@ class EnginePrograms:
         prints it, ``kind`` devmon's program kind, ``active`` the decode
         rows live, ``given`` the static key and the per-kind facts
         (horizon, chunk_rows, chunk_n, chunk_off, bucket, rows,
-        prompt_tokens, padded_tokens, carry_steps = steps of an unfetched
+        prompt_tokens, padded_tokens = the rows a prefill-type program's
+        layers run over, head_rows = the rows its head runs over: one a
+        sampled row, every row in a prompt_logprobs variant,
+        carry_steps = steps of an unfetched
         predecessor the device-side lengths are ahead of the mirrors by,
         write_pages = page windows of the pool that hold a row of a mixed
         step's chunk: what its span write changes, whatever chunk_rows is).
@@ -1588,6 +1611,9 @@ class EnginePrograms:
             m.moe_group_rows_max.set(rec["moe_group_max"])
             if "moe_rows_held" in rec:
                 m.moe_rows_held.inc(rec["moe_rows_held"], program=prog)
+        if "head_rows" in rec:
+            self.metrics.head_rows.inc(rec["head_rows"],
+                                       program=rec["program"])
         if "kda_rows" in rec:
             self.metrics.kda_rows.inc(rec["kda_rows"],
                                       program=rec["program"])
@@ -1760,6 +1786,7 @@ class EnginePrograms:
         drec = self._dispatch_open(
             "prefill_step", "prefill", bucket=bucket,
             prompt_tokens=len(ids), padded_tokens=bucket,
+            head_rows=bucket if kw["prompt_logprobs"] else 1,
             **self._kda_rows(len(ids)))
         with _Dispatching(drec):
             out = prefill_step(self.cfg, self.params, self.cache, *args,
@@ -1852,7 +1879,9 @@ class EnginePrograms:
         drec = self._dispatch_open(
             "prefill_batch_step", "prefill_batch", rows=n_bucket,
             bucket=t_bucket, prompt_tokens=n_prompt,
-            padded_tokens=n_bucket * t_bucket, **self._kda_rows(n_prompt))
+            padded_tokens=n_bucket * t_bucket,
+            head_rows=n_bucket * t_bucket if want_plp else n_bucket,
+            **self._kda_rows(n_prompt))
         with _Dispatching(drec):
             out = prefill_batch_step(self.cfg, self.params, self.cache,
                                      *args, **kw)
@@ -1997,8 +2026,8 @@ class EnginePrograms:
                 **self._state_kw("slot", slot))
             drec = self._dispatch_open(
                 "prefill_chunk_step", "prefill_chunk", chunk_rows=C,
-                chunk_n=len(chunk), chunk_off=off,
-                **self._kda_rows(len(chunk)))
+                chunk_n=len(chunk), chunk_off=off, padded_tokens=C,
+                head_rows=1, **self._kda_rows(len(chunk)))
             with _Dispatching(drec):
                 out = prefill_chunk_step(self.cfg, self.params, self.cache,
                                          *args, **kw)
@@ -2171,6 +2200,8 @@ class EnginePrograms:
             "mixed_step", "mixed_step", active, horizon=1,
             chunk_rows=st["C"], chunk_n=len(chunk), chunk_off=off,
             write_pages=(off + len(chunk) - 1) // ps - off // ps + 1,
+            padded_tokens=self.num_slots + st["C"],
+            head_rows=self.num_slots + 1,
             carry_steps=prev["horizon"] if prev is not None else 0,
             **self._kda_rows(len(active) + len(chunk), len(active)))
         self._book_bubble(drec["t_enqueue"])

@@ -278,8 +278,10 @@ def test_mixed_step_holds_no_pool_copy(chip, monkeypatch, model, slots):
     body holds an aliased Pallas row write (the decode rows), the chunk's
     page-window scatter and the ragged kernel on ONE pool: nothing but
     those writers may produce a pool-shaped value (a copy or a relayout
-    would), and the temporaries stay what the packed rows' float32 logits
-    take."""
+    would). The head runs over the ``slots + 1`` rows that are sampled
+    (PR 35), so the temporaries hold NO packed rows' logits — 1,207 MiB of
+    float32 at the 0.6B's vocabulary before — only a few copies of the
+    sampled rows' and the layers' activations."""
     import math
     import re
 
@@ -301,10 +303,12 @@ def test_mixed_step_holds_no_pool_copy(chip, monkeypatch, model, slots):
     compiled = fn.lower(*args, **kwargs).compile()
     leaf = cache["k"]
     assert leaf.dtype == jnp.bfloat16
-    logits = (slots + CHUNK) * cfg.vocab_size * 4
+    sampled = (slots + 1) * cfg.vocab_size * 4
     assert compiled.memory_analysis().temp_size_in_bytes \
-        < logits + 2 * math.prod(leaf.shape) // 8
+        < 4 * sampled + 2 * math.prod(leaf.shape) // 8
     text = compiled.as_text()
+    # no value of the packed rows' height and the vocabulary's width
+    assert not re.search(rf"\[(1,)?{slots + CHUNK},{cfg.vocab_size}\]", text)
     shape = re.escape("bf16[" + ",".join(map(str, leaf.shape)) + "]")
     makers = set(re.findall(rf" = {shape}\S* ([\w\-]+)\(", text))
     assert makers <= {"parameter", "get-tuple-element", "custom-call",
@@ -313,6 +317,38 @@ def test_mixed_step_holds_no_pool_copy(chip, monkeypatch, model, slots):
     # the ragged one (the names the benchmark's readers match)
     _assert_named_after_wrapper(compiled, pa.cache_write_row_paged)
     _assert_named_after_wrapper(compiled, pa.ragged_attend_pallas_paged)
+
+
+def test_batched_prefill_holds_no_every_row_logits(chip, monkeypatch):
+    """``prefill_batch_step`` at 4 rows x bucket 1,024 of the 0.6B cell
+    (the closed cell's ramp and the warm-up dispatch it): the head over
+    its 4 sampled rows — 2,142 MiB of temporaries before PR 35, most of
+    them the 4,096 rows' bf16 and float32 logits; what is left is the
+    attention's scores."""
+    import re
+
+    from aws_k8s_ansible_provisioner_tpu.config import (MODEL_REGISTRY,
+                                                        ServingConfig)
+    from aws_k8s_ansible_provisioner_tpu.serving import aot
+
+    monkeypatch.setattr(pa, "supported", lambda: True)
+    cfg = MODEL_REGISTRY["Qwen/Qwen3-0.6B"]
+    plan = aot.ProgramPlan(cfg, ServingConfig(
+        model=cfg.name, max_decode_slots=32, max_cache_len=2048,
+        weights_dtype="int8", decode_bblock=8, kv_host_tier_bytes=0))
+    params, cache = aot._abstract_state(plan, None,
+                                        next(iter(chip.device_set)))
+    _, fn, args, kwargs = next(
+        p for p in aot.enumerate_programs(plan, None, params, cache)
+        if p[0].startswith("prefill_batch_n"))
+    rows, bucket = args[3].shape[0], 1024
+    assert rows == 4
+    args = args[:3] + (jax.ShapeDtypeStruct(
+        (rows, bucket), jnp.int32, sharding=args[3].sharding),) + args[4:]
+    compiled = fn.lower(*args, **kwargs).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 512 * 2**20
+    assert not re.search(rf"\[{rows},{bucket},{cfg.vocab_size}\]",
+                         compiled.as_text())
 
 
 @pytest.mark.parametrize(
