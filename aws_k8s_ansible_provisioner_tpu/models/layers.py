@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 
 from aws_k8s_ansible_provisioner_tpu.config import ModelConfig
+from aws_k8s_ansible_provisioner_tpu.models import parts
 
 # An attend callback: (q [B,T,Hq,D], k [B,T,Hkv,D], v [B,T,Hkv,D], layer_cache)
 # -> (context [B,T,Hq,D], new_layer_cache). q/k are already RoPE'd and qk-normed.
@@ -489,20 +490,33 @@ def _mlp(cfg: ModelConfig, h: jnp.ndarray, p: dict) -> jnp.ndarray:
         out = moe_mlp(cfg, h.reshape(B * T, H), p).reshape(B, T, H)
         if cfg.n_shared_experts:   # a dense SwiGLU every token passes
             sp = p["shared"]
-            out = out + _linear(
-                jax.nn.silu(_linear(h, sp["w_gate"])) * _linear(h, sp["w_up"]),
-                sp["w_down"])
+            with jax.named_scope(parts.MLP):
+                out = out + _linear(
+                    jax.nn.silu(_linear(h, sp["w_gate"]))
+                    * _linear(h, sp["w_up"]), sp["w_down"])
         return out
-    if cfg.gated_mlp:  # SwiGLU (Qwen/Llama) / GeGLU (Gemma)
-        gate_act = jax.nn.silu if cfg.act == "silu" \
-            else partial(jax.nn.gelu, approximate=True)  # "gelu_tanh"
-        return _linear(gate_act(_linear(h, p["w_gate"])) * _linear(h, p["w_up"]),
-                       p["w_down"])
-    if cfg.act == "relu":  # OPT
-        act = jax.nn.relu
-    else:
-        act = partial(jax.nn.gelu, approximate=True)  # HF "gelu_new"
-    return _linear(act(_linear(h, p["w_up"])), p["w_down"])
+    with jax.named_scope(parts.MLP):
+        if cfg.gated_mlp:  # SwiGLU (Qwen/Llama) / GeGLU (Gemma)
+            gate_act = jax.nn.silu if cfg.act == "silu" \
+                else partial(jax.nn.gelu, approximate=True)  # "gelu_tanh"
+            return _linear(
+                gate_act(_linear(h, p["w_gate"])) * _linear(h, p["w_up"]),
+                p["w_down"])
+        if cfg.act == "relu":  # OPT
+            act = jax.nn.relu
+        else:
+            act = partial(jax.nn.gelu, approximate=True)  # HF "gelu_new"
+        return _linear(act(_linear(h, p["w_up"])), p["w_down"])
+
+
+def _add_ffn(cfg: ModelConfig, x: jnp.ndarray, h: jnp.ndarray,
+             p: dict) -> jnp.ndarray:
+    """``x`` + the block's FFN of ``h``; the residual add carries the part
+    of the FFN's last operation (an elementwise tail fuses behind the matmul
+    it follows and names the fusion)."""
+    y = _mlp(cfg, h, p)
+    with jax.named_scope(parts.ffn_tail(cfg)):
+        return _residual(cfg, x, y)
 
 
 def decoder_block(cfg: ModelConfig, p: dict, x: jnp.ndarray,
@@ -512,35 +526,38 @@ def decoder_block(cfg: ModelConfig, p: dict, x: jnp.ndarray,
     B, T, _ = x.shape
     rotary_dim = int(cfg.head_dim * cfg.rotary_pct)
 
-    h = apply_norm(cfg, x, p["input_norm"])
-    q, k = _linear(h, p["wq"]), _linear(h, p["wk"])
-    whole = cfg.qk_norm and cfg.qk_norm_span == "projection"
-    if whole:  # OLMoE: RMSNorm over the whole projection, before the split
-        q = rms_norm(q, p["q_norm"]["weight"], cfg.norm_eps)
-        k = rms_norm(k, p["k_norm"]["weight"], cfg.norm_eps)
-    q = q.reshape(B, T, cfg.num_heads, cfg.head_dim)
-    k = k.reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
-    v = _linear(h, p["wv"]).reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
-    if cfg.qk_norm and not whole:  # per-head RMSNorm on q/k (Qwen3)
-        q = rms_norm(q, p["q_norm"]["weight"], cfg.norm_eps)
-        k = rms_norm(k, p["k_norm"]["weight"], cfg.norm_eps)
-    if cfg.pos_embed == "rope" and cfg.attn_use_rope:
-        q = apply_rope(q, cos, sin, rotary_dim)
-        k = apply_rope(k, cos, sin, rotary_dim)
+    with jax.named_scope(parts.NORM):
+        h = apply_norm(cfg, x, p["input_norm"])
+    with jax.named_scope(parts.ATTN_PROJ):
+        q, k = _linear(h, p["wq"]), _linear(h, p["wk"])
+        whole = cfg.qk_norm and cfg.qk_norm_span == "projection"
+        if whole:  # OLMoE: RMSNorm over the whole projection, pre-split
+            q = rms_norm(q, p["q_norm"]["weight"], cfg.norm_eps)
+            k = rms_norm(k, p["k_norm"]["weight"], cfg.norm_eps)
+        q = q.reshape(B, T, cfg.num_heads, cfg.head_dim)
+        k = k.reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
+        v = _linear(h, p["wv"]).reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
+        if cfg.qk_norm and not whole:  # per-head RMSNorm on q/k (Qwen3)
+            q = rms_norm(q, p["q_norm"]["weight"], cfg.norm_eps)
+            k = rms_norm(k, p["k_norm"]["weight"], cfg.norm_eps)
+        if cfg.pos_embed == "rope" and cfg.attn_use_rope:
+            q = apply_rope(q, cos, sin, rotary_dim)
+            k = apply_rope(k, cos, sin, rotary_dim)
 
-    ctx, new_cache_l = attend(q, k, v, cache_l)
-    ctx = ctx.reshape(B, T, cfg.q_size)
-    if cfg.attn_output_gate:   # elementwise, from its own projection
-        ctx = ctx * jax.nn.sigmoid(_linear(h, p["wg"]))
-    attn_out = _linear(ctx, p["wo"])
-
+    with jax.named_scope(parts.ATTN_CORE):
+        ctx, new_cache_l = attend(q, k, v, cache_l)
+        ctx = ctx.reshape(B, T, cfg.q_size)
+    with jax.named_scope(parts.ATTN_OUT):
+        if cfg.attn_output_gate:   # elementwise, from its own projection
+            with jax.named_scope(parts.ATTN_PROJ):
+                gate = jax.nn.sigmoid(_linear(h, p["wg"]))
+            ctx = ctx * gate
+        x = _residual(cfg, x, _linear(ctx, p["wo"]))
     if cfg.parallel_block:  # Phi: attn and MLP both read the same normed input
-        x = x + attn_out + _mlp(cfg, h, p)
-    else:
-        x = _residual(cfg, x, attn_out)
+        return _add_ffn(cfg, x, h, p), new_cache_l
+    with jax.named_scope(parts.NORM):
         h2 = apply_norm(cfg, x, p["post_norm"])
-        x = _residual(cfg, x, _mlp(cfg, h2, p))
-    return x, new_cache_l
+    return _add_ffn(cfg, x, h2, p), new_cache_l
 
 
 def _residual(cfg: ModelConfig, x: jnp.ndarray, y: jnp.ndarray):
@@ -570,20 +587,27 @@ def lightning_block(cfg: ModelConfig, p: dict, x: jnp.ndarray,
     ``rec_l`` names and returns the heads' outputs in float32."""
     B, T, _ = x.shape
     Hl, d = cfg.lightning_num_heads, cfg.lightning_head_dim
-    h = apply_norm(cfg, x, p["input_norm"])
-    q = _linear(h, p["wq"]).reshape(B, T, Hl, d)
-    k = _linear(h, p["wk"]).reshape(B, T, Hl, d)
-    v = _linear(h, p["wv"]).reshape(B, T, Hl, d)
-    if cfg.qk_norm:
-        q = rms_norm(q, p["q_norm"]["weight"], cfg.norm_eps)
-        k = rms_norm(k, p["k_norm"]["weight"], cfg.norm_eps)
-    q, k = apply_rope(q, cos, sin, d), apply_rope(k, cos, sin, d)
-    o, rec = recur.lightning(q, k, v, lightning_slopes(Hl), rec_l)
-    o = rms_norm(o, p["o_norm"]["weight"], cfg.norm_eps).astype(x.dtype)
-    o = o.reshape(B, T, Hl * d) * jax.nn.sigmoid(_linear(h, p["wg"]))
-    x = _residual(cfg, x, _linear(o, p["wo"]))
-    return _residual(cfg, x, _mlp(cfg, apply_norm(cfg, x, p["post_norm"]),
-                                  p)), rec
+    with jax.named_scope(parts.NORM):
+        h = apply_norm(cfg, x, p["input_norm"])
+    with jax.named_scope(parts.ATTN_PROJ):
+        q = _linear(h, p["wq"]).reshape(B, T, Hl, d)
+        k = _linear(h, p["wk"]).reshape(B, T, Hl, d)
+        v = _linear(h, p["wv"]).reshape(B, T, Hl, d)
+        if cfg.qk_norm:
+            q = rms_norm(q, p["q_norm"]["weight"], cfg.norm_eps)
+            k = rms_norm(k, p["k_norm"]["weight"], cfg.norm_eps)
+        q, k = apply_rope(q, cos, sin, d), apply_rope(k, cos, sin, d)
+    with jax.named_scope(parts.RECUR):
+        o, rec = recur.lightning(q, k, v, lightning_slopes(Hl), rec_l)
+        o = rms_norm(o, p["o_norm"]["weight"], cfg.norm_eps).astype(x.dtype)
+    with jax.named_scope(parts.ATTN_PROJ):
+        gate = jax.nn.sigmoid(_linear(h, p["wg"]))
+    with jax.named_scope(parts.ATTN_OUT):
+        o = o.reshape(B, T, Hl * d) * gate
+        x = _residual(cfg, x, _linear(o, p["wo"]))
+    with jax.named_scope(parts.NORM):
+        h2 = apply_norm(cfg, x, p["post_norm"])
+    return _add_ffn(cfg, x, h2, p), rec
 
 
 def kda_block(cfg: ModelConfig, p: dict, x: jnp.ndarray, recur,
@@ -594,52 +618,66 @@ def kda_block(cfg: ModelConfig, p: dict, x: jnp.ndarray, recur,
     and returns the heads' outputs in float32."""
     B, T, _ = x.shape
     Hk, d = cfg.kda_num_heads, cfg.kda_head_dim
-    h = apply_norm(cfg, x, p["input_norm"])
-    qkv = jnp.concatenate([_linear(h, p["wq"]), _linear(h, p["wk"]),
-                           _linear(h, p["wv"])], axis=-1)
-    # log-decay per channel (<= 0) and step size per head in (0, 2): the 2
-    # is what lets a head's transition have negative eigenvalues
-    f = _linear(_linear(h, p["f_a"]), p["f_b"]).astype(jnp.float32)
-    g = -jnp.exp(p["A_log"].astype(jnp.float32))[:, None] * jax.nn.softplus(
-        (f + p["dt_bias"].astype(jnp.float32)).reshape(B, T, Hk, d))
-    beta = 2.0 * jax.nn.sigmoid(_linear(h, p["w_beta"]).astype(jnp.float32))
-    o, rec = recur(p["conv"]["weight"], qkv, g, beta, rec_l)
-    o = rms_norm(o, p["o_norm"]["weight"], cfg.norm_eps).astype(x.dtype)
-    gate = jax.nn.sigmoid(_linear(_linear(h, p["g_a"]), p["g_b"]))
-    x = x + _linear(o.reshape(B, T, Hk * d) * gate, p["wo"])
-    return x + _mlp(cfg, apply_norm(cfg, x, p["post_norm"]), p), rec
+    with jax.named_scope(parts.NORM):
+        h = apply_norm(cfg, x, p["input_norm"])
+    with jax.named_scope(parts.ATTN_PROJ):
+        qkv = jnp.concatenate([_linear(h, p["wq"]), _linear(h, p["wk"]),
+                               _linear(h, p["wv"])], axis=-1)
+        # log-decay per channel (<= 0) and step size per head in (0, 2): the
+        # 2 is what lets a head's transition have negative eigenvalues
+        f = _linear(_linear(h, p["f_a"]), p["f_b"]).astype(jnp.float32)
+        g = -jnp.exp(p["A_log"].astype(jnp.float32))[:, None] \
+            * jax.nn.softplus(
+                (f + p["dt_bias"].astype(jnp.float32)).reshape(B, T, Hk, d))
+        beta = 2.0 * jax.nn.sigmoid(
+            _linear(h, p["w_beta"]).astype(jnp.float32))
+    with jax.named_scope(parts.RECUR):
+        o, rec = recur(p["conv"]["weight"], qkv, g, beta, rec_l)
+        o = rms_norm(o, p["o_norm"]["weight"], cfg.norm_eps).astype(x.dtype)
+    with jax.named_scope(parts.ATTN_PROJ):
+        gate = jax.nn.sigmoid(_linear(_linear(h, p["g_a"]), p["g_b"]))
+    with jax.named_scope(parts.ATTN_OUT):
+        x = _residual(cfg, x, _linear(o.reshape(B, T, Hk * d) * gate,
+                                      p["wo"]))
+    with jax.named_scope(parts.NORM):
+        h2 = apply_norm(cfg, x, p["post_norm"])
+    return _add_ffn(cfg, x, h2, p), rec
 
 
 def _embed_inputs(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
                   positions: jnp.ndarray):
     """Shared forward preamble: token embedding + position tables."""
-    emb = params["embed"]
-    if "scale" in emb:
-        # int8 table (models/quant.py): dequantize the gathered rows with
-        # their per-vocab-row scales; activations take the model compute
-        # dtype, which the (never-quantized) norm weights carry.
-        dt = params["final_norm"]["weight"].dtype
-        x = (emb["weight"][tokens].astype(jnp.float32)
-             * emb["scale"][tokens][..., None]).astype(dt)
-    else:
-        x = emb["weight"][tokens]
-    if cfg.embed_scale:
-        # Gemma scales embeddings by sqrt(H); HF casts the scalar to the
-        # embedding dtype BEFORE multiplying — match that for logit parity.
-        x = x * jnp.asarray(cfg.hidden_size ** 0.5, x.dtype)
-    if cfg.scale_emb != 1.0:    # MiniCPM's muP
-        x = x * jnp.asarray(cfg.scale_emb, x.dtype)
-    if cfg.pos_embed == "learned":
-        # OPT: absolute learned positions, +2 offset; no rotary tables needed
-        # (dummy cos/sin keep the scan signature uniform).
-        x = x + params["pos_embed"]["weight"][positions + 2]
-        cos = sin = jnp.zeros(positions.shape + (0,), jnp.float32)
-    else:
-        # the tables are the rotating layers': the attention layers', or the
-        # Lightning layers' where the attention layers have no positions
-        rotary_dim = int(cfg.head_dim * cfg.rotary_pct) if cfg.attn_use_rope \
-            else cfg.lightning_head_dim
-        cos, sin = rope_cos_sin(positions, rotary_dim, cfg.rope_theta, cfg)
+    with jax.named_scope(parts.EMBED):
+        emb = params["embed"]
+        if "scale" in emb:
+            # int8 table (models/quant.py): dequantize the gathered rows with
+            # their per-vocab-row scales; activations take the model compute
+            # dtype, which the (never-quantized) norm weights carry.
+            dt = params["final_norm"]["weight"].dtype
+            x = (emb["weight"][tokens].astype(jnp.float32)
+                 * emb["scale"][tokens][..., None]).astype(dt)
+        else:
+            x = emb["weight"][tokens]
+        if cfg.embed_scale:
+            # Gemma scales embeddings by sqrt(H); HF casts the scalar to the
+            # embedding dtype BEFORE multiplying — match that for logit
+            # parity.
+            x = x * jnp.asarray(cfg.hidden_size ** 0.5, x.dtype)
+        if cfg.scale_emb != 1.0:    # MiniCPM's muP
+            x = x * jnp.asarray(cfg.scale_emb, x.dtype)
+        if cfg.pos_embed == "learned":
+            # OPT: absolute learned positions, +2 offset; no rotary tables
+            # needed (dummy cos/sin keep the scan signature uniform).
+            x = x + params["pos_embed"]["weight"][positions + 2]
+            cos = sin = jnp.zeros(positions.shape + (0,), jnp.float32)
+        else:
+            # the tables are the rotating layers': the attention layers',
+            # or the Lightning layers' where the attention layers have no
+            # positions
+            rotary_dim = int(cfg.head_dim * cfg.rotary_pct) \
+                if cfg.attn_use_rope else cfg.lightning_head_dim
+            cos, sin = rope_cos_sin(positions, rotary_dim, cfg.rope_theta,
+                                    cfg)
     return x, cos, sin
 
 
@@ -651,21 +689,23 @@ def _final_logits(params: dict, cfg: ModelConfig, x: jnp.ndarray,
     every-row ``[B, T, V]`` holds there. The head carries no adapter
     (models/lora.py targets the attention and MLP projections), so the
     gathered rows need no per-token index."""
-    if head_rows is not None:
-        x = x.reshape(-1, x.shape[-1])[head_rows]
-    x = apply_norm(cfg, x, params["final_norm"])
-    if cfg.logit_scale != 1.0:      # muP: logits over hidden / dim_model_base
-        x = x * jnp.asarray(cfg.logit_scale, x.dtype)
-    if cfg.tie_embeddings:
-        emb = params["embed"]
-        if "scale" in emb:
-            # the tied-logits matmul re-reads the whole table every decode
-            # step — the int8 stream is where the embed quantization pays;
-            # per-vocab-row scales become per-logit-column scales here
-            return ((x @ emb["weight"].T.astype(x.dtype))
-                    * emb["scale"]).astype(x.dtype)
-        return x @ emb["weight"].T
-    return _linear(x, params["lm_head"])
+    with jax.named_scope(parts.HEAD):
+        if head_rows is not None:
+            x = x.reshape(-1, x.shape[-1])[head_rows]
+        x = apply_norm(cfg, x, params["final_norm"])
+        if cfg.logit_scale != 1.0:  # muP: logits over hidden / dim_model_base
+            x = x * jnp.asarray(cfg.logit_scale, x.dtype)
+        if cfg.tie_embeddings:
+            emb = params["embed"]
+            if "scale" in emb:
+                # the tied-logits matmul re-reads the whole table every
+                # decode step — the int8 stream is where the embed
+                # quantization pays; per-vocab-row scales become
+                # per-logit-column scales here
+                return ((x @ emb["weight"].T.astype(x.dtype))
+                        * emb["scale"]).astype(x.dtype)
+            return x @ emb["weight"].T
+        return _linear(x, params["lm_head"])
 
 
 def model_forward(

@@ -22,8 +22,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from aws_k8s_ansible_provisioner_tpu.models import parts
 from aws_k8s_ansible_provisioner_tpu.ops import kv_pool as kvp
-
 
 
 def decode_attend(q: jnp.ndarray, cache_k: jnp.ndarray, cache_v: jnp.ndarray,
@@ -179,8 +179,11 @@ def make_decode_attend_carry_paged(lengths: jnp.ndarray, table: jnp.ndarray,
     resolved = resolve_impl(impl)
 
     dp = mesh.shape.get("dp", 1) if mesh is not None else 1
-    by_len = _length_order(lengths, table, dp, bblock) \
-        if resolved == "pallas" else ()
+    # taken outside the layers' ``attend`` (whose scope models/layers.py
+    # opens), so it names itself
+    with jax.named_scope(parts.ATTN_CORE):
+        by_len = _length_order(lengths, table, dp, bblock) \
+            if resolved == "pallas" else ()
 
     def _write_attend_paged(q, pool, knew, vnew, lens, tab, layer, *by_len):
         from aws_k8s_ansible_provisioner_tpu.ops import pallas_attention

@@ -37,6 +37,8 @@ import jax
 import jax.numpy as jnp
 
 from aws_k8s_ansible_provisioner_tpu.config import ModelConfig
+from aws_k8s_ansible_provisioner_tpu.models import parts
+
 
 def route(cfg: ModelConfig, x: jnp.ndarray, router_kernel: jnp.ndarray,
           router_bias=None):
@@ -133,24 +135,27 @@ def _sorted_groups(cfg: ModelConfig, x: jnp.ndarray, p: dict, live=None):
     """
     N, H = x.shape
     E, k = cfg.num_experts, cfg.num_experts_per_tok
-    w, idx = _route(cfg, x, p)
-    flat_e, group_sizes = _assignments(cfg, idx, live)         # [N*k], [E]
-    order = jnp.argsort(flat_e)                                # stable
-    tok = order // k                                           # source token
-    xs = x[tok]                                                # [N*k, H]
-    sorted_e = flat_e[order]
-    ys = _expert_ffn_ragged(xs, p, group_sizes,
-                            expert_of_row=jnp.minimum(sorted_e, E - 1))
-    ys = ys * w.reshape(-1)[order][:, None]
-    if live is not None or cfg.expert_share:
-        # rows of no group hold whatever the kernel left
-        ys = jnp.where((sorted_e < E)[:, None], ys, 0)
-    # every token owns exactly k sorted rows: bring them home with the
-    # inverse permutation and sum over k (a gather; the scatter-add this
-    # replaces took 13 of 96 ms at 2,072 rows on the chip, PERF.md PR 26)
-    inv = jnp.zeros_like(order).at[order].set(
-        jnp.arange(N * k, dtype=order.dtype))
-    return ys.astype(x.dtype)[inv].reshape(N, k, H).sum(axis=1), group_sizes
+    with jax.named_scope(parts.ROUTER):
+        w, idx = _route(cfg, x, p)
+        flat_e, group_sizes = _assignments(cfg, idx, live)     # [N*k], [E]
+        order = jnp.argsort(flat_e)                            # stable
+        tok = order // k                                       # source token
+    with jax.named_scope(parts.EXPERTS):
+        xs = x[tok]                                            # [N*k, H]
+        sorted_e = flat_e[order]
+        ys = _expert_ffn_ragged(xs, p, group_sizes,
+                                expert_of_row=jnp.minimum(sorted_e, E - 1))
+        ys = ys * w.reshape(-1)[order][:, None]
+        if live is not None or cfg.expert_share:
+            # rows of no group hold whatever the kernel left
+            ys = jnp.where((sorted_e < E)[:, None], ys, 0)
+        # every token owns exactly k sorted rows: bring them home with the
+        # inverse permutation and sum over k (a gather; the scatter-add this
+        # replaces took 13 of 96 ms at 2,072 rows on the chip, PERF.md PR 26)
+        inv = jnp.zeros_like(order).at[order].set(
+            jnp.arange(N * k, dtype=order.dtype))
+        return ys.astype(x.dtype)[inv].reshape(N, k, H).sum(axis=1), \
+            group_sizes
 
 
 def gshard_capacity(cfg: ModelConfig, n_tokens: int) -> int:
@@ -172,18 +177,19 @@ def moe_mlp_gshard(cfg: ModelConfig, x: jnp.ndarray, p: dict) -> jnp.ndarray:
     N, H = x.shape
     E, k = cfg.num_experts, cfg.num_experts_per_tok
     C = gshard_capacity(cfg, N)
-    w, idx = _route(cfg, x, p)
-    # Queue position of each (token, choice) within its expert, in flat
-    # (token-major) arrival order; positions >= C overflow and drop.
-    onehot_e = jax.nn.one_hot(idx.reshape(-1), E, dtype=jnp.int32)  # [N*k, E]
-    pos = (jnp.cumsum(onehot_e, axis=0) - onehot_e)                 # [N*k, E]
-    pos = (pos * onehot_e).sum(-1).reshape(N, k)                    # [N, k]
-    keep = (pos < C).astype(x.dtype)
-    onehot_c = jax.nn.one_hot(pos, C, dtype=x.dtype)                # [N, k, C]
-    oe = onehot_e.reshape(N, k, E).astype(x.dtype)
-    combine = jnp.einsum("nk,nke,nkc->nec", w * keep, oe, onehot_c)
-    dispatch = jnp.einsum("nk,nke,nkc->nec", keep, oe, onehot_c)
-    xe = jnp.einsum("nec,nh->ech", dispatch, x)                     # [E, C, H]
+    with jax.named_scope(parts.ROUTER):
+        w, idx = _route(cfg, x, p)
+        # Queue position of each (token, choice) within its expert, in flat
+        # (token-major) arrival order; positions >= C overflow and drop.
+        onehot_e = jax.nn.one_hot(idx.reshape(-1), E,
+                                  dtype=jnp.int32)              # [N*k, E]
+        pos = (jnp.cumsum(onehot_e, axis=0) - onehot_e)         # [N*k, E]
+        pos = (pos * onehot_e).sum(-1).reshape(N, k)            # [N, k]
+        keep = (pos < C).astype(x.dtype)
+        onehot_c = jax.nn.one_hot(pos, C, dtype=x.dtype)        # [N, k, C]
+        oe = onehot_e.reshape(N, k, E).astype(x.dtype)
+        combine = jnp.einsum("nk,nke,nkc->nec", w * keep, oe, onehot_c)
+        dispatch = jnp.einsum("nk,nke,nkc->nec", keep, oe, onehot_c)
 
     def mm(spec, v, q):
         # int8 expert kernels: upcast fuses into the einsum load; the
@@ -193,10 +199,12 @@ def moe_mlp_gshard(cfg: ModelConfig, x: jnp.ndarray, p: dict) -> jnp.ndarray:
             return (out * q["scale"][:, None, :]).astype(v.dtype)
         return jnp.einsum(spec, v, q["kernel"])
 
-    g = mm("ech,ehi->eci", xe, p["w_gate"])
-    u = mm("ech,ehi->eci", xe, p["w_up"])
-    y = mm("eci,eih->ech", jax.nn.silu(g) * u, p["w_down"])         # [E, C, H]
-    return jnp.einsum("nec,ech->nh", combine, y).astype(x.dtype)
+    with jax.named_scope(parts.EXPERTS):
+        xe = jnp.einsum("nec,nh->ech", dispatch, x)             # [E, C, H]
+        g = mm("ech,ehi->eci", xe, p["w_gate"])
+        u = mm("ech,ehi->eci", xe, p["w_up"])
+        y = mm("eci,eih->ech", jax.nn.silu(g) * u, p["w_down"])  # [E, C, H]
+        return jnp.einsum("nec,ech->nh", combine, y).astype(x.dtype)
 
 
 def _every_expert(cfg: ModelConfig, x: jnp.ndarray, p: dict, live=None):
@@ -212,11 +220,12 @@ def _every_expert(cfg: ModelConfig, x: jnp.ndarray, p: dict, live=None):
     that latches weight tiles 28 (PERF.md, PR 26).
     x: [N, H] → ([N, H], group_sizes [E])."""
     E = cfg.num_experts
-    w, idx = _route(cfg, x, p)
-    flat_e, group_sizes = _assignments(cfg, idx, live)
-    # [N, k, E]: a dead row's id is E, which one_hot maps to all zeros
-    hot = jax.nn.one_hot(flat_e.reshape(idx.shape), E, dtype=x.dtype)
-    combine = jnp.einsum("nk,nke->ne", w, hot)                 # [N, E]
+    with jax.named_scope(parts.ROUTER):
+        w, idx = _route(cfg, x, p)
+        flat_e, group_sizes = _assignments(cfg, idx, live)
+        # [N, k, E]: a dead row's id is E, which one_hot maps to all zeros
+        hot = jax.nn.one_hot(flat_e.reshape(idx.shape), E, dtype=x.dtype)
+        combine = jnp.einsum("nk,nke->ne", w, hot)             # [N, E]
 
     def mm(spec, v, q):
         if "scale" in q:
@@ -224,10 +233,12 @@ def _every_expert(cfg: ModelConfig, x: jnp.ndarray, p: dict, live=None):
             return (out * q["scale"][:, None, :]).astype(v.dtype)
         return jnp.einsum(spec, v, q["kernel"])
 
-    g = mm("nh,ehi->eni", x, p["w_gate"])
-    u = mm("nh,ehi->eni", x, p["w_up"])
-    y = mm("eni,eih->enh", jax.nn.silu(g) * u, p["w_down"])    # [E, N, H]
-    return jnp.einsum("ne,enh->nh", combine, y).astype(x.dtype), group_sizes
+    with jax.named_scope(parts.EXPERTS):
+        g = mm("nh,ehi->eni", x, p["w_gate"])
+        u = mm("nh,ehi->eni", x, p["w_up"])
+        y = mm("eni,eih->enh", jax.nn.silu(g) * u, p["w_down"])  # [E, N, H]
+        return jnp.einsum("ne,enh->nh", combine, y).astype(x.dtype), \
+            group_sizes
 
 
 # -- what a step program tells the expert layer, and what it hears back ------
@@ -304,10 +315,11 @@ def moe_mlp(cfg: ModelConfig, x: jnp.ndarray, p: dict) -> jnp.ndarray:
     else:
         out, group_sizes = _sorted_groups(cfg, x, p, live)
     if ctx is not None:
-        stats = [(group_sizes > 0).sum(), group_sizes.max()]
-        if cfg.expert_share:
-            # (token, expert) pairs of live rows that landed on an expert
-            # held here (the host knows how many those rows chose)
-            stats.append(group_sizes.sum())
-        ctx["layer"] = jnp.stack(stats).astype(jnp.int32)
+        with jax.named_scope(parts.ROUTER):
+            stats = [(group_sizes > 0).sum(), group_sizes.max()]
+            if cfg.expert_share:
+                # (token, expert) pairs of live rows that landed on an
+                # expert held here (the host knows how many those rows chose)
+                stats.append(group_sizes.sum())
+            ctx["layer"] = jnp.stack(stats).astype(jnp.int32)
     return out

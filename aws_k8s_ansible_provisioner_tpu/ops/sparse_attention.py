@@ -40,6 +40,7 @@ dense gather (tests).
 from __future__ import annotations
 
 import contextlib
+import functools
 import threading
 from typing import Tuple
 
@@ -47,6 +48,7 @@ import jax
 import jax.numpy as jnp
 
 from aws_k8s_ansible_provisioner_tpu.config import ModelConfig
+from aws_k8s_ansible_provisioner_tpu.models import parts
 from aws_k8s_ansible_provisioner_tpu.ops import kv_pool as kvp
 from aws_k8s_ansible_provisioner_tpu.ops.attention import (decode_attend,
                                                            resolve_impl)
@@ -54,11 +56,24 @@ from aws_k8s_ansible_provisioner_tpu.ops.attention import (decode_attend,
 _HI = jax.lax.Precision.HIGHEST
 
 
+def _selecting(fn):
+    """``fn``'s operations carry the part ``select`` (models/parts.py): the
+    selection and the selector's cache, inside the ``attn.core`` of the
+    ``attend`` callback that calls them. A scope a call: tracing is
+    re-entrant and runs on more than one thread."""
+    @functools.wraps(fn)
+    def scoped(*args, **kwargs):
+        with jax.named_scope(parts.SELECT):
+            return fn(*args, **kwargs)
+    return scoped
+
+
 # ---------------------------------------------------------------------------
 # The selection
 # ---------------------------------------------------------------------------
 
 
+@_selecting
 def block_scores(cfg: ModelConfig, q: jnp.ndarray, runs: jnp.ndarray,
                  T: jnp.ndarray) -> jnp.ndarray:
     """Steps 1-3. q: [R, Hq, D] (as attention gets it: normed); runs:
@@ -88,6 +103,7 @@ def block_scores(cfg: ModelConfig, q: jnp.ndarray, runs: jnp.ndarray,
     return jnp.maximum(own, before)
 
 
+@_selecting
 def select_blocks(cfg: ModelConfig, scores: jnp.ndarray,
                   T: jnp.ndarray) -> jnp.ndarray:
     """Steps 4-5. scores: [R, Hkv, NB]; T: [R]. Returns the selection
@@ -112,6 +128,7 @@ def select_blocks(cfg: ModelConfig, scores: jnp.ndarray,
     return jnp.where(dense, valid, sel & valid)
 
 
+@_selecting
 def as_list(cfg: ModelConfig, sel: jnp.ndarray) -> Tuple[jnp.ndarray,
                                                          jnp.ndarray]:
     """The selection as ascending lists: (pages [R, Hkv, K] int32, count
@@ -122,6 +139,7 @@ def as_list(cfg: ModelConfig, sel: jnp.ndarray) -> Tuple[jnp.ndarray,
     return jnp.sort(idx, axis=-1)[..., :K], sel.sum(axis=-1).astype(jnp.int32)
 
 
+@_selecting
 def as_bits(sel: jnp.ndarray) -> jnp.ndarray:
     """The selection as int32 words: bit p % 32 of word p // 32 = page p."""
     R, Hkv, NB = sel.shape
@@ -156,6 +174,7 @@ def _attend_rows(q, kd, vd, limits, sel, ps: int):
                      ctx.reshape(R, Hq, D), 0).astype(q.dtype)
 
 
+@_selecting
 def count(sel: jnp.ndarray, T: jnp.ndarray, ps: int) -> jnp.ndarray:
     """[2] int32: the (row, KV head) pairs' live pages and selected pages —
     what the dispatch record sums (``sparse_pages_live/selected``)."""
@@ -207,6 +226,7 @@ def _pool(kv, kc, tally):
 # ---------------------------------------------------------------------------
 
 
+@_selecting
 def add_rows(cfg: ModelConfig, kc, layer, rows, table, knew, impl: str):
     """One new key a slot (decode rows). knew: [B, Hkv, D]; rows: [B]
     logical row (out of range: dropped); table: [B, max_pages]."""
@@ -231,6 +251,7 @@ def add_rows(cfg: ModelConfig, kc, layer, rows, table, knew, impl: str):
         kept + knew.astype(kc.dtype), mode="drop")
 
 
+@_selecting
 def add_span(cfg: ModelConfig, kc, layer, tables, start, k, n_valid):
     """Rows [start, start + n_valid) of N sequences, a page window at a time
     (kv_pool._write_span_by_page's windows: contiguous in the leaf's own
@@ -265,6 +286,7 @@ def add_span(cfg: ModelConfig, kc, layer, tables, start, k, n_valid):
                                 mode="drop")
 
 
+@_selecting
 def _runs_of(kc, layer, table):
     """One layer's run sums in logical order: [B, Hkv, M, D]."""
     g = jax.lax.dynamic_index_in_dim(kc, layer, 0, keepdims=False)[table]

@@ -127,18 +127,6 @@ class AutoscaleMetrics:
         self.actual_replicas = r.register(Gauge(
             "tpu_autoscale_actual_replicas",
             "Replicas currently serving (ready AND in the router pool)"))
-        self.standby_replicas = r.register(Gauge(
-            "tpu_autoscale_standby_replicas",
-            "Prewarmed ready replicas parked out of rotation (promoted "
-            "before any launch on scale-up)"))
-        self.launching_replicas = r.register(Gauge(
-            "tpu_autoscale_launching_replicas",
-            "Replicas spawned but not yet past /readyz (launch retries "
-            "waiting out their backoff are counted separately)"))
-        self.draining_replicas = r.register(Gauge(
-            "tpu_autoscale_draining_replicas",
-            "Replicas out of rotation finishing in-flight work before "
-            "reap (inflight==0)"))
         self.stuck_replicas = r.register(Gauge(
             "tpu_autoscale_stuck_replicas",
             "Draining replicas past drain_stuck_s with inflight still "
@@ -951,9 +939,6 @@ class Autoscaler:
             st = self.status()
             metrics.desired_replicas.set(float(st["desired"]))
             metrics.actual_replicas.set(float(st["actual"]))
-            metrics.standby_replicas.set(float(st["standby"]))
-            metrics.launching_replicas.set(float(st["launching"]))
-            metrics.draining_replicas.set(float(st["draining"]))
             metrics.stuck_replicas.set(float(st["stuck"]))
             metrics.scale_ups.set(float(st["scale_ups"]))
             metrics.scale_downs.set(float(st["scale_downs"]))

@@ -810,7 +810,6 @@ class Engine(EnginePrograms):
         sts = [a.stats() for a in self.allocators]
         self.metrics.kv_pages_total.set(sum(s["pages_total"] for s in sts))
         self.metrics.kv_pages_in_use.set(sum(s["pages_live"] for s in sts))
-        self.metrics.kv_pages_free.set(sum(s["pages_free"] for s in sts))
         self.metrics.kv_pages_evictable.set(
             sum(s["pages_evictable"] for s in sts))
 

@@ -347,12 +347,10 @@ class EngineMetrics:
         self.kv_pages_in_use = r.register(Gauge(
             "tpu_serve_kv_pages_in_use",
             "KV pages currently referenced by live requests"))
-        # Free/evictable split (ISSUE 20 satellite): "pool full" and "pool
+        # The evictable share (ISSUE 20 satellite): "pool full" and "pool
         # full of reusable prefixes" are different capacity situations —
-        # evictable pages reclaim on demand but still serve prefix hits.
-        self.kv_pages_free = r.register(Gauge(
-            "tpu_serve_kv_pages_free",
-            "KV pages on the free list (content meaningless)"))
+        # evictable pages reclaim on demand but still serve prefix hits
+        # (free = total - in use - evictable: no family of its own).
         self.kv_pages_evictable = r.register(Gauge(
             "tpu_serve_kv_pages_evictable",
             "Refcount-zero KV pages retained for prefix reuse "
@@ -531,3 +529,50 @@ class CompileMetrics:
 
 
 compile_stages = CompileMetrics()
+
+
+class ParamMetrics:
+    """Process-wide: what each part of the SERVED parameter tree weighs
+    (models/parts.py: the closed set of part names the step programs'
+    operations carry into the device trace). Set once at engine start-up
+    from the tree after quantisation, LoRA attach and sharding, per chip
+    (``parts.param_weights``); a process serves one tree, the last engine
+    built wins. The one number a weight-stream roofline needs that neither
+    the trace nor a dispatch record holds — bytes ÷ HBM bandwidth is the
+    floor of the part's device time in a decode step, 2 x rows x elements
+    its matmul flops in a prefill — taken from the leaves that are served,
+    not from config arithmetic. Rendered by BOTH /metrics routes, as every
+    process-wide set is (tpulint R2); empty in a router-only process.
+    """
+
+    def __init__(self):
+        self.registry = Registry()
+        r = self.registry
+        self.bytes = r.register(Gauge(
+            "tpu_serve_param_bytes",
+            "Bytes one chip holds of the parameter leaves a part of the "
+            "model reads in one forward pass (kernels with their scales; a "
+            "tied embedding under head)", ("part",)))
+        self.elements = r.register(Gauge(
+            "tpu_serve_param_elements",
+            "Matmul elements (kernel leaves) one chip holds, by the part "
+            "of the model that multiplies by them", ("part",)))
+
+    def publish(self, weights: Dict[str, Tuple[int, int]]) -> None:
+        """Replace both families with ``{part: (bytes, elements)}``."""
+        for gauge, i in ((self.bytes, 0), (self.elements, 1)):
+            with gauge._lock:
+                gauge._values = {(("part", part),): float(w[i])
+                                 for part, w in weights.items()}
+
+    def by_part(self) -> Dict[str, Tuple[float, float]]:
+        """{part: (bytes, elements)} as published (benchmark readers,
+        tests); empty before an engine was built."""
+        with self.bytes._lock:
+            nbytes = {dict(k)["part"]: v
+                      for k, v in self.bytes._values.items()}
+        return {part: (b, self.elements.value(part=part))
+                for part, b in nbytes.items()}
+
+
+params_by_part = ParamMetrics()

@@ -35,6 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from aws_k8s_ansible_provisioner_tpu.config import ModelConfig
+from aws_k8s_ansible_provisioner_tpu.models import parts
 from aws_k8s_ansible_provisioner_tpu.models.layers import (
     lora_context,
     model_forward_carry,
@@ -402,8 +403,10 @@ def _aux(moe, picked):
     (row, KV head) pairs, summed over substeps and selecting layers), None
     for any other (no model has both)."""
     if picked is not None:
-        return picked.reshape(-1, 2).sum(axis=0)
-    return _moe_summary(moe)
+        with jax.named_scope(parts.SELECT):
+            return picked.reshape(-1, 2).sum(axis=0)
+    with jax.named_scope(parts.ROUTER):
+        return _moe_summary(moe)
 
 
 def _recur(cfg: ModelConfig, make, *args):
@@ -443,27 +446,29 @@ def prefill_step(cfg: ModelConfig, params, cache, tokens, true_len, rng,
             params, cfg, tokens, positions, cache, attend,
             _recur(cfg, _la.make_recur_span, slot, 0, true_len),
             head_rows=None if prompt_logprobs else rows)
-    last = _head(logits, rows)                               # [1, V]
-    last = _apply_prefill_repetition(last, tokens, true_len[None],
-                                     rep[None] if rep is not None else None)
-    if bias_ids is not None:
-        last = _apply_logit_bias(last, bias_ids[None], bias_vals[None])
-    if ban_ids is not None:
-        last = _mask_banned(last, ban_ids[None], ban_until[None],
-                            true_len[None])
-    last = _apply_allow(last, allow)
-    # Per-request seeded draw: key = (seed, position), so the stream is
-    # reproducible across restarts/preemption (OpenAI `seed`). ``rng`` is
-    # the legacy fallback when no seed rides the dispatch.
-    keys = per_slot_keys(seed[None], true_len[None]) if seed is not None \
-        else rng
-    token = sample(last, keys, temperature[None], top_k[None],
-                   top_p[None])[0]
-    out = [cache, token]
-    if logprobs:
-        out.append(_logprob_topk(last, token[None]))
-    if prompt_logprobs:
-        out.append(_prompt_logprobs(logits[:1], tokens))
+    with jax.named_scope(parts.SAMPLE):
+        last = _head(logits, rows)                           # [1, V]
+        last = _apply_prefill_repetition(
+            last, tokens, true_len[None],
+            rep[None] if rep is not None else None)
+        if bias_ids is not None:
+            last = _apply_logit_bias(last, bias_ids[None], bias_vals[None])
+        if ban_ids is not None:
+            last = _mask_banned(last, ban_ids[None], ban_until[None],
+                                true_len[None])
+        last = _apply_allow(last, allow)
+        # Per-request seeded draw: key = (seed, position), so the stream
+        # is reproducible across restarts/preemption (OpenAI `seed`).
+        # ``rng`` is the legacy fallback when no seed rides the dispatch.
+        keys = per_slot_keys(seed[None], true_len[None]) \
+            if seed is not None else rng
+        token = sample(last, keys, temperature[None], top_k[None],
+                       top_p[None])[0]
+        out = [cache, token]
+        if logprobs:
+            out.append(_logprob_topk(last, token[None]))
+        if prompt_logprobs:
+            out.append(_prompt_logprobs(logits[:1], tokens))
     return tuple(out)
 
 
@@ -499,20 +504,21 @@ def prefill_batch_step(cfg: ModelConfig, params, cache, tokens, true_lens,
             params, cfg, tokens, positions, cache, attend,
             _recur(cfg, _la.make_recur_batch, slots, true_lens),
             head_rows=None if prompt_logprobs else rows)
-    last = _head(logits, rows)                             # [N, V]
-    last = _apply_prefill_repetition(last, tokens, true_lens, reps)
-    if bias_ids is not None:
-        last = _apply_logit_bias(last, bias_ids, bias_vals)
-    if ban_ids is not None:
-        last = _mask_banned(last, ban_ids, ban_until, true_lens)
-    last = _apply_allow(last, allow)
-    keys = per_slot_keys(seeds, true_lens) if seeds is not None else rng
-    toks = sample(last, keys, temperature, top_k, top_p)
-    out = [cache, toks]
-    if logprobs:
-        out.append(_logprob_topk(last, toks))
-    if prompt_logprobs:
-        out.append(_prompt_logprobs(logits, tokens))
+    with jax.named_scope(parts.SAMPLE):
+        last = _head(logits, rows)                             # [N, V]
+        last = _apply_prefill_repetition(last, tokens, true_lens, reps)
+        if bias_ids is not None:
+            last = _apply_logit_bias(last, bias_ids, bias_vals)
+        if ban_ids is not None:
+            last = _mask_banned(last, ban_ids, ban_until, true_lens)
+        last = _apply_allow(last, allow)
+        keys = per_slot_keys(seeds, true_lens) if seeds is not None else rng
+        toks = sample(last, keys, temperature, top_k, top_p)
+        out = [cache, toks]
+        if logprobs:
+            out.append(_logprob_topk(last, toks))
+        if prompt_logprobs:
+            out.append(_prompt_logprobs(logits, tokens))
     return tuple(out)
 
 
@@ -546,30 +552,31 @@ def prefill_chunk_step(cfg: ModelConfig, params, cache, tokens, start,
             params, cfg, tokens, positions, cache, attend,
             _recur(cfg, _la.make_recur_span, slot, start, chunk_len),
             head_rows=(chunk_len - 1)[None])
-    last = logits                       # [1, V]: the chunk's last valid row
-    if rep is not None and rep_seen is not None:
-        # chunks only carry a slice of the prompt: the seen-set over the
-        # WHOLE context comes precomputed from the host ([V] bool)
-        r = rep.astype(jnp.float32)
-        lf = last.astype(jnp.float32)
-        last = jnp.where(rep_seen[None],
-                         jnp.where(lf > 0, lf / r, lf * r), lf)
-    if bias_ids is not None:
-        last = _apply_logit_bias(last, bias_ids[None], bias_vals[None])
-    if ban_ids is not None:
-        last = _mask_banned(last, ban_ids[None], ban_until[None],
-                            (start + chunk_len)[None])
-    last = _apply_allow(last, allow)
-    # ctr = start + chunk_len = the full context length at the FINAL chunk
-    # (the only one whose sample survives) — matching what decode/prefill
-    # would use for the same position, so seeded streams are chunking-layout
-    # independent.
-    keys = per_slot_keys(seed[None], (start + chunk_len)[None]) \
-        if seed is not None else rng
-    token = sample(last, keys, temperature[None], top_k[None],
-                   top_p[None])[0]
-    if logprobs:
-        return cache, token, _logprob_topk(last, token[None])
+    with jax.named_scope(parts.SAMPLE):
+        last = logits                   # [1, V]: the chunk's last valid row
+        if rep is not None and rep_seen is not None:
+            # chunks only carry a slice of the prompt: the seen-set over the
+            # WHOLE context comes precomputed from the host ([V] bool)
+            r = rep.astype(jnp.float32)
+            lf = last.astype(jnp.float32)
+            last = jnp.where(rep_seen[None],
+                             jnp.where(lf > 0, lf / r, lf * r), lf)
+        if bias_ids is not None:
+            last = _apply_logit_bias(last, bias_ids[None], bias_vals[None])
+        if ban_ids is not None:
+            last = _mask_banned(last, ban_ids[None], ban_until[None],
+                                (start + chunk_len)[None])
+        last = _apply_allow(last, allow)
+        # ctr = start + chunk_len = the full context length at the FINAL
+        # chunk (the only one whose sample survives) — matching what
+        # decode/prefill would use for the same position, so seeded streams
+        # are chunking-layout independent.
+        keys = per_slot_keys(seed[None], (start + chunk_len)[None]) \
+            if seed is not None else rng
+        token = sample(last, keys, temperature[None], top_k[None],
+                       top_p[None])[0]
+        if logprobs:
+            return cache, token, _logprob_topk(last, token[None])
     return cache, token
 
 
@@ -630,40 +637,44 @@ def decode_steps(cfg: ModelConfig, n_steps: int, params, cache, tokens,
             logits, cache = model_forward_carry(
                 params, cfg, tok[:, None], positions, cache, attend,
                 _recur(cfg, _la.make_recur_decode, live))
-        step_logits = logits[:, 0, :]
-        if penalties:
-            # presence/frequency/repetition over the [B, V] generated-token
-            # counts that ride the carry (updated per sampled token, so a
-            # mid-horizon repeat is penalized immediately, not at the next
-            # dispatch); repetition additionally covers the prompt mask
-            step_logits = apply_penalties(step_logits, cnts, presence,
-                                          frequency, repetition, prompt_mask)
-        # OpenAI logit_bias: additive on logits before every sampling
-        # decision, then min_tokens stop suppression (mask wins: a +100 bias
-        # on eos must not resurrect a banned stop token). The ban evaluates
-        # PER SUBSTEP (lens rides the carry), so it can expire mid-horizon
-        # exactly when vLLM's would.
-        step_logits = _apply_logit_bias(step_logits, bias_ids, bias_vals)
-        step_logits = _mask_banned(step_logits, ban_ids, ban_until, lens)
-        # Guided mask is computed for substep 0's state only: in mixed
-        # batches the host emits just that substep for guided slots and
-        # discards the rest (penalized guided slots force horizon 1 so the
-        # per-substep count updates above never cover discarded tokens —
-        # see _do_decode).
-        step_logits = _apply_allow(step_logits, allow)
-        # ctr = lens + 1 = the context length this draw extends TO: distinct
-        # from the prefill draw's ctr (= prompt length) and equal to what a
-        # preemption-resume prefill of the same position would use — the
-        # seed contract's cross-resume reproducibility hangs on this
-        # alignment (review r3).
-        keys = per_slot_keys(seeds, lens + 1) if seeds is not None else rng_i
-        nxt = sample(step_logits, keys, temperature, top_k, top_p)
-        if penalties:
-            cnts = cnts.at[jnp.arange(cnts.shape[0]), nxt].add(1)
-        if logprobs:
-            nxt_out = (nxt, _logprob_topk(step_logits, nxt))
-        else:
-            nxt_out = nxt
+        with jax.named_scope(parts.SAMPLE):
+            step_logits = logits[:, 0, :]
+            if penalties:
+                # presence/frequency/repetition over the [B, V]
+                # generated-token counts that ride the carry (updated per
+                # sampled token, so a mid-horizon repeat is penalized
+                # immediately, not at the next dispatch); repetition
+                # additionally covers the prompt mask
+                step_logits = apply_penalties(
+                    step_logits, cnts, presence, frequency, repetition,
+                    prompt_mask)
+            # OpenAI logit_bias: additive on logits before every sampling
+            # decision, then min_tokens stop suppression (mask wins: a +100
+            # bias on eos must not resurrect a banned stop token). The ban
+            # evaluates PER SUBSTEP (lens rides the carry), so it can expire
+            # mid-horizon exactly when vLLM's would.
+            step_logits = _apply_logit_bias(step_logits, bias_ids, bias_vals)
+            step_logits = _mask_banned(step_logits, ban_ids, ban_until, lens)
+            # Guided mask is computed for substep 0's state only: in mixed
+            # batches the host emits just that substep for guided slots and
+            # discards the rest (penalized guided slots force horizon 1 so
+            # the per-substep count updates above never cover discarded
+            # tokens — see _do_decode).
+            step_logits = _apply_allow(step_logits, allow)
+            # ctr = lens + 1 = the context length this draw extends TO:
+            # distinct from the prefill draw's ctr (= prompt length) and
+            # equal to what a preemption-resume prefill of the same position
+            # would use — the seed contract's cross-resume reproducibility
+            # hangs on this alignment (review r3).
+            keys = per_slot_keys(seeds, lens + 1) if seeds is not None \
+                else rng_i
+            nxt = sample(step_logits, keys, temperature, top_k, top_p)
+            if penalties:
+                cnts = cnts.at[jnp.arange(cnts.shape[0]), nxt].add(1)
+            if logprobs:
+                nxt_out = (nxt, _logprob_topk(step_logits, nxt))
+            else:
+                nxt_out = nxt
         return (cache, cnts, nxt, lens + 1), (nxt_out, routing["stats"],
                                               picked["pages"])
 
@@ -789,44 +800,46 @@ def mixed_step(cfg: ModelConfig, params, cache, tokens, lengths, ptokens,
             params, cfg, packed, positions, cache, attend, recur,
             head_rows=jnp.concatenate(
                 [jnp.arange(B, dtype=jnp.int32), (B + plen - 1)[None]]))
-    # -- decode rows: the decode_steps substep body, verbatim order --------
-    dec_logits = logits[:B]
-    if penalties:
-        dec_logits = apply_penalties(dec_logits, counts, presence, frequency,
-                                     repetition, prompt_mask)
-    dec_logits = _apply_logit_bias(dec_logits, bias_ids, bias_vals)
-    dec_logits = _mask_banned(dec_logits, ban_ids, ban_until, lengths)
-    dec_logits = _apply_allow(dec_logits, allow)
-    keys = per_slot_keys(seeds, lengths + 1) if seeds is not None else rng
-    nxt = sample(dec_logits, keys, temperature, top_k, top_p)
-    if penalties:
-        # pslot's lane counts a garbage sample; _activate's count-row
-        # reset/restore at the final chunk wipes it (same policy as its
-        # stale-occupant rows)
-        counts = counts.at[jnp.arange(counts.shape[0]), nxt].add(1)
-    # -- chunk row: the prefill_chunk_step tail, verbatim order ------------
-    plast = logits[B:]                                            # [1, V]
-    if prep is not None and prep_seen is not None:
-        r = prep.astype(jnp.float32)
-        lf = plast.astype(jnp.float32)
-        plast = jnp.where(prep_seen[None],
-                          jnp.where(lf > 0, lf / r, lf * r), lf)
-    plast = _apply_logit_bias(plast, bias_ids[pslot][None],
-                              bias_vals[pslot][None])
-    plast = _mask_banned(plast, ban_ids[pslot][None], ban_until[pslot][None],
-                         (pstart + plen)[None])
-    plast = _apply_allow(plast, pallow)
-    pkeys = per_slot_keys(pseed[None], (pstart + plen)[None]) \
-        if pseed is not None else rng
-    ptok = sample(plast, pkeys, ptemp[None], ptop_k[None], ptop_p[None])
-    # -- regenerated carry: pslot's lanes become the chunk frontier --------
-    tok_out = jnp.where(is_p, ptok[0], nxt)
-    lens_out = jnp.where(is_p, pstart + plen, lengths + 1)
-    if counts is None:
-        counts = jnp.zeros((B, 1), jnp.int32)  # unused dummy (decode_steps)
-    out = (nxt[None], tuple(a[None] for a in _logprob_topk(dec_logits, nxt))) \
-        if logprobs else nxt[None]
-    pout = (ptok, _logprob_topk(plast, ptok)) if chunk_logprobs else ptok
+    with jax.named_scope(parts.SAMPLE):
+        # -- decode rows: the decode_steps substep body, verbatim order ----
+        dec_logits = logits[:B]
+        if penalties:
+            dec_logits = apply_penalties(dec_logits, counts, presence,
+                                         frequency, repetition, prompt_mask)
+        dec_logits = _apply_logit_bias(dec_logits, bias_ids, bias_vals)
+        dec_logits = _mask_banned(dec_logits, ban_ids, ban_until, lengths)
+        dec_logits = _apply_allow(dec_logits, allow)
+        keys = per_slot_keys(seeds, lengths + 1) if seeds is not None else rng
+        nxt = sample(dec_logits, keys, temperature, top_k, top_p)
+        if penalties:
+            # pslot's lane counts a garbage sample; _activate's count-row
+            # reset/restore at the final chunk wipes it (same policy as its
+            # stale-occupant rows)
+            counts = counts.at[jnp.arange(counts.shape[0]), nxt].add(1)
+        # -- chunk row: the prefill_chunk_step tail, verbatim order --------
+        plast = logits[B:]                                        # [1, V]
+        if prep is not None and prep_seen is not None:
+            r = prep.astype(jnp.float32)
+            lf = plast.astype(jnp.float32)
+            plast = jnp.where(prep_seen[None],
+                              jnp.where(lf > 0, lf / r, lf * r), lf)
+        plast = _apply_logit_bias(plast, bias_ids[pslot][None],
+                                  bias_vals[pslot][None])
+        plast = _mask_banned(plast, ban_ids[pslot][None],
+                             ban_until[pslot][None], (pstart + plen)[None])
+        plast = _apply_allow(plast, pallow)
+        pkeys = per_slot_keys(pseed[None], (pstart + plen)[None]) \
+            if pseed is not None else rng
+        ptok = sample(plast, pkeys, ptemp[None], ptop_k[None], ptop_p[None])
+        # -- regenerated carry: pslot's lanes become the chunk frontier ----
+        tok_out = jnp.where(is_p, ptok[0], nxt)
+        lens_out = jnp.where(is_p, pstart + plen, lengths + 1)
+        if counts is None:
+            counts = jnp.zeros((B, 1), jnp.int32)  # unused dummy
+        out = (nxt[None],
+               tuple(a[None] for a in _logprob_topk(dec_logits, nxt))) \
+            if logprobs else nxt[None]
+        pout = (ptok, _logprob_topk(plast, ptok)) if chunk_logprobs else ptok
     return cache, counts, out, pout, tok_out, lens_out, \
         _aux(routing["stats"], picked["pages"])
 
@@ -863,21 +876,22 @@ def spec_decode_step(cfg: ModelConfig, R: int, params, cache, tokens,
     with lora_context(lora_idx):
         logits, cache = model_forward_carry(params, cfg, tokens, positions,
                                             cache, attend)
-    preds = jnp.argmax(logits, axis=-1).astype(jnp.int32)      # [B, R]
-    drafts = tokens[:, 1:]                                     # [B, R-1]
-    match = (drafts == preds[:, :-1]).astype(jnp.int32)
-    m = jnp.cumprod(match, axis=-1).sum(axis=-1)               # [B]
-    greedy = temperature <= 0.0
-    m = jnp.where(greedy, m, 0)
-    # same ctr convention as decode_steps: this draw extends the context to
-    # lengths + 1
-    keys = per_slot_keys(seeds, lengths + 1) if seeds is not None else rng
-    sampled0 = sample(logits[:, 0], keys, temperature, top_k, top_p)
-    correction = jnp.where(greedy, preds[jnp.arange(B), m], sampled0)
-    pos = jnp.arange(R - 1, dtype=jnp.int32)[None, :]
-    out = jnp.where(pos < m[:, None], drafts, 0)
-    out = jnp.concatenate([out, jnp.zeros((B, 1), jnp.int32)], axis=1)
-    out = out.at[jnp.arange(B), m].set(correction)
+    with jax.named_scope(parts.SAMPLE):
+        preds = jnp.argmax(logits, axis=-1).astype(jnp.int32)      # [B, R]
+        drafts = tokens[:, 1:]                                     # [B, R-1]
+        match = (drafts == preds[:, :-1]).astype(jnp.int32)
+        m = jnp.cumprod(match, axis=-1).sum(axis=-1)               # [B]
+        greedy = temperature <= 0.0
+        m = jnp.where(greedy, m, 0)
+        # same ctr convention as decode_steps: this draw extends the context
+        # to lengths + 1
+        keys = per_slot_keys(seeds, lengths + 1) if seeds is not None else rng
+        sampled0 = sample(logits[:, 0], keys, temperature, top_k, top_p)
+        correction = jnp.where(greedy, preds[jnp.arange(B), m], sampled0)
+        pos = jnp.arange(R - 1, dtype=jnp.int32)[None, :]
+        out = jnp.where(pos < m[:, None], drafts, 0)
+        out = jnp.concatenate([out, jnp.zeros((B, 1), jnp.int32)], axis=1)
+        out = out.at[jnp.arange(B), m].set(correction)
     return cache, out, m + 1
 
 
@@ -1163,6 +1177,20 @@ class EnginePrograms:
             stacked = _lora.stack_adapters(loaded, cfg.num_layers, dtype)
             self.params = params = _lora.attach(params, stacked)
             self.lora_names = [name for name, _ in items]
+        # What each part of the model weighs in the tree as it is served
+        # (models/parts.py; per chip): tpu_serve_param_bytes{part} and
+        # tpu_serve_param_elements{part}, and the start-up log
+        self.param_weights = parts.param_weights(params, cfg)
+        _metrics.params_by_part.publish(self.param_weights)
+        import logging
+
+        logging.getLogger(__name__).info(
+            "params: %.3f GiB a chip — %s",
+            sum(b for b, _ in self.param_weights.values()) / 2**30,
+            ", ".join(f"{part} {b / 2**30:.3f} GiB ({n / 1e6:.1f} M "
+                      f"matmul elements)" if n else
+                      f"{part} {b / 2**30:.3f} GiB"
+                      for part, (b, n) in self.param_weights.items()))
         # Speculation needs no mesh gate: every tp shard executes the
         # identical token stream, so the data-dependent accept length is
         # shard-invariant, and under dp accept lengths are per-slot host

@@ -873,7 +873,8 @@ class RouterHandler(BaseHTTPRequestHandler):
                     + capacity.metrics.registry.render(om)
                     + autoscaler.metrics.registry.render(om)
                     + metrics.pipeline.registry.render(om)
-                    + metrics.compile_stages.registry.render(om))
+                    + metrics.compile_stages.registry.render(om)
+                    + metrics.params_by_part.registry.render(om))
             if om:
                 text += "# EOF\n"
                 ctype = ("application/openmetrics-text; version=1.0.0; "

@@ -351,6 +351,7 @@ class Handler(BaseHTTPRequestHandler):
                     + autoscaler.metrics.registry.render(om)
                     + metrics.pipeline.registry.render(om)
                     + metrics.compile_stages.registry.render(om)
+                    + metrics.params_by_part.registry.render(om)
                     + render_engine_chips())
             if om:
                 text += "# EOF\n"
@@ -444,12 +445,11 @@ class Handler(BaseHTTPRequestHandler):
                     round(eng.metrics.tokens_per_second.value(), 2),
                 "kv_pages_total": int(eng.metrics.kv_pages_total.value()),
                 "kv_pages_in_use": int(eng.metrics.kv_pages_in_use.value()),
-                # Free/evictable split + tier-2 ledger (ISSUE 20): "pool
-                # full" vs "pool full of reusable prefixes" are different
-                # capacity situations, and the tier split says where prefix
-                # hits are actually being served from (hbm share / host
-                # restore / miss) without a /metrics scrape+parse.
-                "kv_pages_free": int(eng.metrics.kv_pages_free.value()),
+                # Evictable share + tier-2 ledger (ISSUE 20): "pool full" vs
+                # "pool full of reusable prefixes" are different capacity
+                # situations, and the tier split says where prefix hits are
+                # actually being served from (hbm share / host restore /
+                # miss) without a /metrics scrape+parse.
                 "kv_pages_evictable":
                     int(eng.metrics.kv_pages_evictable.value()),
                 "prefix_tier_hits": {

@@ -496,3 +496,46 @@ def test_selector_row_add_and_lightning_update_compile_for_v5e(chip):
         sds((6, 1, S_B, H, d, d), f32), sds((), i32), row, row, row, row,
         sds((S_B, H), f32))
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("program", ["decode_fused_h8", f"mixed_c{CHUNK}"])
+def test_part_scopes_leave_the_served_programs_as_they_were(chip,
+                                                            monkeypatch,
+                                                            program):
+    """The names of the model's parts (models/parts.py) are METADATA: the
+    0.6B's served ``decode_steps`` and ``mixed_step`` (int8, 32 slots, block
+    8) compile to the same number of instructions and the same temporaries
+    with ``jax.named_scope`` answering as it does and with it made a no-op —
+    while only the first carries the names the trace's reader looks for."""
+    import contextlib
+    import re
+
+    from aws_k8s_ansible_provisioner_tpu.config import (MODEL_REGISTRY,
+                                                        ServingConfig)
+    from aws_k8s_ansible_provisioner_tpu.serving import aot
+
+    monkeypatch.setattr(pa, "supported", lambda: True)
+    cfg = MODEL_REGISTRY["Qwen/Qwen3-0.6B"]
+    plan = aot.ProgramPlan(cfg, ServingConfig(
+        model=cfg.name, max_decode_slots=32, max_cache_len=2048,
+        weights_dtype="int8", decode_bblock=8, kv_host_tier_bytes=0))
+    params, cache = aot._abstract_state(plan, None,
+                                        next(iter(chip.device_set)))
+
+    def compiled():
+        jax.clear_caches()
+        _, fn, args, kwargs = next(
+            p for p in aot.enumerate_programs(plan, None, params, cache,
+                                              bblock=8) if p[0] == program)
+        c = fn.lower(*args, **kwargs).compile()
+        text = c.as_text()
+        return (len(re.findall(r"^\s+(?:ROOT )?%\S+ = ", text, re.M)),
+                c.memory_analysis().temp_size_in_bytes,
+                len(re.findall(r'op_name="[^"]*/attn\.proj/', text)))
+
+    named = compiled()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare = compiled()
+    assert named[:2] == bare[:2]
+    assert named[2] > 0 and bare[2] == 0
