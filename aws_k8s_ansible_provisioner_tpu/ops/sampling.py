@@ -9,7 +9,10 @@ forwards to vLLM (``llm-d-test.yaml:61-78`` exercises the endpoint with
 - top-k/top-p run on a static ``MAX_TOPK`` candidate set from ``lax.top_k``
   (sorting the full 152k vocab per step would dominate decode time on the VPU);
   requests wanting a larger k degrade to MAX_TOPK, which is standard practice.
-- temperature == 0 selects greedy via ``jnp.where`` — no control flow.
+- temperature == 0 selects greedy. The candidate path (the top-k sort, the
+  nucleus, the draws) sits inside ONE ``lax.cond`` on "does any row draw": a
+  scalar read from the ``temperature`` operand on the device, so an
+  all-greedy batch pays the argmax alone and there is still one program.
 """
 
 from __future__ import annotations
@@ -97,46 +100,57 @@ def sample(
     keys from :func:`per_slot_keys` — the engine's seeded path, where each
     slot's draw is independent of the others' presence.
     """
-    B, V = logits.shape
-    logits = logits.astype(jnp.float32)
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
+    V = logits.shape[-1]
+    # the argmax reads the float32 cast inside its own reduction; the
+    # candidates cast again in their branch, so a greedy batch never writes
+    # a float32 copy of the logits just to hand it to a branch not taken
+    greedy = jnp.argmax(logits.astype(jnp.float32), axis=-1).astype(jnp.int32)
     cap = min(MAX_TOPK, V)  # tiny test vocabularies can be smaller than the cap
-    vals, idxs = jax.lax.top_k(logits, cap)                 # [B, K] desc
-    k_ranks = jnp.arange(cap)[None, :]
-    eff_k = jnp.where(top_k <= 0, cap, jnp.minimum(top_k, cap))
-    vals = jnp.where(k_ranks < eff_k[:, None], vals, -jnp.inf)
+    per_slot = jnp.ndim(rng) == 1 and jax.dtypes.issubdtype(
+        rng.dtype, jax.dtypes.prng_key)
 
-    # top-p (nucleus) over the candidate set: keep the smallest prefix whose
-    # probability mass reaches top_p; always keep the best candidate.
-    safe_t = jnp.maximum(temperature, 1e-6)[:, None]
-    probs = jax.nn.softmax(vals / safe_t, axis=-1)
-    cum = jnp.cumsum(probs, axis=-1)
-    keep = (cum - probs) < top_p[:, None]                    # prefix mass before me
-    keep = keep.at[:, 0].set(True)
-    vals = jnp.where(keep, vals, -jnp.inf)
+    def candidates():
+        vals, idxs = jax.lax.top_k(logits.astype(jnp.float32), cap)  # desc
+        k_ranks = jnp.arange(cap)[None, :]
+        eff_k = jnp.where(top_k <= 0, cap, jnp.minimum(top_k, cap))
+        vals = jnp.where(k_ranks < eff_k[:, None], vals, -jnp.inf)
 
-    scaled = vals / safe_t
-    if jnp.ndim(rng) == 1 and jax.dtypes.issubdtype(rng.dtype,
-                                                    jax.dtypes.prng_key):
-        # Seeded path: TOKEN-ID-KEYED Gumbel-max over the candidate set. The
-        # noise for token t is a pure function of (slot key, t), so masking
-        # one token (min_tokens stop suppression, logit_bias -100, grammar
-        # bans) never perturbs any other token's draw — a banned stream
-        # diverges from its unbanned twin only at positions where the banned
-        # token would have WON. jax.random.categorical's slot-positional
-        # gumbel lacks this: one masked token shifts every later candidate
-        # into a different slot and reshuffles the whole draw (the
-        # engine-level min_tokens determinism contract in test_engine).
-        # Cost: MAX_TOPK fold_in+uniform per slot — noise next to the
-        # forward pass.
-        def slot_draw(key, row_scaled, row_ids):
-            u = jax.vmap(lambda t: jax.random.uniform(
-                jax.random.fold_in(key, t), minval=1e-20))(row_ids)
-            return jnp.argmax(row_scaled - jnp.log(-jnp.log(u)))
+        # top-p (nucleus) over the candidate set: keep the smallest prefix
+        # whose probability mass reaches top_p; always keep the best candidate.
+        safe_t = jnp.maximum(temperature, 1e-6)[:, None]
+        probs = jax.nn.softmax(vals / safe_t, axis=-1)
+        cum = jnp.cumsum(probs, axis=-1)
+        keep = (cum - probs) < top_p[:, None]                # prefix mass before me
+        keep = keep.at[:, 0].set(True)
+        vals = jnp.where(keep, vals, -jnp.inf)
 
-        draw = jax.vmap(slot_draw)(rng, scaled, idxs)           # per-slot
-    else:
-        draw = jax.random.categorical(rng, scaled, axis=-1)     # [B] in [0,K)
-    sampled = jnp.take_along_axis(idxs, draw[:, None], axis=1)[:, 0].astype(jnp.int32)
-    return jnp.where(temperature <= 0.0, greedy, sampled)
+        scaled = vals / safe_t
+        if per_slot:
+            # Seeded path: TOKEN-ID-KEYED Gumbel-max over the candidate set.
+            # The noise for token t is a pure function of (slot key, t), so
+            # masking one token (min_tokens stop suppression, logit_bias -100,
+            # grammar bans) never perturbs any other token's draw — a banned
+            # stream diverges from its unbanned twin only at positions where
+            # the banned token would have WON. jax.random.categorical's
+            # slot-positional gumbel lacks this: one masked token shifts every
+            # later candidate into a different slot and reshuffles the whole
+            # draw (the engine-level min_tokens determinism contract in
+            # test_engine). Cost: MAX_TOPK fold_in+uniform per slot.
+            def slot_draw(key, row_scaled, row_ids):
+                u = jax.vmap(lambda t: jax.random.uniform(
+                    jax.random.fold_in(key, t), minval=1e-20))(row_ids)
+                return jnp.argmax(row_scaled - jnp.log(-jnp.log(u)))
+
+            draw = jax.vmap(slot_draw)(rng, scaled, idxs)       # per-slot
+        else:
+            draw = jax.random.categorical(rng, scaled, axis=-1)  # [B] in [0,K)
+        sampled = jnp.take_along_axis(
+            idxs, draw[:, None], axis=1)[:, 0].astype(jnp.int32)
+        return jnp.where(temperature <= 0.0, greedy, sampled)
+
+    # The sort over the vocabulary, the nucleus and the draws run only when a
+    # row asks for a draw. Every slot's temperature is read, live or not: the
+    # engine zeroes a slot's row where it gives the slot back (test_engine
+    # pins that), so an idle slot never holds the gate open.
+    return jax.lax.cond(jnp.any(temperature > 0.0), candidates,
+                        lambda: greedy)

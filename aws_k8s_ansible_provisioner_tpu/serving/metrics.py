@@ -290,6 +290,13 @@ class EngineMetrics:
             "hold, kind=\"walked\" the pages their blocks walk (block rows "
             "x the block's longest row, blocks cut in order of length)",
             ("kind",)))
+        self.sample_dispatches = r.register(Counter(
+            "tpu_serve_sample_dispatches_total",
+            "Dispatches by what their sampler ran, by step program: "
+            "path=\"greedy\" no sampled row had a temperature above zero "
+            "(idle slots read zero), so it took the argmax alone; "
+            "path=\"candidates\" a row drew, so every row's top-64 sort, "
+            "nucleus and draws ran", ("program", "path")))
         self.kda_rows = r.register(Counter(
             "tpu_serve_kda_rows_total",
             "Rows that advanced a KDA layer's recurrent state, per layer, by "

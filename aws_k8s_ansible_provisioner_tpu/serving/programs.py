@@ -1564,6 +1564,9 @@ class EnginePrograms:
         prompt_tokens, padded_tokens = the rows a prefill-type program's
         layers run over, head_rows = the rows its head runs over: one a
         sampled row, every row in a prompt_logprobs variant,
+        sample_rows = the rows it samples whose temperature is above zero,
+        idle slots included as the program reads them: at 0 its sampler
+        takes the argmax and skips the candidates (ops/sampling.sample),
         carry_steps = steps of an unfetched
         predecessor the device-side lengths are ahead of the mirrors by,
         write_pages = page windows of the pool that hold a row of a mixed
@@ -1659,6 +1662,10 @@ class EnginePrograms:
                                                kind="live")
             self.metrics.decode_attn_pages.inc(rec["attn_pages_walked"],
                                                kind="walked")
+        if "sample_rows" in rec:
+            self.metrics.sample_dispatches.inc(
+                program=rec["program"],
+                path="candidates" if rec["sample_rows"] else "greedy")
         _devmon.note(rec["kind"], device_s, batch=batch, tokens=tokens,
                      ctx_rows=ctx_rows, steps=steps, guided_rows=guided_rows)
         _flight.record("dispatch", None, **rec)
@@ -1815,6 +1822,7 @@ class EnginePrograms:
             "prefill_step", "prefill", bucket=bucket,
             prompt_tokens=len(ids), padded_tokens=bucket,
             head_rows=bucket if kw["prompt_logprobs"] else 1,
+            sample_rows=int(req.temperature > 0),
             **self._kda_rows(len(ids)))
         with _Dispatching(drec):
             out = prefill_step(self.cfg, self.params, self.cache, *args,
@@ -1909,6 +1917,7 @@ class EnginePrograms:
             bucket=t_bucket, prompt_tokens=n_prompt,
             padded_tokens=n_bucket * t_bucket,
             head_rows=n_bucket * t_bucket if want_plp else n_bucket,
+            sample_rows=int((temps > 0).sum()),
             **self._kda_rows(n_prompt))
         with _Dispatching(drec):
             out = prefill_batch_step(self.cfg, self.params, self.cache,
@@ -2055,7 +2064,8 @@ class EnginePrograms:
             drec = self._dispatch_open(
                 "prefill_chunk_step", "prefill_chunk", chunk_rows=C,
                 chunk_n=len(chunk), chunk_off=off, padded_tokens=C,
-                head_rows=1, **self._kda_rows(len(chunk)))
+                head_rows=1, sample_rows=int(req.temperature > 0),
+                **self._kda_rows(len(chunk)))
             with _Dispatching(drec):
                 out = prefill_chunk_step(self.cfg, self.params, self.cache,
                                          *args, **kw)
@@ -2230,6 +2240,8 @@ class EnginePrograms:
             write_pages=(off + len(chunk) - 1) // ps - off // ps + 1,
             padded_tokens=self.num_slots + st["C"],
             head_rows=self.num_slots + 1,
+            sample_rows=int((self.temps > 0).sum()
+                            + (req.temperature > 0)),
             carry_steps=prev["horizon"] if prev is not None else 0,
             **self._kda_rows(len(active) + len(chunk), len(active)))
         self._book_bubble(drec["t_enqueue"])
@@ -2361,7 +2373,8 @@ class EnginePrograms:
             seeds=jnp.asarray(self.seeds), mesh=self.mesh,
             lora_idx=self._lora_vec(), bblock=self.decode_bblock)
         drec = self._dispatch_open("spec_decode_step", "spec_decode", active,
-                                   rows=R)
+                                   rows=R,
+                                   sample_rows=int((self.temps > 0).sum()))
         t0 = drec["t_enqueue"]
         ctx_rows = float(np.mean(self.lengths[list(active)])) \
             if active else 0.0
@@ -2769,6 +2782,7 @@ class EnginePrograms:
         prev = self._inflight
         drec = self._dispatch_open(
             "decode_steps", "decode", active, horizon=horizon,
+            sample_rows=int((self.temps > 0).sum()),
             carry_steps=prev["horizon"] if prev is not None else 0,
             **self._kda_rows(horizon * len(active), len(active)))
         if not self.cfg.selects:    # (its rows walk a selection: the

@@ -392,6 +392,31 @@ def run_requests(srv: Server, model: str) -> dict:
         say(f"requests: a {n_long}-token prompt past the dense length "
             f"({cfg.sparse_dense_len}) beside the live stream ok "
             f"({time.monotonic() - t_mixed:.2f}s)")
+    # the one request of this script that DRAWS: until here every sampler
+    # call took the all-greedy side of its gate (ops/sampling.sample); this
+    # one, admitted under the live greedy stream, opens it for the batch
+    path = "tpu_serve_sample_dispatches_total"
+
+    def sampler_paths() -> dict:
+        """Dispatches by the side of the gate they took, over programs."""
+        status, raw = http_json(port, "GET", "/metrics")
+        check(status == 200, f"/metrics -> {status}")
+        return {side: sum(v for k, v in parse_metrics(raw.decode()).items()
+                          if k.startswith(path) and f'path="{side}"' in k)
+                for side in ("greedy", "candidates")}
+
+    took = sampler_paths()
+    check(took["greedy"] > 0 and took["candidates"] == 0,
+          f"{path}: greedy requests alone, yet it reads {took}")
+    drawn = {"model": model, "prompt": prompt_of(90, 31), "stream": True,
+             "max_tokens": 16, "temperature": 0.8, "top_p": 0.9, "seed": 7,
+             "ignore_eos": True}
+    rs = http_stream(port, "/v1/completions", drawn)
+    check(rs["status"] == 200 and rs["done"] and len(rs["token_ids"]) == 16,
+          f"seeded draw: status {rs['status']} tokens {len(rs['token_ids'])}")
+    check(not bg.get("t_done"),
+          "the background stream ended before the seeded draw did: it was "
+          "not admitted under a live batch")
     status, raw = http_json(
         port, "POST", "/v1/completions",
         {"model": model, "prompt": prompt_of(1100, 4), "max_tokens": 32,
@@ -418,6 +443,21 @@ def run_requests(srv: Server, model: str) -> dict:
     say(f"requests: a new 700-token prompt ({t_mixed - t0:.2f}s) and the "
         f"repeated 1100-token prompt beside a live stream of "
         f"{bg['seconds']:.2f}s ok")
+    # ... and served alone it draws the same stream: the seed contract
+    # (a draw is a function of seed and position, not of the batch) through
+    # the candidates' branch, on the chip. A recurrent model reads no prefix
+    # hit; any other restores the prompt's full page and prefills the rest.
+    ra = http_stream(port, "/v1/completions", drawn)
+    check(ra["status"] == 200 and ra["token_ids"] == rs["token_ids"],
+          f"seeded draw: alone {ra['token_ids']}, beside the live stream "
+          f"{rs['token_ids']}")
+    drew = sampler_paths()["candidates"]
+    check(drew >= 4, f"{path}: two seeded draws of 16 tokens, "
+                     f"{drew} dispatches on the candidates' path")
+    expected += 16 + 16
+    say(f"requests: a seeded draw (temperature 0.8, top_p 0.9) beside the "
+        f"live stream and alone: the same 16 tokens; {int(drew)} dispatches "
+        f"ran the sampler's candidates")
 
     status, raw = http_json(port, "GET", "/metrics")
     check(status == 200, f"/metrics -> {status}")

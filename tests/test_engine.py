@@ -497,3 +497,68 @@ def test_stop_token_ids_and_min_tokens(setup):
     assert deferred.finish_reason == "length"
     assert stop_tok not in deferred.generated
     assert deferred.generated[:idx] == base.generated[:idx]
+
+
+@pytest.mark.parametrize("gives_back", ["finish", "preempt"])
+def test_a_returned_slot_never_holds_the_samplers_gate_open(setup, gives_back):
+    """The sampler's gate (ops/sampling.sample: the candidates run only if
+    some row's temperature is above zero) reads EVERY slot's row, idle ones
+    too. So a slot given back — finished, or preempted — must read zero
+    again: the greedy requests that follow a seeded sampled one, the slot it
+    held among theirs, dispatch with ``sample_rows == 0`` and count on the
+    ``greedy`` path. And the gate keeps the seed contract: the sampled
+    request beside greedy ones draws what it draws served alone."""
+    from aws_k8s_ansible_provisioner_tpu.serving import flightrec
+
+    cfg, params, serving = setup
+    drawn = dict(prompt_ids=[5, 9, 2, 11], max_tokens=10, temperature=0.8,
+                 top_p=0.9, seed=7, ignore_eos=True)
+    alone = run_engine(Engine(cfg, params, serving),
+                       [Request(**drawn)])[0].generated
+
+    eng = Engine(cfg, params, serving)
+    seen, orig = [], flightrec.record
+
+    def tap(*a, **rec):
+        if a[0] == "dispatch":
+            seen.append(dict(rec))
+        return orig(*a, **rec)
+
+    def greedy(n):
+        return [Request(prompt_ids=[3 + i, 7, 8], max_tokens=6,
+                        ignore_eos=True) for i in range(n)]
+
+    path = eng.metrics.sample_dispatches
+    flightrec.record = tap
+    try:
+        mixed = [Request(**drawn)] + greedy(2)
+        for r in mixed:
+            eng.submit(r)
+        while len(mixed[0].generated) < 4:
+            assert eng.step()
+        slot = next(s for s, rq in enumerate(eng.slot_req) if rq is mixed[0])
+        assert eng.temps[slot] > 0
+        assert any(r["sample_rows"] == 1 for r in seen
+                   if r["kind"] == "decode")
+        if gives_back == "preempt":
+            eng._preempt(slot)
+            assert eng.temps[slot] == 0.0
+        run_engine(eng, [])
+        assert mixed[0].generated == alone
+        assert not eng.temps.any()
+        drew = path.value(program="decode_steps", path="candidates")
+        assert drew > 0
+        del seen[:]
+        reused = False
+        for r in greedy(serving.max_decode_slots):
+            eng.submit(r)
+        while eng.step():
+            reused |= eng.slot_req[slot] is not None
+    finally:
+        flightrec.record = orig
+    assert reused and seen and all(r["sample_rows"] == 0 for r in seen)
+    assert path.value(program="decode_steps", path="candidates") == drew
+    assert sum(path.value(program=p, path="greedy")
+               for p in {r["program"] for r in seen}) >= len(seen)
+    assert ('tpu_serve_sample_dispatches_total{path="greedy",'
+            'program="decode_steps"}') in eng.metrics.registry.render()

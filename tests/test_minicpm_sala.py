@@ -809,24 +809,28 @@ def test_aot_plan_sizes_state_and_selector_beside_the_pool():
 # they sample, so their jaxprs gained the row index and the gather of the
 # hidden state before the head, lost the take of one row from every-row
 # logits, and changed in nothing else (tests/test_head_rows.py holds them to
-# their every-row form). The six ``decode_steps`` hashes and the four
-# kernels' are PR 34's, byte for byte.
+# their every-row form). PR 38 re-took all fifteen at its own tree (parent
+# cab5f9b): every step program ends in ops/sampling.sample, whose candidates
+# (top_k, the masks, the draws) moved into one ``cond`` on
+# ``any(temperature > 0)`` — the programs' own code did not change
+# (tests/test_sampling.py holds the gated sampler to the ungated one token for
+# token). The four kernels' hashes are PR 34's, byte for byte.
 PINNED = {
-    ("tiny-olmoe", "decode_steps", "pallas"): "c3a5fd9b3b678813",
-    ("tiny-olmoe", "decode_steps", "xla"): "9328a74029f976d8",
-    ("tiny-olmoe", "mixed_step", "pallas"): "08071fdda64cbf20",
-    ("tiny-olmoe", "mixed_step", "xla"): "ad8ba5da891ababc",
-    ("tiny-olmoe", "prefill_step", "xla"): "89ba3c024edb2a00",
-    ("tiny-qwen3", "decode_steps", "pallas"): "5888be0b14c43f27",
-    ("tiny-qwen3", "decode_steps", "xla"): "2e620697b405335b",
-    ("tiny-qwen3", "mixed_step", "pallas"): "1238e9a330212ccb",
-    ("tiny-qwen3", "mixed_step", "xla"): "eb79f61623a057c9",
-    ("tiny-qwen3", "prefill_step", "xla"): "1c9ec748873e92e7",
-    ("tiny-solar", "decode_steps", "pallas"): "7dc28428869412a8",
-    ("tiny-solar", "decode_steps", "xla"): "df42d4249d688fee",
-    ("tiny-solar", "mixed_step", "pallas"): "cc4751f308463178",
-    ("tiny-solar", "mixed_step", "xla"): "68bc51b5e333ba8f",
-    ("tiny-solar", "prefill_step", "xla"): "34a37dc8c6e7fb47",
+    ("tiny-olmoe", "decode_steps", "pallas"): "eba4bfb3d8c472ec",
+    ("tiny-olmoe", "decode_steps", "xla"): "24166cb7302bca06",
+    ("tiny-olmoe", "mixed_step", "pallas"): "a6838077583d1ead",
+    ("tiny-olmoe", "mixed_step", "xla"): "00bf52f6e08eeefe",
+    ("tiny-olmoe", "prefill_step", "xla"): "fe74d853601263b8",
+    ("tiny-qwen3", "decode_steps", "pallas"): "33c39d047ea043b7",
+    ("tiny-qwen3", "decode_steps", "xla"): "979ebf2eee66c834",
+    ("tiny-qwen3", "mixed_step", "pallas"): "81ec57f8e5f829c5",
+    ("tiny-qwen3", "mixed_step", "xla"): "f29dc91fe02da895",
+    ("tiny-qwen3", "prefill_step", "xla"): "34d3281612f23ac5",
+    ("tiny-solar", "decode_steps", "pallas"): "d97d3b1a27533e57",
+    ("tiny-solar", "decode_steps", "xla"): "fbbaabf7e4d43f6a",
+    ("tiny-solar", "mixed_step", "pallas"): "c19ba5bf863effbb",
+    ("tiny-solar", "mixed_step", "xla"): "391e3d76844cbfa6",
+    ("tiny-solar", "prefill_step", "xla"): "8da44bc738dc28b0",
 }
 PINNED_KERNELS = {"decode": "c14c89f8caa0821f", "ragged": "33b7688923097344",
                   "write": "5ca71686a40fa563", "kda": "9fb3d56211d04454"}

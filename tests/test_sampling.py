@@ -3,8 +3,11 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
-from aws_k8s_ansible_provisioner_tpu.ops.sampling import MAX_TOPK, sample
+from aws_k8s_ansible_provisioner_tpu.ops.sampling import (MAX_TOPK,
+                                                          per_slot_keys,
+                                                          sample)
 
 
 def _logits(rows):
@@ -65,3 +68,79 @@ def test_large_vocab_uses_candidate_cap():
                  jnp.zeros(1, jnp.int32), jnp.asarray([0.99]))
     topk = set(np.argsort(-np.asarray(logits)[0])[:MAX_TOPK].tolist())
     assert int(out[0]) in topk
+
+
+def _ungated(logits, rng, temperature, top_k, top_p):
+    """``sample`` as it was before its candidates sat behind a conditional:
+    every row's top-64, nucleus and draw, and a ``where`` over the argmax."""
+    logits = logits.astype(jnp.float32)
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    cap = min(MAX_TOPK, logits.shape[-1])
+    vals, idxs = jax.lax.top_k(logits, cap)
+    eff_k = jnp.where(top_k <= 0, cap, jnp.minimum(top_k, cap))
+    vals = jnp.where(jnp.arange(cap)[None, :] < eff_k[:, None], vals,
+                     -jnp.inf)
+    safe_t = jnp.maximum(temperature, 1e-6)[:, None]
+    probs = jax.nn.softmax(vals / safe_t, axis=-1)
+    cum = jnp.cumsum(probs, axis=-1)
+    keep = ((cum - probs) < top_p[:, None]).at[:, 0].set(True)
+    scaled = jnp.where(keep, vals, -jnp.inf) / safe_t
+    if jnp.ndim(rng) == 1:
+        def slot_draw(key, row_scaled, row_ids):
+            u = jax.vmap(lambda t: jax.random.uniform(
+                jax.random.fold_in(key, t), minval=1e-20))(row_ids)
+            return jnp.argmax(row_scaled - jnp.log(-jnp.log(u)))
+
+        draw = jax.vmap(slot_draw)(rng, scaled, idxs)
+    else:
+        draw = jax.random.categorical(rng, scaled, axis=-1)
+    sampled = jnp.take_along_axis(idxs, draw[:, None], axis=1)[:, 0]
+    return jnp.where(temperature <= 0.0, greedy, sampled.astype(jnp.int32))
+
+
+def _equations(jaxpr, name, inside_cond=False):
+    """(equation, is it inside a ``cond`` branch) for every ``name`` primitive
+    of a jaxpr, sub-jaxprs walked."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == name:
+            yield eqn, inside_cond
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub, name, inside_cond
+                                  or eqn.primitive.name == "cond")
+
+
+@pytest.mark.parametrize("keys", ["per-slot", "batch"])
+@pytest.mark.parametrize("temps", [(0.0, 0.0, 0.0, 0.0), (0.0, 0.0, 0.9, 0.0),
+                                   (0.7, 1.3, 0.9, 2.0)],
+                         ids=["all-greedy", "one-draws", "all-draw"])
+def test_gated_sampler_is_the_ungated_one_token_for_token(temps, keys):
+    """The candidates behind ``lax.cond(any(temperature > 0))`` change no
+    token: all greedy, one drawing row among greedy ones, all drawing, under
+    per-slot keys and under one batch key, with a banned token's ``-inf`` and
+    a ``+100`` bias among the logits. And the sort over the vocabulary is in
+    the conditional's branch and nowhere else."""
+    rng = np.random.default_rng(3)
+    raw = rng.normal(size=(4, 300)).astype(np.float32) * 3.0
+    raw[0, 17] = raw[2, 40] = -np.inf                  # min_tokens / grammar
+    raw[1, 250] += 100.0                               # logit_bias +100
+    raw[2, int(np.argmax(raw[2]))] = -np.inf           # the ban took the best
+    logits = jnp.asarray(raw)
+    temperature = jnp.asarray(temps, jnp.float32)
+    top_k = jnp.asarray([0, 5, 40, 0], jnp.int32)
+    top_p = jnp.asarray([1.0, 0.9, 0.95, 0.5], jnp.float32)
+    for seed in range(6):
+        key = jax.random.key(seed)
+        if keys == "per-slot":
+            key = per_slot_keys(jnp.arange(4, dtype=jnp.uint32) + seed,
+                                jnp.asarray([9, 3, 11, 30], jnp.int32))
+        got = jax.jit(sample)(logits, key, temperature, top_k, top_p)
+        want = _ungated(logits, key, temperature, top_k, top_p)
+        assert got.dtype == jnp.int32 and got.tolist() == want.tolist()
+    assert all(int(g) == int(np.argmax(row))
+               for t, g, row in zip(temps, np.asarray(got), raw) if t == 0.0)
+    jaxpr = jax.make_jaxpr(sample)(logits, key, temperature, top_k,
+                                   top_p).jaxpr
+    sorts = list(_equations(jaxpr, "top_k"))
+    assert len(sorts) == 1 and sorts[0][1]
+    assert not list(_equations(jaxpr, "sort"))
+    assert len(list(_equations(jaxpr, "cond"))) == 1

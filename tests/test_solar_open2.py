@@ -548,20 +548,21 @@ def test_start_up_refuses_lora_and_gshard():
 # with this very function: every new operand, field and branch is behind a
 # configuration key these models do not set. The four ``mixed_step`` /
 # ``prefill_step`` hashes were re-taken by PR 35 (the head over the sampled
-# rows: tests/test_minicpm_sala.py says what changed in them).
+# rows: tests/test_minicpm_sala.py says what changed in them), and all six
+# by PR 38 (the sampler's candidates behind one ``cond``: the same file).
 PINNED = {
     ("tiny-qwen3", "decode_steps"):
-        "2e620697b405335b",
+        "979ebf2eee66c834",
     ("tiny-qwen3", "mixed_step"):
-        "eb79f61623a057c9",
+        "f29dc91fe02da895",
     ("tiny-olmoe", "decode_steps"):
-        "9328a74029f976d8",
+        "24166cb7302bca06",
     ("tiny-olmoe", "mixed_step"):
-        "ad8ba5da891ababc",
+        "00bf52f6e08eeefe",
     ("tiny-qwen3", "prefill_step"):
-        "1c9ec748873e92e7",
+        "34d3281612f23ac5",
     ("tiny-olmoe", "prefill_step"):
-        "89ba3c024edb2a00",
+        "fe74d853601263b8",
 }
 
 

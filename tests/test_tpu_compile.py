@@ -351,20 +351,9 @@ def test_batched_prefill_holds_no_every_row_logits(chip, monkeypatch):
                          compiled.as_text())
 
 
-@pytest.mark.parametrize(
-    "model,slots", [("Qwen/Qwen3-0.6B", 32), ("Qwen/Qwen3-8B", 16),
-                    ("allenai/OLMoE-1B-7B-0125-Instruct", 24)],
-    ids=["qwen3-0.6b", "qwen3-8b", "olmoe-1b-7b"])
-def test_decode_steps_orders_its_rows_once_a_substep(chip, monkeypatch,
-                                                     model, slots):
-    """The whole ``decode_steps`` of each closed cell (horizon 8, block 8),
-    from the engine's own enumeration: the rows' order in length (PR 33:
-    ops/attention._length_order) is taken in the SUBSTEP's body — two sorts,
-    the order and its inverse, beside the layer loop — and the layer's body
-    holds the kernel under its wrapper's name; the gathers around the call
-    bring no copy of a weight stack."""
-    import re
-
+def _compiled_decode_steps(chip, monkeypatch, model, slots):
+    """``decode_fused_h8`` of a closed cell (int8 weights, block 8) from the
+    engine's own enumeration, compiled for the described chip."""
     from aws_k8s_ansible_provisioner_tpu.config import (MODEL_REGISTRY,
                                                         ServingConfig)
     from aws_k8s_ansible_provisioner_tpu.serving import aot
@@ -379,7 +368,24 @@ def test_decode_steps_orders_its_rows_once_a_substep(chip, monkeypatch,
         p for p in aot.enumerate_programs(plan, None, params, cache,
                                           bblock=8)
         if p[0] == "decode_fused_h8")
-    compiled = fn.lower(*args, **kwargs).compile()
+    return fn.lower(*args, **kwargs).compile()
+
+
+@pytest.mark.parametrize(
+    "model,slots", [("Qwen/Qwen3-0.6B", 32), ("Qwen/Qwen3-8B", 16),
+                    ("allenai/OLMoE-1B-7B-0125-Instruct", 24)],
+    ids=["qwen3-0.6b", "qwen3-8b", "olmoe-1b-7b"])
+def test_decode_steps_orders_its_rows_once_a_substep(chip, monkeypatch,
+                                                     model, slots):
+    """The whole ``decode_steps`` of each closed cell (horizon 8, block 8),
+    from the engine's own enumeration: the rows' order in length (PR 33:
+    ops/attention._length_order) is taken in the SUBSTEP's body — two sorts,
+    the order and its inverse, beside the layer loop — and the layer's body
+    holds the kernel under its wrapper's name; the gathers around the call
+    bring no copy of a weight stack."""
+    import re
+
+    compiled = _compiled_decode_steps(chip, monkeypatch, model, slots)
     text = compiled.as_text()
     _assert_named_after_wrapper(compiled, pa.decode_attend_pallas_paged)
     # gathered as [B, Hq, D], q came out head-major and the compiler fed it
@@ -392,6 +398,58 @@ def test_decode_steps_orders_its_rows_once_a_substep(chip, monkeypatch,
     layer, = [b for b in bodies
               if re.search(r"%decode_attend_pallas_paged(\.\d+)? = ", b)]
     assert layer is not substep
+
+
+def test_decode_steps_sorts_the_vocabulary_only_where_a_row_draws(
+        chip, monkeypatch):
+    """The 0.6B closed cell's ``decode_steps`` (32 slots, horizon 8): its
+    substep holds ONE conditional — the sampler's "does any row draw"
+    (ops/sampling.sample) — and the top-64 over the 151,936-wide logits, as a
+    TopK call or as a sort, lives in a branch of it and nowhere else: a
+    greedy batch takes the other branch, which is the argmax it was handed.
+    The branch's operations still carry the ``sample`` part in their names,
+    so the trace's reader files them where it filed them before."""
+    import re
+
+    from aws_k8s_ansible_provisioner_tpu.config import MODEL_REGISTRY
+    from aws_k8s_ansible_provisioner_tpu.models import parts
+
+    model = "Qwen/Qwen3-0.6B"
+    vocab = MODEL_REGISTRY[model].vocab_size
+    text = _compiled_decode_steps(chip, monkeypatch, model, B).as_text()
+    bodies = {b.lstrip().split(" ", 1)[0]: b for b in text.split("\n\n")}
+    gates = [(b, m.group(1).split(", ")) for b in bodies.values()
+             for m in re.finditer(
+                 r" conditional\(.*branch_computations=\{([^}]*)\}", b)]
+    (substep, branches), = gates
+    assert re.search(rf" = \(s32\[1,{B}\]\S* s32\[1,{B}\]\S* sort\(", substep)
+    assert len(branches) == 2
+
+    def called(names):
+        """The computations reachable from ``names`` (a fusion's body, a
+        reduction's or a custom call's comparator)."""
+        seen, todo = set(), list(names)
+        while todo:
+            name = todo.pop()
+            if name in seen or name not in bodies:
+                continue
+            seen.add(name)
+            todo += re.findall(r"(?:calls|to_apply)=(%[^\s,)}]+)",
+                               bodies[name])
+            for group in re.findall(r"called_computations=\{([^}]*)\}",
+                                    bodies[name]):
+                todo += group.split(", ")
+        return seen
+
+    def wide_sorts(names):
+        return [ln for name in names for ln in bodies[name].splitlines()
+                if 'custom_call_target="TopK"' in ln
+                or (" sort(" in ln and f",{vocab}]" in ln)]
+
+    drawn = called(branches)
+    inside = wide_sorts(drawn)
+    assert len(inside) == 1 and f"/{parts.SAMPLE}/cond/" in inside[0]
+    assert not wide_sorts(set(bodies) - drawn)
 
 
 def test_kda_decode_update_compiles_in_place_under_its_own_name(chip):
