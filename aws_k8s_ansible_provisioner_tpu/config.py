@@ -129,16 +129,31 @@ class ModelConfig:
     # Shared experts: a dense SwiGLU of width n_shared_experts x
     # moe_intermediate_size every token passes, added to the routed sum.
     n_shared_experts: int = 0
+    # The FFN by LAYER (a layer list of a model with experts): the first
+    # num_dense_layers layers take the dense gated MLP of width
+    # intermediate_size, the others the routed one.
+    num_dense_layers: int = 0
+    # The renormalised top-k weights times this (the afmoe router's
+    # route_scale); 1.0 = as they are.
+    route_scale: float = 1.0
     # Per-layer block kinds, one character a layer: "g" softmax attention
     # (the GQA block), "k" KDA linear attention, "s" softmax attention that
     # SELECTS the pages it reads (ops/sparse_attention.py), "l" Lightning
-    # linear attention (ops/linear_attention.py). Two forms. A PERIOD of
-    # "g"/"k" kinds, shorter than the depth, repeats over it ("gkkk"). A
-    # LIST — one character a layer, any order, "g"/"s"/"l" kinds — is the
-    # layers held, as they are ("slllllls"). "" = every layer the one kind
-    # the fields above describe. A string, not a tuple of enums: the config
-    # is a jit static argument and is built from JSON by the benchmark.
+    # linear attention (ops/linear_attention.py), "w" softmax attention
+    # over the last sliding_window keys, rotated (RoPE) whatever
+    # attn_use_rope says of the "g" layers beside it, its K/V in page
+    # leaves and a page inventory of its own (ops/kv_pool.py). Two forms.
+    # A PERIOD of "g"/"k" kinds, shorter than the depth, repeats over it
+    # ("gkkk"). A LIST — one character a layer, any order, "g"/"s"/"l"
+    # kinds or "g"/"w" kinds — is the layers held, as they are
+    # ("slllllls", "wwwgwwwg"). "" = every layer the one kind the fields
+    # above describe. A string, not a tuple of enums: the config is a jit
+    # static argument and is built from JSON by the benchmark.
     layer_pattern: str = ""
+    # Norms on BOTH sides of each branch: the attention's and the FFN's
+    # OUTPUT pass an RMSNorm of their own before the residual add
+    # (attn_out_norm / mlp_out_norm beside input_norm / post_norm).
+    sandwich_norm: bool = False
     # "g"/"s" layers: the attention output is gated elementwise by
     # sigmoid(n . Wg) before the output projection.
     attn_output_gate: bool = False
@@ -184,6 +199,12 @@ class ModelConfig:
 
     def __post_init__(self):
         pat = self.layer_pattern
+        if self.num_dense_layers and not (
+                self.num_experts > 0 and len(pat) == self.num_layers):
+            raise ValueError(
+                f"num_dense_layers={self.num_dense_layers}: the FFN differs "
+                f"by layer only in a model with experts whose layers are a "
+                f"list (layer_pattern one character a layer)")
         if not pat:
             return
         if set(pat) <= set("gk"):
@@ -201,6 +222,20 @@ class ModelConfig:
             if "k" in pat and not (self.kda_num_heads and self.kda_head_dim):
                 raise ValueError("a 'k' layer needs kda_num_heads and "
                                  "kda_head_dim")
+            return
+        if "w" in pat:
+            if set(pat) - set("gw"):
+                raise ValueError(
+                    f"layer_pattern={pat!r}: window ('w') layers come in "
+                    f"a list with full ('g') attention layers and no other "
+                    f"kind")
+            if len(pat) != self.num_layers:
+                raise ValueError(
+                    f"layer_pattern={pat!r} names {len(pat)} layers, "
+                    f"num_layers={self.num_layers}: a list gives one "
+                    f"character a layer held")
+            if self.sliding_window <= 0:
+                raise ValueError("a 'w' layer needs sliding_window > 0")
             return
         if "g" in pat and "s" in pat:
             raise ValueError(
@@ -248,7 +283,7 @@ class ModelConfig:
     def layer_list(self) -> bool:
         """The pattern is a LIST of the layers held (not a period)."""
         return bool(self.layer_pattern) and (
-            bool(set(self.layer_pattern) & set("sl"))
+            bool(set(self.layer_pattern) & set("slw"))
             or (len(self.layer_pattern) == self.num_layers
                 and "k" not in self.layer_pattern))
 
@@ -263,6 +298,24 @@ class ModelConfig:
         names = {"k": "KDA", "l": "Lightning"}
         return "/".join(v for k, v in names.items()
                         if k in self.layer_pattern)
+
+    @property
+    def windowed(self) -> bool:
+        """Some layers of the list ("w") see only the last sliding_window
+        keys, beside full ones: their pages are another inventory."""
+        return "w" in self.layer_pattern
+
+    @property
+    def attn_window(self) -> int:
+        """The window of the layers the pool's ``k`` / ``v`` leaves serve:
+        sliding_window where EVERY layer is windowed (Mistral), 0 (full)
+        for the "g" layers of a list with "w" layers."""
+        return 0 if self.windowed else self.sliding_window
+
+    @property
+    def num_window_layers(self) -> int:
+        """The "w" layers: the leading axis of the pool's ``wk`` / ``wv``."""
+        return self.layer_pattern.count("w")
 
     @property
     def selects(self) -> bool:
@@ -597,7 +650,47 @@ OLMOE_1B_7B_0125_INSTRUCT = ModelConfig(
     hf_repo="allenai/OLMoE-1B-7B-0125-Instruct",
 )
 
+# arcee-ai Trinity-Mini (``model_type`` afmoe, 26B-A3B), the FIRST of four
+# pipeline stages: published layers 0-7 as they stand — window (2,048,
+# RoPE) and full (no positions) attention 3:1, both leading dense layers
+# and six routed ones (128 experts of width 1,024, top-8 by sigmoid score +
+# selection bias, renormalised, x route_scale, beside a shared expert),
+# norms on both sides of each branch, the embedding x sqrt(hidden) — and
+# the head, so that it serves tokens alone (benchmark/configs/
+# trinity-mini-26b-pp4.json states the cut).
+TRINITY_MINI_PP4_STAGE0 = ModelConfig(
+    name="arcee-ai/Trinity-Mini-pp4-stage0",
+    vocab_size=200192,
+    hidden_size=2048,
+    intermediate_size=6144,
+    num_layers=8,
+    num_heads=32,
+    num_kv_heads=4,
+    head_dim=128,
+    max_seq_len=131072,
+    sliding_window=2048,
+    rope_theta=10000.0,
+    norm_eps=1e-5,
+    qk_norm=True,
+    embed_scale=True,
+    tie_embeddings=False,
+    eos_token_id=3,
+    num_experts=128,
+    num_experts_per_tok=8,
+    moe_intermediate_size=1024,
+    norm_topk_prob=True,
+    router_scoring="sigmoid",
+    n_shared_experts=1,
+    num_dense_layers=2,
+    route_scale=2.826,
+    layer_pattern="wwwgwwwg",
+    sandwich_norm=True,
+    attn_output_gate=True,
+    attn_use_rope=False,
+)
+
 MODEL_REGISTRY = {
+    "arcee-ai/Trinity-Mini-pp4-stage0": TRINITY_MINI_PP4_STAGE0,
     "Qwen/Qwen3-0.6B": QWEN3_0_6B,
     "Qwen/Qwen3-30B-A3B": QWEN3_30B_A3B,
     "allenai/OLMoE-1B-7B-0125-Instruct": OLMOE_1B_7B_0125_INSTRUCT,
@@ -764,6 +857,46 @@ def tiny_sala(**overrides) -> ModelConfig:
         scale_depth=1.4,
         mup_depth=32,
         dim_model_base=16,
+    )
+    base.update(overrides)
+    return ModelConfig(**base)
+
+
+def tiny_trinity(**overrides) -> ModelConfig:
+    """A miniature Trinity-shaped list: window ("w": the last 8 keys,
+    rotated) and full ("g": no positions) gated GQA layers, two leading
+    dense FFNs and four routed ones (8 experts, top-2 by sigmoid score +
+    selection bias, x route_scale, plus a shared expert), norms on both
+    sides of each branch, the embedding x sqrt(hidden)."""
+    base = dict(
+        name="tiny-trinity",
+        vocab_size=128,
+        hidden_size=64,
+        intermediate_size=96,
+        num_layers=6,
+        num_heads=4,
+        num_kv_heads=2,
+        head_dim=16,
+        max_seq_len=256,
+        sliding_window=8,
+        rope_theta=10000.0,
+        norm_eps=1e-5,
+        qk_norm=True,
+        embed_scale=True,
+        tie_embeddings=False,
+        eos_token_id=1,
+        num_experts=8,
+        num_experts_per_tok=2,
+        moe_intermediate_size=32,
+        norm_topk_prob=True,
+        router_scoring="sigmoid",
+        n_shared_experts=1,
+        num_dense_layers=2,
+        route_scale=2.826,
+        layer_pattern="wwwgwg",
+        sandwich_norm=True,
+        attn_output_gate=True,
+        attn_use_rope=False,
     )
     base.update(overrides)
     return ModelConfig(**base)

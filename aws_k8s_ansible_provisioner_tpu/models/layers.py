@@ -195,14 +195,20 @@ def _default_attend(q, k, v, cache):
 
 
 def make_default_attend(cfg: ModelConfig):
-    """Full-window (training/parity) attend honoring cfg.sliding_window."""
-    if cfg.sliding_window <= 0:
-        return _default_attend
+    """Full-window (training/parity) attend honoring cfg.sliding_window —
+    every layer's window, or the "w" layers' alone (``attend.window``)
+    where the kinds are a list."""
 
-    def attend(q, k, v, cache):
+    def windowed(q, k, v, cache):
         return causal_attend(q, k, v, window=cfg.sliding_window), cache
 
-    return attend
+    if cfg.windowed:
+        def attend(q, k, v, cache):
+            return causal_attend(q, k, v), cache
+
+        attend.window = windowed
+        return attend
+    return windowed if cfg.sliding_window > 0 else _default_attend
 
 
 # ---------------------------------------------------------------------------
@@ -288,10 +294,14 @@ def init_hybrid_layer_params(cfg: ModelConfig, key: jax.Array, dtype) -> dict:
 
 def init_list_layer_params(cfg: ModelConfig, key: jax.Array, dtype) -> dict:
     """Stacked params of a model whose layer kinds are a LIST: one sub-tree
-    a kind, ``attn`` leaves ``[n_a, ...]`` (the "g" / "s" layers in order;
-    selecting has no parameter of its own) and ``lightning`` leaves
-    ``[n_l, ...]`` — what a run of one kind scans over
-    (:func:`_list_forward_carry`). Every FFN is the dense gated MLP."""
+    a kind, ``attn`` leaves ``[n_a, ...]`` (the "g" / "s" / "w" layers in
+    order; selecting and the window have no parameter of their own) and
+    ``lightning`` leaves ``[n_l, ...]`` — what a run of one kind scans over
+    (:func:`_list_forward_carry`). Every FFN is the dense gated MLP, in its
+    layer's sub-tree — but in a model with experts the FFN differs by LAYER
+    and the two kinds are stacks of their own: ``ffn_dense`` ``[n_d, ...]``
+    (the leading ``num_dense_layers``) and ``ffn_moe`` ``[L - n_d, ...]``
+    (router, expert stacks, shared expert)."""
     H, I = cfg.hidden_size, cfg.intermediate_size
     ka, kl = jax.random.split(key)
 
@@ -305,8 +315,11 @@ def init_list_layer_params(cfg: ModelConfig, key: jax.Array, dtype) -> dict:
         return {"w_gate": dense(ks[0], n, H, I), "w_up": dense(ks[1], n, H, I),
                 "w_down": dense(ks[2], n, I, H)}
 
+    def own_ffn(ks, n):     # the FFN inside the layer's own sub-tree
+        return {} if cfg.num_experts > 0 else ffn(ks, n)
+
     out = {}
-    n = cfg.num_attn_layers
+    n = sum(cfg.layer_pattern.count(c) for c in "gsw")
     if n:
         ks = jax.random.split(ka, 8)
         out["attn"] = {
@@ -314,12 +327,15 @@ def init_list_layer_params(cfg: ModelConfig, key: jax.Array, dtype) -> dict:
             "wq": dense(ks[0], n, H, cfg.q_size),
             "wk": dense(ks[1], n, H, cfg.kv_size),
             "wv": dense(ks[2], n, H, cfg.kv_size),
-            "wo": dense(ks[3], n, cfg.q_size, H), **ffn(ks[5:8], n)}
+            "wo": dense(ks[3], n, cfg.q_size, H), **own_ffn(ks[5:8], n)}
         if cfg.attn_output_gate:
             out["attn"]["wg"] = dense(ks[4], n, H, cfg.q_size)
         if cfg.qk_norm:
             out["attn"]["q_norm"] = norm(n, cfg.head_dim)
             out["attn"]["k_norm"] = norm(n, cfg.head_dim)
+        if cfg.sandwich_norm:
+            out["attn"]["attn_out_norm"] = norm(n)
+            out["attn"]["mlp_out_norm"] = norm(n)
     n = cfg.layer_pattern.count("l")
     if n:
         ks = jax.random.split(kl, 8)
@@ -330,7 +346,15 @@ def init_list_layer_params(cfg: ModelConfig, key: jax.Array, dtype) -> dict:
             "wv": dense(ks[2], n, H, D), "wg": dense(ks[3], n, H, D),
             "wo": dense(ks[4], n, D, H),
             "q_norm": norm(n, d), "k_norm": norm(n, d), "o_norm": norm(n, d),
-            **ffn(ks[5:8], n)}
+            **own_ffn(ks[5:8], n)}
+    if cfg.num_experts > 0:
+        kd, km = jax.random.split(jax.random.fold_in(key, 2))
+        nd = cfg.num_dense_layers
+        if nd:
+            out["ffn_dense"] = ffn(jax.random.split(kd, 3), nd)
+        if cfg.num_layers > nd:
+            out["ffn_moe"] = _init_ffn_params(cfg, km, dtype,
+                                              (cfg.num_layers - nd,))
     return out
 
 
@@ -483,7 +507,9 @@ def _linear(x, p):
 
 
 def _mlp(cfg: ModelConfig, h: jnp.ndarray, p: dict) -> jnp.ndarray:
-    if cfg.num_experts > 0:  # MoE: router + grouped expert compute (ops/moe)
+    # MoE: router + grouped expert compute (ops/moe); where the FFN differs
+    # by layer, a dense layer's params hold no router
+    if cfg.num_experts > 0 and "router" in p:
         from aws_k8s_ansible_provisioner_tpu.ops.moe import moe_mlp
 
         B, T, H = h.shape
@@ -510,20 +536,31 @@ def _mlp(cfg: ModelConfig, h: jnp.ndarray, p: dict) -> jnp.ndarray:
 
 
 def _add_ffn(cfg: ModelConfig, x: jnp.ndarray, h: jnp.ndarray,
-             p: dict) -> jnp.ndarray:
+             p: dict, out_norm: Optional[dict] = None) -> jnp.ndarray:
     """``x`` + the block's FFN of ``h``; the residual add carries the part
     of the FFN's last operation (an elementwise tail fuses behind the matmul
-    it follows and names the fusion)."""
+    it follows and names the fusion). ``out_norm``: the norm the FFN's
+    output passes first, in a model with norms on both sides."""
     y = _mlp(cfg, h, p)
+    if out_norm is not None:
+        with jax.named_scope(parts.NORM):
+            y = apply_norm(cfg, y, out_norm)
     with jax.named_scope(parts.ffn_tail(cfg)):
         return _residual(cfg, x, y)
 
 
 def decoder_block(cfg: ModelConfig, p: dict, x: jnp.ndarray,
                   cos: jnp.ndarray, sin: jnp.ndarray,
-                  attend: AttendFn, cache_l: Any) -> Tuple[jnp.ndarray, Any]:
-    """One transformer block. ``p`` is a per-layer slice (no leading L axis)."""
+                  attend: AttendFn, cache_l: Any, ffn: Optional[dict] = None,
+                  rope: Optional[bool] = None) -> Tuple[jnp.ndarray, Any]:
+    """One transformer block. ``p`` is a per-layer slice (no leading L
+    axis). ``ffn``: the FFN's params where they are no part of ``p`` (the
+    FFN differs by layer: a slice of its own stack); ``rope``: whether THIS
+    layer rotates q/k, where the kinds of one model differ (None = what
+    the config says of every attention layer)."""
     B, T, _ = x.shape
+    if rope is None:
+        rope = cfg.attn_use_rope
     rotary_dim = int(cfg.head_dim * cfg.rotary_pct)
 
     with jax.named_scope(parts.NORM):
@@ -540,7 +577,7 @@ def decoder_block(cfg: ModelConfig, p: dict, x: jnp.ndarray,
         if cfg.qk_norm and not whole:  # per-head RMSNorm on q/k (Qwen3)
             q = rms_norm(q, p["q_norm"]["weight"], cfg.norm_eps)
             k = rms_norm(k, p["k_norm"]["weight"], cfg.norm_eps)
-        if cfg.pos_embed == "rope" and cfg.attn_use_rope:
+        if cfg.pos_embed == "rope" and rope:
             q = apply_rope(q, cos, sin, rotary_dim)
             k = apply_rope(k, cos, sin, rotary_dim)
 
@@ -552,12 +589,19 @@ def decoder_block(cfg: ModelConfig, p: dict, x: jnp.ndarray,
             with jax.named_scope(parts.ATTN_PROJ):
                 gate = jax.nn.sigmoid(_linear(h, p["wg"]))
             ctx = ctx * gate
-        x = _residual(cfg, x, _linear(ctx, p["wo"]))
+        out = _linear(ctx, p["wo"])
+        if cfg.sandwich_norm:
+            with jax.named_scope(parts.NORM):
+                out = apply_norm(cfg, out, p["attn_out_norm"])
+        x = _residual(cfg, x, out)
+    ffn = p if ffn is None else ffn
     if cfg.parallel_block:  # Phi: attn and MLP both read the same normed input
-        return _add_ffn(cfg, x, h, p), new_cache_l
+        return _add_ffn(cfg, x, h, ffn), new_cache_l
     with jax.named_scope(parts.NORM):
         h2 = apply_norm(cfg, x, p["post_norm"])
-    return _add_ffn(cfg, x, h2, p), new_cache_l
+    return _add_ffn(cfg, x, h2, ffn,
+                    p["mlp_out_norm"] if cfg.sandwich_norm else None), \
+        new_cache_l
 
 
 def _residual(cfg: ModelConfig, x: jnp.ndarray, y: jnp.ndarray):
@@ -675,7 +719,8 @@ def _embed_inputs(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
             # or the Lightning layers' where the attention layers have no
             # positions
             rotary_dim = int(cfg.head_dim * cfg.rotary_pct) \
-                if cfg.attn_use_rope else cfg.lightning_head_dim
+                if cfg.attn_use_rope or cfg.windowed \
+                else cfg.lightning_head_dim
             cos, sin = rope_cos_sin(positions, rotary_dim, cfg.rope_theta,
                                     cfg)
     return x, cos, sin
@@ -732,11 +777,15 @@ def model_forward(
             recur_from_zero)
 
         # the stateless form: every sequence whole, from position 0
+        def in_carry(fn):
+            return lambda q, k, v, cl: (fn(q, k, v, None)[0], cl)
+
         fwd = _list_forward_carry if cfg.layer_list else _hybrid_forward_carry
-        logits, _ = fwd(
-            params, cfg, tokens, positions, {},
-            lambda q, k, v, cl: (attend(q, k, v, None)[0], cl),
-            recur_from_zero, remat=remat)
+        stateless = in_carry(attend)
+        if cfg.windowed:
+            stateless.window = in_carry(attend.window)
+        logits, _ = fwd(params, cfg, tokens, positions, {}, stateless,
+                        recur_from_zero, remat=remat)
         return logits, None
     x, cos, sin = _embed_inputs(params, cfg, tokens, positions)
 
@@ -871,37 +920,63 @@ def _hybrid_forward_carry(params, cfg: ModelConfig, tokens, positions, cache,
     return _final_logits(params, cfg, x, head_rows), {**pool, **rec}
 
 
-def layer_runs(pattern: str):
-    """A list of layer kinds as RUNS of one kind: ``(kind, index of the
-    run's first layer among the layers of its kind, length)`` —
-    "slllllls" is ``[("s", 0, 1), ("l", 0, 6), ("s", 1, 1)]``."""
-    runs, seen = [], {}
-    for kind in pattern:
-        stack = "l" if kind == "l" else "a"
-        if runs and runs[-1][0] == kind:
-            runs[-1][2] += 1
+def layer_plan(cfg: ModelConfig):
+    """The list as RUNS of equal (kind, FFN): ``(kind, FFN stack or None,
+    first, cache, ffn, length)`` — ``first`` the run's first layer among
+    the layers of its PARAMS stack (``lightning``, or ``attn`` for the
+    "g" / "s" / "w" kinds together), ``cache`` among the layers that share
+    its CACHE leaves (the Lightning state; the pool's ``k`` / ``v`` for "g"
+    / "s"; its ``wk`` / ``wv`` for "w"), ``ffn`` in its FFN stack
+    (``ffn_dense`` / ``ffn_moe``; None = the FFN lies in the layer's own
+    sub-tree). One scan body a run: "ww|wgwwwg" (dense | routed) has five,
+    the published 32 layers ("wwwg" x 8, two dense) eighteen."""
+    plan, seen = [], {}
+    for i, kind in enumerate(cfg.layer_pattern):
+        stack = "lightning" if kind == "l" else "attn"
+        leaves = stack if kind == "l" else "win" if kind == "w" else "pool"
+        ffn = None if cfg.num_experts <= 0 else \
+            "ffn_dense" if i < cfg.num_dense_layers else "ffn_moe"
+        if plan and plan[-1][:2] == [kind, ffn]:
+            plan[-1][5] += 1
         else:
-            runs.append([kind, seen.get(stack, 0), 1])
-        seen[stack] = seen.get(stack, 0) + 1
-    return [tuple(r) for r in runs]
+            plan.append([kind, ffn, seen.get(stack, 0), seen.get(leaves, 0),
+                         seen.get(ffn, 0), 1])
+        for name in {stack, leaves, ffn}:
+            seen[name] = seen.get(name, 0) + 1
+    return [tuple(r) for r in plan]
+
+
+def layer_runs(pattern: str):
+    """:func:`layer_plan` of a list whose FFNs lie in the layers' own
+    sub-trees, as ``(kind, index of the run's first layer among the layers
+    of its params stack, length)`` — "slllllls" is ``[("s", 0, 1), ("l", 0,
+    6), ("s", 1, 1)]``."""
+    from types import SimpleNamespace
+
+    plan = layer_plan(SimpleNamespace(layer_pattern=pattern, num_experts=0))
+    return [(kind, first, n) for kind, _, first, _, _, n in plan]
 
 
 def _list_forward_carry(params, cfg: ModelConfig, tokens, positions, cache,
                         attend: AttendFn, recur, remat: bool = False,
                         head_rows=None):
     """model_forward_carry for a model whose layer kinds are a LIST (not a
-    period): the list is walked RUN by run of one kind, a run longer than
-    one layer as ONE scan over its layers — so the step programs hold one
-    layer body a run, not one a layer (the published 32-layer list has 9
-    runs; a pipeline stage "s llllll s" has 3). ``cache`` holds the pool's
-    leaves with a leading axis of ATTENDING layers (``attend`` is handed
-    ``(pool, index among the attending layers)``) and the Lightning
-    layers' per-slot state ``[n_l, 1, slots, ...]``, which
-    ``recur.lightning`` reads and writes as ``(state, index among the
-    Lightning layers, 0)``. Both ride the carry. Each layer's params are
-    one dynamic slice a leaf of the kind's whole stack (see
-    :func:`_hybrid_forward_carry`)."""
+    period): the list is walked RUN by run of one kind (and one FFN kind,
+    :func:`layer_plan`), a run longer than one layer as ONE scan over its
+    layers — so the step programs hold one layer body a run, not one a
+    layer (the published 32-layer list has 9 runs; a pipeline stage
+    "s llllll s" has 3). ``cache`` holds the pool's leaves with a leading
+    axis of ATTENDING layers (``attend`` is handed ``(pool, index among
+    the attending layers)``) and the Lightning layers' per-slot state
+    ``[n_l, 1, slots, ...]``, which ``recur.lightning`` reads and writes as
+    ``(state, index among the Lightning layers, 0)``. A window ("w") layer
+    goes to ``attend.window`` with its index among the WINDOW layers: its
+    K/V are leaves of their own in the same pool (ops/kv_pool.py), and it
+    rotates q/k whatever the full layers do. All ride the carry. Each
+    layer's params are one dynamic slice a leaf of the kind's whole stack
+    (see :func:`_hybrid_forward_carry`)."""
     from aws_k8s_ansible_provisioner_tpu.ops import linear_attention as la
+    from aws_k8s_ansible_provisioner_tpu.ops import moe
     from aws_k8s_ansible_provisioner_tpu.ops import sparse_attention as sa
 
     x, cos, sin = _embed_inputs(params, cfg, tokens, positions)
@@ -918,30 +993,46 @@ def _list_forward_carry(params, cfg: ModelConfig, tokens, positions, cache,
             lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
             layers[stack])
 
-    def one(kind, carry, i):
+    def one(kind, carry, i, at=None, ffn=None):
+        """Layer ``i`` of its params stack; ``at`` its index among the
+        layers that share its cache leaves and ``ffn`` = (stack, index) of
+        its FFN, where they are not ``i`` and the layer's own."""
         x, pool, rec = carry
+        at = i if at is None else at
         if kind == "l":
             x, rec = lightning_block(cfg, layer("lightning", i), x, cos, sin,
-                                     recur, (rec, i, 0))
+                                     recur, (rec, at, 0))
         else:
-            x, (pool, _) = decoder_block(cfg, layer("attn", i), x, cos, sin,
-                                         attend, (pool, i))
+            x, (pool, _) = decoder_block(
+                cfg, layer("attn", i), x, cos, sin,
+                attend.window if kind == "w" else attend, (pool, at),
+                ffn=None if ffn is None else layer(*ffn),
+                rope=True if kind == "w" else None)
         return x, pool, rec
 
     carry = (x, pool, rec)
-    for kind, first, n in layer_runs(cfg.layer_pattern):
+    stats = []      # an MoE layer's routing counts (ops/moe.routed_rows)
+    for kind, stack, first, at, ffn, n in layer_plan(cfg):
+
+        def step(carry, i, kind=kind, stack=stack, d_at=at - first,
+                 d_ffn=ffn - first):
+            # (an offset of zero leaves the index as it is: the older
+            # lists' programs are what they were)
+            carry = one(kind, carry, i, i + d_at if d_at else None,
+                        (stack, i + d_ffn) if stack else None)
+            return carry, moe.take_layer_stats()
+
         if n == 1:
-            carry = one(kind, carry, jnp.int32(first))
-            continue
-
-        def body(carry, i, kind=kind):
-            return one(kind, carry, i), None
-
-        if remat:
-            body = jax.checkpoint(body)
-        carry, _ = jax.lax.scan(
-            body, carry, first + jnp.arange(n, dtype=jnp.int32))
+            carry, got = step(carry, jnp.int32(first))
+            got = None if got is None else got[None]
+        else:
+            carry, got = jax.lax.scan(
+                jax.checkpoint(step) if remat else step, carry,
+                first + jnp.arange(n, dtype=jnp.int32))
+        if got is not None:
+            stats.append(got)
     x, pool, rec = carry
     if sa.TALLY in pool:
         sa.put_counts(pool.pop(sa.TALLY))
+    moe.put_stats(jnp.concatenate(stats) if stats else None)
     return _final_logits(params, cfg, x, head_rows), {**pool, **rec}

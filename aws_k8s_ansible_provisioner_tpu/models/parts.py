@@ -51,10 +51,12 @@ PARTS = (EMBED, NORM, ATTN_PROJ, ATTN_CORE, ATTN_OUT, MLP, ROUTER, EXPERTS,
 # The ONE leaf-to-part table: a leaf belongs to the part of the nearest key
 # on its path that is listed here ("layers/wq/kernel" -> attn.proj,
 # "layers/kda/shared/w_up/scale" -> mlp). ``w_gate`` / ``w_up`` / ``w_down``
-# outside a ``shared`` sub-tree are expert stacks in a model with experts.
+# outside a ``shared`` or ``ffn_dense`` (the dense layers of a model whose
+# FFN differs by layer) sub-tree are expert stacks in a model with experts.
 _LEAF_PART = {
     "embed": EMBED, "pos_embed": EMBED,
     "input_norm": NORM, "post_norm": NORM,
+    "attn_out_norm": NORM, "mlp_out_norm": NORM,
     "wq": ATTN_PROJ, "wk": ATTN_PROJ, "wv": ATTN_PROJ, "wg": ATTN_PROJ,
     "q_norm": ATTN_PROJ, "k_norm": ATTN_PROJ,
     "f_a": ATTN_PROJ, "f_b": ATTN_PROJ, "g_a": ATTN_PROJ, "g_b": ATTN_PROJ,
@@ -66,6 +68,7 @@ _LEAF_PART = {
     "final_norm": HEAD, "lm_head": HEAD,
 }
 _EXPERT_STACKS = ("w_gate", "w_up", "w_down")
+_DENSE_FFN = ("shared", "ffn_dense")
 
 
 def ffn_tail(cfg) -> str:
@@ -103,7 +106,8 @@ def param_weights(params, cfg) -> Dict[str, Tuple[int, int]]:
         if part is None:
             raise ValueError(f"parameter leaf {'/'.join(map(str, keys))} "
                              f"belongs to no part: list it in _LEAF_PART")
-        if part == MLP and cfg.num_experts > 0 and "shared" not in keys \
+        if part == MLP and cfg.num_experts > 0 \
+                and not any(k in _DENSE_FFN for k in keys) \
                 and any(k in _EXPERT_STACKS for k in keys):
             part = EXPERTS
         tied_table = keys[0] == "embed" and cfg.tie_embeddings

@@ -119,6 +119,30 @@ def chunk_attend(q: jnp.ndarray, ck: jnp.ndarray, cv: jnp.ndarray,
 # ---------------------------------------------------------------------------
 
 
+def attend_by_kind(make, table, wtable, window: int):
+    """The attend callback of a model whose list holds window ("w") layers
+    beside full ("g") ones: ``make(table, window, of_window_kind)`` builds
+    one callback a kind — the full layers' over ``table`` with no window,
+    the window layers' over ``wtable`` (their own page inventory's table,
+    same width) with the static ``window`` — and each is handed its kind's
+    leaves of the pool under the names it knows (kv_pool.view). Returned as
+    the full
+    layers' callback with the other as its ``.window``
+    (models/layers._list_forward_carry picks by kind)."""
+
+    def on(leaves, inner):
+        def attend(q, k, v, cache_l):
+            pool, layer = cache_l
+            ctx, (part, _) = inner(q, k, v, (kvp.view(pool, leaves), layer))
+            return ctx, (kvp.with_view(pool, leaves, part), layer)
+
+        return attend
+
+    attend = on(kvp.FULL_LEAVES, make(table, 0, False))
+    attend.window = on(kvp.WINDOW_LEAVES, make(wtable, window, True))
+    return attend
+
+
 def _length_order(lengths: jnp.ndarray, table: jnp.ndarray, dp: int,
                   bblock: int) -> tuple:
     """The decode kernel's rows in ONE stable ascending order of length:
@@ -157,7 +181,8 @@ def _length_order(lengths: jnp.ndarray, table: jnp.ndarray, dp: int,
 
 def make_decode_attend_carry_paged(lengths: jnp.ndarray, table: jnp.ndarray,
                                    impl: str = "auto", mesh=None,
-                                   window: int = 0, bblock: int = 1):
+                                   window: int = 0, bblock: int = 1,
+                                   of_window_kind: bool = False):
     """Carry-path decode attend over the PAGED pool: cache_l is
     ``(pool, layer_idx)``; ``table`` [B, max_pages] int32 maps each slot's
     logical pages to physical pool pages. The engine guarantees every row in
@@ -214,6 +239,8 @@ def make_decode_attend_carry_paged(lengths: jnp.ndarray, table: jnp.ndarray,
             pool = {"k": ck, "v": cv}
             scale_kw = {}
         read = functools.partial(
+            pallas_attention.decode_attend_pallas_paged_window
+            if of_window_kind else
             pallas_attention.decode_attend_pallas_paged, interpret=interpret,
             window=window, bblock=bblock, **scale_kw)
         if not by_len:
@@ -369,7 +396,9 @@ def make_mixed_attend_carry_paged(dec_rows: jnp.ndarray, chunk_start,
                                   chunk_len, row_limits: jnp.ndarray,
                                   row_tables: jnp.ndarray,
                                   impl: str = "auto", mesh=None,
-                                  window: int = 0, bblock: int = 1):
+                                  window: int = 0, bblock: int = 1,
+                                  row_map=None,
+                                  of_window_kind: bool = False):
     """RAGGED mixed-batch attend over the PAGED pool: the packed sequence
     holds B single-token decode rows followed by C prefill-chunk rows of one
     chunking slot, and ONE program serves them all (serving/programs
@@ -404,13 +433,20 @@ def make_mixed_attend_carry_paged(dec_rows: jnp.ndarray, chunk_start,
     to the separate decode_attend/chunk_attend programs it replaces. Mesh
     support mirrors make_decode_attend_carry_paged's tp sharding (heads
     over ``tp``); the engine gates ragged dispatch to mesh None / pure-tp,
-    so no dp rebase rides here."""
+    so no dp rebase rides here.
+
+    ``row_map`` [N] (a list with window AND full layers, no mesh, bf16
+    pool): ``row_tables`` is then ONE row a SLOT, [B, max_pages], and row i
+    reads row ``row_map[i]`` of it — a row a packed row outgrows the
+    kernel's SMEM at a long window and a wide chunk; ``of_window_kind``
+    as in make_decode_attend_carry_paged."""
     resolved = resolve_impl(impl)
     B = dec_rows.shape[0]
 
     def _write_chunk(pool, knew, vnew, start, n_valid, tabs, layer):
+        pages = tabs[B] if row_map is None else tabs[row_map[B]]
         return kvp.write_chunk_paged_layer(
-            pool, layer, tabs[B], start, knew[None, B:], vnew[None, B:],
+            pool, layer, pages, start, knew[None, B:], vnew[None, B:],
             pool["k"].shape[3], n_valid=n_valid)
 
     def _write_attend_mixed(q3, pool, knew, vnew, drows, start, n_valid,
@@ -436,9 +472,19 @@ def make_mixed_attend_carry_paged(dec_rows: jnp.ndarray, chunk_start,
         pool = _write_chunk(pool, knew, vnew, start, n_valid, tabs, layer)
         scale_kw = (dict(pool_ks=pool["ks"], pool_vs=pool["vs"])
                     if "ks" in pool else {})
-        ctx = pallas_attention.ragged_attend_pallas_paged(
-            q3, pool["k"], pool["v"], limits, layer, tabs,
-            interpret=interpret, window=window, bblock=bblock, **scale_kw)
+        if row_map is not None and of_window_kind:
+            ctx = pallas_attention.ragged_attend_pallas_paged_slots_window(
+                q3, pool["k"], pool["v"], limits, layer, tabs, row_map,
+                interpret=interpret, window=window, bblock=bblock)
+        elif row_map is not None:
+            ctx = pallas_attention.ragged_attend_pallas_paged_slots(
+                q3, pool["k"], pool["v"], limits, layer, tabs, row_map,
+                interpret=interpret, bblock=bblock)
+        else:
+            ctx = pallas_attention.ragged_attend_pallas_paged(
+                q3, pool["k"], pool["v"], limits, layer, tabs,
+                interpret=interpret, window=window, bblock=bblock,
+                **scale_kw)
         return ctx, pool
 
     def attend(q, k, v, cache_l) -> Tuple[jnp.ndarray, tuple]:
@@ -480,7 +526,9 @@ def make_mixed_attend_carry_paged(dec_rows: jnp.ndarray, chunk_start,
                                            v[0][:B, None], ps)
         pool = _write_chunk(pool, k[0], v[0], chunk_start, chunk_len,
                             row_tables, layer)
-        dense = kvp.gather_layer_dense(pool, layer, row_tables)
+        dense = kvp.gather_layer_dense(
+            pool, layer,
+            row_tables if row_map is None else row_tables[row_map])
         ck, cv = dense["k"], dense["v"]
         if "ks" in dense:
             ck = kvp.dequantize(ck, dense["ks"], dtype=q.dtype)

@@ -13,6 +13,13 @@ serving/paged_kv.py.
   page ids, passed to each step program as a device array; the Pallas
   kernels read it via scalar prefetch and fetch page
   ``table[slot, logical_chunk]``.
+- **A second set of leaves for layers that age differently**: the window
+  ("w") layers of a list that also holds full ones keep their K/V in ``wk,
+  wv : [n_w, P_w, Hkv, page, D]`` — another page count, another table a
+  slot, another host inventory (serving/paged_kv.py) — so a page behind the
+  window goes back while the full layers still hold theirs. Every writer,
+  gather and kernel here works on ``k`` / ``v``; :func:`view` hands them
+  one kind's leaves under those names.
 
 Pages are head-major ``[Hkv, page, D]``, so each kernel page fetch DMAs one
 head-contiguous block and issues a single batched MXU matmul over all heads.
@@ -59,14 +66,55 @@ def scale_lanes(page_size: int) -> int:
     return -(-page_size // 128) * 128
 
 
+# the leaves of each kind of attending layer, under the names every writer,
+# gather and kernel knows
+FULL_LEAVES = {"k": "k", "v": "v"}
+WINDOW_LEAVES = {"k": "wk", "v": "wv"}
+
+
+def view(pool: dict, leaves: dict) -> dict:
+    """One kind's leaves of ``pool`` as a pool of its own."""
+    return {name: pool[src] for name, src in leaves.items()}
+
+
+def with_view(pool: dict, leaves: dict, part: dict) -> dict:
+    """``pool`` with one kind's leaves replaced by :func:`view`'s ``part``
+    as a callback left it."""
+    return {**pool, **{src: part[name] for name, src in leaves.items()}}
+
+
+def window_inventory(cfg: ModelConfig, num_slots: int, pages_per_slot: int,
+                     page_size: int, horizon: int, chunk: int) -> tuple:
+    """(pages a slot can hold at most while it decodes, pages of the whole
+    inventory) for the WINDOW layers of a list that also holds full ones —
+    a page behind the window goes back (Engine._win_cover), so a slot holds
+    the window + two decode horizons (the dispatch in flight and the one
+    being enqueued) + a page, the one slot that is chunking the chunk on
+    top; + the scratch page. Sized for every slot's bound at once: its
+    allocation never fails. (0, 0) for any other model."""
+    if not cfg.windowed:
+        return 0, 0
+    a_slot = min(pages_per_slot,
+                 -(-(cfg.sliding_window + 2 * horizon) // page_size) + 1)
+    return a_slot, num_slots * a_slot + min(
+        pages_per_slot, -(-chunk // page_size) + 1) + 1
+
+
 def init_pool(cfg: ModelConfig, num_pages: int, page_size: int,
-              dtype=jnp.bfloat16, quant: bool = False) -> dict:
+              dtype=jnp.bfloat16, quant: bool = False,
+              win_pages: int = 0) -> dict:
     """Allocate the physical page pool. Leaves carry a leading [L] axis:
     the layers that ATTEND (all of them, or one a period of a model with a
     layer pattern — its other layers keep per-slot state instead,
-    ops/linear_attention.init_state)."""
+    ops/linear_attention.init_state). A model with window layers beside
+    full ones gets ``win_pages`` pages of ``wk`` / ``wv`` for them."""
     shape = (cfg.num_attn_layers, num_pages, cfg.num_kv_heads, page_size,
              cfg.head_dim)
+    if cfg.windowed:
+        wshape = (cfg.num_window_layers, win_pages) + shape[2:]
+        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype),
+                "wk": jnp.zeros(wshape, dtype),
+                "wv": jnp.zeros(wshape, dtype)}
     if quant:
         sshape = shape[:3] + (scale_lanes(page_size),)
         return {
@@ -99,8 +147,12 @@ def selector_bytes(cfg: ModelConfig, num_pages: int, page_size: int) -> int:
 
 
 def pool_bytes(cfg: ModelConfig, num_pages: int, page_size: int,
-               dtype=jnp.bfloat16, quant: bool = False) -> int:
-    heads = 2 * cfg.num_attn_layers * num_pages * cfg.num_kv_heads
+               dtype=jnp.bfloat16, quant: bool = False,
+               win_pages: int = 0) -> int:
+    """Bytes of the pool's leaves: ``num_pages`` of the attending layers'
+    and, for a model with window layers, ``win_pages`` of theirs."""
+    heads = 2 * (cfg.num_attn_layers * num_pages
+                 + cfg.num_window_layers * win_pages) * cfg.num_kv_heads
     if quant:
         return heads * (page_size * cfg.head_dim
                         + 4 * scale_lanes(page_size))

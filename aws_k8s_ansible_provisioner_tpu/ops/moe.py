@@ -49,7 +49,7 @@ def route(cfg: ModelConfig, x: jnp.ndarray, router_kernel: jnp.ndarray,
     ``router_scoring`` "sigmoid": a score per expert, the top-k chosen by
     score + ``router_bias`` ([E], a selection bias: it changes who is chosen
     and never a weight), the weights the chosen SCORES, renormalised over
-    all k chosen (``norm_topk_prob``).
+    all k chosen (``norm_topk_prob``), then times ``route_scale``.
     """
     logits = x.astype(jnp.float32) @ router_kernel.astype(jnp.float32)
     if cfg.router_scoring == "sigmoid":
@@ -59,6 +59,8 @@ def route(cfg: ModelConfig, x: jnp.ndarray, router_kernel: jnp.ndarray,
         w = jnp.take_along_axis(scores, idx, axis=-1)
         if cfg.norm_topk_prob:
             w = w / (w.sum(axis=-1, keepdims=True) + 1e-20)
+        if cfg.route_scale != 1.0:
+            w = w * cfg.route_scale
         return w.astype(x.dtype), idx.astype(jnp.int32)
     probs = jax.nn.softmax(logits, axis=-1)                    # [N, E]
     w, idx = jax.lax.top_k(probs, cfg.num_experts_per_tok)     # [N, k]

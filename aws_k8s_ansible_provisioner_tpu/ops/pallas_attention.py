@@ -675,6 +675,71 @@ def ragged_attend_pallas_paged(q: jnp.ndarray, pool_k: jnp.ndarray,
         pool_ks=pool_ks, pool_vs=pool_vs, share=share)
 
 
+# -- the entry points of a list that holds window AND full layers -------------
+#
+# The same body under jits of their own, so that the device trace tells a
+# window layer's calls from a full layer's (a Pallas call is named after the
+# innermost jitted wrapper around it: ``_named_entry``), and a ragged entry
+# that takes ONE table row a slot: a row a packed row is 2.4 MB of SMEM at
+# 48 + 4,096 rows of 144 pages.
+
+
+def _named_entry(name: str, fn, doc: str):
+    """``fn`` under a jit of its own that the device trace calls ``name``."""
+    @functools.wraps(fn)
+    def entry(*args, **kw):
+        return fn(*args, **kw)
+
+    entry.__name__ = entry.__qualname__ = name
+    entry.__doc__ = doc
+    del entry.__wrapped__
+    return jax.jit(entry, static_argnames=("interpret", "window", "bblock"))
+
+
+def _ragged_by_slot(q, pool_k, pool_v, row_limits, layer, table, row_map,
+                    interpret: bool = False, window: int = 0,
+                    bblock: int = 1):
+    N = q.shape[0]
+    bb = _resolve_bb(bblock, N)
+    row_map = row_map.astype(jnp.int32)
+    row_limits = row_limits.astype(jnp.int32)
+    share = None
+    if bb > 1:      # a block whose live rows all name one slot shares it
+        live = (row_limits > 0).reshape(N // bb, bb)
+        slots = row_map.reshape(N // bb, bb)
+        first = jnp.argmax(live, axis=1).astype(jnp.int32)
+        lead = jnp.take_along_axis(slots, first[:, None], axis=1)
+        same = jnp.all((slots == lead) | ~live, axis=1)
+        share = jnp.where(same & live.any(axis=1),
+                          jnp.arange(N // bb, dtype=jnp.int32) * bb + first,
+                          -1)
+    return _paged_flash_db(
+        q, pool_k, pool_v, row_limits,
+        jnp.asarray(layer, jnp.int32).reshape(1), table.astype(jnp.int32),
+        bb=bb, R=1, spec=False, window=window, interpret=interpret,
+        pool_ks=None, pool_vs=None, share=share, row_map=row_map)
+
+
+decode_attend_pallas_paged_window = _named_entry(
+    "decode_attend_pallas_paged_window", decode_attend_pallas_paged.__wrapped__,
+    """:func:`decode_attend_pallas_paged` for the WINDOW layers of a list
+    that also holds full ones: their own leaves, their own table (entries
+    below a row's window may be anything: released pages), the static
+    ``window``. bf16 pool.""")
+ragged_attend_pallas_paged_slots = _named_entry(
+    "ragged_attend_pallas_paged_slots", _ragged_by_slot,
+    """:func:`ragged_attend_pallas_paged` with ONE table row a slot:
+    ``table`` [S, max_pages] and ``row_map`` [N] naming each packed row's
+    (the chunk rows of a prefill share an entry, which is also how a
+    sharing block is recognised). bf16 pool; the FULL layers of the list
+    call it (``window`` 0).""")
+ragged_attend_pallas_paged_slots_window = _named_entry(
+    "ragged_attend_pallas_paged_slots_window", _ragged_by_slot,
+    """:func:`ragged_attend_pallas_paged_slots` for the WINDOW layers: each
+    row masks below its own ``limit - window`` and a block's walk starts at
+    its lowest row's first live page.""")
+
+
 # SMEM the ragged selecting entry lets its prefetched operands take in one
 # call (of the chip's 1 MiB; the compiler keeps scalars of its own there)
 SELECT_PREFETCH_BYTES = 768 * 1024

@@ -106,6 +106,13 @@ class ProgramPlan:
         self.chunk = serving.prefill_chunk if serving.prefill_chunk > 0 \
             else self.buckets[-1]
         self.horizon = max(1, serving.decode_horizon)
+        # the window layers' inventory of a list that also holds full ones
+        from aws_k8s_ansible_provisioner_tpu.ops.kv_pool import (
+            window_inventory)
+
+        self.win_pages = window_inventory(
+            cfg, self.num_slots, self.pages_per_slot, serving.page_size,
+            self.horizon, self.chunk)[1]
         self.spec_rows = serving.spec_k + 1 if serving.spec_decode else 0
 
     def fingerprint(self) -> dict:
@@ -186,7 +193,8 @@ def _abstract_state(plan, mesh, device=None):
         device)
     cache = jax.eval_shape(
         lambda: kvp.init_pool(cfg, plan.total_pages, serving.page_size,
-                              dtype, quant=plan.kv_quant))
+                              dtype, quant=plan.kv_quant,
+                              win_pages=plan.win_pages))
     cache = _with_sharding(cache, pool_pspecs(plan.kv_quant), mesh, device)
     if cfg.recurrent:
         # the per-slot recurrent state beside the pool (one chip: the
@@ -259,18 +267,22 @@ def enumerate_programs(plan, mesh, params, cache, bblock: int = 1):
     # per-slot state they build (EnginePrograms._state_kw)
     slot_kw = dict(slot=scalar) if cfg.recurrent else {}
 
+    def win_kw(name: str, *lead):
+        """The window layers' table operand (EnginePrograms._win_kw)."""
+        return {name: sds(lead + (pps,), i32)} if cfg.windowed else {}
+
     def prefill_kwargs(n: Optional[int] = None):
         """Per-request operand rows; ``n`` rows for the batch program, the
         single-prompt scalar layout otherwise."""
         if n is None:
             return dict(
-                **slot_kw, pages=sds((pps,), i32),
+                **slot_kw, **win_kw("wpages"), pages=sds((pps,), i32),
                 seed=sds((), u32), ban_ids=sds((BAN_K,), i32),
                 ban_until=scalar, bias_ids=sds((BIAS_K,), i32),
                 bias_vals=sds((BIAS_K,), f32), rep=sds((), f32))
         return dict(
             **({"slots": sds((n,), i32)} if cfg.recurrent else {}),
-            tables=sds((n, pps), i32),
+            **win_kw("wtables", n), tables=sds((n, pps), i32),
             seeds=sds((n,), u32), ban_ids=sds((n, BAN_K), i32),
             ban_until=sds((n,), i32), bias_ids=sds((n, BIAS_K), i32),
             bias_vals=sds((n, BIAS_K), f32), reps=sds((n,), f32))
@@ -304,7 +316,8 @@ def enumerate_programs(plan, mesh, params, cache, bblock: int = 1):
              seed=sds((), u32), ban_ids=sds((BAN_K,), i32),
              ban_until=scalar, bias_ids=sds((BIAS_K,), i32),
              bias_vals=sds((BIAS_K,), f32), rep=sds((), f32),
-             rep_seen=sds((cfg.vocab_size,), jnp.bool_), **slot_kw)))
+             rep_seen=sds((cfg.vocab_size,), jnp.bool_), **slot_kw,
+             **win_kw("wpages"))))
 
     # an MoE model's decode and mixed programs take the live-slot mask
     # (EnginePrograms._live_rows); a dense model's take no such operand
@@ -318,7 +331,8 @@ def enumerate_programs(plan, mesh, params, cache, bblock: int = 1):
             table=sds((B, pps), i32),
             seeds=sds((B,), u32), ban_ids=sds((B, BAN_K), i32),
             ban_until=sds((B,), i32), bias_ids=sds((B, BIAS_K), i32),
-            bias_vals=sds((B, BIAS_K), f32), bblock=bblock, live=live)
+            bias_vals=sds((B, BIAS_K), f32), bblock=bblock, live=live,
+            **win_kw("wtable", B))
         if penalties:
             kw.update(counts=sds((B, cfg.vocab_size), i32),
                       presence=sds((B,), f32), frequency=sds((B,), f32),
@@ -357,7 +371,8 @@ def enumerate_programs(plan, mesh, params, cache, bblock: int = 1):
             table=sds((B, pps), i32), seeds=sds((B,), u32),
             ban_ids=sds((B, BAN_K), i32), ban_until=sds((B,), i32),
             bias_ids=sds((B, BIAS_K), i32),
-            bias_vals=sds((B, BIAS_K), f32), bblock=bblock, live=live)
+            bias_vals=sds((B, BIAS_K), f32), bblock=bblock, live=live,
+            **win_kw("wtable", B))
         programs.append((f"mixed_c{plan.chunk}", mixed_step,
                          mixed_args, mixed_kwargs))
         if serving.ragged_features > 0:

@@ -262,7 +262,8 @@ class Engine(EnginePrograms):
         "table", "lengths", "cache", "counts", "last_token",
         "slot_req", "temps", "pres_pens", "freq_pens", "rep_pens",
         "ban_until", "bias_ids", "bias_vals", "lora_idx", "_bias_n",
-        "_slot_pages", "_chunk",
+        "_slot_pages", "_chunk", "wtable", "_slot_wpages", "_wfirst",
+        "_win_unreleased",
         "_chunk_yield", "_prefill_streak", "_admission_blocked_since",
         "_tok_times", "_admit_seq", "_seq_counter", "prompt_mask",
         "_inflight", "_pipe_carry", "_carry_gen", "_op_cache",
@@ -619,6 +620,10 @@ class Engine(EnginePrograms):
             # with them would be wrong: no lookup, every admission (a
             # preemption's resume too) prefills from token 0
             self.metrics.prefix_lookups_skipped.inc(reason="recurrent_state")
+        elif self.cfg.windowed:
+            # a hit could restore the full layers' pages but not the window
+            # layers', which went back as the context passed them
+            self.metrics.prefix_lookups_skipped.inc(reason="window_pages")
         elif self.serving.prefix_cache and req.prompt_logprobs is None:
             req_lidx = (self.lora_names.index(req.lora) + 1
                         if req.lora is not None else 0)
@@ -777,7 +782,8 @@ class Engine(EnginePrograms):
         row is still pending the next dispatch, so indexing past
         len(ids) - 1 would publish a page with one garbage row to every
         future prefix hit (review r3)."""
-        if not self.serving.prefix_cache or self.cfg.recurrent:
+        if not self.serving.prefix_cache or self.cfg.recurrent \
+                or self.cfg.windowed:
             # no lookup side -> indexing would be pure overhead, and
             # unindexed pages go straight back to the free list at release
             return
@@ -797,6 +803,13 @@ class Engine(EnginePrograms):
         pages another request now owns."""
         self._alloc(slot).release_all(self._slot_pages[slot])
         self._slot_pages[slot] = []
+        if self.cfg.windowed:
+            self.win_allocator.release_all(self._slot_wpages[slot])
+            self._win_unreleased -= int(self._wfirst[slot]) \
+                + len(self._slot_wpages[slot])
+            self._slot_wpages[slot] = []
+            self._wfirst[slot] = 0
+            self.wtable[slot, :] = 0
         # a restore scheduled for a slot torn down before its chunk started
         # (deadline/cancel between admission and dispatch) must not settle
         # against a later tenant's chunk
@@ -812,6 +825,57 @@ class Engine(EnginePrograms):
         self.metrics.kv_pages_in_use.set(sum(s["pages_live"] for s in sts))
         self.metrics.kv_pages_evictable.set(
             sum(s["pages_evictable"] for s in sts))
+        if self.cfg.windowed:
+            _metrics.window_pool.in_use.set(self.win_allocator.pages_in_use)
+
+    def _win_cover(self, slot: int, n: int, upto: int) -> None:
+        """A list with window layers beside full ones: make the pages the
+        slot holds in the WINDOW layers' inventory the logical pages that a
+        query at position ``n`` or later can read or that rows below
+        ``upto`` land in — [(n + 1 - window) // page, ceil(upto / page)).
+        What lies below goes back to the inventory (its table entry reads
+        the scratch page: the kernels start a row's walk at its window and
+        never fetch it; an XLA gather masks it), what is missing above is
+        allocated. Called before every dispatch that writes the slot's
+        rows, with the host mirror's length: a dispatch still in flight was
+        enqueued with the table it needs, and the device runs dispatches in
+        order, so a page given back here is rewritten only after its last
+        reader. The inventory holds every slot's bound
+        (_init_params_and_cache), so the allocation cannot fail."""
+        ps, wp = self.serving.page_size, _metrics.window_pool
+        lo = max(0, n + 1 - self.cfg.sliding_window) // ps
+        hi = min(-(-upto // ps), self.pages_per_slot)
+        pages, first = self._slot_wpages[slot], int(self._wfirst[slot])
+        was = first + len(pages) if pages else 0
+        drop = min(max(0, lo - first), len(pages))
+        if drop:
+            self.win_allocator.release_all(pages[:drop])
+            del pages[:drop]
+            self.wtable[slot, first:first + drop] = 0
+            wp.released.inc(drop)
+        first = max(first + drop, lo) if pages else lo
+        need = hi - (first + len(pages))
+        if need > 0:
+            got = self.win_allocator.alloc(need)
+            if got is None:
+                raise RuntimeError(
+                    f"the window layers' inventory ({self.win_pages} pages) "
+                    f"cannot give slot {slot} {need} more pages beside its "
+                    f"{len(pages)}: it is sized for every slot's bound")
+            self.wtable[slot, first + len(pages):hi] = got
+            pages.extend(got)
+        self._wfirst[slot] = first
+        if not (drop or need > 0):
+            return
+        self._op_dirty_table = True
+        # what the slots would hold with nothing released: their contexts
+        self._win_unreleased += (first + len(pages) if pages else 0) - was
+        in_use = self.win_allocator.pages_in_use
+        if in_use > wp.in_use_peak.value():
+            wp.in_use_peak.set(in_use)
+            wp.unreleased_at_peak.set(self._win_unreleased)
+        if len(pages) > wp.slot_peak.value():
+            wp.slot_peak.set(len(pages))
 
     def _ensure_pages(self, new_rows: int) -> bool:
         """Grow every active slot's page run to cover rows
@@ -831,6 +895,8 @@ class Engine(EnginePrograms):
                 continue
             rows = min(int(self.lengths[slot]) + new_rows,
                        self.pages_per_slot * ps)
+            if self.cfg.windowed:
+                self._win_cover(slot, int(self.lengths[slot]), rows)
             pages = self._slot_pages[slot]
             while len(pages) < -(-rows // ps):
                 need = -(-rows // ps) - len(pages)

@@ -290,6 +290,12 @@ class EngineMetrics:
             "hold, kind=\"walked\" the pages their blocks walk (block rows "
             "x the block's longest row, blocks cut in order of length)",
             ("kind",)))
+        self.window_attn_pages = r.register(Counter(
+            "tpu_serve_window_attn_pages_total",
+            "tpu_serve_decode_attn_pages_total for the WINDOW layers of a "
+            "list that also holds full ones (which that family then "
+            "counts), per window layer: the pages inside the rows' "
+            "windows, and those their blocks walk", ("kind",)))
         self.sample_dispatches = r.register(Counter(
             "tpu_serve_sample_dispatches_total",
             "Dispatches by what their sampler ran, by step program: "
@@ -583,3 +589,50 @@ class ParamMetrics:
 
 
 params_by_part = ParamMetrics()
+
+
+class WindowPoolMetrics:
+    """Process-wide: the second page inventory of a list with window layers
+    beside full ones (serving/paged_kv.py; Engine._win_cover writes it) —
+    the window layers' pages, which go back as a slot's context passes
+    them. ``tpu_serve_kv_pages_*`` keep reading the inventory that grows
+    with the context. Process-wide so that a reader without the engine in
+    hand finds it (the benchmark's ``win_pages_held_pct``); a process
+    serves one model, the last engine built wins. Rendered by BOTH /metrics
+    routes (tpulint R2); every family reads 0 for any other model."""
+
+    def __init__(self):
+        self.registry = Registry()
+        r = self.registry
+        self.total = r.register(Gauge(
+            "tpu_serve_kv_window_pages_total",
+            "Physical pages of the window layers' inventory"))
+        self.in_use = r.register(Gauge(
+            "tpu_serve_kv_window_pages_in_use",
+            "Window-layer pages currently held by slots"))
+        self.in_use_peak = r.register(Gauge(
+            "tpu_serve_kv_window_pages_in_use_peak",
+            "Most window-layer pages held at once, over all slots"))
+        self.unreleased_at_peak = r.register(Gauge(
+            "tpu_serve_kv_window_pages_unreleased_at_peak",
+            "Pages the same slots would have held for the window layers at "
+            "that moment with nothing released (their contexts, in pages)"))
+        self.slot_peak = r.register(Gauge(
+            "tpu_serve_kv_window_pages_slot_peak",
+            "Most window-layer pages ONE slot has held at once: bounded by "
+            "window + the chunk in flight + a page, whatever its context"))
+        self.released = r.register(Counter(
+            "tpu_serve_kv_window_pages_released_total",
+            "Window-layer pages given back because every row that could "
+            "still read them is behind the window (a slot's pages at its "
+            "end are not counted)"))
+
+    def reset(self, total: int) -> None:
+        """A new engine's inventory of ``total`` pages (0: no such model)."""
+        self.total.set(total)
+        for g in (self.in_use, self.in_use_peak, self.unreleased_at_peak,
+                  self.slot_peak):
+            g.set(0)
+
+
+window_pool = WindowPoolMetrics()

@@ -41,6 +41,7 @@ from aws_k8s_ansible_provisioner_tpu.models.layers import (
     model_forward_carry,
 )
 from aws_k8s_ansible_provisioner_tpu.ops.attention import (
+    attend_by_kind,
     make_chunk_prefill_attend_paged_carry,
     make_decode_attend_carry_paged,
     make_mixed_attend_carry_paged,
@@ -409,6 +410,18 @@ def _aux(moe, picked):
         return _moe_summary(moe)
 
 
+def _attend(cfg: ModelConfig, make, table, wtable):
+    """A step program's attend callback over the paged pool, from
+    ``make(table, window, of_window_kind)``: one for every attending layer
+    of the model, or — a list with window ("w") layers beside full ones —
+    one a kind, the window layers' over ``wtable``, their own inventory's
+    table (ops/attention.attend_by_kind). ``wtable`` is None for any other
+    model: no operand, and its jaxpr is what it was."""
+    if cfg.windowed:
+        return attend_by_kind(make, table, wtable, cfg.sliding_window)
+    return make(table, cfg.sliding_window, False)
+
+
 def _recur(cfg: ModelConfig, make, *args):
     """The KDA layers' callback for a step program, from the same row
     metadata its ``attend`` is built from; None for a model without
@@ -423,14 +436,16 @@ def prefill_step(cfg: ModelConfig, params, cache, tokens, true_len, rng,
                  temperature, top_k, top_p, *, pages, logprobs: bool = False,
                  seed=None, ban_ids=None, ban_until=None,
                  bias_ids=None, bias_vals=None, rep=None, allow=None,
-                 lora_idx=None, prompt_logprobs: bool = False, slot=None):
+                 lora_idx=None, prompt_logprobs: bool = False, slot=None,
+                 wpages=None):
     """Prefill one prompt into one slot; returns (cache, first sampled token).
 
     tokens: [1, T] right-padded to a bucket; true_len: scalar valid length;
     ``cache`` is the paged pool and ``pages`` ([max_pages] int32) the slot's
     block table, through which the rows scatter (ops/kv_pool.py). ``slot``
     (scalar; a model with recurrent layers only): whose per-slot state the
-    prompt builds, from zeros.
+    prompt builds, from zeros. ``wpages`` (a model with window layers beside
+    full ones only): the slot's table into the window layers' inventory.
     """
     T = tokens.shape[1]
     positions = jnp.arange(T, dtype=jnp.int32)[None, :]
@@ -440,8 +455,9 @@ def prefill_step(cfg: ModelConfig, params, cache, tokens, true_len, rng,
         # restack buffer OOMed the batch-128 program on chip (r5)
         attend = _sa.make_prefill_attend_select(
             cfg, pages[None], true_len[None]) if cfg.selects \
-            else make_prefill_attend_paged_carry(
-                pages, true_len, window=cfg.sliding_window)
+            else _attend(
+                cfg, lambda t, w, _: make_prefill_attend_paged_carry(
+                    t, true_len, window=w), pages, wpages)
         logits, cache = model_forward_carry(
             params, cfg, tokens, positions, cache, attend,
             _recur(cfg, _la.make_recur_span, slot, 0, true_len),
@@ -481,7 +497,7 @@ def prefill_batch_step(cfg: ModelConfig, params, cache, tokens, true_lens,
                        ban_ids=None, ban_until=None,
                        bias_ids=None, bias_vals=None, reps=None, allow=None,
                        lora_idx=None, prompt_logprobs: bool = False,
-                       slots=None):
+                       slots=None, wtables=None):
     """Prefill N prompts into N slots in ONE dispatch.
 
     tokens: [N, T] right-padded to a (row, length) bucket; true_lens/
@@ -498,8 +514,9 @@ def prefill_batch_step(cfg: ModelConfig, params, cache, tokens, true_lens,
     rows = jnp.arange(N, dtype=jnp.int32) * T + true_lens - 1
     with lora_context(lora_idx):
         attend = _sa.make_prefill_attend_select(cfg, tables, true_lens) \
-            if cfg.selects else make_prefill_attend_batch_paged_carry(
-                tables, true_lens, window=cfg.sliding_window)
+            if cfg.selects else _attend(
+                cfg, lambda t, w, _: make_prefill_attend_batch_paged_carry(
+                    t, true_lens, window=w), tables, wtables)
         logits, cache = model_forward_carry(
             params, cfg, tokens, positions, cache, attend,
             _recur(cfg, _la.make_recur_batch, slots, true_lens),
@@ -529,7 +546,8 @@ def prefill_chunk_step(cfg: ModelConfig, params, cache, tokens, start,
                        logprobs: bool = False, seed=None,
                        ban_ids=None, ban_until=None,
                        bias_ids=None, bias_vals=None, rep=None,
-                       rep_seen=None, allow=None, lora_idx=None, slot=None):
+                       rep_seen=None, allow=None, lora_idx=None, slot=None,
+                       wpages=None):
     """Prefill ONE chunk of a long prompt; decode interleaves between chunks.
 
     tokens: [1, C] (the chunk, right-padded on the final chunk); start: row
@@ -546,8 +564,9 @@ def prefill_chunk_step(cfg: ModelConfig, params, cache, tokens, start,
     with lora_context(lora_idx):
         attend = _sa.make_chunk_prefill_attend_select(
             cfg, pages, start, chunk_len) if cfg.selects \
-            else make_chunk_prefill_attend_paged_carry(
-                pages, start, window=cfg.sliding_window)
+            else _attend(
+                cfg, lambda t, w, _: make_chunk_prefill_attend_paged_carry(
+                    t, start, window=w), pages, wpages)
         logits, cache = model_forward_carry(
             params, cfg, tokens, positions, cache, attend,
             _recur(cfg, _la.make_recur_span, slot, start, chunk_len),
@@ -593,7 +612,7 @@ def decode_steps(cfg: ModelConfig, n_steps: int, params, cache, tokens,
                  penalties: bool = False, seeds=None,
                  ban_ids=None, ban_until=None, bias_ids=None,
                  bias_vals=None, allow=None, lora_idx=None,
-                 bblock: int = 1, live=None):
+                 bblock: int = 1, live=None, wtable=None):
     """``n_steps`` fused decode steps for every slot, one device dispatch.
 
     tokens/lengths/sampling params: [B]; ``cache`` is the paged pool and
@@ -630,9 +649,10 @@ def decode_steps(cfg: ModelConfig, n_steps: int, params, cache, tokens,
         attend = _sa.make_decode_attend_select(
             cfg, lens, table, impl=impl, bblock=bblock, live=live) \
             if cfg.selects \
-            else make_decode_attend_carry_paged(
-                lens, table, impl=impl, mesh=mesh, window=cfg.sliding_window,
-                bblock=bblock)
+            else _attend(
+                cfg, lambda t, w, kind: make_decode_attend_carry_paged(
+                    lens, t, impl=impl, mesh=mesh, window=w, bblock=bblock,
+                    of_window_kind=kind), table, wtable)
         with _moe.routed_rows(live) as routing, _sa.counting() as picked:
             logits, cache = model_forward_carry(
                 params, cfg, tok[:, None], positions, cache, attend,
@@ -700,7 +720,7 @@ def mixed_step(cfg: ModelConfig, params, cache, tokens, lengths, ptokens,
                penalties: bool = False, seeds=None,
                ban_ids=None, ban_until=None, bias_ids=None, bias_vals=None,
                allow=None, pallow=None, lora_idx=None, bblock: int = 1,
-               live=None):
+               live=None, wtable=None):
     """ONE ragged dispatch serving a mixed batch: a decode step for every
     active slot AND one prefill chunk of slot ``pslot`` — the program that
     lets the one-deep pipeline ride across prefill admissions instead of
@@ -761,8 +781,14 @@ def mixed_step(cfg: ModelConfig, params, cache, tokens, lengths, ptokens,
     row_limits = jnp.concatenate(
         [jnp.where(is_p, jnp.int32(0), lengths + 1),
          jnp.where(is_pad, jnp.int32(0), crows + 1)])
-    row_tables = None if cfg.selects else jnp.concatenate(
+    # a table row a packed row; ONE a slot and a row map where the kernel
+    # takes that (a selecting model's, a list with window layers')
+    row_tables = None if cfg.selects or cfg.windowed else jnp.concatenate(
         [table, jnp.broadcast_to(table[pslot][None], (C, table.shape[1]))])
+    row_map = jnp.concatenate(
+        [jnp.arange(B, dtype=jnp.int32),
+         jnp.broadcast_to(pslot.astype(jnp.int32), (C,))]) \
+        if cfg.windowed else None
     packed = jnp.concatenate([tokens[None], ptokens], axis=1)     # [1, B+C]
     positions = jnp.concatenate(
         [jnp.where(is_p, jnp.int32(0), lengths)[None], crows[None]], axis=1)
@@ -773,10 +799,12 @@ def mixed_step(cfg: ModelConfig, params, cache, tokens, lengths, ptokens,
             cfg, jnp.where(is_p, jnp.int32(-1), lengths), pstart, plen,
             row_limits, table, pslot, impl=impl, bblock=bblock, live=live)
     else:
-        attend = make_mixed_attend_carry_paged(
-            jnp.where(is_p, jnp.int32(-1), lengths), pstart, plen,
-            row_limits, row_tables, impl=impl, mesh=mesh,
-            window=cfg.sliding_window, bblock=bblock)
+        attend = _attend(
+            cfg, lambda t, w, kind: make_mixed_attend_carry_paged(
+                jnp.where(is_p, jnp.int32(-1), lengths), pstart, plen,
+                row_limits, t if cfg.windowed else row_tables, impl=impl,
+                mesh=mesh, window=w, bblock=bblock, row_map=row_map,
+                of_window_kind=kind), table, wtable)
     # Per-TOKEN adapter indices over the packed layout: decode row b keeps
     # its slot's adapter, every chunk row runs the chunking slot's — one
     # program serves any adapter mix (models/layers._linear gathers factors
@@ -871,7 +899,7 @@ def spec_decode_step(cfg: ModelConfig, R: int, params, cache, tokens,
     positions = lengths[:, None] + jnp.arange(R, dtype=jnp.int32)[None, :]
     attend = make_spec_attend_carry_paged(lengths, table, impl=impl,
                                           mesh=mesh,
-                                          window=cfg.sliding_window,
+                                          window=cfg.attn_window,
                                           bblock=bblock)
     with lora_context(lora_idx):
         logits, cache = model_forward_carry(params, cfg, tokens, positions,
@@ -1035,13 +1063,33 @@ class EnginePrograms:
         """What cannot be right yet for a model with recurrent layers, an
         expert share or selecting attention is refused at start-up, each
         with its reason."""
-        if not (cfg.recurrent or cfg.expert_share or cfg.selects):
+        if not (cfg.recurrent or cfg.expert_share or cfg.selects
+                or cfg.windowed):
             return
         what = f"recurrent ({cfg.recurrent_kinds}) layers" if cfg.recurrent \
             else "an expert share" if cfg.expert_share \
-            else "attention that selects its pages"
+            else "attention that selects its pages" if cfg.selects \
+            else "window layers beside full ones"
         multi = mesh is not None or serving.mesh.num_devices > 1
         for bad, why in (
+                (cfg.windowed and multi,
+                 "--tp/--dp > 1: the window layers' page inventory has no "
+                 "partition by dp group and its leaves no sharding rule"),
+                (cfg.windowed and serving.spec_decode,
+                 "speculative decoding: the verify program takes one table "
+                 "a slot, and the window layers have their own"),
+                (cfg.windowed and bool(lora),
+                 "LoRA adapters: a prefix is salted by its adapter, and a "
+                 "model that releases pages behind its window keeps no "
+                 "prefix index"),
+                (cfg.windowed and serving.kv_host_tier_bytes > 0,
+                 "the host KV tier (--kv-host-tier-bytes > 0): a spilled "
+                 "page would carry the full layers' K/V without the window "
+                 "layers' pages, which are gone"),
+                (cfg.windowed and serving.kv_dtype == "int8",
+                 "int8 KV: the window layers' leaves have no scale leaves "
+                 "and the one-table-row-a-slot ragged kernel reads a bf16 "
+                 "pool"),
                 (cfg.selects
                  and serving.page_size != cfg.sparse_block_size,
                  f"--page-size {serving.page_size}: its attention selects "
@@ -1277,6 +1325,13 @@ class EnginePrograms:
         # land in a page another slot owns.
         self._group_pages = group_pages + 1     # pool partition size
         total_pages = self.dp_groups * self._group_pages
+        # A list with window layers beside full ones: the window layers'
+        # K/V live in leaves, a table a slot and an inventory of their own,
+        # sized by what a slot can hold AT MOST (kv_pool.window_inventory)
+        self._win_slot_pages, self.win_pages = kvp.window_inventory(
+            cfg, self.num_slots, self.pages_per_slot, ps,
+            max(1, serving.decode_horizon),
+            serving.prefill_chunk or max(self.buckets))
         if self.mesh is not None:
             # born sharded (pages over dp, heads over tp): no device ever
             # holds the full pool. Building it whole and re-sharding with
@@ -1296,8 +1351,10 @@ class EnginePrograms:
                 out_shardings=out_sh)()
         else:
             self.cache = kvp.init_pool(cfg, total_pages, ps, dtype,
-                                       quant=self.kv_quant)
-        pool_leaves = dict(self.cache)
+                                       quant=self.kv_quant,
+                                       win_pages=self.win_pages)
+        pool_leaves = {n: a for n, a in self.cache.items()
+                       if n not in kvp.WINDOW_LEAVES.values()}
         # Recurrent layers keep per-SLOT state beside the pool, in the same
         # pytree the step programs donate (ops/linear_attention.py)
         self.kda_state_bytes = _la.state_bytes(cfg, self.num_slots, dtype)
@@ -1316,9 +1373,42 @@ class EnginePrograms:
                 self.selector_bytes / 2**30,
                 self.kda_state_bytes / 2**30, cfg.num_recurrent_layers,
                 cfg.recurrent_kinds or "recurrent", self.num_slots)
+        if cfg.windowed:
+            import logging
+
+            logging.getLogger(__name__).info(
+                "cache: KV pool %.3f GiB — the %d full layers' inventory "
+                "%.3f GiB (%d pages: %d slots x %d) + the %d window layers' "
+                "%.3f GiB (%d pages: %d a slot of window %d, the chunk on "
+                "top); one inventory and table a slot would hold %.3f GiB",
+                kvp.pool_bytes(cfg, total_pages, ps, dtype,
+                               win_pages=self.win_pages) / 2**30,
+                cfg.num_attn_layers,
+                kvp.pool_bytes(cfg, total_pages, ps, dtype) / 2**30,
+                total_pages, self.num_slots, self.pages_per_slot,
+                cfg.num_window_layers,
+                kvp.pool_bytes(cfg, 0, ps, dtype,
+                               win_pages=self.win_pages) / 2**30,
+                self.win_pages, self._win_slot_pages, cfg.sliding_window,
+                kvp.pool_bytes(cfg, total_pages, ps, dtype) / 2**30
+                * cfg.num_layers / max(1, cfg.num_attn_layers))
         self.allocators = [pkv.PagePool(self._group_pages, ps,
                                         first_page=1)
                            for _ in range(self.dp_groups)]
+        # the window layers' inventory (page 0 its scratch page) and, a
+        # slot, the pages it holds there: logical pages [_wfirst[slot],
+        # _wfirst[slot] + len(_slot_wpages[slot])) — what lies below went
+        # back, and its table entries read scratch
+        self.win_allocator = pkv.PagePool(self.win_pages, ps, first_page=1) \
+            if cfg.windowed else None
+        self.wtable = np.zeros((self.num_slots, self.pages_per_slot),
+                               np.int32) if cfg.windowed else None
+        self._slot_wpages: List[List[int]] = [[] for _ in
+                                              range(self.num_slots)]
+        self._wfirst = np.zeros(self.num_slots, np.int64)
+        self._win_unreleased = 0    # sum of the slots' contexts, in pages
+        _metrics.window_pool.reset(
+            self.win_pages - 1 if cfg.windowed else 0)
         # Tier-2 KV (ISSUE 20): ONE host-RAM store shared by every dp
         # group's allocator — chain-hash keys are group-agnostic, so a
         # prefix evicted from one group's partition can restore into any
@@ -1491,6 +1581,17 @@ class EnginePrograms:
             return {}
         return {name: jnp.asarray(value, jnp.int32)}
 
+    def _win_kw(self, name: str, rows=None) -> dict:
+        """The window layers' table operand of a step program (``rows``: the
+        slots whose rows it takes, a slot or an array of them; None = every
+        slot's, from the operand cache); no operand at all for a model
+        without window layers beside full ones."""
+        if not self.cfg.windowed:
+            return {}
+        if rows is None:
+            return {name: self._decode_operands()["wtable"]}
+        return {name: self._donatable(self.wtable[rows])}
+
     def _kda_rows(self, rows: int, slots: int = 0) -> dict:
         """Dispatch-record fields of a model with recurrent layers:
         ``state_rows`` (rows that advance a state in this dispatch, per
@@ -1517,11 +1618,15 @@ class EnginePrograms:
         dp shard's rows in one ascending order (ops/attention._length_order;
         where the device takes no order, blocks of one row or one block,
         the sum is the same in any order). Every slot counts: the kernel
-        walks an idle slot's row too. Their ratio is the walk's fill."""
+        walks an idle slot's row too. Their ratio is the walk's fill. A
+        list with window layers beside full ones: those two are the FULL
+        layers' and ``win_pages_live`` / ``win_pages_walked`` the window
+        layers' (per window layer), beside ``attn_layers_full`` /
+        ``attn_layers_window``."""
         from aws_k8s_ansible_provisioner_tpu.ops.pallas_attention import (
             _resolve_bb)
 
-        ps, window = self.serving.page_size, self.cfg.sliding_window
+        ps = self.serving.page_size
         dp = self.mesh.shape.get("dp", 1) if self.mesh is not None else 1
         bb = _resolve_bb(self.decode_bblock, self.num_slots // dp)
         # [substep, dp shard, row]: the live columns of each kernel row
@@ -1529,13 +1634,35 @@ class EnginePrograms:
             (self.lengths.astype(np.int64) + carry_steps + 1
              + np.arange(horizon)[:, None]).reshape(horizon, dp, -1), axis=-1)
         hi = np.minimum(-(-limits // ps), self.pages_per_slot)
-        lo = np.maximum(limits - window, 0) // ps if window > 0 \
-            else np.zeros_like(hi)
         blocks = (horizon, -1, bb)
-        walked = bb * (hi.reshape(blocks)[..., -1]
-                       - lo.reshape(blocks)[..., 0])
-        return {"attn_pages_live": int((hi - lo).sum()),
-                "attn_pages_walked": int(walked.sum())}
+
+        def pages(window: int):
+            lo = np.maximum(limits - window, 0) // ps if window > 0 \
+                else np.zeros_like(hi)
+            walked = bb * (hi.reshape(blocks)[..., -1]
+                           - lo.reshape(blocks)[..., 0])
+            return int((hi - lo).sum()), int(walked.sum())
+
+        live, walked = pages(self.cfg.attn_window)
+        out = {"attn_pages_live": live, "attn_pages_walked": walked}
+        if self.cfg.windowed:
+            live, walked = pages(self.cfg.sliding_window)
+            out.update(win_pages_live=live, win_pages_walked=walked,
+                       **self._attn_layers())
+        return out
+
+    def _attn_layers(self) -> dict:
+        """Dispatch-record fields of a list with window layers beside full
+        ones: how many attending layers of each kind a forward pass runs,
+        and what the window inventory holds as the dispatch leaves — its
+        pages in use beside the pages the same slots would hold for those
+        layers with nothing released (their contexts)."""
+        if not self.cfg.windowed:
+            return {}
+        return {"attn_layers_full": self.cfg.num_attn_layers,
+                "attn_layers_window": self.cfg.num_window_layers,
+                "win_pages_held": int(self.win_allocator.pages_in_use),
+                "win_pages_unreleased": int(self._win_unreleased)}
 
     def _live_rows(self, active):
         """[B] bool device mask of the decode rows that hold a request, for
@@ -1661,6 +1788,11 @@ class EnginePrograms:
             self.metrics.decode_attn_pages.inc(rec["attn_pages_live"],
                                                kind="live")
             self.metrics.decode_attn_pages.inc(rec["attn_pages_walked"],
+                                               kind="walked")
+        if "win_pages_live" in rec:
+            self.metrics.window_attn_pages.inc(rec["win_pages_live"],
+                                               kind="live")
+            self.metrics.window_attn_pages.inc(rec["win_pages_walked"],
                                                kind="walked")
         if "sample_rows" in rec:
             self.metrics.sample_dispatches.inc(
@@ -1801,6 +1933,8 @@ class EnginePrograms:
         tokens = np.zeros((1, bucket), np.int32)
         tokens[0, :len(ids)] = ids
         self._fill_sampling_rows(req, slot)
+        if self.cfg.windowed:
+            self._win_cover(slot, len(ids), len(ids))
         args = (jnp.asarray(tokens), jnp.int32(len(ids)), self._next_rng(),
                 jnp.float32(req.temperature), jnp.int32(req.top_k),
                 jnp.float32(req.top_p))
@@ -1817,7 +1951,7 @@ class EnginePrograms:
             lora_idx=(jnp.asarray(self.lora_idx[slot:slot + 1])
                       if self.lora_names else None),
             prompt_logprobs=req.prompt_logprobs is not None,
-            **self._state_kw("slot", slot))
+            **self._state_kw("slot", slot), **self._win_kw("wpages", slot))
         drec = self._dispatch_open(
             "prefill_step", "prefill", bucket=bucket,
             prompt_tokens=len(ids), padded_tokens=bucket,
@@ -1859,6 +1993,7 @@ class EnginePrograms:
         # table: their cache writes drop
         slots = np.full(n_bucket, self.num_slots, np.int32)
         tb = np.full((n_bucket, self.pages_per_slot), kvp.OOB_PAGE, np.int32)
+        wtb = tb.copy() if self.cfg.windowed else None
         temps = np.zeros(n_bucket, np.float32)
         top_ks = np.zeros(n_bucket, np.int32)
         top_ps = np.ones(n_bucket, np.float32)
@@ -1869,6 +2004,9 @@ class EnginePrograms:
             true_lens[i] = len(ids)
             slots[i] = slot
             tb[i] = self.table[slot]
+            if wtb is not None:
+                self._win_cover(slot, len(ids), len(ids))
+                wtb[i] = self.wtable[slot]
             temps[i] = req.temperature
             top_ks[i] = req.top_k
             top_ps[i] = req.top_p
@@ -1910,7 +2048,8 @@ class EnginePrograms:
             reps=jnp.asarray(reps), allow=allow,
             lora_idx=(jnp.asarray(row_lora) if self.lora_names
                       else None),
-            prompt_logprobs=want_plp, **self._state_kw("slots", slots))
+            prompt_logprobs=want_plp, **self._state_kw("slots", slots),
+            **({} if wtb is None else {"wtables": jnp.asarray(wtb)}))
         n_prompt = int(true_lens[:len(batch)].sum())
         drec = self._dispatch_open(
             "prefill_batch_step", "prefill_batch", rows=n_bucket,
@@ -1967,9 +2106,13 @@ class EnginePrograms:
                  and (req.guided is None
                       or self.serving.ragged_features > 0)
                  # (a model whose attention selects has ONE chunk program
-                 # that reads a long window: the ragged one)
+                 # that reads a long window: the ragged one; so has a list
+                 # with window layers — the plain chunk program attends
+                 # over a gather of the slot's WHOLE page run, 4.8 GB of
+                 # logits for a 4,096-row chunk of a 9,216-token slot)
                  and (self._inflight is not None
-                      or bool(self._active_slots()) or self.cfg.selects))
+                      or bool(self._active_slots()) or self.cfg.selects
+                      or self.cfg.windowed))
         if not mixed:
             # chunking rewrites the slot's length out of band of any decode
             # carry (admission already drained the pipeline; belt-and-braces)
@@ -2042,6 +2185,8 @@ class EnginePrograms:
         final_lp = (req.logprobs is not None and not st["resumed"]
                     and off + len(chunk) >= len(ids))
         lp_t = None
+        if self.cfg.windowed:
+            self._win_cover(slot, off, off + len(chunk))
         try:
             args = (jnp.asarray(tokens), jnp.int32(off),
                     jnp.int32(len(chunk)), self._next_rng(),
@@ -2060,7 +2205,8 @@ class EnginePrograms:
                 allow=self._allow_row(req),
                 lora_idx=(jnp.asarray(self.lora_idx[slot:slot + 1])
                           if self.lora_names else None),
-                **self._state_kw("slot", slot))
+                **self._state_kw("slot", slot),
+                **self._win_kw("wpages", slot))
             drec = self._dispatch_open(
                 "prefill_chunk_step", "prefill_chunk", chunk_rows=C,
                 chunk_n=len(chunk), chunk_off=off, padded_tokens=C,
@@ -2224,6 +2370,8 @@ class EnginePrograms:
         tokens[0, :len(chunk)] = chunk
         allow = self._allow_words(gslots)
         pallow = self._allow_row(req)
+        if self.cfg.windowed:
+            self._win_cover(slot, off, off + len(chunk))
         oc = self._decode_operands()
         args = (jnp.asarray(tokens), jnp.int32(slot), jnp.int32(off),
                 jnp.int32(len(chunk)),
@@ -2243,7 +2391,8 @@ class EnginePrograms:
             sample_rows=int((self.temps > 0).sum()
                             + (req.temperature > 0)),
             carry_steps=prev["horizon"] if prev is not None else 0,
-            **self._kda_rows(len(active) + len(chunk), len(active)))
+            **self._kda_rows(len(active) + len(chunk), len(active)),
+            **self._attn_layers())
         self._book_bubble(drec["t_enqueue"])
         real_counts = self.counts
         with _Dispatching(drec):
@@ -2267,7 +2416,7 @@ class EnginePrograms:
                 pallow=pallow,
                 lora_idx=oc["lora"],
                 bblock=self.decode_bblock,
-                live=self._live_rows(active))
+                live=self._live_rows(active), **self._win_kw("wtable"))
         self.counts = new_counts if want_pen else real_counts
         self._pipe_carry = (tok, lens, self._carry_gen)
         _metrics.pipeline.dispatches.inc()
@@ -2581,6 +2730,13 @@ class EnginePrograms:
             self._op_dirty_sampling = False
         if self._op_dirty_table or "table" not in oc:
             oc["table"] = jnp.asarray(self.table)
+            if self.cfg.windowed:
+                # a COPY (_donatable's reason): Engine._win_cover rewrites
+                # entries in place — a released page's reads scratch, then
+                # another slot's page — while a dispatch that took the
+                # table may still be in flight, and on the CPU backend
+                # jnp.asarray of the mirror is a view of it
+                oc["wtable"] = self._donatable(self.wtable)
             self._op_dirty_table = False
         return oc
 
@@ -2811,7 +2967,7 @@ class EnginePrograms:
                 allow=allow,
                 lora_idx=oc["lora"],
                 bblock=self.decode_bblock,
-                live=self._live_rows(active))
+                live=self._live_rows(active), **self._win_kw("wtable"))
         # un-penalized dispatches return a dummy counts array — keep ours
         self.counts = new_counts if want_pen else real_counts
         self._pipe_carry = (tok, lens, self._carry_gen)
@@ -3090,7 +3246,7 @@ class EnginePrograms:
                     bias_vals=jnp.asarray(self.bias_vals),
                     lora_idx=self._lora_vec(),
                     bblock=self.decode_bblock,
-                    live=self._live_rows(()))
+                    live=self._live_rows(()), **self._win_kw("wtable"))
             return
 
         # Distinct token values per warmup request — identical prompts would
@@ -3181,7 +3337,7 @@ class EnginePrograms:
             bias_vals=jnp.asarray(self.bias_vals),
                     lora_idx=self._lora_vec(),
                     bblock=self.decode_bblock,
-                    live=self._live_rows(()))
+                    live=self._live_rows(()), **self._win_kw("wtable"))
         del cnts, mask
         # Logprobs program variants ('logprobs' is a static arg on every step
         # fn — distinct programs): one isolated request compiles the
@@ -3221,4 +3377,4 @@ class EnginePrograms:
             bias_vals=jnp.asarray(self.bias_vals),
                     lora_idx=self._lora_vec(),
                     bblock=self.decode_bblock,
-                    live=self._live_rows(()))
+                    live=self._live_rows(()), **self._win_kw("wtable"))

@@ -874,7 +874,8 @@ class RouterHandler(BaseHTTPRequestHandler):
                     + autoscaler.metrics.registry.render(om)
                     + metrics.pipeline.registry.render(om)
                     + metrics.compile_stages.registry.render(om)
-                    + metrics.params_by_part.registry.render(om))
+                    + metrics.params_by_part.registry.render(om)
+                    + metrics.window_pool.registry.render(om))
             if om:
                 text += "# EOF\n"
                 ctype = ("application/openmetrics-text; version=1.0.0; "

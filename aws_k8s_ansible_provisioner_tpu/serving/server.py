@@ -352,6 +352,7 @@ class Handler(BaseHTTPRequestHandler):
                     + metrics.pipeline.registry.render(om)
                     + metrics.compile_stages.registry.render(om)
                     + metrics.params_by_part.registry.render(om)
+                    + metrics.window_pool.registry.render(om)
                     + render_engine_chips())
             if om:
                 text += "# EOF\n"
@@ -1665,6 +1666,14 @@ def build_state(serving_cfg=None, model_cfg=None, params=None,
                                   sparse_kernel_stride=ps // 4,
                                   sparse_window_size=2 * ps,
                                   sparse_dense_len=4 * ps)
+        elif serving.model == "tiny-trinity":
+            # the dry-run list with window ("w") layers beside full ones,
+            # leading dense FFNs, then routed ones with a shared expert
+            from aws_k8s_ansible_provisioner_tpu.config import tiny_trinity
+
+            model_cfg = tiny_trinity(vocab_size=tokenizer.vocab_size,
+                                     eos_token_id=tokenizer.eos_token_id,
+                                     sliding_window=2 * serving.page_size)
         else:
             raise ValueError(f"unknown model {serving.model!r} and no checkpoint")
 
@@ -1836,6 +1845,15 @@ def build_parser() -> argparse.ArgumentParser:
                         "hiding host emit/SSE time behind device compute "
                         "(seeded streams stay byte-identical). 0 restores "
                         "the synchronous dispatch-fetch-emit loop")
+    p.add_argument("--decode-horizon", type=int, default=8,
+                   help="tokens a fused decode dispatch generates when no "
+                        "prompt waits (ServingConfig.decode_horizon). A "
+                        "caller that comes back while one runs waits for "
+                        "its end before it is admitted, and callers whose "
+                        "streams end in the same dispatch come back "
+                        "together: a model with a slow step takes fewer "
+                        "(two steps already hide the host's work behind "
+                        "the device's)")
     p.add_argument("--ragged-attention", type=int, default=1,
                    help="ragged mixed-batch attention: chunked prefill "
                         "packs into the SAME dispatch as the decode batch "
@@ -1985,6 +2003,7 @@ def serving_config_from_args(args):
         max_cache_len=args.max_cache_len, dtype=args.dtype,
         kv_dtype=args.kv_dtype, weights_dtype=args.weights_dtype,
         decode_bblock=args.decode_bblock,
+        decode_horizon=args.decode_horizon,
         decode_pipeline=args.decode_pipeline,
         ragged_attention=args.ragged_attention,
         ragged_features=args.ragged_features,

@@ -369,12 +369,17 @@ def run_requests(srv: Server, model: str) -> dict:
                     dict(body, stream=True, logprobs=0))
     t_mixed = time.monotonic()
     cfg = srv.engine.cfg
-    if cfg.selects:
+    if cfg.selects or cfg.windowed:
         # a prompt PAST the dense length, several chunks of mixed_step under
         # the live stream: every later chunk's rows select their pages, the
         # Lightning state is handed over between chunks, and the 24 generated
-        # tokens read 64 selected pages each
-        n_long = cfg.sparse_dense_len + cfg.sparse_dense_len // 8 + 37
+        # tokens read 64 selected pages each. A list with window layers:
+        # three windows and a bit (two chunks at the served chunk), so that
+        # pages of the window layers are released between its chunks and
+        # AGAIN among its 24 generated tokens, which also cross a page
+        n_long = cfg.sparse_dense_len + cfg.sparse_dense_len // 8 + 37 \
+            if cfg.selects \
+            else 3 * cfg.sliding_window + cfg.sliding_window // 8 + 53
         rl = http_stream(port, "/v1/completions", {
             "model": model, "prompt": prompt_of(n_long, 77), "stream": True,
             "max_tokens": 24, "temperature": 0.0, "ignore_eos": True,
@@ -389,8 +394,10 @@ def run_requests(srv: Server, model: str) -> dict:
                             "token_ids": rl["token_ids"],
                             "logprobs": rl["logprobs"]}
         expected += 24
-        say(f"requests: a {n_long}-token prompt past the dense length "
-            f"({cfg.sparse_dense_len}) beside the live stream ok "
+        say(f"requests: a {n_long}-token prompt past the "
+            + (f"dense length ({cfg.sparse_dense_len})" if cfg.selects
+               else f"window ({cfg.sliding_window}) three times over")
+            + f" beside the live stream ok "
             f"({time.monotonic() - t_mixed:.2f}s)")
     # the one request of this script that DRAWS: until here every sampler
     # call took the all-greedy side of its gate (ops/sampling.sample); this
@@ -473,17 +480,34 @@ def run_requests(srv: Server, model: str) -> dict:
         key = f'tpu_serve_request_total{{status="{bad}"}}'
         check(delta(key) == 0, f"{key} moved by {delta(key)}")
     hits = delta("tpu_serve_prefix_cache_hits_total")
-    if srv.engine.cfg.recurrent:
+    if srv.engine.cfg.recurrent or srv.engine.cfg.windowed:
         # K/V pages restored without the recurrent state that goes with
         # them would be wrong: such a model is never handed a prefix hit
+        # (nor is one whose window layers' pages went back)
         skipped = sum(v for k, v in after.items() if k.startswith(
             "tpu_serve_prefix_lookups_skipped_total"))
         check(hits == 0 and skipped >= 1,
-              f"a model with recurrent layers read {hits} prefix hit(s); "
-              f"lookups skipped {skipped}")
+              f"a model with recurrent layers or released window pages read "
+              f"{hits} prefix hit(s); lookups skipped {skipped}")
     else:
         check(hits >= 1,
               "the repeated long prompt did not hit the prefix cache")
+    if srv.engine.cfg.windowed:
+        peak = after.get("tpu_serve_kv_window_pages_slot_peak", 0.0)
+        eng = srv.engine
+        bound = -(-(eng.cfg.sliding_window + eng._chunk_size)
+                  // eng.serving.page_size) + 1
+        check(0 < peak <= bound
+              and after.get("tpu_serve_kv_window_pages_released_total", 0) > 0,
+              f"a slot held {peak} pages of the window layers (bound: window "
+              f"+ chunk + a page = {bound})")
+        say(f"window inventory: {int(after['tpu_serve_kv_window_pages_total'])}"
+            f" pages; a slot held at most {int(peak)} (bound {bound}); "
+            f"{int(after['tpu_serve_kv_window_pages_released_total'])} pages "
+            f"released; at its fullest "
+            f"{int(after['tpu_serve_kv_window_pages_in_use_peak'])} pages "
+            f"held where nothing released would hold "
+            f"{int(after['tpu_serve_kv_window_pages_unreleased_at_peak'])}")
 
     status, raw = http_json(port, "GET", "/healthz")
     check(status == 200, f"/healthz -> {status}")
@@ -1212,6 +1236,27 @@ def check_selection_cause(cfg, params, plain, T: int,
               "selector")
 
 
+def _handed_route(own, handed, use, picked: list):
+    """``ops.moe.route`` wrapped for one trace of an unrolled forward pass:
+    records each routed layer's own choices in ``picked`` and, where ``use``
+    is set, takes ``handed[layer]`` instead — weighted by the program's own
+    sigmoid scores of them, renormalised, times the model's route scale."""
+    import jax
+    import jax.numpy as jnp
+
+    def route(c, x, kernel, bias=None):
+        w, idx = own(c, x, kernel, bias)
+        theirs = handed[len(picked)]
+        picked.append(idx)
+        s = jax.nn.sigmoid(x.astype(jnp.float32) @ kernel.astype(jnp.float32))
+        wt = jnp.take_along_axis(s, theirs, axis=-1)
+        wt = (wt / (wt.sum(axis=-1, keepdims=True) + 1e-20)
+              * c.route_scale).astype(w.dtype)
+        return jnp.where(use, wt, w), jnp.where(use, theirs, idx)
+
+    return route
+
+
 def handed_routing_program(cfg, T: int):
     """The program's own stateless forward over one T-token sequence
     (bfloat16, the served tree, ``kda_span`` from zero, the expert form the
@@ -1229,18 +1274,7 @@ def handed_routing_program(cfg, T: int):
 
     def program(tree, toks, handed, use):
         picked, own = [], moe.route
-
-        def route(c, x, kernel, bias=None):
-            w, idx = own(c, x, kernel, bias)
-            theirs = handed[len(picked)]
-            picked.append(idx)
-            s = jax.nn.sigmoid(x.astype(jnp.float32)
-                               @ kernel.astype(jnp.float32))
-            wt = jnp.take_along_axis(s, theirs, axis=-1)
-            wt = (wt / wt.sum(axis=-1, keepdims=True)).astype(w.dtype)
-            return jnp.where(use, wt, w), jnp.where(use, theirs, idx)
-
-        moe.route = route
+        moe.route = _handed_route(own, handed, use, picked)
         try:
             pos = jnp.arange(T, dtype=jnp.int32)[None]
             x, cos, sin = L._embed_inputs(tree, cfg, toks[None], pos)
@@ -1413,6 +1447,19 @@ def check_lower_precision(name: str, stream: dict, cfg, params, tokenizer,
     held against the served stream by the same two limits, has to come out
     NOT correct — else the limits would pass a server computing in that
     type. Returns {control: refused}."""
+    refused = check_controls(
+        name, stream, cfg, params, tokenizer, plain,
+        {f"lower={lower!r}": dict(lower=lower) for lower in ("state", "act")})
+    return {label.split("'")[1]: no for label, no in refused.items()}
+
+
+def check_controls(name: str, stream: dict, cfg, params, tokenizer, plain,
+                   controls: dict) -> dict:
+    """check_lower_precision for any instrument of a reference: each of
+    ``controls`` ({label: keyword arguments of ``plain.logprobs``} — a
+    mechanism left out, a precision lowered), held against the served
+    stream by the same two limits, has to come out NOT correct. Returns
+    {label: refused}."""
     import dataclasses
 
     import numpy as np
@@ -1422,19 +1469,255 @@ def check_lower_precision(name: str, stream: dict, cfg, params, tokenizer,
         + [int(t) for t in stream["token_ids"]]
     n = len(stream["token_ids"])
     at, tok = np.arange(n), np.asarray(stream["token_ids"])
-    for lower in ("state", "act"):
-        rows = plain.logprobs(dataclasses.asdict(cfg), params, ids, n,
-                              lower=lower)
+    for label, kw in controls.items():
+        rows = plain.logprobs(dataclasses.asdict(cfg), params, ids, n, **kw)
         gap = float(np.max(rows.max(axis=-1) - rows[at, tok]))
         agree = float(np.max(np.abs(np.asarray(stream["logprobs"])
                                     - rows[at, tok])))
-        refused[lower] = gap > NEAR_MAX_NATS or agree > LOGPROB_NATS
-        say(f"control[{name}, reference with lower={lower!r}]: served token "
-            f"below its maximum by <= {gap:.4f} nats (tol {NEAR_MAX_NATS}); "
-            f"served vs lowered reference differ <= {agree:.4f} nats (tol "
+        refused[label] = gap > NEAR_MAX_NATS or agree > LOGPROB_NATS
+        say(f"control[{name}, reference with {label}]: served token below "
+            f"its maximum by <= {gap:.4f} nats (tol {NEAR_MAX_NATS}); served "
+            f"vs that reference differ <= {agree:.4f} nats (tol "
             f"{LOGPROB_NATS}): "
-            f"{'NOT correct' if refused[lower] else 'correct'}")
+            f"{'NOT correct' if refused[label] else 'correct'}")
     return refused
+
+
+def handed_routing_list_program(cfg, T: int):
+    """handed_routing_program for a model whose layers are a LIST with the
+    FFN by layer (window and full attention, dense then routed FFNs): the
+    program's own stateless forward over one T-token sequence (bfloat16,
+    the served tree), unrolled layer by layer with ``ops.moe.route``
+    wrapped. Jitted: (tree, tokens [T], handed [routed layers, T, k], use)
+    -> (logprob rows [T - 1, V] float32, the choices made)."""
+    import jax
+    import jax.numpy as jnp
+
+    from aws_k8s_ansible_provisioner_tpu.models import layers as L
+    from aws_k8s_ansible_provisioner_tpu.ops import moe
+
+    nd = cfg.num_dense_layers
+
+    def program(tree, toks, handed, use):
+        picked, own = [], moe.route
+        moe.route = _handed_route(own, handed, use, picked)
+        try:
+            pos = jnp.arange(T, dtype=jnp.int32)[None]
+            x, cos, sin = L._embed_inputs(tree, cfg, toks[None], pos)
+            attend = L.make_default_attend(cfg)
+
+            def stateless(fn):
+                return lambda q, kk, v, cl: (fn(q, kk, v, None)[0], cl)
+
+            for i, kind in enumerate(cfg.layer_pattern):
+                lp = jax.tree.map(lambda a: a[i], tree["layers"]["attn"])
+                stack, j = ("ffn_moe", i - nd) if i >= nd \
+                    else ("ffn_dense", i)
+                fp = jax.tree.map(lambda a: a[j], tree["layers"][stack])
+                x, _ = L.decoder_block(
+                    cfg, lp, x, cos, sin,
+                    stateless(attend.window if kind == "w" else attend),
+                    None, ffn=fp, rope=True if kind == "w" else None)
+            logits = L._final_logits(tree, cfg, x)[0].astype(jnp.float32)
+        finally:
+            moe.route = own
+        return jax.nn.log_softmax(logits, axis=-1)[:-1], jnp.stack(picked)
+
+    return jax.jit(program)
+
+
+def check_list_routing_cause(cfg, params, plain, rows: int,
+                             strict: bool = True) -> None:
+    """check_routing_cause for the Trinity list, whose routed branches the
+    maker keeps at the other branches' gain: the reference routes on
+    float32 activations and the program on bfloat16 ones, so a token's
+    8th and 9th of 128 scores + bias flip between them in 7 % of
+    token-layers at the first routed layer and 21 % at the last (my chip
+    run, PR 39) — and because the maker's router is top-heavy (its
+    docstring) a flip swaps 2-4 % of a routed sum, not an eighth. Here the
+    routing is HANDED over both ways: what is left has to be inside the
+    limit on either routing, and under handed routing the reference with
+    ``route_scale`` 1, the program handed a WRONG expert (an id shifted by
+    one) and one expert DROPPED (its down scale zeroed in every layer) have
+    each to be outside it. Distances as check_routing_cause's."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    T = rows
+    mc = dataclasses.asdict(cfg)
+    k, E, nd = cfg.num_experts_per_tok, cfg.num_experts, cfg.num_dense_layers
+    n_moe = cfg.num_layers - nd
+
+    def without(tree, drop):
+        lay = dict(tree["layers"])
+        ffn = dict(lay["ffn_moe"])
+        sc = ffn["w_down"]["scale"]                      # [n_moe, E, H]
+        ffn["w_down"] = dict(ffn["w_down"], scale=sc * jnp.where(
+            jnp.arange(E)[:, None] == drop, 0.0, 1.0))
+        lay["ffn_moe"] = ffn
+        return dict(tree, layers=lay)
+
+    program = handed_routing_list_program(cfg, T)
+    ids = np.random.default_rng(20260930).integers(32, 127, T)
+    toks = jnp.asarray(ids, jnp.int32)
+    none = jnp.zeros((n_moe, T, k), jnp.int32)
+    at = np.arange(T - 1)
+
+    def reference(tree, **kw):
+        with jax.default_matmul_precision("highest"):
+            lg, idx = plain.forward(mc, tree, list(ids), T - 1, **kw)
+            return (np.asarray(jax.nn.log_softmax(lg, axis=-1)),
+                    np.asarray(idx))
+
+    def served(tree, handed=None):
+        lp, idx = program(tree, toks, none if handed is None
+                          else jnp.asarray(handed), handed is not None)
+        return np.asarray(lp), np.asarray(idx)
+
+    def apart(a, b, tok):
+        d = np.abs(a[at, tok] - b[at, tok])
+        worst16 = [d[i:i + 16].max() for i in range(0, len(d) - 15)]
+        return float(d.max()), float(np.median(worst16))
+
+    t0 = time.monotonic()
+    hot = params
+    ref_lp, ref_idx = reference(hot)
+    tok = ref_lp.argmax(-1)
+    own_lp, own_idx = served(hot)
+    flips = (np.sort(own_idx, -1) != np.sort(ref_idx, -1)).any(-1).mean(1)
+    d_own = apart(own_lp, ref_lp, tok)
+    d_handed = apart(served(hot, ref_idx)[0], ref_lp, tok)
+    d_back = apart(own_lp, reference(hot, routing=own_idx)[0], tok)
+    say(f"routing cause ({T} tokens): the "
+        f"chosen sets differ in "
+        f"{' '.join(f'{100 * x:.0f}' for x in flips)} % of tokens by routed "
+        f"layer; on their own routing {d_own[0]:.4f} / {d_own[1]:.4f} nats "
+        f"(worst position / median of the worst of 16); the program handed "
+        f"the reference's {d_handed[0]:.4f} / {d_handed[1]:.4f}; the "
+        f"reference handed the program's {d_back[0]:.4f} / {d_back[1]:.4f} "
+        f"({time.monotonic() - t0:.1f}s)")
+    served_handed = served(hot, ref_idx)[0]
+    d_scale = apart(reference(hot, routing=ref_idx,
+                              wrong="route_scale_1")[0], served_handed, tok)
+    busiest = int(np.bincount(ref_idx.reshape(-1), minlength=E).argmax())
+    wrong_idx = np.where(ref_idx == busiest, (busiest + 1) % E, ref_idx)
+    d_wrong = apart(served(hot, wrong_idx)[0], ref_lp, tok)
+    d_drop = apart(served(without(params, busiest), ref_idx)[0],
+                   ref_lp, tok)
+    share = 100 * (ref_idx == busiest).any(-1).mean()
+    say(f"routing cause, under HANDED routing: the reference with "
+        f"route_scale 1 {d_scale[0]:.4f} / {d_scale[1]:.4f} nats from the "
+        f"program; expert {busiest} (chosen in {share:.1f} % of "
+        f"token-layers) computed as its neighbour {d_wrong[0]:.4f} / "
+        f"{d_wrong[1]:.4f}; dropped {d_drop[0]:.4f} / {d_drop[1]:.4f}")
+    if strict:
+        check(max(d_own[0], d_handed[0], d_back[0]) <= LOGPROB_NATS,
+              "the program and the reference are apart on their own or on "
+              "handed routing: the routed branches are at full gain and "
+              "have to be inside the limit either way")
+        check(min(d_scale[1], d_wrong[0], d_drop[0]) > LOGPROB_NATS,
+              "under handed routing route_scale 1, a wrong expert or a "
+              "dropped one stays inside the limit: nothing guards them")
+
+
+def window_kernel_parity(cfg, slots: int, window: int, page: int, bb: int,
+                         chunk: int, interpret: bool) -> None:
+    """The entry points of a list with window AND full layers at the served
+    widths against the jax.numpy reference (ops/attention.decode_attend
+    over kv_pool.gather_layer_dense): the decode kernel under its window
+    name, and the ragged kernel with ONE table row a slot (full, and under
+    the window), on seeded inputs with the pages BELOW each row's window
+    released — their table entries read the scratch page, which holds
+    garbage no row may see."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from aws_k8s_ansible_provisioner_tpu.ops import kv_pool as kvp
+    from aws_k8s_ansible_provisioner_tpu.ops import pallas_attention as pa
+    from aws_k8s_ansible_provisioner_tpu.ops.attention import decode_attend
+
+    Hq, Hkv, D, W = (cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
+                     cfg.sliding_window)
+    B, MP = slots, window // page
+    rng = np.random.default_rng(39)
+    keys = jax.random.split(jax.random.PRNGKey(39), 4)
+    shape = (2, B * MP + 1, Hkv, page, D)
+    pool = {n: jax.random.normal(kk, shape, jnp.bfloat16)
+            for n, kk in zip("kv", keys)}
+    layer = jnp.int32(1)
+    base = [window, 1, page, W + 1, W + page - 1, 3 * W + 77, 2 * W, 7]
+    lens = np.asarray([min(base[i % len(base)], window) for i in range(B)])
+    full = (rng.permutation(B * MP) + 1).reshape(B, MP).astype(np.int32)
+    # the window layers' table: what lies below a row's window went back
+    first = np.maximum(lens - W, 0) // page
+    released = np.where(np.arange(MP)[None] < first[:, None], 0, full)
+
+    def close(name, got, want):
+        got = np.asarray(jnp.asarray(got, jnp.float32))
+        want = np.asarray(jnp.asarray(want, jnp.float32))
+        err = float(np.max(np.abs(got - want)
+                           / (KERNEL_TOL + KERNEL_TOL * np.abs(want))))
+        check(np.all(np.isfinite(got)) and err <= 1.0,
+              f"{name}: off by {err:.2f}x the tolerance")
+        return float(np.max(np.abs(got - want)))
+
+    def want_of(q, limits, tab, w):
+        dense = kvp.gather_layer_dense(pool, layer, jnp.asarray(tab))
+        return decode_attend(q, dense["k"], dense["v"], jnp.asarray(limits),
+                             window=w)
+
+    def want_of_chunk(qc, first_limit, pages, w, block=512):
+        """Consecutive rows of ONE slot (row r sees first_limit + r
+        columns), a block of rows at a time against the slot's gathered
+        view: 4,096 rows at once are 4.8 GB of logits."""
+        dense = kvp.gather_layer_dense(pool, layer, jnp.asarray(pages[None]))
+        out = [decode_attend(qc[None, s:s + block], dense["k"], dense["v"],
+                             jnp.asarray([first_limit + s]), window=w)[0]
+               for s in range(0, qc.shape[0], block)]
+        return jnp.concatenate(out)
+
+    q = jax.random.normal(keys[2], (B, 1, Hq, D), jnp.bfloat16)
+    got = pa.decode_attend_pallas_paged_window(
+        q, pool["k"], pool["v"], jnp.asarray(lens, jnp.int32), layer,
+        jnp.asarray(released), interpret=interpret, window=W, bblock=bb)
+    d_dec = close("decode kernel under the window, pages released", got,
+                  want_of(q, lens, full, W))
+    # the mixed layout: every slot's decode row, then a chunk of slot 5
+    # whose earlier chunks' pages below ITS first row's window went back
+    pslot, C = 5 % B, chunk
+    off = min(2 * W + 19, window - C)
+    crows = off + 1 + np.arange(C)
+    crows[-(C // 5):] = 0                           # the chunk's padding
+    limits = np.concatenate([np.where(np.arange(B) == pslot, 0, lens),
+                             crows]).astype(np.int32)
+    row_map = np.concatenate([np.arange(B), np.full(C, pslot)]
+                             ).astype(np.int32)
+    wtab = released.copy()
+    wtab[pslot] = np.where(np.arange(MP) < max(off + 1 - W, 0) // page, 0,
+                           full[pslot])
+    qn = jax.random.normal(keys[3], (B + C, Hq, D), jnp.bfloat16)
+    out = {}
+    for name, fn, tab, w, kw in (
+            ("full", pa.ragged_attend_pallas_paged_slots, full, 0, {}),
+            ("window", pa.ragged_attend_pallas_paged_slots_window, wtab, W,
+             {"window": W})):
+        got = fn(qn, pool["k"], pool["v"], jnp.asarray(limits), layer,
+                 jnp.asarray(tab), jnp.asarray(row_map),
+                 interpret=interpret, bblock=bb, **kw)
+        want = jnp.concatenate([
+            want_of(qn[:B, None], limits[:B], full, w)[:, 0],
+            want_of_chunk(qn[B:], off + 1, full[pslot], w)])
+        want = jnp.where((limits > 0)[:, None, None], want, 0)
+        out[name] = close(f"ragged kernel, one table row a slot, {name}",
+                          got, want)
+    say(f"kernel parity (window and full kinds, Hq {Hq} Hkv {Hkv}, {B} slots "
+        f"+ a {C}-row chunk, window {W} of {window}, bblock {bb}): decode "
+        f"under the window max |diff| {d_dec:.4f}; ragged by slot full "
+        f"{out['full']:.4f}, under the window {out['window']:.4f}")
 
 
 def check_shards(engine, n: int) -> None:
@@ -1630,6 +1913,18 @@ def main() -> int:
                               "--prefill-chunk": "128",
                               "--prefill-buckets": "64,128,2048",
                               "--max-decode-slots": "8"}
+        elif _config.MODEL_REGISTRY[model].windowed:
+            # the list with window layers beside full ones, dense then
+            # routed FFNs; the window, the chunk and the cache cut
+            model = "rehearse-trinity"
+            _config.MODEL_REGISTRY[model] = _config.tiny_trinity(
+                name=model, intermediate_size=256, moe_intermediate_size=64,
+                num_heads=4, num_kv_heads=2, head_dim=32,
+                sliding_window=128, **tiny)
+            rehearse_flags = {"--max-cache-len": "4096",
+                              "--prefill-chunk": "256",
+                              "--prefill-buckets": "64,128,2048",
+                              "--max-decode-slots": "8"}
         elif _config.MODEL_REGISTRY[model].layer_pattern:
             # the hybrid: gated NoPE GQA + KDA layers, an expert share
             model = "rehearse-solar"
@@ -1653,9 +1948,16 @@ def main() -> int:
         import dataclasses
 
         t0 = time.monotonic()
+        # (the rehearsal's tiny list routes top-2 of 8: a routing flip
+        # between the float32 reference and the bfloat16 program swaps a
+        # third of a routed sum there, so its routed branches stay small;
+        # the chip run serves the maker's own, as large as the others)
+        small = {"moe_gain": 0.15} if opts.rehearse \
+            and _config.MODEL_REGISTRY[model].windowed else {}
         params = bench_module("weight_makers", cfg_file["weights_maker"]).make(
             dataclasses.asdict(_config.MODEL_REGISTRY[model]),
-            int(cfg_file["weights_seed"]), cfg_file["weights_dtype"] == "int8")
+            int(cfg_file["weights_seed"]), cfg_file["weights_dtype"] == "int8",
+            **small)
         jax.block_until_ready(params)
         plain = bench_module("reference", cfg_file["reference"])
         say(f"weights: {cfg_file['weights_maker']} "
@@ -1706,8 +2008,8 @@ def main() -> int:
             check_numerics(f"{name}, tp=4 vs one device", got[name], cfg,
                            params, tokenizer)
     else:
-        for name in ("c70", "c30", "m700") + (("mlong",) if cfg.selects
-                                              else ()):
+        for name in ("c70", "c30", "m700") + (
+                ("mlong",) if cfg.selects or cfg.windowed else ()):
             # m700 (and mlong, several chunks): through mixed_step
             check_numerics(name, got[name], cfg, eng.params, tokenizer, plain)
         if cfg.selects:
@@ -1719,6 +2021,17 @@ def main() -> int:
                 cfg, eng.params, plain,
                 cfg.sparse_dense_len + (64 if opts.rehearse else 1024),
                 strict=not opts.rehearse)
+        if cfg.windowed:
+            # the reference's own list; its CONTROLS_REPORTED are shown only
+            refused = check_controls(
+                "mlong", got["mlong"], cfg, eng.params, tokenizer, plain,
+                {**plain.CONTROLS, **plain.CONTROLS_REPORTED})
+            check(opts.rehearse or all(refused[c] for c in plain.CONTROLS),
+                  f"the comparison passes a reference without a mechanism: "
+                  f"{refused}")
+            check_list_routing_cause(cfg, eng.params, plain,
+                                     96 if opts.rehearse else 512,
+                                     strict=not opts.rehearse)
         if cfg.num_experts > 0:
             check_routing_counts(srv.port, cfg)
         if cfg.expert_share:
@@ -1735,7 +2048,16 @@ def main() -> int:
         # (groups = 1, 16 KV heads: OLMoE's), which gives a decode block of 8
         # slots 8 query rows a KV head where the 0.6B gives 16
         mha = _config.MODEL_REGISTRY["allenai/OLMoE-1B-7B-0125-Instruct"]
-        if cfg.selects:
+        if cfg.windowed:
+            # (the plain parity packs a table row a packed ROW: 2.4 MB of
+            # SMEM at 48 + 4,096 rows of 144 pages; the default run has it)
+            if opts.rehearse:
+                window_kernel_parity(cfg, 8, 1024, page, 4, 64,
+                                     interpret=True)
+            else:
+                window_kernel_parity(cfg, slots, window, page, bb,
+                                     eng._chunk_size, interpret=False)
+        elif cfg.selects:
             # (the plain kernels' parity packs a table row a packed ROW,
             # which a 512-page table does not fit: the default run has it)
             if opts.rehearse:

@@ -534,6 +534,70 @@ def test_ragged_kernel_under_page_masks_compiles_for_v5e(chip, chunk):
     _assert_named_after_wrapper(compiled, fn)
 
 
+# Trinity-Mini's shape as served: 4 KV heads, 32 query heads (groups 8), 48
+# slots, a 9,216-token cache (144-page tables), page 64, a 2,048-token window,
+# a 4,096-row chunk.
+
+T_HQ, T_HKV, T_B, T_MP, T_W, T_C = 32, 4, 48, 144, 2048, 4096
+
+
+def _trinity_pool(chip, pages):
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+
+    return sds, sds((6, pages, T_HKV, PS, D), jnp.bfloat16)
+
+
+def test_window_decode_kernel_compiles_for_v5e_under_its_own_name(chip):
+    """The window layers' decode calls carry a wrapper's name of their own:
+    that is how the device trace (and benchlib/trinity_opsbytes.py) tells
+    them from the full layers' calls of the same kernel."""
+    import os
+    import sys
+
+    sds, kv = _trinity_pool(chip, 1698)
+    i32 = jnp.int32
+    fn = pa.decode_attend_pallas_paged_window
+    compiled = _compile(
+        functools.partial(fn, bblock=8, window=T_W),
+        sds((T_B, 1, T_HQ, D), jnp.bfloat16), kv, kv, sds((T_B,), i32),
+        sds((), i32), sds((T_B, T_MP), i32))
+    assert "tpu_custom_call" in compiled.as_text()
+    _assert_named_after_wrapper(compiled, fn)
+    import re
+
+    assert not re.search(r"%decode_attend_pallas_paged(\.\d+)? = ",
+                         compiled.as_text())
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark"))
+    from benchlib import trinity_opsbytes as tob
+
+    assert tob.WINDOW_KERNEL_RE == "^%" + fn.__name__
+    assert re.match(tob.FULL_KERNEL_RE,
+                    "%" + pa.decode_attend_pallas_paged.__name__ + ".3 = ")
+
+
+@pytest.mark.parametrize("kind", ["full", "window"])
+def test_ragged_kernel_by_slot_compiles_for_v5e(chip, kind):
+    """ONE table row a slot and a row map (a table row a packed row is 2.4
+    MB of SMEM at 48 + 4,096 rows of 144 pages), full and under the
+    window, each under its own name."""
+    sds, kv = _trinity_pool(chip, 6913 if kind == "full" else 1698)
+    i32, N = jnp.int32, T_B + T_C
+    if kind == "full":
+        fn = functools.partial(pa.ragged_attend_pallas_paged_slots, bblock=8)
+        wrapper = pa.ragged_attend_pallas_paged_slots
+    else:
+        fn = functools.partial(pa.ragged_attend_pallas_paged_slots_window,
+                               bblock=8, window=T_W)
+        wrapper = pa.ragged_attend_pallas_paged_slots_window
+    compiled = _compile(
+        fn, sds((N, T_HQ, D), jnp.bfloat16), kv, kv, sds((N,), i32),
+        sds((), i32), sds((T_B, T_MP), i32), sds((N,), i32))
+    assert "tpu_custom_call" in compiled.as_text()
+    _assert_named_after_wrapper(compiled, wrapper)
+
+
 def test_selector_row_add_and_lightning_update_compile_for_v5e(chip):
     from aws_k8s_ansible_provisioner_tpu.ops import linear_attention as la
 
