@@ -115,12 +115,13 @@ def _per_pair(vals, shape, hkv: int, groups: int, tiled: bool):
 # (batch, page_size, kv_dtype)); 1 remains valid and still double-buffers.
 
 
-def _paged_db_body(lengths_ref, layer_ref, table_ref, share_ref, rowmap_ref,
-                   sel_ref, cnt_ref, bits_ref, q_ref,
+def _paged_db_body(lengths_ref, layer_ref, table_ref, share_ref, wide_ref,
+                   rowmap_ref, sel_ref, cnt_ref, bits_ref, q_ref,
                    k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf,
                    vs_buf, acc_ref, m_ref, l_ref, sem, acc_t, m_t, l_t,
+                   wide_state,
                    *, ps: int, groups: int, scale: float, R: int, bb: int,
-                   num_pages: int, window: int, spec: bool):
+                   num_pages: int, window: int, spec: bool, tile: int):
     """Shared double-buffered paged flash body (decode R=1 / spec-verify R>1,
     bf16 / int8 pools, full / sliding-window attention).
 
@@ -146,9 +147,25 @@ def _paged_db_body(lengths_ref, layer_ref, table_ref, share_ref, rowmap_ref,
     ``share_ref`` (ragged entry, bb > 1; else None and compiled out) holds
     per block the row whose table every live row of the block shares, or -1.
     A sharing block fetches page c ONCE (not once per row) and runs the
-    flash update with the block as one [bb*groups]-row query tile per KV
-    head — the chunk rows of one prefill are the case; each row still masks
-    to its own limit and window.
+    flash update with the block as one query tile per KV head — the chunk
+    rows of one prefill are the case; each row still masks to its own limit
+    and window. The tile's state keeps its rows on lanes
+    (``rows_on_lanes``); under an int8 pool or a page selection it is the
+    [bb*groups]-row tile of ``shared``.
+
+    ``tile`` > bb with ``wide_ref`` and ``wide_state`` (the ragged entries
+    over a bf16 pool that select nothing; elsewhere ``tile`` == bb, both
+    None and all of this compiled out): a grid step holds ``tile`` rows,
+    ``tile // bb`` blocks, and ``wide_ref`` says per step what they are —
+    the row whose table every live row of the STEP shares (then the step is
+    ONE sharing tile of ``tile`` rows: page c is fetched once for all of
+    them, ``tile // bb`` times fewer page steps than its blocks would walk;
+    the chunk rows of a mixed step are the case), -1 (rows of several
+    tables — the step that holds the decode rows: its blocks run one after
+    the other as they would have alone) or -2 (no live row: zeros out,
+    nothing fetched). A row's arithmetic is the same in a tile of any
+    width: the pages outside its own range that a wider tile walks are
+    fully masked for it, which leaves its state bit for bit where it was.
 
     SELECTION (a model whose attention reads chosen pages only,
     ops/sparse_attention.py; every operand None and compiled out
@@ -166,7 +183,9 @@ def _paged_db_body(lengths_ref, layer_ref, table_ref, share_ref, rowmap_ref,
       for a KV head is its own where the bit is set and 0 where it is not,
       and a page no row of the block selects skips its flash update. The
       chunk rows of a prefill share one page stream a block (the sharing
-      path), each row and head masking what it did not choose.
+      path), each row and head masking what it did not choose. Blocks of
+      ``bb`` rows only: a page that none of 8 rows chose is common, one
+      that none of 56 chose is not.
 
     ``rowmap_ref`` (ragged entry): packed row -> row of ``table_ref``, so
     the chunk rows of one slot name ONE table row instead of each carrying
@@ -179,13 +198,9 @@ def _paged_db_body(lengths_ref, layer_ref, table_ref, share_ref, rowmap_ref,
     d = q_ref.shape[2]
     hkv = k_buf.shape[2]
     ext = R if spec else 0      # spec rows see up to R columns past lengths
-    # BB per-row SCALARS (see _per_slot: a stacked scalar vector reshaped
-    # to [BB, 1, 1] is a shape cast Mosaic refuses)
-    lens = [lengths_ref[g * bb + i] for i in range(bb)]
-    alive = [ln + ext > 0 for ln in lens]
-    hi = [jnp.minimum(pl.cdiv(ln + ext, ps), num_pages) - 1
-          for ln in lens]                                 # -1 = dead row
-    hi_max = functools.reduce(jnp.maximum, hi)
+    # a sharing block's, or tile's, flash state keeps its rows on lanes
+    # (rows_on_lanes) unless pages are selected or scaled (_paged_flash_db)
+    on_lanes = share_ref is not None and not quant and bits_ref is None
 
     def trow(row):
         """Offset of packed row ``row``'s page run in the flat table."""
@@ -193,53 +208,25 @@ def _paged_db_body(lengths_ref, layer_ref, table_ref, share_ref, rowmap_ref,
             row = rowmap_ref[row]
         return row * num_pages
 
-    pairs = [(i, h) for i in range(bb) for h in range(hkv)]
-    if sel_ref is not None:
-        nsel = sel_ref.shape[0] // cnt_ref.shape[0]
-        cnts = [jnp.where(alive[i], cnt_ref[(g * bb + i) * hkv + h], 0)
-                for i, h in pairs]
-        hi_max = functools.reduce(jnp.maximum, cnts) - 1
+    def last_pages(lens):
+        """Per row its last live logical page (-1 = dead row), and the
+        largest of them."""
+        hi = [jnp.minimum(pl.cdiv(ln + ext, ps), num_pages) - 1
+              for ln in lens]
+        return hi, functools.reduce(jnp.maximum, hi)
 
-        def listed(c):
-            """The logical page each (row, KV head) reads at list position
-            c: clamped into its own list; 0 for an empty one."""
-            return [sel_ref[((g * bb + i) * hkv + h) * nsel
-                            + jnp.clip(c, 0, jnp.maximum(n - 1, 0))]
-                    for (i, h), n in zip(pairs, cnts)]
-
-    def chosen(c):
-        """Per (row, KV head): is page c in its selection (bits form)."""
-        nw = bits_ref.shape[0] // (lengths_ref.shape[0] * hkv)
-        return [(bits_ref[((g * bb + i) * hkv + h) * nw + c // 32]
-                 >> (c % 32)) & 1 for i, h in pairs]
-    if window > 0:
+    def first_pages(lens, alive):
+        """Per row the first logical page inside its window, and the
+        smallest over the live rows (``num_pages`` where none is)."""
+        if window == 0:
+            return [jnp.int32(0)] * len(lens), jnp.int32(0)
         lo = [jnp.maximum(ln + (1 if spec else 0) - window, 0) // ps
               for ln in lens]
-        lo_min = functools.reduce(
+        return lo, functools.reduce(
             jnp.minimum, [jnp.where(a, x, num_pages)
                           for a, x in zip(alive, lo)])
-    else:
-        lo = [jnp.int32(0)] * bb
-        lo_min = jnp.int32(0)
 
-    def row_pages(c):
-        """(buffer row, physical page) of every row's page-c copy. A row
-        clamps into its own live range: table entries past it may be
-        anything valid (scratch, stale) — never fetch them. A dead row in a
-        live block rides along on physical page 0 (always in the pool)."""
-        return [(i, jnp.where(
-            alive[i],
-            table_ref[trow(g * bb + i)
-                      + jnp.clip(c, lo[i], jnp.maximum(hi[i], 0))], 0))
-                for i in range(bb)]
-
-    def listed_pages(c):
-        """row_pages for the list form: ((row, KV head), physical page)."""
-        return [((i, h), jnp.where(n > 0, table_ref[trow(g * bb + i) + lp],
-                                   0))
-                for (i, h), n, lp in zip(pairs, cnts, listed(c))]
-
-    def walk(pages, update):
+    def walk(lo_min, hi_max, pages, update):
         """The double-buffered walk over [lo_min, hi_max]: ``pages(c)`` names
         page c's copies, ``update(c, buf)`` folds buffer ``buf`` into the
         flash state."""
@@ -312,133 +299,278 @@ def _paged_db_body(lengths_ref, layer_ref, table_ref, share_ref, rowmap_ref,
         m[:] = jnp.full_like(m, NEG_INF)
         l[:] = jnp.zeros_like(l)
 
-    def per_row():
-        """Every row streams its own pages: BB copies a page step, BB*Hkv
-        matmuls of ``groups`` rows."""
-        lens_b = _per_slot(lens, (bb, hq, ps))
-        q3s = [(q_ref[:, r * hq:(r + 1) * hq].astype(jnp.float32) * scale)
-               .reshape(bb * hkv, groups, d) for r in range(R)]
+    def rows_on_lanes(row, q_ref, lens, lo_min, hi_max):
+        """All live rows read table row ``row`` (a bf16 or float32 pool, no
+        selection): ONE copy a page step into buffer row 0 for a tile of
+        ``len(lens)`` rows, whatever their number. The flash state is kept
+        TRANSPOSED — a page's keys on sublanes, the tile's rows * groups
+        query rows on lanes: logits [Hkv, page, n], context [Hkv, D, n] —
+        so m, l and the column masks are lane vectors of n entries (not n
+        sublane rows of one live lane each), the max and the sum over a
+        page's keys run down vregs, and a 64-key page wastes no lane; the
+        MXU takes both products transposed. Q and K meet it as the values
+        they are stored as and the scale falls on the logits: the same
+        products (a bf16 pair's is exact in float32) in one pass, where a
+        float32 pair takes six. Per row the arithmetic is one order of
+        pages, one order of keys within a page, float32 throughout: a
+        row's result does not depend on the width of its tile. Returns
+        [rows, Hq, D], dead rows zero."""
+        rows = len(lens)
+        n = rows * groups
+        acc, m, l = (acc_t, m_t, l_t) if rows == bb else wide_state
+        stored = k_buf.dtype == q_ref.dtype
+        qt = q_ref[:].astype(jnp.float32)           # [rows, Hq, D] ->
+        qt = qt.reshape(rows, hkv, groups, d).transpose(1, 0, 2, 3) \
+            .reshape(hkv, n, d)                     # [Hkv, n, D]
+        if stored:
+            qt = qt.astype(q_ref.dtype)
+        # one chain of selects a tile: the column masks and the dead rows'
+        limit = _per_slot(lens, (1, 1, n), axis=2, each=groups)
 
         def update(c, buf):
-            k3 = k_buf[buf].astype(jnp.float32).reshape(bb * hkv, ps, d)
-            v3 = v_buf[buf].astype(jnp.float32).reshape(bb * hkv, ps, d)
-            if quant:
-                # scale pages arrive lane-padded (kv_pool.scale_lanes);
-                # only the first ``ps`` lanes are rows of this page
-                kscale = ks_buf[buf][:, :, :ps].reshape(bb * hkv, ps)
-                vscale = vs_buf[buf][:, :, :ps].reshape(bb * hkv, ps)
-            col = c * ps + jax.lax.broadcasted_iota(jnp.int32,
-                                                    (bb, hq, ps), 2)
-            limit_b = lens_b
-            if sel_ref is not None:
-                # a column is where its (row, head)'s listed page puts it;
-                # past the end of a list: nowhere
-                base = [jnp.where(c < n, lp * ps, num_pages * ps)
-                        for n, lp in zip(cnts, listed(c))]
-                col = jax.lax.broadcasted_iota(jnp.int32, (bb, hq, ps), 2) \
-                    + _per_pair(base, (bb, hq, ps), hkv, groups, False)
-            elif bits_ref is not None:
-                limit_b = _per_pair(
-                    [jnp.where(b > 0, lens[i], 0)
-                     for (i, _), b in zip(pairs, chosen(c))],
-                    (bb, hq, ps), hkv, groups, False)
-            for r in range(R):         # static unroll over draft rows
-                sl = slice(r * hq, (r + 1) * hq)
-                s = jax.lax.dot_general(
-                    q3s[r], k3, (((2,), (2,)), ((0,), (0,))),
-                    preferred_element_type=jnp.float32)   # [BB*Hkv, G, ps]
+            k3, v3 = k_buf[buf, 0], v_buf[buf, 0]             # [Hkv, ps, D]
+            if not stored:
+                k3 = k3.astype(jnp.float32)
+            s = jax.lax.dot_general(
+                k3, qt, (((2,), (2,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32) * scale   # [Hkv, ps, n]
+            col = c * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps, n), 1)
+            live_col = col < limit
+            if window > 0:
+                live_col &= col >= limit - window
+            s = jnp.where(live_col, s, NEG_INF)
+            m_prev, l_prev = m[:], l[:]                       # [Hkv, 1, n]
+            m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            corr = jnp.exp(m_prev - m_cur)
+            p = jnp.exp(s - m_cur)
+            l[:] = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
+            m[:] = m_cur
+            acc[:] = acc[:] * corr + jax.lax.dot_general(
+                v3.astype(jnp.float32), p, (((1,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)           # [Hkv, D, n]
+
+        reset(acc, m, l)
+        walk(lo_min, hi_max, lambda c: [(0, table_ref[trow(row) + c])],
+             update)
+        # dead rows hold whatever rode through their lanes: exactly zero out
+        ctx = jnp.where(limit > 0, acc[:] / jnp.maximum(l[:], 1e-9), 0.0)
+        return jnp.swapaxes(ctx, 1, 2).reshape(hkv, rows, groups, d) \
+            .transpose(1, 0, 2, 3).reshape(rows, hq, d)
+
+    def block(blk, q_ref, o_ref):
+        """Rows [blk * bb, (blk + 1) * bb): a block of a call that tiles no
+        wider, or one of a step whose rows read several tables."""
+        # BB per-row SCALARS (see _per_slot: a stacked scalar vector
+        # reshaped to [BB, 1, 1] is a shape cast Mosaic refuses)
+        lens = [lengths_ref[blk * bb + i] for i in range(bb)]
+        alive = [ln + ext > 0 for ln in lens]
+        hi, hi_max = last_pages(lens)
+        pairs = [(i, h) for i in range(bb) for h in range(hkv)]
+        if sel_ref is not None:
+            nsel = sel_ref.shape[0] // cnt_ref.shape[0]
+            cnts = [jnp.where(alive[i], cnt_ref[(blk * bb + i) * hkv + h], 0)
+                    for i, h in pairs]
+            hi_max = functools.reduce(jnp.maximum, cnts) - 1
+
+            def listed(c):
+                """The logical page each (row, KV head) reads at list
+                position c: clamped into its own list; 0 for an empty
+                one."""
+                return [sel_ref[((blk * bb + i) * hkv + h) * nsel
+                                + jnp.clip(c, 0, jnp.maximum(n - 1, 0))]
+                        for (i, h), n in zip(pairs, cnts)]
+
+        def chosen(c):
+            """Per (row, KV head): is page c in its selection (bits
+            form)."""
+            nw = bits_ref.shape[0] // (lengths_ref.shape[0] * hkv)
+            return [(bits_ref[((blk * bb + i) * hkv + h) * nw + c // 32]
+                     >> (c % 32)) & 1 for i, h in pairs]
+        lo, lo_min = first_pages(lens, alive)
+
+        def row_pages(c):
+            """(buffer row, physical page) of every row's page-c copy. A
+            row clamps into its own live range: table entries past it may
+            be anything valid (scratch, stale) — never fetch them. A dead
+            row in a live block rides along on physical page 0 (always in
+            the pool)."""
+            return [(i, jnp.where(
+                alive[i],
+                table_ref[trow(blk * bb + i)
+                          + jnp.clip(c, lo[i], jnp.maximum(hi[i], 0))], 0))
+                    for i in range(bb)]
+
+        def listed_pages(c):
+            """row_pages for the list form: ((row, KV head), physical
+            page)."""
+            return [((i, h), jnp.where(
+                n > 0, table_ref[trow(blk * bb + i) + lp], 0))
+                    for (i, h), n, lp in zip(pairs, cnts, listed(c))]
+
+        def per_row():
+            """Every row streams its own pages: BB copies a page step,
+            BB*Hkv matmuls of ``groups`` rows."""
+            lens_b = _per_slot(lens, (bb, hq, ps))
+            q3s = [(q_ref[:, r * hq:(r + 1) * hq].astype(jnp.float32)
+                    * scale).reshape(bb * hkv, groups, d) for r in range(R)]
+
+            def update(c, buf):
+                k3 = k_buf[buf].astype(jnp.float32).reshape(bb * hkv, ps, d)
+                v3 = v_buf[buf].astype(jnp.float32).reshape(bb * hkv, ps, d)
                 if quant:
-                    s = s * kscale[:, None, :]
+                    # scale pages arrive lane-padded (kv_pool.scale_lanes);
+                    # only the first ``ps`` lanes are rows of this page
+                    kscale = ks_buf[buf][:, :, :ps].reshape(bb * hkv, ps)
+                    vscale = vs_buf[buf][:, :, :ps].reshape(bb * hkv, ps)
+                col = c * ps + jax.lax.broadcasted_iota(jnp.int32,
+                                                        (bb, hq, ps), 2)
+                limit_b = lens_b
+                if sel_ref is not None:
+                    # a column is where its (row, head)'s listed page puts
+                    # it; past the end of a list: nowhere
+                    base = [jnp.where(c < n, lp * ps, num_pages * ps)
+                            for n, lp in zip(cnts, listed(c))]
+                    col = jax.lax.broadcasted_iota(jnp.int32,
+                                                   (bb, hq, ps), 2) \
+                        + _per_pair(base, (bb, hq, ps), hkv, groups, False)
+                elif bits_ref is not None:
+                    limit_b = _per_pair(
+                        [jnp.where(b > 0, lens[i], 0)
+                         for (i, _), b in zip(pairs, chosen(c))],
+                        (bb, hq, ps), hkv, groups, False)
+                for r in range(R):         # static unroll over draft rows
+                    sl = slice(r * hq, (r + 1) * hq)
+                    s = jax.lax.dot_general(
+                        q3s[r], k3, (((2,), (2,)), ((0,), (0,))),
+                        preferred_element_type=jnp.float32)  # [BB*Hkv,G,ps]
+                    if quant:
+                        s = s * kscale[:, None, :]
+
+                    def pv_of(p):
+                        p3 = p.reshape(bb * hkv, groups, ps)
+                        if quant:
+                            p3 = p3 * vscale[:, None, :]
+                        return jax.lax.dot_general(
+                            p3, v3, (((2,), (1,)), ((0,), (0,))),
+                            preferred_element_type=jnp.float32
+                        ).reshape(bb, hq, d)                 # [BB*Hkv,G,d]
+
+                    flash(s.reshape(bb, hq, ps), col,
+                          limit_b + (1 + r if spec else 0), m_ref, l_ref,
+                          acc_ref, sl, pv_of)
+
+            reset(acc_ref, m_ref, l_ref)
+            walk(lo_min, hi_max,
+                 row_pages if sel_ref is None else listed_pages, update)
+            return acc_ref[:] / jnp.maximum(l_ref[:, :, :1], 1e-9)
+
+        def shared(row):
+            """All live rows read table row ``row`` (an int8 pool, or a
+            page selection a row and KV head): ONE copy a page step into
+            buffer row 0, Hkv matmuls of BB*groups rows (tile row =
+            b*groups + group)."""
+            n = bb * groups
+
+            def tile(x):       # [BB, Hq, *] -> [Hkv, BB*groups, *]
+                return x.reshape(bb, hkv, groups, -1).transpose(1, 0, 2, 3) \
+                    .reshape(hkv, n, -1)
+
+            qt = tile(q_ref[:].astype(jnp.float32) * scale)
+            limit = _per_slot(lens, (hkv, n, ps), axis=1, each=groups)
+
+            def update(c, buf):
+                if bits_ref is None:
+                    return fold(c, buf, limit)
+                picks = chosen(c)
+
+                @pl.when(functools.reduce(jnp.bitwise_or, picks) > 0)
+                def _some_row_chose_it():
+                    fold(c, buf, _per_pair(
+                        [jnp.where(b > 0, lens[i], 0)
+                         for (i, _), b in zip(pairs, picks)],
+                        (hkv, n, ps), hkv, groups, True))
+
+            def fold(c, buf, limit):
+                k3 = k_buf[buf, 0].astype(jnp.float32)        # [Hkv, ps, d]
+                v3 = v_buf[buf, 0].astype(jnp.float32)
+                s = jax.lax.dot_general(
+                    qt, k3, (((2,), (2,)), ((0,), (0,))),
+                    preferred_element_type=jnp.float32)       # [Hkv, n, ps]
+                if quant:
+                    s = s * ks_buf[buf, 0][:, :ps][:, None, :]
 
                 def pv_of(p):
-                    p3 = p.reshape(bb * hkv, groups, ps)
                     if quant:
-                        p3 = p3 * vscale[:, None, :]
+                        p = p * vs_buf[buf, 0][:, :ps][:, None, :]
                     return jax.lax.dot_general(
-                        p3, v3, (((2,), (1,)), ((0,), (0,))),
-                        preferred_element_type=jnp.float32
-                    ).reshape(bb, hq, d)                  # [BB*Hkv, G, d]
+                        p, v3, (((2,), (1,)), ((0,), (0,))),
+                        preferred_element_type=jnp.float32)   # [Hkv, n, d]
 
-                flash(s.reshape(bb, hq, ps), col,
-                      limit_b + (1 + r if spec else 0), m_ref, l_ref,
-                      acc_ref, sl, pv_of)
+                col = c * ps + jax.lax.broadcasted_iota(jnp.int32,
+                                                        (hkv, n, ps), 2)
+                flash(s, col, limit, m_t, l_t, acc_t, slice(None), pv_of)
 
-        reset(acc_ref, m_ref, l_ref)
-        walk(row_pages if sel_ref is None else listed_pages, update)
-        return acc_ref[:] / jnp.maximum(l_ref[:, :, :1], 1e-9)
+            reset(acc_t, m_t, l_t)
+            walk(lo_min, hi_max, lambda c: [(0, table_ref[trow(row) + c])],
+                 update)
+            out = acc_t[:] / jnp.maximum(l_t[:, :, :1], 1e-9)
+            return out.reshape(hkv, bb, groups, d).transpose(1, 0, 2, 3) \
+                .reshape(bb, hq, d)
 
-    def shared(row):
-        """All live rows read table row ``row``: ONE copy a page step into
-        buffer row 0, Hkv matmuls of BB*groups rows (tile row = b*groups +
-        group)."""
-        n = bb * groups
+        def emit(ctx):
+            # dead rows hold whatever rode through their lanes: exactly
+            # zero out
+            live_row = _per_slot(lens, ctx.shape) + ext > 0
+            o_ref[:] = jnp.where(live_row, ctx, 0.0).astype(o_ref.dtype)
 
-        def tile(x):       # [BB, Hq, *] -> [Hkv, BB*groups, *]
-            return x.reshape(bb, hkv, groups, -1).transpose(1, 0, 2, 3) \
-                .reshape(hkv, n, -1)
-
-        qt = tile(q_ref[:].astype(jnp.float32) * scale)
-        limit = _per_slot(lens, (hkv, n, ps), axis=1, each=groups)
-
-        def update(c, buf):
-            if bits_ref is None:
-                return fold(c, buf, limit)
-            picks = chosen(c)
-
-            @pl.when(functools.reduce(jnp.bitwise_or, picks) > 0)
-            def _some_row_chose_it():
-                fold(c, buf, _per_pair(
-                    [jnp.where(b > 0, lens[i], 0)
-                     for (i, _), b in zip(pairs, picks)],
-                    (hkv, n, ps), hkv, groups, True))
-
-        def fold(c, buf, limit):
-            k3 = k_buf[buf, 0].astype(jnp.float32)            # [Hkv, ps, d]
-            v3 = v_buf[buf, 0].astype(jnp.float32)
-            s = jax.lax.dot_general(
-                qt, k3, (((2,), (2,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32)           # [Hkv, n, ps]
-            if quant:
-                s = s * ks_buf[buf, 0][:, :ps][:, None, :]
-
-            def pv_of(p):
-                if quant:
-                    p = p * vs_buf[buf, 0][:, :ps][:, None, :]
-                return jax.lax.dot_general(
-                    p, v3, (((2,), (1,)), ((0,), (0,))),
-                    preferred_element_type=jnp.float32)       # [Hkv, n, d]
-
-            col = c * ps + jax.lax.broadcasted_iota(jnp.int32,
-                                                    (hkv, n, ps), 2)
-            flash(s, col, limit, m_t, l_t, acc_t, slice(None), pv_of)
-
-        reset(acc_t, m_t, l_t)
-        walk(lambda c: [(0, table_ref[trow(row) + c])], update)
-        out = acc_t[:] / jnp.maximum(l_t[:, :, :1], 1e-9)
-        return out.reshape(hkv, bb, groups, d).transpose(1, 0, 2, 3) \
-            .reshape(bb, hq, d)
-
-    def emit(ctx):
-        # dead rows hold whatever rode through their lanes: exactly zero out
-        live_row = _per_slot(lens, ctx.shape) + ext > 0
-        o_ref[:] = jnp.where(live_row, ctx, 0.0).astype(o_ref.dtype)
-
-    if share_ref is None:
-        emit(per_row())
-    else:
-        row = share_ref[g]
-
-        @pl.when(row >= 0)
-        def _shared():
-            emit(shared(row))
-
-        @pl.when(row < 0)
-        def _per_row():
+        if share_ref is None:
             emit(per_row())
+        else:
+            row = share_ref[blk]
+
+            @pl.when(row >= 0)
+            def _shared():
+                if on_lanes:
+                    o_ref[:] = rows_on_lanes(row, q_ref, lens, lo_min,
+                                             hi_max).astype(o_ref.dtype)
+                else:
+                    emit(shared(row))
+
+            @pl.when(row < 0)
+            def _per_row():
+                emit(per_row())
+
+    if wide_ref is None:
+        return block(g, q_ref, o_ref)
+    shares = wide_ref[g]
+
+    @pl.when(shares >= 0)
+    def _one_tile():
+        lens = [lengths_ref[g * tile + i] for i in range(tile)]
+        _, hi_max = last_pages(lens)
+        # a window tile starts at its LOWEST row's first live page: nothing
+        # below every row's window (released pages) is ever fetched
+        _, lo_min = first_pages(lens, [ln > 0 for ln in lens])
+        o_ref[:] = rows_on_lanes(shares, q_ref, lens, lo_min,
+                                 hi_max).astype(o_ref.dtype)
+
+    @pl.when(shares == -1)
+    def _block_by_block():
+        def one(j, carry):
+            rows = pl.ds(pl.multiple_of(j * bb, bb), bb)
+            block(g * (tile // bb) + j, q_ref.at[rows], o_ref.at[rows])
+            return carry
+
+        jax.lax.fori_loop(0, tile // bb, one, 0)
+
+    @pl.when(shares < -1)
+    def _no_live_row():
+        o_ref[:] = jnp.zeros_like(o_ref)
 
 
-def _paged_db_kernel(*refs, quant: bool, share: bool, rowmap: bool = False,
-                     sel: bool = False, bits: bool = False, **kw):
+def _paged_db_kernel(*refs, quant: bool, share: bool, wide: bool = False,
+                     rowmap: bool = False, sel: bool = False,
+                     bits: bool = False, **kw):
     """Name the pallas_call's positional refs (scalar prefetch, inputs,
     output, scratch, in _paged_flash_db's order) for _paged_db_body; what a
     bf16 pool or a call without a share fact leaves out is None."""
@@ -449,6 +581,7 @@ def _paged_db_kernel(*refs, quant: bool, share: bool, rowmap: bool = False,
 
     lengths_ref, layer_ref, table_ref = take(3)
     share_ref, = take(1, share)
+    wide_ref, = take(1, wide)
     rowmap_ref, = take(1, rowmap)
     sel_ref, cnt_ref = take(2, sel)
     bits_ref, = take(1, bits)
@@ -458,10 +591,12 @@ def _paged_db_kernel(*refs, quant: bool, share: bool, rowmap: bool = False,
     ks_buf, vs_buf = take(2, quant)
     acc_ref, m_ref, l_ref, sem = take(4)
     acc_t, m_t, l_t = take(3, share)
-    _paged_db_body(lengths_ref, layer_ref, table_ref, share_ref, rowmap_ref,
-                   sel_ref, cnt_ref, bits_ref, q_ref,
+    wide_state = take(3, wide)
+    _paged_db_body(lengths_ref, layer_ref, table_ref, share_ref, wide_ref,
+                   rowmap_ref, sel_ref, cnt_ref, bits_ref, q_ref,
                    k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf,
-                   vs_buf, acc_ref, m_ref, l_ref, sem, acc_t, m_t, l_t, **kw)
+                   vs_buf, acc_ref, m_ref, l_ref, sem, acc_t, m_t, l_t,
+                   wide_state, **kw)
 
 
 def _resolve_bb(bblock, B: int) -> int:
@@ -472,10 +607,67 @@ def _resolve_bb(bblock, B: int) -> int:
     return bb
 
 
+# The widest query tile of a ragged call, in packed rows: a tile walks the
+# pages of its longest row for every row, and consecutive chunk rows are a
+# token apart, so up to a page's width (64 served) a wider tile adds at
+# most one part-masked page step to what its rows need.
+TILE_ROWS = 64
+# VMEM a wide tile may take: its float32 context, the queries, the logits,
+# P and P.V of one page step, and the pipeline's two buffers each of its q
+# and o blocks — beside the page buffers, inside the 16 MiB a kernel gets
+# by default on a v5e.
+TILE_VMEM_BYTES = 8 * 1024 * 1024
+
+
+def _tile_rows(N: int, bb: int, hq: int, d: int, ps: int, dtype) -> int:
+    """Packed rows a grid step of a ragged call holds: the largest multiple
+    of ``bb`` that divides N, is no wider than TILE_ROWS and keeps a wide
+    tile's working set inside TILE_VMEM_BYTES (2,080 rows of 16 heads ->
+    40; 2,064 -> 48; 2,072 and 4,144 -> 56); ``bb`` where none is wider,
+    where blocks are one row (nothing is shared) and under an int8 pool
+    (its scales ride a page's lanes: the blocks of ``shared``). From the
+    call's shapes and the pool's ``dtype`` alone — under a ``tp`` mesh the
+    shard's."""
+    dtype = jnp.dtype(dtype)
+    if bb == 1 or dtype == jnp.int8:
+        return bb
+    per_row = hq * (4 * (2 * d + 2 * ps) + 5 * d * dtype.itemsize)
+    return max([t for t in range(bb, TILE_ROWS + 1, bb)
+                if N % t == 0 and t * per_row <= TILE_VMEM_BYTES],
+               default=bb)
+
+
+def _shared_row(live, keys, width: int, dead: int = -1):
+    """Per run of ``width`` packed rows: its first live row if every live
+    row's key ([N] slot or [N, max_pages] table row) equals that row's —
+    then any page one of them needs is at the same entry of that row's
+    table —, -1 if they differ, ``dead`` if no row is live."""
+    N = live.shape[0]
+    live = live.reshape(N // width, width)
+    keys = keys.reshape(N // width, width, -1)
+    first = jnp.argmax(live, axis=1).astype(jnp.int32)
+    lead = jnp.take_along_axis(keys, first[:, None, None], axis=1)
+    same = jnp.all((keys == lead) | ~live[:, :, None], axis=(1, 2))
+    row = jnp.arange(N // width, dtype=jnp.int32) * width + first
+    return jnp.where(live.any(axis=1), jnp.where(same, row, -1), dead)
+
+
+def _share_facts(q, pool_k, row_limits, keys, bb: int):
+    """(share, wide) of a ragged call (_paged_flash_db): who shares a page
+    stream, per block of ``bb`` rows and per tile of :func:`_tile_rows`
+    rows — read off the rows' keys, per call, not set by anyone."""
+    if bb == 1:
+        return None, None
+    (N, hq, d), live = q.shape, row_limits > 0
+    tile = _tile_rows(N, bb, hq, d, pool_k.shape[3], pool_k.dtype)
+    return (_shared_row(live, keys, bb),
+            _shared_row(live, keys, tile, dead=-2) if tile > bb else None)
+
+
 def _paged_flash_db(q2, pool_k, pool_v, lengths, layer_arr, table,
                     *, bb: int, R: int, spec: bool, window: int,
                     interpret: bool, pool_ks, pool_vs, share=None,
-                    row_map=None, sel=None, cnt=None, bits=None):
+                    wide=None, row_map=None, sel=None, cnt=None, bits=None):
     """Build + dispatch the double-buffered paged flash call.
 
     q2: [B, R*Hq, D] (R=1 for plain decode). Grid is (B // bb,); the pools
@@ -483,6 +675,9 @@ def _paged_flash_db(q2, pool_k, pool_v, lengths, layer_arr, table,
     DMAs exactly the live pages), q/o are VMEM-blocked per slot block.
     ``share`` [B // bb] int32 (ragged entry only): per block, the row whose
     table its live rows all share, or -1 — see _paged_db_body.
+    ``wide`` [B // tile] int32 (with ``share``): the same fact per run of
+    ``tile`` rows, ``tile`` a multiple of bb read off its length — a grid
+    step is then ``tile`` rows (_paged_db_body; :func:`_tile_rows`).
     ``row_map`` [B]: the row of ``table`` each packed row reads (None: its
     own). ``sel`` [B, Hkv, K] with ``cnt`` [B, Hkv], or ``bits``
     [B, Hkv, ceil(pages / 32)] int32: the pages each row and KV head
@@ -493,6 +688,7 @@ def _paged_flash_db(q2, pool_k, pool_v, lengths, layer_arr, table,
     groups = (RHq // R) // Hkv
     num_pages = table.shape[1]
     quant = pool_ks is not None
+    tile = bb if wide is None else B // wide.shape[0]
 
     in_specs = [pl.BlockSpec(memory_space=pltpu.ANY)] * 2    # the pools
     operands = [q2, pool_k, pool_v]
@@ -527,34 +723,43 @@ def _paged_flash_db(q2, pool_k, pool_v, lengths, layer_arr, table,
             "page selection: bf16 pool, full attention, one row a query"
     if share is not None:
         prefetch.append(share)
+    if wide is not None:
+        prefetch.append(wide)
     if row_map is not None:
         prefetch.append(row_map)
     if sel is not None:
         prefetch += [sel.reshape(-1), cnt.reshape(-1)]
     if bits is not None:
         prefetch.append(bits.reshape(-1))
-    if share is not None:
+    if share is not None and (quant or bits is not None):
         scratch += [                       # the sharing blocks' flash state
             pltpu.VMEM((Hkv, bb * groups, D), jnp.float32),
             pltpu.VMEM((Hkv, bb * groups, 128), jnp.float32),
             pltpu.VMEM((Hkv, bb * groups, 128), jnp.float32),
         ]
+    elif share is not None:     # a block's and a tile's, rows on lanes
+        for rows in (bb,) + ((tile,) if tile > bb else ()):
+            scratch += [
+                pltpu.VMEM((Hkv, D, rows * groups), jnp.float32),
+                pltpu.VMEM((Hkv, 1, rows * groups), jnp.float32),
+                pltpu.VMEM((Hkv, 1, rows * groups), jnp.float32),
+            ]
 
     def q_map(g, *prefetched):
         return (g, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
-        grid=(B // bb,),
-        in_specs=[pl.BlockSpec((bb, RHq, D), q_map)] + in_specs,
-        out_specs=pl.BlockSpec((bb, RHq, D), q_map),
+        grid=(B // tile,),
+        in_specs=[pl.BlockSpec((tile, RHq, D), q_map)] + in_specs,
+        out_specs=pl.BlockSpec((tile, RHq, D), q_map),
         scratch_shapes=scratch,
     )
     kernel = functools.partial(
         _paged_db_kernel, quant=quant, share=share is not None,
-        rowmap=row_map is not None, sel=sel is not None,
-        bits=bits is not None, ps=ps, groups=groups, scale=1.0 / (D ** 0.5),
-        R=R, bb=bb,
+        wide=wide is not None, rowmap=row_map is not None,
+        sel=sel is not None, bits=bits is not None, ps=ps, groups=groups,
+        scale=1.0 / (D ** 0.5), R=R, bb=bb, tile=tile,
         num_pages=num_pages, window=window, spec=spec)
     return pl.pallas_call(
         kernel,
@@ -636,43 +841,36 @@ def ragged_attend_pallas_paged(q: jnp.ndarray, pool_k: jnp.ndarray,
       entries are never used and may be anything, and its output is zero.
 
     The work follows the live (row, page) pairs. Where the live rows of one
-    bblock-wide grid step all carry the same table row — the chunk rows of
-    one slot do — the step fetches each page ONCE and updates the block as
-    one query tile (_paged_db_body's sharing path); that is read off
-    ``row_tables`` here, per call, not set by anyone. Other blocks (decode
-    rows of distinct slots, a block straddling decode and chunk rows) stream
-    a page per row, as the decode entry does.
+    grid step all carry the same table row — the chunk rows of one slot do
+    — the step fetches each page ONCE and updates its rows as one query
+    tile (_paged_db_body's sharing path); that is read off ``row_tables``
+    here, per call, not set by anyone. A grid step is a TILE of
+    :func:`_tile_rows` rows (40-64 where the row count has such a divisor,
+    from the shapes alone; ``bblock`` under an int8 pool): 4,096 chunk
+    rows stream their pages 73 times where blocks of 8 streamed them 512
+    times. A step whose live rows carry several tables (the one that
+    holds the decode rows) runs its ``bblock``-row blocks one after the
+    other: a block of chunk rows as a tile of its own, decode rows of
+    distinct slots a page per row, as the decode entry does.
 
     q: [N, Hq, D] packed query rows; row_limits: [N] live columns per row;
     row_tables: [N, max_pages] int32 (entries at or past a row's live range
     may be any valid id — clamped away, never fetched); layer: scalar.
     Returns [N, Hq, D]. pool_ks/vs switch the int8 scale-folding body;
     ``window`` > 0 applies per-row sliding-window masking off each row's own
-    limit. ``bblock`` packed rows share each grid step (resolved to the
-    largest divisor of N).
+    limit. ``bblock`` (resolved to the largest divisor of N) is the width
+    of the blocks that stream a page per row.
     """
     N = q.shape[0]
     bb = _resolve_bb(bblock, N)
     row_limits = row_limits.astype(jnp.int32)
     row_tables = row_tables.astype(jnp.int32)
     layer_arr = jnp.asarray(layer, jnp.int32).reshape(1)
-    share = None
-    if bb > 1:
-        # per block: its first live row, if every live row's table equals
-        # that row's (then any page one of them needs is at the same entry
-        # of that row), else -1
-        live = (row_limits > 0).reshape(N // bb, bb)
-        tabs = row_tables.reshape(N // bb, bb, -1)
-        first = jnp.argmax(live, axis=1).astype(jnp.int32)
-        lead = jnp.take_along_axis(tabs, first[:, None, None], axis=1)
-        same = jnp.all((tabs == lead) | ~live[:, :, None], axis=(1, 2))
-        share = jnp.where(same & live.any(axis=1),
-                          jnp.arange(N // bb, dtype=jnp.int32) * bb + first,
-                          -1)
+    share, wide = _share_facts(q, pool_k, row_limits, row_tables, bb)
     return _paged_flash_db(
         q, pool_k, pool_v, row_limits, layer_arr, row_tables,
         bb=bb, R=1, spec=False, window=window, interpret=interpret,
-        pool_ks=pool_ks, pool_vs=pool_vs, share=share)
+        pool_ks=pool_ks, pool_vs=pool_vs, share=share, wide=wide)
 
 
 # -- the entry points of a list that holds window AND full layers -------------
@@ -703,21 +901,14 @@ def _ragged_by_slot(q, pool_k, pool_v, row_limits, layer, table, row_map,
     bb = _resolve_bb(bblock, N)
     row_map = row_map.astype(jnp.int32)
     row_limits = row_limits.astype(jnp.int32)
-    share = None
-    if bb > 1:      # a block whose live rows all name one slot shares it
-        live = (row_limits > 0).reshape(N // bb, bb)
-        slots = row_map.reshape(N // bb, bb)
-        first = jnp.argmax(live, axis=1).astype(jnp.int32)
-        lead = jnp.take_along_axis(slots, first[:, None], axis=1)
-        same = jnp.all((slots == lead) | ~live, axis=1)
-        share = jnp.where(same & live.any(axis=1),
-                          jnp.arange(N // bb, dtype=jnp.int32) * bb + first,
-                          -1)
+    # a block, or a tile, whose live rows all name one slot shares it
+    share, wide = _share_facts(q, pool_k, row_limits, row_map, bb)
     return _paged_flash_db(
         q, pool_k, pool_v, row_limits,
         jnp.asarray(layer, jnp.int32).reshape(1), table.astype(jnp.int32),
         bb=bb, R=1, spec=False, window=window, interpret=interpret,
-        pool_ks=None, pool_vs=None, share=share, row_map=row_map)
+        pool_ks=None, pool_vs=None, share=share, wide=wide,
+        row_map=row_map)
 
 
 decode_attend_pallas_paged_window = _named_entry(

@@ -296,6 +296,15 @@ class EngineMetrics:
             "list that also holds full ones (which that family then "
             "counts), per window layer: the pages inside the rows' "
             "windows, and those their blocks walk", ("kind",)))
+        self.ragged_page_steps = r.register(Counter(
+            "tpu_serve_ragged_page_steps_total",
+            "Page steps (one page fetched and folded into a flash state) "
+            "the ragged kernel walks for the chunk rows of the mixed "
+            "dispatches, over every attending layer: path=\"tile\" as its "
+            "grid steps are cut — a tile of chunk rows is one walk —, "
+            "path=\"by8\" what blocks of decode_bblock rows walk for the "
+            "same rows; their ratio is how much wider the tile is where "
+            "it engages", ("path",)))
         self.sample_dispatches = r.register(Counter(
             "tpu_serve_sample_dispatches_total",
             "Dispatches by what their sampler ran, by step program: "

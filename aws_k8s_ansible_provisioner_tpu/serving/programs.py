@@ -1664,6 +1664,57 @@ class EnginePrograms:
                 "win_pages_held": int(self.win_allocator.pages_in_use),
                 "win_pages_unreleased": int(self._win_unreleased)}
 
+    def _chunk_page_steps(self, C: int, off: int, n: int) -> dict:
+        """Dispatch-record fields of a mixed dispatch, per attending layer:
+        ``chunk_page_steps`` — the page steps (one page fetched and folded
+        into a flash state) the ragged kernel walks for the chunk's ``n``
+        live rows at ``off`` of a ``C``-row chunk, its grid steps cut as
+        the device cuts them: a tile of pallas_attention._tile_rows rows
+        that holds chunk rows only is ONE walk from its lowest row's first
+        page to its highest row's last, one that also holds decode rows
+        walks block by block — beside ``chunk_page_steps_by8``, what blocks
+        of ``decode_bblock`` rows walk for the same rows (every tile's
+        cost before the tile widened; a selecting model's still). Decode
+        rows that share a BLOCK with chunk rows (slots no multiple of the
+        block) are left out of both. A list with window layers beside
+        full ones: those two are a FULL layer's, ``win_chunk_page_steps``
+        / ``win_chunk_page_steps_by8`` a window layer's."""
+        from aws_k8s_ansible_provisioner_tpu.ops.pallas_attention import (
+            _resolve_bb, _tile_rows)
+
+        ps, B = self.serving.page_size, self.num_slots
+        tp = self.mesh.shape.get("tp", 1) if self.mesh is not None else 1
+        bb = _resolve_bb(self.decode_bblock, B + C)
+        tile = bb if self.cfg.selects else _tile_rows(
+            B + C, bb, self.cfg.num_heads // tp, self.cfg.head_dim, ps,
+            jnp.int8 if self.kv_quant else self.serving.dtype)
+        limits = np.zeros(B + C, np.int64)
+        limits[B:B + n] = off + 1 + np.arange(n)
+        hi = np.minimum(-(-limits // ps), self.pages_per_slot) - 1
+
+        def steps(window: int) -> tuple:
+            lo = np.maximum(limits - window, 0) // ps if window > 0 \
+                else np.zeros_like(hi)
+
+            def walks(width: int):      # a run of ``width`` rows: one walk
+                top = hi.reshape(-1, width).max(axis=1)
+                low = np.where(limits > 0, lo, self.pages_per_slot) \
+                    .reshape(-1, width).min(axis=1)
+                return np.maximum(top - low + 1, 0)
+
+            by8 = walks(bb)
+            mixed = -(-B // tile)       # the tiles that hold decode rows
+            return (int(walks(tile)[mixed:].sum()
+                        + by8[:mixed * (tile // bb)].sum()), int(by8.sum()))
+
+        wide, by8 = steps(self.cfg.attn_window)
+        out = {"chunk_page_steps": wide, "chunk_page_steps_by8": by8}
+        if self.cfg.windowed:
+            wide, by8 = steps(self.cfg.sliding_window)
+            out.update(win_chunk_page_steps=wide,
+                       win_chunk_page_steps_by8=by8)
+        return out
+
     def _live_rows(self, active):
         """[B] bool device mask of the decode rows that hold a request, for
         an MoE model's step programs (None for a dense model: no operand);
@@ -1697,7 +1748,8 @@ class EnginePrograms:
         carry_steps = steps of an unfetched
         predecessor the device-side lengths are ahead of the mirrors by,
         write_pages = page windows of the pool that hold a row of a mixed
-        step's chunk: what its span write changes, whatever chunk_rows is).
+        step's chunk: what its span write changes, whatever chunk_rows is,
+        chunk_page_steps / chunk_page_steps_by8 = ``_chunk_page_steps``).
         Closed by ``_dispatch_close`` on the blocking half. For an MoE
         model ``_decode_fetch`` adds, to the decode and mixed records,
         ``moe_rows`` ((token, expert) rows of live tokens per layer: k x
@@ -1794,6 +1846,14 @@ class EnginePrograms:
                                                kind="live")
             self.metrics.window_attn_pages.inc(rec["win_pages_walked"],
                                                kind="walked")
+        if "chunk_page_steps" in rec:
+            full = rec.get("attn_layers_full", self.cfg.num_attn_layers)
+            win = rec.get("attn_layers_window", 0)
+            for path, sfx in (("tile", ""), ("by8", "_by8")):
+                self.metrics.ragged_page_steps.inc(
+                    full * rec["chunk_page_steps" + sfx]
+                    + win * rec.get("win_chunk_page_steps" + sfx, 0),
+                    path=path)
         if "sample_rows" in rec:
             self.metrics.sample_dispatches.inc(
                 program=rec["program"],
@@ -2392,7 +2452,8 @@ class EnginePrograms:
                             + (req.temperature > 0)),
             carry_steps=prev["horizon"] if prev is not None else 0,
             **self._kda_rows(len(active) + len(chunk), len(active)),
-            **self._attn_layers())
+            **self._attn_layers(),
+            **self._chunk_page_steps(st["C"], off, len(chunk)))
         self._book_bubble(drec["t_enqueue"])
         real_counts = self.counts
         with _Dispatching(drec):

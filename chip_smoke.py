@@ -518,6 +518,13 @@ def run_requests(srv: Server, model: str) -> dict:
     for kind in ("prefill", "prefill_batch", "decode", "mixed_step"):
         check(kind in ran, f"program kind {kind!r} never dispatched "
                            f"(dispatched: {sorted(ran)})")
+    tile, by8 = (delta(f'tpu_serve_ragged_page_steps_total{{path="{p}"}}')
+                 for p in ("tile", "by8"))
+    check(by8 >= tile > 0, f"the admitted chunks walked {tile} page steps "
+                           f"as tiles, {by8} as blocks of 8 rows")
+    say(f"requests: the admitted chunks' rows walked {int(tile)} page steps "
+        f"as the ragged kernel's tiles are cut; blocks of 8 rows would "
+        f"have walked {int(by8)}")
     say(f"requests: /metrics tokens +{int(got)}, prefix hits +{int(hits)}, "
         f"0 error/timeout; programs dispatched: {sorted(ran)}")
     return streams
@@ -722,12 +729,50 @@ def kernel_parity(cfg, slots: int, window: int, page: int, bblocks,
                   .any(), f"ragged {tag} bb={bb}: a dead row is not zero")
             say(f"parity: decode/ragged paged {tag} bb={bb}: max abs err "
                 f"{e1:.2e} / {e2:.2e} (tol {KERNEL_TOL})")
+        # the served mixed step's WIDTH: every decode row, then a chunk as
+        # wide as the window — slot 3's prompt of 640 tokens from row 0 —
+        # so the grid steps are the wide tiles of pallas_attention
+        # ._tile_rows: the first straddles decode and chunk rows (its
+        # blocks run one by one), the chunk's first rows read ONE page,
+        # the tile that holds row 640 is part dead and 1,400 rows of
+        # tiles are dead. The chunk's reference a block of rows at a time
+        # against slot 3's gathered view (2,048 rows at once: 8.6 GB)
+        bb = max(bblocks)
+        wide_n = jnp.arange(window, dtype=jnp.int32)
+        wlimits = jnp.concatenate(
+            [jnp.where(jnp.arange(B) == 3, 0, lengths),
+             jnp.where(wide_n < 640, wide_n + 1, 0)])
+        wtab = jnp.concatenate(
+            [table, jnp.broadcast_to(table[3][None], (window, MP))])
+        qw = jax.random.normal(keys[3], (B + window, Hq, D), jnp.bfloat16)
+        out = pa.ragged_attend_pallas_paged(
+            qw, pool["k"], pool["v"], wlimits, layer, wtab,
+            interpret=interpret, bblock=bb, **skw)
+        with jax.default_matmul_precision("highest"):
+            ck, cv = dense_view(table)
+            want = [decode_attend(qw[:B, None], ck, cv, wlimits[:B])[:, 0]]
+            ck, cv = dense_view(table[3][None])
+            want += [decode_attend(qw[None, B + s0:B + s0 + 512], ck, cv,
+                                   jnp.asarray([s0 + 1]))[0]
+                     for s0 in range(0, window, 512)]
+        want = jnp.where((wlimits > 0)[:, None, None],
+                         jnp.concatenate(want), 0)
+        tile = pa._tile_rows(B + window, pa._resolve_bb(bb, B + window),
+                             Hq, D, page, pool["k"].dtype)
+        e4 = close(f"ragged_attend_pallas_paged {tag} bb={bb}, tiles of "
+                   f"{tile} rows", out, want)
+        check(not np.asarray(out, np.float32)[np.asarray(wlimits) == 0]
+              .any(), f"ragged {tag}, tiles of {tile}: a dead row is not "
+                      f"zero")
+        say(f"parity: ragged paged {tag} at the served width, {B}+{window} "
+            f"rows in tiles of {tile} (one straddling, one part dead, "
+            f"one-page rows): max abs err {e4:.2e} (tol {KERNEL_TOL})")
+        del ck, cv, want, wtab, qw
         # the decode program's call: make_decode_attend_carry_paged writes
         # each slot's row, then hands the kernel the rows IN ORDER OF LENGTH
         # (every slot-order block of 8 here holds a one-page row beside a
         # full window) and un-permutes the context — compiled, against the
         # reference and BITWISE against the slot-order call on the same pool
-        bb = max(bblocks)
         knew, vnew = (jax.random.normal(k, (B, 1, Hkv, D), jnp.bfloat16)
                       for k in keys[6:8])
         attend = make_decode_attend_carry_paged(lengths - 1, table,
@@ -834,6 +879,17 @@ def chunk_write_time(kvp, pool, table, layer, page, chunk, tag) -> None:
         f"layer: {(time.monotonic() - t0) / (n * reps) * 1e3:.3f} ms")
 
 
+def call_ms(call, n: int) -> float:
+    """Milliseconds a call of ``call`` takes on the device: ``n`` of them
+    enqueued back to back after one that compiled it."""
+    call().block_until_ready()
+    t0 = time.monotonic()
+    for _ in range(n):
+        out = call()
+    out.block_until_ready()
+    return (time.monotonic() - t0) / n * 1e3
+
+
 def ragged_call_time(pa, pool, skw, table, lengths, layer, Hq, D, chunk,
                      bb, tag) -> None:
     """Device time of ONE ragged call at the served mixed step's shape:
@@ -860,13 +916,8 @@ def ragged_call_time(pa, pool, skw, table, lengths, layer, Hq, D, chunk,
                 q, pool["k"], pool["v"], limits, layer, rtab, bblock=bb,
                 **skw)
 
-        call().block_until_ready()
-        t0, n = time.monotonic(), 20
-        for _ in range(n):
-            out = call()
-        out.block_until_ready()
         say(f"ragged call {tag} bb={bb}, {B}+{chunk} rows, 640-token "
-            f"chunk, {name}: {(time.monotonic() - t0) / n * 1e3:.3f} ms")
+            f"chunk, {name}: {call_ms(call, 20):.3f} ms")
 
 
 # ---------------------------------------------------------------------------
@@ -1714,6 +1765,28 @@ def window_kernel_parity(cfg, slots: int, window: int, page: int, bb: int,
         want = jnp.where((limits > 0)[:, None, None], want, 0)
         out[name] = close(f"ragged kernel, one table row a slot, {name}",
                           got, want)
+    if not interpret:
+        # ONE call of each kind at the served mixed step's shape: a first
+        # chunk (every row live from row 0) and a second one (from row
+        # ``chunk``, three fifths of it live) beside the decode rows
+        for name, fn, tab, kw in (
+                ("full", pa.ragged_attend_pallas_paged_slots, full, {}),
+                ("window", pa.ragged_attend_pallas_paged_slots_window, wtab,
+                 {"window": W})):
+            for what, first, n_live in (("first chunk", 0, C),
+                                        ("second chunk", C, 3 * C // 5)):
+                if first + C > window:
+                    continue
+                lim = np.concatenate(
+                    [np.where(np.arange(B) == pslot, 0, lens),
+                     np.where(np.arange(C) < n_live,
+                              first + 1 + np.arange(C), 0)]).astype(np.int32)
+                args = (qn, pool["k"], pool["v"], jnp.asarray(lim), layer,
+                        jnp.asarray(full if first == 0 else tab),
+                        jnp.asarray(row_map))
+                ms = call_ms(lambda: fn(*args, bblock=bb, **kw), 10)
+                say(f"ragged call by slot, {name}, {B}+{C} rows, {what} "
+                    f"({n_live} live from row {first}): {ms:.3f} ms")
     say(f"kernel parity (window and full kinds, Hq {Hq} Hkv {Hkv}, {B} slots "
         f"+ a {C}-row chunk, window {W} of {window}, bblock {bb}): decode "
         f"under the window max |diff| {d_dec:.4f}; ragged by slot full "

@@ -412,3 +412,94 @@ def test_trace_names_the_benchmark_readers_match():
     for name in ("jit_decode_steps", "jit_mixed_step", "jit_prefill_step",
                  "jit_prefill_batch_step"):
         assert name[4:] in seen
+
+
+# -- the chunk's page steps (PR 40) ------------------------------------------
+
+
+def _page_steps_row_by_row(B, C, off, n, bb, tile, ps, pages, window):
+    """``_chunk_page_steps`` again, a grid step at a time as the kernel's
+    body reads it: (as the tiles walk, as blocks of ``bb`` walk)."""
+    limits = [0] * B + [off + j + 1 if j < n else 0 for j in range(C)]
+
+    def walk(rows):
+        live = [x for x in rows if x > 0]
+        if not live:
+            return 0
+        hi = max(min(-(-x // ps), pages) - 1 for x in live)
+        lo = min(max(x - window, 0) // ps for x in live) if window else 0
+        return hi - lo + 1
+
+    by8 = [walk(limits[i:i + bb]) for i in range(0, B + C, bb)]
+    tiles = 0
+    for g in range(0, B + C, tile):
+        if g < B:       # holds decode rows: block by block
+            tiles += sum(by8[g // bb:(g + tile) // bb])
+        else:
+            tiles += walk(limits[g:g + tile])
+    return tiles, sum(by8)
+
+
+@pytest.mark.parametrize("slots,chunk,off,n,window", [
+    (8, 112, 0, 100, 0),        # 120 rows: tiles of 40, the worked case
+    (8, 112, 0, 9, 0),          # a one-page prompt
+    (8, 112, 70, 112, 0),       # a later chunk, from mid page
+    (8, 112, 70, 40, 64),       # under a window
+    (8, 160, 300, 160, 64),     # 168 rows: tiles of 56, a window behind
+    (4, 16, 0, 16, 0),          # 20 rows: no wider tile than a block of 4
+    (40, 80, 5, 80, 0),         # a whole tile of decode rows
+])
+def test_chunk_page_steps_mirror_the_kernels_grid(model, slots, chunk, off,
+                                                  n, window):
+    import dataclasses
+
+    from aws_k8s_ansible_provisioner_tpu.ops.pallas_attention import (
+        _resolve_bb, _tile_rows)
+
+    _, cfg, params = model
+    cfg = dataclasses.replace(cfg, sliding_window=window, max_seq_len=512)
+    eng = Engine(cfg, params, _serving(
+        max_decode_slots=slots, max_cache_len=512, prefill_chunk=chunk,
+        decode_bblock=8, decode_pipeline=1, ragged_attention=1))
+    got = eng._chunk_page_steps(chunk, off, n)
+    bb = _resolve_bb(8, slots + chunk)
+    tile = _tile_rows(slots + chunk, bb, cfg.num_heads, cfg.head_dim, 32,
+                      jnp.float32)
+    want = _page_steps_row_by_row(slots, chunk, off, n, bb, tile, 32,
+                                  eng.pages_per_slot, window)
+    assert (got["chunk_page_steps"], got["chunk_page_steps_by8"]) == want
+    assert got["chunk_page_steps_by8"] >= got["chunk_page_steps"] > 0
+    if (slots, chunk, off, n) == (8, 112, 0, 100):
+        assert tile == 40 and want == (4 + 3 + 4, 4 + 11 + 13)
+    if tile == bb:
+        assert want[0] == want[1]
+
+
+def test_mixed_record_and_metrics_carry_the_chunks_page_steps(model):
+    """A mixed dispatch's record says how many page steps its chunk rows
+    walk as the kernel's tiles are cut and how many blocks of 8 would have,
+    and ``/metrics`` counts both over the attending layers; no other
+    program's record carries them."""
+    _, cfg, params = model
+    eng = Engine(cfg, params, _serving(
+        max_decode_slots=8, max_cache_len=256, prefill_chunk=112,
+        decode_bblock=8, decode_pipeline=1, ragged_attention=1))
+    eng.submit(_req(9, 24))
+    for _ in range(3):
+        eng.step()                  # a decode dispatch is in flight
+    eng.submit(_req(100, 4, start=50))
+    _drain(eng)
+    events = [e for e in _flight.get().tail(4096) if e["type"] == "dispatch"]
+    mixed = [e for e in events if e["program"] == "mixed_step"]
+    assert mixed and all(("chunk_page_steps" in e) == (e in mixed)
+                         for e in events)
+    rec, = mixed
+    assert (rec["chunk_off"], rec["chunk_n"], rec["chunk_rows"]) \
+        == (0, 100, 112)
+    assert (rec["chunk_page_steps"], rec["chunk_page_steps_by8"]) == (11, 28)
+    text = eng.metrics.registry.render()
+    layers = cfg.num_attn_layers
+    for path, steps in (("tile", 11), ("by8", 28)):
+        line, = [ln for ln in text.splitlines() if ln.startswith(
+            f'tpu_serve_ragged_page_steps_total{{path="{path}"}}')]
+        assert float(line.split()[-1]) == layers * steps

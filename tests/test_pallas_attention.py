@@ -67,6 +67,15 @@ def _to_pool(dense, table, PS):
     return pool
 
 
+def _with_nan_page(pool):
+    """``pool`` with one more page at the end, all NaN (127 in an int8
+    leaf): what a fetch that should not have happened brings back."""
+    return {n: jnp.concatenate([a, jnp.full_like(a[:, :1], jnp.nan)
+                                if a.dtype == jnp.float32
+                                else jnp.full_like(a[:, :1], 127)], axis=1)
+            for n, a in pool.items()}
+
+
 @pytest.mark.parametrize("bb", [1, 4, 8])
 @pytest.mark.parametrize("quant", [False, True])
 def test_paged_db_decode_parity(bb, quant):
@@ -200,10 +209,7 @@ def test_ragged_mixed_layout_parity(quant, bb, window, B, pstart, plen):
     tab = np.asarray(table)
     # one more page, all NaN, that no table names: an out-of-range id clamps
     # onto it in interpret mode, so a fetch through a dead row's entry shows
-    pool = {n: jnp.concatenate([a, jnp.full_like(a[:, :1], jnp.nan)
-                                if a.dtype == jnp.float32
-                                else jnp.full_like(a[:, :1], 127)], axis=1)
-            for n, a in pool.items()}
+    pool = _with_nan_page(pool)
     lengths = np.asarray([1, 128, 0, 64, 33, 97, 2, 128][:B], np.int32)
     j = np.arange(C)
     limits = np.concatenate([np.where(np.arange(B) == pslot, 0, lengths),
@@ -231,6 +237,140 @@ def test_ragged_mixed_layout_parity(quant, bb, window, B, pstart, plen):
     np.testing.assert_allclose(out[live], np.asarray(ref)[live],
                                rtol=tol, atol=tol)
     assert np.array_equal(out[~live], np.zeros_like(out[~live]))
+
+
+# ---------------------------------------------------------------------------
+# The WIDE tile: where N has a divisor of 16..64 rows a grid step holds that
+# many, and a step whose live rows all read one table row — the chunk's —
+# is served as one query tile over one page stream. The served row counts
+# scaled down: 2,080 = 52 x 40 -> 120 = 3 x 40; 2,064 = 43 x 48 -> 144;
+# 2,072 and 4,144 = 37 / 74 x 56 -> 168 and 280; 576 = 9 x 64 -> 192.
+# ---------------------------------------------------------------------------
+
+
+def _wide_case(N, B, pstart, plen, *, groups=2, window=0, quant=False,
+               by_slot=False, PS=16, Hkv=2, D=32, seed=59):
+    """A mixed step's packed rows — B decode rows (slot 2's dead: it is the
+    one chunking), then N - B chunk rows of slot 2 at pstart + j, the first
+    ``plen`` of them live — through the ragged entry, and the jnp reference.
+    Under a window the chunking slot's pages BELOW its first row's window
+    are released: their table entries name the NaN page."""
+    C, pslot, Hq = N - B, 2, Hkv * groups
+    S = -(-(pstart + C + 1) // PS) * PS
+    dense, pool, table = _paged_layout(B=B, S=S, Hkv=Hkv, D=D, PS=PS,
+                                       quant=quant, seed=seed)
+    nan_page = pool["k"].shape[1]
+    pool = _with_nan_page(pool)
+    tab = np.asarray(table).copy()
+    lengths = np.resize(np.asarray([1, S, 0, PS, 2 * PS + 1, S - 3, 2, S],
+                                   np.int32), B)
+    j = np.arange(C)
+    limits = np.concatenate([np.where(np.arange(B) == pslot, 0, lengths),
+                             np.where(j < plen, pstart + j + 1, 0)]
+                            ).astype(np.int32)
+    rows_of = np.concatenate([np.arange(B), np.full(C, pslot)])
+    q = jax.random.normal(jax.random.PRNGKey(seed + 1), (N, Hq, D))
+    ck, cv = dense["k"][0][rows_of], dense["v"][0][rows_of]
+    if quant:
+        ck = kvp.dequantize(ck, dense["ks"][0][rows_of])
+        cv = kvp.dequantize(cv, dense["vs"][0][rows_of])
+    ref = decode_attend(q[:, None], ck, cv, jnp.asarray(limits),
+                        window=window)[:, 0]
+    if window:      # what lies below every row's window went back
+        for b, first in enumerate(lengths):
+            first = pstart + 1 if b == pslot else first
+            tab[b, :max(first - window, 0) // PS] = nan_page
+    pkw = dict(pool_ks=pool["ks"], pool_vs=pool["vs"]) if quant else {}
+    if by_slot:
+        fn = (pa.ragged_attend_pallas_paged_slots_window if window
+              else pa.ragged_attend_pallas_paged_slots)
+        out = fn(q, pool["k"], pool["v"], jnp.asarray(limits), jnp.int32(0),
+                 jnp.asarray(tab), jnp.asarray(rows_of, jnp.int32),
+                 interpret=True, bblock=8,
+                 **({"window": window} if window else {}))
+    else:
+        row_tables = tab[rows_of].copy()
+        row_tables[limits == 0] = 10_000      # dead rows: never a page id
+        out = pa.ragged_attend_pallas_paged(
+            q, pool["k"], pool["v"], jnp.asarray(limits), jnp.int32(0),
+            jnp.asarray(row_tables), interpret=True, window=window,
+            bblock=8, **pkw)
+    return np.asarray(out), np.asarray(ref), limits
+
+
+_WIDE_GRID = [
+    # id, N, B, pstart (chunk_off), plen, kwargs
+    ("n120-t40-straddle", 120, 8, 0, 112, {}),
+    ("n120-t40-part-dead-tile", 120, 8, 0, 50, {}),
+    ("n120-t40-dead-tiles", 120, 8, 0, 20, {}),
+    ("n120-t40-mid-page-off", 120, 8, 37, 112, {}),
+    ("n120-t40-one-page-prompt", 120, 8, 0, 9, {}),
+    ("n120-t40-aligned-decode", 120, 40, 21, 80, {}),
+    ("n144-t48", 144, 8, 5, 136, {}),
+    ("n168-t56", 168, 8, 70, 160, {}),
+    ("n280-t56", 280, 8, 200, 272, {}),
+    ("n192-t64", 192, 8, 33, 150, {}),
+    ("n168-t56-mha", 168, 8, 70, 100, {"groups": 1}),
+    ("n168-t56-groups8", 168, 8, 70, 160, {"groups": 8, "Hkv": 1}),
+    ("n120-int8-keeps-blocks-of-8", 120, 8, 37, 100, {"quant": True}),
+    ("n120-t40-window", 120, 8, 150, 112, {"window": 48}),
+    ("n120-t40-by-slot", 120, 8, 37, 100, {"by_slot": True}),
+    ("n168-t56-by-slot-window-released", 168, 8, 300, 130,
+     {"by_slot": True, "window": 48, "groups": 8, "Hkv": 1}),
+    ("n168-t56-by-slot-window-one-page", 168, 8, 0, 11,
+     {"by_slot": True, "window": 48}),
+]
+
+
+@pytest.mark.parametrize("N,B,pstart,plen,kw",
+                         [c[1:] for c in _WIDE_GRID],
+                         ids=[c[0] for c in _WIDE_GRID])
+def test_ragged_wide_tile_parity(N, B, pstart, plen, kw):
+    out, ref, limits = _wide_case(N, B, pstart, plen, **kw)
+    live = limits > 0
+    assert np.isfinite(out).all()
+    tol = 4e-2 if kw.get("quant") else 2e-5
+    np.testing.assert_allclose(out[live], ref[live], rtol=tol, atol=tol)
+    assert np.array_equal(out[~live], np.zeros_like(out[~live]))
+
+
+def test_tile_rows_come_from_the_shapes():
+    """The served row counts and head shapes give the widths the records
+    name; a row count with no divisor of 16..64 keeps blocks of 8."""
+    for n, hq, want in ((2080, 16, 40), (2064, 32, 48), (2072, 16, 56),
+                        (4144, 32, 56), (2072, 4, 56), (8 * 257, 16, 8)):
+        assert pa._tile_rows(n, 8, hq, 128, 64, jnp.bfloat16) == want, \
+            (n, hq)
+    # 64 query heads: a 64-row tile's working set outgrows the budget
+    assert pa._tile_rows(576, 8, 16, 128, 64, jnp.bfloat16) == 64
+    assert pa._tile_rows(576, 8, 64, 128, 64, jnp.bfloat16) == 32
+    # nothing shares a stream in blocks of one row; an int8 pool's scales
+    # ride a page's lanes, so its sharing blocks stay 8 rows
+    assert pa._tile_rows(2080, 1, 16, 128, 64, jnp.bfloat16) == 1
+    assert pa._tile_rows(2080, 8, 16, 128, 64, jnp.int8) == 8
+
+
+@pytest.mark.parametrize("case", ["n168-t56", "n120-t40-window",
+                                  "n168-t56-by-slot-window-released"])
+def test_a_rows_output_is_bitwise_the_same_in_a_tile_of_any_width(
+        case, monkeypatch):
+    """Tile widths T and 8 (blocks of 8 rows: the path every row took
+    before the tile widened) give every row the same bits: what a wider
+    tile walks outside a row's own pages is fully masked for it. At the
+    served head_dim: at a toy one the CPU backend multiplies 16 rows by
+    another routine than 112 and the last bit of a product moves."""
+    _, N, B, pstart, plen, kw = next(c for c in _WIDE_GRID if c[0] == case)
+    kw = dict(kw, D=128)
+    wide, _, _ = _wide_case(N, B, pstart, plen, **kw)
+    widths = []
+    real = pa._tile_rows
+    monkeypatch.setattr(pa, "_tile_rows",
+                        lambda *a: widths.append(real(*a)) or a[1])
+    jax.clear_caches()
+    by8, _, _ = _wide_case(N, B, pstart, plen, **kw)
+    jax.clear_caches()
+    assert widths and widths[0] > 8
+    assert np.array_equal(wide, by8)
 
 
 # ---------------------------------------------------------------------------

@@ -86,6 +86,40 @@ def _assert_named_after_wrapper(compiled, fn):
                      compiled.as_text()), fn.__name__
 
 
+def _pallas_grids(fn, *args, **kw):
+    """The grid of every Pallas call ``fn`` traces to."""
+    grids = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                grids.append(tuple(eqn.params["grid_mapping"].grid))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(lambda *a, **k: fn(*a, **k))(*args, **kw).jaxpr)
+    return grids
+
+
+def _assert_one_call_of_wide_tiles(compiled, wrapper, fn, rows, *args, **kw):
+    """A ragged entry stays ONE Pallas call under its wrapper's name
+    (benchmark/layer_metrics/ragged_attn_roofline_pct.py multiplies one
+    call's need by the events it finds) whose grid steps are the tiles
+    pallas_attention._tile_rows reads off the shapes."""
+    import re
+
+    text = compiled.as_text()
+    assert len(re.findall(rf"%{wrapper.__name__}(\.\d+)? = [^\n]*"
+                          rf"custom-call", text)) == 1
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
+                          text)) == 1
+    q, pool = args[0], args[1]
+    tile = pa._tile_rows(rows, 8, q.shape[1], q.shape[2], pool.shape[3],
+                         pool.dtype)
+    assert tile > 8 and _pallas_grids(fn, *args, **kw) == [(rows // tile,)]
+    return tile
+
+
 CASES = [
     # (id, entry point, quant, bblock, rows, query rows per slot, Hq, Hkv)
     ("decode-bf16-bb1", "decode", False, 1, B, 1, HQ, HKV),
@@ -106,13 +140,21 @@ CASES = [
     # query rows a KV head, and a 64-token page is 262 KB each for K and V
     ("decode-bf16-bb8-mha", "decode", False, 8, 24, 1, 16, 16),
     ("ragged-bf16-bb8-mha", "ragged", False, 8, 24 + CHUNK, 1, 16, 16),
+    # the KDA hybrid's attending layers: 64 slots and a 512-row chunk, 64
+    # query heads — a 64-row tile's working set outgrows its VMEM budget
+    ("ragged-bf16-bb8-solar", "ragged", False, 8, 64 + 512, 1, 64, 8),
 ]
+# the width of a ragged case's tiles at block 8 (pa._tile_rows)
+# (an int8 pool keeps blocks of 8: its scales ride a page's lanes)
+TILES = {"ragged-bf16-bb8": 40, "ragged-bf16-bb8-8b": 48,
+         "ragged-bf16-bb8-tp4": 40, "ragged-bf16-bb8-mha": 56,
+         "ragged-bf16-bb8-solar": 32}
 
 
-@pytest.mark.parametrize("entry,quant,bb,rows,R,hq,hkv",
-                         [c[1:] for c in CASES], ids=[c[0] for c in CASES])
-def test_paged_attention_kernel_compiles_for_v5e(chip, entry, quant, bb,
-                                                 rows, R, hq, hkv):
+@pytest.mark.parametrize("case,entry,quant,bb,rows,R,hq,hkv", CASES,
+                         ids=[c[0] for c in CASES])
+def test_paged_attention_kernel_compiles_for_v5e(chip, case, entry, quant,
+                                                 bb, rows, R, hq, hkv):
     sds, kv, skw = _pool(chip, quant, hkv)
     lens, lay = sds((rows,), jnp.int32), sds((), jnp.int32)
     table = sds((rows, 32), jnp.int32)
@@ -129,6 +171,13 @@ def test_paged_attention_kernel_compiles_for_v5e(chip, entry, quant, bb,
                         lay, table, **skw)
     assert "tpu_custom_call" in compiled.as_text()
     _assert_named_after_wrapper(compiled, fn)
+    if case in TILES:
+        assert _assert_one_call_of_wide_tiles(
+            compiled, fn, functools.partial(fn, bblock=bb), rows, q, kv, kv,
+            lens, lay, table, **skw) == TILES[case]
+    else:       # decode, spec, a block of one row, an int8 pool: blocks
+        assert _pallas_grids(functools.partial(fn, bblock=bb), q, kv, kv,
+                             lens, lay, table, **skw) == [(rows // bb,)]
 
 
 @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
@@ -524,14 +573,19 @@ def test_ragged_kernel_under_page_masks_compiles_for_v5e(chip, chunk):
     sds, kv = _sala_pool(chip)
     i32, N = jnp.int32, S_B + chunk
     fn = pa.ragged_attend_pallas_paged_select
-    compiled = _compile(
-        functools.partial(fn, bblock=8), sds((N, S_HQ, D), jnp.bfloat16), kv,
-        kv, sds((N,), i32), sds((), i32), sds((S_B, S_MP), i32),
-        sds((N,), i32), sds((N, S_HKV, S_MP // 32), i32))
+    args = (sds((N, S_HQ, D), jnp.bfloat16), kv, kv, sds((N,), i32),
+            sds((), i32), sds((S_B, S_MP), i32), sds((N,), i32),
+            sds((N, S_HKV, S_MP // 32), i32))
+    compiled = _compile(functools.partial(fn, bblock=8), *args)
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.as_text().count("custom_call_target=\"tpu_custom_call\"") \
         == (2 if chunk == 8192 else 1)
     _assert_named_after_wrapper(compiled, fn)
+    # the selecting entry keeps blocks of 8 rows a grid step (a page none of
+    # 8 rows chose skips its update; the union over 56 is nearly every page)
+    grids = _pallas_grids(functools.partial(fn, bblock=8), *args)
+    assert sum(g for g, in grids) == N // 8 and len(grids) == \
+        (2 if chunk == 8192 else 1)
 
 
 # Trinity-Mini's shape as served: 4 KV heads, 32 query heads (groups 8), 48
@@ -591,11 +645,15 @@ def test_ragged_kernel_by_slot_compiles_for_v5e(chip, kind):
         fn = functools.partial(pa.ragged_attend_pallas_paged_slots_window,
                                bblock=8, window=T_W)
         wrapper = pa.ragged_attend_pallas_paged_slots_window
-    compiled = _compile(
-        fn, sds((N, T_HQ, D), jnp.bfloat16), kv, kv, sds((N,), i32),
-        sds((), i32), sds((T_B, T_MP), i32), sds((N,), i32))
+    args = (sds((N, T_HQ, D), jnp.bfloat16), kv, kv, sds((N,), i32),
+            sds((), i32), sds((T_B, T_MP), i32), sds((N,), i32))
+    compiled = _compile(fn, *args)
     assert "tpu_custom_call" in compiled.as_text()
     _assert_named_after_wrapper(compiled, wrapper)
+    # 48 + 4,096 rows = 74 tiles of 56: the chunk's 4,096 rows stream their
+    # pages 73 times a layer where blocks of 8 streamed them 512 times
+    assert _assert_one_call_of_wide_tiles(compiled, wrapper, fn, N,
+                                          *args) == 56
 
 
 def test_selector_row_add_and_lightning_update_compile_for_v5e(chip):
