@@ -136,17 +136,23 @@ class ModelConfig:
     # The renormalised top-k weights times this (the afmoe router's
     # route_scale); 1.0 = as they are.
     route_scale: float = 1.0
+    # What a sigmoid router's renormalisation adds to the sum of the chosen
+    # scores (a constant of the model: afmoe 1e-20, lfm2_moe 1e-6).
+    route_norm_eps: float = 1e-20
     # Per-layer block kinds, one character a layer: "g" softmax attention
     # (the GQA block), "k" KDA linear attention, "s" softmax attention that
     # SELECTS the pages it reads (ops/sparse_attention.py), "l" Lightning
     # linear attention (ops/linear_attention.py), "w" softmax attention
     # over the last sliding_window keys, rotated (RoPE) whatever
     # attn_use_rope says of the "g" layers beside it, its K/V in page
-    # leaves and a page inventory of its own (ops/kv_pool.py). Two forms.
-    # A PERIOD of "g"/"k" kinds, shorter than the depth, repeats over it
-    # ("gkkk"). A LIST — one character a layer, any order, "g"/"s"/"l"
-    # kinds or "g"/"w" kinds — is the layers held, as they are
-    # ("slllllls", "wwwgwwwg"). "" = every layer the one kind the fields
+    # leaves and a page inventory of its own (ops/kv_pool.py), "c" a gated
+    # short convolution (conv_taps taps over the hidden width; its state
+    # the conv_taps - 1 rows before a span, ops/linear_attention.py). Two
+    # forms. A PERIOD of "g"/"k" kinds, shorter than the depth, repeats
+    # over it ("gkkk"). A LIST — one character a layer, any order,
+    # "g"/"s"/"l"/"c" kinds or "g"/"w" kinds — is the layers held, as they
+    # are ("slllllls", "wwwgwwwg", "ccgcccg..."). "" = every layer the one
+    # kind the fields
     # above describe. A string, not a tuple of enums: the config is a jit
     # static argument and is built from JSON by the benchmark.
     layer_pattern: str = ""
@@ -172,6 +178,11 @@ class ModelConfig:
     # per-head RMSNorm and a sigmoid gate on the output.
     lightning_num_heads: int = 0
     lightning_head_dim: int = 0
+    # "c" layers (the LFM2 gated short convolution): [B, C, X] = split3 of
+    # one hidden -> 3 x hidden projection, a depthwise causal convolution
+    # of conv_taps taps over B * X (no bias, no activation), gated by C,
+    # then a hidden -> hidden output projection.
+    conv_taps: int = 0
     # "s" layers (InfLLM-v2 block selection, arXiv:2509.24663): keys are
     # mean-pooled over windows of sparse_kernel_size every
     # sparse_kernel_stride tokens; a query scores the pooled keys, a block
@@ -242,16 +253,19 @@ class ModelConfig:
                 f"layer_pattern={pat!r}: the attending layers of a list "
                 f"either all select ('s') or none does ('g') — one attend "
                 f"callback serves them all")
-        if set(pat) - set("gsl"):
+        if set(pat) - set("gslc"):
             raise ValueError(
                 f"layer_pattern={pat!r}: a list holds 'g' (attention), 's' "
-                f"(selecting attention) and 'l' (Lightning) layers; 'k' "
-                f"(KDA) layers come in a period with one 'g'")
+                f"(selecting attention), 'l' (Lightning) and 'c' (gated "
+                f"short convolution) layers; 'k' (KDA) layers come in a "
+                f"period with one 'g'")
         if len(pat) != self.num_layers:
             raise ValueError(
                 f"layer_pattern={pat!r} names {len(pat)} layers, "
-                f"num_layers={self.num_layers}: a list with 's' or 'l' "
-                f"kinds gives one character a layer held")
+                f"num_layers={self.num_layers}: a list with 's', 'l' or "
+                f"'c' kinds gives one character a layer held")
+        if "c" in pat and self.conv_taps < 2:
+            raise ValueError("a 'c' layer needs conv_taps >= 2")
         if "l" in pat and not (self.lightning_num_heads
                                and self.lightning_head_dim):
             raise ValueError("an 'l' layer needs lightning_num_heads and "
@@ -283,19 +297,19 @@ class ModelConfig:
     def layer_list(self) -> bool:
         """The pattern is a LIST of the layers held (not a period)."""
         return bool(self.layer_pattern) and (
-            bool(set(self.layer_pattern) & set("slw"))
+            bool(set(self.layer_pattern) & set("slwc"))
             or (len(self.layer_pattern) == self.num_layers
                 and "k" not in self.layer_pattern))
 
     @property
     def recurrent(self) -> bool:
         """Some layers keep a recurrent state per sequence beside K/V."""
-        return bool(set(self.layer_pattern) & set("kl"))
+        return bool(set(self.layer_pattern) & set("klc"))
 
     @property
     def recurrent_kinds(self) -> str:
         """The recurrent kinds that are there, for a log line or a refusal."""
-        names = {"k": "KDA", "l": "Lightning"}
+        names = {"k": "KDA", "l": "Lightning", "c": "conv"}
         return "/".join(v for k, v in names.items()
                         if k in self.layer_pattern)
 
@@ -336,7 +350,7 @@ class ModelConfig:
     @property
     def num_recurrent_layers(self) -> int:
         if self.layer_list:
-            return self.layer_pattern.count("l")
+            return sum(self.layer_pattern.count(c) for c in "lc")
         return self.num_periods * self.kda_per_period
 
     @property
@@ -364,6 +378,33 @@ class ModelConfig:
         bs = self.sparse_block_size
         return max(self.sparse_topk, -(-self.sparse_dense_len // bs)) \
             if bs else 0
+
+    @property
+    def kv_lane_pack(self) -> int:
+        """K/V heads stored side by side in one pool row. LAYOUT, not
+        mathematics, and read off the shapes: a head narrower than the 128
+        lanes of a TPU vreg cannot be a pool's minor axis on the chip (the
+        HBM tiling pads a 64-wide page to twice its bytes and Mosaic refuses
+        to slice it), so where whole heads fill a row exactly — 128 //
+        head_dim of them, dividing num_kv_heads — they share one, and the
+        paged kernels read them as ONE 128-wide head
+        (ops/attention.lane_packed). A list with selecting ("s") or window
+        ("w") layers keeps a head a row: the selector's pooled keys are per
+        head, and neither kind's attend callback is wrapped."""
+        n = 128 // self.head_dim if self.head_dim < 128 else 1
+        if n * self.head_dim != 128 or self.num_kv_heads % n \
+                or set(self.layer_pattern) & set("sw"):
+            return 1
+        return n
+
+    @property
+    def pool_kv_heads(self) -> int:
+        """KV heads of the pool's leaves (``kv_lane_pack`` heads a row)."""
+        return self.num_kv_heads // self.kv_lane_pack
+
+    @property
+    def pool_head_dim(self) -> int:
+        return self.head_dim * self.kv_lane_pack
 
     @property
     def kda_size(self) -> int:
@@ -689,7 +730,42 @@ TRINITY_MINI_PP4_STAGE0 = ModelConfig(
     attn_use_rope=False,
 )
 
+# LiquidAI LFM2-8B-A1B (``model_type`` lfm2_moe), WHOLE: the published 24
+# layers — 18 gated short convolutions ("c", 3 taps) and 6 GQA layers ("g":
+# 32 query / 8 KV heads of 64, per-head q/k RMSNorm, RoPE) as
+# ``layer_types`` lists them, the two leading layers with a dense SwiGLU of
+# 7,168, the other 22 with 32 experts of 1,792, top-4 by sigmoid score +
+# selection bias, renormalised (+ 1e-6), no shared expert; tied embeddings
+# (benchmark/configs/lfm2-8b-a1b-int8.json states what is assumed).
+LFM2_8B_A1B = ModelConfig(
+    name="LiquidAI/LFM2-8B-A1B",
+    vocab_size=65536,
+    hidden_size=2048,
+    intermediate_size=7168,
+    num_layers=24,
+    num_heads=32,
+    num_kv_heads=8,
+    head_dim=64,
+    max_seq_len=128000,
+    rope_theta=1000000.0,
+    norm_eps=1e-5,
+    qk_norm=True,
+    tie_embeddings=True,
+    eos_token_id=7,
+    num_experts=32,
+    num_experts_per_tok=4,
+    moe_intermediate_size=1792,
+    norm_topk_prob=True,
+    router_scoring="sigmoid",
+    num_dense_layers=2,
+    route_norm_eps=1e-6,
+    layer_pattern="ccgcccgcccgcccgcccgccgcc",
+    conv_taps=3,
+    hf_repo="LiquidAI/LFM2-8B-A1B",
+)
+
 MODEL_REGISTRY = {
+    "LiquidAI/LFM2-8B-A1B": LFM2_8B_A1B,
     "arcee-ai/Trinity-Mini-pp4-stage0": TRINITY_MINI_PP4_STAGE0,
     "Qwen/Qwen3-0.6B": QWEN3_0_6B,
     "Qwen/Qwen3-30B-A3B": QWEN3_30B_A3B,
@@ -897,6 +973,41 @@ def tiny_trinity(**overrides) -> ModelConfig:
         sandwich_norm=True,
         attn_output_gate=True,
         attn_use_rope=False,
+    )
+    base.update(overrides)
+    return ModelConfig(**base)
+
+
+def tiny_lfm2(**overrides) -> ModelConfig:
+    """A miniature LFM2-shaped list: gated short convolutions ("c", 3 taps)
+    and GQA layers ("g": per-head q/k norm, RoPE) in the published order's
+    shape — two leading conv layers with a dense FFN, then whole "gccc"
+    periods and two shorter "gcc" ones — 8 experts top-2 by sigmoid score +
+    selection bias, no shared expert, tied embeddings."""
+    base = dict(
+        name="tiny-lfm2",
+        vocab_size=128,
+        hidden_size=64,
+        intermediate_size=96,
+        num_layers=16,
+        num_heads=4,
+        num_kv_heads=2,
+        head_dim=16,
+        max_seq_len=256,
+        rope_theta=1000000.0,
+        norm_eps=1e-5,
+        qk_norm=True,
+        tie_embeddings=True,
+        eos_token_id=1,
+        num_experts=8,
+        num_experts_per_tok=2,
+        moe_intermediate_size=32,
+        norm_topk_prob=True,
+        router_scoring="sigmoid",
+        num_dense_layers=2,
+        route_norm_eps=1e-6,
+        layer_pattern="ccgcccgcccgccgcc",
+        conv_taps=3,
     )
     base.update(overrides)
     return ModelConfig(**base)

@@ -15,6 +15,13 @@ Key-name maps cover the supported families:
   span the whole projection (``[q_size]`` / ``[kv_size]``).
 - Phi-2: ``self_attn.dense``, ``mlp.fc1/fc2`` with biases, LayerNorm
   weight+bias, ``lm_head`` with bias, no post-attention norm (parallel block).
+- LFM2-MoE (``_convert_lfm2``; the names are from memory of
+  ``transformers``' lfm2_moe and hold until a checkpoint says otherwise):
+  ``conv.in_proj`` / ``conv.conv.weight`` ``[H, 1, K]`` / ``conv.out_proj``,
+  ``self_attn.{q,k,v}_proj`` / ``out_proj`` / ``q_layernorm`` /
+  ``k_layernorm``, ``operator_norm`` / ``ffn_norm``, dense
+  ``feed_forward.{w1,w2,w3}``, routed ``feed_forward.gate`` /
+  ``expert_bias`` / ``experts.N.{w1,w2,w3}``, ``embedding_norm``.
 - OPT (pre-norm variants): ``model.decoder.layers.N.self_attn.*_proj``,
   ``self_attn_layer_norm``/``final_layer_norm``, ``fc1/fc2``, learned
   ``embed_positions`` (+2 offset), tied embeddings.
@@ -59,6 +66,8 @@ def _get(tensors: Dict[str, "np.ndarray"], key: str) -> np.ndarray:
 def convert_state_dict(cfg: ModelConfig, tensors: Dict[str, np.ndarray],
                        dtype=jnp.bfloat16) -> dict:
     """Convert a flat HF state dict (torch tensors or numpy) to our pytree."""
+    if "c" in cfg.layer_pattern:
+        return _convert_lfm2(cfg, tensors, dtype)
     phi = cfg.parallel_block
     L = cfg.num_layers
 
@@ -175,6 +184,81 @@ def convert_state_dict(cfg: ModelConfig, tensors: Dict[str, np.ndarray],
             params["lm_head"]["bias"] = _get(tensors, "lm_head.bias")
 
     return jax.tree.map(lambda x: jnp.asarray(x, dtype), params)
+
+
+def _convert_lfm2(cfg: ModelConfig, tensors: Dict[str, np.ndarray],
+                  dtype) -> dict:
+    """The LFM2-MoE list: one stack a kind (``conv``, ``attn``) and one a
+    FFN kind (``ffn_dense`` the leading ``num_dense_layers``, ``ffn_moe`` the
+    rest), each in layer order (models/layers.init_list_layer_params). w1 is
+    the gate, w3 the up and w2 the down projection; the depthwise
+    convolution's ``[H, 1, K]`` weight becomes taps ``[K, H]``, the oldest
+    row's first, as torch's causal Conv1d orders them."""
+    nd = cfg.num_dense_layers
+    of_kind = {k: [i for i, c in enumerate(cfg.layer_pattern) if c == k]
+               for k in "cg"}
+
+    def stack(layers, name: str, transpose: bool = False) -> np.ndarray:
+        mats = [_get(tensors, f"model.layers.{i}.{name}") for i in layers]
+        return np.stack([m.T if transpose else m for m in mats])
+
+    def dense(layers, name: str) -> dict:
+        return {"kernel": stack(layers, name + ".weight", transpose=True)}
+
+    def norms(layers) -> dict:
+        return {"input_norm": {"weight": stack(layers,
+                                               "operator_norm.weight")},
+                "post_norm": {"weight": stack(layers, "ffn_norm.weight")}}
+
+    def experts(layers, proj: str) -> np.ndarray:
+        first = _get(tensors, f"model.layers.{layers[0]}.feed_forward."
+                              f"experts.0.{proj}.weight")
+        out = np.empty((len(layers), cfg.num_experts) + first.T.shape,
+                       jnp.dtype(dtype))
+        for n, i in enumerate(layers):
+            for e in range(cfg.num_experts):
+                out[n, e] = _get(
+                    tensors, f"model.layers.{i}.feed_forward.experts.{e}."
+                             f"{proj}.weight").T.astype(out.dtype)
+        return out
+
+    conv, attn = of_kind["c"], of_kind["g"]
+    routed = list(range(nd, cfg.num_layers))
+    layers = {
+        "conv": {**norms(conv),
+                 "w_in": dense(conv, "conv.in_proj"),
+                 "conv": {"weight": np.stack(
+                     [_get(tensors, f"model.layers.{i}.conv.conv.weight")
+                      [:, 0, :].T for i in conv])},
+                 "wo": dense(conv, "conv.out_proj")},
+        "attn": {**norms(attn),
+                 "wq": dense(attn, "self_attn.q_proj"),
+                 "wk": dense(attn, "self_attn.k_proj"),
+                 "wv": dense(attn, "self_attn.v_proj"),
+                 "wo": dense(attn, "self_attn.out_proj"),
+                 "q_norm": {"weight": stack(
+                     attn, "self_attn.q_layernorm.weight")},
+                 "k_norm": {"weight": stack(
+                     attn, "self_attn.k_layernorm.weight")}},
+        "ffn_dense": {"w_gate": dense(range(nd), "feed_forward.w1"),
+                      "w_up": dense(range(nd), "feed_forward.w3"),
+                      "w_down": dense(range(nd), "feed_forward.w2")},
+        "ffn_moe": {"router": {"kernel": stack(
+                        routed, "feed_forward.gate.weight", transpose=True)},
+                    "w_gate": {"kernel": experts(routed, "w1")},
+                    "w_up": {"kernel": experts(routed, "w3")},
+                    "w_down": {"kernel": experts(routed, "w2")}}}
+    params = {"embed": {"weight": _get(tensors, "model.embed_tokens.weight")},
+              "layers": layers,
+              "final_norm": {"weight": _get(
+                  tensors, "model.embedding_norm.weight")}}
+    if not cfg.tie_embeddings:
+        params["lm_head"] = {"kernel": _get(tensors, "lm_head.weight").T}
+    params = jax.tree.map(lambda x: jnp.asarray(x, dtype), params)
+    # the selection bias stays float32 (models/quant.py leaves it so too)
+    params["layers"]["ffn_moe"]["router"]["bias"] = jnp.asarray(
+        stack(routed, "feed_forward.expert_bias"), jnp.float32)
+    return params
 
 
 def load_checkpoint(

@@ -295,8 +295,9 @@ def init_hybrid_layer_params(cfg: ModelConfig, key: jax.Array, dtype) -> dict:
 def init_list_layer_params(cfg: ModelConfig, key: jax.Array, dtype) -> dict:
     """Stacked params of a model whose layer kinds are a LIST: one sub-tree
     a kind, ``attn`` leaves ``[n_a, ...]`` (the "g" / "s" / "w" layers in
-    order; selecting and the window have no parameter of their own) and
-    ``lightning`` leaves ``[n_l, ...]`` — what a run of one kind scans over
+    order; selecting and the window have no parameter of their own),
+    ``lightning`` leaves ``[n_l, ...]`` and ``conv`` leaves ``[n_c, ...]``
+    (the gated short convolutions) — what a run of one kind scans over
     (:func:`_list_forward_carry`). Every FFN is the dense gated MLP, in its
     layer's sub-tree — but in a model with experts the FFN differs by LAYER
     and the two kinds are stacks of their own: ``ffn_dense`` ``[n_d, ...]``
@@ -347,6 +348,15 @@ def init_list_layer_params(cfg: ModelConfig, key: jax.Array, dtype) -> dict:
             "wo": dense(ks[4], n, D, H),
             "q_norm": norm(n, d), "k_norm": norm(n, d), "o_norm": norm(n, d),
             **own_ffn(ks[5:8], n)}
+    n = cfg.layer_pattern.count("c")
+    if n:
+        ks = jax.random.split(jax.random.fold_in(key, 3), 8)
+        out["conv"] = {
+            "input_norm": norm(n), "post_norm": norm(n),
+            "w_in": dense(ks[0], n, H, 3 * H),
+            "conv": {"weight": _dense_init(ks[1], (n, cfg.conv_taps, H),
+                                           dtype, 0.5)},
+            "wo": dense(ks[2], n, H, H), **own_ffn(ks[5:8], n)}
     if cfg.num_experts > 0:
         kd, km = jax.random.split(jax.random.fold_in(key, 2))
         nd = cfg.num_dense_layers
@@ -654,6 +664,28 @@ def lightning_block(cfg: ModelConfig, p: dict, x: jnp.ndarray,
     return _add_ffn(cfg, x, h2, p), rec
 
 
+def conv_block(cfg: ModelConfig, p: dict, x: jnp.ndarray, recur, rec_l: Any,
+               ffn: Optional[dict] = None) -> Tuple[jnp.ndarray, Any]:
+    """One gated short-convolution block (LFM2): ``[B, C, X] = split3(n
+    W_in)``, a depthwise causal convolution over ``B * X``, gated by ``C``,
+    then the output projection. ``recur.conv`` (ops/linear_attention.py)
+    runs the gates and the convolution over the per-slot tail ``rec_l``
+    names and returns float32; ``ffn``: the FFN's params where they are no
+    part of ``p`` (see :func:`decoder_block`)."""
+    with jax.named_scope(parts.NORM):
+        h = apply_norm(cfg, x, p["input_norm"])
+    with jax.named_scope(parts.ATTN_PROJ):
+        bcx = _linear(h, p["w_in"])
+    with jax.named_scope(parts.RECUR):
+        y, rec = recur.conv(p["conv"]["weight"], bcx, rec_l)
+        y = y.astype(x.dtype)
+    with jax.named_scope(parts.ATTN_OUT):
+        x = _residual(cfg, x, _linear(y, p["wo"]))
+    with jax.named_scope(parts.NORM):
+        h2 = apply_norm(cfg, x, p["post_norm"])
+    return _add_ffn(cfg, x, h2, p if ffn is None else ffn), rec
+
+
 def kda_block(cfg: ModelConfig, p: dict, x: jnp.ndarray, recur,
               rec_l: Any) -> Tuple[jnp.ndarray, Any]:
     """One KDA linear-attention block (ops/linear_attention.py has the
@@ -923,17 +955,18 @@ def _hybrid_forward_carry(params, cfg: ModelConfig, tokens, positions, cache,
 def layer_plan(cfg: ModelConfig):
     """The list as RUNS of equal (kind, FFN): ``(kind, FFN stack or None,
     first, cache, ffn, length)`` — ``first`` the run's first layer among
-    the layers of its PARAMS stack (``lightning``, or ``attn`` for the
-    "g" / "s" / "w" kinds together), ``cache`` among the layers that share
-    its CACHE leaves (the Lightning state; the pool's ``k`` / ``v`` for "g"
-    / "s"; its ``wk`` / ``wv`` for "w"), ``ffn`` in its FFN stack
+    the layers of its PARAMS stack (``lightning``, ``conv``, or ``attn`` for
+    the "g" / "s" / "w" kinds together), ``cache`` among the layers that
+    share its CACHE leaves (the Lightning state; the conv tails; the pool's
+    ``k`` / ``v`` for "g" / "s"; its ``wk`` / ``wv`` for "w"), ``ffn`` in
+    its FFN stack
     (``ffn_dense`` / ``ffn_moe``; None = the FFN lies in the layer's own
     sub-tree). One scan body a run: "ww|wgwwwg" (dense | routed) has five,
     the published 32 layers ("wwwg" x 8, two dense) eighteen."""
     plan, seen = [], {}
     for i, kind in enumerate(cfg.layer_pattern):
-        stack = "lightning" if kind == "l" else "attn"
-        leaves = stack if kind == "l" else "win" if kind == "w" else "pool"
+        stack = {"l": "lightning", "c": "conv"}.get(kind, "attn")
+        leaves = stack if kind in "lc" else "win" if kind == "w" else "pool"
         ffn = None if cfg.num_experts <= 0 else \
             "ffn_dense" if i < cfg.num_dense_layers else "ffn_moe"
         if plan and plan[-1][:2] == [kind, ffn]:
@@ -944,6 +977,36 @@ def layer_plan(cfg: ModelConfig):
         for name in {stack, leaves, ffn}:
             seen[name] = seen.get(name, 0) + 1
     return [tuple(r) for r in plan]
+
+
+def layer_periods(plan):
+    """:func:`layer_plan`'s runs, FOLDED where a sequence of them repeats:
+    ``[(runs, repeats, strides)]`` — ``runs`` consecutive runs of the plan,
+    followed ``repeats - 1`` times by runs of the same (kind, FFN, length)
+    whose layers lie ``strides`` (one ``(first, cache, ffn)`` a run) further
+    in their stacks with each copy. The published LFM2 list, 13 runs, is
+    three such groups — "cc" | ("g", "ccc") x 4 | ("g", "cc") x 2 — so its
+    step programs hold five layer bodies, not thirteen; a list that
+    repeats nothing (every list served before it) comes out run by run,
+    each alone and once, and its programs are what they were. Greedy from
+    the front: the period that covers the most runs, the shortest first."""
+    sig = [(kind, ffn, n) for kind, ffn, _, _, _, n in plan]
+    out, p = [], 0
+    while p < len(plan):
+        m, reps = 1, 1
+        for width in range(1, (len(plan) - p) // 2 + 1):
+            r = 1
+            while sig[p + r * width:p + (r + 1) * width] == sig[p:p + width]:
+                r += 1
+            if r > 1 and r * width > m * reps:
+                m, reps = width, r
+        strides = tuple(
+            tuple(b - a for a, b in zip(plan[p + j][2:5],
+                                        plan[p + m + j][2:5]))
+            if reps > 1 else (0, 0, 0) for j in range(m))
+        out.append((tuple(plan[p:p + m]), reps, strides))
+        p += m * reps
+    return out
 
 
 def layer_runs(pattern: str):
@@ -969,7 +1032,9 @@ def _list_forward_carry(params, cfg: ModelConfig, tokens, positions, cache,
     axis of ATTENDING layers (``attend`` is handed ``(pool, index among
     the attending layers)``) and the Lightning layers' per-slot state
     ``[n_l, 1, slots, ...]``, which ``recur.lightning`` reads and writes as
-    ``(state, index among the Lightning layers, 0)``. A window ("w") layer
+    ``(state, index among the Lightning layers, 0)``; a gated short
+    convolution ("c") hands ``recur.conv`` ``(state, index among the conv
+    layers)`` for its tail rows. A window ("w") layer
     goes to ``attend.window`` with its index among the WINDOW layers: its
     K/V are leaves of their own in the same pool (ops/kv_pool.py), and it
     rotates q/k whatever the full layers do. All ride the carry. Each
@@ -1002,6 +1067,9 @@ def _list_forward_carry(params, cfg: ModelConfig, tokens, positions, cache,
         if kind == "l":
             x, rec = lightning_block(cfg, layer("lightning", i), x, cos, sin,
                                      recur, (rec, at, 0))
+        elif kind == "c":
+            x, rec = conv_block(cfg, layer("conv", i), x, recur, (rec, at),
+                                ffn=None if ffn is None else layer(*ffn))
         else:
             x, (pool, _) = decoder_block(
                 cfg, layer("attn", i), x, cos, sin,
@@ -1010,25 +1078,55 @@ def _list_forward_carry(params, cfg: ModelConfig, tokens, positions, cache,
                 rope=True if kind == "w" else None)
         return x, pool, rec
 
-    carry = (x, pool, rec)
-    stats = []      # an MoE layer's routing counts (ops/moe.routed_rows)
-    for kind, stack, first, at, ffn, n in layer_plan(cfg):
+    def run(carry, kind, stack, first, n, d_at, d_ffn):
+        """``n`` layers of one kind from layer ``first`` of its params
+        stack (a number, or traced inside a folded period); ``d_at`` /
+        ``d_ffn``: how far the layer's cache and FFN indices lie from it.
+        Returns (carry, the layers' routing counts [n, ...] or None)."""
 
-        def step(carry, i, kind=kind, stack=stack, d_at=at - first,
-                 d_ffn=ffn - first):
+        def step(carry, i):
             # (an offset of zero leaves the index as it is: the older
             # lists' programs are what they were)
-            carry = one(kind, carry, i, i + d_at if d_at else None,
+            carry = one(kind, carry, i,
+                        None if isinstance(d_at, int) and not d_at
+                        else i + d_at,
                         (stack, i + d_ffn) if stack else None)
             return carry, moe.take_layer_stats()
 
         if n == 1:
             carry, got = step(carry, jnp.int32(first))
-            got = None if got is None else got[None]
+            return carry, None if got is None else got[None]
+        return jax.lax.scan(
+            jax.checkpoint(step) if remat else step, carry,
+            first + jnp.arange(n, dtype=jnp.int32))
+
+    carry = (x, pool, rec)
+    stats = []      # an MoE layer's routing counts (ops/moe.routed_rows)
+    for runs, repeats, strides in layer_periods(layer_plan(cfg)):
+        if repeats == 1:
+            (kind, stack, first, at, ffn, n), = runs
+            carry, got = run(carry, kind, stack, first, n, at - first,
+                             ffn - first)
         else:
+            def period(carry, t, runs=runs, strides=strides):
+                """Copy ``t`` of a folded group: its runs in order, every
+                index ``t`` strides further in its stack."""
+                got = []
+                for (kind, stack, first, at, ffn, n), (s_first, s_at,
+                                                       s_ffn) in zip(
+                        runs, strides):
+                    carry, g = run(carry, kind, stack, first + t * s_first,
+                                   n, at - first + t * (s_at - s_first),
+                                   ffn - first + t * (s_ffn - s_first))
+                    got.append(g)
+                return carry, None if got[0] is None \
+                    else jnp.concatenate(got)
+
             carry, got = jax.lax.scan(
-                jax.checkpoint(step) if remat else step, carry,
-                first + jnp.arange(n, dtype=jnp.int32))
+                jax.checkpoint(period) if remat else period, carry,
+                jnp.arange(repeats, dtype=jnp.int32))
+            if got is not None:     # [repeats, layers a period, ...]
+                got = got.reshape((-1,) + got.shape[2:])
         if got is not None:
             stats.append(got)
     x, pool, rec = carry

@@ -16,7 +16,8 @@ names the work (a selecting layer's ``select`` inside its ``attn.core``).
 - ``norm``: a block's input and post-attention norms;
 - ``attn.proj``: wq / wk / wv, the output gate's ``wg``, q/k norm and RoPE —
   and a recurrent layer's input projections (KDA's low-rank decay, gate and
-  step-size projections with their activations): the same weight stream
+  step-size projections with their activations; a gated short
+  convolution's one ``w_in``): the same weight stream
   through the same ``_linear``, dequantisation inside;
 - ``attn.core``: the ``attend`` callback — the kernel calls and what
   surrounds them (the length order's gathers, the row and chunk writes, the
@@ -26,7 +27,8 @@ names the work (a selecting layer's ``select`` inside its ``attn.core``).
 - ``router``: scores, bias, top-k, the sort, the group sizes and the
   routing counts a step program asks for;
 - ``experts``: the grouped / every-expert matmuls and their combine;
-- ``recur``: KDA and Lightning — the convolution, the state update and the
+- ``recur``: KDA, Lightning and the gated short convolution — the
+  convolution (its gates, taps and tail rows), the state update and the
   output norm (what touches the per-slot state);
 - ``select``: a selecting layer's run add, pooled-key scores, the rank
   count, the page lists and bitmasks, the page tally;
@@ -58,6 +60,7 @@ _LEAF_PART = {
     "input_norm": NORM, "post_norm": NORM,
     "attn_out_norm": NORM, "mlp_out_norm": NORM,
     "wq": ATTN_PROJ, "wk": ATTN_PROJ, "wv": ATTN_PROJ, "wg": ATTN_PROJ,
+    "w_in": ATTN_PROJ,
     "q_norm": ATTN_PROJ, "k_norm": ATTN_PROJ,
     "f_a": ATTN_PROJ, "f_b": ATTN_PROJ, "g_a": ATTN_PROJ, "g_b": ATTN_PROJ,
     "w_beta": ATTN_PROJ, "A_log": ATTN_PROJ, "dt_bias": ATTN_PROJ,

@@ -46,11 +46,13 @@ from aws_k8s_ansible_provisioner_tpu.config import ModelConfig
 # stack, [L, E] of an expert stack, [P] / [P, n_k] of a kind's sub-tree in a
 # model with a layer pattern), a kernel is [..., in, out]: the contraction
 # axis is its second-to-last. ``wg`` is the attention gate, ``shared`` the
-# shared expert's sub-tree. A KDA layer's low-rank decay/gate projections,
+# shared expert's sub-tree, ``w_in`` / ``wo`` a gated short convolution's
+# two projections (its taps stay in the model dtype). A KDA layer's low-rank decay/gate projections,
 # step-size projection, convolution taps, A_log and dt_bias stay in the
 # model dtype (a few MB a layer, and the decay is precision-critical), like
 # the router and its bias.
-_QUANT_KEYS = ("wq", "wk", "wv", "wo", "wg", "w_gate", "w_up", "w_down")
+_QUANT_KEYS = ("wq", "wk", "wv", "wo", "wg", "w_in", "w_gate", "w_up",
+               "w_down")
 
 
 def _quant_kernel(w: jnp.ndarray, in_axis: int):

@@ -119,6 +119,37 @@ def chunk_attend(q: jnp.ndarray, ck: jnp.ndarray, cv: jnp.ndarray,
 # ---------------------------------------------------------------------------
 
 
+def lane_packed(cfg, attend):
+    """``attend`` over a pool that holds ``cfg.kv_lane_pack`` K/V heads side
+    by side in one row (config.py says why): K and V rows are folded so —
+    neighbouring heads are neighbours in memory, a reshape —, every query
+    head is widened to the row with ZEROS in the other heads' lanes (its
+    product with a packed K row is its product with its own head), scaled by
+    sqrt(n) because the kernels divide by the square root of the width they
+    see, and takes its own head's lanes of the output. Everything under it —
+    the pool, the writers, the paged kernels, the XLA fallbacks — sees ``Hkv
+    / n`` heads of ``n x head_dim``. No pack: ``attend`` as it is."""
+    n = cfg.kv_lane_pack
+    if n == 1:
+        return attend
+    Hq, Hkv, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    # query head i reads KV head i // groups: lanes [own * D, own * D + D)
+    own = (jnp.arange(Hq) // (Hq // Hkv)) % n
+
+    def packed(q, k, v, cache_l):
+        lead = q.shape[:-2]
+        lanes = jax.nn.one_hot(own, n, dtype=q.dtype)[:, :, None]  # [Hq,n,1]
+        qs = (q.astype(jnp.float32) * (n ** 0.5)).astype(q.dtype)
+        ctx, cache_l = attend(
+            (qs[..., None, :] * lanes).reshape(lead + (Hq, n * D)),
+            k.reshape(lead + (Hkv // n, n * D)),
+            v.reshape(lead + (Hkv // n, n * D)), cache_l)
+        return (ctx.reshape(lead + (Hq, n, D)) * lanes.astype(ctx.dtype)
+                ).sum(-2), cache_l
+
+    return packed
+
+
 def attend_by_kind(make, table, wtable, window: int):
     """The attend callback of a model whose list holds window ("w") layers
     beside full ("g") ones: ``make(table, window, of_window_kind)`` builds

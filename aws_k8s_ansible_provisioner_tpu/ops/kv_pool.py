@@ -106,10 +106,11 @@ def init_pool(cfg: ModelConfig, num_pages: int, page_size: int,
     """Allocate the physical page pool. Leaves carry a leading [L] axis:
     the layers that ATTEND (all of them, or one a period of a model with a
     layer pattern — its other layers keep per-slot state instead,
-    ops/linear_attention.init_state). A model with window layers beside
+    ops/linear_attention.init_state); ``kv_lane_pack`` heads lie side by
+    side in one row of ``pool_head_dim``. A model with window layers beside
     full ones gets ``win_pages`` pages of ``wk`` / ``wv`` for them."""
-    shape = (cfg.num_attn_layers, num_pages, cfg.num_kv_heads, page_size,
-             cfg.head_dim)
+    shape = (cfg.num_attn_layers, num_pages, cfg.pool_kv_heads, page_size,
+             cfg.pool_head_dim)
     if cfg.windowed:
         wshape = (cfg.num_window_layers, win_pages) + shape[2:]
         return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype),

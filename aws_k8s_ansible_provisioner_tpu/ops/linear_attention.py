@@ -34,7 +34,12 @@ the state in both forms.
 
 The short convolution (depthwise, causal, ``K`` taps, SiLU) in front of
 q/k/v needs the ``K - 1`` rows before a span: they are the second per-slot
-leaf, ``kda_conv``.
+leaf, ``kda_conv``. The same primitive (:func:`short_conv`, and the same
+window and tail helpers) IS the mixer of a gated short-convolution layer
+("c", LFM2): ``[B, C, X] = split3(u W_in)``, ``z = B * X``, ``c_t = sum_j
+w_j z_(t-K+1+j)`` (no activation), output ``C * c`` — whose only state is
+the ``K - 1`` rows of ``z`` before a span, the leaf ``conv_tail`` float32
+``[n_c, slots, K - 1, hidden]``.
 
 State layout, beside the paged pool in the same ``cache`` pytree the step
 programs donate: ``kda_state`` float32 ``[P, n_k, slots, H, d_k, d_v]`` and
@@ -77,12 +82,17 @@ def _state_shapes(cfg: ModelConfig, num_slots: int, dtype) -> dict:
         H, d = cfg.lightning_num_heads, cfg.lightning_head_dim
         out["lin_state"] = ((cfg.layer_pattern.count("l"), 1, num_slots, H,
                              d, d), jnp.float32)
+    if "c" in cfg.layer_pattern:
+        # float32: a chunk that starts from a carried tail reads the very
+        # numbers the rows before it held inside their own span
+        out["conv_tail"] = ((cfg.layer_pattern.count("c"), num_slots,
+                             cfg.conv_taps - 1, cfg.hidden_size), jnp.float32)
     return out
 
 
 def init_state(cfg: ModelConfig, num_slots: int, dtype=jnp.bfloat16) -> dict:
     """The per-slot leaves of a model with recurrent layers: two for KDA
-    layers, one for Lightning layers."""
+    layers, one for Lightning layers, one for gated short convolutions."""
     return {name: jnp.zeros(shape, dt) for name, (shape, dt)
             in _state_shapes(cfg, num_slots, dtype).items()}
 
@@ -95,7 +105,7 @@ def state_bytes(cfg: ModelConfig, num_slots: int, dtype=jnp.bfloat16) -> int:
 
 
 def is_state(name: str) -> bool:
-    return name.startswith(("kda_", "lin_"))
+    return name.startswith(("kda_", "lin_", "conv_"))
 
 
 # ---------------------------------------------------------------------------
@@ -107,18 +117,24 @@ def _l2norm(x):
     return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
 
 
-def conv_qkv(window: jnp.ndarray, taps: jnp.ndarray, H: int, d: int):
-    """Depthwise causal convolution + SiLU over ``window`` [..., T + K - 1,
-    3 H d] (the K - 1 rows of history in front), then the split:
-    ``q = L2norm(.) / sqrt(d)``, ``k = L2norm(.)``, ``v`` as it is — each
-    [..., T, H, d] float32."""
+def short_conv(window: jnp.ndarray, taps: jnp.ndarray) -> jnp.ndarray:
+    """THE short convolution: depthwise, causal, no bias, no activation.
+    ``window`` [..., T + K - 1, C] holds the K - 1 rows of history in front
+    of the T rows, ``taps`` [K, C] the oldest row's tap first; returns
+    [..., T, C] float32."""
     K = taps.shape[0]
     T = window.shape[-2] - (K - 1)
     w = taps.astype(jnp.float32)
     win = window.astype(jnp.float32)
-    y = sum(w[i] * jax.lax.slice_in_dim(win, i, i + T, axis=-2)
-            for i in range(K))
-    y = jax.nn.silu(y)
+    return sum(w[i] * jax.lax.slice_in_dim(win, i, i + T, axis=-2)
+               for i in range(K))
+
+
+def conv_qkv(window: jnp.ndarray, taps: jnp.ndarray, H: int, d: int):
+    """:func:`short_conv` + SiLU over ``window`` [..., T + K - 1, 3 H d],
+    then the split: ``q = L2norm(.) / sqrt(d)``, ``k = L2norm(.)``, ``v`` as
+    it is — each [..., T, H, d] float32."""
+    y = jax.nn.silu(short_conv(window, taps))
     q, k, v = (a.reshape(a.shape[:-1] + (H, d))
                for a in jnp.split(y, 3, axis=-1))
     return _l2norm(q) * (d ** -0.5), _l2norm(k), v
@@ -269,6 +285,33 @@ def _pad_to_block(arrays, T: int):
     return _pad_to(BLOCK, arrays, T)
 
 
+def _span_window(leaf, at, fresh, x):
+    """[N, K - 1 + T, C]: each span's rows ``x`` behind the K - 1 rows its
+    slot kept (``leaf[at]`` [N, K - 1, C]; zeros where ``fresh``)."""
+    hist = jnp.where(fresh[:, None, None], 0, leaf[at])
+    return jnp.concatenate([hist.astype(x.dtype), x], axis=1)
+
+
+def _span_tail(window, n_valid, K: int):
+    """The K - 1 rows in front of row ``n_valid`` [N] of each span: what
+    its slot keeps (rows at or past ``n_valid`` leave none)."""
+    return jax.vmap(lambda w, n: jax.lax.dynamic_slice_in_dim(
+        w, n, K - 1, axis=0))(window, n_valid)
+
+
+def _rows_window(hist, x):
+    """[B, K, C]: one new row a slot behind the K - 1 rows it kept."""
+    return jnp.concatenate([hist, x[:, None].astype(hist.dtype)], axis=1)
+
+
+def _rows_tail(tail, hist, live):
+    """What a slot keeps after its new row: ``tail``, the K - 1 newest rows
+    of its window; a dead row (``live`` [B] False) the history it found."""
+    if live is None:
+        return tail
+    return jnp.where(live[:, None, None], tail, hist)
+
+
 def _span(rec_l, taps, qkv, g, beta, slots, fresh, n_valid):
     """N spans [N, T, ...] into slots ``slots`` [N]: each starts from zeros
     where ``fresh`` [N] (position 0) and from its slot's leaves otherwise;
@@ -282,18 +325,14 @@ def _span(rec_l, taps, qkv, g, beta, slots, fresh, n_valid):
     rd = jnp.clip(slots, 0, rec["kda_state"].shape[2] - 1)
     S0 = jnp.where(fresh[:, None, None, None], 0.0,
                    rec["kda_state"][period, j, rd])
-    hist = jnp.where(fresh[:, None, None], 0,
-                     rec["kda_conv"][period, j, rd])
-    window = jnp.concatenate([hist.astype(qkv.dtype), qkv], axis=1)
+    window = _span_window(rec["kda_conv"], (period, j, rd), fresh, qkv)
     q, k, v = conv_qkv(window, taps, H, d)
     live = (jnp.arange(T)[None] < n_valid[:, None])          # [N, T]
     g = jnp.where(live[..., None, None], g, 0.0)
     beta = jnp.where(live[..., None], beta, 0.0)
     q, k, v, g, beta = _pad_to_block((q, k, v, g, beta), T)
     o, S = kda_span(S0, q, k, v, g, beta)
-    # the K - 1 rows in front of row n_valid of the span
-    tail = jax.vmap(lambda w, n: jax.lax.dynamic_slice_in_dim(
-        w, n, K - 1, axis=0))(window, n_valid)
+    tail = _span_tail(window, n_valid, K)
     rec = _layer_set(rec, "kda_state", period, j, S, slots)
     rec = _layer_set(rec, "kda_conv", period, j, tail, slots)
     return o[:, :T], rec
@@ -305,13 +344,13 @@ def _rows(rec_l, taps, qkv, g, beta, live):
     rec, period, j = rec_l
     B, H, d = g.shape
     hist = _layer_get(rec, "kda_conv", period, j)            # [B, K-1, 3Hd]
-    window = jnp.concatenate([hist, qkv[:, None].astype(hist.dtype)], axis=1)
+    window = _rows_window(hist, qkv)
     q, k, v = conv_qkv(window, taps, H, d)
     tail = window[:, 1:]
     if live is not None:
         g = jnp.where(live[:, None, None], g, 0.0)
         beta = jnp.where(live[:, None], beta, 0.0)
-        tail = jnp.where(live[:, None, None], tail, hist)
+    tail = _rows_tail(tail, hist, live)
     from aws_k8s_ansible_provisioner_tpu.ops import pallas_attention
 
     if pallas_attention.supported():
@@ -328,6 +367,51 @@ def _rows(rec_l, taps, qkv, g, beta, live):
     return o, rec
 
 
+# ---------------------------------------------------------------------------
+# The gated short convolution ("c" layers): ``recur.conv(taps [K, H], bcx
+# [B, T, 3 H] — one projection's rows, split [B | C | X] —, (rec, i))`` ->
+# (C * short_conv(B * X) [B, T, H] float32, rec); ``i`` (traced) the layer's
+# index among the conv layers. The same span forms as above, over the one
+# leaf ``conv_tail``.
+# ---------------------------------------------------------------------------
+
+
+def _gate_in(bcx):
+    """(z = B * X, C) of the projection's rows, float32."""
+    b, c, x = jnp.split(bcx.astype(jnp.float32), 3, axis=-1)
+    return b * x, c
+
+
+def _conv_span(taps, bcx, rec_l, *, slots, fresh, n_valid):
+    """N spans [N, T, 3 H] into slots ``slots`` [N] (see :func:`_span`)."""
+    rec, i = rec_l
+    arr = rec["conv_tail"]
+    z, c = _gate_in(bcx)
+    rd = jnp.clip(slots, 0, arr.shape[1] - 1)
+    window = _span_window(arr, (i, rd), fresh, z)
+    tail = _span_tail(window, n_valid, taps.shape[0])
+    return c * short_conv(window, taps), \
+        {**rec, "conv_tail": arr.at[i, slots].set(tail, mode="drop")}
+
+
+def _conv_rows(rec_l, taps, bcx, live):
+    """One token for every slot (row b = slot b); see :func:`_rows`."""
+    rec, i = rec_l
+    arr = rec["conv_tail"]
+    z, c = _gate_in(bcx)
+    hist = jax.lax.dynamic_index_in_dim(arr, i, 0, keepdims=False)
+    window = _rows_window(hist, z)
+    arr = jax.lax.dynamic_update_slice(
+        arr, _rows_tail(window[:, 1:], hist, live)[None], (i, 0, 0, 0))
+    return c * short_conv(window, taps)[:, 0], {**rec, "conv_tail": arr}
+
+
+def _conv_from_zero(taps, bcx, rec_l):
+    z, c = _gate_in(bcx)
+    window = jnp.pad(z, [(0, 0), (taps.shape[0] - 1, 0), (0, 0)])
+    return c * short_conv(window, taps), rec_l[0]
+
+
 def make_recur_decode(live=None):
     """decode_steps: x is [B, 1, ...], row b is slot b."""
 
@@ -339,7 +423,12 @@ def make_recur_decode(live=None):
         o, rec = _lin_rows(rec_l, q[:, 0], k[:, 0], v[:, 0], slopes, live)
         return o[:, None], rec
 
+    def conv(taps, bcx, rec_l):
+        o, rec = _conv_rows(rec_l, taps, bcx[:, 0], live)
+        return o[:, None], rec
+
     recur.lightning = lightning
+    recur.conv = conv
     return recur
 
 
@@ -355,6 +444,8 @@ def make_recur_span(slot, start, n_valid):
 
     recur.lightning = functools.partial(_lin_span, slots=slots, fresh=fresh,
                                         n_valid=n)
+    recur.conv = functools.partial(_conv_span, slots=slots, fresh=fresh,
+                                   n_valid=n)
     return recur
 
 
@@ -368,6 +459,8 @@ def make_recur_batch(slots, true_lens):
 
     recur.lightning = functools.partial(_lin_span, slots=slots, fresh=fresh,
                                         n_valid=true_lens)
+    recur.conv = functools.partial(_conv_span, slots=slots, fresh=fresh,
+                                   n_valid=true_lens)
     return recur
 
 
@@ -394,7 +487,13 @@ def make_recur_mixed(B: int, live, pslot, pstart, plen):
                                  (rec,) + tuple(rec_l[1:]))
         return jnp.concatenate([od[None], oc], axis=1), rec
 
+    def conv(taps, bcx, rec_l):
+        od, rec = _conv_rows(rec_l, taps, bcx[0, :B], live)
+        oc, rec = span.conv(taps, bcx[:, B:], (rec,) + tuple(rec_l[1:]))
+        return jnp.concatenate([od[None], oc], axis=1), rec
+
     recur.lightning = lightning
+    recur.conv = conv
     return recur
 
 
@@ -421,6 +520,7 @@ def _lightning_from_zero(q, k, v, slopes, rec_l):
 
 
 recur_from_zero.lightning = _lightning_from_zero
+recur_from_zero.conv = _conv_from_zero
 
 
 # ---------------------------------------------------------------------------

@@ -49,7 +49,8 @@ def route(cfg: ModelConfig, x: jnp.ndarray, router_kernel: jnp.ndarray,
     ``router_scoring`` "sigmoid": a score per expert, the top-k chosen by
     score + ``router_bias`` ([E], a selection bias: it changes who is chosen
     and never a weight), the weights the chosen SCORES, renormalised over
-    all k chosen (``norm_topk_prob``), then times ``route_scale``.
+    all k chosen (``norm_topk_prob``; their sum + ``route_norm_eps``, a
+    constant of the model), then times ``route_scale``.
     """
     logits = x.astype(jnp.float32) @ router_kernel.astype(jnp.float32)
     if cfg.router_scoring == "sigmoid":
@@ -58,7 +59,7 @@ def route(cfg: ModelConfig, x: jnp.ndarray, router_kernel: jnp.ndarray,
                                cfg.num_experts_per_tok)
         w = jnp.take_along_axis(scores, idx, axis=-1)
         if cfg.norm_topk_prob:
-            w = w / (w.sum(axis=-1, keepdims=True) + 1e-20)
+            w = w / (w.sum(axis=-1, keepdims=True) + cfg.route_norm_eps)
         if cfg.route_scale != 1.0:
             w = w * cfg.route_scale
         return w.astype(x.dtype), idx.astype(jnp.int32)
@@ -221,13 +222,19 @@ def _every_expert(cfg: ModelConfig, x: jnp.ndarray, p: dict, live=None):
     OLMoE at 24 rows on a v5e, where the sorted form takes 34 and a kernel
     that latches weight tiles 28 (PERF.md, PR 26).
     x: [N, H] → ([N, H], group_sizes [E])."""
-    E = cfg.num_experts
+    E, N = cfg.num_experts, x.shape[0]
     with jax.named_scope(parts.ROUTER):
         w, idx = _route(cfg, x, p)
         flat_e, group_sizes = _assignments(cfg, idx, live)
         # [N, k, E]: a dead row's id is E, which one_hot maps to all zeros
         hot = jax.nn.one_hot(flat_e.reshape(idx.shape), E, dtype=x.dtype)
         combine = jnp.einsum("nk,nke->ne", w, hot)             # [N, E]
+    pad = EVERY_EXPERT_TILE_PAD if N % 128 == 0 else 0
+    if pad:
+        # rows of zeros that no expert's output is kept of: they keep the
+        # stacks in the layout they lie in (``EVERY_EXPERT_TILE_PAD``)
+        x = jnp.pad(x, ((0, pad), (0, 0)))
+        combine = jnp.pad(combine, ((0, pad), (0, 0)))
 
     def mm(spec, v, q):
         if "scale" in q:
@@ -239,8 +246,8 @@ def _every_expert(cfg: ModelConfig, x: jnp.ndarray, p: dict, live=None):
         g = mm("nh,ehi->eni", x, p["w_gate"])
         u = mm("nh,ehi->eni", x, p["w_up"])
         y = mm("eni,eih->enh", jax.nn.silu(g) * u, p["w_down"])  # [E, N, H]
-        return jnp.einsum("ne,enh->nh", combine, y).astype(x.dtype), \
-            group_sizes
+        out = jnp.einsum("ne,enh->nh", combine, y).astype(x.dtype)
+        return (out[:N] if pad else out), group_sizes
 
 
 # -- what a step program tells the expert layer, and what it hears back ------
@@ -300,6 +307,17 @@ def put_stats(per_layer) -> None:
 # whatever they hold) and reads 75.2 at 768 and 81.8 at 1,024. Shapes are
 # static, so a program holds one form.
 EVERY_EXPERT_MAX_ROW_EXPERTS = 768 * 64
+
+# Zero rows the every-expert form appends to a batch that is a whole number
+# of 128-row MXU tiles. At such a count XLA's TPU layout assignment may want
+# the gate / up stacks contraction-minor and hoist a transposed copy of both
+# WHOLE stacks out of the layer loop (deviceless compiles, PR 42: 5.2 GB of
+# temporaries in every LFM2-8B-A1B step program of 128, 256, 384, 512 or 640
+# rows, 4.3 GB in OLMoE's decode program at 128 slots — a program that no
+# longer fits the chip, and a copy of gigabytes a dispatch if it did); at 120,
+# 136 or 192 rows the stacks stream as they lie. A shape rule, like the bound
+# above: 8 rows are one sublane tile and 6 % of the smallest batch it meets.
+EVERY_EXPERT_TILE_PAD = 8
 
 
 def moe_mlp(cfg: ModelConfig, x: jnp.ndarray, p: dict) -> jnp.ndarray:

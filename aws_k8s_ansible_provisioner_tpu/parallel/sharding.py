@@ -32,10 +32,13 @@ from aws_k8s_ansible_provisioner_tpu.config import ModelConfig
 
 
 def check_tp_divisibility(cfg: ModelConfig, tp: int, ep: int = 1) -> None:
-    """TP must evenly split query heads, kv heads, and the MLP intermediate
-    (the MoE expert intermediate when sparse); ep must split the experts."""
+    """TP must evenly split query heads, kv heads (the pool's rows of them:
+    ``cfg.pool_kv_heads``, narrow heads lie several a row), and the MLP
+    intermediate (the MoE expert intermediate when sparse); ep must split
+    the experts."""
     dims = [("num_heads", cfg.num_heads),
             ("num_kv_heads", cfg.num_kv_heads),
+            ("pool_kv_heads", cfg.pool_kv_heads),
             ("vocab_size", cfg.vocab_size)]
     if cfg.num_experts > 0:
         dims.append(("moe_intermediate_size", cfg.moe_intermediate_size))

@@ -316,6 +316,9 @@ class Engine(EnginePrograms):
         if cfg.recurrent:
             self.metrics.recurrent_state_bytes.set(
                 self.kda_state_bytes, kind=cfg.recurrent_kinds)
+        if "conv_tail" in self.cache:
+            self.metrics.conv_state_bytes.set(
+                self.cache["conv_tail"].nbytes)
         if cfg.selects:
             self.metrics.selector_cache_bytes.set(self.selector_bytes)
         # AOT manifest summary (serving/aot.py), installed by

@@ -320,7 +320,7 @@ class EngineMetrics:
         self.state_rows = r.register(Counter(
             "tpu_serve_state_rows_total",
             "Rows that advanced a recurrent state, per layer, by the kind "
-            "of layer that keeps it (KDA, Lightning) and step program",
+            "of layer that keeps it (KDA, Lightning, conv) and step program",
             ("kind", "program")))
         self.recurrent_state_bytes = r.register(Gauge(
             "tpu_serve_recurrent_state_bytes",
@@ -339,6 +339,11 @@ class EngineMetrics:
         self.kda_state_bytes = r.register(Gauge(
             "tpu_serve_kda_state_bytes",
             "Bytes of per-slot recurrent state held beside the KV pool"))
+        self.conv_state_bytes = r.register(Gauge(
+            "tpu_serve_conv_state_bytes",
+            "Bytes of the gated short convolutions' per-slot tails (the "
+            "conv_taps - 1 rows before a span, float32) held beside the KV "
+            "pool"))
         self.prefix_lookups_skipped = r.register(Counter(
             "tpu_serve_prefix_lookups_skipped_total",
             "Admissions that did not consult the prefix index, by reason",

@@ -42,6 +42,7 @@ from aws_k8s_ansible_provisioner_tpu.models.layers import (
 )
 from aws_k8s_ansible_provisioner_tpu.ops.attention import (
     attend_by_kind,
+    lane_packed,
     make_chunk_prefill_attend_paged_carry,
     make_decode_attend_carry_paged,
     make_mixed_attend_carry_paged,
@@ -419,7 +420,7 @@ def _attend(cfg: ModelConfig, make, table, wtable):
     model: no operand, and its jaxpr is what it was."""
     if cfg.windowed:
         return attend_by_kind(make, table, wtable, cfg.sliding_window)
-    return make(table, cfg.sliding_window, False)
+    return lane_packed(cfg, make(table, cfg.sliding_window, False))
 
 
 def _recur(cfg: ModelConfig, make, *args):
@@ -969,7 +970,7 @@ class EnginePrograms:
 
         cfg = self.cfg
         ps = self.serving.page_size
-        q = jnp.zeros((self.num_slots, 1, cfg.num_heads, cfg.head_dim),
+        q = jnp.zeros((self.num_slots, 1, cfg.num_heads, cfg.pool_head_dim),
                       jnp.bfloat16 if self.serving.dtype == "bfloat16"
                       else jnp.float32)
         lengths = jnp.full((self.num_slots,), self.pages_per_slot * ps,
@@ -1023,9 +1024,10 @@ class EnginePrograms:
             window=cfg.sliding_window, bblock=bb)
         acc = jnp.bfloat16 if self.serving.dtype == "bfloat16" \
             else jnp.float32
-        q = jnp.zeros((self.num_slots, 1, cfg.num_heads, cfg.head_dim), acc)
-        kv = jnp.zeros((self.num_slots, 1, cfg.num_kv_heads, cfg.head_dim),
-                       acc)
+        q = jnp.zeros((self.num_slots, 1, cfg.num_heads, cfg.pool_head_dim),
+                      acc)
+        kv = jnp.zeros((self.num_slots, 1, cfg.pool_kv_heads,
+                        cfg.pool_head_dim), acc)
         ctx, _ = attend(q, kv, kv, (self.cache, jnp.int32(0)))
         jax.block_until_ready(ctx)
 
@@ -1373,6 +1375,14 @@ class EnginePrograms:
                 self.selector_bytes / 2**30,
                 self.kda_state_bytes / 2**30, cfg.num_recurrent_layers,
                 cfg.recurrent_kinds or "recurrent", self.num_slots)
+        if "conv_tail" in self.cache:
+            tail = self.cache["conv_tail"]
+            logging.getLogger(__name__).info(
+                "cache: conv tails %d bytes (%d conv layers x %d slots x %d "
+                "rows of %d, float32: %d bytes a slot and layer) beside the "
+                "KV pool's %d", tail.nbytes, *tail.shape,
+                tail.nbytes // (tail.shape[0] * tail.shape[1]),
+                kvp.pool_bytes(cfg, total_pages, ps, dtype, self.kv_quant))
         if cfg.windowed:
             import logging
 
@@ -1597,8 +1607,8 @@ class EnginePrograms:
         ``state_rows`` (rows that advance a state in this dispatch, per
         layer: horizon x active for a decode dispatch), ``state_slots``
         (slots whose state a decode or mixed dispatch reads and writes) and
-        ``state_kind`` (KDA, Lightning); a model with KDA layers carries the
-        first two as ``kda_rows`` / ``kda_slots`` too, the names its readers
+        ``state_kind`` (KDA, Lightning, conv); a model with KDA layers carries
+        the first two as ``kda_rows`` / ``kda_slots`` too, the names its readers
         know."""
         if not self.cfg.recurrent:
             return {}
@@ -1686,7 +1696,7 @@ class EnginePrograms:
         tp = self.mesh.shape.get("tp", 1) if self.mesh is not None else 1
         bb = _resolve_bb(self.decode_bblock, B + C)
         tile = bb if self.cfg.selects else _tile_rows(
-            B + C, bb, self.cfg.num_heads // tp, self.cfg.head_dim, ps,
+            B + C, bb, self.cfg.num_heads // tp, self.cfg.pool_head_dim, ps,
             jnp.int8 if self.kv_quant else self.serving.dtype)
         limits = np.zeros(B + C, np.int64)
         limits[B:B + n] = off + 1 + np.arange(n)

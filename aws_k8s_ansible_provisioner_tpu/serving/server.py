@@ -1674,6 +1674,13 @@ def build_state(serving_cfg=None, model_cfg=None, params=None,
             model_cfg = tiny_trinity(vocab_size=tokenizer.vocab_size,
                                      eos_token_id=tokenizer.eos_token_id,
                                      sliding_window=2 * serving.page_size)
+        elif serving.model == "tiny-lfm2":
+            # the dry-run list of gated short convolutions ("c") and GQA
+            # layers, leading dense FFNs, then routed ones
+            from aws_k8s_ansible_provisioner_tpu.config import tiny_lfm2
+
+            model_cfg = tiny_lfm2(vocab_size=tokenizer.vocab_size,
+                                  eos_token_id=tokenizer.eos_token_id)
         else:
             raise ValueError(f"unknown model {serving.model!r} and no checkpoint")
 

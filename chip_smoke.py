@@ -1301,7 +1301,7 @@ def _handed_route(own, handed, use, picked: list):
         picked.append(idx)
         s = jax.nn.sigmoid(x.astype(jnp.float32) @ kernel.astype(jnp.float32))
         wt = jnp.take_along_axis(s, theirs, axis=-1)
-        wt = (wt / (wt.sum(axis=-1, keepdims=True) + 1e-20)
+        wt = (wt / (wt.sum(axis=-1, keepdims=True) + c.route_norm_eps)
               * c.route_scale).astype(w.dtype)
         return jnp.where(use, wt, w), jnp.where(use, theirs, idx)
 
@@ -1536,7 +1536,8 @@ def check_controls(name: str, stream: dict, cfg, params, tokenizer, plain,
 
 def handed_routing_list_program(cfg, T: int):
     """handed_routing_program for a model whose layers are a LIST with the
-    FFN by layer (window and full attention, dense then routed FFNs): the
+    FFN by layer (window and full attention, or gated short convolutions
+    and attention; dense then routed FFNs): the
     program's own stateless forward over one T-token sequence (bfloat16,
     the served tree), unrolled layer by layer with ``ops.moe.route``
     wrapped. Jitted: (tree, tokens [T], handed [routed layers, T, k], use)
@@ -1545,6 +1546,7 @@ def handed_routing_list_program(cfg, T: int):
     import jax.numpy as jnp
 
     from aws_k8s_ansible_provisioner_tpu.models import layers as L
+    from aws_k8s_ansible_provisioner_tpu.ops import linear_attention as la
     from aws_k8s_ansible_provisioner_tpu.ops import moe
 
     nd = cfg.num_dense_layers
@@ -1560,11 +1562,19 @@ def handed_routing_list_program(cfg, T: int):
             def stateless(fn):
                 return lambda q, kk, v, cl: (fn(q, kk, v, None)[0], cl)
 
+            seen = {"conv": 0, "attn": 0}
             for i, kind in enumerate(cfg.layer_pattern):
-                lp = jax.tree.map(lambda a: a[i], tree["layers"]["attn"])
+                own_stack = "conv" if kind == "c" else "attn"
+                at = seen[own_stack]
+                seen[own_stack] += 1
+                lp = jax.tree.map(lambda a: a[at], tree["layers"][own_stack])
                 stack, j = ("ffn_moe", i - nd) if i >= nd \
                     else ("ffn_dense", i)
                 fp = jax.tree.map(lambda a: a[j], tree["layers"][stack])
+                if kind == "c":
+                    x, _ = L.conv_block(cfg, lp, x, la.recur_from_zero,
+                                        ({}, at), ffn=fp)
+                    continue
                 x, _ = L.decoder_block(
                     cfg, lp, x, cos, sin,
                     stateless(attend.window if kind == "w" else attend),
@@ -1578,7 +1588,9 @@ def handed_routing_list_program(cfg, T: int):
 
 
 def check_list_routing_cause(cfg, params, plain, rows: int,
-                             strict: bool = True) -> None:
+                             strict: bool = True,
+                             scale_control: bool = True,
+                             outside: bool = True) -> None:
     """check_routing_cause for the Trinity list, whose routed branches the
     maker keeps at the other branches' gain: the reference routes on
     float32 activations and the program on bfloat16 ones, so a token's
@@ -1590,7 +1602,11 @@ def check_list_routing_cause(cfg, params, plain, rows: int,
     limit on either routing, and under handed routing the reference with
     ``route_scale`` 1, the program handed a WRONG expert (an id shifted by
     one) and one expert DROPPED (its down scale zeroed in every layer) have
-    each to be outside it. Distances as check_routing_cause's."""
+    each to be outside it (``scale_control`` False: a model whose router
+    has no scale — LFM2 — holds the wrong and the dropped expert alone;
+    ``outside`` False: those two are shown and not required, for a list
+    whose routed branches are smaller than its others).
+    Distances as check_routing_cause's."""
     import dataclasses
 
     import jax
@@ -1652,7 +1668,8 @@ def check_list_routing_cause(cfg, params, plain, rows: int,
         f"({time.monotonic() - t0:.1f}s)")
     served_handed = served(hot, ref_idx)[0]
     d_scale = apart(reference(hot, routing=ref_idx,
-                              wrong="route_scale_1")[0], served_handed, tok)
+                              wrong="route_scale_1")[0], served_handed, tok) \
+        if scale_control else (float("inf"), float("inf"))
     busiest = int(np.bincount(ref_idx.reshape(-1), minlength=E).argmax())
     wrong_idx = np.where(ref_idx == busiest, (busiest + 1) % E, ref_idx)
     d_wrong = apart(served(hot, wrong_idx)[0], ref_lp, tok)
@@ -1669,7 +1686,8 @@ def check_list_routing_cause(cfg, params, plain, rows: int,
               "the program and the reference are apart on their own or on "
               "handed routing: the routed branches are at full gain and "
               "have to be inside the limit either way")
-        check(min(d_scale[1], d_wrong[0], d_drop[0]) > LOGPROB_NATS,
+        check(not outside
+              or min(d_scale[1], d_wrong[0], d_drop[0]) > LOGPROB_NATS,
               "under handed routing route_scale 1, a wrong expert or a "
               "dropped one stays inside the limit: nothing guards them")
 
@@ -1998,6 +2016,16 @@ def main() -> int:
                               "--prefill-chunk": "256",
                               "--prefill-buckets": "64,128,2048",
                               "--max-decode-slots": "8"}
+        elif "c" in _config.MODEL_REGISTRY[model].layer_pattern:
+            # the list of gated short convolutions and GQA layers of
+            # 64-wide heads (two a pool row), dense then routed FFNs
+            model = "rehearse-lfm2"
+            _config.MODEL_REGISTRY[model] = _config.tiny_lfm2(
+                name=model, intermediate_size=256, moe_intermediate_size=64,
+                num_heads=4, num_kv_heads=2, head_dim=64, **tiny)
+            # (128 slots of a tiny model are slow on the CPU: the long
+            # background stream would outlast its client)
+            rehearse_flags = {"--max-decode-slots": "8"}
         elif _config.MODEL_REGISTRY[model].layer_pattern:
             # the hybrid: gated NoPE GQA + KDA layers, an expert share
             model = "rehearse-solar"
@@ -2105,6 +2133,22 @@ def main() -> int:
             check_list_routing_cause(cfg, eng.params, plain,
                                      96 if opts.rehearse else 512,
                                      strict=not opts.rehearse)
+        if "c" in cfg.layer_pattern:
+            # m700: two chunks of mixed_step, the second from a carried
+            # tail; every control of the reference is shown, and the ones
+            # the limits are known to see (PERF.md section 6, PR 42) have
+            # to be refused
+            refused = check_controls(
+                "m700", got["m700"], cfg, eng.params, tokenizer, plain,
+                {**plain.CONTROLS, **plain.CONTROLS_REPORTED})
+            check(opts.rehearse or all(refused[c] for c in plain.CONTROLS
+                                       if c in plain.CONTROLS_SEEN_LONG),
+                  f"the comparison passes a reference without a mechanism: "
+                  f"{refused}")
+            check_list_routing_cause(cfg, eng.params, plain,
+                                     96 if opts.rehearse else 512,
+                                     strict=not opts.rehearse,
+                                     scale_control=False, outside=False)
         if cfg.num_experts > 0:
             check_routing_counts(srv.port, cfg)
         if cfg.expert_share:
@@ -2148,8 +2192,11 @@ def main() -> int:
                                          head_dim=32), 8, 256, 32, [4],
                               interpret=True)
         else:
-            kernel_parity(cfg, slots, window, page, sorted({1, bb}),
-                          interpret=False)
+            # (at the geometry the kernels see: narrow heads lie
+            # cfg.kv_lane_pack a pool row)
+            kernel_parity(cfg.scaled(
+                head_dim=cfg.pool_head_dim, num_kv_heads=cfg.pool_kv_heads),
+                slots, window, page, sorted({1, bb}), interpret=False)
             if cfg_file is None:
                 kernel_parity(mha, 24, window, page, [1, 8], interpret=False)
         if cfg.num_experts > 0:

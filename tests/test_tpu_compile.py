@@ -719,3 +719,69 @@ def test_part_scopes_leave_the_served_programs_as_they_were(chip,
     bare = compiled()
     assert named[:2] == bare[:2]
     assert named[2] > 0 and bare[2] == 0
+
+
+@pytest.mark.parametrize("pad", [8, 0])
+def test_lfm2_decode_program_streams_its_expert_stacks_as_they_lie(
+        chip, monkeypatch, pad):
+    """LFM2-8B-A1B's served ``decode_steps`` (int8, 128 slots, block 8)
+    compiles for the chip — 64-wide heads, two a 128-lane pool row (read off
+    the head's width: ``ModelConfig.kv_lane_pack``), through the paged
+    kernels as they are — with temporaries of megabytes. Without
+    ``ops/moe.EVERY_EXPERT_TILE_PAD`` the 128-row batch is a whole MXU tile,
+    XLA's layout assignment wants the gate / up stacks contraction-minor and
+    hoists a transposed copy of both whole stacks out of the layer loop:
+    5.2 GB, and the program no longer fits beside its 11.6 GB of operands."""
+    from aws_k8s_ansible_provisioner_tpu.config import (MODEL_REGISTRY,
+                                                        ServingConfig)
+    from aws_k8s_ansible_provisioner_tpu.ops import moe
+    from aws_k8s_ansible_provisioner_tpu.serving import aot
+
+    monkeypatch.setattr(pa, "supported", lambda: True)
+    monkeypatch.setattr(moe, "EVERY_EXPERT_TILE_PAD", pad)
+    jax.clear_caches()      # the constant is read when the program is traced
+    cfg = MODEL_REGISTRY["LiquidAI/LFM2-8B-A1B"]
+    assert (cfg.pool_kv_heads, cfg.pool_head_dim) == (4, 128)
+    plan = aot.ProgramPlan(cfg, ServingConfig(
+        model=cfg.name, max_decode_slots=128, max_cache_len=2048,
+        weights_dtype="int8", decode_bblock=8, kv_host_tier_bytes=0,
+        prefill_chunk=512))
+    params, cache = aot._abstract_state(plan, None,
+                                        next(iter(chip.device_set)))
+    assert cache["k"].shape == (6, 4097, 4, 64, 128)
+    assert cache["conv_tail"].shape == (18, 128, 2, 2048)
+    _, fn, args, kwargs = next(
+        p for p in aot.enumerate_programs(plan, None, params, cache,
+                                          bblock=8)
+        if p[0] == "decode_fused_h8")
+    compiled = fn.lower(*args, **kwargs).compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert "tpu_custom_call" in compiled.as_text()
+    if not pad:
+        assert temp > 4 * 2**30
+        return
+    assert temp < 256 * 2**20
+    # What the ``recur`` part moves through HBM, which
+    # benchmark/benchlib/lfm2_opsbytes.conv_decode_dispatch counts: of the
+    # operands and results of its fusions only the ``conv_tail`` leaf (sliced
+    # for the read, updated in place) and the taps lie outside the compiler's
+    # fast memory (``S(n)`` in a layout); W_in's rows B, C, X and the gated
+    # row never pass HBM under that part's name.
+    import re
+
+    text = compiled.as_text()
+    types = dict(re.findall(r"(%[\w.\-]+) = (\(?\w+\[[\d,]*\]\{[^}]*\})",
+                            text))
+    hbm, inside = set(), False
+    for line in text.splitlines():
+        if not line.startswith(" "):        # a computation's header, or "}"
+            inside = "fused_computation" in line
+            continue
+        m = re.match(r"\s+(?:ROOT )?%[\w.\-]+ = (\(?.*?\)?) fusion\(([^)]*)\)"
+                     r".*op_name=\"[^\"]*/recur/", line)
+        if inside or not m:         # (a fusion's own body names no memory)
+            continue
+        seen = [m[1]] + [types.get(re.sub(r"/\*.*?\*/", "", a).strip(), "")
+                         for a in m[2].split(",")]
+        hbm |= {t.split("{")[0] for t in seen if t and "S(" not in t}
+    assert hbm and hbm <= {"f32[18,128,2,2048]", "bf16[18,3,2048]"}, hbm
