@@ -943,7 +943,13 @@ class Engine(EnginePrograms):
         # make the resume a prefix hit — but only over fully-WRITTEN pages
         # (the last generated token's row is pending the next dispatch)
         self._index_prompt_pages(slot, ids, n_valid=len(ids) - 1)
-        self._resume_ctx[req.id] = ids
+        if req.generated:
+            self._resume_ctx[req.id] = ids
+        # else: its first token is still on the device (the final chunk of
+        # its walk is in flight, programs._advance_chunk_mixed) and is
+        # discarded at that fetch; nothing was emitted, so it comes back as
+        # the FRESH admission it still is — the prefill samples the first
+        # token again under the same (seed, position) key
         self.slot_req[slot] = None
         # the preempted slot's host state diverges from any in-flight
         # dispatch's device carry, and its sampling rows are rewritten
@@ -1297,13 +1303,19 @@ class Engine(EnginePrograms):
 
     def _admit_round(self):
         """One admission pass of a step: (batch of (req, slot) that prefill
-        together, the admission that starts a chunk walk or None)."""
+        together, the admission that starts a chunk walk or None, whether
+        the pass LEFT a request waiting with a slot free — a page-starved
+        head: the decode horizon's question, _do_decode). The last is read
+        BEFORE the pop that found nothing to admit: a caller that comes
+        back after that pop (each scheduler call releases the GIL to it)
+        was not there for this pass, and the next step's takes it."""
         # Admission decisions come from the runtime core (FCFS; skips
         # cancelled-in-queue requests, surfacing them for client notification).
         # Bucket-fitting prompts batch into one dispatch; a chunk-needing
         # prompt ends the batch and starts the chunked path.
         batch: List = []
         chunk_next = None
+        waiting = False
         while len(batch) < max(1, self.serving.max_prefill_batch):
             # Admission is gated by the allocators' headroom (free +
             # evictable pages) — capacity scales with ACTUAL lengths, the
@@ -1313,9 +1325,12 @@ class Engine(EnginePrograms):
             # fuller group, _paged_admit fails and the requeue below retries
             # — the freed slot rotates to the back of the free deque, so
             # retries walk onto other groups' slots.
+            st = self.sched.stats()
             action = self.sched.pop_admission(
                 max(a.free_pages for a in self.allocators))
             if action is None:
+                waiting = (st.queue_depth > 0
+                           and st.active_slots < st.num_slots)
                 break
             if action[0] == "cancelled":
                 with self._lock:
@@ -1351,6 +1366,7 @@ class Engine(EnginePrograms):
                 self.sched.submit_front(
                     rid, len(ids_q),
                     max(1, req.max_tokens - len(req.generated)))
+                waiting = True
                 break
             ids, off, resumed = prep
             # prefix reuse and resumes walk the chunk program from the
@@ -1372,7 +1388,7 @@ class Engine(EnginePrograms):
                 chunk_next = (req, slot, ids, off, resumed)
                 break
             batch.append((req, slot))
-        return batch, chunk_next
+        return batch, chunk_next, waiting
 
     def step(self) -> bool:
         """One scheduling step. Priority: advance a chunked prefill (with one
@@ -1434,19 +1450,22 @@ class Engine(EnginePrograms):
         # or start a chunk — slot reuse under unfetched tokens would
         # mis-route the deferred emits to the new request. With the ragged
         # mixed path on, admission under an in-flight dispatch is forced
-        # onto the chunk walk (below), which keeps the carry valid and
-        # never activates a slot before the dispatch settles — so the
-        # pipeline stays open across admissions (the whole point of the
-        # ragged program; deferred emits for a freed slot are discarded by
-        # the slot_req-is-None guard in _decode_fetch, never mis-routed,
-        # because _activate only runs after the in-flight fetch).
+        # onto the chunk walk (below), which keeps the carry valid — so the
+        # pipeline stays open across admissions, the walk's final chunk
+        # included (the whole point of the ragged program). The invariant:
+        # a slot JOINS the batch only after every dispatch that listed its
+        # previous occupant has been fetched (_advance_chunk_mixed joins
+        # after fetching the predecessor of the walk's final mixed
+        # dispatch, which lists the slot nowhere among its decode rows), so
+        # deferred emits for a freed slot are discarded by the
+        # slot_req-is-None guard in _decode_fetch, never mis-routed.
         if (self._inflight is not None
                 and self.sched.stats().queue_depth > 0
                 and not self._ragged_on()):
             self._drain_decode_pipeline("prefill")
         self._await_arrival()
         with _phase(PH_ADMIT):
-            batch, chunk_next = self._admit_round()
+            batch, chunk_next, waiting = self._admit_round()
         if batch or chunk_next is not None:
             self._admission_blocked_since = 0.0
         else:
@@ -1504,7 +1523,7 @@ class Engine(EnginePrograms):
             self._chunk_yield = True
             return True
         if self._active_slots():
-            self._do_decode()
+            self._do_decode(prefill_possible=waiting)
             return True
         if self._inflight is not None:
             # cancel/deadline reaps emptied the batch with a dispatch still
@@ -1528,7 +1547,14 @@ class Engine(EnginePrograms):
         the emit loop took tens of ms and such a caller was queued by the
         time the engine looked; now the engine looks within a few ms), and
         any arrival in the first half of a dispatch. The half is a choice,
-        not a swept optimum (PERF.md section 7)."""
+        not a swept optimum (PERF.md section 7). A running mixed dispatch
+        whose final chunk rides it (its record's ``first``) is waited on
+        like any other: the callers its predecessor's fetch just answered
+        come back in these milliseconds and are admitted BEHIND it (each a
+        mixed step that also advances every decode row) and not behind a
+        whole decode horizon; the first token it carries goes out at its
+        fetch, which the other half of its time still precedes (PERF.md
+        section 6, PR 44, has the host's wait at those fetches)."""
         rec = self._inflight
         if rec is None or self.draining:
             return

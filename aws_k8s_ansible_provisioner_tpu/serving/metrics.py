@@ -246,6 +246,18 @@ class EngineMetrics:
             "tpu_serve_pipeline_depth",
             "Decode dispatches currently in flight past the fetched one "
             "(1 = pipelined steady state, 0 = synchronous/drained)"))
+        # How the slot of an admission that ended a ragged mixed chunk walk
+        # joined the batch (programs._advance_chunk_mixed): "in_flight" —
+        # the final mixed dispatch stayed in flight, the slot joined from
+        # its device carry and the first token went out at its fetch —, or
+        # "settled" — the dispatch was fetched before anything else was
+        # enqueued (a resume, penalties, a guided request, prompt_logprobs,
+        # spec decode, a draining engine), which idles the device for the
+        # host's turn and books it as decode bubble above.
+        self.activations = r.register(Counter(
+            "tpu_serve_activations_total",
+            "Admissions that ended a ragged mixed chunk walk, by how the "
+            "slot joined the batch", ("path",)))
         # Wall time spent inside device dispatches (prefill + decode). The
         # node metrics exporter scrapes this across the process boundary and
         # derives tpu_duty_cycle_percent from its rate — the engine process

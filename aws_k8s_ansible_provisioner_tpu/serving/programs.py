@@ -354,6 +354,13 @@ def _host_lp(lp_t, row: int, k: int):
     return (sel, [(int(ids[j]), float(vals[j])) for j in range(k)])
 
 
+@jax.jit
+def _zero_lanes(lens, dead):
+    """The length carry with the lanes of the slots that hold no request
+    set to 0, as their mirror is (``EnginePrograms._carry_in``)."""
+    return jnp.where(dead, 0, lens)
+
+
 @partial(jax.jit, donate_argnums=(0,))
 def _reset_count_row(counts, slot, token):
     """Zero a recycled slot's generated-token counts and count its first
@@ -1884,7 +1891,13 @@ class EnginePrograms:
 
     def _activate(self, req: Request, slot: int, token: int, lp=None,
                   ids: Optional[List[int]] = None, resumed: bool = False):
-        """Shared post-prefill bookkeeping: slot state + TTFT + first token.
+        """Shared post-prefill bookkeeping of a prefill whose sampled token
+        is on the host: the slot joins the batch (``_join``) and, unless
+        this is a resume, its first token goes out (``_first_token``), back
+        to back. The device carry of a dispatch still in flight does not
+        hold this slot's token and length, so the carry generation moves
+        (the final chunk of a mixed walk that stays in flight calls the two
+        halves apart, and does NOT move it: ``_advance_chunk_mixed``).
 
         ``ids`` overrides the cache-resident token sequence when it differs
         from the request prompt — a preemption resume re-prefills
@@ -1895,18 +1908,46 @@ class EnginePrograms:
         decode dispatch produces the continuation with penalties and the
         seeded key it would have used without the preemption — bit-identical
         streams either way."""
-        ids = list(req.prompt_ids) if ids is None else ids
         # an in-flight decode dispatch's device carry (token/length) no
-        # longer describes the batch once this slot joins it; its sampling
-        # operand rows change too
+        # longer describes the batch once this slot joins it
         self._carry_gen += 1
-        self._op_dirty_sampling = True
+        self._join(req, slot, ids, resumed, token)
+        if not resumed:
+            self._first_token(req, slot, token, lp)
+
+    @staticmethod
+    def _penalised(req: Request) -> bool:
+        return bool(req.presence_penalty or req.frequency_penalty
+                    or (req.repetition_penalty
+                        and req.repetition_penalty != 1.0))
+
+    def _first_token(self, req: Request, slot: int, token: int, lp=None):
+        """The half of an activation that needs the sampled token's VALUE:
+        TTFT, the emit (stop check, ``last_token``'s mirror) and the
+        stream's first item. Inside an emit phase."""
         now = time.monotonic()
-        if not req.t_first_token:     # don't re-observe on preemption resume
+        if not req.t_first_token:
             req.t_first_token = now
             self.metrics.ttft.observe(now - req.t_submit,
                                       trace_id=req.trace_id or None)
             _slo.get().observe_ttft(now - req.t_submit)
+        # the first token is TTFT: an item of its own, put at once
+        self._emit(slot, token, lp)
+        self._put_pending(req)
+
+    def _join(self, req: Request, slot: int,
+              ids: Optional[List[int]] = None, resumed: bool = False,
+              token: Optional[int] = None):
+        """The half of an activation the host knows at ENQUEUE time: the
+        slot holds the request from here on (``slot_req``, its length, its
+        sampling rows — uploaded with the next dispatch's operands —, the
+        scheduler's and the prefix index's view of it). ``token`` is the
+        sampled first token, which only a penalised request's count row
+        takes; a caller that has not fetched it yet passes None and never
+        joins such a request (``_first_token_can_wait``)."""
+        ids = list(req.prompt_ids) if ids is None else ids
+        self._op_dirty_sampling = True
+        now = time.monotonic()
         _flight.record("admit", req.id, slot=slot, resumed=resumed,
                        queue_wait_s=round(max(0.0, (req.t_prefill_start
                                                     or now) - req.t_submit),
@@ -1940,9 +1981,7 @@ class EnginePrograms:
             self.prompt_mask = _set_mask_row(self.prompt_mask,
                                              jnp.int32(slot),
                                              jnp.asarray(row))
-        if (req.presence_penalty or req.frequency_penalty
-                or (req.repetition_penalty
-                    and req.repetition_penalty != 1.0)):
+        if self._penalised(req):
             # Only penalized occupants touch the counts array: a stale row
             # under a zero-penalty occupant is multiplied by zero, so
             # un-penalized prefills never pay this extra device dispatch.
@@ -1978,10 +2017,6 @@ class EnginePrograms:
                 # the same stale mark _start_chunk applied, kept for the
                 # invariant "resumed slot => stale" independent of path
                 self.draft.mark_stale(slot)
-        else:
-            # the first token is TTFT: an item of its own, put at once
-            self._emit(slot, token, lp)
-            self._put_pending(req)
 
     @staticmethod
     def _host_prompt_lp(req: Request, plp, row: int, n_prompt: int) -> None:
@@ -2315,6 +2350,26 @@ class EnginePrograms:
                 self._activate(req, slot, token, lp,
                                ids=list(st["ids"]), resumed=st["resumed"])
 
+    def _first_token_can_wait(self, st: dict) -> bool:
+        """May the FINAL chunk of this mixed walk stay in flight, its slot
+        joining the batch from the device carry (``mixed_step`` leaves the
+        sampled first token and the post-prompt length in the slot's carry
+        lanes) and its first token going out one dispatch later, at the
+        fetch? Read off the request and the engine, no knob: not where the
+        token's VALUE or the emitted state is needed before the next
+        dispatch can be built — a resume (its sampled token is discarded
+        and the carry's lane is wrong for it), penalties (the count row
+        takes the token), a guided request (its FSM must see the token
+        before the next mask), ``prompt_logprobs``, spec decode or a draft
+        model (they read the host mirrors), a draining engine (which must
+        reach "nothing in flight" with its last emit)."""
+        req = st["req"]
+        return not (st["resumed"] or req.guided is not None
+                    or req.prompt_logprobs is not None
+                    or self._penalised(req)
+                    or self.serving.spec_decode or self.draft is not None
+                    or self.draining)
+
     def _advance_chunk_mixed(self, st: dict) -> None:
         """One RAGGED mixed dispatch: this walk's next prefill chunk packed
         alongside the whole decode batch, served by a single program
@@ -2325,10 +2380,21 @@ class EnginePrograms:
         admission plus a serialized chunk dispatch per chunk; here both
         costs go to zero.
 
-        The final chunk is the one exception: activation needs the sampled
-        first token immediately, so that dispatch settles synchronously.
-        Nothing is discarded early — both it and any in-flight predecessor
-        are fully emitted — so the drain counter does NOT move.
+        The FINAL chunk stays in flight too (``_first_token_can_wait``):
+        once its predecessor is fetched — never before, so no deferred emit
+        of the slot's previous occupant can reach the new request — the
+        slot JOINS the batch on the host (``_join``) WITHOUT moving the
+        carry generation: this dispatch's device carry describes the batch
+        with the slot in it. The next dispatch is enqueued behind it with
+        the slot among its rows, and the first token is emitted where this
+        one is fetched (``_decode_fetch``: the record's ``first``). A
+        request that left the slot meanwhile (cancel, deadline, a
+        preemption — which moves the generation and drains) has its unseen
+        token discarded there. Where the token is needed at once the final
+        dispatch settles synchronously as before — predecessor, then
+        itself, then ``_activate``; nothing is discarded early, so the
+        drain counter does NOT move. ``tpu_serve_activations_total{path}``
+        counts each way.
         """
         req, slot = st["req"], st["slot"]
         C = st["C"]
@@ -2336,6 +2402,7 @@ class EnginePrograms:
         off = st["off"]
         chunk = ids[off:off + C]
         final = off + len(chunk) >= len(ids)
+        rides = final and self._first_token_can_wait(st)
         _flight.record("prefill_chunk", req.id, off=off, n=len(chunk),
                        mixed=True)
         prev = self._inflight
@@ -2370,32 +2437,33 @@ class EnginePrograms:
             # emitted, at activation).
             self._settle_inflight()
             prev = None
-        if self._carry_valid():
-            # valid after a settle too (prev is None, carry retained)
-            tok_in, len_in = self._pipe_carry[0], self._pipe_carry[1]
-        else:
-            tok_in = self._donatable(self.last_token)
-            len_in = self._donatable(self.lengths)
         try:
-            rec = self._mixed_dispatch(st, chunk, tok_in, len_in)
+            # (the carry is valid after a settle too: prev is None, the
+            # carry retained)
+            rec = self._mixed_dispatch(st, chunk, *self._carry_in())
+            if final:
+                # how this admission's slot joins the batch, beside
+                # chunk_n in the engine.dispatch record
+                rec["drec"]["activation"] = \
+                    "in_flight" if rides else "settled"
             st["off"] = off + len(chunk)
             self.lengths[slot] = st["off"]
-            if not final:
+            if rides or not final:
                 # steady state: leave the mixed dispatch in flight, settle
                 # its predecessor while the device runs this one
                 self._inflight = rec
                 self.metrics.pipeline_depth.set(1.0)
                 if prev is not None:
                     self._decode_fetch(prev, tail=False)
-                return
-            # final chunk: settle in order — predecessor first, then this
-            # dispatch (whose chunk token activates the slot below)
-            self._pipe_carry = None
-            if prev is not None:
-                self._inflight = None
-                self.metrics.pipeline_depth.set(0.0)
-                self._decode_fetch(prev, tail=False)
-            self._decode_fetch(rec, tail=True)
+            else:
+                # settle in order — predecessor first, then this dispatch
+                # (whose chunk token activates the slot below)
+                self._pipe_carry = None
+                if prev is not None:
+                    self._inflight = None
+                    self.metrics.pipeline_depth.set(0.0)
+                    self._decode_fetch(prev, tail=False)
+                self._decode_fetch(rec, tail=True)
         except Exception:
             # exactly-once release: clearing _chunk BEFORE the raise means
             # the engine's failover (_fail_all) sees no chunk in progress
@@ -2407,12 +2475,28 @@ class EnginePrograms:
             self.metrics.mark_request("error", 0.0)
             self._close_stream(req)
             raise
+        if not final:
+            return
+        self._chunk = None
+        self.metrics.activations.inc(path=rec["drec"]["activation"])
+        if rides:
+            # from here the slot is an active one like any other (reaped,
+            # preempted, failed as such); only its first token is still on
+            # the device, and goes out at this record's fetch
+            rec["first"] = (req, slot)
+            self._join(req, slot, ids=list(st["ids"]))
+            return
+        with self._emit_phase():
+            self._activate(req, slot, *self._chunk_sample(rec, req),
+                           ids=list(st["ids"]), resumed=st["resumed"])
+
+    @staticmethod
+    def _chunk_sample(rec: dict, req: Request) -> tuple:
+        """(token, logprobs or None) a FETCHED mixed record's chunk row
+        sampled: the first token of the request whose walk it ended."""
         lp = _host_lp(rec["chunk_lp_t"], 0, req.logprobs) \
             if rec["chunk_lp"] else None
-        self._chunk = None
-        with self._emit_phase():
-            self._activate(req, slot, rec["chunk_token"], lp,
-                           ids=list(st["ids"]), resumed=st["resumed"])
+        return rec["chunk_token"], lp
 
     def _mixed_dispatch(self, st: dict, chunk, tok_in, len_in) -> dict:
         """Enqueue ONE ragged mixed dispatch (prefill chunk + decode batch)
@@ -2707,10 +2791,40 @@ class EnginePrograms:
     def _carry_valid(self) -> bool:
         """True while the device-resident token/length carry of the
         in-flight dispatch still describes the batch — no slot was
-        activated, preempted, or otherwise rewritten since it was
-        enqueued (every such transition bumps ``_carry_gen``)."""
+        activated from the host, preempted, or otherwise rewritten since
+        it was enqueued (every such transition bumps ``_carry_gen``; a
+        slot that joins from a mixed dispatch's own carry lanes is IN the
+        carry and bumps nothing)."""
         return (self._pipe_carry is not None
                 and self._pipe_carry[2] == self._carry_gen)
+
+    def _carry_in(self) -> tuple:
+        """The (token, length) operands of the next decode or mixed
+        dispatch. While the carry is valid, dispatch N's final arrays feed
+        dispatch N+1 directly (donated) — no host round-trip; still so
+        after a settle (``_settle_inflight``'s contract). Else a fresh
+        upload of the host mirrors. The length lanes of the slots that
+        hold NO request are zeroed on the way, as their mirror is (one
+        tiny program, enqueued like an operand upload; the mask is
+        uploaded when the set of empty slots changes): the step programs
+        run every slot, a lane left to itself grows a step a token and its
+        row then walks ever more pages of scratch. The mirrors' upload
+        after every activation used to reset them; with admissions joining
+        from the carry the pipeline no longer closes, and under open
+        traffic most slots are empty. (A slot mid-walk is one of them: the
+        mixed program ignores its lane and sets it, ``is_p``.)"""
+        if not self._carry_valid():
+            return (self._donatable(self.last_token),
+                    self._donatable(self.lengths))
+        tok, lens = self._pipe_carry[:2]
+        empty = tuple(r is None for r in self.slot_req)
+        if any(empty):
+            oc = self._op_cache
+            if oc.get("empty_key") != empty:
+                oc["empty_key"], oc["empty"] = empty, jnp.asarray(
+                    np.asarray(empty))
+            lens = _zero_lanes(lens, oc["empty"])
+        return tok, lens
 
     def _drain_decode_pipeline(self, reason: str = "drain") -> None:
         """Fetch + emit the in-flight decode dispatch, if any.
@@ -2748,8 +2862,12 @@ class EnginePrograms:
         engine._finish), so ``_pipe_carry`` remains valid and the next
         dispatch feeds it straight back in, device-resident: no host
         re-upload, no ``tpu_serve_pipeline_drains_total`` increment. Only
-        transitions that REWRITE slot state (activate/preempt/spec-verify
-        host advance) invalidate the carry.
+        transitions that REWRITE slot state out of band of the carry (an
+        activation from a token already on the host, a preemption, the
+        spec verify's host advance) invalidate it; a slot that joins from
+        the carry itself — the final chunk of a mixed walk left in flight
+        — does not. Settling such a record emits that slot's first token
+        with the rest (``_decode_fetch``).
         """
         rec = self._inflight
         if rec is None:
@@ -2800,19 +2918,23 @@ class EnginePrograms:
             oc["lora"] = self._lora_vec()
             self._op_dirty_sampling = False
         if self._op_dirty_table or "table" not in oc:
-            oc["table"] = jnp.asarray(self.table)
+            # COPIES (_donatable's reason): the mirrors are rewritten in
+            # place — Engine._win_cover (a released page's row reads
+            # scratch, then another slot's page), an admission that hands
+            # a freed slot its pages — while a dispatch that took the table
+            # may still be in flight, and on the CPU backend jnp.asarray of
+            # a mirror is a VIEW of it: that dispatch's dead row for the
+            # slot (length lane 0: _carry_in) would write into the new
+            # occupant's first page and not into scratch
+            oc["table"] = self._donatable(self.table)
             if self.cfg.windowed:
-                # a COPY (_donatable's reason): Engine._win_cover rewrites
-                # entries in place — a released page's reads scratch, then
-                # another slot's page — while a dispatch that took the
-                # table may still be in flight, and on the CPU backend
-                # jnp.asarray of the mirror is a view of it
                 oc["wtable"] = self._donatable(self.wtable)
             self._op_dirty_table = False
         return oc
 
     def _do_decode(self, max_horizon: Optional[int] = None,
-                   fair_horizon: bool = False):
+                   fair_horizon: bool = False,
+                   prefill_possible: Optional[bool] = None):
         ch = _chaos.get()
         if ch.enabled:
             # an armed "stalled_decode" wedges here (standing in for a hung
@@ -2836,8 +2958,17 @@ class EnginePrograms:
         # A fairness-forced decode (``fair_horizon``) takes the FULL horizon
         # even though a prefill is possible: that is the point — one real
         # decode dispatch per prefill_fairness prefills.
-        st = self.sched.stats()
-        prefill_possible = st.queue_depth > 0 and st.active_slots < st.num_slots
+        # (``prefill_possible``: Engine.step hands over what its admission
+        # pass left waiting, read before the pop that found nothing to
+        # admit — a caller that comes back between that pop and this point
+        # is taken by the next step's walk behind a fused dispatch like any
+        # arrival a moment later; read HERE it chose the one-step program,
+        # which no warm-up reaches, a few times a window once admissions
+        # stopped settling: PERF.md section 6, PR 44)
+        if prefill_possible is None:
+            st = self.sched.stats()
+            prefill_possible = (st.queue_depth > 0
+                                and st.active_slots < st.num_slots)
         horizon = 1 if (prefill_possible and not fair_horizon) \
             else max(1, self.serving.decode_horizon)
         if max_horizon is not None:
@@ -2956,17 +3087,8 @@ class EnginePrograms:
         want_pen = self.counts is not None and bool(
             self.pres_pens.any() or self.freq_pens.any()
             or (self.rep_pens != 1.0).any())
-        if self._carry_valid():
-            # device-resident carry: dispatch N's final token/length arrays
-            # feed dispatch N+1 directly (donated) — no host round-trip.
-            # Still valid after a settle (prev is None but the carry
-            # survives — _settle_inflight's contract).
-            tok_in, len_in = self._pipe_carry[0], self._pipe_carry[1]
-        else:
-            tok_in = self._donatable(self.last_token)
-            len_in = self._donatable(self.lengths)
         rec = self._decode_dispatch(horizon, active, gset, gslots, want_lp,
-                                    want_pen, tok_in, len_in)
+                                    want_pen, *self._carry_in())
         if self._pipeline_on() and (feats or not gset):
             # leave the new dispatch in flight: its fetch is deferred to
             # the next decode step (or a pipeline drain), so the entire
@@ -3062,7 +3184,9 @@ class EnginePrograms:
         enqueued was still computed speculatively on the device; its
         surplus tokens are discarded here by the ``slot_req is None``
         guard, under the same rewrite invariant the guided/chunk surplus
-        paths rely on.
+        paths rely on. A mixed record whose final chunk stayed in flight
+        carries ``first`` (request, slot): that request's first token is
+        emitted here, after the decode rows'.
         """
         ch = _chaos.get()
         if ch.enabled:
@@ -3095,7 +3219,8 @@ class EnginePrograms:
             if rec.get("mixed"):
                 # chunk-row outputs ride the same record: the sampled token
                 # of the chunk's last position (only meaningful on the final
-                # chunk, where _advance_chunk_mixed activates with it)
+                # chunk: the slot's first token, emitted below or handed to
+                # _activate by _advance_chunk_mixed)
                 pout = rec["pout"]
                 if rec["chunk_lp"]:
                     ptok_arr, plp = pout
@@ -3190,6 +3315,17 @@ class EnginePrograms:
                 guided_rows=len(rec["gslots"]), tail=tail, emitted=emitted,
                 puts=int(self.metrics.stream_items.total() - puts0),
                 t_wait=t_fetch)
+        first = rec.get("first")
+        if first is not None and self.slot_req[first[1]] is first[0]:
+            # a final chunk that stayed in flight (_advance_chunk_mixed):
+            # its slot joined the batch at the enqueue, its first token
+            # goes out here, after the decode rows' as it always did. A
+            # request that left the slot meanwhile (cancel, deadline,
+            # preemption) fails the identity test — no later occupant can
+            # have joined before this fetch — and the token is discarded.
+            req, slot = first
+            with self._emit_phase():
+                self._first_token(req, slot, *self._chunk_sample(rec, req))
         self._tok_times.append((rec["drec"]["t_enqueue"], emitted))
         if len(self._tok_times) >= 2:
             span = time.monotonic() - self._tok_times[0][0]
@@ -3294,6 +3430,10 @@ class EnginePrograms:
                 self.step()
 
         horizon = max(1, self.serving.decode_horizon)
+        # the carry's empty-lane reset (_carry_in): first met with a slot
+        # empty under an open pipeline, i.e. while streams are served
+        _zero_lanes(self._donatable(self.lengths),
+                    jnp.zeros(self.num_slots, bool))
         if scope == "bench":
             nb = min(self.serving.max_prefill_batch, self.num_slots)
             rs = [Request(prompt_ids=[0] * 4, max_tokens=1, ignore_eos=True)

@@ -518,6 +518,14 @@ def run_requests(srv: Server, model: str) -> dict:
     for kind in ("prefill", "prefill_batch", "decode", "mixed_step"):
         check(kind in ran, f"program kind {kind!r} never dispatched "
                            f"(dispatched: {sorted(ran)})")
+    rode, settled = (delta(f'tpu_serve_activations_total{{path="{p}"}}')
+                     for p in ("in_flight", "settled"))
+    check(rode >= 1 and settled == 0,
+          f"the admissions beside the live stream ended {rode} walks with "
+          f"the final chunk in flight and settled {settled}: none of them "
+          f"is resumed, penalised or guided")
+    say(f"requests: {int(rode)} admissions joined the batch from the final "
+        f"chunk's device carry, their first token at its fetch; 0 settled")
     tile, by8 = (delta(f'tpu_serve_ragged_page_steps_total{{path="{p}"}}')
                  for p in ("tile", "by8"))
     check(by8 >= tile > 0, f"the admitted chunks walked {tile} page steps "

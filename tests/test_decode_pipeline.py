@@ -378,6 +378,7 @@ def _edge_drains() -> int:
 
 _LONG_A = [(i % 150) + 4 for i in range(100)]
 _LONG_B = [(i % 90) + 6 for i in range(80)]
+_SHORT = [(i % 40) + 7 for i in range(20)]      # one chunk of 32
 
 
 def _ragged_engine(model, ragged: int, **over):
@@ -408,6 +409,23 @@ def test_mixed_traffic_pipeline_stays_open_and_byte_identical(model):
         # flight, or the admission edges below exercise nothing
         assert eng._inflight is not None
         before = _edge_drains()
+        # a ONE-chunk admission under the live batch: its only mixed
+        # dispatch is the walk's final one
+        short = eng.submit(Request(prompt_ids=list(_SHORT), max_tokens=6,
+                                   temperature=0.0, ignore_eos=True))
+        eng.step()
+        if ragged:
+            # ... which stays in flight, the slot joined and its first
+            # token still on the device; the step AFTER finds a dispatch in
+            # flight too (the next decode, enqueued behind it)
+            rec = eng._inflight
+            assert eng._chunk is None and rec is not None
+            assert rec["first"][0] is short and short.generated == []
+            eng.step()
+            assert eng._inflight is not None and eng._inflight is not rec
+            assert len(short.generated) >= 1
+        while not short.finish_reason:
+            eng.step()
         late_a = eng.submit(Request(prompt_ids=list(_LONG_A), max_tokens=8,
                                     temperature=0.9, seed=7,
                                     ignore_eos=True))
@@ -417,7 +435,7 @@ def test_mixed_traffic_pipeline_stays_open_and_byte_identical(model):
                                     temperature=0.8, seed=13,
                                     ignore_eos=True))
         _drain(eng)
-        return eng, (first, late_a, late_b), _edge_drains() - before
+        return eng, (first, short, late_a, late_b), _edge_drains() - before
 
     eng1, ragged_streams, ragged_edge = run(1)
     eng0, legacy_streams, legacy_edge = run(0)
@@ -628,8 +646,12 @@ def test_stream_items_one_a_dispatch_and_concatenate(model, kind, horizon):
                 return real(*a, **kw)
             finally:
                 eng._dispatch_close = real_close
+                # (a first token that rode a mixed record goes out at its
+                # fetch, as the activation's own item: not the record's)
+                rode = a[0].get("first") if isinstance(a[0], dict) else None
                 grew = [len(r.generated) - before[r.id] for r in reqs
-                        if r.id in before]
+                        if r.id in before
+                        and (rode is None or rode[0] is not r)]
                 closed.append((got["rec"], sum(1 for g in grew if g),
                                sum(grew)))
         setattr(eng, name, wrapped)
@@ -997,6 +1019,427 @@ def test_chaos_seasoned_mixed_features_zero_feature_drains(model, tmp_path):
     finally:
         stop.set()
         t.join(timeout=10)
+
+
+# -- the final chunk of an admission stays in flight (ISSUE 44) --------------
+
+
+def _live(eng, n=100):
+    """A background stream that is decoding with a dispatch in flight."""
+    live = eng.submit(Request(prompt_ids=[5, 9, 2], max_tokens=n,
+                              temperature=0.9, seed=42, ignore_eos=True))
+    for _ in range(6):
+        eng.step()
+    assert eng._inflight is not None
+    return live
+
+
+def _admit_in_flight(eng, **spec):
+    """Submit a one-chunk request under the live batch and step ONCE: the
+    walk's final (only) mixed dispatch is left in flight, the slot has
+    joined the batch and nothing of the request has been seen yet."""
+    req = eng.submit(Request(**spec))
+    eng.step()
+    rec = eng._inflight
+    assert eng._chunk is None and rec is not None and rec.get("mixed")
+    assert rec["drec"]["activation"] == "in_flight"
+    slot = rec["first"][1]
+    assert rec["first"][0] is req and eng.slot_req[slot] is req
+    assert req.generated == [] and not req.t_first_token
+    return req, slot
+
+
+def _paths(eng):
+    m = eng.metrics.activations
+    return int(m.value(path="in_flight")), int(m.value(path="settled"))
+
+
+_UNDER_LIVE = {
+    "greedy": dict(prompt_ids=list(_SHORT), max_tokens=9, temperature=0.0,
+                   ignore_eos=True),
+    "seeded": dict(prompt_ids=list(_SHORT), max_tokens=9, temperature=0.9,
+                   top_p=0.9, seed=7, ignore_eos=True),
+    "logprobs": dict(prompt_ids=list(_SHORT), max_tokens=9, temperature=0.8,
+                     seed=11, ignore_eos=True, logprobs=3),
+    "min_tokens_bias": dict(prompt_ids=list(_SHORT), max_tokens=9,
+                            temperature=0.0, min_tokens=4,
+                            logit_bias=((_EOS, 100.0),)),
+}
+
+
+@pytest.mark.ragged_smoke
+@pytest.mark.parametrize("kind", list(_UNDER_LIVE))
+def test_in_flight_final_chunk_streams_are_the_legacy_engines(model, kind):
+    """Closed mixed traffic: a request admitted under a live batch whose
+    final chunk stays in flight streams byte for byte what the legacy walk
+    streams — greedy, seeded, with logprobs, with the first token under a
+    min_tokens ban and a bias — and so does its neighbour."""
+
+    def run(ragged):
+        eng = _ragged_engine(model, ragged)
+        live = _live(eng)
+        late = eng.submit(Request(**_UNDER_LIVE[kind]))
+        _drain(eng)
+        _assert_released(eng, 2)
+        return eng, live, late
+
+    eng1, live1, late1 = run(1)
+    _, live0, late0 = run(0)
+    assert _paths(eng1) == (1, 0)
+    if kind == "logprobs":
+        # the VALUES come from two programs (mixed_step and the legacy
+        # chunk program; one decode batch and two) and differ in the last
+        # float32 digit, at the parent commit as here: ids exact, values
+        # to 1e-5
+        assert late1.generated == late0.generated
+        for (own1, alts1), (own0, alts0) in zip(late1.logprob_data,
+                                                late0.logprob_data):
+            assert [t for t, _ in alts1] == [t for t, _ in alts0]
+            assert [own1] + [v for _, v in alts1] == pytest.approx(
+                [own0] + [v for _, v in alts0], abs=1e-5)
+    else:
+        assert _stream_bytes(late1) == _stream_bytes(late0)
+    assert _stream_bytes(live1) == _stream_bytes(live0)
+    assert late1.t_first_token and eng1.metrics.ttft._total == 2
+
+
+@pytest.mark.ragged_smoke
+def test_two_waiting_requests_walk_one_behind_the_other(model):
+    """Two requests wait at once beside a live stream: the second meets the
+    first's final chunk in flight and takes the chunk walk too — its mixed
+    dispatch is enqueued behind, decodes the first's row from the device
+    carry, and the first's token goes out where ITS dispatch is fetched.
+    No prefill program runs on an idle pipeline; the streams are the legacy
+    engine's."""
+    specs = [dict(prompt_ids=list(_SHORT), max_tokens=9, temperature=0.9,
+                  seed=7, ignore_eos=True),
+             dict(prompt_ids=list(_SHORT[3:]), max_tokens=9,
+                  temperature=0.0, ignore_eos=True)]
+
+    def run(ragged):
+        eng = _ragged_engine(model, ragged, max_decode_slots=3)
+        live = _live(eng)
+        a, b = (eng.submit(Request(**s)) for s in specs)
+        if ragged:
+            eng.step()
+            m1 = eng._inflight
+            assert m1["first"][0] is a and eng._chunk is None
+            eng.step()
+            m2 = eng._inflight
+            assert m2["first"][0] is b and m2["drec"]["carry_steps"] == 1
+            assert m1["first"][1] in m2["active"], \
+                "the first's row must decode in the second's mixed dispatch"
+            assert len(a.generated) == 1 and b.generated == []
+            eng.step()
+            assert len(a.generated) == 2 and len(b.generated) == 1
+        _drain(eng)
+        _assert_released(eng, 3)
+        return eng, (live, a, b)
+
+    eng, ragged = run(1)
+    _, legacy = run(0)
+    assert _paths(eng) == (2, 0)
+    for r, s in zip(ragged, legacy):
+        assert _stream_bytes(r) == _stream_bytes(s)
+
+
+@pytest.mark.ragged_smoke
+@pytest.mark.parametrize("how", ["max_tokens_1", "eos"])
+def test_first_token_that_ends_its_request_finishes_once(model, how):
+    """A first token that ends its request (the budget, EOS) finishes the
+    slot at the mixed dispatch's fetch, once; the rows the FOLLOWING
+    dispatch already computed for the slot are surplus and discarded, and
+    the slot's next occupant receives none of them."""
+    spec = dict(prompt_ids=list(_SHORT), temperature=0.0, stream=True)
+    if how == "eos":
+        spec.update(max_tokens=8, logit_bias=((_EOS, 100.0),))
+    else:
+        spec.update(max_tokens=1, ignore_eos=True)
+    follower = dict(prompt_ids=[8, 3, 1, 4], max_tokens=7, temperature=0.9,
+                    seed=5, ignore_eos=True, stream=True)
+    solo = _run_set(_ragged_engine(model, 1), [dict(follower)])[0]
+
+    eng = _ragged_engine(model, 1)
+    live = _live(eng)
+    finished0 = eng.sched.stats().finished_total
+    req, slot = _admit_in_flight(eng, **spec)
+    eng.step()      # the next decode enqueued WITH the slot, then M's fetch
+    assert req.finish_reason == ("stop" if how == "eos" else "length")
+    assert len(req.generated) == 1 and eng.slot_req[slot] is None
+    assert (how == "eos") == (req.generated == [_EOS])
+    assert eng.sched.stats().finished_total == finished0 + 1
+    surplus = eng._inflight
+    assert surplus is not None and slot in surplus["active"], \
+        "no dispatch holds surplus rows of the slot (test is vacuous)"
+    # the next occupant of the SAME slot, admitted under that dispatch
+    nxt, slot2 = _admit_in_flight(eng, **follower)
+    assert slot2 == slot
+    _drain(eng)
+    items, nones = _queue_items(req)
+    assert (items, nones) == ([req.generated], 1), "finished more than once"
+    assert nxt.generated == solo.generated, \
+        "the slot's next occupant received its predecessor's surplus rows"
+    items, nones = _queue_items(nxt)
+    assert nones == 1 and [t for it in items for t in it] == nxt.generated
+    assert items[0] == nxt.generated[:1]
+    assert live.finish_reason == "length"
+    _assert_released(eng, 3)
+
+
+@pytest.mark.ragged_smoke
+@pytest.mark.parametrize("how", ["cancel", "deadline"])
+def test_request_reaped_while_its_final_chunk_is_in_flight(model, how):
+    """A cancel or a deadline between the join and the fetch: the request
+    is reaped as any active slot is, the chunk token that comes back is
+    discarded (never emitted), slot and pages go back exactly once and the
+    neighbour's seeded stream is what it is alone."""
+    alone = _ragged_engine(model, 1)
+    ref = _live(alone)
+    _drain(alone)
+
+    eng = _ragged_engine(model, 1)
+    live = _live(eng)
+    req, slot = _admit_in_flight(eng, prompt_ids=list(_SHORT), max_tokens=9,
+                                 temperature=0.0, ignore_eos=True,
+                                 stream=True)
+    if how == "cancel":
+        eng.cancel(req)
+    else:
+        req.t_deadline = time.monotonic() - 1e-3
+    eng.step()
+    assert req.finish_reason == ("cancelled" if how == "cancel"
+                                 else "timeout")
+    assert eng.slot_req[slot] is None
+    _drain(eng)
+    assert req.generated == [] and not req.t_first_token
+    assert _queue_items(req) == ([], 1)
+    assert _stream_bytes(live) == _stream_bytes(ref)
+    assert eng.metrics.ttft._total == 1
+    _assert_released(eng, 2)
+
+
+@pytest.mark.ragged_smoke
+@pytest.mark.parametrize("fault", ["pipeline_fetch_error",
+                                   "ragged_dispatch_error"])
+def test_fetch_error_while_a_final_chunk_is_in_flight(model, fault):
+    """The mixed dispatch's fetch raises with the slot already joined: the
+    failover finishes it as the active slot it is — slot and pages released
+    exactly once, nothing emitted — and the engine keeps serving."""
+    eng = _ragged_engine(model, 1)
+    live = _live(eng)
+    req, slot = _admit_in_flight(eng, prompt_ids=list(_SHORT), max_tokens=9,
+                                 temperature=0.0, ignore_eos=True,
+                                 stream=True)
+    n_live = len(live.generated)
+    _chaos.get().inject(fault, after=0, times=1)
+    with pytest.raises(_chaos.InjectedFault):
+        eng.step()
+    eng._fail_all("injected")           # what run_forever does with it
+    assert req.finish_reason == live.finish_reason == "error"
+    assert req.generated == [] and _queue_items(req) == ([], 1)
+    assert len(live.generated) == n_live, "the failed fetch emitted"
+    assert eng._inflight is None and eng._chunk is None
+    _assert_released(eng, 2)
+    ok = eng.submit(Request(prompt_ids=[2, 4, 6], max_tokens=6,
+                            temperature=0.0, ignore_eos=True))
+    _drain(eng)
+    assert ok.finish_reason == "length" and len(ok.generated) == 6
+    _assert_released(eng, 3)
+
+
+@pytest.mark.ragged_smoke
+def test_preempted_before_its_first_token_was_seen(model):
+    """The pool runs dry and the newest request — the one whose final chunk
+    is still in flight — is preempted: the carry generation moves, the
+    pipeline drains, the unseen first token is discarded, and the request
+    comes back as the fresh admission it still is (nothing of it was
+    emitted), sampling that token again under the same key: its stream is
+    the unpreempted one."""
+    spec = dict(prompt_ids=list(_SHORT), max_tokens=9, temperature=0.9,
+                seed=7, ignore_eos=True, stream=True)
+
+    def run(preempt):
+        eng = _ragged_engine(model, 1)
+        live = _live(eng)
+        req, slot = _admit_in_flight(eng, **spec)
+        if preempt:
+            drains = _edge_drains()
+            eng._preempt(slot)
+            assert req.id not in eng._resume_ctx
+            eng.step()
+            assert _edge_drains() == drains + 1 and req.generated == []
+        _drain(eng)
+        _assert_released(eng, 3 if preempt else 2)
+        return eng, live, req
+
+    eng, live, req = run(True)
+    _, live0, req0 = run(False)
+    assert int(eng.metrics.preemptions.total()) == 1
+    assert _stream_bytes(req) == _stream_bytes(req0)
+    assert _stream_bytes(live) == _stream_bytes(live0)
+    items, nones = _queue_items(req)
+    assert nones == 1 and [t for it in items for t in it] == req.generated
+    assert eng.metrics.ttft._total == 2
+
+
+def _settling_penalised(model, eng):
+    return eng.submit(Request(prompt_ids=list(_SHORT), max_tokens=9,
+                              temperature=0.7, seed=5, ignore_eos=True,
+                              presence_penalty=0.5, frequency_penalty=0.3,
+                              repetition_penalty=1.15))
+
+
+def _settling_guided(model, eng):
+    tok = model[0]
+    g = grammar_for(tok, {"type": "json_object"}, [tok.eos_token_id])
+    return eng.generate(tok.encode("json:"), guided=g, max_tokens=40,
+                        temperature=0.0, logit_bias=_PRESSURE)
+
+
+def _settling_prompt_logprobs(model, eng):
+    # (never a walk: the chunk programs return no prompt logprobs, so the
+    # admission settles the pipeline and prefills whole, Engine._admit_round)
+    return eng.submit(Request(prompt_ids=list(_SHORT), max_tokens=6,
+                              temperature=0.0, ignore_eos=True,
+                              prompt_logprobs=2))
+
+
+def _settling_resumed(model, eng):
+    req = eng.submit(Request(prompt_ids=list(_SHORT), max_tokens=12,
+                             temperature=0.9, seed=7, ignore_eos=True))
+    while len(req.generated) < 3:
+        eng.step()
+    eng._preempt(eng.slot_req.index(req))
+    assert req.id in eng._resume_ctx
+    return req
+
+
+# what tpu_serve_activations_total{path} counts meanwhile: (in_flight, settled)
+_SETTLING = {"penalised": (_settling_penalised, (0, 1)),
+             "guided": (_settling_guided, (0, 1)),
+             "prompt_logprobs": (_settling_prompt_logprobs, (0, 0)),
+             # (its FIRST admission rides in flight; the resume settles)
+             "resumed": (_settling_resumed, (1, 1))}
+
+
+@pytest.mark.ragged_smoke
+@pytest.mark.parametrize("kind", list(_SETTLING))
+def test_final_chunk_settles_where_the_token_is_needed_at_once(model, kind):
+    """A resumed walk, a penalised, a guided and a ``prompt_logprobs``
+    request: the next dispatch cannot be built before the token (or the
+    state its emit leaves) is on the host, so their final chunk settles as
+    it always did — counted as such — and their streams are the legacy
+    engine's."""
+
+    def run(ragged):
+        eng = _ragged_engine(model, ragged)
+        live = _live(eng, n=140)
+        before = _paths(eng)
+        req = _SETTLING[kind][0](model, eng)
+        while not req.finish_reason and not any(r is req
+                                                for r in eng.slot_req):
+            eng.step()      # ... until its walk has ended
+        walked = _paths(eng)
+        assert req.generated, "activated with its token on the host"
+        _drain(eng)
+        _assert_released(eng)
+        return (live, req), tuple(b - a for a, b in zip(before, walked))
+
+    ragged, paths = run(1)
+    legacy, _ = run(0)
+    assert paths == _SETTLING[kind][1], paths
+    for r, s in zip(ragged, legacy):
+        assert _stream_bytes(r) == _stream_bytes(s)
+    assert ragged[1].prompt_logprob_data == legacy[1].prompt_logprob_data
+    assert ragged[1].finish_reason in ("length", "stop")
+
+
+@pytest.mark.ragged_smoke
+def test_back_to_back_admissions_under_a_live_batch_book_no_bubble(model):
+    """Ten admissions one after the other beside a live stream: each ends
+    its walk in flight (``tpu_serve_activations_total{path}``), the device
+    always has the next dispatch behind the one that runs, and the host
+    bubble counter — which every settle used to feed — does not move."""
+    eng = _engine(model, decode_pipeline=1, ragged_attention=1,
+                  prefill_chunk=32, max_cache_len=512, decode_horizon=4)
+    live = _live(eng, n=400)
+    bubble = eng.metrics.decode_bubble_seconds.total()
+    drains = _edge_drains()
+    reqs = []
+    for i in range(10):
+        reqs.append(eng.submit(Request(
+            prompt_ids=[(7 * i + j) % 60 + 4 for j in range(12 + i)],
+            max_tokens=3, temperature=0.9, seed=i, ignore_eos=True)))
+        while not reqs[-1].finish_reason:
+            eng.step()
+            assert eng._inflight is not None
+    assert not live.finish_reason, "the batch went idle (vacuous)"
+    assert _paths(eng) == (10, 0)
+    assert eng.metrics.decode_bubble_seconds.total() == bubble
+    assert _edge_drains() == drains
+    assert all(len(r.generated) == 3 for r in reqs)
+    # a penalised request is the other path, and its settle is a bubble
+    pen = _settling_penalised(model, eng)
+    while not pen.finish_reason:
+        eng.step()
+    assert _paths(eng) == (10, 1)
+    assert eng.metrics.decode_bubble_seconds.total() > bubble
+    eng.cancel(live)
+    _drain(eng)
+    _assert_released(eng)
+
+
+@pytest.mark.ragged_smoke
+def test_empty_slots_carry_lanes_do_not_grow_under_an_open_pipeline(model):
+    """The step programs run every slot, and a lane of the device carry
+    grows a step a token whoever holds the slot. The mirrors' upload after
+    each activation used to zero the empty slots' lanes; now that
+    admissions join from the carry the pipeline stays open for good, so
+    the carry itself is told which slots are empty where it is consumed:
+    an empty slot's lane never reads more than one dispatch's steps, while
+    a stream decodes for hundreds of tokens and requests come and go."""
+    import numpy as np
+
+    tok, _, _ = model
+    cfg = tiny_qwen3(vocab_size=tok.vocab_size,
+                     eos_token_id=tok.eos_token_id, max_seq_len=1024)
+    eng = Engine(cfg, model[2], ServingConfig(
+        weights_dtype="bf16", model=MODEL, max_decode_slots=6,
+        max_cache_len=1024, page_size=32, prefill_buckets=(16, 32),
+        dtype="float32", derived_seed=0, decode_pipeline=1,
+        ragged_attention=1, prefill_chunk=32, decode_horizon=4))
+    live = _live(eng, n=700)
+    worst, was_empty, uploads = 0, set(), 0
+    for step in range(160):
+        if step % 5 == 0:
+            eng.submit(Request(prompt_ids=list(_SHORT), max_tokens=6,
+                               temperature=0.9, seed=step, ignore_eos=True))
+        eng.step()
+        uploads += not eng._carry_valid()
+        if eng._carry_valid():
+            lens = np.asarray(eng._pipe_carry[1])
+            empty = {s for s, r in enumerate(eng.slot_req) if r is None}
+            # (a slot emptied by THIS step's fetch is zeroed at the next
+            # build, and one mid-walk holds its chunk frontier)
+            seen = empty & was_empty - {(eng._chunk or {}).get("slot")}
+            worst = max([worst] + [int(lens[s]) for s in seen])
+            was_empty = empty
+    assert not live.finish_reason and len(live.generated) > 300
+    assert _paths(eng)[0] >= 20 and uploads == 0, \
+        "the pipeline closed between admissions (test is vacuous)"
+    assert worst <= eng.serving.decode_horizon, worst
+    eng.cancel(live)
+    _drain(eng)
+    _assert_released(eng)
+
+
+def test_activations_counter_renders_on_metrics(model):
+    eng = _ragged_engine(model, 1)
+    _live(eng)
+    eng.submit(Request(**_UNDER_LIVE["greedy"]))
+    _drain(eng)
+    text = "\n".join(eng.metrics.registry.render().splitlines())
+    assert 'tpu_serve_activations_total{path="in_flight"} 1.0' in text
 
 
 # -- metrics and observability ----------------------------------------------

@@ -159,6 +159,41 @@ def test_spec_stream_on_ragged_pipeline_matches_hf_generate():
         "drafter never proposed (test is vacuous)"
 
 
+@pytest.mark.parametrize("n_prompt", [13, 45])
+def test_stream_admitted_under_a_live_batch_matches_hf_generate(n_prompt):
+    """A request admitted beside a live stream walks ``mixed_step`` (one
+    chunk of 32, or two) and its FINAL chunk stays in flight: the slot
+    joins the batch from the device carry and the first token goes out a
+    dispatch later. The greedy stream is torch's, first token included —
+    and so is the neighbour's, which decoded through every one of those
+    dispatches."""
+    cfg = tiny_qwen3()
+    model = _hf_qwen3(cfg)
+    params = convert_state_dict(cfg, dict(model.state_dict()),
+                                dtype=jnp.float32)
+    rng = np.random.default_rng(17)
+    live_prompt = rng.integers(2, cfg.vocab_size, 9).tolist()
+    prompt = rng.integers(2, cfg.vocab_size, n_prompt).tolist()
+    eng = Engine(cfg, params, ServingConfig(
+        weights_dtype="bf16", max_decode_slots=2, max_cache_len=128,
+        prefill_buckets=(16, 32), dtype="float32", prefix_cache=False,
+        decode_horizon=4, prefill_chunk=32, **_RAGGED_FEATS))
+    live = eng.submit(Request(prompt_ids=list(live_prompt), max_tokens=60,
+                              ignore_eos=True))
+    for _ in range(5):
+        eng.step()
+    assert eng._inflight is not None    # or nothing rides mixed_step
+    late = eng.submit(Request(prompt_ids=list(prompt), max_tokens=N_NEW,
+                              ignore_eos=True))
+    for _ in range(10000):
+        if not eng.step():
+            break
+    assert eng.metrics.activations.value(path="in_flight") == 1
+    assert late.generated == _hf_greedy(model, prompt, N_NEW), \
+        "stream admitted through an in-flight final chunk diverged from HF"
+    assert live.generated == _hf_greedy(model, live_prompt, 60)
+
+
 def test_zero_b_lora_stream_on_ragged_pipeline_matches_hf_generate(tmp_path):
     """A zero-B adapter is algebraically a no-op: the tuned row — packed
     into the mixed dispatch via the per-row adapter-index operand, beside a

@@ -477,18 +477,23 @@ def _drain(eng):
 
 def _streams(eng):
     """A 90-token prompt (three chunks of 32, the later ones from a carried
-    tail) arrives under a live stream; two short prompts queue together (a
-    packed batch); then a fifth request takes a slot another left."""
-    a = eng.submit(Request(prompt_ids=_ids(20, 3), max_tokens=40,
-                           ignore_eos=True, logprobs=0))
+    tail) arrives under a live stream, and two short prompts behind it: each
+    a one-chunk walk whose dispatch stays in flight, as the 90-token
+    prompt's last does (the conv tail of a slot that joins from the device
+    carry); two more queue together on the idle engine (a packed batch);
+    then a request takes a slot another left."""
+    def submit(n, s, max_tokens=12):
+        return eng.submit(Request(prompt_ids=_ids(n, s), ignore_eos=True,
+                                  max_tokens=max_tokens, logprobs=0))
+
+    a = submit(20, 3, 40)
     for _ in range(3):
         eng.step()
-    reqs = [a] + [eng.submit(Request(prompt_ids=_ids(n, s), max_tokens=12,
-                                     ignore_eos=True, logprobs=0))
-                  for n, s in ((90, 4), (9, 5), (11, 6))]
+    reqs = [a] + [submit(n, s) for n, s in ((90, 4), (9, 5), (11, 6))]
     _drain(eng)
-    reqs.append(eng.submit(Request(prompt_ids=_ids(13, 7), max_tokens=6,
-                                   ignore_eos=True, logprobs=0)))
+    reqs += [submit(n, s) for n, s in ((10, 8), (12, 9))]
+    _drain(eng)
+    reqs.append(submit(13, 7, 6))
     _drain(eng)
     return reqs
 
@@ -523,12 +528,15 @@ def served(request):
 
 def test_prefill_then_decode_through_the_cache_is_the_references(served):
     """prefill_step, prefill_batch_step, three chunks of mixed_step beside
-    a live row, decode steps, a reused slot: every stream is the
+    a live row and two one-chunk walks behind them (every final chunk left
+    in flight), decode steps, a reused slot: every stream is the
     reference's full pass — with 64-wide heads two a pool row too (the
     "wide" cases; with ``pallas`` through the paged kernels)."""
     cfg, params, eng, reqs, seen = served
-    assert [r["chunk_n"] for r in seen if r["program"] == "mixed_step"] \
-        == [32, 32, 26]
+    mixed = [r for r in seen if r["program"] == "mixed_step"]
+    assert [r["chunk_n"] for r in mixed] == [32, 32, 26, 9, 11]
+    assert [r.get("activation") for r in mixed] \
+        == [None, None] + ["in_flight"] * 3
     assert "prefill_batch_step" in {r["program"] for r in seen}
     for r in reqs:
         rows, ref_lp = _ref_logprobs(cfg, params, r)
