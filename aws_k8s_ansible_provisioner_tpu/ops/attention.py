@@ -182,9 +182,10 @@ def _length_order(lengths: jnp.ndarray, table: jnp.ndarray, dp: int,
     order cannot matter: blocks of one row, or one block (static facts).
 
     Why: a grid step of decode_attend_pallas_paged serves ``bblock`` rows
-    and walks the pages of its LONGEST one, every shorter row re-copying its
-    last page and running a masked flash update to the end
-    (pallas_attention._paged_db_body). In slot order a block's rows are
+    and walks the pages of its LONGEST one, every shorter row running a
+    masked flash update to the end (since PR 45 without a copy: a row past
+    its own pages fetches nothing; pallas_attention._paged_db_body). In slot
+    order a block's rows are
     strangers — a slot's context is wherever its request stands — and three
     tenths of the pages walked lie beyond some row's end (PERF.md, PR 33).
     Cut from the sorted order a block's rows are neighbours. A row's flash

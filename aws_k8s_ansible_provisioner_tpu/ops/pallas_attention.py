@@ -13,10 +13,12 @@ Raggedness (every row at a different length) is handled two ways:
 - *page skipping*: the in-kernel page loop runs over a block's live page
   range only, so a slot at length 130 reads 3 pages of 64, not the window.
 
-WHO ORDERS THE ROWS: a block of ``bblock`` rows costs ``bblock`` x the pages
-of its LONGEST row (the shorter ones re-copy their last page and run a masked
-update to the end of the walk), so how rows are grouped into blocks decides
-how much of the walk is live. The kernels take the rows as given; the decode
+WHO ORDERS THE ROWS: a block of ``bblock`` rows walks the pages of its
+LONGEST row with a masked update for every row; a row COPIES only the pages
+it holds itself (past its own range it starts no copy), so the bytes follow
+the rows and the page steps follow the blocks: how rows are grouped decides
+how many of a walk's updates have a page to fold. The kernels take the rows
+as given; the decode
 program sorts them by length around the call (ops/attention._length_order,
 in plain XLA ops: two small sorts a substep, two row gathers a layer) and
 un-permutes the context. Not in here: the order is the same for
@@ -134,9 +136,18 @@ def _paged_db_body(lengths_ref, layer_ref, table_ref, share_ref, wide_ref,
     raggedness inside a block rides the column mask
     (shorter rows' dead columns contribute exp(-1e30 - m) == 0 exactly once
     any live column has been seen — bit-identical to the skip-based
-    single-slot accumulation); the per-row page index clamps into the
-    row's OWN live range so a mixed block never fetches a neighbor's
-    garbage table entries.
+    single-slot accumulation). A row OUTSIDE its own live range
+    [lo[i], hi[i]] at page step c — past its last page, below its window,
+    or dead — STARTS NO COPY and waits for none (``fetches``: one predicate
+    of the lengths, the same at start and wait time): a mixed block never
+    fetches a neighbor's garbage table entries, and the bytes a row does
+    not hold are time the step does not take. Its masked update still runs
+    on what its buffer slot holds — its own earlier page, or zeros: the V
+    (and V-scale) slots that no copy of the block fills before their first
+    read are zeroed where the block starts (``zero_unfilled``), because
+    0 x NaN in P.V is NaN and nothing may lean on what VMEM held before the
+    call. The selecting entries keep the older rule (every row of a live
+    block copies, its page index clamped into its own range).
 
     A row with NO live column (limit 0: an idle slot, a padding row of a
     prefill chunk) is DEAD: it has no live page, widens no block's range,
@@ -177,7 +188,7 @@ def _paged_db_body(lengths_ref, layer_ref, table_ref, share_ref, wide_ref,
       its list names at c (one DMA a head: the heads of a row read
       different pages) — so a row past the dense length costs ``topk``
       page steps whatever its context; a list shorter than the block's
-      longest re-copies its last page under a mask, like a shorter row.
+      longest re-copies its last page under a mask (the older rule).
     - ``bits_ref`` (the ragged entry): a BITMASK over logical pages. The
       walk is the plain one over [lo_min, hi_max]; at page c a row's limit
       for a KV head is its own where the bit is set and 0 where it is not,
@@ -226,52 +237,65 @@ def _paged_db_body(lengths_ref, layer_ref, table_ref, share_ref, wide_ref,
             jnp.minimum, [jnp.where(a, x, num_pages)
                           for a, x in zip(alive, lo)])
 
-    def walk(lo_min, hi_max, pages, update):
+    def walk(lo_min, hi_max, pages, update, fetches=None):
         """The double-buffered walk over [lo_min, hi_max]: ``pages(c)`` names
         page c's copies, ``update(c, buf)`` folds buffer ``buf`` into the
-        flash state."""
+        flash state. ``fetches(i, c)`` (the per-row path): does buffer row i
+        copy page c at all — a row outside its own range starts nothing and
+        waits for nothing; None: every named copy runs."""
 
-        def copies(c):
+        def copies(c, act):
             # created identically at start and wait time — the documented
-            # make_async_copy pattern
+            # make_async_copy pattern —, and a row's under ONE predicate of
+            # the same scalars both times: a wait nobody signals hangs
             slot = c % 2
-            out = []
             for i, pg in pages(c):
                 if sel_ref is not None:     # (row, KV head): one head's rows
                     i, h = i
-                    out.append(pltpu.make_async_copy(
+                    act(pltpu.make_async_copy(
                         k_hbm.at[lay, pg, h], k_buf.at[slot, i, h],
                         sem.at[slot, i, 2 * h]))
-                    out.append(pltpu.make_async_copy(
+                    act(pltpu.make_async_copy(
                         v_hbm.at[lay, pg, h], v_buf.at[slot, i, h],
                         sem.at[slot, i, 2 * h + 1]))
                     continue
-                out.append(pltpu.make_async_copy(
-                    k_hbm.at[lay, pg], k_buf.at[slot, i], sem.at[slot, i, 0]))
-                out.append(pltpu.make_async_copy(
-                    v_hbm.at[lay, pg], v_buf.at[slot, i], sem.at[slot, i, 1]))
+                row = [pltpu.make_async_copy(
+                    k_hbm.at[lay, pg], k_buf.at[slot, i], sem.at[slot, i, 0]),
+                    pltpu.make_async_copy(
+                    v_hbm.at[lay, pg], v_buf.at[slot, i], sem.at[slot, i, 1])]
                 if quant:
-                    out.append(pltpu.make_async_copy(
+                    row += [pltpu.make_async_copy(
                         ks_hbm.at[lay, pg], ks_buf.at[slot, i],
-                        sem.at[slot, i, 2]))
-                    out.append(pltpu.make_async_copy(
+                        sem.at[slot, i, 2]),
+                        pltpu.make_async_copy(
                         vs_hbm.at[lay, pg], vs_buf.at[slot, i],
-                        sem.at[slot, i, 3]))
-            return out
+                        sem.at[slot, i, 3])]
+
+                def all_of(row=row):
+                    for dma in row:
+                        act(dma)
+
+                if fetches is None:
+                    all_of()
+                else:
+                    pl.when(fetches(i, c))(all_of)
+
+        def start(dma):
+            dma.start()
+
+        def wait(dma):
+            dma.wait()
 
         @pl.when(lo_min <= hi_max)
         def _prologue():                   # first page in flight
-            for dma in copies(lo_min):
-                dma.start()
+            copies(lo_min, start)
 
         def step(c, carry):
             @pl.when(c < hi_max)
             def _prefetch():               # fetch page c+1 while computing c
-                for dma in copies(c + 1):
-                    dma.start()
+                copies(c + 1, start)
 
-            for dma in copies(c):
-                dma.wait()
+            copies(c, wait)
             update(c, c % 2)
             return carry
 
@@ -388,17 +412,52 @@ def _paged_db_body(lengths_ref, layer_ref, table_ref, share_ref, wide_ref,
                      >> (c % 32)) & 1 for i, h in pairs]
         lo, lo_min = first_pages(lens, alive)
 
+        # the selecting ragged entry (the bits form) keeps every row of a
+        # live block copying, clamped: past the dense length there is
+        # nothing to skip, and its program stays as it was
+        skips = sel_ref is None and bits_ref is None
+
+        def fetches(i, c):
+            """Does row i copy page c: only a live row, inside its OWN
+            range. Read off the lengths, like the ranges themselves."""
+            return alive[i] & (lo[i] <= c) & (c <= hi[i])
+
         def row_pages(c):
-            """(buffer row, physical page) of every row's page-c copy. A
-            row clamps into its own live range: table entries past it may
-            be anything valid (scratch, stale) — never fetch them. A dead
-            row in a live block rides along on physical page 0 (always in
-            the pool)."""
+            """(buffer row, physical page) of every row's page-c copy.
+            Table entries past a row's live range may be anything valid
+            (scratch, stale) and those below its window released pages:
+            never fetch them. Where rows skip (``fetches``) an entry
+            outside the range is read and not used; elsewhere a row clamps
+            into its range and a dead row in a live block rides along on
+            physical page 0 (always in the pool)."""
+            if skips:
+                return [(i, table_ref[trow(blk * bb + i) + c])
+                        for i in range(bb)]
             return [(i, jnp.where(
                 alive[i],
                 table_ref[trow(blk * bb + i)
                           + jnp.clip(c, lo[i], jnp.maximum(hi[i], 0))], 0))
                     for i in range(bb)]
+
+        def zero_unfilled():
+            """A row that copies nothing at one of the walk's first two
+            steps (a one-page row's second buffer slot; a dead row; a
+            window row above the block's first page) would leave that
+            slot of its V buffer as the call found it, and the masked
+            update still multiplies it: p is 0 there — or 1 before the
+            row's first live column, which ``corr`` = 0 wipes later — and
+            0 x NaN is NaN (K is behind the select on ``live_col``). Zero
+            those slots; every later read finds the row's own copy or
+            this."""
+            for c in (lo_min, lo_min + 1):
+                for i in range(bb):
+                    @pl.when((c <= hi_max) & ~fetches(i, c))
+                    def _zero(c=c, i=i):
+                        v_buf[c % 2, i] = jnp.zeros(v_buf.shape[2:],
+                                                    v_buf.dtype)
+                        if quant:
+                            vs_buf[c % 2, i] = jnp.zeros(vs_buf.shape[2:],
+                                                         vs_buf.dtype)
 
         def listed_pages(c):
             """row_pages for the list form: ((row, KV head), physical
@@ -460,8 +519,11 @@ def _paged_db_body(lengths_ref, layer_ref, table_ref, share_ref, wide_ref,
                           acc_ref, sl, pv_of)
 
             reset(acc_ref, m_ref, l_ref)
+            if skips:
+                zero_unfilled()
             walk(lo_min, hi_max,
-                 row_pages if sel_ref is None else listed_pages, update)
+                 row_pages if sel_ref is None else listed_pages, update,
+                 fetches if skips else None)
             return acc_ref[:] / jnp.maximum(l_ref[:, :, :1], 1e-9)
 
         def shared(row):
@@ -784,13 +846,14 @@ def decode_attend_pallas_paged(q: jnp.ndarray, pool_k: jnp.ndarray,
     q: [B, 1, Hq, D]; pool_k/v: [L, P, Hkv, page, D]; lengths: [B] (counting
     the just-written token); layer: scalar int32; table: [B, max_pages] int32
     physical page ids (row b maps slot b's logical pages; entries at or past
-    the slot's live range may be any valid id — they are clamped away, never
-    fetched). Returns [B, 1, Hq, D]. pool_ks/vs switch the int8 scale-folding
+    the slot's live range may be any valid id — no copy of them starts).
+    Returns [B, 1, Hq, D]. pool_ks/vs switch the int8 scale-folding
     body. ``bblock`` slots share each grid step
     (resolved to the largest divisor of B); page i+1 prefetches while page i
     computes regardless of bblock — see _paged_db_body. Rows are served in
     the order given, ``bblock`` consecutive rows a grid step, and a step
-    walks the pages of its longest row for all of them: the caller that
+    walks the pages of its longest row with an update for all of them (a
+    row copies its own pages only): the caller that
     wants a full walk hands neighbours in length (the decode program does:
     ops/attention.make_decode_attend_carry_paged sorts rows, lengths and
     table alike and un-permutes the result; a row's output does not depend
@@ -855,7 +918,7 @@ def ragged_attend_pallas_paged(q: jnp.ndarray, pool_k: jnp.ndarray,
 
     q: [N, Hq, D] packed query rows; row_limits: [N] live columns per row;
     row_tables: [N, max_pages] int32 (entries at or past a row's live range
-    may be any valid id — clamped away, never fetched); layer: scalar.
+    may be any valid id — no copy of them starts); layer: scalar.
     Returns [N, Hq, D]. pool_ks/vs switch the int8 scale-folding body;
     ``window`` > 0 applies per-row sliding-window masking off each row's own
     limit. ``bblock`` (resolved to the largest divisor of N) is the width

@@ -294,20 +294,24 @@ class EngineMetrics:
             "held on this chip (an expert share routes over more experts "
             "than it holds), per layer, by step program", ("program",)))
         # live / walked is the fill of the decode kernel's page walk: a grid
-        # step walks the pages of its block's longest row for every row
+        # step walks the pages of its block's longest row for every row, and
+        # a row copies only the pages it holds (copied == live)
         self.decode_attn_pages = r.register(Counter(
             "tpu_serve_decode_attn_pages_total",
             "Pages of the plain decode dispatches' attention, per attending "
             "layer, summed over substeps: kind=\"live\" the pages the rows "
             "hold, kind=\"walked\" the pages their blocks walk (block rows "
-            "x the block's longest row, blocks cut in order of length)",
+            "x the block's longest row, blocks cut in order of length), "
+            "kind=\"copied\" the pages the kernel's copies fetch (a row "
+            "past its own pages starts no copy)",
             ("kind",)))
         self.window_attn_pages = r.register(Counter(
             "tpu_serve_window_attn_pages_total",
             "tpu_serve_decode_attn_pages_total for the WINDOW layers of a "
             "list that also holds full ones (which that family then "
             "counts), per window layer: the pages inside the rows' "
-            "windows, and those their blocks walk", ("kind",)))
+            "windows, those their blocks walk and those the copies fetch",
+            ("kind",)))
         self.ragged_page_steps = r.register(Counter(
             "tpu_serve_ragged_page_steps_total",
             "Page steps (one page fetched and folded into a flash state) "

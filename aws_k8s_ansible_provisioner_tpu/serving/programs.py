@@ -1635,10 +1635,16 @@ class EnginePrograms:
         dp shard's rows in one ascending order (ops/attention._length_order;
         where the device takes no order, blocks of one row or one block,
         the sum is the same in any order). Every slot counts: the kernel
-        walks an idle slot's row too. Their ratio is the walk's fill. A
-        list with window layers beside full ones: those two are the FULL
-        layers' and ``win_pages_live`` / ``win_pages_walked`` the window
-        layers' (per window layer), beside ``attn_layers_full`` /
+        walks an idle slot's row too. Their ratio is the walk's fill: the
+        share of the blocks' masked updates that has a page to fold.
+        ``attn_pages_copied`` is what the kernel's copies FETCH: a row
+        starts a copy at a page step of its block's walk only inside its
+        own range (pallas_attention._paged_db_body, ``fetches``), so it
+        equals live — it was walked while a row past its pages re-copied
+        its last one. A list with window layers beside full ones: those
+        three are the FULL layers' and ``win_pages_live`` /
+        ``win_pages_walked`` / ``win_pages_copied`` the window layers'
+        (per window layer), beside ``attn_layers_full`` /
         ``attn_layers_window``."""
         from aws_k8s_ansible_provisioner_tpu.ops.pallas_attention import (
             _resolve_bb)
@@ -1656,16 +1662,21 @@ class EnginePrograms:
         def pages(window: int):
             lo = np.maximum(limits - window, 0) // ps if window > 0 \
                 else np.zeros_like(hi)
-            walked = bb * (hi.reshape(blocks)[..., -1]
-                           - lo.reshape(blocks)[..., 0])
-            return int((hi - lo).sum()), int(walked.sum())
+            first = lo.reshape(blocks)[..., :1]
+            last = hi.reshape(blocks)[..., -1:]
+            # a row's copies: the steps of its block's walk inside its range
+            copied = (np.minimum(hi.reshape(blocks), last)
+                      - np.maximum(lo.reshape(blocks), first))
+            return (int((hi - lo).sum()), int(bb * (last - first).sum()),
+                    int(copied.sum()))
 
-        live, walked = pages(self.cfg.attn_window)
-        out = {"attn_pages_live": live, "attn_pages_walked": walked}
+        live, walked, copied = pages(self.cfg.attn_window)
+        out = {"attn_pages_live": live, "attn_pages_walked": walked,
+               "attn_pages_copied": copied}
         if self.cfg.windowed:
-            live, walked = pages(self.cfg.sliding_window)
+            live, walked, copied = pages(self.cfg.sliding_window)
             out.update(win_pages_live=live, win_pages_walked=walked,
-                       **self._attn_layers())
+                       win_pages_copied=copied, **self._attn_layers())
         return out
 
     def _attn_layers(self) -> dict:
@@ -1774,8 +1785,8 @@ class EnginePrograms:
         routed), ``moe_experts_hit`` (experts with a live row, mean over
         layers and substeps) and ``moe_group_max`` (rows of the largest
         group), the last two from the program's own output. A plain
-        decode dispatch also carries ``attn_pages_live`` and
-        ``attn_pages_walked`` (``_attn_pages``)."""
+        decode dispatch also carries ``attn_pages_live``,
+        ``attn_pages_walked`` and ``attn_pages_copied`` (``_attn_pages``)."""
         n = len(active)
         lens = self.lengths[list(active)] if n else None
         return {"seq": next(_DISPATCH_SEQ), "program": program, "kind": kind,
@@ -1858,11 +1869,15 @@ class EnginePrograms:
                                                kind="live")
             self.metrics.decode_attn_pages.inc(rec["attn_pages_walked"],
                                                kind="walked")
+            self.metrics.decode_attn_pages.inc(rec["attn_pages_copied"],
+                                               kind="copied")
         if "win_pages_live" in rec:
             self.metrics.window_attn_pages.inc(rec["win_pages_live"],
                                                kind="live")
             self.metrics.window_attn_pages.inc(rec["win_pages_walked"],
                                                kind="walked")
+            self.metrics.window_attn_pages.inc(rec["win_pages_copied"],
+                                               kind="copied")
         if "chunk_page_steps" in rec:
             full = rec.get("attn_layers_full", self.cfg.num_attn_layers)
             win = rec.get("attn_layers_window", 0)

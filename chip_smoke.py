@@ -637,12 +637,14 @@ def check_numerics(name: str, stream: dict, cfg, params, tokenizer,
 
 
 def kernel_parity(cfg, slots: int, window: int, page: int, bblocks,
-                  interpret: bool) -> None:
+                  interpret: bool, parent=None) -> None:
     """decode / ragged / write kernels at the served widths against the
     repo's jax.numpy references (ops/attention.decode_attend over
     kv_pool.gather_layer_dense; kv_pool.write_token_layer_paged), on
     seeded inputs, bf16 and int8 pools. Depth is cut to 2 layers — a layer
-    is an index into the pool here — everything else is the server's."""
+    is an index into the pool here — everything else is the server's.
+    ``parent``: another checkout's ``ops.pallas_attention`` (``--parent``),
+    whose outputs the copy-skipping kernels' must equal bit for bit."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -800,6 +802,8 @@ def kernel_parity(cfg, slots: int, window: int, page: int, bblocks,
             f"bb={bb}, {Hkv} KV heads: bitwise the slot-order call, max abs "
             f"err {e3:.2e} (tol {KERNEL_TOL})")
         del wrote, wkw, slot_order
+        copy_skip_parity(pa, parent, pool, skw, table, layer, page, window,
+                         (q, q3), limits, rtab, bblocks, tag, interpret)
         if not interpret:
             ragged_call_time(pa, pool, skw, table, lengths, layer, Hq, D,
                              window, max(bblocks), tag)
@@ -856,6 +860,116 @@ def kernel_parity(cfg, slots: int, window: int, page: int, bblocks,
             chunk_write_time(kvp, pool, table, layer, page, window, tag)
         del got
         del pool, want, gk
+
+
+def load_kernels(root: str):
+    """``ops/pallas_attention.py`` of the checkout at ``root`` as a module
+    of its own, beside this checkout's."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "parent_pallas_attention", os.path.join(
+            root, "aws_k8s_ansible_provisioner_tpu", "ops",
+            "pallas_attention.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def poison_vmem(interpret: bool) -> None:
+    """A Pallas call that leaves NaN in 12 of the 16 MiB of VMEM a kernel
+    gets (every 16 bits 0x7FC0: NaN read as bf16 or as float32), so that
+    the call after it does not pass by what its page buffers happened to
+    hold."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    rows, step = 12 * 1024 * 1024 // (128 * 2), 1024
+
+    def kernel(o_ref, scratch):
+        def fill(i, carry):
+            scratch[pl.ds(pl.multiple_of(i * step, step), step)] = \
+                jnp.full((step, 128), jnp.nan, scratch.dtype)
+            return carry
+
+        jax.lax.fori_loop(0, rows // step, fill, 0)
+        o_ref[:] = scratch[rows - 16:]
+
+    out = pl.pallas_call(
+        kernel, out_shape=jax.ShapeDtypeStruct((16, 128), jnp.bfloat16),
+        scratch_shapes=[pltpu.VMEM((rows, 128), jnp.bfloat16)],
+        interpret=interpret)()
+    check(np.isnan(np.asarray(out, np.float32)).all(),
+          "the poisoning call left no NaN")
+
+
+def copy_skip_parity(pa, parent, pool, skw, table, layer, page, window,
+                     queries, limits, rtab, bblocks, tag, interpret) -> None:
+    """A row past its own pages starts no copy (PR 45): the decode entry and
+    the ragged entry's decode-row tile, each call made right after
+    :func:`poison_vmem`, BITWISE against the same rows in blocks of ONE (a
+    row alone walks its own range: the copies the parent made) and, where
+    ``parent`` is given, against the parent's kernel in the same blocks.
+    Every block of 8 holds a full window beside a one-page row, a row that
+    ends on a page edge and a DEAD row; under the window (4 pages) the long
+    rows' first live page lies above their block's."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    q, q3 = queries
+    B = table.shape[0]
+    base = [window, 1, page, 0, 2 * page - 1, 300, 1100, 7]
+    lens = jnp.asarray([min(base[i % len(base)], window) for i in range(B)],
+                       jnp.int32)
+    rlim = jnp.concatenate([jnp.where(limits[:B] > 0, lens, 0), limits[B:]])
+
+    def same(name, got, want, rows=slice(None)):
+        got, want = (np.asarray(a, np.float32)[rows] for a in (got, want))
+        check(np.isfinite(got).all(), f"{name}: non-finite output (a page "
+                                      f"buffer nothing filled was read)")
+        check(np.array_equal(got, want),
+              f"{name}: {int((got != want).sum())} elements differ, max "
+              f"{np.abs(got - want).max():.3e}")
+
+    for win in (0, 4 * page):
+        def decode(mod, bb):
+            return mod.decode_attend_pallas_paged(
+                q, pool["k"], pool["v"], lens, layer, table,
+                interpret=interpret, window=win, bblock=bb, **skw)
+
+        def ragged(mod, bb):
+            return mod.ragged_attend_pallas_paged(
+                q3, pool["k"], pool["v"], rlim, layer, rtab,
+                interpret=interpret, window=win, bblock=bb, **skw)
+
+        alone = decode(pa, 1), ragged(pa, 1)
+        for bb in (b for b in bblocks if b > 1):
+            name = f"copy skip {tag} bb={bb} window={win}"
+            poison_vmem(interpret)
+            out = decode(pa, bb)
+            same(f"{name}: decode entry against blocks of one row", out,
+                 alone[0])
+            check(not np.asarray(out, np.float32)[np.asarray(lens) == 0]
+                  .any(), f"{name}: a dead row of the decode entry is not "
+                          f"zero")
+            poison_vmem(interpret)
+            rout = ragged(pa, bb)
+            # (the chunk rows of a sharing block take another arithmetic
+            # than rows alone: the decode rows only)
+            same(f"{name}: ragged entry's decode rows against blocks of one "
+                 f"row", rout, alone[1], slice(0, B))
+            if parent is not None:
+                same(f"{name}: decode entry against the parent's kernel",
+                     out, decode(parent, bb))
+                same(f"{name}: ragged entry against the parent's kernel",
+                     rout, ragged(parent, bb))
+            say(f"parity: {name}: decode entry and the ragged entry's decode "
+                f"rows after a NaN-filled VMEM, bitwise blocks of one row"
+                + ("" if parent is None else " and the parent's kernel")
+                + f" ({int((np.asarray(lens) == 0).sum())} dead rows zero)")
 
 
 def chunk_write_time(kvp, pool, table, layer, page, chunk, tag) -> None:
@@ -1908,6 +2022,10 @@ def main() -> int:
     ap.add_argument("--rehearse", action="store_true",
                     help="builder's CPU rehearsal: tiny model, no TPU "
                          "required, never prints an ok line")
+    ap.add_argument("--parent", default="",
+                    help="a checkout of the parent commit: kernel_parity "
+                         "also holds the paged kernels bitwise to its "
+                         "ops/pallas_attention.py")
     opts = ap.parse_args()
     # the server's own log lines (scheduler pick, compile cache, devmon's
     # device kind and peaks, drain) go to stderr, as under main()
@@ -2173,6 +2291,7 @@ def main() -> int:
         # (groups = 1, 16 KV heads: OLMoE's), which gives a decode block of 8
         # slots 8 query rows a KV head where the 0.6B gives 16
         mha = _config.MODEL_REGISTRY["allenai/OLMoE-1B-7B-0125-Instruct"]
+        parent = load_kernels(opts.parent) if opts.parent else None
         if cfg.windowed:
             # (the plain parity packs a table row a packed ROW: 2.4 MB of
             # SMEM at 48 + 4,096 rows of 144 pages; the default run has it)
@@ -2194,19 +2313,22 @@ def main() -> int:
                                    interpret=False)
         elif opts.rehearse:
             # interpret mode is slow: same code path at a small shape
-            kernel_parity(cfg, 8, 256, 32, sorted({1, 4}), interpret=True)
+            kernel_parity(cfg, 8, 256, 32, sorted({1, 4}), interpret=True,
+                          parent=parent)
             if cfg_file is None:
                 kernel_parity(mha.scaled(num_heads=4, num_kv_heads=4,
                                          head_dim=32), 8, 256, 32, [4],
-                              interpret=True)
+                              interpret=True, parent=parent)
         else:
             # (at the geometry the kernels see: narrow heads lie
             # cfg.kv_lane_pack a pool row)
             kernel_parity(cfg.scaled(
                 head_dim=cfg.pool_head_dim, num_kv_heads=cfg.pool_kv_heads),
-                slots, window, page, sorted({1, bb}), interpret=False)
+                slots, window, page, sorted({1, bb}), interpret=False,
+                parent=parent)
             if cfg_file is None:
-                kernel_parity(mha, 24, window, page, [1, 8], interpret=False)
+                kernel_parity(mha, 24, window, page, [1, 8], interpret=False,
+                              parent=parent)
         if cfg.num_experts > 0:
             expert_forms_parity(cfg, slots)
 

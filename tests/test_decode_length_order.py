@@ -6,8 +6,10 @@ BITWISE: through ``make_decode_attend_carry_paged`` (interpret mode,
 ``impl="pallas"``) the context equals ``decode_attend_pallas_paged`` called on
 the same rows in slot order, the K/V rows land where the slot-order scatter
 puts them, ties keep slot order, and a mesh orders each shard's own rows.
-The host's witness — ``attn_pages_live`` / ``attn_pages_walked`` on the
-dispatch record and /metrics — is cut exactly as the device cuts its blocks.
+The host's witness — ``attn_pages_live`` / ``attn_pages_walked`` /
+``attn_pages_copied`` on the dispatch record and /metrics — is cut exactly
+as the device cuts its blocks; since PR 45 a row copies only the pages it
+holds, so copied == live where it used to be walked.
 """
 
 import jax
@@ -217,18 +219,22 @@ def _engine(slots=8, bblock=4, window=0, **kw):
 
 
 def _walk_by_the_kernels_rule(lens, horizon, ps, num_pages, bb, window):
-    """(live, walked) as pallas_attention._paged_db_body walks: a block of
-    ``bb`` rows, cut from the rows in stable order of length, visits pages
-    [lo_min, hi_max] with a copy and a flash update for EVERY row."""
-    live = walked = 0
+    """(live, walked, copied) as pallas_attention._paged_db_body walks: a
+    block of ``bb`` rows, cut from the rows in stable order of length,
+    visits pages [lo_min, hi_max] with a flash update for EVERY row and a
+    copy for the rows whose own range holds the page (``fetches``)."""
+    live = walked = copied = 0
     for s in range(horizon):
         limits = sorted(int(n) + 1 + s for n in lens)
         hi = [min(-(-n // ps), num_pages) - 1 for n in limits]
         lo = [max(n - window, 0) // ps if window else 0 for n in limits]
         live += sum(h - l + 1 for h, l in zip(hi, lo))
         for b in range(0, len(limits), bb):
-            walked += bb * (max(hi[b:b + bb]) - min(lo[b:b + bb]) + 1)
-    return live, walked
+            steps = range(min(lo[b:b + bb]), max(hi[b:b + bb]) + 1)
+            walked += bb * len(steps)
+            copied += sum(l <= c <= h for c in steps
+                          for h, l in zip(hi[b:b + bb], lo[b:b + bb]))
+    return live, walked, copied
 
 
 @pytest.mark.parametrize("lens,window,bblock", [
@@ -244,18 +250,19 @@ def test_the_page_counters_cut_blocks_as_the_device_does(lens, window,
     eng = _engine(bblock=bblock, window=window)
     eng.lengths[:] = lens
     got = eng._attn_pages(horizon=4, carry_steps=4)
-    live, walked = _walk_by_the_kernels_rule(
+    live, walked, copied = _walk_by_the_kernels_rule(
         [n + 4 for n in lens], 4, 16, eng.pages_per_slot, bblock, window)
-    assert got == {"attn_pages_live": live, "attn_pages_walked": walked}
-    assert live <= walked
+    assert got == {"attn_pages_live": live, "attn_pages_walked": walked,
+                   "attn_pages_copied": copied}
+    assert copied == live <= walked
     if len(set(lens)) == 1 or bblock == 1:
         assert live == walked
 
 
 def test_decode_records_and_metrics_carry_the_page_counters():
     """Requests of unlike length through the engine: every plain decode
-    record carries both counters (live <= walked), no other record does, and
-    /metrics exports their sums by kind."""
+    record carries the three counters (copied == live <= walked), no other
+    record does, and /metrics exports their sums by kind."""
     from aws_k8s_ansible_provisioner_tpu.serving import flightrec
     from aws_k8s_ansible_provisioner_tpu.serving.engine import Request
 
@@ -284,11 +291,13 @@ def test_decode_records_and_metrics_carry_the_page_counters():
     for r in decode:
         assert 8 * r["horizon"] <= r["attn_pages_live"] \
             <= r["attn_pages_walked"]
+        assert r["attn_pages_copied"] == r["attn_pages_live"]
     assert any(r["attn_pages_live"] < r["attn_pages_walked"] for r in decode)
     m = eng.metrics.decode_attn_pages
     assert m.value(kind="live") == sum(r["attn_pages_live"] for r in decode)
     assert m.value(kind="walked") == sum(r["attn_pages_walked"]
                                          for r in decode)
+    assert m.value(kind="copied") == m.value(kind="live")
     text = eng.metrics.registry.render()
-    for kind in ("live", "walked"):
+    for kind in ("live", "walked", "copied"):
         assert f'tpu_serve_decode_attn_pages_total{{kind="{kind}"}}' in text

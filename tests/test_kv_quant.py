@@ -52,11 +52,15 @@ def test_quant_cache_decode_close_to_float():
                                    atol=0.05, rtol=0.05)
 
 
-def test_pallas_quant_attend_matches_xla_dequant():
+@pytest.mark.parametrize("bb", [1, 4])
+def test_pallas_quant_attend_matches_xla_dequant(bb):
     """The int8 Pallas kernel (interpret) == XLA attend over the dequantized
     rows, to float tolerance — the scales fold exactly. The pool is the
     logical cache cut into pages under an identity table, its scale leaves
-    lane-padded as the engine allocates them."""
+    lane-padded as the engine allocates them. ``bb`` 4: one block holds two
+    one-page rows beside two-page ones — past their page they copy neither
+    rows nor scales (PR 45), and the V-scale slot nothing filled is zeroed
+    (interpret mode's scratch starts as NaN; 0 x NaN would reach P.V)."""
     L, B, Hkv, S, D, Hq, PS = 3, 4, 2, 64, 32, 4, 32
     rng = np.random.default_rng(2)
     k = jnp.asarray(rng.normal(0, 1, (L, B, Hkv, S, D)), dtype=jnp.float32)
@@ -78,7 +82,7 @@ def test_pallas_quant_attend_matches_xla_dequant():
         got = pa.decode_attend_pallas_paged(
             q, pages(qk), pages(qv), lengths, jnp.int32(layer), table,
             interpret=True, pool_ks=jnp.pad(pages(ks), pad),
-            pool_vs=jnp.pad(pages(vs), pad))
+            pool_vs=jnp.pad(pages(vs), pad), bblock=bb)
         ref = decode_attend(q, kvc.dequantize(qk[layer], ks[layer]),
                             kvc.dequantize(qv[layer], vs[layer]), lengths)
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),

@@ -815,27 +815,33 @@ def test_aot_plan_sizes_state_and_selector_beside_the_pool():
 # ``any(temperature > 0)`` — the programs' own code did not change
 # (tests/test_sampling.py holds the gated sampler to the ungated one token for
 # token). The four kernels' hashes are PR 34's, byte for byte.
+# PR 45 re-took the six ``pallas`` hashes and the "decode" / "ragged"
+# kernels' at its own tree (parent cb17104): the paged body's per-row walk
+# is its subject — a row's copies start and wait under its own range's
+# predicate and the V slots nothing fills are zeroed; the ``xla`` programs,
+# "write" and "kda" did not move, and the SELECTING entries' jaxprs are the
+# parent's (tests/test_tpu_compile.py pins them).
 PINNED = {
-    ("tiny-olmoe", "decode_steps", "pallas"): "eba4bfb3d8c472ec",
+    ("tiny-olmoe", "decode_steps", "pallas"): "aa98963a5752b0f6",
     ("tiny-olmoe", "decode_steps", "xla"): "24166cb7302bca06",
-    ("tiny-olmoe", "mixed_step", "pallas"): "a6838077583d1ead",
+    ("tiny-olmoe", "mixed_step", "pallas"): "7e5fc3ce848a8a8f",
     ("tiny-olmoe", "mixed_step", "xla"): "00bf52f6e08eeefe",
     ("tiny-olmoe", "prefill_step", "xla"): "fe74d853601263b8",
-    ("tiny-qwen3", "decode_steps", "pallas"): "33c39d047ea043b7",
+    ("tiny-qwen3", "decode_steps", "pallas"): "1e12c12ca574063d",
     ("tiny-qwen3", "decode_steps", "xla"): "979ebf2eee66c834",
-    ("tiny-qwen3", "mixed_step", "pallas"): "81ec57f8e5f829c5",
+    ("tiny-qwen3", "mixed_step", "pallas"): "6ab323ad4a5fdd4e",
     ("tiny-qwen3", "mixed_step", "xla"): "f29dc91fe02da895",
     ("tiny-qwen3", "prefill_step", "xla"): "34d3281612f23ac5",
-    ("tiny-solar", "decode_steps", "pallas"): "d97d3b1a27533e57",
+    ("tiny-solar", "decode_steps", "pallas"): "e46a1ccc1ad8a0bf",
     ("tiny-solar", "decode_steps", "xla"): "fbbaabf7e4d43f6a",
-    ("tiny-solar", "mixed_step", "pallas"): "c19ba5bf863effbb",
+    ("tiny-solar", "mixed_step", "pallas"): "b906c3af38e7fd75",
     ("tiny-solar", "mixed_step", "xla"): "391e3d76844cbfa6",
     ("tiny-solar", "prefill_step", "xla"): "8da44bc738dc28b0",
 }
 # ("ragged" moved with PR 40, whose subject it is: a sharing block keeps its
 # rows on lanes and the share fact is read per block AND per tile; the
-# decode entry, traced by the same body, did not move)
-PINNED_KERNELS = {"decode": "c14c89f8caa0821f", "ragged": "a6213eccc3e6bcc2",
+# decode entry, traced by the same body, did not move; both moved with PR 45)
+PINNED_KERNELS = {"decode": "81c80a049add0383", "ragged": "c5ff36111da9ca40",
                   "write": "5ca71686a40fa563", "kda": "9fb3d56211d04454"}
 MODELS = {"tiny-qwen3": tiny_qwen3, "tiny-olmoe": tiny_olmoe,
           "tiny-solar": tiny_solar}

@@ -10,6 +10,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from aws_k8s_ansible_provisioner_tpu.ops import kv_pool as kvp
 from aws_k8s_ansible_provisioner_tpu.ops import pallas_attention as pa
@@ -179,6 +181,140 @@ def test_paged_db_poisoned_dead_pages_ignored():
                                         interpret=True, bblock=4)
     np.testing.assert_allclose(np.asarray(out), np.asarray(base),
                                rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# A row past its own pages starts no copy (PR 45). In the per-row path a
+# block's page step c copies for row i only where lo[i] <= c <= hi[i]; the
+# row's masked update still runs on whatever its buffer slot holds, so the
+# kernel zeroes the V slots no copy of the block fills first. Interpret mode
+# hands every scratch buffer over as NaN (pinned below), so a slot nothing
+# filled shows as a NaN in the output. Blocks of ONE row never skip (a row's
+# walk is its own range), so ``bblock=1`` is the program the parent ran, bit
+# for bit.
+# ---------------------------------------------------------------------------
+
+
+def test_interpret_mode_poisons_scratch_buffers():
+    """What the tests below lean on: a scratch buffer nobody wrote reads as
+    NaN in interpret mode (a JAX that zero-filled it would hide an unfilled
+    page buffer, as the chip does not)."""
+    def kernel(o_ref, scratch):
+        o_ref[:] = scratch[:]
+
+    out = pl.pallas_call(
+        kernel, out_shape=jax.ShapeDtypeStruct((8, 128), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((8, 128), jnp.float32)],
+        interpret=True)()
+    assert np.isnan(np.asarray(out)).all()
+
+
+# a one-page row beside a full window, a row that ends on a page edge, a
+# dead row in a live block, mid-page rows (pages of 32; the window of 48
+# puts the full rows' first live page above the block's)
+_SKIP_LENGTHS = [1, 128, 32, 0, 33, 97, 64, 5]
+
+
+def _skip_case(kind, bb):
+    """(kernel rows, live mask, XLA reference rows) of one call in which
+    rows of a block hold unlike page ranges, scratch poisoned."""
+    quant, window = "int8" in kind, 48 if "window" in kind else 0
+    dense, pool, table = _paged_layout(quant=quant, seed=59)
+    lengths = jnp.asarray(_SKIP_LENGTHS, jnp.int32)
+    ck, cv = dense["k"][0], dense["v"][0]
+    if quant:
+        ck = kvp.dequantize(ck, dense["ks"][0])
+        cv = kvp.dequantize(cv, dense["vs"][0])
+    pkw = dict(pool_ks=pool["ks"], pool_vs=pool["vs"]) if quant else {}
+    kw = dict(interpret=True, window=window, bblock=bb, **pkw)
+    if kind.startswith("spec"):
+        R = 3
+        q = jax.random.normal(jax.random.PRNGKey(9), (8, R, 4, 32))
+        lengths = jnp.minimum(lengths, 128 - R)
+        out = pa.decode_attend_pallas_spec_paged(
+            q, pool["k"], pool["v"], lengths, jnp.int32(0), table, **kw)
+        ref = decode_attend(q, ck, cv, lengths + 1, window=window)
+        return out, np.ones(8, bool), ref
+    if kind.startswith("ragged"):
+        # the decode rows of a mixed step: every slot's row (slot 2's dead:
+        # it is the one chunking), then 16 chunk rows of slot 2
+        j = np.arange(16)
+        limits = jnp.asarray(np.concatenate(
+            [np.where(np.arange(8) == 2, 0, _SKIP_LENGTHS),
+             np.where(j < 11, 40 + j + 1, 0)]), jnp.int32)
+        rows_of = np.concatenate([np.arange(8), np.full(16, 2)])
+        q = jax.random.normal(jax.random.PRNGKey(9), (24, 4, 32))
+        out = pa.ragged_attend_pallas_paged(
+            q, pool["k"], pool["v"], limits, jnp.int32(0), table[rows_of],
+            **kw)
+        ref = decode_attend(q[:, None], ck[rows_of], cv[rows_of], limits,
+                            window=window)[:, 0]
+        # the chunk rows of a sharing block take another arithmetic
+        return out[:8], np.asarray(limits[:8]) > 0, ref[:8]
+    q = jax.random.normal(jax.random.PRNGKey(9), (8, 1, 4, 32))
+    out = pa.decode_attend_pallas_paged(
+        q, pool["k"], pool["v"], lengths, jnp.int32(0), table, **kw)
+    return out, np.asarray(lengths) > 0, \
+        decode_attend(q, ck, cv, lengths, window=window)
+
+
+@pytest.mark.parametrize("bb", [4, 8])
+@pytest.mark.parametrize("kind", [
+    "decode", "decode-int8", "decode-window", "decode-int8-window", "spec",
+    "spec-int8", "spec-window", "ragged", "ragged-int8", "ragged-window"])
+def test_a_row_outside_its_range_copies_nothing_and_keeps_its_bits(kind,
+                                                                   bb):
+    out, live, ref = (np.asarray(a, np.float32)
+                      for a in _skip_case(kind, bb))
+    live = live.astype(bool)
+    assert np.isfinite(out).all(), "an unfilled buffer slot reached a matmul"
+    alone, _, _ = _skip_case(kind, 1)
+    np.testing.assert_array_equal(out, np.asarray(alone, np.float32))
+    tol = 4e-2 if "int8" in kind else 2e-5
+    np.testing.assert_allclose(out[live], ref[live], rtol=tol, atol=tol)
+    assert not out[~live].any()
+
+
+def _copy_eqns(jaxpr, under_cond=False, found=None):
+    """(primitive name, is it under a ``cond``) of every copy start and
+    wait in ``jaxpr``, the kernel's body included."""
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in ("dma_start", "dma_wait"):
+            found.append((eqn.primitive.name, under_cond))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _copy_eqns(sub, under_cond or eqn.primitive.name == "cond",
+                       found)
+    return found
+
+
+@pytest.mark.parametrize("entry", ["decode", "decode-int8", "spec", "ragged"])
+def test_a_per_row_copy_starts_and_waits_under_one_predicate(entry):
+    """Every start of the per-row walk has its wait, both inside a ``cond``
+    (the row's ``fetches``; the prologue and the prefetch add theirs around
+    the starts): none runs for a row outside its range. (The parent's waits
+    were unconditional; the selecting entries' still are — their jaxprs are
+    pinned in tests/test_tpu_compile.py.)"""
+    sds, i32 = jax.ShapeDtypeStruct, jnp.int32
+    B, Hq, Hkv, PS, D, MP = 4, 4, 2, 16, 16, 4
+    kv = sds((2, 17, Hkv, PS, D), jnp.int8 if "int8" in entry
+             else jnp.bfloat16)
+    args = (kv, kv, sds((B,), i32), sds((), i32), sds((B, MP), i32))
+    pkw = {}
+    if "int8" in entry:
+        sc = sds((2, 17, Hkv, kvp.scale_lanes(PS)), jnp.float32)
+        pkw = dict(pool_ks=sc, pool_vs=sc)
+    fn, q = {"spec": (pa.decode_attend_pallas_spec_paged, (B, 3, Hq, D)),
+             "ragged": (pa.ragged_attend_pallas_paged, (B, Hq, D))}.get(
+        entry, (pa.decode_attend_pallas_paged, (B, 1, Hq, D)))
+    found = _copy_eqns(jax.make_jaxpr(
+        lambda *a, **k: fn(*a, interpret=True, bblock=2, **k))(
+            sds(q, jnp.bfloat16), *args, **pkw).jaxpr)
+    starts = [c for n, c in found if n == "dma_start"]
+    waits = [c for n, c in found if n == "dma_wait"]
+    assert starts and all(starts) and all(waits)
+    # the prologue's and the prefetch's starts, one wait for both
+    assert len(starts) == 2 * len(waits)
 
 
 # ---------------------------------------------------------------------------

@@ -22,7 +22,13 @@ from aws_k8s_ansible_provisioner_tpu.ops.attention import decode_attend
 from aws_k8s_ansible_provisioner_tpu.serving.engine import Engine, Request
 
 
-def test_pallas_windowed_attend_matches_xla():
+@pytest.mark.parametrize("bb", [1, 3])
+def test_pallas_windowed_attend_matches_xla(bb):
+    """``bb`` 3: one block holds a row inside its first page beside rows
+    whose windows start at pages 1 and 3 — above the block's first page, so
+    their buffers are read (masked) before any copy of theirs fills them,
+    and the first row stops copying after page 0 (PR 45: a row outside its
+    range starts no copy; interpret mode's scratch starts as NaN)."""
     L, B, Hkv, S, D, Hq, W = 2, 3, 2, 64, 16, 4, 8
     rng = np.random.default_rng(0)
     k = jnp.asarray(rng.normal(0, 1, (L, B, Hkv, S, D)), jnp.float32)
@@ -39,7 +45,7 @@ def test_pallas_windowed_attend_matches_xla():
     table = jnp.arange(B * (S // PS), dtype=jnp.int32).reshape(B, S // PS)
     got = pa.decode_attend_pallas_paged(q, pages(k), pages(v), lengths,
                                         jnp.int32(1), table, interpret=True,
-                                        window=W)
+                                        window=W, bblock=bb)
     ref = decode_attend(q, k[1], v[1], lengths, window=W)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                atol=1e-5, rtol=1e-5)

@@ -135,6 +135,11 @@ CASES = [
     ("ragged-bf16-bb8-tp4", "ragged", False, 8, B + CHUNK, 1, HQ // 4,
      HKV // 4),
     ("spec-bf16-bb1", "spec", False, 1, B, 5, HQ, HKV),
+    # (blocks of 8 rows of 5 drafts, either pool: the per-row walk whose
+    # copies sit under each row's own predicate since PR 45, ``ext``
+    # columns past the length inside the row's range)
+    ("spec-bf16-bb8", "spec", False, 8, B, 5, HQ, HKV),
+    ("spec-int8-bb8", "spec", True, 8, B, 5, HQ, HKV),
     # OLMoE: multi-head attention, one query head a KV head (groups = 1),
     # 16 KV heads, 24 slots — a decode block of 8 slots gives the kernel 8
     # query rows a KV head, and a 64-token page is 262 KB each for K and V
@@ -586,6 +591,38 @@ def test_ragged_kernel_under_page_masks_compiles_for_v5e(chip, chunk):
     grids = _pallas_grids(functools.partial(fn, bblock=8), *args)
     assert sum(g for g, in grids) == N // 8 and len(grids) == \
         (2 if chunk == 8192 else 1)
+
+
+# sha256 of str(jax.make_jaxpr(...)) of the two selecting entries at the
+# parent of PR 45 (cb17104), taken with this very function in a checkout of
+# it: PR 45 moved the per-row walk of every OTHER entry of the paged body (a
+# row past its own pages starts no copy) and left these two — a list per row
+# and KV head, a bitmask over pages: past the dense length nothing to skip —
+# as they were, jaxpr for jaxpr.
+PINNED_SELECT = {"decode": "54178aab508e23a1", "ragged": "3f35e8b859231c93"}
+
+
+@pytest.mark.parametrize("entry", sorted(PINNED_SELECT))
+def test_selecting_entries_are_the_parents_jaxpr_for_jaxpr(entry):
+    import hashlib
+
+    sds, i32 = jax.ShapeDtypeStruct, jnp.int32
+    lyr, pgs, hkv, ps, d, b, mp, hq, k, n = 2, 17, 2, 16, 16, 4, 4, 4, 3, 8
+    kv = sds((lyr, pgs, hkv, ps, d), jnp.bfloat16)
+    if entry == "decode":
+        fn = pa.decode_attend_pallas_paged_select
+        args = (sds((b, 1, hq, d), jnp.bfloat16), kv, kv, sds((b,), i32),
+                sds((), i32), sds((b, mp), i32), sds((b, hkv, k), i32),
+                sds((b, hkv), i32))
+    else:
+        fn = pa.ragged_attend_pallas_paged_select
+        args = (sds((n, hq, d), jnp.bfloat16), kv, kv, sds((n,), i32),
+                sds((), i32), sds((b, mp), i32), sds((n,), i32),
+                sds((n, hkv, 1), i32))
+    text = str(jax.make_jaxpr(
+        lambda *a: fn(*a, interpret=True, bblock=2))(*args))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == PINNED_SELECT[entry]
 
 
 # Trinity-Mini's shape as served: 4 KV heads, 32 query heads (groups 8), 48

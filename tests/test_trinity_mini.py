@@ -352,16 +352,23 @@ def test_dispatch_records_and_metrics_tell_the_kinds_apart(mixed_run):
         assert r["win_pages_live"] <= r["attn_pages_live"]
         assert r["win_pages_live"] <= r["horizon"] * 4 * (WINDOW // PS + 1)
         assert r["win_pages_walked"] >= r["win_pages_live"]
+        # a row copies the pages it holds and no other (PR 45), either kind
+        assert r["win_pages_copied"] == r["win_pages_live"]
+        assert r["attn_pages_copied"] == r["attn_pages_live"]
     late = dec[-1]
     assert late["win_pages_live"] < late["attn_pages_live"]
     m = eng.metrics
     assert m.window_attn_pages.total() == sum(
-        r["win_pages_live"] + r["win_pages_walked"] for r in dec)
+        2 * r["win_pages_live"] + r["win_pages_walked"] for r in dec)
     assert m.decode_attn_pages.total() == sum(
-        r["attn_pages_live"] + r["attn_pages_walked"] for r in dec)
+        2 * r["attn_pages_live"] + r["attn_pages_walked"] for r in dec)
+    assert m.window_attn_pages.value(kind="copied") \
+        == m.window_attn_pages.value(kind="live")
     assert m.prefix_tokens_reused.total() == 0
     text = m.registry.render() + metrics_mod.window_pool.registry.render()
     for name in ('tpu_serve_window_attn_pages_total{kind="live"}',
+                 'tpu_serve_window_attn_pages_total{kind="copied"}',
+                 'tpu_serve_decode_attn_pages_total{kind="copied"}',
                  'tpu_serve_prefix_lookups_skipped_total{reason='
                  '"window_pages"}',
                  "tpu_serve_kv_window_pages_total 17",
