@@ -426,10 +426,9 @@ def make_spec_attend_carry_paged(lengths: jnp.ndarray, table: jnp.ndarray,
 
 def make_mixed_attend_carry_paged(dec_rows: jnp.ndarray, chunk_start,
                                   chunk_len, row_limits: jnp.ndarray,
-                                  row_tables: jnp.ndarray,
+                                  table: jnp.ndarray, row_map: jnp.ndarray,
                                   impl: str = "auto", mesh=None,
                                   window: int = 0, bblock: int = 1,
-                                  row_map=None,
                                   of_window_kind: bool = False):
     """RAGGED mixed-batch attend over the PAGED pool: the packed sequence
     holds B single-token decode rows followed by C prefill-chunk rows of one
@@ -449,74 +448,58 @@ def make_mixed_attend_carry_paged(dec_rows: jnp.ndarray, chunk_start,
       context + 1; chunk: p + 1 — plain causality; 0 = a DEAD row — the
       chunk's padding, the chunking slot's decode row — which fetches
       nothing and returns zeros, from the kernel and the fallback alike);
-    - ``row_tables`` [N, max_pages]: the page run of the slot row i belongs
-      to (chunk rows repeat the chunking slot's run).
+    - ``table`` [B, max_pages], one page run a SLOT, and ``row_map`` [N]:
+      the row of ``table`` packed row i reads (decode row b: b; every chunk
+      row: the chunking slot's).
 
     Two needs, two writers: a decode row is one row in each of B page runs
     (the row kernel decode_steps uses, one grid step a slot), the chunk is
     one span of one run (kv_pool.write_chunk_paged_layer, the prefill
-    programs' writer, one page window at a time: 0.07 ms for the K and V
-    of a layer at the served shape, my chip run, PR 29 — a grid step a ROW
-    was 2,080 steps a call, two thirds of them padding, 0.8 ms a layer and
-    22 ms of a 58-ms step: ledger, PR 26). All writes land before any row
-    attends; causality then reduces to the per-row column mask, so a chunk
-    row sees exactly its prefix (earlier chunks + this chunk's earlier
-    rows) and a decode row sees exactly its own slot — byte-identical math
-    to the separate decode_attend/chunk_attend programs it replaces. Mesh
-    support mirrors make_decode_attend_carry_paged's tp sharding (heads
-    over ``tp``); the engine gates ragged dispatch to mesh None / pure-tp,
-    so no dp rebase rides here.
-
-    ``row_map`` [N] (a list with window AND full layers, no mesh, bf16
-    pool): ``row_tables`` is then ONE row a SLOT, [B, max_pages], and row i
-    reads row ``row_map[i]`` of it — a row a packed row outgrows the
-    kernel's SMEM at a long window and a wide chunk; ``of_window_kind``
-    as in make_decode_attend_carry_paged."""
+    programs' writer, one page window at a time). All writes land before
+    any row attends; causality then reduces to the per-row column mask, so
+    a chunk row sees exactly its prefix (earlier chunks + this chunk's
+    earlier rows) and a decode row sees exactly its own slot —
+    byte-identical math to the separate decode_attend/chunk_attend programs
+    it replaces. Mesh support mirrors make_decode_attend_carry_paged's tp
+    sharding (heads over ``tp``; table and map whole on every shard); the
+    engine gates ragged dispatch to mesh None / pure-tp, so no dp rebase
+    rides here. ``of_window_kind`` as in make_decode_attend_carry_paged:
+    the trace name of the kernel call, nothing else."""
     resolved = resolve_impl(impl)
     B = dec_rows.shape[0]
 
-    def _write_chunk(pool, knew, vnew, start, n_valid, tabs, layer):
-        pages = tabs[B] if row_map is None else tabs[row_map[B]]
+    def _write_chunk(pool, knew, vnew, start, n_valid, tab, rmap, layer):
         return kvp.write_chunk_paged_layer(
-            pool, layer, pages, start, knew[None, B:], vnew[None, B:],
+            pool, layer, tab[rmap[B]], start, knew[None, B:], vnew[None, B:],
             pool["k"].shape[3], n_valid=n_valid)
 
     def _write_attend_mixed(q3, pool, knew, vnew, drows, start, n_valid,
-                            limits, tabs, layer):
+                            limits, tab, rmap, layer):
         from aws_k8s_ansible_provisioner_tpu.ops import pallas_attention
 
         interpret = not pallas_attention.supported()
         ck, cv = pool["k"], pool["v"]
         if "ks" in pool:
             ck, ks = pallas_attention.cache_write_row_quant_paged(
-                ck, pool["ks"], knew[:B], drows, tabs[:B], layer,
+                ck, pool["ks"], knew[:B], drows, tab, layer,
                 interpret=interpret)
             cv, vs = pallas_attention.cache_write_row_quant_paged(
-                cv, pool["vs"], vnew[:B], drows, tabs[:B], layer,
+                cv, pool["vs"], vnew[:B], drows, tab, layer,
                 interpret=interpret)
             pool = {"k": ck, "v": cv, "ks": ks, "vs": vs}
         else:
             ck = pallas_attention.cache_write_row_paged(
-                ck, knew[:B], drows, tabs[:B], layer, interpret=interpret)
+                ck, knew[:B], drows, tab, layer, interpret=interpret)
             cv = pallas_attention.cache_write_row_paged(
-                cv, vnew[:B], drows, tabs[:B], layer, interpret=interpret)
+                cv, vnew[:B], drows, tab, layer, interpret=interpret)
             pool = {"k": ck, "v": cv}
-        pool = _write_chunk(pool, knew, vnew, start, n_valid, tabs, layer)
-        scale_kw = (dict(pool_ks=pool["ks"], pool_vs=pool["vs"])
-                    if "ks" in pool else {})
-        if row_map is not None and of_window_kind:
-            ctx = pallas_attention.ragged_attend_pallas_paged_slots_window(
-                q3, pool["k"], pool["v"], limits, layer, tabs, row_map,
-                interpret=interpret, window=window, bblock=bblock)
-        elif row_map is not None:
-            ctx = pallas_attention.ragged_attend_pallas_paged_slots(
-                q3, pool["k"], pool["v"], limits, layer, tabs, row_map,
-                interpret=interpret, bblock=bblock)
-        else:
-            ctx = pallas_attention.ragged_attend_pallas_paged(
-                q3, pool["k"], pool["v"], limits, layer, tabs,
-                interpret=interpret, window=window, bblock=bblock,
-                **scale_kw)
+        pool = _write_chunk(pool, knew, vnew, start, n_valid, tab, rmap,
+                            layer)
+        ragged = pallas_attention.ragged_attend_pallas_paged_window \
+            if of_window_kind else pallas_attention.ragged_attend_pallas_paged
+        ctx = ragged(q3, pool["k"], pool["v"], limits, layer, tab, rmap,
+                     interpret=interpret, pool_ks=pool.get("ks"),
+                     pool_vs=pool.get("vs"), window=window, bblock=bblock)
         return ctx, pool
 
     def attend(q, k, v, cache_l) -> Tuple[jnp.ndarray, tuple]:
@@ -541,26 +524,22 @@ def make_mixed_attend_carry_paged(dec_rows: jnp.ndarray, chunk_start,
                               P(None),                # dec_rows [B]
                               P(), P(),               # chunk start, len
                               P(None),                # row_limits [N]
-                              P(None, None),          # row_tables
+                              P(None, None),          # table (slot rows)
+                              P(None),                # row_map [N]
                               P()),                   # layer scalar
                     out_specs=(P(None, "tp", None), pool_spec),
                     check_vma=False,
                 )
-                ctx, pool = fn(q3, pool, knew, vnew, dec_rows, chunk_start,
-                               chunk_len, row_limits, row_tables, layer)
             else:
-                ctx, pool = _write_attend_mixed(
-                    q3, pool, knew, vnew, dec_rows, chunk_start, chunk_len,
-                    row_limits, row_tables, layer)
+                fn = _write_attend_mixed
+            ctx, pool = fn(q3, pool, knew, vnew, dec_rows, chunk_start,
+                           chunk_len, row_limits, table, row_map, layer)
             return ctx[None], (pool, layer)
-        pool = kvp.write_token_layer_paged(pool, layer, dec_rows,
-                                           row_tables[:B], k[0][:B, None],
-                                           v[0][:B, None], ps)
-        pool = _write_chunk(pool, k[0], v[0], chunk_start, chunk_len,
-                            row_tables, layer)
-        dense = kvp.gather_layer_dense(
-            pool, layer,
-            row_tables if row_map is None else row_tables[row_map])
+        pool = kvp.write_token_layer_paged(pool, layer, dec_rows, table,
+                                           k[0][:B, None], v[0][:B, None], ps)
+        pool = _write_chunk(pool, k[0], v[0], chunk_start, chunk_len, table,
+                            row_map, layer)
+        dense = kvp.gather_layer_dense(pool, layer, table[row_map])
         ck, cv = dense["k"], dense["v"]
         if "ks" in dense:
             ck = kvp.dequantize(ck, dense["ks"], dtype=q.dtype)

@@ -198,9 +198,10 @@ def _paged_db_body(lengths_ref, layer_ref, table_ref, share_ref, wide_ref,
       ``bb`` rows only: a page that none of 8 rows chose is common, one
       that none of 56 chose is not.
 
-    ``rowmap_ref`` (ragged entry): packed row -> row of ``table_ref``, so
-    the chunk rows of one slot name ONE table row instead of each carrying
-    a copy (2,072 rows x 512 pages do not fit SMEM).
+    ``rowmap_ref`` (the ragged entries; None for a decode entry, whose row
+    i reads table row i): packed row -> row of ``table_ref``, so the chunk
+    rows of one slot name ONE table row instead of each carrying a copy
+    (2,072 rows x 512 pages do not fit SMEM).
     """
     g = pl.program_id(0)
     lay = layer_ref[0]
@@ -701,9 +702,11 @@ def _tile_rows(N: int, bb: int, hq: int, d: int, ps: int, dtype) -> int:
 
 def _shared_row(live, keys, width: int, dead: int = -1):
     """Per run of ``width`` packed rows: its first live row if every live
-    row's key ([N] slot or [N, max_pages] table row) equals that row's —
-    then any page one of them needs is at the same entry of that row's
-    table —, -1 if they differ, ``dead`` if no row is live."""
+    row's key (``row_map``'s entry, [N]: the slot it names) equals that
+    row's — then any page one of them needs is at the same entry of that
+    slot's table row —, -1 if they differ, ``dead`` if no row is live. (The
+    comparison takes keys of any trailing width, [N, max_pages] table rows
+    too; every caller hands [N].)"""
     N = live.shape[0]
     live = live.reshape(N // width, width)
     keys = keys.reshape(N // width, width, -1)
@@ -775,10 +778,9 @@ def _paged_flash_db(q2, pool_k, pool_v, lengths, layer_arr, table,
         pltpu.SemaphoreType.DMA((2, bb, 4 if quant else
                                  2 * Hkv if sel is not None else 2)),
     ]
-    # The table rides SMEM flattened: a 2-D s32[N, max_pages] operand pads
-    # its minor dim to 128 lanes there, and the mixed program's per-ROW table
-    # (N = slots + chunk = 2,080 rows x 32 pages at the default config)
-    # then needs 1.04 MiB of the chip's 1 MiB.
+    # The table rides SMEM flattened: a 2-D s32[S, max_pages] operand pads
+    # its minor dim to 128 lanes there (a 32-page row to four times its
+    # bytes, of the chip's 1 MiB).
     prefetch = [lengths, layer_arr, table.reshape(-1)]
     if sel is not None or bits is not None:
         assert not quant and window == 0 and not spec, \
@@ -874,75 +876,81 @@ def decode_attend_pallas_paged(q: jnp.ndarray, pool_k: jnp.ndarray,
 @functools.partial(jax.jit, static_argnames=("interpret", "window", "bblock"))
 def ragged_attend_pallas_paged(q: jnp.ndarray, pool_k: jnp.ndarray,
                                pool_v: jnp.ndarray, row_limits: jnp.ndarray,
-                               layer: jnp.ndarray, row_tables: jnp.ndarray,
+                               layer: jnp.ndarray, table: jnp.ndarray,
+                               row_map: jnp.ndarray,
                                interpret: bool = False,
                                pool_ks: jnp.ndarray = None,
                                pool_vs: jnp.ndarray = None,
                                window: int = 0,
                                bblock: int = 1) -> jnp.ndarray:
     """RAGGED paged flash attention: N query-token-packed rows, each with its
-    OWN (page table row, live-column count) — one program serves a mixed
-    batch of single-token decode rows and prefill-chunk rows in a single
-    dispatch (PAPERS.md "Ragged Paged Attention").
+    OWN (page run, live-column count) — one program serves a mixed batch of
+    single-token decode rows and prefill-chunk rows in a single dispatch
+    (PAPERS.md "Ragged Paged Attention").
 
     The key move is that the double-buffered body (_paged_db_body) never
     cared that row i belonged to slot i — its math is entirely driven by the
-    (table row, limit) pair it is handed per query row. Lifting the table to
-    PER-ROW indirection (``row_tables`` [N, max_pages]: row i holds the page
-    run of whatever slot row i queries) turns the per-slot decode kernel
-    into a variable-length-rows kernel with zero changes to the flash
-    accumulation, the page-clamp raggedness handling, or the two-slot DMA
-    pipeline:
+    (table row, limit) pair it is handed per query row. ``table``
+    [S, max_pages] holds ONE row a slot and ``row_map`` [N] names the row of
+    it each packed row reads (a row a packed row would be 266 KB of the
+    chip's 1 MiB of SMEM at 2,080 rows x 32 pages and 2.4 MB at 48 + 4,096
+    rows of 144 pages; a row a slot and the map are 12 KB). That turns the
+    per-slot decode kernel into a variable-length-rows kernel with zero
+    changes to the flash accumulation, the page-clamp raggedness handling,
+    or the two-slot DMA pipeline:
 
-    - a DECODE row carries its slot's table row and limit = context + 1;
-    - a PREFILL-CHUNK row at position p carries the chunking slot's table
+    - a DECODE row names its slot's table row and limit = context + 1;
+    - a PREFILL-CHUNK row at position p names the chunking slot's table
       row and limit = p + 1 (plain causality), so C chunk rows of one slot
       pack alongside B decode rows of B other slots and every row masks to
       exactly its own live columns;
     - a row with limit 0 (the chunk's padding rows, the chunking slot's own
-      decode row) is DEAD: it costs no fetch and no flash update, its table
-      entries are never used and may be anything, and its output is zero.
+      decode row) is DEAD: it costs no fetch and no flash update, the table
+      row it names is never read and may hold anything, and its output is
+      zero.
 
     The work follows the live (row, page) pairs. Where the live rows of one
-    grid step all carry the same table row — the chunk rows of one slot do
-    — the step fetches each page ONCE and updates its rows as one query
-    tile (_paged_db_body's sharing path); that is read off ``row_tables``
-    here, per call, not set by anyone. A grid step is a TILE of
-    :func:`_tile_rows` rows (40-64 where the row count has such a divisor,
-    from the shapes alone; ``bblock`` under an int8 pool): 4,096 chunk
-    rows stream their pages 73 times where blocks of 8 streamed them 512
-    times. A step whose live rows carry several tables (the one that
-    holds the decode rows) runs its ``bblock``-row blocks one after the
-    other: a block of chunk rows as a tile of its own, decode rows of
-    distinct slots a page per row, as the decode entry does.
+    grid step all name the same slot — the chunk rows of a prefill do — the
+    step fetches each page ONCE and updates its rows as one query tile
+    (_paged_db_body's sharing path); that is read off ``row_map`` here, per
+    call, not set by anyone. A grid step is a TILE of :func:`_tile_rows`
+    rows (40-64 where the row count has such a divisor, from the shapes
+    alone; ``bblock`` under an int8 pool): 4,096 chunk rows stream their
+    pages 73 times where blocks of 8 streamed them 512 times. A step whose
+    live rows name several slots (the one that holds the decode rows) runs
+    its ``bblock``-row blocks one after the other: a block of chunk rows as
+    a tile of its own, decode rows of distinct slots a page per row, as the
+    decode entry does.
 
     q: [N, Hq, D] packed query rows; row_limits: [N] live columns per row;
-    row_tables: [N, max_pages] int32 (entries at or past a row's live range
-    may be any valid id — no copy of them starts); layer: scalar.
-    Returns [N, Hq, D]. pool_ks/vs switch the int8 scale-folding body;
-    ``window`` > 0 applies per-row sliding-window masking off each row's own
-    limit. ``bblock`` (resolved to the largest divisor of N) is the width
-    of the blocks that stream a page per row.
+    table: [S, max_pages] int32 (entries at or past a row's live range may
+    be any valid id — no copy of them starts — and those below its window
+    released pages); row_map: [N] int32; layer: scalar. Returns [N, Hq, D].
+    pool_ks/vs switch the int8 scale-folding body; ``window`` > 0 applies
+    per-row sliding-window masking off each row's own limit, and a block's
+    walk then starts at its lowest row's first live page. ``bblock``
+    (resolved to the largest divisor of N) is the width of the blocks that
+    stream a page per row.
     """
     N = q.shape[0]
     bb = _resolve_bb(bblock, N)
+    row_map = row_map.astype(jnp.int32)
     row_limits = row_limits.astype(jnp.int32)
-    row_tables = row_tables.astype(jnp.int32)
-    layer_arr = jnp.asarray(layer, jnp.int32).reshape(1)
-    share, wide = _share_facts(q, pool_k, row_limits, row_tables, bb)
+    # a block, or a tile, whose live rows all name one slot shares it
+    share, wide = _share_facts(q, pool_k, row_limits, row_map, bb)
     return _paged_flash_db(
-        q, pool_k, pool_v, row_limits, layer_arr, row_tables,
+        q, pool_k, pool_v, row_limits,
+        jnp.asarray(layer, jnp.int32).reshape(1), table.astype(jnp.int32),
         bb=bb, R=1, spec=False, window=window, interpret=interpret,
-        pool_ks=pool_ks, pool_vs=pool_vs, share=share, wide=wide)
+        pool_ks=pool_ks, pool_vs=pool_vs, share=share, wide=wide,
+        row_map=row_map)
 
 
 # -- the entry points of a list that holds window AND full layers -------------
 #
-# The same body under jits of their own, so that the device trace tells a
+# The same bodies under jits of their own, so that the device trace tells a
 # window layer's calls from a full layer's (a Pallas call is named after the
-# innermost jitted wrapper around it: ``_named_entry``), and a ragged entry
-# that takes ONE table row a slot: a row a packed row is 2.4 MB of SMEM at
-# 48 + 4,096 rows of 144 pages.
+# innermost jitted wrapper around it: ``_named_entry``).
 
 
 def _named_entry(name: str, fn, doc: str):
@@ -957,41 +965,16 @@ def _named_entry(name: str, fn, doc: str):
     return jax.jit(entry, static_argnames=("interpret", "window", "bblock"))
 
 
-def _ragged_by_slot(q, pool_k, pool_v, row_limits, layer, table, row_map,
-                    interpret: bool = False, window: int = 0,
-                    bblock: int = 1):
-    N = q.shape[0]
-    bb = _resolve_bb(bblock, N)
-    row_map = row_map.astype(jnp.int32)
-    row_limits = row_limits.astype(jnp.int32)
-    # a block, or a tile, whose live rows all name one slot shares it
-    share, wide = _share_facts(q, pool_k, row_limits, row_map, bb)
-    return _paged_flash_db(
-        q, pool_k, pool_v, row_limits,
-        jnp.asarray(layer, jnp.int32).reshape(1), table.astype(jnp.int32),
-        bb=bb, R=1, spec=False, window=window, interpret=interpret,
-        pool_ks=None, pool_vs=None, share=share, wide=wide,
-        row_map=row_map)
-
-
 decode_attend_pallas_paged_window = _named_entry(
     "decode_attend_pallas_paged_window", decode_attend_pallas_paged.__wrapped__,
     """:func:`decode_attend_pallas_paged` for the WINDOW layers of a list
     that also holds full ones: their own leaves, their own table (entries
     below a row's window may be anything: released pages), the static
     ``window``. bf16 pool.""")
-ragged_attend_pallas_paged_slots = _named_entry(
-    "ragged_attend_pallas_paged_slots", _ragged_by_slot,
-    """:func:`ragged_attend_pallas_paged` with ONE table row a slot:
-    ``table`` [S, max_pages] and ``row_map`` [N] naming each packed row's
-    (the chunk rows of a prefill share an entry, which is also how a
-    sharing block is recognised). bf16 pool; the FULL layers of the list
-    call it (``window`` 0).""")
-ragged_attend_pallas_paged_slots_window = _named_entry(
-    "ragged_attend_pallas_paged_slots_window", _ragged_by_slot,
-    """:func:`ragged_attend_pallas_paged_slots` for the WINDOW layers: each
-    row masks below its own ``limit - window`` and a block's walk starts at
-    its lowest row's first live page.""")
+ragged_attend_pallas_paged_window = _named_entry(
+    "ragged_attend_pallas_paged_window", ragged_attend_pallas_paged.__wrapped__,
+    """:func:`ragged_attend_pallas_paged` for the WINDOW layers of such a
+    list, likewise.""")
 
 
 # SMEM the ragged selecting entry lets its prefetched operands take in one

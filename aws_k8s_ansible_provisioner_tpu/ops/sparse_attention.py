@@ -348,22 +348,21 @@ def make_decode_attend_select(cfg: ModelConfig, lengths, table,
 
 
 def make_mixed_attend_select(cfg: ModelConfig, dec_rows, chunk_start,
-                             chunk_len, row_limits, table, pslot,
+                             chunk_len, row_limits, table, row_map,
                              impl: str = "auto", bblock: int = 1,
                              live=None):
     """mixed_step's packed [1, B + C]: B decode rows, then the C chunk rows
-    of slot ``pslot`` (attention.make_mixed_attend_carry_paged's contract,
-    with ``table`` [B, max_pages] one row a SLOT: the chunk rows name
-    ``pslot``'s). All writes land first; every row then selects over its own
+    of one slot (attention.make_mixed_attend_carry_paged's contract:
+    ``table`` [B, max_pages] one row a SLOT, ``row_map`` [B + C] the row of
+    it each packed row reads — the chunk rows all name the chunking
+    slot's). All writes land first; every row then selects over its own
     slot's pooled keys at its own length and reads under its mask."""
     resolved = resolve_impl(impl)
     B = dec_rows.shape[0]
-    N = row_limits.shape[0]
+    pslot = row_map[B]
     if live is not None:        # an idle slot's decode row reads nothing
         row_limits = row_limits.at[:B].set(
             jnp.where(live, row_limits[:B], 0))
-    row_map = jnp.concatenate([jnp.arange(B, dtype=jnp.int32),
-                               jnp.full((N - B,), pslot, jnp.int32)])
 
     def attend(q, k, v, cache_l):
         from aws_k8s_ansible_provisioner_tpu.ops import pallas_attention

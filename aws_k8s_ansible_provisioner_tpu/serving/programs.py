@@ -789,30 +789,26 @@ def mixed_step(cfg: ModelConfig, params, cache, tokens, lengths, ptokens,
     row_limits = jnp.concatenate(
         [jnp.where(is_p, jnp.int32(0), lengths + 1),
          jnp.where(is_pad, jnp.int32(0), crows + 1)])
-    # a table row a packed row; ONE a slot and a row map where the kernel
-    # takes that (a selecting model's, a list with window layers')
-    row_tables = None if cfg.selects or cfg.windowed else jnp.concatenate(
-        [table, jnp.broadcast_to(table[pslot][None], (C, table.shape[1]))])
+    # which row of ``table`` (one a SLOT) each packed row reads: a decode
+    # row its own slot's, every chunk row pslot's
     row_map = jnp.concatenate(
         [jnp.arange(B, dtype=jnp.int32),
-         jnp.broadcast_to(pslot.astype(jnp.int32), (C,))]) \
-        if cfg.windowed else None
+         jnp.broadcast_to(pslot.astype(jnp.int32), (C,))])
     packed = jnp.concatenate([tokens[None], ptokens], axis=1)     # [1, B+C]
     positions = jnp.concatenate(
         [jnp.where(is_p, jnp.int32(0), lengths)[None], crows[None]], axis=1)
     # K/V writes: one row a decode slot (pslot's own dropped), the chunk as
     # the span [pstart, pstart + plen) of pslot's page run
-    if cfg.selects:     # one table row a SLOT: the chunk rows name pslot's
+    if cfg.selects:
         attend = _sa.make_mixed_attend_select(
             cfg, jnp.where(is_p, jnp.int32(-1), lengths), pstart, plen,
-            row_limits, table, pslot, impl=impl, bblock=bblock, live=live)
+            row_limits, table, row_map, impl=impl, bblock=bblock, live=live)
     else:
         attend = _attend(
             cfg, lambda t, w, kind: make_mixed_attend_carry_paged(
                 jnp.where(is_p, jnp.int32(-1), lengths), pstart, plen,
-                row_limits, t if cfg.windowed else row_tables, impl=impl,
-                mesh=mesh, window=w, bblock=bblock, row_map=row_map,
-                of_window_kind=kind), table, wtable)
+                row_limits, t, row_map, impl=impl, mesh=mesh, window=w,
+                bblock=bblock, of_window_kind=kind), table, wtable)
     # Per-TOKEN adapter indices over the packed layout: decode row b keeps
     # its slot's adapter, every chunk row runs the chunking slot's — one
     # program serves any adapter mix (models/layers._linear gathers factors

@@ -717,11 +717,10 @@ def kernel_parity(cfg, slots: int, window: int, page: int, bblocks,
         limits = jnp.concatenate(
             [jnp.where(jnp.arange(B) == 3, 0, lengths),
              jnp.where(jnp.arange(C) < 40, crow + 1, 0)])
-        rtab = jnp.concatenate(
-            [table, jnp.broadcast_to(table[3][None], (C, MP))])
+        rmap = slot_rows(B, C, 3)
         q3 = jax.random.normal(keys[3], (B + C, Hq, D), jnp.bfloat16)
         with jax.default_matmul_precision("highest"):
-            ck, cv = dense_view(rtab)
+            ck, cv = dense_view(table[rmap])
             rref = decode_attend(q3[:, None], ck, cv, limits)[:, 0]
         rref = jnp.where((limits > 0)[:, None, None], rref, 0)
         del ck, cv
@@ -731,7 +730,7 @@ def kernel_parity(cfg, slots: int, window: int, page: int, bblocks,
                 interpret=interpret, bblock=bb, **skw)
             e1 = close(f"decode_attend_pallas_paged {tag} bb={bb}", out, ref)
             out = pa.ragged_attend_pallas_paged(
-                q3, pool["k"], pool["v"], limits, layer, rtab,
+                q3, pool["k"], pool["v"], limits, layer, table, rmap,
                 interpret=interpret, bblock=bb, **skw)
             e2 = close(f"ragged_attend_pallas_paged {tag} bb={bb}", out,
                        rref)
@@ -752,12 +751,10 @@ def kernel_parity(cfg, slots: int, window: int, page: int, bblocks,
         wlimits = jnp.concatenate(
             [jnp.where(jnp.arange(B) == 3, 0, lengths),
              jnp.where(wide_n < 640, wide_n + 1, 0)])
-        wtab = jnp.concatenate(
-            [table, jnp.broadcast_to(table[3][None], (window, MP))])
         qw = jax.random.normal(keys[3], (B + window, Hq, D), jnp.bfloat16)
         out = pa.ragged_attend_pallas_paged(
-            qw, pool["k"], pool["v"], wlimits, layer, wtab,
-            interpret=interpret, bblock=bb, **skw)
+            qw, pool["k"], pool["v"], wlimits, layer, table,
+            slot_rows(B, window, 3), interpret=interpret, bblock=bb, **skw)
         with jax.default_matmul_precision("highest"):
             ck, cv = dense_view(table)
             want = [decode_attend(qw[:B, None], ck, cv, wlimits[:B])[:, 0]]
@@ -777,7 +774,7 @@ def kernel_parity(cfg, slots: int, window: int, page: int, bblocks,
         say(f"parity: ragged paged {tag} at the served width, {B}+{window} "
             f"rows in tiles of {tile} (one straddling, one part dead, "
             f"one-page rows): max abs err {e4:.2e} (tol {KERNEL_TOL})")
-        del ck, cv, want, wtab, qw
+        del ck, cv, want, qw
         # the decode program's call: make_decode_attend_carry_paged writes
         # each slot's row, then hands the kernel the rows IN ORDER OF LENGTH
         # (every slot-order block of 8 here holds a one-page row beside a
@@ -803,7 +800,7 @@ def kernel_parity(cfg, slots: int, window: int, page: int, bblocks,
             f"err {e3:.2e} (tol {KERNEL_TOL})")
         del wrote, wkw, slot_order
         copy_skip_parity(pa, parent, pool, skw, table, layer, page, window,
-                         (q, q3), limits, rtab, bblocks, tag, interpret)
+                         (q, q3), limits, rmap, bblocks, tag, interpret)
         if not interpret:
             ragged_call_time(pa, pool, skw, table, lengths, layer, Hq, D,
                              window, max(bblocks), tag)
@@ -834,9 +831,10 @@ def kernel_parity(cfg, slots: int, window: int, page: int, bblocks,
              jnp.where(jnp.arange(C) < 40, crow, -1)])
         pnew = jax.random.normal(keys[5], (B + C, Hkv, D), jnp.bfloat16)
         want = kvp.write_token_layer_paged(
-            pool, layer, prow, rtab, pnew[:, None], pnew[:, None], page)
+            pool, layer, prow, table[rmap], pnew[:, None], pnew[:, None],
+            page)
         attend = make_mixed_attend_carry_paged(
-            prow[:B], jnp.int32(300), jnp.int32(40), limits, rtab,
+            prow[:B], jnp.int32(300), jnp.int32(40), limits, table, rmap,
             impl="pallas", bblock=max(bblocks))
         _, (got, _) = jax.jit(attend)(q3[None], pnew[None], pnew[None],
                                       (pool, layer))
@@ -860,6 +858,28 @@ def kernel_parity(cfg, slots: int, window: int, page: int, bblocks,
             chunk_write_time(kvp, pool, table, layer, page, window, tag)
         del got
         del pool, want, gk
+
+
+def slot_rows(slots: int, chunk: int, pslot: int):
+    """mixed_step's row map: packed row -> the row of the table (one a
+    slot) it reads — every decode row its own slot's, the ``chunk`` rows
+    slot ``pslot``'s."""
+    import jax.numpy as jnp
+
+    return jnp.concatenate([jnp.arange(slots, dtype=jnp.int32),
+                            jnp.full((chunk,), pslot, jnp.int32)])
+
+
+def ragged_tables(mod, table, rmap) -> tuple:
+    """The table operands of ``mod.ragged_attend_pallas_paged``: ``(table,
+    row_map)``; for the kernels of a checkout from before PR 46
+    (``--parent``) the table row a packed row they took."""
+    import inspect
+
+    if "row_map" in inspect.signature(
+            mod.ragged_attend_pallas_paged).parameters:
+        return table, rmap
+    return (table[rmap],)
 
 
 def load_kernels(root: str):
@@ -907,7 +927,7 @@ def poison_vmem(interpret: bool) -> None:
 
 
 def copy_skip_parity(pa, parent, pool, skw, table, layer, page, window,
-                     queries, limits, rtab, bblocks, tag, interpret) -> None:
+                     queries, limits, rmap, bblocks, tag, interpret) -> None:
     """A row past its own pages starts no copy (PR 45): the decode entry and
     the ragged entry's decode-row tile, each call made right after
     :func:`poison_vmem`, BITWISE against the same rows in blocks of ONE (a
@@ -942,8 +962,9 @@ def copy_skip_parity(pa, parent, pool, skw, table, layer, page, window,
 
         def ragged(mod, bb):
             return mod.ragged_attend_pallas_paged(
-                q3, pool["k"], pool["v"], rlim, layer, rtab,
-                interpret=interpret, window=win, bblock=bb, **skw)
+                q3, pool["k"], pool["v"], rlim, layer,
+                *ragged_tables(mod, table, rmap), interpret=interpret,
+                window=win, bblock=bb, **skw)
 
         alone = decode(pa, 1), ragged(pa, 1)
         for bb in (b for b in bblocks if b > 1):
@@ -1022,10 +1043,9 @@ def ragged_call_time(pa, pool, skw, table, lengths, layer, Hq, D, chunk,
     import jax
     import jax.numpy as jnp
 
-    B, MP = table.shape
+    B = table.shape[0]
     j = jnp.arange(chunk, dtype=jnp.int32)
-    rtab = jnp.concatenate(
-        [table, jnp.broadcast_to(table[3][None], (chunk, MP))])
+    tables = ragged_tables(pa, table, slot_rows(B, chunk, 3))
     q = jax.random.normal(jax.random.PRNGKey(5), (B + chunk, Hq, D),
                           jnp.bfloat16)
     decode = jnp.where(jnp.arange(B) == 3, 0, lengths)
@@ -1035,7 +1055,7 @@ def ragged_call_time(pa, pool, skw, table, lengths, layer, Hq, D, chunk,
 
         def call():
             return pa.ragged_attend_pallas_paged(
-                q, pool["k"], pool["v"], limits, layer, rtab, bblock=bb,
+                q, pool["k"], pool["v"], limits, layer, *tables, bblock=bb,
                 **skw)
 
         say(f"ragged call {tag} bb={bb}, {B}+{chunk} rows, 640-token "
@@ -1206,8 +1226,7 @@ def sala_kernel_parity(cfg, slots: int, window: int, page: int, bb: int,
     lims = np.concatenate([np.where(np.arange(B) == pslot, 0, lengths),
                            off + 1 + np.arange(C)]).astype(np.int32)
     lims[B + C - 5:] = 0                           # the chunk's padding
-    row_map = jnp.concatenate([jnp.arange(B, dtype=jnp.int32),
-                               jnp.full((C,), pslot, jnp.int32)])
+    row_map = slot_rows(B, C, pslot)
     q3 = jax.random.normal(keys[3], (B + C, Hq, D), jnp.bfloat16)
     sel = random_selection(lims)
     with jax.default_matmul_precision("highest"):
@@ -1819,7 +1838,7 @@ def window_kernel_parity(cfg, slots: int, window: int, page: int, bb: int,
     """The entry points of a list with window AND full layers at the served
     widths against the jax.numpy reference (ops/attention.decode_attend
     over kv_pool.gather_layer_dense): the decode kernel under its window
-    name, and the ragged kernel with ONE table row a slot (full, and under
+    name, and the ragged kernel under both of its names (full, and under
     the window), on seeded inputs with the pages BELOW each row's window
     released — their table entries read the scratch page, which holds
     garbage no row may see."""
@@ -1885,34 +1904,28 @@ def window_kernel_parity(cfg, slots: int, window: int, page: int, bb: int,
     crows[-(C // 5):] = 0                           # the chunk's padding
     limits = np.concatenate([np.where(np.arange(B) == pslot, 0, lens),
                              crows]).astype(np.int32)
-    row_map = np.concatenate([np.arange(B), np.full(C, pslot)]
-                             ).astype(np.int32)
+    row_map = slot_rows(B, C, pslot)
     wtab = released.copy()
     wtab[pslot] = np.where(np.arange(MP) < max(off + 1 - W, 0) // page, 0,
                            full[pslot])
     qn = jax.random.normal(keys[3], (B + C, Hq, D), jnp.bfloat16)
     out = {}
-    for name, fn, tab, w, kw in (
-            ("full", pa.ragged_attend_pallas_paged_slots, full, 0, {}),
-            ("window", pa.ragged_attend_pallas_paged_slots_window, wtab, W,
-             {"window": W})):
+    kinds = (("full", pa.ragged_attend_pallas_paged, full, 0),
+             ("window", pa.ragged_attend_pallas_paged_window, wtab, W))
+    for name, fn, tab, w in kinds:
         got = fn(qn, pool["k"], pool["v"], jnp.asarray(limits), layer,
-                 jnp.asarray(tab), jnp.asarray(row_map),
-                 interpret=interpret, bblock=bb, **kw)
+                 jnp.asarray(tab), row_map, interpret=interpret, window=w,
+                 bblock=bb)
         want = jnp.concatenate([
             want_of(qn[:B, None], limits[:B], full, w)[:, 0],
             want_of_chunk(qn[B:], off + 1, full[pslot], w)])
         want = jnp.where((limits > 0)[:, None, None], want, 0)
-        out[name] = close(f"ragged kernel, one table row a slot, {name}",
-                          got, want)
+        out[name] = close(f"ragged kernel, {name}", got, want)
     if not interpret:
         # ONE call of each kind at the served mixed step's shape: a first
         # chunk (every row live from row 0) and a second one (from row
         # ``chunk``, three fifths of it live) beside the decode rows
-        for name, fn, tab, kw in (
-                ("full", pa.ragged_attend_pallas_paged_slots, full, {}),
-                ("window", pa.ragged_attend_pallas_paged_slots_window, wtab,
-                 {"window": W})):
+        for name, fn, tab, w in kinds:
             for what, first, n_live in (("first chunk", 0, C),
                                         ("second chunk", C, 3 * C // 5)):
                 if first + C > window:
@@ -1922,14 +1935,13 @@ def window_kernel_parity(cfg, slots: int, window: int, page: int, bb: int,
                      np.where(np.arange(C) < n_live,
                               first + 1 + np.arange(C), 0)]).astype(np.int32)
                 args = (qn, pool["k"], pool["v"], jnp.asarray(lim), layer,
-                        jnp.asarray(full if first == 0 else tab),
-                        jnp.asarray(row_map))
-                ms = call_ms(lambda: fn(*args, bblock=bb, **kw), 10)
-                say(f"ragged call by slot, {name}, {B}+{C} rows, {what} "
+                        jnp.asarray(full if first == 0 else tab), row_map)
+                ms = call_ms(lambda: fn(*args, window=w, bblock=bb), 10)
+                say(f"ragged call, {name}, {B}+{C} rows, {what} "
                     f"({n_live} live from row {first}): {ms:.3f} ms")
     say(f"kernel parity (window and full kinds, Hq {Hq} Hkv {Hkv}, {B} slots "
         f"+ a {C}-row chunk, window {W} of {window}, bblock {bb}): decode "
-        f"under the window max |diff| {d_dec:.4f}; ragged by slot full "
+        f"under the window max |diff| {d_dec:.4f}; ragged full "
         f"{out['full']:.4f}, under the window {out['window']:.4f}")
 
 
@@ -2293,8 +2305,9 @@ def main() -> int:
         mha = _config.MODEL_REGISTRY["allenai/OLMoE-1B-7B-0125-Instruct"]
         parent = load_kernels(opts.parent) if opts.parent else None
         if cfg.windowed:
-            # (the plain parity packs a table row a packed ROW: 2.4 MB of
-            # SMEM at 48 + 4,096 rows of 144 pages; the default run has it)
+            # (both kinds' kernels under their own names, the window
+            # layers' over a table with released pages; the default run
+            # has the plain parity)
             if opts.rehearse:
                 window_kernel_parity(cfg, 8, 1024, page, 4, 64,
                                      interpret=True)
@@ -2302,8 +2315,8 @@ def main() -> int:
                 window_kernel_parity(cfg, slots, window, page, bb,
                                      eng._chunk_size, interpret=False)
         elif cfg.selects:
-            # (the plain kernels' parity packs a table row a packed ROW,
-            # which a 512-page table does not fit: the default run has it)
+            # (the selecting entries; the default run has the plain
+            # kernels' parity)
             if opts.rehearse:
                 sala_kernel_parity(cfg.scaled(
                     lightning_num_heads=8, lightning_head_dim=128), 8,

@@ -80,6 +80,40 @@ def test_mesh_engine_pallas_interpret_parity(setup):
     assert got == expected
 
 
+def test_mesh_mixed_step_pallas_interpret_parity(setup):
+    """``mixed_step`` under a pure-tp mesh through the kernels (interpret
+    mode): a chunked admission beside a live stream, the table (one row a
+    slot) and the row map operands of its ``shard_map`` like the lengths —
+    token for token the unsharded engine's. (An int8 pool through the map
+    under the mesh: tests/test_paged_kv.py's ``pallas-tp2`` cases.)"""
+    cfg, params, serving = setup
+    serving = dataclasses.replace(
+        serving, attention_impl="pallas", decode_pipeline=1,
+        ragged_attention=1, prefill_chunk=16, decode_horizon=4, page_size=32)
+    rng = np.random.default_rng(5)
+    live, late = (rng.integers(2, cfg.vocab_size, n).tolist()
+                  for n in (4, 21))
+
+    def run(mesh):
+        eng = Engine(cfg, params, serving, mesh=mesh)
+        mixed = []
+        real = eng._mixed_dispatch
+        eng._mixed_dispatch = lambda *a: mixed.append(1) or real(*a)
+        first = eng.submit(Request(prompt_ids=live, max_tokens=30,
+                                   ignore_eos=True))
+        for _ in range(4):
+            eng.step()
+        second = eng.submit(Request(prompt_ids=late, max_tokens=6,
+                                    ignore_eos=True))
+        for _ in range(10000):
+            if not eng.step():
+                break
+        assert len(mixed) >= 2, "the admission never rode a mixed dispatch"
+        return first.generated, second.generated
+
+    assert run(_mesh(1, 2)) == run(None)
+
+
 @pytest.mark.parametrize("kv_dtype", ["auto", "int8"])
 def test_mesh_pool_is_actually_sharded(setup, kv_dtype):
     """The KV pool must be allocated sharded: each device holds 1/(dp*tp)

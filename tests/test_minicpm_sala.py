@@ -821,27 +821,34 @@ def test_aot_plan_sizes_state_and_selector_beside_the_pool():
 # predicate and the V slots nothing fills are zeroed; the ``xla`` programs,
 # "write" and "kda" did not move, and the SELECTING entries' jaxprs are the
 # parent's (tests/test_tpu_compile.py pins them).
+#
+# PR 46 re-took the six ``mixed_step`` hashes, pallas AND xla, and ``ragged``:
+# every model's mixed step hands its attend callback ``table`` (one row a
+# SLOT) and ``row_map`` [B + C] where these three built a table row a packed
+# ROW; the ragged entry takes the pair, the decode rows' writers ``table``
+# itself, the fallback gathers ``table[row_map]``. Every ``decode_steps`` and
+# ``prefill_step`` hash and "decode", "write" and "kda" are the parent's.
 PINNED = {
     ("tiny-olmoe", "decode_steps", "pallas"): "aa98963a5752b0f6",
     ("tiny-olmoe", "decode_steps", "xla"): "24166cb7302bca06",
-    ("tiny-olmoe", "mixed_step", "pallas"): "7e5fc3ce848a8a8f",
-    ("tiny-olmoe", "mixed_step", "xla"): "00bf52f6e08eeefe",
+    ("tiny-olmoe", "mixed_step", "pallas"): "08fec05b72a19584",
+    ("tiny-olmoe", "mixed_step", "xla"): "c6e13478d3763fe2",
     ("tiny-olmoe", "prefill_step", "xla"): "fe74d853601263b8",
     ("tiny-qwen3", "decode_steps", "pallas"): "1e12c12ca574063d",
     ("tiny-qwen3", "decode_steps", "xla"): "979ebf2eee66c834",
-    ("tiny-qwen3", "mixed_step", "pallas"): "6ab323ad4a5fdd4e",
-    ("tiny-qwen3", "mixed_step", "xla"): "f29dc91fe02da895",
+    ("tiny-qwen3", "mixed_step", "pallas"): "53bad8ae3f34e008",
+    ("tiny-qwen3", "mixed_step", "xla"): "87bf59bd4df3281b",
     ("tiny-qwen3", "prefill_step", "xla"): "34d3281612f23ac5",
     ("tiny-solar", "decode_steps", "pallas"): "e46a1ccc1ad8a0bf",
     ("tiny-solar", "decode_steps", "xla"): "fbbaabf7e4d43f6a",
-    ("tiny-solar", "mixed_step", "pallas"): "b906c3af38e7fd75",
-    ("tiny-solar", "mixed_step", "xla"): "391e3d76844cbfa6",
+    ("tiny-solar", "mixed_step", "pallas"): "0e8bf0085994ae6b",
+    ("tiny-solar", "mixed_step", "xla"): "99f92aa1b81146f5",
     ("tiny-solar", "prefill_step", "xla"): "8da44bc738dc28b0",
 }
 # ("ragged" moved with PR 40, whose subject it is: a sharing block keeps its
 # rows on lanes and the share fact is read per block AND per tile; the
 # decode entry, traced by the same body, did not move; both moved with PR 45)
-PINNED_KERNELS = {"decode": "81c80a049add0383", "ragged": "c5ff36111da9ca40",
+PINNED_KERNELS = {"decode": "81c80a049add0383", "ragged": "1f791b720959c9cf",
                   "write": "5ca71686a40fa563", "kda": "9fb3d56211d04454"}
 MODELS = {"tiny-qwen3": tiny_qwen3, "tiny-olmoe": tiny_olmoe,
           "tiny-solar": tiny_solar}
@@ -912,7 +919,7 @@ def kernel_hash(entry):
         f = lambda *a: pa.ragged_attend_pallas_paged(*a, interpret=True,
                                                      bblock=2)
         args = (sds((B, Hq, D), jnp.bfloat16), kv, kv, sds((B,), i32),
-                sds((), i32), sds((B, MP), i32))
+                sds((), i32), sds((B, MP), i32), sds((B,), i32))
     elif entry == "write":
         f = lambda *a: pa.cache_write_row_paged(*a, interpret=True)
         args = (kv, sds((B, Hkv, D), jnp.bfloat16), sds((B,), i32),

@@ -165,8 +165,7 @@ def _mixed_rows(cfg, tree, live_ids, chunk_ids, C=16):
     is_pad = crows >= n
     row_limits = jnp.concatenate([jnp.where(is_p, 0, lengths + 1),
                                   jnp.where(is_pad, 0, crows + 1)])
-    row_tables = jnp.concatenate(
-        [table, jnp.broadcast_to(table[1][None], (C, PPS))])
+    row_map = jnp.concatenate([jnp.arange(3), jnp.full((C,), 1)])
     ptok = np.zeros(C, np.int32)
     ptok[:n] = chunk_ids
     packed = jnp.concatenate(
@@ -175,7 +174,7 @@ def _mixed_rows(cfg, tree, live_ids, chunk_ids, C=16):
     live = jnp.concatenate([jnp.asarray([True, False, False]), ~is_pad])
     attend = make_mixed_attend_carry_paged(
         jnp.where(is_p, -1, lengths), jnp.int32(0), jnp.int32(n), row_limits,
-        row_tables, impl="xla")
+        table, row_map, impl="xla")
     with moe.routed_rows(live) as routing:
         logits, _ = model_forward_carry(tree, cfg, packed[None],
                                         positions[None], pool, attend)

@@ -529,7 +529,7 @@ def test_mixed_step_write_path_matches_row_by_row(first, plen, quant, hkv,
     is_pad = np.arange(C) >= plen
     limits = np.concatenate([np.where(dec_rows < 0, 0, lengths + 1),
                              np.where(is_pad, 0, pstart + np.arange(C) + 1)])
-    tabs = np.concatenate([table, np.repeat(table[pslot:pslot + 1], C, 0)])
+    row_map = np.concatenate([np.arange(nB), np.full(C, pslot)])
     q, k, v = (jax.random.normal(jax.random.PRNGKey(i), (1, nB + C, hkv, D),
                                  jnp.bfloat16) for i in (3, 4, 5))
     mesh = None
@@ -539,11 +539,21 @@ def test_mixed_step_write_path_matches_row_by_row(first, plen, quant, hkv,
         if len(jax.devices()) < 2:
             pytest.skip("needs two devices")
         mesh = make_mesh(MeshConfig(tp=2))
+    operands = (jnp.asarray(dec_rows), jnp.int32(pstart), jnp.int32(plen),
+                jnp.asarray(limits, jnp.int32), jnp.asarray(table),
+                jnp.asarray(row_map, jnp.int32))
     attend = make_mixed_attend_carry_paged(
-        jnp.asarray(dec_rows), jnp.int32(pstart), jnp.int32(plen),
-        jnp.asarray(limits, jnp.int32), jnp.asarray(tabs),
-        impl=impl.split("-")[0], mesh=mesh)
-    _, (got, _) = jax.jit(attend)(q, k, v, (pool, jnp.int32(layer)))
+        *operands, impl=impl.split("-")[0], mesh=mesh)
+    ctx, (got, _) = jax.jit(attend)(q, k, v, (pool, jnp.int32(layer)))
+    if mesh is not None and first:
+        # what the rows then READ through the table and the map, operands
+        # of the shard_map like the rest: a KV head's rows do not depend on
+        # which shard holds them
+        alone, _ = jax.jit(make_mixed_attend_carry_paged(
+            *operands, impl="pallas"))(q, k, v, (pool, jnp.int32(layer)))
+        np.testing.assert_array_equal(np.asarray(ctx, np.float32),
+                                      np.asarray(alone, np.float32))
+        assert not np.asarray(ctx, np.float32)[0][limits == 0].any()
 
     where = [(b, int(dec_rows[b]), table[b]) for b in range(nB)
              if dec_rows[b] >= 0]

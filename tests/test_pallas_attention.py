@@ -78,6 +78,17 @@ def _with_nan_page(pool):
             for n, a in pool.items()}
 
 
+def _slot_rows(tab, rows_of, limits, nan_page):
+    """(table, row_map) of a packed call: ``tab`` [S, max_pages], one row a
+    slot, and ``rows_of`` [N], the slot each packed row reads. A DEAD row
+    keeps a poisoned view: it names one more slot whose table row is all
+    ``nan_page`` (_with_nan_page), so a fetch through it shows."""
+    dead = np.full((1, tab.shape[1]), nan_page, tab.dtype)
+    return (jnp.asarray(np.concatenate([tab, dead])),
+            jnp.asarray(np.where(limits > 0, rows_of, tab.shape[0]),
+                        jnp.int32))
+
+
 @pytest.mark.parametrize("bb", [1, 4, 8])
 @pytest.mark.parametrize("quant", [False, True])
 def test_paged_db_decode_parity(bb, quant):
@@ -245,8 +256,8 @@ def _skip_case(kind, bb):
         rows_of = np.concatenate([np.arange(8), np.full(16, 2)])
         q = jax.random.normal(jax.random.PRNGKey(9), (24, 4, 32))
         out = pa.ragged_attend_pallas_paged(
-            q, pool["k"], pool["v"], limits, jnp.int32(0), table[rows_of],
-            **kw)
+            q, pool["k"], pool["v"], limits, jnp.int32(0), table,
+            jnp.asarray(rows_of, jnp.int32), **kw)
         ref = decode_attend(q[:, None], ck[rows_of], cv[rows_of], limits,
                             window=window)[:, 0]
         # the chunk rows of a sharing block take another arithmetic
@@ -300,6 +311,8 @@ def test_a_per_row_copy_starts_and_waits_under_one_predicate(entry):
     kv = sds((2, 17, Hkv, PS, D), jnp.int8 if "int8" in entry
              else jnp.bfloat16)
     args = (kv, kv, sds((B,), i32), sds((), i32), sds((B, MP), i32))
+    if entry == "ragged":
+        args += (sds((B,), i32),)       # row_map
     pkw = {}
     if "int8" in entry:
         sc = sds((2, 17, Hkv, kvp.scale_lanes(PS)), jnp.float32)
@@ -343,16 +356,15 @@ def test_ragged_mixed_layout_parity(quant, bb, window, B, pstart, plen):
     C, pslot, S, PS, Hq, D = 16 if B == 8 else 20, 2, 128, 32, 4, 32
     dense, pool, table = _paged_layout(B=B, S=S, PS=PS, quant=quant, seed=53)
     tab = np.asarray(table)
-    # one more page, all NaN, that no table names: an out-of-range id clamps
-    # onto it in interpret mode, so a fetch through a dead row's entry shows
+    # one more page, all NaN, that no live row's table names: a fetch
+    # through a dead row's entry shows
+    nan_page = pool["k"].shape[1]
     pool = _with_nan_page(pool)
     lengths = np.asarray([1, 128, 0, 64, 33, 97, 2, 128][:B], np.int32)
     j = np.arange(C)
     limits = np.concatenate([np.where(np.arange(B) == pslot, 0, lengths),
                              np.where(j < plen, pstart + j + 1, 0)])
     rows_of = np.concatenate([np.arange(B), np.full(C, pslot)])
-    row_tables = tab[rows_of].copy()
-    row_tables[limits == 0] = 10_000          # dead rows: never a page id
     N = B + C
     q = jax.random.normal(jax.random.PRNGKey(7), (N, Hq, D))
 
@@ -365,8 +377,8 @@ def test_ragged_mixed_layout_parity(quant, bb, window, B, pstart, plen):
     pkw = dict(pool_ks=pool["ks"], pool_vs=pool["vs"]) if quant else {}
     out = pa.ragged_attend_pallas_paged(
         q, pool["k"], pool["v"], jnp.asarray(limits), jnp.int32(0),
-        jnp.asarray(row_tables), interpret=True, window=window, bblock=bb,
-        **pkw)
+        *_slot_rows(tab, rows_of, limits, nan_page), interpret=True,
+        window=window, bblock=bb, **pkw)
     out, live = np.asarray(out), limits > 0
     assert live.sum() == B - 1 + plen
     tol = 4e-2 if quant else 2e-5
@@ -385,7 +397,7 @@ def test_ragged_mixed_layout_parity(quant, bb, window, B, pstart, plen):
 
 
 def _wide_case(N, B, pstart, plen, *, groups=2, window=0, quant=False,
-               by_slot=False, PS=16, Hkv=2, D=32, seed=59):
+               PS=16, Hkv=2, D=32, seed=59):
     """A mixed step's packed rows — B decode rows (slot 2's dead: it is the
     one chunking), then N - B chunk rows of slot 2 at pstart + j, the first
     ``plen`` of them live — through the ragged entry, and the jnp reference.
@@ -417,20 +429,12 @@ def _wide_case(N, B, pstart, plen, *, groups=2, window=0, quant=False,
             first = pstart + 1 if b == pslot else first
             tab[b, :max(first - window, 0) // PS] = nan_page
     pkw = dict(pool_ks=pool["ks"], pool_vs=pool["vs"]) if quant else {}
-    if by_slot:
-        fn = (pa.ragged_attend_pallas_paged_slots_window if window
-              else pa.ragged_attend_pallas_paged_slots)
-        out = fn(q, pool["k"], pool["v"], jnp.asarray(limits), jnp.int32(0),
-                 jnp.asarray(tab), jnp.asarray(rows_of, jnp.int32),
-                 interpret=True, bblock=8,
-                 **({"window": window} if window else {}))
-    else:
-        row_tables = tab[rows_of].copy()
-        row_tables[limits == 0] = 10_000      # dead rows: never a page id
-        out = pa.ragged_attend_pallas_paged(
-            q, pool["k"], pool["v"], jnp.asarray(limits), jnp.int32(0),
-            jnp.asarray(row_tables), interpret=True, window=window,
-            bblock=8, **pkw)
+    # a list's window layers call the entry under its other trace name
+    fn = pa.ragged_attend_pallas_paged_window if window \
+        else pa.ragged_attend_pallas_paged
+    out = fn(q, pool["k"], pool["v"], jnp.asarray(limits), jnp.int32(0),
+             *_slot_rows(tab, rows_of, limits, nan_page), interpret=True,
+             window=window, bblock=8, **pkw)
     return np.asarray(out), np.asarray(ref), limits
 
 
@@ -450,11 +454,11 @@ _WIDE_GRID = [
     ("n168-t56-groups8", 168, 8, 70, 160, {"groups": 8, "Hkv": 1}),
     ("n120-int8-keeps-blocks-of-8", 120, 8, 37, 100, {"quant": True}),
     ("n120-t40-window", 120, 8, 150, 112, {"window": 48}),
-    ("n120-t40-by-slot", 120, 8, 37, 100, {"by_slot": True}),
-    ("n168-t56-by-slot-window-released", 168, 8, 300, 130,
-     {"by_slot": True, "window": 48, "groups": 8, "Hkv": 1}),
-    ("n168-t56-by-slot-window-one-page", 168, 8, 0, 11,
-     {"by_slot": True, "window": 48}),
+    ("n120-int8-window-released", 120, 8, 150, 100,
+     {"quant": True, "window": 48}),
+    ("n168-t56-window-released", 168, 8, 300, 130,
+     {"window": 48, "groups": 8, "Hkv": 1}),
+    ("n168-t56-window-one-page", 168, 8, 0, 11, {"window": 48}),
 ]
 
 
@@ -487,7 +491,7 @@ def test_tile_rows_come_from_the_shapes():
 
 
 @pytest.mark.parametrize("case", ["n168-t56", "n120-t40-window",
-                                  "n168-t56-by-slot-window-released"])
+                                  "n168-t56-window-released"])
 def test_a_rows_output_is_bitwise_the_same_in_a_tile_of_any_width(
         case, monkeypatch):
     """Tile widths T and 8 (blocks of 8 rows: the path every row took

@@ -549,16 +549,18 @@ def test_start_up_refuses_lora_and_gshard():
 # configuration key these models do not set. The four ``mixed_step`` /
 # ``prefill_step`` hashes were re-taken by PR 35 (the head over the sampled
 # rows: tests/test_minicpm_sala.py says what changed in them), and all six
-# by PR 38 (the sampler's candidates behind one ``cond``: the same file).
+# by PR 38 (the sampler's candidates behind one ``cond``: the same file); the
+# two ``mixed_step`` hashes again by PR 46 (one table row a slot and a row
+# map for every model; the fallback gathers ``table[row_map]``).
 PINNED = {
     ("tiny-qwen3", "decode_steps"):
         "979ebf2eee66c834",
     ("tiny-qwen3", "mixed_step"):
-        "f29dc91fe02da895",
+        "87bf59bd4df3281b",
     ("tiny-olmoe", "decode_steps"):
         "24166cb7302bca06",
     ("tiny-olmoe", "mixed_step"):
-        "00bf52f6e08eeefe",
+        "c6e13478d3763fe2",
     ("tiny-qwen3", "prefill_step"):
         "34d3281612f23ac5",
     ("tiny-olmoe", "prefill_step"):

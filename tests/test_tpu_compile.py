@@ -127,7 +127,7 @@ CASES = [
     ("decode-int8-bb1", "decode", True, 1, B, 1, HQ, HKV),
     ("decode-int8-bb8", "decode", True, 8, B, 1, HQ, HKV),
     # the mixed program's packed layout: every slot plus a full chunk, one
-    # table row per packed row — the shape that outgrew SMEM
+    # table row a SLOT and a row map
     ("ragged-bf16-bb1", "ragged", False, 1, B + CHUNK, 1, HQ, HKV),
     ("ragged-bf16-bb8", "ragged", False, 8, B + CHUNK, 1, HQ, HKV),
     ("ragged-int8-bb8", "ragged", True, 8, B + CHUNK, 1, HQ, HKV),
@@ -162,27 +162,29 @@ def test_paged_attention_kernel_compiles_for_v5e(chip, case, entry, quant,
                                                  bb, rows, R, hq, hkv):
     sds, kv, skw = _pool(chip, quant, hkv)
     lens, lay = sds((rows,), jnp.int32), sds((), jnp.int32)
-    table = sds((rows, 32), jnp.int32)
+    tables = (sds((rows, 32), jnp.int32),)
     if entry == "decode":
         fn, q = pa.decode_attend_pallas_paged, sds((rows, 1, hq, D),
                                                    jnp.bfloat16)
     elif entry == "ragged":
         fn, q = pa.ragged_attend_pallas_paged, sds((rows, hq, D),
                                                    jnp.bfloat16)
+        slots = rows - (512 if case.endswith("solar") else CHUNK)
+        tables = (sds((slots, 32), jnp.int32), lens)    # table, row_map
     else:
         fn, q = pa.decode_attend_pallas_spec_paged, sds((rows, R, hq, D),
                                                         jnp.bfloat16)
-    compiled = _compile(functools.partial(fn, bblock=bb), q, kv, kv, lens,
-                        lay, table, **skw)
+    args = (q, kv, kv, lens, lay) + tables
+    compiled = _compile(functools.partial(fn, bblock=bb), *args, **skw)
     assert "tpu_custom_call" in compiled.as_text()
     _assert_named_after_wrapper(compiled, fn)
     if case in TILES:
         assert _assert_one_call_of_wide_tiles(
-            compiled, fn, functools.partial(fn, bblock=bb), rows, q, kv, kv,
-            lens, lay, table, **skw) == TILES[case]
+            compiled, fn, functools.partial(fn, bblock=bb), rows, *args,
+            **skw) == TILES[case]
     else:       # decode, spec, a block of one row, an int8 pool: blocks
-        assert _pallas_grids(functools.partial(fn, bblock=bb), q, kv, kv,
-                             lens, lay, table, **skw) == [(rows // bb,)]
+        assert _pallas_grids(functools.partial(fn, bblock=bb), *args,
+                             **skw) == [(rows // bb,)]
 
 
 @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
@@ -670,18 +672,18 @@ def test_window_decode_kernel_compiles_for_v5e_under_its_own_name(chip):
 
 @pytest.mark.parametrize("kind", ["full", "window"])
 def test_ragged_kernel_by_slot_compiles_for_v5e(chip, kind):
-    """ONE table row a slot and a row map (a table row a packed row is 2.4
-    MB of SMEM at 48 + 4,096 rows of 144 pages), full and under the
-    window, each under its own name."""
+    """The list's shape (a table row a packed row would be 2.4 MB of SMEM
+    at 48 + 4,096 rows of 144 pages), full and under the window, each under
+    its own name."""
     sds, kv = _trinity_pool(chip, 6913 if kind == "full" else 1698)
     i32, N = jnp.int32, T_B + T_C
     if kind == "full":
-        fn = functools.partial(pa.ragged_attend_pallas_paged_slots, bblock=8)
-        wrapper = pa.ragged_attend_pallas_paged_slots
+        fn = functools.partial(pa.ragged_attend_pallas_paged, bblock=8)
+        wrapper = pa.ragged_attend_pallas_paged
     else:
-        fn = functools.partial(pa.ragged_attend_pallas_paged_slots_window,
+        fn = functools.partial(pa.ragged_attend_pallas_paged_window,
                                bblock=8, window=T_W)
-        wrapper = pa.ragged_attend_pallas_paged_slots_window
+        wrapper = pa.ragged_attend_pallas_paged_window
     args = (sds((N, T_HQ, D), jnp.bfloat16), kv, kv, sds((N,), i32),
             sds((), i32), sds((T_B, T_MP), i32), sds((N,), i32))
     compiled = _compile(fn, *args)
