@@ -147,12 +147,16 @@ class ModelConfig:
     # attn_use_rope says of the "g" layers beside it, its K/V in page
     # leaves and a page inventory of its own (ops/kv_pool.py), "c" a gated
     # short convolution (conv_taps taps over the hidden width; its state
-    # the conv_taps - 1 rows before a span, ops/linear_attention.py). Two
+    # the conv_taps - 1 rows before a span, ops/linear_attention.py), "h" a
+    # block with TWO mixers on one normed input — a Mamba-2 state-space
+    # mixer and the GQA attention, both added to the residual stream before
+    # the FFN — which is an attending layer (a pool leaf) AND a recurrent
+    # one (a per-slot state and a conv tail). Two
     # forms. A PERIOD of "g"/"k" kinds, shorter than the depth, repeats
     # over it ("gkkk"). A LIST — one character a layer, any order,
-    # "g"/"s"/"l"/"c" kinds or "g"/"w" kinds — is the layers held, as they
-    # are ("slllllls", "wwwgwwwg", "ccgcccg..."). "" = every layer the one
-    # kind the fields
+    # "g"/"s"/"l"/"c" kinds, "g"/"w" kinds or "h" alone — is the layers
+    # held, as they are ("slllllls", "wwwgwwwg", "ccgcccg...", "hhhh").
+    # "" = every layer the one kind the fields
     # above describe. A string, not a tuple of enums: the config is a jit
     # static argument and is built from JSON by the benchmark.
     layer_pattern: str = ""
@@ -183,6 +187,29 @@ class ModelConfig:
     # of conv_taps taps over B * X (no bias, no activation), gated by C,
     # then a hidden -> hidden output projection.
     conv_taps: int = 0
+    # "h" layers (falcon_h1: Mamba-2 / SSD, arXiv:2405.21060, beside the
+    # attention): ssm_num_heads heads of ssm_head_dim, a float32 state
+    # [ssm_state_size, ssm_head_dim] a head, B and C shared by the heads of
+    # one of ssm_num_groups groups, a scalar decay a head and token, a
+    # biased SiLU convolution of conv_taps taps over x | B | C, a gated
+    # RMSNorm over each group's channels.
+    ssm_num_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state_size: int = 0
+    ssm_num_groups: int = 0
+    # The falcon_h1 muP multipliers, as published (1.0 / () = none): the
+    # embedding's rows, the logits, the attention's input / output and its
+    # keys, the SSM's input / output, the five segments z | x | B | C | dt
+    # of the SSM's in-projection, the FFN's gate and its output.
+    embedding_multiplier: float = 1.0
+    lm_head_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    ssm_multipliers: tuple = ()
+    mlp_multipliers: tuple = ()
     # "s" layers (InfLLM-v2 block selection, arXiv:2509.24663): keys are
     # mean-pooled over windows of sparse_kernel_size every
     # sparse_kernel_stride tokens; a query scores the pooled keys, a block
@@ -210,6 +237,17 @@ class ModelConfig:
 
     def __post_init__(self):
         pat = self.layer_pattern
+        for name in ("ssm_multipliers", "mlp_multipliers",
+                     "extra_eos_token_ids"):
+            # built from JSON by the benchmark: a list is not hashable
+            object.__setattr__(self, name, tuple(getattr(self, name)))
+        if len(self.ssm_multipliers) not in (0, 5) \
+                or len(self.mlp_multipliers) not in (0, 2):
+            raise ValueError(
+                f"ssm_multipliers={self.ssm_multipliers} names the in-"
+                f"projection's five segments z | x | B | C | dt and "
+                f"mlp_multipliers={self.mlp_multipliers} the FFN's gate and "
+                f"output (or neither is given)")
         if self.num_dense_layers and not (
                 self.num_experts > 0 and len(pat) == self.num_layers):
             raise ValueError(
@@ -217,6 +255,20 @@ class ModelConfig:
                 f"by layer only in a model with experts whose layers are a "
                 f"list (layer_pattern one character a layer)")
         if not pat:
+            return
+        if "h" in pat:
+            if set(pat) != {"h"} or len(pat) != self.num_layers:
+                raise ValueError(
+                    f"layer_pattern={pat!r}: 'h' (state-space + attention) "
+                    f"layers come in a list of their own, one character a "
+                    f"layer held (num_layers={self.num_layers})")
+            H, G = self.ssm_num_heads, self.ssm_num_groups
+            if not (H and self.ssm_head_dim and self.ssm_state_size and G) \
+                    or H % G or self.conv_taps < 2:
+                raise ValueError(
+                    "an 'h' layer needs ssm_num_heads (a whole number of "
+                    "ssm_num_groups), ssm_head_dim, ssm_state_size and "
+                    "conv_taps >= 2")
             return
         if set(pat) <= set("gk"):
             if len(pat) == self.num_layers and "k" not in pat:
@@ -297,19 +349,19 @@ class ModelConfig:
     def layer_list(self) -> bool:
         """The pattern is a LIST of the layers held (not a period)."""
         return bool(self.layer_pattern) and (
-            bool(set(self.layer_pattern) & set("slwc"))
+            bool(set(self.layer_pattern) & set("slwch"))
             or (len(self.layer_pattern) == self.num_layers
                 and "k" not in self.layer_pattern))
 
     @property
     def recurrent(self) -> bool:
         """Some layers keep a recurrent state per sequence beside K/V."""
-        return bool(set(self.layer_pattern) & set("klc"))
+        return bool(set(self.layer_pattern) & set("klch"))
 
     @property
     def recurrent_kinds(self) -> str:
         """The recurrent kinds that are there, for a log line or a refusal."""
-        names = {"k": "KDA", "l": "Lightning", "c": "conv"}
+        names = {"k": "KDA", "l": "Lightning", "c": "conv", "h": "SSM"}
         return "/".join(v for k, v in names.items()
                         if k in self.layer_pattern)
 
@@ -344,13 +396,13 @@ class ModelConfig:
     def num_attn_layers(self) -> int:
         """Layers that attend over K/V: the pool's leading axis."""
         if self.layer_list:
-            return sum(self.layer_pattern.count(c) for c in "gs")
+            return sum(self.layer_pattern.count(c) for c in "gsh")
         return self.num_periods if self.layer_pattern else self.num_layers
 
     @property
     def num_recurrent_layers(self) -> int:
         if self.layer_list:
-            return sum(self.layer_pattern.count(c) for c in "lc")
+            return sum(self.layer_pattern.count(c) for c in "lch")
         return self.num_periods * self.kda_per_period
 
     @property
@@ -409,6 +461,21 @@ class ModelConfig:
     @property
     def kda_size(self) -> int:
         return self.kda_num_heads * self.kda_head_dim
+
+    @property
+    def ssm_size(self) -> int:
+        """The SSM's inner width (``mamba_d_ssm``)."""
+        return self.ssm_num_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_size(self) -> int:
+        """Channels of the SSM's convolution: x | B | C."""
+        return self.ssm_size + 2 * self.ssm_num_groups * self.ssm_state_size
+
+    @property
+    def ssm_in_size(self) -> int:
+        """The in-projection's width: z | x | B | C | dt."""
+        return self.ssm_size + self.ssm_conv_size + self.ssm_num_heads
 
     @property
     def lightning_size(self) -> int:
@@ -764,7 +831,48 @@ LFM2_8B_A1B = ModelConfig(
     hf_repo="LiquidAI/LFM2-8B-A1B",
 )
 
+# tiiuae Falcon-H1-34B-Instruct (``model_type`` falcon_h1), the FIRST of eight
+# pipeline stages: published layers 0-8 as they stand — every layer a Mamba-2
+# mixer (32 heads of 128, 2 groups, state 256, 4 taps) AND 20 query / 4 KV
+# heads of 128 on one normed input, then a SwiGLU of 21,504 — the embedding
+# and the untied 261,120-row head, the twelve muP multipliers as published
+# (benchmark/configs/falcon-h1-34b-pp8.json states the cut and what is
+# assumed).
+FALCON_H1_34B_PP8_STAGE0 = ModelConfig(
+    name="tiiuae/Falcon-H1-34B-Instruct-pp8-stage0",
+    vocab_size=261120,
+    hidden_size=5120,
+    intermediate_size=21504,
+    num_layers=9,
+    num_heads=20,
+    num_kv_heads=4,
+    head_dim=128,
+    max_seq_len=262144,
+    rope_theta=1e11,
+    norm_eps=1e-5,
+    tie_embeddings=False,
+    eos_token_id=11,
+    layer_pattern="hhhhhhhhh",
+    conv_taps=4,
+    ssm_num_heads=32,
+    ssm_head_dim=128,
+    ssm_state_size=256,
+    ssm_num_groups=2,
+    embedding_multiplier=5.656854249492381,
+    lm_head_multiplier=0.0078125,
+    attention_in_multiplier=1.0,
+    attention_out_multiplier=0.0375,
+    key_multiplier=0.011048543456039804,
+    ssm_in_multiplier=0.25,
+    ssm_out_multiplier=0.08838834764831845,
+    ssm_multipliers=(0.3535533905932738, 0.25, 0.1767766952966369, 0.5,
+                     0.3535533905932738),
+    mlp_multipliers=(0.1767766952966369, 0.011160714285714284),
+    hf_repo="tiiuae/Falcon-H1-34B-Instruct",
+)
+
 MODEL_REGISTRY = {
+    "tiiuae/Falcon-H1-34B-Instruct-pp8-stage0": FALCON_H1_34B_PP8_STAGE0,
     "LiquidAI/LFM2-8B-A1B": LFM2_8B_A1B,
     "arcee-ai/Trinity-Mini-pp4-stage0": TRINITY_MINI_PP4_STAGE0,
     "Qwen/Qwen3-0.6B": QWEN3_0_6B,
@@ -1008,6 +1116,45 @@ def tiny_lfm2(**overrides) -> ModelConfig:
         route_norm_eps=1e-6,
         layer_pattern="ccgcccgcccgccgcc",
         conv_taps=3,
+    )
+    base.update(overrides)
+    return ModelConfig(**base)
+
+
+def tiny_falcon_h1(**overrides) -> ModelConfig:
+    """A miniature Falcon-H1-shaped list: every layer a Mamba-2 mixer (4
+    heads of 16 in 2 groups, state 32, 4 taps) AND GQA attention at a query
+    group of 5 on one normed input, an untied head, and all twelve muP
+    multipliers different from 1."""
+    base = dict(
+        name="tiny-falcon-h1",
+        vocab_size=128,
+        hidden_size=64,
+        intermediate_size=96,
+        num_layers=3,
+        num_heads=5,
+        num_kv_heads=1,
+        head_dim=16,
+        max_seq_len=256,
+        rope_theta=1e11,
+        norm_eps=1e-5,
+        tie_embeddings=False,
+        eos_token_id=1,
+        layer_pattern="hhh",
+        conv_taps=4,
+        ssm_num_heads=4,
+        ssm_head_dim=16,
+        ssm_state_size=32,
+        ssm_num_groups=2,
+        embedding_multiplier=5.656854249492381,
+        lm_head_multiplier=0.5,
+        attention_in_multiplier=1.25,
+        attention_out_multiplier=0.6,
+        key_multiplier=0.7,
+        ssm_in_multiplier=0.5,
+        ssm_out_multiplier=0.8,
+        ssm_multipliers=(0.7, 0.5, 0.9, 1.5, 0.8),
+        mlp_multipliers=(0.6, 0.4),
     )
     base.update(overrides)
     return ModelConfig(**base)

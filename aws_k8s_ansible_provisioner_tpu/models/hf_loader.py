@@ -22,6 +22,13 @@ Key-name maps cover the supported families:
   ``k_layernorm``, ``operator_norm`` / ``ffn_norm``, dense
   ``feed_forward.{w1,w2,w3}``, routed ``feed_forward.gate`` /
   ``expert_bias`` / ``experts.N.{w1,w2,w3}``, ``embedding_norm``.
+- Falcon-H1 (``_convert_falcon_h1``; the names are from memory of
+  ``transformers``' falcon_h1 and UNTESTED until a checkpoint is in the
+  repository): ``mamba.in_proj`` / ``mamba.conv1d.weight`` ``[C, 1, K]`` +
+  ``.bias`` / ``mamba.dt_bias`` / ``mamba.A_log`` / ``mamba.D`` /
+  ``mamba.norm`` / ``mamba.out_proj``, ``self_attn.{q,k,v,o}_proj``,
+  ``feed_forward.{gate,up,down}_proj``, ``input_layernorm`` /
+  ``pre_ff_layernorm``, ``final_layernorm``.
 - OPT (pre-norm variants): ``model.decoder.layers.N.self_attn.*_proj``,
   ``self_attn_layer_norm``/``final_layer_norm``, ``fc1/fc2``, learned
   ``embed_positions`` (+2 offset), tied embeddings.
@@ -68,6 +75,8 @@ def convert_state_dict(cfg: ModelConfig, tensors: Dict[str, np.ndarray],
     """Convert a flat HF state dict (torch tensors or numpy) to our pytree."""
     if "c" in cfg.layer_pattern:
         return _convert_lfm2(cfg, tensors, dtype)
+    if "h" in cfg.layer_pattern:
+        return _convert_falcon_h1(cfg, tensors, dtype)
     phi = cfg.parallel_block
     L = cfg.num_layers
 
@@ -258,6 +267,50 @@ def _convert_lfm2(cfg: ModelConfig, tensors: Dict[str, np.ndarray],
     # the selection bias stays float32 (models/quant.py leaves it so too)
     params["layers"]["ffn_moe"]["router"]["bias"] = jnp.asarray(
         stack(routed, "feed_forward.expert_bias"), jnp.float32)
+    return params
+
+
+def _convert_falcon_h1(cfg: ModelConfig, tensors: Dict[str, np.ndarray],
+                       dtype) -> dict:
+    """The Falcon-H1 list: one stack ``par`` of the layers held (a pipeline
+    stage holds the FIRST ``num_layers`` published layers), the state-space
+    mixer's leaves under ``ssm`` (models/layers.init_list_layer_params). The
+    depthwise convolution's ``[C, 1, K]`` weight becomes taps ``[K, C]``, the
+    oldest row's first; A_log, dt_bias and D stay float32."""
+    L = range(cfg.num_layers)
+
+    def stack(name: str, f=lambda m: m) -> np.ndarray:
+        return np.stack([f(_get(tensors, f"model.layers.{i}.{name}"))
+                         for i in L])
+
+    def dense(name: str) -> dict:
+        return {"kernel": stack(name + ".weight", lambda m: m.T)}
+
+    def norm(name: str) -> dict:
+        return {"weight": stack(name + ".weight")}
+
+    par = {"input_norm": norm("input_layernorm"),
+           "post_norm": norm("pre_ff_layernorm"),
+           "wq": dense("self_attn.q_proj"), "wk": dense("self_attn.k_proj"),
+           "wv": dense("self_attn.v_proj"), "wo": dense("self_attn.o_proj"),
+           "w_gate": dense("feed_forward.gate_proj"),
+           "w_up": dense("feed_forward.up_proj"),
+           "w_down": dense("feed_forward.down_proj"),
+           "ssm": {"w_in": dense("mamba.in_proj"),
+                   "conv": {"weight": stack("mamba.conv1d.weight",
+                                            lambda m: m[:, 0, :].T),
+                            "bias": stack("mamba.conv1d.bias")},
+                   "o_norm": norm("mamba.norm"),
+                   "wo": dense("mamba.out_proj")}}
+    params = jax.tree.map(lambda x: jnp.asarray(x, dtype), {
+        "embed": {"weight": _get(tensors, "model.embed_tokens.weight")},
+        "layers": {"par": par},
+        "final_norm": {"weight": _get(tensors,
+                                      "model.final_layernorm.weight")},
+        "lm_head": {"kernel": _get(tensors, "lm_head.weight").T}})
+    for name in ("dt_bias", "A_log", "D"):
+        params["layers"]["par"]["ssm"][name] = jnp.asarray(
+            stack("mamba." + name), jnp.float32)
     return params
 
 

@@ -111,8 +111,11 @@ def rope_cos_sin(positions: jnp.ndarray, rotary_dim: int, theta: float,
     ``cfg`` enables family-specific frequency scaling (``rope_scaling``);
     without it (or with ``rope_scaling == 'none'``) this is plain RoPE.
     """
+    # float(): a config built from JSON gives an int, and one past int32
+    # (1e11) does not parse as a weakly typed operand
     inv_freq = 1.0 / (
-        theta ** (jnp.arange(0, rotary_dim, 2, dtype=jnp.float32) / rotary_dim)
+        float(theta) ** (jnp.arange(0, rotary_dim, 2, dtype=jnp.float32)
+                         / rotary_dim)
     )
     if cfg is not None and cfg.rope_scaling == "llama3":
         inv_freq = _llama3_scale_inv_freq(inv_freq, cfg)
@@ -296,8 +299,10 @@ def init_list_layer_params(cfg: ModelConfig, key: jax.Array, dtype) -> dict:
     """Stacked params of a model whose layer kinds are a LIST: one sub-tree
     a kind, ``attn`` leaves ``[n_a, ...]`` (the "g" / "s" / "w" layers in
     order; selecting and the window have no parameter of their own),
-    ``lightning`` leaves ``[n_l, ...]`` and ``conv`` leaves ``[n_c, ...]``
-    (the gated short convolutions) — what a run of one kind scans over
+    ``lightning`` leaves ``[n_l, ...]``, ``conv`` leaves ``[n_c, ...]``
+    (the gated short convolutions) and ``par`` leaves ``[n_h, ...]`` (the
+    blocks with two mixers: the attention's leaves and, under ``ssm``, the
+    state-space mixer's) — what a run of one kind scans over
     (:func:`_list_forward_carry`). Every FFN is the dense gated MLP, in its
     layer's sub-tree — but in a model with experts the FFN differs by LAYER
     and the two kinds are stacks of their own: ``ffn_dense`` ``[n_d, ...]``
@@ -357,6 +362,34 @@ def init_list_layer_params(cfg: ModelConfig, key: jax.Array, dtype) -> dict:
             "conv": {"weight": _dense_init(ks[1], (n, cfg.conv_taps, H),
                                            dtype, 0.5)},
             "wo": dense(ks[2], n, H, H), **own_ffn(ks[5:8], n)}
+    n = cfg.layer_pattern.count("h")
+    if n:
+        ks = jax.random.split(jax.random.fold_in(key, 4), 16)
+        Hs, C = cfg.ssm_num_heads, cfg.ssm_conv_size
+        # the family's init, as for KDA: A uniform on [1, 16], dt_bias the
+        # inverse softplus of a step log-uniform on [1e-3, 1e-1]
+        dt = jnp.exp(jax.random.uniform(ks[10], (n, Hs), jnp.float32,
+                                        jnp.log(1e-3), jnp.log(1e-1)))
+        out["par"] = {
+            "input_norm": norm(n), "post_norm": norm(n),
+            "wq": dense(ks[0], n, H, cfg.q_size),
+            "wk": dense(ks[1], n, H, cfg.kv_size),
+            "wv": dense(ks[2], n, H, cfg.kv_size),
+            "wo": dense(ks[3], n, cfg.q_size, H),
+            # the state-space mixer's leaves, under a key of their own: its
+            # out-projection is a ``wo`` too
+            "ssm": {
+                "w_in": dense(ks[4], n, H, cfg.ssm_in_size),
+                "conv": {"weight": _dense_init(ks[5], (n, cfg.conv_taps, C),
+                                               dtype, 0.5),
+                         "bias": _dense_init(ks[6], (n, C), dtype, 0.1)},
+                "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+                "A_log": jnp.log(jax.random.uniform(
+                    ks[9], (n, Hs), jnp.float32, 1.0, 16.0)),
+                "D": jnp.ones((n, Hs), jnp.float32),
+                "o_norm": norm(n, cfg.ssm_size),
+                "wo": dense(ks[7], n, cfg.ssm_size, H)},
+            **own_ffn(ks[11:14], n)}
     if cfg.num_experts > 0:
         kd, km = jax.random.split(jax.random.fold_in(key, 2))
         nd = cfg.num_dense_layers
@@ -535,6 +568,12 @@ def _mlp(cfg: ModelConfig, h: jnp.ndarray, p: dict) -> jnp.ndarray:
         if cfg.gated_mlp:  # SwiGLU (Qwen/Llama) / GeGLU (Gemma)
             gate_act = jax.nn.silu if cfg.act == "silu" \
                 else partial(jax.nn.gelu, approximate=True)  # "gelu_tanh"
+            if cfg.mlp_multipliers:     # muP: the gate's input, the output
+                m_gate, m_down = (jnp.asarray(m, h.dtype)
+                                  for m in cfg.mlp_multipliers)
+                return _linear(
+                    gate_act(_linear(h, p["w_gate"]) * m_gate)
+                    * _linear(h, p["w_up"]), p["w_down"]) * m_down
             return _linear(
                 gate_act(_linear(h, p["w_gate"])) * _linear(h, p["w_up"]),
                 p["w_down"])
@@ -686,6 +725,82 @@ def conv_block(cfg: ModelConfig, p: dict, x: jnp.ndarray, recur, rec_l: Any,
     return _add_ffn(cfg, x, h2, p if ffn is None else ffn), rec
 
 
+def ssm_in_scale(cfg: ModelConfig) -> jnp.ndarray:
+    """What the state-space mixer's in-projection is multiplied by, a number
+    a column [ssm_in_size] float32: ``ssm_in_multiplier`` (on the normed
+    input: a scalar, so it commutes with the matmul) times the segment's
+    entry of ``ssm_multipliers`` over z | x | B | C | dt."""
+    GN = cfg.ssm_num_groups * cfg.ssm_state_size
+    m = cfg.ssm_multipliers or (1.0,) * 5
+    widths = (cfg.ssm_size, cfg.ssm_size, GN, GN, cfg.ssm_num_heads)
+    return cfg.ssm_in_multiplier * jnp.concatenate(
+        [jnp.full((w,), v, jnp.float32) for w, v in zip(widths, m)])
+
+
+def gated_group_norm(cfg: ModelConfig, y: jnp.ndarray, z: jnp.ndarray,
+                     weight: jnp.ndarray) -> jnp.ndarray:
+    """The state-space mixer's output norm: the gate FIRST (``y * SiLU(z)``,
+    ``norm_before_gate`` false), then an RMSNorm over the channels of each
+    of the ``ssm_num_groups`` groups. y, z: [..., ssm_size] float32."""
+    G = cfg.ssm_num_groups
+    v = (y * jax.nn.silu(z)).reshape(y.shape[:-1] + (G, -1))
+    v = v * jax.lax.rsqrt(jnp.mean(v * v, axis=-1, keepdims=True)
+                          + cfg.norm_eps)
+    return v.reshape(y.shape) * weight.astype(jnp.float32)
+
+
+def two_mixer_block(cfg: ModelConfig, p: dict, x: jnp.ndarray,
+                    cos: jnp.ndarray, sin: jnp.ndarray, attend: AttendFn,
+                    cache_l: Any, recur, rec_l: Any
+                    ) -> Tuple[jnp.ndarray, Any, Any]:
+    """One block with TWO mixers on one normed input (falcon_h1): a Mamba-2
+    state-space mixer (``p["ssm"]``; ``recur.ssm`` runs its convolution and
+    recurrence over the per-slot state ``rec_l`` names, ops/
+    linear_attention.py) and the GQA attention (``attend`` over ``cache_l``),
+    each times its muP multipliers, BOTH added to the residual stream in one
+    add, then the FFN. Returns (x, the attend's cache, rec)."""
+    B, T, _ = x.shape
+    s = p["ssm"]
+    Hs, P = cfg.ssm_num_heads, cfg.ssm_head_dim
+    with jax.named_scope(parts.NORM):
+        h = apply_norm(cfg, x, p["input_norm"])
+    with jax.named_scope(parts.ATTN_PROJ):
+        u = _linear(h, s["w_in"]).astype(jnp.float32) * ssm_in_scale(cfg)
+        z, xbc, dt = jnp.split(
+            u, [cfg.ssm_size, cfg.ssm_size + cfg.ssm_conv_size], axis=-1)
+        # the step size a head and token (>= 0); the decay is exp(dt A)
+        dt = jax.nn.softplus(dt + s["dt_bias"].astype(jnp.float32))
+        A = -jnp.exp(s["A_log"].astype(jnp.float32))
+        ha = h if cfg.attention_in_multiplier == 1.0 \
+            else h * jnp.asarray(cfg.attention_in_multiplier, h.dtype)
+        q = _linear(ha, p["wq"]).reshape(B, T, cfg.num_heads, cfg.head_dim)
+        k = _linear(ha, p["wk"])
+        if cfg.key_multiplier != 1.0:
+            k = k * jnp.asarray(cfg.key_multiplier, k.dtype)
+        k = k.reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
+        v = _linear(ha, p["wv"]).reshape(B, T, cfg.num_kv_heads,
+                                         cfg.head_dim)
+        q = apply_rope(q, cos, sin, cfg.head_dim)
+        k = apply_rope(k, cos, sin, cfg.head_dim)
+    with jax.named_scope(parts.RECUR):
+        y, rec = recur.ssm(cfg, s["conv"], xbc, dt, A,
+                           s["D"].astype(jnp.float32), rec_l)
+        y = gated_group_norm(cfg, y.reshape(B, T, Hs * P), z,
+                             s["o_norm"]["weight"]).astype(x.dtype)
+    with jax.named_scope(parts.ATTN_CORE):
+        ctx, cache_l = attend(q, k, v, cache_l)
+        ctx = ctx.reshape(B, T, cfg.q_size)
+    with jax.named_scope(parts.ATTN_OUT):
+        out = _linear(y, s["wo"]) * jnp.asarray(cfg.ssm_out_multiplier,
+                                                x.dtype) \
+            + _linear(ctx, p["wo"]) * jnp.asarray(
+                cfg.attention_out_multiplier, x.dtype)
+        x = x + out
+    with jax.named_scope(parts.NORM):
+        h2 = apply_norm(cfg, x, p["post_norm"])
+    return _add_ffn(cfg, x, h2, p), cache_l, rec
+
+
 def kda_block(cfg: ModelConfig, p: dict, x: jnp.ndarray, recur,
               rec_l: Any) -> Tuple[jnp.ndarray, Any]:
     """One KDA linear-attention block (ops/linear_attention.py has the
@@ -741,6 +856,8 @@ def _embed_inputs(params: dict, cfg: ModelConfig, tokens: jnp.ndarray,
             x = x * jnp.asarray(cfg.hidden_size ** 0.5, x.dtype)
         if cfg.scale_emb != 1.0:    # MiniCPM's muP
             x = x * jnp.asarray(cfg.scale_emb, x.dtype)
+        if cfg.embedding_multiplier != 1.0:     # falcon_h1's
+            x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
         if cfg.pos_embed == "learned":
             # OPT: absolute learned positions, +2 offset; no rotary tables
             # needed (dummy cos/sin keep the scan signature uniform).
@@ -782,7 +899,11 @@ def _final_logits(params: dict, cfg: ModelConfig, x: jnp.ndarray,
                 return ((x @ emb["weight"].T.astype(x.dtype))
                         * emb["scale"]).astype(x.dtype)
             return x @ emb["weight"].T
-        return _linear(x, params["lm_head"])
+        logits = _linear(x, params["lm_head"])
+        if cfg.lm_head_multiplier != 1.0:
+            logits = logits * jnp.asarray(cfg.lm_head_multiplier,
+                                          logits.dtype)
+        return logits
 
 
 def model_forward(
@@ -955,7 +1076,9 @@ def _hybrid_forward_carry(params, cfg: ModelConfig, tokens, positions, cache,
 def layer_plan(cfg: ModelConfig):
     """The list as RUNS of equal (kind, FFN): ``(kind, FFN stack or None,
     first, cache, ffn, length)`` — ``first`` the run's first layer among
-    the layers of its PARAMS stack (``lightning``, ``conv``, or ``attn`` for
+    the layers of its PARAMS stack (``lightning``, ``conv``, ``par`` for
+    the two-mixer blocks, which come in a list of their own: one index
+    names a layer's params, its pool leaf and its state, or ``attn`` for
     the "g" / "s" / "w" kinds together), ``cache`` among the layers that
     share its CACHE leaves (the Lightning state; the conv tails; the pool's
     ``k`` / ``v`` for "g" / "s"; its ``wk`` / ``wv`` for "w"), ``ffn`` in
@@ -965,7 +1088,7 @@ def layer_plan(cfg: ModelConfig):
     the published 32 layers ("wwwg" x 8, two dense) eighteen."""
     plan, seen = [], {}
     for i, kind in enumerate(cfg.layer_pattern):
-        stack = {"l": "lightning", "c": "conv"}.get(kind, "attn")
+        stack = {"l": "lightning", "c": "conv", "h": "par"}.get(kind, "attn")
         leaves = stack if kind in "lc" else "win" if kind == "w" else "pool"
         ffn = None if cfg.num_experts <= 0 else \
             "ffn_dense" if i < cfg.num_dense_layers else "ffn_moe"
@@ -1070,6 +1193,10 @@ def _list_forward_carry(params, cfg: ModelConfig, tokens, positions, cache,
         elif kind == "c":
             x, rec = conv_block(cfg, layer("conv", i), x, recur, (rec, at),
                                 ffn=None if ffn is None else layer(*ffn))
+        elif kind == "h":
+            x, (pool, _), rec = two_mixer_block(
+                cfg, layer("par", i), x, cos, sin, attend, (pool, at), recur,
+                (rec, at))
         else:
             x, (pool, _) = decoder_block(
                 cfg, layer("attn", i), x, cos, sin,

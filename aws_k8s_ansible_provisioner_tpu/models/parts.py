@@ -17,19 +17,23 @@ names the work (a selecting layer's ``select`` inside its ``attn.core``).
 - ``attn.proj``: wq / wk / wv, the output gate's ``wg``, q/k norm and RoPE —
   and a recurrent layer's input projections (KDA's low-rank decay, gate and
   step-size projections with their activations; a gated short
-  convolution's one ``w_in``): the same weight stream
+  convolution's one ``w_in``; a state-space mixer's ``w_in`` with its muP
+  column scale, its step size's softplus and log-decay): the same weight
+  stream
   through the same ``_linear``, dequantisation inside;
 - ``attn.core``: the ``attend`` callback — the kernel calls and what
   surrounds them (the length order's gathers, the row and chunk writes, the
   XLA attention of the non-pallas paths);
-- ``attn.out``: the gate's multiply, wo, and the residual add behind it;
+- ``attn.out``: the gate's multiply, wo, and the residual add behind it (a
+  block with two mixers: both out-projections and their one add);
 - ``mlp``: the dense SwiGLU / GELU FFN, a shared expert, the residual add;
 - ``router``: scores, bias, top-k, the sort, the group sizes and the
   routing counts a step program asks for;
 - ``experts``: the grouped / every-expert matmuls and their combine;
-- ``recur``: KDA, Lightning and the gated short convolution — the
-  convolution (its gates, taps and tail rows), the state update and the
-  output norm (what touches the per-slot state);
+- ``recur``: KDA, Lightning, the gated short convolution and the
+  state-space mixer — the convolution (its gates, taps, bias and tail
+  rows), the state update with its ``D`` skip and the (gated) output norm
+  (what touches the per-slot state);
 - ``select``: a selecting layer's run add, pooled-key scores, the rank
   count, the page lists and bitmasks, the page tally;
 - ``head``: the sampled rows' gather, final norm, muP scale, the vocabulary
@@ -67,7 +71,7 @@ _LEAF_PART = {
     "wo": ATTN_OUT,
     "w_gate": MLP, "w_up": MLP, "w_down": MLP, "shared": MLP,
     "router": ROUTER,
-    "conv": RECUR, "o_norm": RECUR,
+    "conv": RECUR, "o_norm": RECUR, "D": RECUR,
     "final_norm": HEAD, "lm_head": HEAD,
 }
 _EXPERT_STACKS = ("w_gate", "w_up", "w_down")

@@ -47,7 +47,10 @@ from aws_k8s_ansible_provisioner_tpu.config import ModelConfig
 # model with a layer pattern), a kernel is [..., in, out]: the contraction
 # axis is its second-to-last. ``wg`` is the attention gate, ``shared`` the
 # shared expert's sub-tree, ``w_in`` / ``wo`` a gated short convolution's
-# two projections (its taps stay in the model dtype). A KDA layer's low-rank decay/gate projections,
+# two projections (its taps stay in the model dtype), ``ssm`` a state-space
+# mixer's sub-tree (its in- and out-projection, ``w_in`` / ``wo``, go to
+# int8; taps and their bias, A_log, dt_bias, D and the gated norm stay in the
+# model dtype). A KDA layer's low-rank decay/gate projections,
 # step-size projection, convolution taps, A_log and dt_bias stay in the
 # model dtype (a few MB a layer, and the decay is precision-critical), like
 # the router and its bias.
@@ -86,8 +89,9 @@ def _quantize_layers(layers: dict, kern) -> dict:
             p["kernel"], p["scale"] = kern(p["kernel"],
                                            in_axis=p["kernel"].ndim - 2)
             out[key] = p
-    if "shared" in out:
-        out["shared"] = _quantize_layers(out["shared"], kern)
+    for sub in ("shared", "ssm"):
+        if sub in out:
+            out[sub] = _quantize_layers(out[sub], kern)
     return out
 
 

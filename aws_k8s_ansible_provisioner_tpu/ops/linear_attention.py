@@ -41,6 +41,20 @@ w_j z_(t-K+1+j)`` (no activation), output ``C * c`` — whose only state is
 the ``K - 1`` rows of ``z`` before a span, the leaf ``conv_tail`` float32
 ``[n_c, slots, K - 1, hidden]``.
 
+A Mamba-2 state-space mixer (SSD, arXiv:2405.21060; the "h" layers) is the
+scalar-decay form below with a state that is NOT square and a decay that
+depends on the token: per head ``S`` [d_state, d_head] float32,
+
+    S_t = exp(dt_t A) S_{t-1} + B_t (dt_t x_t)^T      y_t = S_t^T C_t + D x_t
+
+— Lightning's step with ``k = B``, ``q = C`` (a group's, shared by its
+heads), ``v = x``, ``g = dt A`` and ``beta = dt`` (:func:`ssd_step`,
+:func:`ssd_span`, :func:`ssd_scan`), behind a biased SiLU convolution over
+x | B | C whose tail is the leaf ``ssm_conv`` float32 ``[n, slots, K - 1,
+channels]``; the state is ``ssm_state`` float32 ``[n, 1, slots, H, d_state,
+d_head]`` — d_head (128) on the lanes, so the decode kernel streams whole
+rows.
+
 State layout, beside the paged pool in the same ``cache`` pytree the step
 programs donate: ``kda_state`` float32 ``[P, n_k, slots, H, d_k, d_v]`` and
 ``kda_conv`` ``[P, n_k, slots, K - 1, 3 H d_k]`` (P periods, n_k KDA layers
@@ -87,12 +101,20 @@ def _state_shapes(cfg: ModelConfig, num_slots: int, dtype) -> dict:
         # numbers the rows before it held inside their own span
         out["conv_tail"] = ((cfg.layer_pattern.count("c"), num_slots,
                              cfg.conv_taps - 1, cfg.hidden_size), jnp.float32)
+    if "h" in cfg.layer_pattern:
+        n = cfg.layer_pattern.count("h")
+        out["ssm_state"] = ((n, 1, num_slots, cfg.ssm_num_heads,
+                             cfg.ssm_state_size, cfg.ssm_head_dim),
+                            jnp.float32)
+        out["ssm_conv"] = ((n, num_slots, cfg.conv_taps - 1,
+                            cfg.ssm_conv_size), jnp.float32)
     return out
 
 
 def init_state(cfg: ModelConfig, num_slots: int, dtype=jnp.bfloat16) -> dict:
     """The per-slot leaves of a model with recurrent layers: two for KDA
-    layers, one for Lightning layers, one for gated short convolutions."""
+    layers, one for Lightning layers, one for gated short convolutions, two
+    for state-space mixers."""
     return {name: jnp.zeros(shape, dt) for name, (shape, dt)
             in _state_shapes(cfg, num_slots, dtype).items()}
 
@@ -105,7 +127,7 @@ def state_bytes(cfg: ModelConfig, num_slots: int, dtype=jnp.bfloat16) -> int:
 
 
 def is_state(name: str) -> bool:
-    return name.startswith(("kda_", "lin_", "conv_"))
+    return name.startswith(("kda_", "lin_", "conv_", "ssm_"))
 
 
 # ---------------------------------------------------------------------------
@@ -427,8 +449,14 @@ def make_recur_decode(live=None):
         o, rec = _conv_rows(rec_l, taps, bcx[:, 0], live)
         return o[:, None], rec
 
+    def ssm(cfg, conv_p, xbc, dt, A, D, rec_l):
+        o, rec = _ssm_rows(cfg, rec_l, conv_p, xbc[:, 0], dt[:, 0], A, D,
+                           live)
+        return o[:, None], rec
+
     recur.lightning = lightning
     recur.conv = conv
+    recur.ssm = ssm
     return recur
 
 
@@ -446,6 +474,8 @@ def make_recur_span(slot, start, n_valid):
                                         n_valid=n)
     recur.conv = functools.partial(_conv_span, slots=slots, fresh=fresh,
                                    n_valid=n)
+    recur.ssm = functools.partial(_ssm_span, slots=slots, fresh=fresh,
+                                  n_valid=n)
     return recur
 
 
@@ -461,6 +491,8 @@ def make_recur_batch(slots, true_lens):
                                         n_valid=true_lens)
     recur.conv = functools.partial(_conv_span, slots=slots, fresh=fresh,
                                    n_valid=true_lens)
+    recur.ssm = functools.partial(_ssm_span, slots=slots, fresh=fresh,
+                                  n_valid=true_lens)
     return recur
 
 
@@ -492,8 +524,16 @@ def make_recur_mixed(B: int, live, pslot, pstart, plen):
         oc, rec = span.conv(taps, bcx[:, B:], (rec,) + tuple(rec_l[1:]))
         return jnp.concatenate([od[None], oc], axis=1), rec
 
+    def ssm(cfg, conv_p, xbc, dt, A, D, rec_l):
+        od, rec = _ssm_rows(cfg, rec_l, conv_p, xbc[0, :B], dt[0, :B], A, D,
+                            live)
+        oc, rec = span.ssm(cfg, conv_p, xbc[:, B:], dt[:, B:], A, D,
+                           (rec,) + tuple(rec_l[1:]))
+        return jnp.concatenate([od[None], oc], axis=1), rec
+
     recur.lightning = lightning
     recur.conv = conv
+    recur.ssm = ssm
     return recur
 
 
@@ -521,6 +561,16 @@ def _lightning_from_zero(q, k, v, slopes, rec_l):
 
 recur_from_zero.lightning = _lightning_from_zero
 recur_from_zero.conv = _conv_from_zero
+
+
+def _ssm_from_zero(cfg, conv_p, xbc, dt, A, D, rec_l):
+    window = jnp.pad(xbc.astype(jnp.float32),
+                     [(0, 0), (conv_p["weight"].shape[0] - 1, 0), (0, 0)])
+    return _ssm_rows_of_spans(cfg, conv_p, window, dt, A, D, None)[0], \
+        rec_l[0]
+
+
+recur_from_zero.ssm = _ssm_from_zero
 
 
 # ---------------------------------------------------------------------------
@@ -640,6 +690,129 @@ def _lin_rows(rec_l, q, k, v, slopes, live):
 
 
 # ---------------------------------------------------------------------------
+# The Mamba-2 state-space mixer ("h" layers): the scalar-decay forms above
+# with k = B, q = C (a group's rows, repeated over its heads), v = x, g = dt A
+# and beta = dt, plus the D skip. ``recur.ssm(cfg, conv {weight [K, C], bias
+# [C]}, xbc [B, T, C] — x | B | C before the convolution —, dt [B, T, H]
+# float32 (after the softplus), A [H] (< 0), D [H], (rec, i))`` -> (y [B, T,
+# H, d_head] float32, rec); ``i`` (traced) the layer's index among the "h"
+# layers. A row that carries no token comes out with dt = 0 (so g = 0): the
+# identity on the state, and it leaves no convolution row.
+# ---------------------------------------------------------------------------
+
+
+def _ssd(form, S, x, Bm, Cm, dt, A, D, **kw):
+    """``form`` — a scalar-decay step, span or scan, ``(S, q, k, v, g, beta)
+    -> (o, S)`` — over Mamba-2 rows: a group's B and C rows [..., G, N]
+    repeated over its H / G heads as k and q, and the D skip on top."""
+    def per_head(a):
+        return jnp.repeat(a, x.shape[-2] // a.shape[-2], axis=-2)
+
+    o, S = form(S, per_head(Cm), per_head(Bm), x, dt * A, dt, **kw)
+    return o + D[:, None] * x, S
+
+
+def ssd_step(S, x, Bm, Cm, dt, A, D):
+    """One token a row. S: [B, H, N, P] float32; x: [B, H, P]; Bm, Cm:
+    [B, G, N]; dt: [B, H] (>= 0; 0 = a dead row); A (< 0), D: [H]. Returns
+    (y [B, H, P], S_new)."""
+    return _ssd(lightning_step, S, x, Bm, Cm, dt, A, D)
+
+
+def ssd_span(S0, x, Bm, Cm, dt, A, D, block: int = 0):
+    """T rows of N sequences in blocks (:func:`lightning_span`: the pairwise
+    decay from the DIFFERENCE of the running sums of ``dt A``). x: [N, T, H,
+    P]; Bm, Cm: [N, T, G, N]; dt: [N, T, H]; T a multiple of the block
+    (``LIN_BLOCK`` rows; the published ``mamba_chunk_size`` is a hint for it,
+    any block gives the same numbers)."""
+    return _ssd(lightning_span, S0, x, Bm, Cm, dt, A, D,
+                block=block or LIN_BLOCK)
+
+
+def ssd_scan(S0, x, Bm, Cm, dt, A, D):
+    """The same rows token by token: what the span form is tested against."""
+    return _ssd(lightning_scan, S0, x, Bm, Cm, dt, A, D)
+
+
+def _ssm_conv(conv_p, window):
+    """SiLU(the biased short convolution) of ``window`` [..., K - 1 + T, C]:
+    x | B | C as the recurrence takes them, float32 [..., T, C]."""
+    return jax.nn.silu(short_conv(window, conv_p["weight"])
+                       + conv_p["bias"].astype(jnp.float32))
+
+
+def _ssm_split(cfg: ModelConfig, xbc):
+    """x [..., H, P], B [..., G, N], C [..., G, N] of convolved rows."""
+    H, P, G, N = (cfg.ssm_num_heads, cfg.ssm_head_dim, cfg.ssm_num_groups,
+                  cfg.ssm_state_size)
+    x, Bm, Cm = jnp.split(xbc, [H * P, H * P + G * N], axis=-1)
+    lead = xbc.shape[:-1]
+    return (x.reshape(lead + (H, P)), Bm.reshape(lead + (G, N)),
+            Cm.reshape(lead + (G, N)))
+
+
+def _ssm_rows_of_spans(cfg, conv_p, window, dt, A, D, S0):
+    """Spans whose rows stand behind their K - 1 rows of history in
+    ``window`` [N, K - 1 + T, C], from state ``S0`` (None = zeros): (y [N,
+    T, H, P], S after the last row)."""
+    N, T = dt.shape[:2]
+    rows = _ssm_split(cfg, _ssm_conv(conv_p, window))
+    if S0 is None:
+        S0 = jnp.zeros((N, cfg.ssm_num_heads, cfg.ssm_state_size,
+                        cfg.ssm_head_dim), jnp.float32)
+    y, S = ssd_span(S0, *_pad_to(LIN_BLOCK, rows + (dt,), T), A, D)
+    return y[:, :T], S
+
+
+def _ssm_span(cfg, conv_p, xbc, dt, A, D, rec_l, *, slots, fresh, n_valid):
+    """N spans [N, T, C] into slots ``slots`` [N] (see :func:`_span`)."""
+    rec, i = rec_l
+    T = xbc.shape[1]
+    rd = jnp.clip(slots, 0, rec["ssm_state"].shape[2] - 1)
+    S0 = jnp.where(fresh[:, None, None, None], 0.0,
+                   rec["ssm_state"][i, 0, rd])
+    window = _span_window(rec["ssm_conv"], (i, rd), fresh,
+                          xbc.astype(jnp.float32))
+    live = (jnp.arange(T)[None] < n_valid[:, None])[..., None]   # [N, T, 1]
+    y, S = _ssm_rows_of_spans(cfg, conv_p, window, jnp.where(live, dt, 0.0),
+                              A, D, S0)
+    tail = _span_tail(window, n_valid, conv_p["weight"].shape[0])
+    rec = _layer_set(rec, "ssm_state", i, 0, S, slots)
+    return y, {**rec, "ssm_conv": rec["ssm_conv"].at[i, slots].set(
+        tail, mode="drop")}
+
+
+def _ssm_rows(cfg, rec_l, conv_p, xbc, dt, A, D, live):
+    """One token for every slot (row b = slot b); see :func:`_rows`."""
+    rec, i = rec_l
+    arr = rec["ssm_conv"]
+    hist = jax.lax.dynamic_index_in_dim(arr, i, 0, keepdims=False)
+    window = _rows_window(hist, xbc)
+    x, Bm, Cm = _ssm_split(cfg, _ssm_conv(conv_p, window)[:, 0])
+    arr = jax.lax.dynamic_update_slice(
+        arr, _rows_tail(window[:, 1:], hist, live)[None], (i, 0, 0, 0))
+    if live is not None:
+        dt = jnp.where(live[:, None], dt, 0.0)
+    from aws_k8s_ansible_provisioner_tpu.ops import pallas_attention
+
+    if pallas_attention.supported():
+        # the KDA kernel without its delta rule, over [d_state, d_head]
+        # tiles: one pass, in place on the leaf
+        def in_place(state, q, k, v, g, beta):
+            return kda_decode_update(
+                state, i, 0, q, k, v, jnp.broadcast_to(g[..., None], q.shape),
+                beta, delta_rule=False)
+
+        y, state = _ssd(in_place, rec["ssm_state"], x, Bm, Cm, dt, A, D)
+        rec = {**rec, "ssm_state": state}
+    else:
+        y, S = ssd_step(_layer_get(rec, "ssm_state", i, 0), x, Bm, Cm, dt,
+                        A, D)
+        rec = _layer_set(rec, "ssm_state", i, 0, S)
+    return y, {**rec, "ssm_conv": arr}
+
+
+# ---------------------------------------------------------------------------
 # The decode update as ONE pass over the state (TPU)
 # ---------------------------------------------------------------------------
 
@@ -650,10 +823,12 @@ HEADS_PER_STEP = 8      # heads a grid step: 8 x [d, d] float32 tiles in VMEM
 def kda_decode_update(state, period, j: int, q, k, v, g, beta,
                       interpret: bool = False, delta_rule: bool = True):
     """:func:`kda_step` for every slot of one layer, IN PLACE on the full
-    state leaf: ``state`` [P, n_k, B, H, d, d] float32 is read once and
+    state leaf: ``state`` [P, n_k, B, H, d_k, d_v] float32 (d_k = d_v = d
+    for KDA and Lightning; [d_state, d_head] for a state-space mixer) is
+    read once and
     written once (``input_output_aliases``), where the XLA form reads it
     twice (a reduce fusion for the two products with ``S'``, then the
-    update fusion). q, k, g: [B, H, d]; v: [B, H, d]; beta: [B, H] (a dead
+    update fusion). q, k, g: [B, H, d_k]; v: [B, H, d_v]; beta: [B, H] (a dead
     row comes with g = 0, beta = 0: the identity). Returns (o [B, H, d]
     float32, state). ``delta_rule`` False is the Lightning layers' step on
     the same tiles: ``S_t = diag(exp(g)) S + beta k v^T`` with no correction
@@ -666,7 +841,7 @@ def kda_decode_update(state, period, j: int, q, k, v, g, beta,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    P, nk, B, H, d, _ = state.shape
+    P, nk, B, H, d, dv = state.shape
     hb = HEADS_PER_STEP if H % HEADS_PER_STEP == 0 else H
     nh = H // hb
 
@@ -707,21 +882,21 @@ def kda_decode_update(state, period, j: int, q, k, v, g, beta,
         in_specs=[pl.BlockSpec((1, 1, d, hb), col_map),
                   pl.BlockSpec((1, 1, d, hb), col_map),
                   pl.BlockSpec((1, 1, d, hb), col_map),
-                  pl.BlockSpec((1, hb, d), row_map),
-                  pl.BlockSpec((1, hb, d), row_map),
-                  pl.BlockSpec((1, 1, 1, hb, d, d), st_map)],
-        out_specs=[pl.BlockSpec((1, hb, d), row_map),
-                   pl.BlockSpec((1, 1, 1, hb, d, d), st_map)])
+                  pl.BlockSpec((1, hb, dv), row_map),
+                  pl.BlockSpec((1, hb, dv), row_map),
+                  pl.BlockSpec((1, 1, 1, hb, d, dv), st_map)],
+        out_specs=[pl.BlockSpec((1, hb, dv), row_map),
+                   pl.BlockSpec((1, 1, 1, hb, d, dv), st_map)])
     f32 = jnp.float32
     o, state = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((B, H, d), f32),
+        out_shape=[jax.ShapeDtypeStruct((B, H, dv), f32),
                    jax.ShapeDtypeStruct(state.shape, state.dtype)],
         # the state: operand 6, after the scalars and the five row operands
         input_output_aliases={6: 1},
         interpret=interpret,
     )(idx, cols(q.astype(f32)), cols(k.astype(f32)),
       cols(jnp.exp(g.astype(f32))), v.astype(f32),
-      jnp.broadcast_to(beta.astype(f32)[..., None], (B, H, d)),
+      jnp.broadcast_to(beta.astype(f32)[..., None], (B, H, dv)),
       state)
     return o, state

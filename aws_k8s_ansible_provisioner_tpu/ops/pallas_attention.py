@@ -932,18 +932,36 @@ def ragged_attend_pallas_paged(q: jnp.ndarray, pool_k: jnp.ndarray,
     (resolved to the largest divisor of N) is the width of the blocks that
     stream a page per row.
     """
-    N = q.shape[0]
+    N, hq, d = q.shape
     bb = _resolve_bb(bblock, N)
     row_map = row_map.astype(jnp.int32)
     row_limits = row_limits.astype(jnp.int32)
+    # A tile's blocks are SLICES of its [rows, Hq, D] queries in VMEM, and
+    # Mosaic slices whole sublane tiles: past one tile of 8 heads, where Hq is
+    # no multiple of 8 (20 heads in groups of 5: ``memref<64x24x128> ->
+    # 8x20x128`` is refused), every KV head's group gets dead query heads of
+    # zeros, the fewest that make it one (5 -> 6), and their outputs are
+    # dropped. Read off the shapes: up to 8 heads and at every multiple of 8
+    # (every group served before) the call is what it was.
+    hkv = pool_k.shape[2]
+    groups = padded = hq // hkv
+    while hq > 8 and (hkv * padded) % 8:
+        padded += 1
+    if padded != groups:
+        q = jnp.pad(q.reshape(N, hkv, groups, d),
+                    ((0, 0), (0, 0), (0, padded - groups), (0, 0))
+                    ).reshape(N, hkv * padded, d)
     # a block, or a tile, whose live rows all name one slot shares it
     share, wide = _share_facts(q, pool_k, row_limits, row_map, bb)
-    return _paged_flash_db(
+    out = _paged_flash_db(
         q, pool_k, pool_v, row_limits,
         jnp.asarray(layer, jnp.int32).reshape(1), table.astype(jnp.int32),
         bb=bb, R=1, spec=False, window=window, interpret=interpret,
         pool_ks=pool_ks, pool_vs=pool_vs, share=share, wide=wide,
         row_map=row_map)
+    if padded != groups:
+        out = out.reshape(N, hkv, padded, d)[:, :, :groups].reshape(N, hq, d)
+    return out
 
 
 # -- the entry points of a list that holds window AND full layers -------------

@@ -319,6 +319,9 @@ class Engine(EnginePrograms):
         if "conv_tail" in self.cache:
             self.metrics.conv_state_bytes.set(
                 self.cache["conv_tail"].nbytes)
+        if "ssm_state" in self.cache:
+            self.metrics.ssm_state_bytes.set(
+                self.cache["ssm_state"].nbytes + self.cache["ssm_conv"].nbytes)
         if cfg.selects:
             self.metrics.selector_cache_bytes.set(self.selector_bytes)
         # AOT manifest summary (serving/aot.py), installed by

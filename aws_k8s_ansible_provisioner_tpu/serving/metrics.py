@@ -336,7 +336,8 @@ class EngineMetrics:
         self.state_rows = r.register(Counter(
             "tpu_serve_state_rows_total",
             "Rows that advanced a recurrent state, per layer, by the kind "
-            "of layer that keeps it (KDA, Lightning, conv) and step program",
+            "of layer that keeps it (KDA, Lightning, conv, SSM) and step "
+            "program",
             ("kind", "program")))
         self.recurrent_state_bytes = r.register(Gauge(
             "tpu_serve_recurrent_state_bytes",
@@ -360,6 +361,15 @@ class EngineMetrics:
             "Bytes of the gated short convolutions' per-slot tails (the "
             "conv_taps - 1 rows before a span, float32) held beside the KV "
             "pool"))
+        self.ssm_state_bytes = r.register(Gauge(
+            "tpu_serve_ssm_state_bytes",
+            "Bytes of the state-space mixers' per-slot leaves (the float32 "
+            "[heads, d_state, d_head] state and the conv tail) held beside "
+            "the KV pool"))
+        self.ssm_span_rows = r.register(Counter(
+            "tpu_serve_ssm_span_rows_total",
+            "Chunk rows a mixed step ran through the state-space mixers' "
+            "span form, per layer"))
         self.prefix_lookups_skipped = r.register(Counter(
             "tpu_serve_prefix_lookups_skipped_total",
             "Admissions that did not consult the prefix index, by reason",

@@ -1386,6 +1386,16 @@ class EnginePrograms:
                 "KV pool's %d", tail.nbytes, *tail.shape,
                 tail.nbytes // (tail.shape[0] * tail.shape[1]),
                 kvp.pool_bytes(cfg, total_pages, ps, dtype, self.kv_quant))
+        if "ssm_state" in self.cache:
+            state, tail = self.cache["ssm_state"], self.cache["ssm_conv"]
+            logging.getLogger(__name__).info(
+                "cache: SSM state %d bytes (%d layers x %d slots x %d heads "
+                "x [%d, %d], float32: %d bytes a slot and layer) + conv "
+                "tails %d bytes (%d rows of %d, float32) beside the KV "
+                "pool's %d", state.nbytes, state.shape[0], *state.shape[2:],
+                state.nbytes // (state.shape[0] * state.shape[2]),
+                tail.nbytes, *tail.shape[2:],
+                kvp.pool_bytes(cfg, total_pages, ps, dtype, self.kv_quant))
         if cfg.windowed:
             import logging
 
@@ -1605,20 +1615,26 @@ class EnginePrograms:
             return {name: self._decode_operands()["wtable"]}
         return {name: self._donatable(self.wtable[rows])}
 
-    def _kda_rows(self, rows: int, slots: int = 0) -> dict:
+    def _kda_rows(self, rows: int, slots: int = 0, span: int = 0) -> dict:
         """Dispatch-record fields of a model with recurrent layers:
         ``state_rows`` (rows that advance a state in this dispatch, per
         layer: horizon x active for a decode dispatch), ``state_slots``
         (slots whose state a decode or mixed dispatch reads and writes) and
-        ``state_kind`` (KDA, Lightning, conv); a model with KDA layers carries
-        the first two as ``kda_rows`` / ``kda_slots`` too, the names its readers
-        know."""
+        ``state_kind`` (KDA, Lightning, conv, SSM); a model with KDA layers
+        carries the first two as ``kda_rows`` / ``kda_slots`` too, the names
+        its readers know; one with state-space mixers ``ssm_slots`` (the
+        live rows its decode update streams), and of a mixed step's chunk
+        ``ssm_span_rows`` (``span``) in ``ssm_span_blocks`` blocks of the
+        span form."""
         if not self.cfg.recurrent:
             return {}
         out = {"state_rows": int(rows), "state_slots": int(slots),
                "state_kind": self.cfg.recurrent_kinds}
         if "k" in self.cfg.layer_pattern:
             out.update(kda_rows=int(rows), kda_slots=int(slots))
+        if "h" in self.cfg.layer_pattern:
+            out.update(ssm_slots=int(slots), ssm_span_rows=int(span),
+                       ssm_span_blocks=-(-int(span) // _la.LIN_BLOCK))
         return out
 
     def _attn_pages(self, horizon: int, carry_steps: int) -> dict:
@@ -1855,6 +1871,8 @@ class EnginePrograms:
             self.metrics.state_rows.inc(rec["state_rows"],
                                         kind=rec["state_kind"],
                                         program=rec["program"])
+        if rec.get("ssm_span_rows"):
+            self.metrics.ssm_span_rows.inc(rec["ssm_span_rows"])
         if "sparse_pages_live" in rec:
             self.metrics.sparse_pages.inc(rec["sparse_pages_live"],
                                           kind="live")
@@ -2556,7 +2574,8 @@ class EnginePrograms:
             sample_rows=int((self.temps > 0).sum()
                             + (req.temperature > 0)),
             carry_steps=prev["horizon"] if prev is not None else 0,
-            **self._kda_rows(len(active) + len(chunk), len(active)),
+            **self._kda_rows(len(active) + len(chunk), len(active),
+                             span=len(chunk)),
             **self._attn_layers(),
             **self._chunk_page_steps(st["C"], off, len(chunk)))
         self._book_bubble(drec["t_enqueue"])

@@ -1681,6 +1681,13 @@ def build_state(serving_cfg=None, model_cfg=None, params=None,
 
             model_cfg = tiny_lfm2(vocab_size=tokenizer.vocab_size,
                                   eos_token_id=tokenizer.eos_token_id)
+        elif serving.model == "tiny-falcon-h1":
+            # the dry-run list of blocks with two mixers ("h": a state-space
+            # mixer beside GQA attention at a query group of 5)
+            from aws_k8s_ansible_provisioner_tpu.config import tiny_falcon_h1
+
+            model_cfg = tiny_falcon_h1(vocab_size=tokenizer.vocab_size,
+                                       eos_token_id=tokenizer.eos_token_id)
         else:
             raise ValueError(f"unknown model {serving.model!r} and no checkpoint")
 

@@ -2164,6 +2164,14 @@ def main() -> int:
             # (128 slots of a tiny model are slow on the CPU: the long
             # background stream would outlast its client)
             rehearse_flags = {"--max-decode-slots": "8"}
+        elif "h" in _config.MODEL_REGISTRY[model].layer_pattern:
+            # the list of blocks with two mixers: a state-space mixer beside
+            # GQA attention at a query group of 5
+            model = "rehearse-falcon-h1"
+            _config.MODEL_REGISTRY[model] = _config.tiny_falcon_h1(
+                name=model, intermediate_size=256, head_dim=32,
+                ssm_head_dim=32, **tiny)
+            rehearse_flags = {"--max-decode-slots": "8"}
         elif _config.MODEL_REGISTRY[model].layer_pattern:
             # the hybrid: gated NoPE GQA + KDA layers, an expert share
             model = "rehearse-solar"
@@ -2271,18 +2279,20 @@ def main() -> int:
             check_list_routing_cause(cfg, eng.params, plain,
                                      96 if opts.rehearse else 512,
                                      strict=not opts.rehearse)
-        if "c" in cfg.layer_pattern:
+        if set(cfg.layer_pattern) & set("ch"):
             # m700: two chunks of mixed_step, the second from a carried
-            # tail; every control of the reference is shown, and the ones
-            # the limits are known to see (PERF.md section 6, PR 42) have
-            # to be refused
+            # tail (and, for a state-space mixer, a carried state); every
+            # control of the reference is shown, and the ones the limits
+            # are known to see at that length (PERF.md section 6, PRs 42
+            # and 48) have to be refused
             refused = check_controls(
                 "m700", got["m700"], cfg, eng.params, tokenizer, plain,
                 {**plain.CONTROLS, **plain.CONTROLS_REPORTED})
-            check(opts.rehearse or all(refused[c] for c in plain.CONTROLS
-                                       if c in plain.CONTROLS_SEEN_LONG),
+            check(opts.rehearse or all(refused[c]
+                                       for c in plain.CONTROLS_SEEN_LONG),
                   f"the comparison passes a reference without a mechanism: "
                   f"{refused}")
+        if "c" in cfg.layer_pattern:
             check_list_routing_cause(cfg, eng.params, plain,
                                      96 if opts.rehearse else 512,
                                      strict=not opts.rehearse,

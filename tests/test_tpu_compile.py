@@ -824,3 +824,44 @@ def test_lfm2_decode_program_streams_its_expert_stacks_as_they_lie(
                          for a in m[2].split(",")]
         hbm |= {t.split("{")[0] for t in seen if t and "S(" not in t}
     assert hbm and hbm <= {"f32[18,128,2,2048]", "bf16[18,3,2048]"}, hbm
+
+
+@pytest.mark.parametrize("program", ["decode_fused_h8", "mixed_c512"])
+def test_falcon_h1_stage_compiles_at_a_query_group_of_five(
+        chip, monkeypatch, program):
+    """The Falcon-H1 stage's served ``decode_steps`` and ``mixed_step``
+    (int8, 64 slots, block 8, a 512-row chunk) compile for the chip: 20
+    query heads on 4 KV heads through the paged decode kernel as it is, the
+    ragged entry with every group padded to 6 (a tile's blocks are slices
+    of its [rows, Hq, D] queries and Mosaic slices whole sublane tiles: at
+    20 heads it refused ``memref<64x24x128> -> 8x20x128``), the decode
+    update over [256, 128] state tiles under the KDA kernel's name — beside
+    11.4 GB of operands with temporaries of a few hundred MB."""
+    from aws_k8s_ansible_provisioner_tpu.config import (MODEL_REGISTRY,
+                                                        ServingConfig)
+    from aws_k8s_ansible_provisioner_tpu.ops import linear_attention as la
+    from aws_k8s_ansible_provisioner_tpu.serving import aot
+
+    monkeypatch.setattr(pa, "supported", lambda: True)
+    cfg = MODEL_REGISTRY["tiiuae/Falcon-H1-34B-Instruct-pp8-stage0"]
+    plan = aot.ProgramPlan(cfg, ServingConfig(
+        model=cfg.name, max_decode_slots=64, max_cache_len=2048,
+        weights_dtype="int8", decode_bblock=8, kv_host_tier_bytes=0,
+        prefill_chunk=512, prefill_buckets=(256, 512)))
+    params, cache = aot._abstract_state(plan, None,
+                                        next(iter(chip.device_set)))
+    assert cache["k"].shape == (9, 2049, 4, 64, 128)
+    assert cache["ssm_state"].shape == (9, 1, 64, 32, 256, 128)
+    assert cache["ssm_conv"].shape == (9, 64, 3, 5120)
+    _, fn, args, kwargs = next(
+        p for p in aot.enumerate_programs(plan, None, params, cache,
+                                          bblock=8)
+        if p[0] == program)
+    compiled = fn.lower(*args, **kwargs).compile()
+    mem = compiled.memory_analysis()
+    assert 11.3e9 < mem.argument_size_in_bytes < 11.6e9
+    assert mem.temp_size_in_bytes < 768 * 2**20
+    _assert_named_after_wrapper(compiled, la.kda_decode_update)
+    _assert_named_after_wrapper(
+        compiled, pa.decode_attend_pallas_paged if program.startswith("dec")
+        else pa.ragged_attend_pallas_paged)
