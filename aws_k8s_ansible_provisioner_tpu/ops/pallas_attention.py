@@ -118,8 +118,9 @@ def _per_pair(vals, shape, hkv: int, groups: int, tiled: bool):
 
 
 def _paged_db_body(lengths_ref, layer_ref, table_ref, share_ref, wide_ref,
-                   rowmap_ref, sel_ref, cnt_ref, bits_ref, q_ref,
-                   k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf,
+                   rowmap_ref, sel_ref, cnt_ref, bits_ref, bitsat_ref, q_ref,
+                   lanebits_ref, k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref, k_buf,
+                   v_buf, ks_buf,
                    vs_buf, acc_ref, m_ref, l_ref, sem, acc_t, m_t, l_t,
                    wide_state,
                    *, ps: int, groups: int, scale: float, R: int, bb: int,
@@ -161,12 +162,12 @@ def _paged_db_body(lengths_ref, layer_ref, table_ref, share_ref, wide_ref,
     flash update with the block as one query tile per KV head — the chunk
     rows of one prefill are the case; each row still masks to its own limit
     and window. The tile's state keeps its rows on lanes
-    (``rows_on_lanes``); under an int8 pool or a page selection it is the
-    [bb*groups]-row tile of ``shared``.
+    (``rows_on_lanes``); a BLOCK's under an int8 pool or a page selection
+    is the [bb*groups]-row tile of ``shared``.
 
     ``tile`` > bb with ``wide_ref`` and ``wide_state`` (the ragged entries
-    over a bf16 pool that select nothing; elsewhere ``tile`` == bb, both
-    None and all of this compiled out): a grid step holds ``tile`` rows,
+    over a bf16 pool; elsewhere ``tile`` == bb, both None and all of this
+    compiled out): a grid step holds ``tile`` rows,
     ``tile // bb`` blocks, and ``wide_ref`` says per step what they are —
     the row whose table every live row of the STEP shares (then the step is
     ONE sharing tile of ``tile`` rows: page c is fetched once for all of
@@ -189,14 +190,25 @@ def _paged_db_body(lengths_ref, layer_ref, table_ref, share_ref, wide_ref,
       different pages) — so a row past the dense length costs ``topk``
       page steps whatever its context; a list shorter than the block's
       longest re-copies its last page under a mask (the older rule).
-    - ``bits_ref`` (the ragged entry): a BITMASK over logical pages. The
-      walk is the plain one over [lo_min, hi_max]; at page c a row's limit
-      for a KV head is its own where the bit is set and 0 where it is not,
-      and a page no row of the block selects skips its flash update. The
-      chunk rows of a prefill share one page stream a block (the sharing
-      path), each row and head masking what it did not choose. Blocks of
-      ``bb`` rows only: a page that none of 8 rows chose is common, one
-      that none of 56 chose is not.
+    - a BITMASK over logical pages (the ragged entry), read in two
+      layouts. The walk is the plain one over [lo_min, hi_max] and every
+      routed page of a row is read under the row's own limit.
+      ``lanebits_ref`` (VMEM, blocked a grid step: [1, Hkv, W, tile *
+      groups] int32, word ``c // 32`` of each (row, KV head) spread over
+      the lanes its query rows take in ``rows_on_lanes``) serves a SHARING
+      TILE — the chunk rows of a prefill: page c is live for a lane where
+      bit ``c % 32`` of its word is set, one more term of the column mask,
+      a vector shift and compare a page step; no scalar is read and no
+      page skips its update (a page that none of 24 rows x 2 heads chose
+      is rare, and past the dense length a third of the pages are forced).
+      ``bits_ref`` (SMEM, flat [rows, Hkv, W]) serves the BLOCKS of a step
+      whose rows read several tables (the decode rows) and every block of
+      a call that tiles no wider: at page c a row's limit for a KV head is
+      its own where the bit is set and 0 where it is not (``chosen``:
+      bb x Hkv scalar reads and an iota-select chain a page step), and a
+      page no row of the block selects skips its flash update. With
+      ``wide_ref`` the words in SMEM are those of the steps that read them
+      only, ``bitsat_ref[g]`` naming step g's first row there.
 
     ``rowmap_ref`` (the ragged entries; None for a decode entry, whose row
     i reads table row i): packed row -> row of ``table_ref``, so the chunk
@@ -325,9 +337,9 @@ def _paged_db_body(lengths_ref, layer_ref, table_ref, share_ref, wide_ref,
         l[:] = jnp.zeros_like(l)
 
     def rows_on_lanes(row, q_ref, lens, lo_min, hi_max):
-        """All live rows read table row ``row`` (a bf16 or float32 pool, no
-        selection): ONE copy a page step into buffer row 0 for a tile of
-        ``len(lens)`` rows, whatever their number. The flash state is kept
+        """All live rows read table row ``row`` (a bf16 or float32 pool):
+        ONE copy a page step into buffer row 0 for a tile of ``len(lens)``
+        rows, whatever their number. The flash state is kept
         TRANSPOSED — a page's keys on sublanes, the tile's rows * groups
         query rows on lanes: logits [Hkv, page, n], context [Hkv, D, n] —
         so m, l and the column masks are lane vectors of n entries (not n
@@ -338,8 +350,10 @@ def _paged_db_body(lengths_ref, layer_ref, table_ref, share_ref, wide_ref,
         products (a bf16 pair's is exact in float32) in one pass, where a
         float32 pair takes six. Per row the arithmetic is one order of
         pages, one order of keys within a page, float32 throughout: a
-        row's result does not depend on the width of its tile. Returns
-        [rows, Hq, D], dead rows zero."""
+        row's result does not depend on the width of its tile. Under a
+        page selection (``lanebits_ref``, a whole grid step's tile) a
+        lane's columns of page c are live only where its word has bit c.
+        Returns [rows, Hq, D], dead rows zero."""
         rows = len(lens)
         n = rows * groups
         acc, m, l = (acc_t, m_t, l_t) if rows == bb else wide_state
@@ -363,6 +377,9 @@ def _paged_db_body(lengths_ref, layer_ref, table_ref, share_ref, wide_ref,
             live_col = col < limit
             if window > 0:
                 live_col &= col >= limit - window
+            if lanebits_ref is not None:
+                word = lanebits_ref[0, :, pl.ds(c // 32, 1), :]
+                live_col &= ((word >> (c % 32)) & 1) > 0      # [Hkv, 1, n]
             s = jnp.where(live_col, s, NEG_INF)
             m_prev, l_prev = m[:], l[:]                       # [Hkv, 1, n]
             m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -382,9 +399,10 @@ def _paged_db_body(lengths_ref, layer_ref, table_ref, share_ref, wide_ref,
         return jnp.swapaxes(ctx, 1, 2).reshape(hkv, rows, groups, d) \
             .transpose(1, 0, 2, 3).reshape(rows, hq, d)
 
-    def block(blk, q_ref, o_ref):
+    def block(blk, q_ref, o_ref, bits_row=None):
         """Rows [blk * bb, (blk + 1) * bb): a block of a call that tiles no
-        wider, or one of a step whose rows read several tables."""
+        wider, or one of a step whose rows read several tables (then
+        ``bits_row`` is its first row among the bit words held in SMEM)."""
         # BB per-row SCALARS (see _per_slot: a stacked scalar vector
         # reshaped to [BB, 1, 1] is a shape cast Mosaic refuses)
         lens = [lengths_ref[blk * bb + i] for i in range(bb)]
@@ -408,8 +426,10 @@ def _paged_db_body(lengths_ref, layer_ref, table_ref, share_ref, wide_ref,
         def chosen(c):
             """Per (row, KV head): is page c in its selection (bits
             form)."""
-            nw = bits_ref.shape[0] // (lengths_ref.shape[0] * hkv)
-            return [(bits_ref[((blk * bb + i) * hkv + h) * nw + c // 32]
+            first = blk * bb if bits_row is None else bits_row
+            nw = bits_ref.shape[0] // (lengths_ref.shape[0] * hkv) \
+                if lanebits_ref is None else lanebits_ref.shape[2]
+            return [(bits_ref[((first + i) * hkv + h) * nw + c // 32]
                      >> (c % 32)) & 1 for i, h in pairs]
         lo, lo_min = first_pages(lens, alive)
 
@@ -621,7 +641,8 @@ def _paged_db_body(lengths_ref, layer_ref, table_ref, share_ref, wide_ref,
     def _block_by_block():
         def one(j, carry):
             rows = pl.ds(pl.multiple_of(j * bb, bb), bb)
-            block(g * (tile // bb) + j, q_ref.at[rows], o_ref.at[rows])
+            block(g * (tile // bb) + j, q_ref.at[rows], o_ref.at[rows],
+                  None if bitsat_ref is None else bitsat_ref[g] + j * bb)
             return carry
 
         jax.lax.fori_loop(0, tile // bb, one, 0)
@@ -648,7 +669,10 @@ def _paged_db_kernel(*refs, quant: bool, share: bool, wide: bool = False,
     rowmap_ref, = take(1, rowmap)
     sel_ref, cnt_ref = take(2, sel)
     bits_ref, = take(1, bits)
-    q_ref, k_hbm, v_hbm = take(3)
+    bitsat_ref, = take(1, bits and wide)
+    q_ref, = take(1)
+    lanebits_ref, = take(1, bits and wide)
+    k_hbm, v_hbm = take(2)
     ks_hbm, vs_hbm = take(2, quant)
     o_ref, k_buf, v_buf = take(3)
     ks_buf, vs_buf = take(2, quant)
@@ -656,10 +680,10 @@ def _paged_db_kernel(*refs, quant: bool, share: bool, wide: bool = False,
     acc_t, m_t, l_t = take(3, share)
     wide_state = take(3, wide)
     _paged_db_body(lengths_ref, layer_ref, table_ref, share_ref, wide_ref,
-                   rowmap_ref, sel_ref, cnt_ref, bits_ref, q_ref,
-                   k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf,
-                   vs_buf, acc_ref, m_ref, l_ref, sem, acc_t, m_t, l_t,
-                   wide_state, **kw)
+                   rowmap_ref, sel_ref, cnt_ref, bits_ref, bitsat_ref, q_ref,
+                   lanebits_ref, k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref, k_buf,
+                   v_buf, ks_buf, vs_buf, acc_ref, m_ref, l_ref, sem, acc_t,
+                   m_t, l_t, wide_state, **kw)
 
 
 def _resolve_bb(bblock, B: int) -> int:
@@ -686,11 +710,14 @@ def _tile_rows(N: int, bb: int, hq: int, d: int, ps: int, dtype) -> int:
     """Packed rows a grid step of a ragged call holds: the largest multiple
     of ``bb`` that divides N, is no wider than TILE_ROWS and keeps a wide
     tile's working set inside TILE_VMEM_BYTES (2,080 rows of 16 heads ->
-    40; 2,064 -> 48; 2,072 and 4,144 -> 56); ``bb`` where none is wider,
-    where blocks are one row (nothing is shared) and under an int8 pool
-    (its scales ride a page's lanes: the blocks of ``shared``). From the
-    call's shapes and the pool's ``dtype`` alone — under a ``tp`` mesh the
-    shard's."""
+    40; 2,064 -> 48; 2,072 and 4,144 -> 56; the selecting model's 4,632 =
+    8 x 3 x 193 -> 24, its only multiple of 8 past 8); ``bb`` where none is
+    wider, where blocks are one row (nothing is shared) and under an int8
+    pool (its scales ride a page's lanes: the blocks of ``shared``). From
+    the call's shapes and the pool's ``dtype`` alone — under a ``tp`` mesh
+    the shard's. The plain and the selecting ragged entries cut their grid
+    steps by it alike (a selecting tile's lane words, 2 x Hkv x W x rows x
+    groups x 4 bytes in the pipeline's buffers, are 0.1 MiB at 24 rows)."""
     dtype = jnp.dtype(dtype)
     if bb == 1 or dtype == jnp.int8:
         return bb
@@ -732,7 +759,8 @@ def _share_facts(q, pool_k, row_limits, keys, bb: int):
 def _paged_flash_db(q2, pool_k, pool_v, lengths, layer_arr, table,
                     *, bb: int, R: int, spec: bool, window: int,
                     interpret: bool, pool_ks, pool_vs, share=None,
-                    wide=None, row_map=None, sel=None, cnt=None, bits=None):
+                    wide=None, row_map=None, sel=None, cnt=None, bits=None,
+                    bits_at=None, lanebits=None):
     """Build + dispatch the double-buffered paged flash call.
 
     q2: [B, R*Hq, D] (R=1 for plain decode). Grid is (B // bb,); the pools
@@ -746,7 +774,11 @@ def _paged_flash_db(q2, pool_k, pool_v, lengths, layer_arr, table,
     ``row_map`` [B]: the row of ``table`` each packed row reads (None: its
     own). ``sel`` [B, Hkv, K] with ``cnt`` [B, Hkv], or ``bits``
     [B, Hkv, ceil(pages / 32)] int32: the pages each row and KV head
-    reads, as a list or as a mask (bf16 pool, no window).
+    reads, as a list or as a mask (bf16 pool, no window). With ``wide`` the
+    mask comes twice: ``lanebits`` [B // tile, Hkv, W, tile * groups], a
+    step's words on the lanes of its sharing tile (VMEM-blocked), and
+    ``bits`` [rows, Hkv, W] for the steps whose blocks run one by one
+    only, ``bits_at`` [B // tile] naming each such step's first row in it.
     """
     B, RHq, D = q2.shape
     Hkv, ps = pool_k.shape[2], pool_k.shape[3]
@@ -757,6 +789,10 @@ def _paged_flash_db(q2, pool_k, pool_v, lengths, layer_arr, table,
 
     in_specs = [pl.BlockSpec(memory_space=pltpu.ANY)] * 2    # the pools
     operands = [q2, pool_k, pool_v]
+    if lanebits is not None:
+        in_specs.insert(0, pl.BlockSpec((1,) + lanebits.shape[1:],
+                                        lambda g, *prefetched: (g, 0, 0, 0)))
+        operands.insert(1, lanebits)
     if quant:
         in_specs += [pl.BlockSpec(memory_space=pltpu.ANY)] * 2
         operands += [pool_ks, pool_vs]
@@ -795,14 +831,21 @@ def _paged_flash_db(q2, pool_k, pool_v, lengths, layer_arr, table,
         prefetch += [sel.reshape(-1), cnt.reshape(-1)]
     if bits is not None:
         prefetch.append(bits.reshape(-1))
-    if share is not None and (quant or bits is not None):
-        scratch += [                       # the sharing blocks' flash state
-            pltpu.VMEM((Hkv, bb * groups, D), jnp.float32),
-            pltpu.VMEM((Hkv, bb * groups, 128), jnp.float32),
-            pltpu.VMEM((Hkv, bb * groups, 128), jnp.float32),
-        ]
-    elif share is not None:     # a block's and a tile's, rows on lanes
-        for rows in (bb,) + ((tile,) if tile > bb else ()):
+    if bits_at is not None:
+        prefetch.append(bits_at)
+    if share is not None:
+        # the sharing blocks' flash state: rows on sublanes under scales or
+        # a selection (``shared``), else on lanes like a wide tile's
+        lanes = (tile,) if tile > bb else ()
+        if quant or bits is not None:
+            scratch += [
+                pltpu.VMEM((Hkv, bb * groups, D), jnp.float32),
+                pltpu.VMEM((Hkv, bb * groups, 128), jnp.float32),
+                pltpu.VMEM((Hkv, bb * groups, 128), jnp.float32),
+            ]
+        else:
+            lanes = (bb,) + lanes
+        for rows in lanes:
             scratch += [
                 pltpu.VMEM((Hkv, D, rows * groups), jnp.float32),
                 pltpu.VMEM((Hkv, 1, rows * groups), jnp.float32),
@@ -1029,52 +1072,70 @@ def ragged_attend_pallas_paged_select(q, pool_k, pool_v, row_limits, layer,
                                       bblock: int = 1):
     """:func:`ragged_attend_pallas_paged` under a page SELECTION a row and
     KV head: ``bits`` [N, Hkv, W] int32, bit p % 32 of word p // 32 set
-    where the row's head reads logical page p. Every live page of a block
-    is still walked (a page nobody chose skips its update); what a row did
-    not choose is masked. ``table`` [S, max_pages] holds ONE row a slot and
-    ``row_map`` [N] names each packed row's — the chunk rows of a prefill
-    share an entry, which is also how a sharing block is recognised.
+    where the row's head reads logical page p. Every live page of a grid
+    step is still walked; what a row did not choose is masked. ``table``
+    [S, max_pages] holds ONE row a slot and ``row_map`` [N] names each
+    packed row's — the chunk rows of a prefill share an entry, which is
+    also how a sharing tile is recognised, as in the plain entry: a grid
+    step is a TILE of :func:`_tile_rows` rows (24 of 24 + 4,608), one that
+    shares is one page stream with the selection as a mask over its lanes
+    (``lanebits``, laid out here once a call), one whose live rows name
+    several slots (the decode rows) runs its ``bblock``-row blocks with
+    their words in SMEM. A slot's live rows are CONSECUTIVE packed rows
+    (every packed batch: a decode row a slot, a chunk one run), so at most
+    one step a slot straddles two and SMEM holds the words of that many.
     q: [N, Hq, D]. bf16 pool. More rows than one call's prefetched operands
     fit in SMEM (``SELECT_PREFETCH_BYTES``) are walked by further calls."""
-    N = q.shape[0]
+    N, hq, d = q.shape
     bb = _resolve_bb(bblock, N)
     row_limits = row_limits.astype(jnp.int32)
     row_map = row_map.astype(jnp.int32)
     table = table.astype(jnp.int32)
     bits = bits.astype(jnp.int32)
     layer_arr = jnp.asarray(layer, jnp.int32).reshape(1)
+    hkv, words = bits.shape[1:]
 
-    def call(q, lim, rmap, bits):
-        n = q.shape[0]
-        share = None
-        if bb > 1:
-            live = (lim > 0).reshape(n // bb, bb)
-            slots = rmap.reshape(n // bb, bb)
-            first = jnp.argmax(live, axis=1).astype(jnp.int32)
-            lead = jnp.take_along_axis(slots, first[:, None], axis=1)
-            same = jnp.all((slots == lead) | ~live, axis=1)
-            share = jnp.where(
-                same & live.any(axis=1),
-                jnp.arange(n // bb, dtype=jnp.int32) * bb + first, -1)
+    def held(n):
+        """(tile, rows whose words ride SMEM) of a call of ``n`` rows."""
+        tile = _tile_rows(n, bb, hq, d, pool_k.shape[3], pool_k.dtype)
+        return tile, n if tile == bb else min(n, table.shape[0] * tile)
+
+    def call(lo, hi):
+        rows = slice(lo, hi)
+        share, wide = _share_facts(q[rows], pool_k, row_limits[rows],
+                                   row_map[rows], bb)
+        smem, kw = bits[rows], {}
+        if wide is not None:
+            tile, keep = held(hi - lo)
+            by_tile = smem.reshape(-1, tile, hkv, words)
+            blocks = wide == -1       # the steps that run block by block
+            smem = by_tile[jnp.argsort(~blocks, stable=True)[:keep // tile]]
+            at = jnp.minimum(jnp.cumsum(blocks) - 1, keep // tile - 1)
+            kw = dict(bits_at=jnp.where(blocks, at * tile, 0)
+                      .astype(jnp.int32),
+                      lanebits=jnp.repeat(by_tile.transpose(0, 2, 3, 1),
+                                          hq // hkv, axis=3))
         return _paged_flash_db(
-            q, pool_k, pool_v, lim, layer_arr, table, bb=bb, R=1,
-            spec=False, window=0, interpret=interpret, pool_ks=None,
-            pool_vs=None, share=share, row_map=rmap, bits=bits)
+            q[rows], pool_k, pool_v, row_limits[rows], layer_arr, table,
+            bb=bb, R=1, spec=False, window=0, interpret=interpret,
+            pool_ks=None, pool_vs=None, share=share, wide=wide,
+            row_map=row_map[rows], bits=smem, **kw)
 
-    # The prefetched operands ride SMEM: the table, and a packed row its
-    # limit, its map entry and its Hkv x W bit words (8,216 rows x 2 x 16
-    # words alone are the chip's 1 MiB). Rows are independent, so more rows
-    # than fit go in further calls, each of whole blocks.
-    per_row = 4 * (2 + bits.shape[1] * bits.shape[2]) + 4
-    fit = max(bb, (SELECT_PREFETCH_BYTES - 4 * table.size) // per_row
-              // bb * bb)
-    if N <= fit:
-        return call(q, row_limits, row_map, bits)
-    step = -(-N // -(-N // fit) // bb) * bb
-    return jnp.concatenate([
-        call(q[lo:lo + step], row_limits[lo:lo + step],
-             row_map[lo:lo + step], bits[lo:lo + step])
-        for lo in range(0, N, step)])
+    def calls(lo, hi):
+        """The prefetched operands ride SMEM: the table, a packed row's
+        limit, map entry and share fact, and Hkv x W bit words a row held
+        (8,216 rows x 2 x 16 words alone are the chip's 1 MiB). Rows are
+        independent, so more rows than fit go in further calls, each of
+        whole blocks."""
+        n = hi - lo
+        need = 4 * (table.size + 3 * n + held(n)[1] * hkv * words)
+        if n <= bb or need <= SELECT_PREFETCH_BYTES:
+            return [call(lo, hi)]
+        mid = lo + -(-n // (2 * bb)) * bb
+        return calls(lo, mid) + calls(mid, hi)
+
+    out = calls(0, N)
+    return out[0] if len(out) == 1 else jnp.concatenate(out)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "stride"))

@@ -32,7 +32,8 @@ KV head and walks those (pallas_attention.decode_attend_pallas_paged_select:
 64 page steps at any context). The rows of a prefill chunk hand it a BITMASK
 and walk every live page under it
 (ragged_attend_pallas_paged_select) — the first form: a chunk's rows share
-one page stream a block, and a kernel that skips what NONE of a block's rows
+one page stream a TILE of rows (24 of the served 24 + 4,608), the mask a
+term of the tile's column mask; a kernel that walks only what a tile's rows
 chose is ROADMAP M6's open half. Off the chip the same selection masks a
 dense gather (tests).
 """

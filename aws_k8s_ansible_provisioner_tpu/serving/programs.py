@@ -1714,7 +1714,10 @@ class EnginePrograms:
         page to its highest row's last, one that also holds decode rows
         walks block by block — beside ``chunk_page_steps_by8``, what blocks
         of ``decode_bblock`` rows walk for the same rows (every tile's
-        cost before the tile widened; a selecting model's still). Decode
+        cost before the tile widened). A selecting model's tiles are cut
+        the same way (ragged_attend_pallas_paged_select; a chunk so long
+        that the entry walks it in several calls — 8,192 rows — is cut a
+        call at a time there and as one here). Decode
         rows that share a BLOCK with chunk rows (slots no multiple of the
         block) are left out of both. A list with window layers beside
         full ones: those two are a FULL layer's, ``win_chunk_page_steps``
@@ -1725,7 +1728,7 @@ class EnginePrograms:
         ps, B = self.serving.page_size, self.num_slots
         tp = self.mesh.shape.get("tp", 1) if self.mesh is not None else 1
         bb = _resolve_bb(self.decode_bblock, B + C)
-        tile = bb if self.cfg.selects else _tile_rows(
+        tile = _tile_rows(
             B + C, bb, self.cfg.num_heads // tp, self.cfg.pool_head_dim, ps,
             jnp.int8 if self.kv_quant else self.serving.dtype)
         limits = np.zeros(B + C, np.int64)

@@ -1140,13 +1140,21 @@ def expert_forms_parity(cfg, rows: int) -> None:
 
 
 def sala_kernel_parity(cfg, slots: int, window: int, page: int, bb: int,
-                       interpret: bool) -> None:
+                       chunk: int, interpret: bool, parent=None) -> None:
     """The kernels a model with selecting attention and Lightning layers
     adds, at the served widths (``groups`` 16, 512-page tables at the 32k
     window) against jax.numpy: the decode kernel over LISTS of selected
-    pages, the ragged kernel under BITMASKS with one table row a slot, the
-    selector's row add, and the Lightning decode update — on seeded inputs
-    and seeded (random) selections that differ per KV head."""
+    pages, the ragged kernel under BITMASKS with one table row a slot —
+    every slot's decode row and a ``chunk``-row chunk whose contexts
+    straddle the dense length, the served mixed step's rows: the wide
+    tiles of ``pallas_attention._tile_rows`` with the selection as a lane
+    mask, the decode rows' tile block by block —, the selector's row add,
+    and the Lightning decode update — on seeded inputs and seeded (random)
+    selections that differ per KV head. On the chip the ragged call is
+    also TIMED, a first chunk (every page chosen) and the straddling one;
+    with ``parent`` (``--parent``: that checkout's kernels) in the order
+    parent, change, change, parent on the same inputs, the outputs
+    compared."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -1221,10 +1229,11 @@ def sala_kernel_parity(cfg, slots: int, window: int, page: int, bb: int,
         f"{MP}-page tables, lists of {K}]: max |diff| {worst:.4f}; "
         f"{differ} of {B} rows read other pages a KV head")
     # -- ragged: bitmasks, one table row a slot -------------------------------
-    C, pslot = 64 if interpret else 256, 3
-    off = int(lengths[1]) if lengths[1] + C <= window else page
-    lims = np.concatenate([np.where(np.arange(B) == pslot, 0, lengths),
-                           off + 1 + np.arange(C)]).astype(np.int32)
+    C, pslot = chunk, 3
+    off = max(dense_len - 3 * C // 8, 0) + page // 2 \
+        if dense_len + C <= window else page
+    dec = np.where(np.arange(B) == pslot, 0, lengths)
+    lims = np.concatenate([dec, off + 1 + np.arange(C)]).astype(np.int32)
     lims[B + C - 5:] = 0                           # the chunk's padding
     row_map = slot_rows(B, C, pslot)
     q3 = jax.random.normal(keys[3], (B + C, Hq, D), jnp.bfloat16)
@@ -1239,9 +1248,33 @@ def sala_kernel_parity(cfg, slots: int, window: int, page: int, bb: int,
         q3, pool["k"], pool["v"], jnp.asarray(lims), layer, table, row_map,
         sa.as_bits(sel), interpret=interpret, bblock=bb)
     worst = close(f"ragged under page masks, bblock {bb}", got, want)
+    tile = pa._tile_rows(B + C, pa._resolve_bb(bb, B + C), Hq, D, page,
+                         pool["k"].dtype)
     say(f"kernel parity [ragged, page masks, {B} decode rows + a {C}-row "
-        f"chunk at {off}, one table row a slot]: max |diff| {worst:.4f}")
+        f"chunk at {off}, one table row a slot, tiles of {tile} rows]: max "
+        f"|diff| {worst:.4f}")
     del dense
+    if not interpret:
+        first = np.concatenate([dec, 1 + np.arange(C)]).astype(np.int32)
+        for name, lm in (("a first chunk", first),
+                         (f"a chunk at {off}", lims)):
+            bits = sa.as_bits(sel if lm is lims else random_selection(lm))
+            outs = []
+            for tag, mod in [("parent", parent), ("change", pa),
+                             ("change", pa), ("parent", parent)] \
+                    if parent else [("change", pa)]:
+                def call():
+                    return mod.ragged_attend_pallas_paged_select(
+                        q3, pool["k"], pool["v"], jnp.asarray(lm), layer,
+                        table, row_map, bits, bblock=bb)
+
+                say(f"ragged call under page masks [{tag}] bb={bb}, "
+                    f"{B}+{C} rows, {name}: {call_ms(call, 10):.3f} ms")
+                outs.append(call())
+            if parent:
+                close(f"ragged under page masks, {name}: change against "
+                      f"parent", outs[1], outs[0])
+                check(bool((outs[1] == outs[2]).all()), "two calls differ")
     # -- the selector's row add ----------------------------------------------
     runs = page // cfg.sparse_kernel_stride
     kc = jax.random.normal(keys[4], (L, P, Hkv, runs, D), jnp.float32)
@@ -2330,10 +2363,11 @@ def main() -> int:
             if opts.rehearse:
                 sala_kernel_parity(cfg.scaled(
                     lightning_num_heads=8, lightning_head_dim=128), 8,
-                    16 * page, page, 4, interpret=True)
+                    16 * page, page, 4, 64, interpret=True)
             else:
                 sala_kernel_parity(cfg, slots, window, page, bb,
-                                   interpret=False)
+                                   eng._chunk_size, interpret=False,
+                                   parent=parent)
         elif opts.rehearse:
             # interpret mode is slow: same code path at a small shape
             kernel_parity(cfg, 8, 256, 32, sorted({1, 4}), interpret=True,

@@ -475,6 +475,40 @@ def test_chunk_page_steps_mirror_the_kernels_grid(model, slots, chunk, off,
         assert want[0] == want[1]
 
 
+@pytest.mark.parametrize("slots,chunk,off,n,tile", [
+    (24, 240, 0, 240, 24),      # 264 rows: 24, the first tile the decode rows
+    (24, 240, 100, 90, 24),     # a later chunk, part dead
+    (8, 112, 70, 112, 40),      # decode rows beside chunk rows in a tile
+    (8, 128, 0, 128, 8),        # 136 = 8 x 17 rows: no wider tile
+])
+def test_a_selecting_models_chunk_page_steps_mirror_its_kernels_grid(
+        slots, chunk, off, n, tile):
+    """Since PR 49 the selecting ragged entry cuts its grid steps as the
+    plain one does (a sharing tile walks its pages once, the selection a
+    mask over its lanes), and the host's count follows it."""
+    from aws_k8s_ansible_provisioner_tpu.config import tiny_sala
+    from aws_k8s_ansible_provisioner_tpu.ops.pallas_attention import (
+        _resolve_bb, _tile_rows)
+
+    cfg = tiny_sala(max_seq_len=512)
+    eng = Engine(cfg, init_params(cfg, jax.random.PRNGKey(0), jnp.float32),
+                 _serving(model="tiny-sala", max_decode_slots=slots,
+                          max_cache_len=512, page_size=8, prefill_chunk=chunk,
+                          prefill_buckets=(16, 32), decode_bblock=8,
+                          decode_pipeline=1, ragged_attention=1,
+                          kv_host_tier_bytes=0))
+    assert eng.cfg.selects
+    got = eng._chunk_page_steps(chunk, off, n)
+    bb = _resolve_bb(8, slots + chunk)
+    assert _tile_rows(slots + chunk, bb, cfg.num_heads, cfg.head_dim, 8,
+                      jnp.float32) == tile
+    want = _page_steps_row_by_row(slots, chunk, off, n, bb, tile, 8,
+                                  eng.pages_per_slot, 0)
+    assert (got["chunk_page_steps"], got["chunk_page_steps_by8"]) == want
+    assert got["chunk_page_steps_by8"] >= got["chunk_page_steps"] > 0
+    assert (want[0] < want[1]) == (tile > bb)
+
+
 def test_mixed_record_and_metrics_carry_the_chunks_page_steps(model):
     """A mixed dispatch's record says how many page steps its chunk rows
     walk as the kernel's tiles are cut and how many blocks of 8 would have,

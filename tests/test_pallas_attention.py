@@ -514,6 +514,148 @@ def test_a_rows_output_is_bitwise_the_same_in_a_tile_of_any_width(
 
 
 # ---------------------------------------------------------------------------
+# The SELECTING ragged entry's wide tiles: a sharing tile of _tile_rows rows is
+# one page stream with the selection as a mask over its lanes; the tile that
+# holds the decode rows runs its blocks of 8 with their words in SMEM.
+# 264 = 11 x 24 (the served width: 4,632 = 193 x 24), 120 = 3 x 40; a chunk
+# from token 300 walks 34 pages of 16: two mask words a row and KV head.
+# ---------------------------------------------------------------------------
+
+_DENSE_PAGES = 4        # a row of up to this many pages reads every page
+
+
+def _selection(pick, limits, PS, MP, Hkv, rng):
+    """[N, Hkv, MP] bool: the pages each row and KV head reads. A live row
+    always reads one live page at least (the model forces its first and its
+    last blocks)."""
+    N = limits.shape[0]
+    page = np.arange(MP)[None, None, :]
+    hi = np.maximum(-(-limits // PS) - 1, 0)[:, None, None]
+    forced = (page == 0) | ((page >= hi - 1) & (page <= hi))
+    if pick == "forced":
+        sel = np.broadcast_to(forced, (N, Hkv, MP)).copy()
+    elif pick == "disjoint":    # neighbours read ONE page each, another each
+        own = (np.arange(N)[:, None] + np.arange(Hkv)[None, :])[:, :, None]
+        sel = page == own % (hi + 1)
+    elif pick == "hole":        # every page but one that lies on every walk
+        sel = np.broadcast_to(page != 1, (N, Hkv, MP)).copy()
+    else:                       # learned blocks past the dense length
+        sel = forced | (rng.random((N, Hkv, MP)) < 0.4) \
+            | (hi < _DENSE_PAGES)
+    return sel
+
+
+def _select_case(N, B, pstart, plen, pick, *, groups=2, Hkv=2, D=32, PS=16,
+                 dtype=jnp.float32, decode_last=False, seed=61, tile=None):
+    """_wide_case's packed rows through the SELECTING ragged entry (a tile
+    of ``tile`` rows where given, else what the shapes give), and the dense
+    jnp reference under the same selection (None with ``tile`` given: the
+    caller has it)."""
+    from aws_k8s_ansible_provisioner_tpu.ops import sparse_attention as sa
+
+    C, pslot, Hq = N - B, 2, Hkv * groups
+    S = -(-(pstart + C + 1) // PS) * PS
+    dense, pool, table = _paged_layout(B=B, S=S, Hkv=Hkv, D=D, PS=PS,
+                                       seed=seed, dtype=dtype)
+    nan_page = pool["k"].shape[1]
+    pool = _with_nan_page(pool) if dtype == jnp.float32 else pool
+    lengths = np.resize(np.asarray([1, S, 0, PS, 2 * PS + 1, S - 3, 2, S],
+                                   np.int32), B)
+    j = np.arange(C)
+    dec = np.where(np.arange(B) == pslot, 0, lengths)
+    chunk = np.where(j < plen, pstart + j + 1, 0)
+    limits = np.concatenate([chunk, dec] if decode_last else [dec, chunk]
+                            ).astype(np.int32)
+    rows_of = np.concatenate([np.full(C, pslot), np.arange(B)] if decode_last
+                             else [np.arange(B), np.full(C, pslot)])
+    rng = np.random.default_rng(seed)
+    sel = _selection(pick, limits, PS, S // PS, Hkv, rng)
+    q = jax.random.normal(jax.random.PRNGKey(seed + 1), (N, Hq, D), dtype)
+    ref = None if tile else np.asarray(jax.vmap(
+        lambda q1, k1, v1, l1, s1: sa._attend_rows(
+            q1[None], k1, v1, l1[None], s1[None], PS)[0])(
+                q, dense["k"][0][rows_of], dense["v"][0][rows_of],
+                jnp.asarray(limits), jnp.asarray(sel)), np.float32)
+    tab, rmap = _slot_rows(np.asarray(table), rows_of, limits, nan_page) \
+        if dtype == jnp.float32 else (table, jnp.asarray(rows_of, jnp.int32))
+    widths = []
+    real = pa._tile_rows
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(pa, "_tile_rows", lambda *a: widths.append(
+            real(*a) if tile is None else tile) or widths[-1])
+        out = pa.ragged_attend_pallas_paged_select.__wrapped__(
+            q, pool["k"], pool["v"], jnp.asarray(limits), jnp.int32(0), tab,
+            rmap, sa.as_bits(jnp.asarray(sel)), interpret=True, bblock=8)
+    return np.asarray(out, np.float32), ref, limits, widths[0]
+
+
+_SELECT_GRID = [
+    # id, N, B, pstart (chunk_off), plen, pick, tile, kwargs
+    ("n264-t24-under-the-dense-length", 264, 24, 0, 40, "learned", 24, {}),
+    ("n264-t24-straddles-the-dense-length", 264, 24, 50, 240, "learned", 24,
+     {}),
+    ("n264-t24-disjoint-pages", 264, 24, 300, 240, "disjoint", 24, {}),
+    ("n264-t24-forced-blocks-only", 264, 24, 300, 240, "forced", 24, {}),
+    ("n264-t24-a-page-no-row-chose", 264, 24, 300, 240, "hole", 24, {}),
+    ("n264-t24-part-dead-and-dead-tiles", 264, 24, 70, 100, "learned", 24,
+     {}),
+    ("n264-t24-mid-page-off", 264, 24, 37, 230, "learned", 24, {}),
+    ("n120-t40-decode-rows-beside-chunk-rows", 120, 8, 70, 112, "learned",
+     40, {}),
+    ("n120-t40-decode-rows-last", 120, 8, 70, 112, "learned", 40,
+     {"decode_last": True}),
+    ("n264-t24-mha", 264, 24, 70, 200, "learned", 24, {"groups": 1}),
+    ("n264-t24-groups8", 264, 24, 70, 200, "learned", 24,
+     {"groups": 8, "Hkv": 1}),
+    ("n264-t24-groups16", 264, 24, 70, 200, "learned", 24, {"groups": 16}),
+    ("n264-t24-bf16", 264, 24, 70, 240, "learned", 24,
+     {"dtype": jnp.bfloat16, "groups": 16}),
+]
+
+
+@pytest.mark.parametrize("N,B,pstart,plen,pick,tile,kw",
+                         [c[1:] for c in _SELECT_GRID],
+                         ids=[c[0] for c in _SELECT_GRID])
+def test_ragged_select_wide_tile_parity(N, B, pstart, plen, pick, tile, kw):
+    """Against the dense reference under the same selection AND against the
+    same call in blocks of 8 (the parent's program: every block's words in
+    SMEM, a sharing block row-major), output for output."""
+    out, ref, limits, width = _select_case(N, B, pstart, plen, pick, **kw)
+    assert width == tile
+    by8, _, _, _ = _select_case(N, B, pstart, plen, pick, tile=8, **kw)
+    live = limits > 0
+    assert np.isfinite(out).all()
+    tol = 2e-2 if kw.get("dtype") == jnp.bfloat16 else 2e-5
+    np.testing.assert_allclose(out[live], ref[live], rtol=tol, atol=tol)
+    np.testing.assert_allclose(out, by8, rtol=tol, atol=tol)
+    assert np.array_equal(out[~live], np.zeros_like(out[~live]))
+
+
+def test_ragged_select_holds_in_smem_the_words_its_blocks_read():
+    """With a wide tile the SMEM operand is the words of the steps that run
+    block by block (one a slot at most), the lanes operand every step's."""
+    seen = {}
+    real = pa._paged_flash_db
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(pa, "_paged_flash_db", lambda *a, **kw: (
+            seen.update(kw), real(*a, **kw))[1])
+        _select_case(264, 24, 300, 200, "learned")
+    assert seen["lanebits"].shape == (11, 2, 2, 24 * 2)
+    assert seen["bits"].shape == (11, 24, 2, 2)     # 24 slots >= 11 steps
+    at = np.asarray(seen["bits_at"])
+    assert at[0] == 0 and (np.asarray(seen["wide"])[1:] != -1).all()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(pa, "_paged_flash_db", lambda *a, **kw: (
+            seen.update(kw), real(*a, **kw))[1])
+        _select_case(120, 2, 70, 112, "learned", decode_last=True)
+    # 2 slots (+ the dead rows' poisoned one): 3 steps' words at most, the
+    # step that holds the decode rows is the LAST and its words come first
+    assert seen["bits"].shape == (3, 40, 2, 1)
+    assert list(np.asarray(seen["wide"]) == -1) == [False, False, True]
+    assert int(np.asarray(seen["bits_at"])[2]) == 0
+
+
+# ---------------------------------------------------------------------------
 # The decode kernel across page sizes, groupings, lengths, layers, dtypes —
 # and the row writers against the XLA scatter
 # ---------------------------------------------------------------------------

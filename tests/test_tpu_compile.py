@@ -570,13 +570,18 @@ def test_decode_kernel_over_selected_pages_compiles_for_v5e(chip, bb):
     assert sala_opsbytes.DECODE_KERNEL_RE == "^%" + fn.__name__
 
 
-@pytest.mark.parametrize("chunk", [1024, CHUNK, 8192])
-def test_ragged_kernel_under_page_masks_compiles_for_v5e(chip, chunk):
+@pytest.mark.parametrize("chunk,grids", [
+    (1024, [131]), (CHUNK, [37]), (4608, [193]), (8192, [257, 171])])
+def test_ragged_kernel_under_page_masks_compiles_for_v5e(chip, chunk, grids):
     """The BITMASK form with ONE table row a slot (a table row a packed row
     is 4.2 MB of SMEM at this window): 24 + chunk rows, 16 mask words a row
-    and KV head — at 8,192 rows (a chunk an operator may ask for; PR 34
-    tried it) the words alone are the chip's SMEM, and the entry walks the
-    rows in two calls."""
+    and KV head. A grid step is a tile of ``_tile_rows`` rows, the selection
+    a VMEM-blocked mask over its lanes: 24 of the served 4,632 rows — ONE
+    custom call a layer under the wrapper's name, 193 steps —, 56 of 2,072;
+    1,048 rows have no such divisor and keep blocks of 8 with every row's
+    words in SMEM. At 8,216 rows (a chunk an operator may ask for; PR 34
+    tried it; blocks of 8 too) those words alone are the chip's SMEM and
+    the entry walks the rows in two calls, each of which tiles wider."""
     sds, kv = _sala_pool(chip)
     i32, N = jnp.int32, S_B + chunk
     fn = pa.ragged_attend_pallas_paged_select
@@ -586,26 +591,26 @@ def test_ragged_kernel_under_page_masks_compiles_for_v5e(chip, chunk):
     compiled = _compile(functools.partial(fn, bblock=8), *args)
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.as_text().count("custom_call_target=\"tpu_custom_call\"") \
-        == (2 if chunk == 8192 else 1)
+        == len(grids)
     _assert_named_after_wrapper(compiled, fn)
-    # the selecting entry keeps blocks of 8 rows a grid step (a page none of
-    # 8 rows chose skips its update; the union over 56 is nearly every page)
-    grids = _pallas_grids(functools.partial(fn, bblock=8), *args)
-    assert sum(g for g, in grids) == N // 8 and len(grids) == \
-        (2 if chunk == 8192 else 1)
+    assert _pallas_grids(functools.partial(fn, bblock=8), *args) \
+        == [(g,) for g in grids]
 
 
-# sha256 of str(jax.make_jaxpr(...)) of the two selecting entries at the
-# parent of PR 45 (cb17104), taken with this very function in a checkout of
-# it: PR 45 moved the per-row walk of every OTHER entry of the paged body (a
-# row past its own pages starts no copy) and left these two — a list per row
-# and KV head, a bitmask over pages: past the dense length nothing to skip —
-# as they were, jaxpr for jaxpr.
-PINNED_SELECT = {"decode": "54178aab508e23a1", "ragged": "3f35e8b859231c93"}
+# sha256 of str(jax.make_jaxpr(...)) of the two selecting entries. "decode"
+# is the one taken at the parent of PR 45 (cb17104) with this very function
+# in a checkout of it: PR 45 moved the per-row walk of every OTHER entry of
+# the paged body (a row past its own pages starts no copy) and left the two —
+# a list per row and KV head, a bitmask over pages: past the dense length
+# nothing to skip — as they were, jaxpr for jaxpr. "ragged" moved with PR 49,
+# whose subject it is (a grid step is a tile of _tile_rows rows, a sharing
+# tile's selection a mask over its lanes); the decode entry, traced by the
+# same body, did not.
+PINNED_SELECT = {"decode": "54178aab508e23a1", "ragged": "c917292d68302a3a"}
 
 
 @pytest.mark.parametrize("entry", sorted(PINNED_SELECT))
-def test_selecting_entries_are_the_parents_jaxpr_for_jaxpr(entry):
+def test_selecting_entries_are_the_pinned_jaxprs(entry):
     import hashlib
 
     sds, i32 = jax.ShapeDtypeStruct, jnp.int32
