@@ -1337,8 +1337,10 @@ class ServingConfig:
     prefill_buckets: tuple = (32, 64, 128, 256, 512, 1024, 2048)
     # Max tokens of KV cache per slot (static decode shape).
     max_cache_len: int = 2048
-    # Fused decode horizon: tokens generated per device dispatch when no
-    # prefill is waiting (amortizes dispatch latency; see engine.decode_steps).
+    # Fused decode horizon: AT MOST this many tokens a slot per device
+    # dispatch (amortizes dispatch latency; programs.decode_steps). The
+    # engine runs them all while no admission can follow the dispatch and a
+    # few while one can (EnginePrograms._decode_horizon).
     decode_horizon: int = 8
     # One-deep asynchronous decode pipeline: the engine enqueues decode
     # dispatch N+1 (JAX async dispatch — no block) before fetching N's

@@ -244,8 +244,9 @@ def enumerate_programs(plan, mesh, params, cache, bblock: int = 1):
     """Full program set for the config: one (name, jit_fn, args, kwargs)
     per distinct compiled executable the engine can dispatch. Mirrors
     ``warmup(scope="full")``: every prefill bucket, batched prefill, the
-    chunk program, fused + horizon-1 decode, the penalties and logprobs
-    variants, and the spec-verify program when speculation is on."""
+    chunk program, the fused decode (one program for every substep count),
+    its penalties and logprobs variants, and the spec-verify program when
+    speculation is on."""
     import jax
     import jax.numpy as jnp
 
@@ -332,7 +333,7 @@ def enumerate_programs(plan, mesh, params, cache, bblock: int = 1):
             seeds=sds((B,), u32), ban_ids=sds((B, BAN_K), i32),
             ban_until=sds((B,), i32), bias_ids=sds((B, BIAS_K), i32),
             bias_vals=sds((B, BIAS_K), f32), bblock=bblock, live=live,
-            **win_kw("wtable", B))
+            steps=scalar, **win_kw("wtable", B))
         if penalties:
             kw.update(counts=sds((B, cfg.vocab_size), i32),
                       presence=sds((B,), f32), frequency=sds((B,), f32),
@@ -343,12 +344,10 @@ def enumerate_programs(plan, mesh, params, cache, bblock: int = 1):
     decode_args = (cfg, plan.horizon, params, cache, sds((B,), i32),
                    sds((B,), i32), rng, sds((B,), f32), sds((B,), i32),
                    sds((B,), f32))
+    # ONE program whatever the substeps a dispatch runs: the count is the
+    # ``steps`` operand, the horizon only sizes the outputs
     programs.append((f"decode_fused_h{plan.horizon}", decode_steps,
                      decode_args, decode_kwargs()))
-    if plan.horizon > 1:
-        programs.append((
-            "decode_h1", decode_steps,
-            (cfg, 1) + decode_args[2:], decode_kwargs()))
     programs.append((f"decode_fused_h{plan.horizon}_penalties", decode_steps,
                      decode_args, decode_kwargs(penalties=True)))
     programs.append((f"decode_fused_h{plan.horizon}_logprobs", decode_steps,

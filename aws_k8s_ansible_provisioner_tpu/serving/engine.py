@@ -7,9 +7,10 @@ The reference delegates this entire component to the external vLLM container
   ``prefill_step`` (one program per prompt-length bucket),
   ``prefill_batch_step`` (N waiting prompts in one dispatch, N a power of
   two), ``prefill_chunk_step`` (one fixed-size chunk of a long prompt, decode
-  interleaved between chunks), and ``decode_steps`` (two programs over all
-  slots: fused horizon=N when no prompt waits, horizon=1 otherwise —
-  ``n_steps`` is static). Static shapes throughout — XLA's compilation model
+  interleaved between chunks), and ``decode_steps`` (one program over all
+  slots, up to ``decode_horizon`` fused substeps: how many a dispatch runs
+  is an operand, the whole horizon while no admission can follow it and a
+  few substeps while one can). Static shapes throughout — XLA's compilation model
   is the design constraint (SURVEY.md §7 hard part #2: "continuous batching
   under XLA's static-shape constraint").
 - **Prefill/decode interleaving** with prefill priority: TTFT p50 is the headline
@@ -52,6 +53,7 @@ from aws_k8s_ansible_provisioner_tpu.serving import metrics as _metrics
 from aws_k8s_ansible_provisioner_tpu.serving import slo as _slo
 from aws_k8s_ansible_provisioner_tpu.serving.metrics import EngineMetrics
 from aws_k8s_ansible_provisioner_tpu.serving.programs import (  # noqa: F401
+    AWAIT_SHARE,
     BAN_K,
     BBLOCK_CANDIDATES,
     BIAS_K,
@@ -270,6 +272,7 @@ class Engine(EnginePrograms):
         "_op_dirty_sampling", "_op_dirty_table", "_last_ready",
         "_busy_watermark", "_allow_dev", "_allow_batch_dev",
         "_restore_pending", "_emit_streams", "_dispatch_s",
+        "_host_s", "_t_fetched", "_t_awaited", "_waited_s",
     )
 
     def __init__(self, cfg: ModelConfig, params, serving: ServingConfig,
@@ -1467,6 +1470,7 @@ class Engine(EnginePrograms):
                 and not self._ragged_on()):
             self._drain_decode_pipeline("prefill")
         self._await_arrival()
+        self._t_awaited = time.monotonic()
         with _phase(PH_ADMIT):
             batch, chunk_next, waiting = self._admit_round()
         if batch or chunk_next is not None:
@@ -1542,10 +1546,13 @@ class Engine(EnginePrograms):
         and whatever is enqueued now stands between a request that arrives
         a moment later and its admission for a whole dispatch more. So
         with a slot free and nobody waiting, give an arrival the first half
-        of the running dispatch's expected time (its program's last one,
-        ``_dispatch_s``) to show up — the engine thread would otherwise
+        (``AWAIT_SHARE``) of the running dispatch's expected time (its
+        program's last seconds a substep, ``_dispatch_s``, times the
+        substeps it runs) to show up — the engine thread would otherwise
         spend that time blocked in the fetch — and keep the other half for
-        building and enqueueing what comes next. Who gains: a caller that
+        building and enqueueing what comes next (a short dispatch is sized
+        so that it is enough: ``_note_host``; what is waited here is booked
+        in ``_waited_s``, no work of the host's). Who gains: a caller that
         asks again right after its answer (when a token woke its handler
         the emit loop took tens of ms and such a caller was queued by the
         time the engine looked; now the engine looks within a few ms), and
@@ -1554,8 +1561,8 @@ class Engine(EnginePrograms):
         whose final chunk rides it (its record's ``first``) is waited on
         like any other: the callers its predecessor's fetch just answered
         come back in these milliseconds and are admitted BEHIND it (each a
-        mixed step that also advances every decode row) and not behind a
-        whole decode horizon; the first token it carries goes out at its
+        mixed step that also advances every decode row) and not behind
+        another decode dispatch; the first token it carries goes out at its
         fetch, which the other half of its time still precedes (PERF.md
         section 6, PR 44, has the host's wait at those fetches)."""
         rec = self._inflight
@@ -1565,17 +1572,19 @@ class Engine(EnginePrograms):
         if st.queue_depth > 0 or st.active_slots >= st.num_slots:
             return
         drec = rec["drec"]
-        expect = self._dispatch_s.get((drec["program"], drec.get("horizon")))
-        if expect is None:
+        per = self._dispatch_s.get(drec["program"])
+        if per is None:
             return
-        until = max(drec["t_enqueue"], self._busy_watermark) + 0.5 * expect
+        until = max(drec["t_enqueue"], self._busy_watermark) \
+            + AWAIT_SHARE * per * drec["horizon"]
         self._work_event.clear()        # submit() and cancel() set it
         if self.sched.stats().queue_depth > 0:
             return                      # arrived between the two reads
-        timeout = until - time.monotonic()
-        if timeout > 0.001:
+        t0 = time.monotonic()
+        if until - t0 > 0.001:
             with _phase(PH_IDLE):
-                self._work_event.wait(timeout)
+                self._work_event.wait(until - t0)
+            self._waited_s += time.monotonic() - t0
 
     def _emit(self, slot: int, token: int, lp=None):
         """Record one generated token for a slot; handle stop conditions."""

@@ -328,6 +328,16 @@ class EngineMetrics:
             "(idle slots read zero), so it took the argmax alone; "
             "path=\"candidates\" a row drew, so every row's top-64 sort, "
             "nucleus and draws ran", ("program", "path")))
+        self.decode_dispatches = r.register(Counter(
+            "tpu_serve_decode_dispatches_total",
+            "Fused decode dispatches by the substeps they ran: "
+            "substeps=\"whole\" the configured horizon (no admission could "
+            "follow the dispatch), substeps=\"short\" fewer (one could, or "
+            "a path capped the count)", ("substeps",)))
+        self.decode_substeps = r.register(Counter(
+            "tpu_serve_decode_substeps_total",
+            "Substeps the fused decode dispatches ran; over "
+            "tpu_serve_decode_dispatches_total the mean count a dispatch"))
         self.kda_rows = r.register(Counter(
             "tpu_serve_kda_rows_total",
             "Rows that advanced a KDA layer's recurrent state, per layer, by "

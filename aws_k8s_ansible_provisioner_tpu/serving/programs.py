@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import contextlib
 import itertools
+import math
 import os
 import threading
 import time
@@ -95,6 +96,12 @@ STEP_PROGRAMS = ("prefill_step", "prefill_batch_step", "prefill_chunk_step",
 # annotation of a program is the k-th execution of jit_<program> after it
 # (one stream, in order), and the same seq is on the engine.dispatch span.
 _DISPATCH_SEQ = itertools.count(1)
+
+# The share of a running dispatch's expected time that Engine._await_arrival
+# gives an arrival to show up before the next dispatch is bound; the rest is
+# kept for building and enqueueing it (_note_host sizes a short dispatch so
+# that it is enough). A choice, not a swept optimum (PERF.md section 7).
+AWAIT_SHARE = 0.5
 # engine.dispatch spans take their ids from the record, never from the
 # request tracer's seeded generator (whose draws must stay a pure function
 # of the requests); the prefix keeps replicas apart in one backend.
@@ -390,15 +397,18 @@ def _restore_count_row(counts, slot, row):
         counts, row[None].astype(counts.dtype), (slot, jnp.int32(0)))
 
 
-def _moe_summary(stats):
+def _moe_summary(stats, steps=None):
     """int32 [..., L, 2] per-layer (experts hit, largest group) → float32
     [2]: mean experts hit, largest group anywhere. None stays None. An
     expert share's [..., L, 3] also carries the pairs that landed on a held
     expert and gives [3]: summed over substeps, mean over layers — per
-    layer, as the record's ``moe_rows`` is."""
+    layer, as the record's ``moe_rows`` is. ``steps``: the leading rows
+    that were run (decode_steps; the rest are zeros and leave the mean)."""
     if stats is None:
         return None
-    out = [stats[..., 0].astype(jnp.float32).mean(),
+    hit = stats[..., 0].astype(jnp.float32)
+    out = [hit.mean() if steps is None
+           else hit.sum() / (steps * stats.shape[-2]),
            stats[..., 1].max().astype(jnp.float32)]
     if stats.shape[-1] == 3:
         out.append(stats[..., 2].astype(jnp.float32).reshape(
@@ -406,16 +416,16 @@ def _moe_summary(stats):
     return jnp.stack(out)
 
 
-def _aux(moe, picked):
+def _aux(moe, picked, steps=None):
     """The step programs' last output: an MoE model's routing summary, a
     selecting model's page counts ([2] int32: live and selected pages of the
     (row, KV head) pairs, summed over substeps and selecting layers), None
-    for any other (no model has both)."""
+    for any other (no model has both). ``steps``: see ``_moe_summary``."""
     if picked is not None:
         with jax.named_scope(parts.SELECT):
             return picked.reshape(-1, 2).sum(axis=0)
     with jax.named_scope(parts.ROUTER):
-        return _moe_summary(moe)
+        return _moe_summary(moe, steps)
 
 
 def _attend(cfg: ModelConfig, make, table, wtable):
@@ -620,26 +630,34 @@ def decode_steps(cfg: ModelConfig, n_steps: int, params, cache, tokens,
                  penalties: bool = False, seeds=None,
                  ban_ids=None, ban_until=None, bias_ids=None,
                  bias_vals=None, allow=None, lora_idx=None,
-                 bblock: int = 1, live=None, wtable=None):
-    """``n_steps`` fused decode steps for every slot, one device dispatch.
+                 bblock: int = 1, live=None, wtable=None, steps=None):
+    """Up to ``n_steps`` fused decode steps for every slot, one device
+    dispatch: ``steps`` of them, a traced int32 scalar in [1, n_steps]
+    (None: all ``n_steps``). ONE compiled program serves every count — the
+    static ``n_steps`` only sizes the outputs.
 
     tokens/lengths/sampling params: [B]; ``cache`` is the paged pool and
     ``table`` [B, max_pages] int32 the slots' block tables. Returns
-    (cache, counts, out [n_steps, B], last_tok [B], lens [B], moe) — the
+    (cache, counts, out [n_steps, B], last_tok [B], lens [B], moe) — rows
+    of ``out`` (and of the logprob arrays) at and past ``steps`` are zeros
+    the host never reads; the
     final token/length carry stays device-resident so a pipelined engine can
     feed dispatch N's carry straight into dispatch N+1 (donated, no host
     round-trip; see EnginePrograms._decode_dispatch). ``moe`` is None for a
     dense model; for an MoE model ``live`` [B] bool marks the slots that
     hold a request (an idle slot's row is routed to no expert) and ``moe``
-    is float32 [2]: experts with a live row (mean over steps and layers)
-    and the rows of the largest group (``_moe_summary``).
+    is float32 [2]: experts with a live row (mean over the substeps run and
+    the layers) and the rows of the largest group (``_moe_summary``).
 
-    Fusing the token loop into one ``lax.scan`` is a TPU-first scheduling
+    Fusing the token loop into one device loop is a TPU-first scheduling
     decision: per-dispatch host→device latency (worst over a network-attached
     chip) is paid once per *horizon* instead of once per token, and XLA keeps
-    the KV cache resident in HBM across all substeps (donated carry). The
-    scheduler only uses a horizon > 1 when no prefill is waiting, so TTFT is
-    not taxed. Slots that hit a stop condition mid-horizon generate a few
+    the KV cache resident in HBM across all substeps (donated carry, in
+    place in the loop). What is enqueued stands between an arrival and its
+    admission, so the scheduler asks for the whole horizon only while no
+    admission can follow the dispatch and for the fewest substeps that keep
+    the device fed while one can (EnginePrograms._decode_horizon). Slots
+    that hit a stop condition mid-horizon generate a few
     surplus tokens which the host discards; surplus K/V writes past
     ``max_len`` are dropped (cache_write_row_paged masks rows outside the
     window; the XLA fallback's scatter drops them natively) — never corrupt
@@ -709,10 +727,26 @@ def decode_steps(cfg: ModelConfig, n_steps: int, params, cache, tokens,
     if counts is None:
         counts = jnp.zeros((tokens.shape[0], 1), jnp.int32)  # unused dummy
     rngs = jax.random.split(rng, n_steps)
+    carry = (cache, counts, tokens, lengths)
+    if steps is None:
+        steps = n_steps
+
+    def substep(i, state):
+        carry, outs = state
+        carry, ys = body(carry, rngs[i])
+        return carry, jax.tree.map(
+            lambda buf, y: jax.lax.dynamic_update_index_in_dim(buf, y, i, 0),
+            outs, ys)
+
     with lora_context(lora_idx):
-        (cache, counts, tok, lens), (out, moe, picked) = jax.lax.scan(
-            body, (cache, counts, tokens, lengths), rngs)
-    return cache, counts, out, tok, lens, _aux(moe, picked)
+        # each substep's outputs land in their row of buffers sized for
+        # n_steps (shapes only: the body is traced for the loop alone)
+        outs = jax.tree.map(
+            lambda y: jnp.zeros((n_steps,) + y.shape, y.dtype),
+            jax.eval_shape(lambda c, r: body(c, r)[1], carry, rngs[0]))
+        (cache, counts, tok, lens), (out, moe, picked) = jax.lax.fori_loop(
+            0, steps, substep, (carry, outs))
+    return cache, counts, out, tok, lens, _aux(moe, picked, steps)
 
 
 @partial(jax.jit, static_argnums=(0,),
@@ -1455,10 +1489,18 @@ class EnginePrograms:
         # slot -> scheduled-but-unsettled restore record (timing +
         # byte accounting; correctness rides XLA data dependencies)
         self._restore_pending: dict = {}
-        # (program, horizon) -> seconds the last such dispatch took on the
-        # device (_dispatch_close): how long the one in flight will take
-        # (Engine._await_arrival)
+        # program -> device seconds A SUBSTEP the last such dispatch took
+        # (_dispatch_close; a program without substeps is one): how long
+        # the one in flight will take (Engine._await_arrival) and what a
+        # decode substep buys (_short_horizon)
         self._dispatch_s: dict = {}
+        # what a dispatch has to cover for the device to stay fed: the
+        # host's seconds from a fetch's return to the next enqueue's end
+        # (_note_host), and the stamps it is taken from
+        self._host_s = 0.0
+        self._t_fetched = 0.0       # the last fetch's return
+        self._t_awaited = 0.0       # Engine._await_arrival's last return
+        self._waited_s = 0.0        # ... and what it waited since that fetch
         # per-slot global id of its group's scratch page (group 0's is 0,
         # preserving the single-device layout)
         self._scratch = np.repeat(
@@ -1781,7 +1823,9 @@ class EnginePrograms:
         tpulint R8 stands). ``program`` is the jitted function as the trace
         prints it, ``kind`` devmon's program kind, ``active`` the decode
         rows live, ``given`` the static key and the per-kind facts
-        (horizon, chunk_rows, chunk_n, chunk_off, bucket, rows,
+        (horizon = the substeps the dispatch RUNS, with horizon_why =
+        what chose that count for a decode dispatch: ``_decode_horizon``;
+        chunk_rows, chunk_n, chunk_off, bucket, rows,
         prompt_tokens, padded_tokens = the rows a prefill-type program's
         layers run over, head_rows = the rows its head runs over: one a
         sampled row, every row in a prompt_logprobs variant,
@@ -1845,11 +1889,12 @@ class EnginePrograms:
             # t_ready is when it FINISHED and device_s is its time; already
             # done (a host stall: another program's compile, say), it took
             # at most this. first_use: its own compile would be in it.
-            key = rec["program"], rec.get("horizon")
+            key, per = rec["program"], device_s / steps
             if t_ready - t_wait > 0.1 * device_s:
-                self._dispatch_s[key] = device_s
+                self._dispatch_s[key] = per
             elif key in self._dispatch_s:
-                self._dispatch_s[key] = min(self._dispatch_s[key], device_s)
+                self._dispatch_s[key] = min(self._dispatch_s[key], per)
+        self._t_fetched, self._waited_s = t_ready, 0.0
         rec["t_ready"] = t_ready
         rec["tail"] = tail
         rec["emitted"] = emitted
@@ -1903,6 +1948,11 @@ class EnginePrograms:
                     full * rec["chunk_page_steps" + sfx]
                     + win * rec.get("win_chunk_page_steps" + sfx, 0),
                     path=path)
+        if "horizon_why" in rec:
+            self.metrics.decode_dispatches.inc(
+                substeps="whole" if rec["horizon"] >= max(
+                    1, self.serving.decode_horizon) else "short")
+            self.metrics.decode_substeps.inc(rec["horizon"])
         if "sample_rows" in rec:
             self.metrics.sample_dispatches.inc(
                 program=rec["program"],
@@ -2605,6 +2655,7 @@ class EnginePrograms:
                 lora_idx=oc["lora"],
                 bblock=self.decode_bblock,
                 live=self._live_rows(active), **self._win_kw("wtable"))
+        self._note_host(drec)
         self.counts = new_counts if want_pen else real_counts
         self._pipe_carry = (tok, lens, self._carry_gen)
         _metrics.pipeline.dispatches.inc()
@@ -2965,6 +3016,76 @@ class EnginePrograms:
             self._op_dirty_table = False
         return oc
 
+    def _note_host(self, drec: dict) -> None:
+        """An enqueue just ended: what the host took since the last fetch
+        returned is what the dispatch in flight meanwhile had to cover for
+        the device to stay fed. Its own work — the emits, the step, the
+        operands and the enqueue; what ``_await_arrival`` chose to wait is
+        no work — and, since that wait runs to ``AWAIT_SHARE`` of the
+        running dispatch whenever a slot is free, the part that follows the
+        wait within the share it leaves. ``_host_s`` is the turn-around a
+        SHORT dispatch exists to cover, the dear one: the fetch before it
+        finishes streams and the step after it admits a successor. So the
+        admission of a walk's first chunk behind a short decode dispatch
+        SETS it (the last value, not a mean: the batch and the prompts move
+        with the traffic) and any other turn-around can only raise it
+        until the next such admission. (An enqueue that compiled says
+        nothing about the next.)"""
+        now = time.monotonic()
+        if self._t_fetched and not drec["first_use"]:
+            after = now - self._t_awaited \
+                if self._t_awaited >= self._t_fetched else 0.0
+            took = max(now - self._t_fetched - self._waited_s,
+                       after / (1.0 - AWAIT_SHARE))
+            prev = self._inflight
+            admits = drec["program"] == "mixed_step" and prev is not None \
+                and prev["drec"].get("horizon_why", "whole") != "whole"
+            self._host_s = took if admits else max(self._host_s, took)
+        self._t_fetched = 0.0
+
+    def _short_horizon(self) -> int:
+        """The fewest substeps whose device time still covers the host's
+        work for one dispatch, both as the engine last measured them
+        (``_dispatch_s``, ``_host_s``); one while either is unknown."""
+        step_s = self._dispatch_s.get("decode_steps", 0.0)
+        if step_s <= 0.0 or self._host_s <= 0.0:
+            return 1
+        return max(1, math.ceil(self._host_s / step_s))
+
+    def _decode_horizon(self, prev: Optional[dict], active: List[int],
+                        waiting: bool) -> tuple:
+        """(substeps, why) of the next fused decode dispatch. What is
+        enqueued stands between an arrival and its admission: its mixed
+        step can only run behind it. So a dispatch runs the WHOLE horizon
+        (``decode_horizon``, the cap) only while no admission can follow
+        it — every slot holds a stream whose budget reaches past what is
+        in flight (``whole``) — and SHORT (``_short_horizon``) while one
+        can: a request waits with a slot free (``waiting``), a slot is free
+        now (``slot_free``), or a live stream's budget — ``max_tokens`` or
+        the cache window — ends inside the dispatch still in flight
+        ``prev``, so its slot is free by the time this one starts
+        (``budget_ends``; a stream that ends at EOS is seen one fetch
+        later, as a free slot)."""
+        whole = max(1, self.serving.decode_horizon)
+        why = "whole"
+        if waiting:
+            why = "waiting"
+        elif len(active) < self.num_slots:
+            why = "slot_free"
+        elif prev is not None:
+            steps, gset = prev["horizon"], prev["gset"]
+            for slot in active:
+                req = self.slot_req[slot]
+                left = min(req.max_tokens - len(req.generated),
+                           self.max_len - 1 - int(self.lengths[slot]))
+                # (a guided slot emits one token a dispatch)
+                if left <= (1 if slot in gset else steps):
+                    why = "budget_ends"
+                    break
+        if why == "whole":
+            return whole, why
+        return min(whole, self._short_horizon()), why
+
     def _do_decode(self, max_horizon: Optional[int] = None,
                    fair_horizon: bool = False,
                    prefill_possible: Optional[bool] = None):
@@ -2983,35 +3104,37 @@ class EnginePrograms:
             self._drain_decode_pipeline("prefill")
             prev = None
         active = self._active_slots()
-        # Fused horizon unless a waiting prompt could actually prefill next
-        # step (pending AND a free slot): then take a single step so TTFT
-        # isn't taxed. Under saturation (pending but no free slot) a prefill
-        # is impossible anyway, so keep the fused horizon — dropping to
-        # horizon=1 there would disable the amortization exactly at peak load.
-        # A fairness-forced decode (``fair_horizon``) takes the FULL horizon
-        # even though a prefill is possible: that is the point — one real
-        # decode dispatch per prefill_fairness prefills.
+        # How many substeps: ``_decode_horizon``'s rule, then the bounds of
+        # the paths that force fewer — each a VALUE of the one program's
+        # ``steps`` operand (``capped`` in the record).
         # (``prefill_possible``: Engine.step hands over what its admission
         # pass left waiting, read before the pop that found nothing to
         # admit — a caller that comes back between that pop and this point
-        # is taken by the next step's walk behind a fused dispatch like any
-        # arrival a moment later; read HERE it chose the one-step program,
-        # which no warm-up reaches, a few times a window once admissions
-        # stopped settling: PERF.md section 6, PR 44)
+        # is taken by the next step's walk behind this dispatch like any
+        # arrival a moment later.)
         if prefill_possible is None:
             st = self.sched.stats()
             prefill_possible = (st.queue_depth > 0
                                 and st.active_slots < st.num_slots)
-        horizon = 1 if (prefill_possible and not fair_horizon) \
-            else max(1, self.serving.decode_horizon)
-        if max_horizon is not None:
-            horizon = min(horizon, max_horizon)
+        whole = max(1, self.serving.decode_horizon)
+        # A fairness-forced decode (``fair_horizon``) takes the WHOLE
+        # horizon even though a prefill is possible: that is the point —
+        # one real decode dispatch per prefill_fairness prefills.
+        horizon, why = (whole, "whole") if fair_horizon \
+            else self._decode_horizon(prev, active, prefill_possible)
+        # speculation runs only while nothing waits to prefill and the path
+        # may fuse at all (prefill priority stands)
+        may_spec = whole > 1 and why != "waiting" \
+            and (max_horizon is None or max_horizon > 1)
+        cap = whole if max_horizon is None else max_horizon
         # Draft-model speculation keeps plain-path horizons within one
         # catch-up dispatch (R = spec_k + 1 rows): a full fused horizon
         # would put the draft cache R+ tokens behind, needing multiple
         # teacher-forcing rounds to recover (serving/draft.py).
         if self.draft is not None and self.serving.spec_decode:
-            horizon = min(horizon, self.serving.spec_k + 1)
+            cap = min(cap, self.serving.spec_k + 1)
+        if horizon > cap:
+            horizon, why = cap, "capped"
         # The device cannot allocate: every active slot's pages must
         # cover its whole write horizon (incl. the spec path's R rows)
         # BEFORE the dispatch. May preempt the newest requests when the
@@ -3047,7 +3170,7 @@ class EnginePrograms:
         # (VERDICT r3 weak #4: the old global .any() gates gave a single
         # request a batch-wide blast radius). Falls back when no context
         # matched.
-        if (self.serving.spec_decode and horizon > 1
+        if (self.serving.spec_decode and may_spec
                 and not self._spec_plain_due):
             if prev is not None:
                 # Carry-generation handoff (ISSUE 16): the proposer and the
@@ -3112,16 +3235,17 @@ class EnginePrograms:
                 s for s in active
                 if self.slot_req[s] is not None
                 and self.slot_req[s].guided is not None)
-        if gset and not any(self.slot_req[s] is not None and s not in gset
-                            for s in active):
-            horizon = 1
+        if horizon > 1 and gset and not any(
+                self.slot_req[s] is not None and s not in gset
+                for s in active):
+            horizon, why = 1, "capped"
         gslots = list(gset)
         want_lp = self._want_logprobs(self.slot_req)
         want_pen = self.counts is not None and bool(
             self.pres_pens.any() or self.freq_pens.any()
             or (self.rep_pens != 1.0).any())
-        rec = self._decode_dispatch(horizon, active, gset, gslots, want_lp,
-                                    want_pen, *self._carry_in())
+        rec = self._decode_dispatch(horizon, why, active, gset, gslots,
+                                    want_lp, want_pen, *self._carry_in())
         if self._pipeline_on() and (feats or not gset):
             # leave the new dispatch in flight: its fetch is deferred to
             # the next decode step (or a pipeline drain), so the entire
@@ -3150,21 +3274,23 @@ class EnginePrograms:
                 self._decode_fetch(prev, tail=False)
             self._decode_fetch(rec, tail=True)
 
-    def _decode_dispatch(self, horizon: int, active: List[int], gset,
-                         gslots: List[int], want_lp: bool, want_pen: bool,
-                         tok_in, len_in) -> dict:
-        """Enqueue ONE fused decode dispatch and return its in-flight
-        record. JAX async dispatch: this returns as soon as the program is
-        enqueued — no blocking device reads on this half (tpulint R8; they
-        belong in _decode_fetch), so the host is free to emit the previous
-        dispatch's tokens while the device runs this one."""
+    def _decode_dispatch(self, horizon: int, why: str, active: List[int],
+                         gset, gslots: List[int], want_lp: bool,
+                         want_pen: bool, tok_in, len_in) -> dict:
+        """Enqueue ONE fused decode dispatch of ``horizon`` substeps (the
+        program's ``steps`` operand; ``why``: what chose the count) and
+        return its in-flight record. JAX async dispatch: this returns as
+        soon as the program is enqueued — no blocking device reads on this
+        half (tpulint R8; they belong in _decode_fetch), so the host is free
+        to emit the previous dispatch's tokens while the device runs this
+        one."""
         oc = self._decode_operands()
         rng = self._next_rng()
         allow = self._allow_words(gslots)
         prev = self._inflight
         drec = self._dispatch_open(
             "decode_steps", "decode", active, horizon=horizon,
-            sample_rows=int((self.temps > 0).sum()),
+            horizon_why=why, sample_rows=int((self.temps > 0).sum()),
             carry_steps=prev["horizon"] if prev is not None else 0,
             **self._kda_rows(horizon * len(active), len(active)))
         if not self.cfg.selects:    # (its rows walk a selection: the
@@ -3174,8 +3300,10 @@ class EnginePrograms:
         real_counts = self.counts
         with _Dispatching(drec):
             self.cache, new_counts, out, tok, lens, moe = decode_steps(
-                self.cfg, horizon, self.params, self.cache, tok_in, len_in,
+                self.cfg, max(1, self.serving.decode_horizon), self.params,
+                self.cache, tok_in, len_in,
                 rng, oc["temps"], oc["top_ks"], oc["top_ps"],
+                steps=np.int32(horizon),
                 mesh=self.mesh, impl=self.serving.attention_impl,
                 logprobs=want_lp,
                 counts=self.counts if want_pen else None,
@@ -3194,6 +3322,7 @@ class EnginePrograms:
                 lora_idx=oc["lora"],
                 bblock=self.decode_bblock,
                 live=self._live_rows(active), **self._win_kw("wtable"))
+        self._note_host(drec)
         # un-penalized dispatches return a dummy counts array — keep ours
         self.counts = new_counts if want_pen else real_counts
         self._pipe_carry = (tok, lens, self._carry_gen)
@@ -3371,8 +3500,8 @@ class EnginePrograms:
         of XLA compile time per program.
 
         scope="full" (serving): every variant — each prefill bucket, batched/
-        chunked prefill, prefix cache, speculative, penalties, logprobs, both
-        decode horizons. ~20 programs, minutes of XLA time cold — fine at
+        chunked prefill, prefix cache, speculative, penalties, logprobs, the
+        fused decode. ~20 programs, minutes of XLA time cold — fine at
         server startup (the readiness probe gates traffic) but NOT inside a
         bounded benchmark window.
 
@@ -3474,23 +3603,23 @@ class EnginePrograms:
             for r in rs:
                 self.submit(r)
             drain()
-            if horizon > 1:
-                self.cache, _, _, _, _, _ = decode_steps(
-                    self.cfg, horizon, self.params, self.cache,
-                    self._donatable(self.last_token),
-                    self._donatable(self.lengths),
-                    self._next_rng(), jnp.asarray(self.temps),
-                    jnp.asarray(self.top_ks), jnp.asarray(self.top_ps),
-                    mesh=self.mesh, impl=self.serving.attention_impl,
-                    table=jnp.asarray(self.table),
-                    seeds=jnp.asarray(self.seeds),
-                    ban_ids=jnp.asarray(self.ban_ids),
-                    ban_until=jnp.asarray(self.ban_until),
-                    bias_ids=jnp.asarray(self.bias_ids),
-                    bias_vals=jnp.asarray(self.bias_vals),
-                    lora_idx=self._lora_vec(),
-                    bblock=self.decode_bblock,
-                    live=self._live_rows(()), **self._win_kw("wtable"))
+            self.cache, _, _, _, _, _ = decode_steps(
+                self.cfg, horizon, self.params, self.cache,
+                self._donatable(self.last_token),
+                self._donatable(self.lengths),
+                self._next_rng(), jnp.asarray(self.temps),
+                jnp.asarray(self.top_ks), jnp.asarray(self.top_ps),
+                steps=np.int32(horizon),
+                mesh=self.mesh, impl=self.serving.attention_impl,
+                table=jnp.asarray(self.table),
+                seeds=jnp.asarray(self.seeds),
+                ban_ids=jnp.asarray(self.ban_ids),
+                ban_until=jnp.asarray(self.ban_until),
+                bias_ids=jnp.asarray(self.bias_ids),
+                bias_vals=jnp.asarray(self.bias_vals),
+                lora_idx=self._lora_vec(),
+                bblock=self.decode_bblock,
+                live=self._live_rows(()), **self._win_kw("wtable"))
             return
 
         # Distinct token values per warmup request — identical prompts would
@@ -3544,16 +3673,16 @@ class EnginePrograms:
                         max_tokens=self.serving.spec_k + 2, ignore_eos=True)
             self.submit(r)
             drain()
-        # compile the fused decode program too (horizon path), and its
-        # penalties variant ('penalties' is a static arg — a distinct
-        # program): the first penalized request must not pay a 20-40s XLA
-        # compile inside step(), freezing every in-flight stream (and
-        # burning most of the /health stall budget).
-        if horizon > 1:
-            r = Request(prompt_ids=[0] * 4, max_tokens=horizon + 1,
-                        ignore_eos=True)
-            self.submit(r)
-            drain()
+        # compile the fused decode program too — ONE program whatever the
+        # substeps a dispatch runs (the count is its ``steps`` operand) —
+        # and its penalties variant ('penalties' is a static arg — a
+        # distinct program): the first penalized request must not pay a
+        # 20-40s XLA compile inside step(), freezing every in-flight stream
+        # (and burning most of the /health stall budget).
+        r = Request(prompt_ids=[0] * 4, max_tokens=horizon + 1,
+                    ignore_eos=True)
+        self.submit(r)
+        drain()
         # Penalties variants compile against THROWAWAY buffers so warmup does
         # not permanently allocate the [num_slots, vocab] counts array (~78 MB
         # int32 at Qwen3 vocab x 128 slots) an engine whose clients never use
@@ -3568,6 +3697,7 @@ class EnginePrograms:
             self._donatable(self.last_token), self._donatable(self.lengths),
             self._next_rng(), jnp.asarray(self.temps),
             jnp.asarray(self.top_ks), jnp.asarray(self.top_ps),
+            steps=np.int32(horizon),
             mesh=self.mesh, impl=self.serving.attention_impl,
             counts=cnts, presence=jnp.asarray(self.pres_pens),
             frequency=jnp.asarray(self.freq_pens),
@@ -3601,24 +3731,3 @@ class EnginePrograms:
             for r in rs:
                 self.submit(r)
             drain()
-        # The horizon=1 decode variant (selected whenever a prefill is
-        # possible) is a distinct compiled program (n_steps is static);
-        # compile it now so the first decode overlapping a queued request
-        # doesn't stall all in-flight streams on XLA. Direct call, no slot
-        # state touched: writes land at position 0 of idle slots and are
-        # overwritten by real prefills.
-        self.cache, _, _, _, _, _ = decode_steps(
-            self.cfg, 1, self.params, self.cache,
-            self._donatable(self.last_token), self._donatable(self.lengths),
-            self._next_rng(), jnp.asarray(self.temps),
-            jnp.asarray(self.top_ks), jnp.asarray(self.top_ps),
-            mesh=self.mesh, impl=self.serving.attention_impl,
-            table=jnp.asarray(self.table),
-            seeds=jnp.asarray(self.seeds),
-            ban_ids=jnp.asarray(self.ban_ids),
-            ban_until=jnp.asarray(self.ban_until),
-            bias_ids=jnp.asarray(self.bias_ids),
-            bias_vals=jnp.asarray(self.bias_vals),
-                    lora_idx=self._lora_vec(),
-                    bblock=self.decode_bblock,
-                    live=self._live_rows(()), **self._win_kw("wtable"))

@@ -1860,14 +1860,15 @@ def build_parser() -> argparse.ArgumentParser:
                         "(seeded streams stay byte-identical). 0 restores "
                         "the synchronous dispatch-fetch-emit loop")
     p.add_argument("--decode-horizon", type=int, default=8,
-                   help="tokens a fused decode dispatch generates when no "
-                        "prompt waits (ServingConfig.decode_horizon). A "
-                        "caller that comes back while one runs waits for "
-                        "its end before it is admitted, and callers whose "
-                        "streams end in the same dispatch come back "
-                        "together: a model with a slow step takes fewer "
-                        "(two steps already hide the host's work behind "
-                        "the device's)")
+                   help="AT MOST this many tokens a slot a fused decode "
+                        "dispatch generates (ServingConfig.decode_horizon): "
+                        "the engine runs them all while every slot holds a "
+                        "stream that outlasts what is in flight, and the "
+                        "fewest substeps that keep the device fed while an "
+                        "admission can follow the dispatch (a slot free, a "
+                        "stream about to end, a request waiting), so a "
+                        "caller waits behind a few decode steps, not a "
+                        "horizon")
     p.add_argument("--ragged-attention", type=int, default=1,
                    help="ragged mixed-batch attention: chunked prefill "
                         "packs into the SAME dispatch as the decode batch "

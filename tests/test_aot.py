@@ -25,6 +25,7 @@ from aws_k8s_ansible_provisioner_tpu.config import (
 from aws_k8s_ansible_provisioner_tpu.models.layers import init_params
 from aws_k8s_ansible_provisioner_tpu.parallel.mesh import make_mesh
 from aws_k8s_ansible_provisioner_tpu.serving import aot
+from aws_k8s_ansible_provisioner_tpu.serving import programs as aot_programs
 from aws_k8s_ansible_provisioner_tpu.serving.aot import (
     LEDGER_FIELDS, MANIFEST_SCHEMA, PROGRAM_FIELDS, ProgramPlan,
     build_ledger, build_manifest, enumerate_programs, verify_manifest)
@@ -96,18 +97,28 @@ def test_plan_rejects_indivisible_layouts():
 
 def test_enumeration_covers_every_program_family():
     """The program set must mirror warmup's full scope: one program per
-    bucket, the logprob/batch/chunk variants, both decode horizons plus the
-    penalties and logprobs variants, and spec-verify iff speculation is on."""
+    bucket, the logprob/batch/chunk variants, the ONE fused decode program
+    (its substep count is the ``steps`` operand: no one-step program beside
+    it) plus the penalties and logprobs variants, and spec-verify iff
+    speculation is on."""
     serving = _tiny_serving(spec_decode=True, spec_k=3)
     plan = ProgramPlan(tiny_qwen3(), serving)
     params, cache = aot._abstract_state(plan, None)
     names = [p[0] for p in enumerate_programs(plan, None, params, cache)]
     assert names.count("prefill_b16") == 1 and names.count("prefill_b32") == 1
     for expect in ("prefill_b16_logprobs", "prefill_batch_n4_b16",
-                   "prefill_chunk_c32", "decode_fused_h8", "decode_h1",
+                   "prefill_chunk_c32", "decode_fused_h8",
                    "decode_fused_h8_penalties", "decode_fused_h8_logprobs",
                    "spec_verify_r4"):
         assert expect in names, f"{expect} missing from {names}"
+    decodes = [p for p in enumerate_programs(plan, None, params, cache)
+               if p[1] is aot_programs.decode_steps]
+    assert [p[0] for p in decodes] == [
+        "decode_fused_h8", "decode_fused_h8_penalties",
+        "decode_fused_h8_logprobs"]
+    for _, _, args, kwargs in decodes:      # the horizon sizes, steps counts
+        assert args[1] == 8 and kwargs["steps"].shape == () \
+            and kwargs["steps"].dtype == jnp.int32
     no_spec = ProgramPlan(tiny_qwen3(), _tiny_serving())
     names2 = [p[0] for p in enumerate_programs(
         no_spec, None, *aot._abstract_state(no_spec, None))]
@@ -253,7 +264,8 @@ def test_aot_smoke_deviceless_compile_and_fit(tmp_path):
     assert m["hbm_ledger"]["fit"] is True
     assert m["total_compile_seconds"] > 0
     names = [p["name"] for p in m["programs"]]
-    assert "prefill_b16" in names and "decode_h1" in names
+    assert "prefill_b16" in names and "decode_fused_h2" in names \
+        and "decode_h1" not in names
     # the same compiled set against a micro HBM budget must flip the verdict
     plan = ProgramPlan(cfg, serving)
     params, cache = aot._abstract_state(plan, None)

@@ -733,14 +733,16 @@ def test_next_dispatch_is_bound_late_for_an_arrival(model):
     for _ in range(5):
         eng.step()
     assert eng._inflight is not None
-    key = ("decode_steps", eng._inflight["horizon"])
+    key = "decode_steps"        # (kept A SUBSTEP, whatever count ran)
     # what was measured so far has no compile in it (a loaded host may not
     # have caught a dispatch still running yet: then nothing is measured)
     assert all(0 < v < 5.0 for v in eng._dispatch_s.values())
     ev = eng._work_event = _ArrivalEvent(eng._work_event)
 
     def step(expect=4.0):
-        eng._dispatch_s[key] = expect         # as if a dispatch took so long
+        # as if the dispatch in flight took so long
+        if eng._inflight is not None:
+            eng._dispatch_s[key] = expect / eng._inflight["horizon"]
         eng._busy_watermark = time.monotonic()   # ... and has just begun
         before = len(ev.waits)
         eng.step()
@@ -761,7 +763,7 @@ def test_next_dispatch_is_bound_late_for_an_arrival(model):
     assert len(first.generated) == 100 and len(late.generated) == 4
     # an expectation that was too high comes down by itself: a dispatch that
     # had finished before the host looked took at most that long
-    assert eng._dispatch_s[key] < 4.0
+    assert eng._dispatch_s[key] < 4.0 / eng.serving.decode_horizon
     # nothing in flight: an idle device is never made to wait
     eng.submit(Request(prompt_ids=[7, 7], max_tokens=2, ignore_eos=True))
     assert eng._inflight is None and step() == []
@@ -795,6 +797,9 @@ def test_guided_streams_byte_identical_ragged_features_on_off(model):
 
     def run(feats):
         eng = _ragged_engine(model, 1, ragged_features=feats)
+        # (the whole horizon with a slot free too: the arms' dispatch
+        # counts compare the pipelines, not two measured short counts)
+        eng._short_horizon = lambda: eng.serving.decode_horizon
         g = grammar_for(tok, {"type": "json_object"}, [tok.eos_token_id])
         first = eng.submit(Request(prompt_ids=[5, 9, 2], max_tokens=100,
                                    temperature=0.9, seed=42,
@@ -1424,7 +1429,9 @@ def test_empty_slots_carry_lanes_do_not_grow_under_an_open_pipeline(model):
             seen = empty & was_empty - {(eng._chunk or {}).get("slot")}
             worst = max([worst] + [int(lens[s]) for s in seen])
             was_empty = empty
-    assert not live.finish_reason and len(live.generated) > 300
+    # (slots are free throughout, so a dispatch runs a measured 1 to 4
+    # substeps: 160 steps give the stream 160 tokens at the least)
+    assert not live.finish_reason and len(live.generated) > 120
     assert _paths(eng)[0] >= 20 and uploads == 0, \
         "the pipeline closed between admissions (test is vacuous)"
     assert worst <= eng.serving.decode_horizon, worst

@@ -246,8 +246,10 @@ def test_stream_gets_its_tokens_then_one_sentinel(setup, end, reason,
     ends, whatever ends the request, with every generated token delivered
     and exactly one sentinel behind them."""
     cfg, params, serving = setup
+    # (one slot: the stream holds every slot, so its dispatches run the
+    # whole horizon — with a slot free they run a measured few)
     engine = Engine(cfg, params, dataclasses.replace(
-        serving, decode_horizon=horizon))
+        serving, decode_horizon=horizon, max_decode_slots=1))
     req = Request(prompt_ids=[5, 6, 7], max_tokens=21, ignore_eos=True,
                   stream=True)
     engine.submit(req)

@@ -209,7 +209,10 @@ def test_record_counts_puts_and_the_counter_counts_items(model, horizon):
     a stream is handed ONE queue item for what a dispatch gave it, so the
     tokens a handler wake-up carries are the horizon, and 1 at horizon 1."""
     _, cfg, params = model
-    eng = Engine(cfg, params, _serving(decode_horizon=horizon))
+    # (three slots for three streams: every slot held, so the dispatches
+    # run the whole horizon; with one free they run a measured few)
+    eng = Engine(cfg, params, _serving(decode_horizon=horizon,
+                                       max_decode_slots=3))
     rec = _Recorder()
     tracer = tracing.Tracer("tpu-serve-engine", exporter=rec)
     eng.tracer_source = lambda: tracer

@@ -276,14 +276,22 @@ def _strip_volatile(raw: bytes, stream: bool) -> bytes:
     trace/span ids (all differ across ANY two requests, sanitizer or not)."""
     if not stream:
         return json.dumps(_scrub(json.loads(raw)), sort_keys=True).encode()
-    out = []
+    # ... and minus the GROUPING of the tokens into events: an event
+    # carries what one dispatch gave the stream, and with a slot free a
+    # dispatch runs a measured few substeps (EnginePrograms._decode_horizon)
+    # — the text, the ids in order and the finish are what must not move
+    text, ids, other = "", [], []
     for line in raw.split(b"\n"):
         if line.startswith(b"data: ") and line != b"data: [DONE]":
             obj = _scrub(json.loads(line[len(b"data: "):]))
-            out.append(b"data: " + json.dumps(obj, sort_keys=True).encode())
-        else:
-            out.append(line)
-    return b"\n".join(out)
+            for ch in obj.get("choices", []):
+                text += ch.pop("text", "")
+                ids += ch.pop("token_ids", None) or []
+            if obj not in other:
+                other.append(obj)
+        elif line and line not in other:
+            other.append(line.decode())
+    return json.dumps([text, ids, other], sort_keys=True).encode()
 
 
 def test_seeded_responses_byte_identical_with_locksan_on_vs_off(

@@ -828,19 +828,27 @@ def test_aot_plan_sizes_state_and_selector_beside_the_pool():
 # ROW; the ragged entry takes the pair, the decode rows' writers ``table``
 # itself, the fallback gathers ``table[row_map]``. Every ``decode_steps`` and
 # ``prefill_step`` hash and "decode", "write" and "kda" are the parent's.
+#
+# PR 50 re-took the six ``decode_steps`` hashes, in the SERVED form (the
+# ``steps`` operand given): the token loop runs as many substeps as that
+# operand says and writes each substep's outputs into its row of buffers
+# sized by the static ``n_steps`` (a ``while`` where the scan was); the
+# substep's body is the parent's (tests/test_decode_horizon.py holds the
+# operand form to the static program at 1, 3 and 8 substeps). Every
+# ``mixed_step`` / ``prefill_step`` hash and all four kernels' did not move.
 PINNED = {
-    ("tiny-olmoe", "decode_steps", "pallas"): "aa98963a5752b0f6",
-    ("tiny-olmoe", "decode_steps", "xla"): "24166cb7302bca06",
+    ("tiny-olmoe", "decode_steps", "pallas"): "c38058a42fb84b66",
+    ("tiny-olmoe", "decode_steps", "xla"): "f8640e998e7a20d6",
     ("tiny-olmoe", "mixed_step", "pallas"): "08fec05b72a19584",
     ("tiny-olmoe", "mixed_step", "xla"): "c6e13478d3763fe2",
     ("tiny-olmoe", "prefill_step", "xla"): "fe74d853601263b8",
-    ("tiny-qwen3", "decode_steps", "pallas"): "1e12c12ca574063d",
-    ("tiny-qwen3", "decode_steps", "xla"): "979ebf2eee66c834",
+    ("tiny-qwen3", "decode_steps", "pallas"): "5b0517df3dfac48f",
+    ("tiny-qwen3", "decode_steps", "xla"): "988ed9e0463c4593",
     ("tiny-qwen3", "mixed_step", "pallas"): "53bad8ae3f34e008",
     ("tiny-qwen3", "mixed_step", "xla"): "87bf59bd4df3281b",
     ("tiny-qwen3", "prefill_step", "xla"): "34d3281612f23ac5",
-    ("tiny-solar", "decode_steps", "pallas"): "e46a1ccc1ad8a0bf",
-    ("tiny-solar", "decode_steps", "xla"): "fbbaabf7e4d43f6a",
+    ("tiny-solar", "decode_steps", "pallas"): "85f1c90d3e598ae3",
+    ("tiny-solar", "decode_steps", "xla"): "f021e8d594e15038",
     ("tiny-solar", "mixed_step", "pallas"): "0e8bf0085994ae6b",
     ("tiny-solar", "mixed_step", "xla"): "99f92aa1b81146f5",
     ("tiny-solar", "prefill_step", "xla"): "8da44bc738dc28b0",
@@ -881,7 +889,7 @@ def program_hash(cfg, program, impl):
                                                    impl=impl, **k)
         args = (params, cache, sds((B,), i32), sds((B,), i32), rng,
                 sds((B,), f32), sds((B,), i32), sds((B,), f32))
-        kw = row
+        kw = dict(row, steps=sds((), i32))      # the served form: a count
     elif program == "mixed_step":
         fn = lambda p, c, *a, **k: pg.mixed_step(cfg, p, c, *a, impl=impl,
                                                  **k)

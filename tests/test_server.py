@@ -29,10 +29,10 @@ def _serve_tiny(port, **serving_over):
     tok = ByteTokenizer()
     cfg = tiny_qwen3(vocab_size=tok.vocab_size, eos_token_id=tok.eos_token_id)
     params = init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
-    serving = ServingConfig(weights_dtype="bf16", model=MODEL_NAME, max_decode_slots=4,
-                            max_cache_len=128,
-                            prefill_buckets=(16, 32, 64), dtype="float32",
-                            **serving_over)
+    serving = ServingConfig(**dict(
+        dict(weights_dtype="bf16", model=MODEL_NAME, max_decode_slots=4,
+             max_cache_len=128, prefill_buckets=(16, 32, 64),
+             dtype="float32"), **serving_over))
     state = build_state(serving, model_cfg=cfg, params=params, tokenizer=tok)
     ready, stop = threading.Event(), threading.Event()
     t = threading.Thread(target=serve,
@@ -601,6 +601,15 @@ def per_token_server():
     yield from _serve_tiny(18124, decode_horizon=1)
 
 
+@pytest.fixture(scope="module")
+def one_slot_server():
+    """The same server with ONE slot: a stream holds every slot, so its
+    dispatches run the whole horizon of 8 and its items are 8 tokens — with
+    a slot free a dispatch runs a measured few (the engine's choice:
+    EnginePrograms._decode_horizon), and the grouping moves with the host."""
+    yield from _serve_tiny(18126, max_decode_slots=1)
+
+
 def _sse(url, payload, path="/v1/completions"):
     """A streamed request as its client sees it, per choice index: joined
     text, joined token_ids, finish_reason, the ids of each content chunk
@@ -643,12 +652,13 @@ def _same_stream(a, b):
     {"temperature": 1.0, "seed": 12, "top_p": 0.9}],
     ids=["greedy", "seeded", "seeded-top-p"])
 def test_stream_equals_nonstream_and_the_per_token_stream(
-        server, per_token_server, sampling):
+        one_slot_server, per_token_server, sampling):
     """Fewer events, the same stream: joined text and token_ids of a stream
     whose chunks carry a dispatch's tokens equal the non-stream response's
     and the one-token-a-chunk stream's, for the same seed."""
     body = {"model": MODEL_NAME, "prompt": "same stream", "max_tokens": 21,
             "ignore_eos": True, **sampling}
+    server = one_slot_server
     _, full = _post(server + "/v1/completions", body)
     got = _sse(server, body)[0]
     ref = _sse(per_token_server, body)[0]
@@ -691,8 +701,8 @@ def test_stop_string_inside_an_item_cuts_where_the_per_token_stream_cuts(
 
 
 @pytest.mark.parametrize("seed", [21, 22, 23])
-def test_no_token_id_leaves_ahead_of_its_text(server, per_token_server,
-                                              seed):
+def test_no_token_id_leaves_ahead_of_its_text(one_slot_server,
+                                              per_token_server, seed):
     """Items that end inside a multi-byte character (tokens drawn from "a"
     and the two bytes of "\u00e9", in any order): an id whose bytes the
     detokenizer still holds stays back with them, so after EVERY event the
@@ -703,6 +713,7 @@ def test_no_token_id_leaves_ahead_of_its_text(server, per_token_server,
     body = {"model": MODEL_NAME, "prompt": "bytes", "max_tokens": 40,
             "ignore_eos": True, "temperature": 1.0, "seed": seed,
             "logit_bias": {"97": 100, "195": 100, "169": 100}}
+    server = one_slot_server        # (items of 8: the ``ends`` below)
     _, full = _post(server + "/v1/completions", body)
     got = _sse(server, body)[0]
     ref = _sse(per_token_server, body)[0]

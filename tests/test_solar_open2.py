@@ -551,14 +551,16 @@ def test_start_up_refuses_lora_and_gshard():
 # rows: tests/test_minicpm_sala.py says what changed in them), and all six
 # by PR 38 (the sampler's candidates behind one ``cond``: the same file); the
 # two ``mixed_step`` hashes again by PR 46 (one table row a slot and a row
-# map for every model; the fallback gathers ``table[row_map]``).
+# map for every model; the fallback gathers ``table[row_map]``); the two
+# ``decode_steps`` hashes by PR 50, in the served form (the ``steps``
+# operand: tests/test_minicpm_sala.py says what changed in them).
 PINNED = {
     ("tiny-qwen3", "decode_steps"):
-        "979ebf2eee66c834",
+        "988ed9e0463c4593",
     ("tiny-qwen3", "mixed_step"):
         "87bf59bd4df3281b",
     ("tiny-olmoe", "decode_steps"):
-        "24166cb7302bca06",
+        "f8640e998e7a20d6",
     ("tiny-olmoe", "mixed_step"):
         "c6e13478d3763fe2",
     ("tiny-qwen3", "prefill_step"):
@@ -588,7 +590,7 @@ def jaxpr_hash(cfg, program):
                                                    impl="xla", **k)
         args = (params, cache, sds((B,), i32), sds((B,), i32), rng,
                 sds((B,), f32), sds((B,), i32), sds((B,), f32))
-        kw = row
+        kw = dict(row, steps=sds((), i32))      # the served form: a count
     elif program == "mixed_step":
         fn = lambda p, c, *a, **k: pg.mixed_step(cfg, p, c, *a, impl="xla",
                                                  **k)

@@ -424,7 +424,36 @@ def _compiled_decode_steps(chip, monkeypatch, model, slots):
         p for p in aot.enumerate_programs(plan, None, params, cache,
                                           bblock=8)
         if p[0] == "decode_fused_h8")
+    assert kwargs["steps"].shape == ()      # the count: an operand
     return fn.lower(*args, **kwargs).compile()
+
+
+@pytest.mark.parametrize("model,slots,pool,temp_mib", [
+    ("Qwen/Qwen3-0.6B", 32, "bf16[28,1025,8,64,128]", 2),
+    ("Qwen/Qwen3-8B", 16, "bf16[36,513,8,64,128]", 150)],
+    ids=["qwen3-0.6b", "qwen3-8b"])
+def test_decode_steps_holds_no_pool_copy(chip, monkeypatch, model, slots,
+                                         pool, temp_mib):
+    """The served ``decode_steps`` of the two Qwen3 closed cells, whose
+    token loop runs as many substeps as its ``steps`` operand says: the
+    pool rides the loop's carry in place as it rode the static scan's —
+    nothing but the program's parameters, the loops' tuples and the two
+    aliased Pallas row writes produces a pool-shaped value, the
+    temporaries are what the static scan's were (1.0 MiB; 145.1 at the
+    8B's widths, half of one 288-MiB pool leaf) — and each substep's tokens
+    land in their row of the ``[8, slots]`` output the loop carries."""
+    import re
+
+    compiled = _compiled_decode_steps(chip, monkeypatch, model, slots)
+    text = compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < temp_mib * 2**20
+    makers = set(re.findall(rf" = {re.escape(pool)}\S* ([\w\-]+)\(", text))
+    assert {"parameter", "custom-call"} <= makers \
+        <= {"parameter", "get-tuple-element", "custom-call"}, makers
+    assert re.search(rf"s32\[8,{slots}\]\S* dynamic-update-slice\(", text) \
+        or re.search(rf"s32\[8,{slots}\]\S* fusion\(", text)
+    _assert_named_after_wrapper(compiled, pa.cache_write_row_paged)
 
 
 @pytest.mark.parametrize(
