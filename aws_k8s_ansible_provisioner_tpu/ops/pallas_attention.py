@@ -727,6 +727,32 @@ def _tile_rows(N: int, bb: int, hq: int, d: int, ps: int, dtype) -> int:
                default=bb)
 
 
+# The narrowest tile a plain ragged call settles for before it pads its rows
+TILE_MIN = TILE_ROWS // 2
+
+
+def _ragged_pad(N: int, bblock, hq: int, d: int, ps: int, dtype) -> int:
+    """Packed rows a plain ragged call RUNS for the ``N`` it is handed: ``N``
+    where its tile (:func:`_tile_rows`) is at least TILE_MIN rows, or where
+    blocks are the unit anyway (one-row blocks, an int8 pool) or the call is
+    no wider than one tile; else ``N``
+    rounded up to a whole number of TILE_ROWS — dead rows behind the last,
+    which cost a grid step nothing — where that gives a wider tile. 24 +
+    1,024 = 8 x 131 rows have no tile past a block of 8 and 48 + 2,048 =
+    16 x 131 none past 16 (the narrow bodies of serving/programs.mixed_step
+    in two cells): 1,088 and 2,112 rows run as tiles of 64. Every count a
+    cell served before keeps its own (40-64). From the shapes alone; the
+    engine's record counts its page steps by the same function."""
+    bb = _resolve_bb(bblock, N)
+    tile = _tile_rows(N, bb, hq, d, ps, dtype)
+    if (bb == 1 or jnp.dtype(dtype) == jnp.int8 or tile >= TILE_MIN
+            or N <= TILE_ROWS):
+        return N
+    rows = -(-N // TILE_ROWS) * TILE_ROWS
+    wider = _tile_rows(rows, _resolve_bb(bblock, rows), hq, d, ps, dtype)
+    return rows if wider > tile else N
+
+
 def _shared_row(live, keys, width: int, dead: int = -1):
     """Per run of ``width`` packed rows: its first live row if every live
     row's key (``row_map``'s entry, [N]: the slot it names) equals that
@@ -994,6 +1020,14 @@ def ragged_attend_pallas_paged(q: jnp.ndarray, pool_k: jnp.ndarray,
         q = jnp.pad(q.reshape(N, hkv, groups, d),
                     ((0, 0), (0, 0), (0, padded - groups), (0, 0))
                     ).reshape(N, hkv * padded, d)
+    # a row count without a tile of its own gets dead rows behind it
+    rows = _ragged_pad(N, bblock, q.shape[1], d, pool_k.shape[3],
+                       pool_k.dtype)
+    if rows != N:
+        bb = _resolve_bb(bblock, rows)
+        q = jnp.pad(q, ((0, rows - N), (0, 0), (0, 0)))
+        row_limits = jnp.pad(row_limits, (0, rows - N))
+        row_map = jnp.pad(row_map, (0, rows - N))
     # a block, or a tile, whose live rows all name one slot shares it
     share, wide = _share_facts(q, pool_k, row_limits, row_map, bb)
     out = _paged_flash_db(
@@ -1001,7 +1035,7 @@ def ragged_attend_pallas_paged(q: jnp.ndarray, pool_k: jnp.ndarray,
         jnp.asarray(layer, jnp.int32).reshape(1), table.astype(jnp.int32),
         bb=bb, R=1, spec=False, window=window, interpret=interpret,
         pool_ks=pool_ks, pool_vs=pool_vs, share=share, wide=wide,
-        row_map=row_map)
+        row_map=row_map)[:N]
     if padded != groups:
         out = out.reshape(N, hkv, padded, d)[:, :, :groups].reshape(N, hq, d)
     return out
@@ -1065,6 +1099,26 @@ def decode_attend_pallas_paged_select(q, pool_k, pool_v, lengths, layer,
     return out[:, None]
 
 
+def _select_held(n: int, bb: int, hq: int, d: int, ps: int, dtype,
+                 slots: int) -> tuple:
+    """(tile, rows whose words ride SMEM) of a selecting ragged call of
+    ``n`` rows."""
+    tile = _tile_rows(n, bb, hq, d, ps, dtype)
+    return tile, n if tile == bb else min(n, slots * tile)
+
+
+def select_fits_one_call(n: int, bb: int, hq: int, d: int, ps: int, dtype,
+                         table_shape: tuple, hkv: int, words: int) -> bool:
+    """Do the prefetched operands of ONE selecting ragged call of ``n``
+    rows fit the SMEM it may take (``SELECT_PREFETCH_BYTES``)? From the
+    shapes alone: ragged_attend_pallas_paged_select cuts its rows by it,
+    and serving/programs.mixed_narrow_rows reads it."""
+    slots, pages = table_shape
+    held = _select_held(n, bb, hq, d, ps, dtype, slots)[1]
+    return n <= bb or 4 * (slots * pages + 3 * n + held * hkv * words) \
+        <= SELECT_PREFETCH_BYTES
+
+
 @functools.partial(jax.jit, static_argnames=("interpret", "bblock"))
 def ragged_attend_pallas_paged_select(q, pool_k, pool_v, row_limits, layer,
                                       table, row_map, bits,
@@ -1095,18 +1149,14 @@ def ragged_attend_pallas_paged_select(q, pool_k, pool_v, row_limits, layer,
     layer_arr = jnp.asarray(layer, jnp.int32).reshape(1)
     hkv, words = bits.shape[1:]
 
-    def held(n):
-        """(tile, rows whose words ride SMEM) of a call of ``n`` rows."""
-        tile = _tile_rows(n, bb, hq, d, pool_k.shape[3], pool_k.dtype)
-        return tile, n if tile == bb else min(n, table.shape[0] * tile)
-
     def call(lo, hi):
         rows = slice(lo, hi)
         share, wide = _share_facts(q[rows], pool_k, row_limits[rows],
                                    row_map[rows], bb)
         smem, kw = bits[rows], {}
         if wide is not None:
-            tile, keep = held(hi - lo)
+            tile, keep = _select_held(hi - lo, bb, hq, d, pool_k.shape[3],
+                                      pool_k.dtype, table.shape[0])
             by_tile = smem.reshape(-1, tile, hkv, words)
             blocks = wide == -1       # the steps that run block by block
             smem = by_tile[jnp.argsort(~blocks, stable=True)[:keep // tile]]
@@ -1128,8 +1178,8 @@ def ragged_attend_pallas_paged_select(q, pool_k, pool_v, row_limits, layer,
         independent, so more rows than fit go in further calls, each of
         whole blocks."""
         n = hi - lo
-        need = 4 * (table.size + 3 * n + held(n)[1] * hkv * words)
-        if n <= bb or need <= SELECT_PREFETCH_BYTES:
+        if select_fits_one_call(n, bb, hq, d, pool_k.shape[3], pool_k.dtype,
+                                table.shape, hkv, words):
             return [call(lo, hi)]
         mid = lo + -(-n // (2 * bb)) * bb
         return calls(lo, mid) + calls(mid, hi)

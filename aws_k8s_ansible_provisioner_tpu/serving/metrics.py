@@ -338,6 +338,12 @@ class EngineMetrics:
             "tpu_serve_decode_substeps_total",
             "Substeps the fused decode dispatches ran; over "
             "tpu_serve_decode_dispatches_total the mean count a dispatch"))
+        self.mixed_steps = r.register(Counter(
+            "tpu_serve_mixed_steps_total",
+            "Mixed dispatches by the body of the ONE mixed_step program "
+            "that ran: body=\"narrow\" the layers over the slots and half "
+            "a chunk's rows (the chunk's tokens fit them), body=\"wide\" "
+            "over the whole chunk's", ("body",)))
         self.kda_rows = r.register(Counter(
             "tpu_serve_kda_rows_total",
             "Rows that advanced a KDA layer's recurrent state, per layer, by "

@@ -28,7 +28,7 @@ import math
 import os
 import threading
 import time
-from functools import partial
+from functools import lru_cache, partial
 from typing import List, Optional
 
 import jax
@@ -447,6 +447,41 @@ def _recur(cfg: ModelConfig, make, *args):
     return make(*args) if cfg.recurrent else None
 
 
+@lru_cache(maxsize=None)
+def mixed_narrow_rows(cfg: ModelConfig, slots: int, C: int, page_size: int,
+                      bblock: int, pages: int, kv_dtype) -> int:
+    """Chunk rows of ``mixed_step``'s NARROW body — ``C // 2`` — or 0 where
+    the shapes admit one width only. The program holds its layers twice, over
+    ``slots + C`` and over ``slots + C // 2`` packed rows, and a chunk of at
+    most ``C // 2`` tokens (``mixed_takes_narrow``) runs the narrow one: the
+    rows it leaves out are dead in the wide one. One width where half a
+    chunk is no whole number of pool pages (the span write's windows) or of
+    the ragged kernel's row blocks at ``slots + C`` (the block, and with it
+    every tile, would shrink), and for a selecting model whose ``slots + C``
+    rows the ragged entry walks in several calls (the calls are cut from
+    the row count: pallas_attention.select_fits_one_call). Read from the
+    shapes, by the program (tracing) and by the engine (the dispatch
+    record's rows) alike."""
+    from aws_k8s_ansible_provisioner_tpu.ops.pallas_attention import (
+        _resolve_bb, select_fits_one_call)
+
+    W = C // 2
+    bb = _resolve_bb(bblock, slots + C)
+    if C % 2 or W == 0 or W % page_size or W % bb:
+        return 0
+    if cfg.selects and not select_fits_one_call(
+            slots + C, bb, cfg.num_heads, cfg.pool_head_dim, page_size,
+            kv_dtype, (slots, pages), cfg.pool_kv_heads, -(-pages // 32)):
+        return 0
+    return W
+
+
+def mixed_takes_narrow(plen, narrow: int):
+    """Does a chunk of ``plen`` tokens run ``mixed_step``'s narrow body?
+    ``plen`` the program's traced operand or the engine's int."""
+    return plen <= narrow
+
+
 @partial(jax.jit, static_argnums=(0,),
          static_argnames=("logprobs", "prompt_logprobs"),
          donate_argnums=(2,))
@@ -771,8 +806,8 @@ def mixed_step(cfg: ModelConfig, params, cache, tokens, lengths, ptokens,
     2604.15464).
 
     Layout: the forward pass runs ONCE over a query-token-packed sequence
-    ``[1, B + C]`` — B decode rows (token ``tokens[b]`` at position
-    ``lengths[b]``), then the C chunk rows of ``ptokens`` at positions
+    ``[1, B + W]`` — B decode rows (token ``tokens[b]`` at position
+    ``lengths[b]``), then the first W chunk rows of ``ptokens`` at positions
     ``pstart + j``. MLP/norm/projections are per-token, so packing changes
     nothing; attention goes through make_mixed_attend_carry_paged, whose
     per-row (write row, live-column limit, page-table row) metadata gives
@@ -780,11 +815,27 @@ def mixed_step(cfg: ModelConfig, params, cache, tokens, lengths, ptokens,
     gave it — byte-identical streams either way (pinned by
     tests/test_decode_pipeline.py's ragged parity cases).
 
+    TWO widths, one program: ``ptokens`` arrives padded to the chunk width
+    C = ``ptokens.shape[1]`` whatever the chunk holds, and the program holds
+    its layers (``body``: everything whose shape has the chunk's rows in
+    it) twice — over W = C rows and over W = C // 2, the first half of
+    ``ptokens`` — and runs the narrow body where ``plen`` fits it
+    (``mixed_takes_narrow``; the engine's record states the rows that ran
+    by the same predicate). The projections and the MLP run over every
+    packed row whether it holds a token or not, so a prompt of a quarter of
+    the chunk pays for half the rows, not all. Where the shapes admit no
+    second width (``mixed_narrow_rows``: read from the shapes, no option)
+    the program is the one body. What comes out of a body — the sampled
+    rows' logits ``[B + 1, V]``, the pool, the routing summary, the page
+    counts — has no W in its shape; the sampling tail below is one.
+
     ``pslot``'s own decode row is a dead passenger while it chunks, and so
-    is every chunk row at or past ``plen`` (the chunk arrives padded to C):
-    a dead row's K/V is not written (the decode row's write row is -1, the
-    chunk is written as the span [pstart, pstart + plen)) and it attends
-    nothing (limit 0), which costs the ragged kernel nothing. For ``pslot``
+    is every chunk row at or past ``plen``: a dead row's K/V is not written
+    (the decode row's write row is -1, the chunk is written as the span
+    [pstart, pstart + plen)), it attends nothing (limit 0), which costs the
+    ragged kernel nothing, it is routed to no expert and advances no state
+    — so the rows ``[C // 2, C)`` the narrow body leaves out are rows that
+    changed nothing in the wide one. For ``pslot``
     the returned carry overrides its lanes with the chunk's sample
     (``tok_out[pslot] = chunk token``, ``lens_out[pslot] = pstart + plen``)
     so the device carry matches the host mirrors a final-chunk activation
@@ -803,7 +854,7 @@ def mixed_step(cfg: ModelConfig, params, cache, tokens, lengths, ptokens,
     FSM bitsets, all-ones elsewhere); ``pallow`` [1, ceil(V/32)] masks the
     chunk row when the CHUNKING request itself is guided. Both are program
     variants (None = compiled out). ``lora_idx`` [B] per-slot adapter
-    indices are packed in-program to per-TOKEN indices over the [1, B + C]
+    indices are packed in-program to per-TOKEN indices over the [1, B + W]
     layout (the chunk rows inherit ``lora_idx[pslot]``), selecting each
     row's A/B delta inside one shared program (models/layers._linear's
     per-token branch).
@@ -817,55 +868,89 @@ def mixed_step(cfg: ModelConfig, params, cache, tokens, lengths, ptokens,
     B = tokens.shape[0]
     C = ptokens.shape[1]
     is_p = jnp.arange(B, dtype=jnp.int32) == pslot
-    crows = pstart + jnp.arange(C, dtype=jnp.int32)
-    # the chunk is padded to C rows: rows past the prompt are dead too
-    is_pad = jnp.arange(C, dtype=jnp.int32) >= plen
-    row_limits = jnp.concatenate(
-        [jnp.where(is_p, jnp.int32(0), lengths + 1),
-         jnp.where(is_pad, jnp.int32(0), crows + 1)])
-    # which row of ``table`` (one a SLOT) each packed row reads: a decode
-    # row its own slot's, every chunk row pslot's
-    row_map = jnp.concatenate(
-        [jnp.arange(B, dtype=jnp.int32),
-         jnp.broadcast_to(pslot.astype(jnp.int32), (C,))])
-    packed = jnp.concatenate([tokens[None], ptokens], axis=1)     # [1, B+C]
-    positions = jnp.concatenate(
-        [jnp.where(is_p, jnp.int32(0), lengths)[None], crows[None]], axis=1)
-    # K/V writes: one row a decode slot (pslot's own dropped), the chunk as
-    # the span [pstart, pstart + plen) of pslot's page run
-    if cfg.selects:
-        attend = _sa.make_mixed_attend_select(
-            cfg, jnp.where(is_p, jnp.int32(-1), lengths), pstart, plen,
-            row_limits, table, row_map, impl=impl, bblock=bblock, live=live)
+
+    def body(W: int, cache):
+        """The layers over ``B + W`` packed rows: the chunk's first ``W``
+        (static). Returns (cache, logits [B + 1, V], routing stats, picked
+        pages): nothing with ``W`` in its shape."""
+        crows = pstart + jnp.arange(W, dtype=jnp.int32)
+        # the chunk is padded to W rows: rows past the prompt are dead too
+        is_pad = jnp.arange(W, dtype=jnp.int32) >= plen
+        row_limits = jnp.concatenate(
+            [jnp.where(is_p, jnp.int32(0), lengths + 1),
+             jnp.where(is_pad, jnp.int32(0), crows + 1)])
+        # which row of ``table`` (one a SLOT) each packed row reads: a
+        # decode row its own slot's, every chunk row pslot's
+        row_map = jnp.concatenate(
+            [jnp.arange(B, dtype=jnp.int32),
+             jnp.broadcast_to(pslot.astype(jnp.int32), (W,))])
+        packed = jnp.concatenate([tokens[None], ptokens[:, :W]],
+                                 axis=1)                       # [1, B+W]
+        positions = jnp.concatenate(
+            [jnp.where(is_p, jnp.int32(0), lengths)[None], crows[None]],
+            axis=1)
+        # K/V writes: one row a decode slot (pslot's own dropped), the chunk
+        # as the span [pstart, pstart + plen) of pslot's page run
+        if cfg.selects:
+            attend = _sa.make_mixed_attend_select(
+                cfg, jnp.where(is_p, jnp.int32(-1), lengths), pstart, plen,
+                row_limits, table, row_map, impl=impl, bblock=bblock,
+                live=live)
+        else:
+            attend = _attend(
+                cfg, lambda t, w, kind: make_mixed_attend_carry_paged(
+                    jnp.where(is_p, jnp.int32(-1), lengths), pstart, plen,
+                    row_limits, t, row_map, impl=impl, mesh=mesh, window=w,
+                    bblock=bblock, of_window_kind=kind), table, wtable)
+        # Per-TOKEN adapter indices over the packed layout: decode row b
+        # keeps its slot's adapter, every chunk row runs the chunking
+        # slot's — one program serves any adapter mix (models/layers._linear
+        # gathers factors per token when the index rank matches x's row
+        # rank).
+        packed_lora = None
+        if lora_idx is not None:
+            packed_lora = jnp.concatenate(
+                [lora_idx, jnp.broadcast_to(lora_idx[pslot], (W,))])[None]
+        packed_live = None
+        if live is not None:
+            packed_live = jnp.concatenate([live & ~is_p, ~is_pad])
+        recur = None
+        if cfg.recurrent:   # (not through _recur: its operands would be traced)
+            recur = _la.make_recur_mixed(
+                B, None if live is None else live & ~is_p, pslot, pstart,
+                plen)
+        with lora_context(packed_lora), \
+                _moe.routed_rows(packed_live) as routing, \
+                _sa.counting() as picked:
+            # the head over the rows that are sampled: every decode row and
+            # the chunk's last valid one — [B + 1, V], not [B + W, V]
+            logits, cache = model_forward_carry(
+                params, cfg, packed, positions, cache, attend, recur,
+                head_rows=jnp.concatenate(
+                    [jnp.arange(B, dtype=jnp.int32), (B + plen - 1)[None]]))
+        return cache, logits, routing["stats"], picked["pages"]
+
+    narrow = mixed_narrow_rows(cfg, B, C, cache["k"].shape[3], bblock,
+                               table.shape[1], cache["k"].dtype)
+    if narrow:
+        # Both bodies in the ONE program, each inside a loop that runs once
+        # or not at all: the operand the program already has picks. A loop,
+        # not ``lax.cond``: the pool rides a loop's carry in place (as
+        # through decode_steps' substeps), while under a conditional the
+        # TPU compiler copied it into and out of one branch's layer loop
+        # at EVERY layer (deviceless compile, PR 55: two pool-sized copies
+        # a leaf a layer in the 8B's, OLMoE's and Falcon-H1's programs —
+        # 16.7 GB asked of the 8B cell's 15.75).
+        takes = mixed_takes_narrow(plen, narrow).astype(jnp.int32)
+        state = (cache,) + jax.tree.map(
+            lambda y: jnp.zeros(y.shape, y.dtype),
+            jax.eval_shape(partial(body, C), cache)[1:])
+        for rows, times in ((narrow, takes), (C, 1 - takes)):
+            state = jax.lax.fori_loop(
+                0, times, lambda _, st, rows=rows: body(rows, st[0]), state)
+        cache, logits, moe, picked = state
     else:
-        attend = _attend(
-            cfg, lambda t, w, kind: make_mixed_attend_carry_paged(
-                jnp.where(is_p, jnp.int32(-1), lengths), pstart, plen,
-                row_limits, t, row_map, impl=impl, mesh=mesh, window=w,
-                bblock=bblock, of_window_kind=kind), table, wtable)
-    # Per-TOKEN adapter indices over the packed layout: decode row b keeps
-    # its slot's adapter, every chunk row runs the chunking slot's — one
-    # program serves any adapter mix (models/layers._linear gathers factors
-    # per token when the index rank matches x's row rank).
-    packed_lora = None
-    if lora_idx is not None:
-        packed_lora = jnp.concatenate(
-            [lora_idx, jnp.broadcast_to(lora_idx[pslot], (C,))])[None]
-    packed_live = None
-    if live is not None:
-        packed_live = jnp.concatenate([live & ~is_p, ~is_pad])
-    recur = None
-    if cfg.recurrent:   # (not through _recur: its operands would be traced)
-        recur = _la.make_recur_mixed(
-            B, None if live is None else live & ~is_p, pslot, pstart, plen)
-    with lora_context(packed_lora), _moe.routed_rows(packed_live) as routing, \
-            _sa.counting() as picked:
-        # the head over the rows that are sampled: every decode row and the
-        # chunk's last valid one — [B + 1, V], not [B + C, V]
-        logits, cache = model_forward_carry(
-            params, cfg, packed, positions, cache, attend, recur,
-            head_rows=jnp.concatenate(
-                [jnp.arange(B, dtype=jnp.int32), (B + plen - 1)[None]]))
+        cache, logits, moe, picked = body(C, cache)
     with jax.named_scope(parts.SAMPLE):
         # -- decode rows: the decode_steps substep body, verbatim order ----
         dec_logits = logits[:B]
@@ -906,8 +991,7 @@ def mixed_step(cfg: ModelConfig, params, cache, tokens, lengths, ptokens,
                tuple(a[None] for a in _logprob_topk(dec_logits, nxt))) \
             if logprobs else nxt[None]
         pout = (ptok, _logprob_topk(plast, ptok)) if chunk_logprobs else ptok
-    return cache, counts, out, pout, tok_out, lens_out, \
-        _aux(routing["stats"], picked["pages"])
+    return cache, counts, out, pout, tok_out, lens_out, _aux(moe, picked)
 
 
 @partial(jax.jit, static_argnums=(0, 1), static_argnames=("impl", "mesh",
@@ -1733,6 +1817,11 @@ class EnginePrograms:
                        win_pages_copied=copied, **self._attn_layers())
         return out
 
+    @property
+    def _kv_dtype(self):
+        """The pool's element type, as the kernels' shape rules read it."""
+        return jnp.int8 if self.kv_quant else self.serving.dtype
+
     def _attn_layers(self) -> dict:
         """Dispatch-record fields of a list with window layers beside full
         ones: how many attending layers of each kind a forward pass runs,
@@ -1750,7 +1839,8 @@ class EnginePrograms:
         """Dispatch-record fields of a mixed dispatch, per attending layer:
         ``chunk_page_steps`` — the page steps (one page fetched and folded
         into a flash state) the ragged kernel walks for the chunk's ``n``
-        live rows at ``off`` of a ``C``-row chunk, its grid steps cut as
+        live rows at ``off`` of a ``C``-row chunk (the width of the body
+        of ``mixed_step`` that runs them), its grid steps cut as
         the device cuts them: a tile of pallas_attention._tile_rows rows
         that holds chunk rows only is ONE walk from its lowest row's first
         page to its highest row's last, one that also holds decode rows
@@ -1765,15 +1855,19 @@ class EnginePrograms:
         full ones: those two are a FULL layer's, ``win_chunk_page_steps``
         / ``win_chunk_page_steps_by8`` a window layer's."""
         from aws_k8s_ansible_provisioner_tpu.ops.pallas_attention import (
-            _resolve_bb, _tile_rows)
+            _ragged_pad, _resolve_bb, _tile_rows)
 
         ps, B = self.serving.page_size, self.num_slots
         tp = self.mesh.shape.get("tp", 1) if self.mesh is not None else 1
-        bb = _resolve_bb(self.decode_bblock, B + C)
-        tile = _tile_rows(
-            B + C, bb, self.cfg.num_heads // tp, self.cfg.pool_head_dim, ps,
-            jnp.int8 if self.kv_quant else self.serving.dtype)
-        limits = np.zeros(B + C, np.int64)
+        shape = (self.cfg.num_heads // tp, self.cfg.pool_head_dim, ps,
+                 self._kv_dtype)
+        # (the plain entry pads a row count that has no tile of its own;
+        # the selecting one takes its rows as they are)
+        N = B + C if self.cfg.selects \
+            else _ragged_pad(B + C, self.decode_bblock, *shape)
+        bb = _resolve_bb(self.decode_bblock, N)
+        tile = _tile_rows(N, bb, *shape)
+        limits = np.zeros(N, np.int64)
         limits[B:B + n] = off + 1 + np.arange(n)
         hi = np.minimum(-(-limits // ps), self.pages_per_slot) - 1
 
@@ -1825,7 +1919,9 @@ class EnginePrograms:
         rows live, ``given`` the static key and the per-kind facts
         (horizon = the substeps the dispatch RUNS, with horizon_why =
         what chose that count for a decode dispatch: ``_decode_horizon``;
-        chunk_rows, chunk_n, chunk_off, bucket, rows,
+        chunk_rows = the chunk rows the program's layers run over (of a
+        mixed step: the width of the body that runs), chunk_n, chunk_off,
+        bucket, rows,
         prompt_tokens, padded_tokens = the rows a prefill-type program's
         layers run over, head_rows = the rows its head runs over: one a
         sampled row, every row in a prompt_logprobs variant,
@@ -1948,6 +2044,10 @@ class EnginePrograms:
                     full * rec["chunk_page_steps" + sfx]
                     + win * rec.get("win_chunk_page_steps" + sfx, 0),
                     path=path)
+        if rec["program"] == "mixed_step":
+            self.metrics.mixed_steps.inc(
+                body="narrow" if rec["chunk_rows"] < self._chunk_size
+                else "wide")
         if "horizon_why" in rec:
             self.metrics.decode_dispatches.inc(
                 substeps="whole" if rec["horizon"] >= max(
@@ -2584,7 +2684,13 @@ class EnginePrograms:
         """Enqueue ONE ragged mixed dispatch (prefill chunk + decode batch)
         and return its in-flight record. Async half only — no blocking
         device reads here (tpulint R8); the transfer and emits happen in
-        _decode_fetch, which also unpacks the chunk-row outputs."""
+        _decode_fetch, which also unpacks the chunk-row outputs. The
+        program gets the chunk padded to ``C`` rows always and runs one of
+        its two bodies (``mixed_step``); the record's ``chunk_rows``,
+        ``padded_tokens``, page steps are those of the rows that RUN, by the
+        program's own two functions (``mixed_narrow_rows``,
+        ``mixed_takes_narrow``) — the benchmark's readers compute a step's
+        need and fill from them."""
         req, slot, off = st["req"], st["slot"], st["off"]
         ids = st["walk"]
         active = [s for s in self._active_slots() if s != slot]
@@ -2602,7 +2708,12 @@ class EnginePrograms:
             or (self.rep_pens != 1.0).any())
         chunk_lp = (req.logprobs is not None and not st["resumed"]
                     and off + len(chunk) >= len(ids))
-        tokens = np.zeros((1, st["C"]), np.int32)
+        C = st["C"]
+        narrow = mixed_narrow_rows(
+            self.cfg, self.num_slots, C, self.serving.page_size,
+            self.decode_bblock, self.pages_per_slot, self._kv_dtype)
+        W = narrow if mixed_takes_narrow(len(chunk), narrow) else C
+        tokens = np.zeros((1, C), np.int32)
         tokens[0, :len(chunk)] = chunk
         allow = self._allow_words(gslots)
         pallow = self._allow_row(req)
@@ -2620,9 +2731,9 @@ class EnginePrograms:
         ps = self.serving.page_size
         drec = self._dispatch_open(
             "mixed_step", "mixed_step", active, horizon=1,
-            chunk_rows=st["C"], chunk_n=len(chunk), chunk_off=off,
+            chunk_rows=W, chunk_n=len(chunk), chunk_off=off,
             write_pages=(off + len(chunk) - 1) // ps - off // ps + 1,
-            padded_tokens=self.num_slots + st["C"],
+            padded_tokens=self.num_slots + W,
             head_rows=self.num_slots + 1,
             sample_rows=int((self.temps > 0).sum()
                             + (req.temperature > 0)),
@@ -2630,7 +2741,7 @@ class EnginePrograms:
             **self._kda_rows(len(active) + len(chunk), len(active),
                              span=len(chunk)),
             **self._attn_layers(),
-            **self._chunk_page_steps(st["C"], off, len(chunk)))
+            **self._chunk_page_steps(W, off, len(chunk)))
         self._book_bubble(drec["t_enqueue"])
         real_counts = self.counts
         with _Dispatching(drec):

@@ -533,6 +533,12 @@ def run_requests(srv: Server, model: str) -> dict:
     say(f"requests: the admitted chunks' rows walked {int(tile)} page steps "
         f"as the ragged kernel's tiles are cut; blocks of 8 rows would "
         f"have walked {int(by8)}")
+    narrow, wide = (delta(f'tpu_serve_mixed_steps_total{{body="{b}"}}')
+                    for b in ("narrow", "wide"))
+    check(narrow + wide >= 1, "no mixed_step dispatch was counted by body")
+    say(f"requests: mixed_step ran its layers over half a chunk's rows "
+        f"{int(narrow)} times (the chunks that fit them) and over the whole "
+        f"chunk's {int(wide)}")
     say(f"requests: /metrics tokens +{int(got)}, prefix hits +{int(hits)}, "
         f"0 error/timeout; programs dispatched: {sorted(ran)}")
     return streams

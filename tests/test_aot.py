@@ -125,6 +125,24 @@ def test_enumeration_covers_every_program_family():
     assert not any(n.startswith("spec_verify") for n in names2)
 
 
+def test_enumeration_holds_one_mixed_program_a_variant():
+    """``mixed_step`` runs its layers at two widths INSIDE one program (the
+    chunk's length, an operand, picks): the set that is compiled ahead keeps
+    ONE entry a variant, at the one chunk width its operands have."""
+    plan = ProgramPlan(tiny_qwen3(), _tiny_serving(
+        decode_pipeline=1, ragged_attention=1, ragged_features=1))
+    assert aot_programs.mixed_narrow_rows(
+        plan.cfg, plan.num_slots, plan.chunk, 8, 1, plan.pages_per_slot,
+        jnp.float32) == plan.chunk // 2
+    mixed = [p for p in enumerate_programs(
+        plan, None, *aot._abstract_state(plan, None))
+        if p[1] is aot_programs.mixed_step]
+    assert [p[0] for p in mixed] == [f"mixed_c{plan.chunk}",
+                                     f"mixed_c{plan.chunk}_guided"]
+    for _, _, args, _ in mixed:
+        assert args[5].shape == (1, plan.chunk)
+
+
 def test_sharded_bytes_divides_by_mesh_axes(cpu_devices):
     """Per-chip ledger bytes: tp=2 halves the KV pool (heads sharded) and
     shrinks params; replicated leaves (norms) still count whole."""

@@ -459,6 +459,14 @@ _WIDE_GRID = [
     ("n168-t56-window-released", 168, 8, 300, 130,
      {"window": 48, "groups": 8, "Hkv": 1}),
     ("n168-t56-window-one-page", 168, 8, 0, 11, {"window": 48}),
+    # a row count with no tile of its own (8 x 11, 16 x 11: the narrow
+    # bodies' 1,048 = 8 x 131 and 2,096 = 16 x 131 scaled down) runs with
+    # dead rows behind it, as tiles of 64 (PR 55: _ragged_pad)
+    ("n88-pads-to-128", 88, 8, 5, 70, {}),
+    ("n88-pads-to-128-dead-tiles", 88, 8, 0, 9, {}),
+    ("n176-pads-to-192", 176, 8, 33, 150, {}),
+    ("n88-pads-to-128-window-released", 88, 8, 150, 60, {"window": 48}),
+    ("n88-int8-keeps-its-rows", 88, 8, 37, 70, {"quant": True}),
 ]
 
 
@@ -488,6 +496,38 @@ def test_tile_rows_come_from_the_shapes():
     # ride a page's lanes, so its sharing blocks stay 8 rows
     assert pa._tile_rows(2080, 1, 16, 128, 64, jnp.bfloat16) == 1
     assert pa._tile_rows(2080, 8, 16, 128, 64, jnp.int8) == 8
+
+
+def test_a_row_count_without_a_tile_is_padded_to_one():
+    """The plain ragged entry runs a row count whose widest tile is under
+    32 rows with dead rows behind it, a whole number of 64: the two narrow
+    bodies of ``mixed_step`` that need it (OLMoE's 24 + 1,024, the
+    window/full list's 48 + 2,048). Every count a cell served before PR 55,
+    the other narrow bodies, blocks of one row and an int8 pool keep
+    theirs (the SELECTING entry takes its rows as they are: 24 + 4,608
+    and 24 + 2,304 run as tiles of 24)."""
+    bf = jnp.bfloat16
+    assert pa._ragged_pad(1048, 8, 16, 128, 64, bf) == 1088
+    assert pa._ragged_pad(2096, 8, 32, 128, 64, bf) == 2112
+    assert pa._tile_rows(1088, 8, 16, 128, 64, bf) == 64
+    assert pa._tile_rows(2112, 8, 32, 128, 64, bf) == 64
+    for n, hq in ((2080, 16), (2064, 32), (2072, 16), (4144, 32), (576, 16),
+                  (640, 16), (576, 20), (1056, 16), (1040, 32), (320, 16),
+                  (384, 16)):
+        assert pa._ragged_pad(n, 8, hq, 128, 64, bf) == n, n
+    assert pa._ragged_pad(1048, 1, 16, 128, 64, bf) == 1048
+    assert pa._ragged_pad(1048, 8, 16, 128, 64, jnp.int8) == 1048
+    # the traced call: 88 rows in, 128 run, 88 out
+    seen, real = [], pa._paged_flash_db
+    try:
+        pa._paged_flash_db = lambda q, *a, **k: seen.append(q.shape[0]) \
+            or real(q, *a, **k)
+        jax.clear_caches()
+        out, _, _ = _wide_case(88, 8, 5, 70)
+    finally:
+        pa._paged_flash_db = real
+        jax.clear_caches()
+    assert seen == [128] and out.shape[0] == 88
 
 
 @pytest.mark.parametrize("case", ["n168-t56", "n120-t40-window",

@@ -373,6 +373,73 @@ def test_mixed_step_holds_no_pool_copy(chip, monkeypatch, model, slots):
     # the ragged one (the names the benchmark's readers match)
     _assert_named_after_wrapper(compiled, pa.cache_write_row_paged)
     _assert_named_after_wrapper(compiled, pa.ragged_attend_pallas_paged)
+    # and the program holds its layers at BOTH widths (PR 55): activations
+    # of slots + 2,048 and of slots + 1,024 rows, a ragged call each, the
+    # donated pool through both in place (the makers above) — under a
+    # ``lax.cond`` the 8B's and OLMoE's wide layer loop copied each pool
+    # leaf in and out at every layer, 16.7 GB asked of the chip's 15.75
+    for rows in (slots + CHUNK, slots + CHUNK // 2):
+        assert re.search(rf"bf16\[1,{rows},{cfg.hidden_size}\]", text), rows
+        # (OLMoE's 1,048 = 8 x 131 rows run as 1,088: a tile of 64)
+        ran = pa._ragged_pad(rows, 8, cfg.num_heads, cfg.pool_head_dim, PS,
+                             jnp.bfloat16)
+        assert ran == rows or (slots, rows) == (24, 1048)
+        assert re.search(rf"ragged_attend_pallas_paged\S* = bf16\[{ran},",
+                         text), rows
+
+
+@pytest.mark.parametrize("cell,chunk,pool", [
+    ("minicpm-sala-9b-pp4", 4608, (2, 12289, 2, 64, 128)),
+    ("trinity-mini-26b-pp4", 4096, (2, 6913, 4, 64, 128))])
+def test_long_chunk_cells_mixed_step_holds_both_widths_in_place(
+        chip, monkeypatch, cell, chunk, pool):
+    """The two cells whose chunk is longest, at their served shapes (the
+    cell's own file: model, server flags): the selecting hybrid's 24 +
+    4,608 / 24 + 2,304 rows (one selecting ragged call a body; tiles of 24
+    at either width) and the window/full list's 48 + 4,096 / 48 + 2,048
+    (two pools, two tables) compile for the chip with every pool leaf made
+    by its writers alone."""
+    import json
+    import os
+    import re
+
+    from aws_k8s_ansible_provisioner_tpu.config import ModelConfig
+    from aws_k8s_ansible_provisioner_tpu.serving import aot, server
+    from aws_k8s_ansible_provisioner_tpu.serving import programs as pg
+
+    monkeypatch.setattr(pa, "supported", lambda: True)
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmark", "configs", cell + ".json"),
+              encoding="utf-8") as f:
+        file = json.load(f)
+    cfg = ModelConfig(**file["model_config"])
+    serving = server.serving_config_from_args(
+        server.build_parser().parse_args(file["server_flags"]))
+    plan = aot.ProgramPlan(cfg, serving)
+    slots = plan.num_slots
+    assert pg.mixed_narrow_rows(cfg, slots, chunk, serving.page_size, 8,
+                                plan.pages_per_slot, jnp.bfloat16) \
+        == chunk // 2
+    params, cache = aot._abstract_state(plan, None,
+                                        next(iter(chip.device_set)))
+    assert cache["k"].shape == pool and cache["k"].dtype == jnp.bfloat16
+    _, fn, args, kwargs = next(
+        p for p in aot.enumerate_programs(plan, None, params, cache,
+                                          bblock=8)
+        if p[0] == f"mixed_c{chunk}")
+    compiled = fn.lower(*args, **kwargs).compile()
+    text = compiled.as_text()
+    for name in ("k", "wk"):
+        if name not in cache:
+            continue
+        shape = re.escape("bf16[" + ",".join(map(str, cache[name].shape))
+                          + "]")
+        makers = set(re.findall(rf" = {shape}\S* ([\w\-]+)\(", text))
+        assert makers <= {"parameter", "get-tuple-element", "custom-call",
+                          "scatter", "fusion"}, (name, makers)
+    for rows in (slots + chunk, slots + chunk // 2):
+        assert re.search(rf"bf16\[1,{rows},{cfg.hidden_size}\]", text), rows
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2**30
 
 
 def test_batched_prefill_holds_no_every_row_logits(chip, monkeypatch):
